@@ -14,8 +14,6 @@ from typing import Any, Callable, Optional
 
 import jax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  (aliases drifted jax APIs)
-
 __version__ = "0.2.0"
 
 from deepspeed_tpu.accelerator import get_accelerator  # noqa: F401

@@ -103,5 +103,5 @@ class Accelerator(abc.ABC):
 
     # --- flops -------------------------------------------------------------
     def peak_tflops(self, dtype: str = "bf16") -> float:
-        """Advertised peak TFLOPS per chip for MFU math; 0 when unknown."""
-        return 0.0
+        """Published peak TFLOP/s of one chip, for utilization math."""
+        raise ValueError(f"the {self._name} accelerator has no published peak")

@@ -23,15 +23,33 @@ def _detect() -> Accelerator:
     if override == "tpu":
         return TPUAccelerator()
 
-    try:
-        import jax
-        platform = jax.local_devices()[0].platform
-    except Exception:
-        platform = "cpu"
-    # Treat any non-cpu XLA platform (tpu, experimental tunnels) as the TPU path.
-    if platform != "cpu":
+    import jax
+    # no guessing: a probe that fails, or a platform this package has no
+    # accelerator for, is an error — never a silent CPU or TPU stand-in
+    platform = jax.local_devices()[0].platform
+    if platform == "tpu":
         return TPUAccelerator()
-    return CPUAccelerator()
+    if platform == "cpu":
+        return CPUAccelerator()
+    raise RuntimeError(
+        f"no accelerator for jax platform {platform!r} (tpu | cpu); set "
+        "DSTPU_ACCELERATOR to force one")
+
+
+def require_tpu(who: str) -> list:
+    """For scripts that measure or prove something about the chip: jax's
+    devices when they are TPUs, otherwise one line and a nonzero exit. A run
+    that finds no chip fails; it never falls back to the CPU."""
+    import jax
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:             # no backend could be initialised
+        raise SystemExit(f"{who}: no TPU: {str(e).splitlines()[0]}")
+    if devices[0].platform != "tpu":
+        raise SystemExit(f"{who}: no TPU: jax found platform "
+                         f"{devices[0].platform!r}, and this script never "
+                         "runs on the CPU")
+    return devices
 
 
 def get_accelerator() -> Accelerator:

@@ -8,14 +8,16 @@ from typing import Any, List
 
 from deepspeed_tpu.accelerator.abstract_accelerator import Accelerator
 
-# chip generation -> peak dense TFLOPS (bf16). Public figures.
-_PEAK_TFLOPS_BF16 = {
-    "v4": 275.0,
-    "v5 lite": 197.0,   # v5e
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6 lite": 918.0,   # trillium
-    "v6e": 918.0,
+# Peak dense TFLOP/s (TOP/s for int8) of one chip, keyed by the device_kind
+# jax reports. Source: Google Cloud TPU documentation, the "System
+# architecture" page of each generation (v4: 275 bf16; v5e: 197 bf16 / 393
+# int8; v5p: 459 bf16 / 918 int8; v6e: 918 bf16 / 1836 int8). A kind or dtype
+# that is not here is an error, not a default.
+_PEAK_TFLOPS = {
+    "TPU v4": {"bf16": 275.0},
+    "TPU v5 lite": {"bf16": 197.0, "int8": 393.0},      # v5e
+    "TPU v5": {"bf16": 459.0, "int8": 918.0},           # v5p
+    "TPU v6 lite": {"bf16": 918.0, "int8": 1836.0},     # v6e (Trillium)
 }
 
 
@@ -33,16 +35,10 @@ class TPUAccelerator(Accelerator):
         return "ici+dcn"
 
     def peak_tflops(self, dtype: str = "bf16") -> float:
-        devs = self.devices()
-        if not devs:
-            return 0.0
-        kind = getattr(devs[0], "device_kind", "").lower()
-        for key, tflops in _PEAK_TFLOPS_BF16.items():
-            if key in kind:
-                scale = 1.0
-                if dtype in ("int8", "fp8"):
-                    scale = 2.0
-                elif dtype == "fp32":
-                    scale = 0.5
-                return tflops * scale
-        return 0.0
+        kind = self.devices()[0].device_kind
+        try:
+            return _PEAK_TFLOPS[kind][dtype]
+        except KeyError:
+            raise ValueError(
+                f"no published peak for device_kind {kind!r} in {dtype!r} "
+                f"(table: {_PEAK_TFLOPS})") from None

@@ -27,7 +27,7 @@ Scope — what CAN be bounded on TPU:
   carries "last-completed comm op" per worker and a wedged device shows
   up as a stalled op sequence + stale heartbeat.
 
-Outcome taxonomy (every guarded call is classified, never just raised):
+Outcome classes (every guarded call is classified, never just raised):
 
   ok        completed inside the deadline
   timeout   wedged past the deadline -> ``CommWedgeError``
@@ -115,7 +115,7 @@ class CommGuardConfig(DeepSpeedTPUConfigModel):
 
 
 # ---------------------------------------------------------------------------
-# fault taxonomy
+# fault classes
 # ---------------------------------------------------------------------------
 class CommFaultError(RuntimeError):
     """Base class for classified comm faults. Carries the op name, the

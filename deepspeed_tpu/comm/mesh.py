@@ -84,23 +84,17 @@ def create_mesh(cfg: Optional[MeshConfig] = None,
     sizes = resolve_axis_sizes(cfg, len(devices))
     shape = tuple(sizes[a] for a in MESH_AXES)
 
+    # no flat-reshape fallback: on real chips a failure here is a topology
+    # problem (a shape the slice cannot hold, DCN axes on a single slice)
+    from jax.experimental import mesh_utils
     dcn_axes = list(cfg.dcn_axes or [])
     if dcn_axes:
-        from jax.experimental import mesh_utils
         ici_shape = tuple(1 if a in dcn_axes else sizes[a] for a in MESH_AXES)
         dcn_shape = tuple(sizes[a] if a in dcn_axes else 1 for a in MESH_AXES)
-        try:
-            device_array = mesh_utils.create_hybrid_device_mesh(
-                ici_shape, dcn_shape, devices=devices)
-        except Exception as e:  # single-slice / CPU: no slice_index attribute
-            logger.warning(f"hybrid mesh unavailable ({e}); falling back to flat mesh")
-            device_array = np.asarray(devices).reshape(shape)
+        device_array = mesh_utils.create_hybrid_device_mesh(
+            ici_shape, dcn_shape, devices=devices)
     else:
-        try:
-            from jax.experimental import mesh_utils
-            device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            device_array = np.asarray(devices).reshape(shape)
+        device_array = mesh_utils.create_device_mesh(shape, devices=devices)
 
     mesh = Mesh(device_array, MESH_AXES)
     log_dist(f"created mesh {dict(zip(MESH_AXES, shape))} over {len(devices)} devices",

@@ -401,8 +401,7 @@ class InferenceEngineV2:
                 block_size=self.kv.cfg.block_size,
                 attn_impl=self.config.attn_impl)
             # sample on device; only [B] token ids cross to the host — the
-            # [B, vocab] logits D2H fetch is the decode-loop bottleneck on
-            # tunneled / multi-host topologies
+            # [B, vocab] logits D2H fetch would dominate the decode loop
             toks = self._sample_batch(logits)
             for j, seq in enumerate(seqs):
                 tok = int(toks[j])

@@ -3,8 +3,7 @@
 Reference analog: the reference's FastGen pipeline samples in MII; the engine
 itself shipped argmax. Here sampling is a first-class jitted device-side op so
 the serving loop fetches only the sampled token ids ([B] int32, a few bytes)
-instead of the full [B, vocab] logits every step — on a tunneled or multi-host
-topology the logits D2H round trip is the decode bottleneck, not compute.
+instead of the full [B, vocab] logits every step.
 """
 
 import dataclasses

@@ -26,7 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 # logical activation axes -> mesh axes
 from deepspeed_tpu.comm.mesh import BATCH_AXES  # ("data", "fsdp_out", "fsdp")
@@ -85,36 +85,40 @@ TINY_LLAMA = LlamaConfig(vocab_size=512, hidden_size=128, intermediate_size=256,
                          num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=256)
 
 
+def _fit_spec(shape, spec: Tuple, mesh, names) -> Tuple:
+    """``spec`` restricted to the mesh axes in ``names``. An entry whose axes
+    do not divide its dimension is dropped (that dimension stays replicated):
+    the params-init trace runs the model on a 2-row example batch whatever
+    the mesh, and a hand-built mesh may lack canonical axes (fsdp_out)."""
+    def fit(dim, entry):
+        axes = entry if isinstance(entry, (tuple, list)) else (entry,)
+        kept = tuple(a for a in axes if a in names)
+        if not kept or dim % int(np.prod([mesh.shape[a] for a in kept])):
+            return None
+        return kept if isinstance(entry, (tuple, list)) else kept[0]
+    return tuple(fit(d, e) for d, e in zip(shape, spec))
+
+
 def shard_activation(x, spec: Tuple):
-    """with_sharding_constraint filtered to the active mesh's axis names
-    (hand-built meshes may lack canonical axes, e.g. fsdp_out); degrades to
-    no-op outside a mesh context."""
+    """with_sharding_constraint on the global mesh's axes; a no-op without a
+    global mesh."""
     from deepspeed_tpu.comm import mesh as mesh_lib
     mesh = mesh_lib.get_global_mesh()
-    if mesh is not None:
-        names = set(mesh.axis_names)
-        # inside a partial-manual shard_map (e.g. the qgZ int8-wire gradient
-        # phase) the manual axes are already local — a constraint naming them
-        # would be rejected; keep constraining the still-automatic axes
-        try:
-            names -= set(jax.sharding.get_abstract_mesh().manual_axes)
-        except AttributeError:  # older jax without AbstractMesh.manual_axes
-            pass
-
-        def filt(entry):
-            if isinstance(entry, (tuple, list)):
-                kept = tuple(a for a in entry if a in names)
-                return kept if kept else None
-            return entry if entry in names else None
-        spec = tuple(filt(e) for e in spec)
-        if all(e is None for e in spec):
-            # nothing survived filtering (fully non-canonical mesh): an
-            # all-None spec would force replication, not act as a no-op
-            return x
-    try:
-        return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
-    except Exception:
+    if mesh is None:
         return x
+    # inside a partial-manual shard_map (e.g. the qgZ int8-wire gradient
+    # phase) the manual axes are already local — a constraint naming them
+    # would be rejected; keep constraining the still-automatic axes
+    region = jax.sharding.get_abstract_mesh()
+    manual = set(region.manual_axes)
+    spec = _fit_spec(x.shape, spec, mesh, set(mesh.axis_names) - manual)
+    if all(e is None for e in spec):
+        # an all-None spec would force replication, not act as a no-op
+        return x
+    # a NamedSharding needs no mesh context; inside a manual region jax wants
+    # the context's abstract mesh (outer axes already Manual), not the concrete
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(region if manual else mesh, PartitionSpec(*spec)))
 
 
 class RMSNorm(nn.Module):
@@ -196,6 +200,35 @@ def _xla_attention(q, k, v, causal: bool = True, segment_ids=None, window=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _sharded_flash_attention(q, k, v, causal, window, segment_ids):
+    """The flash kernel under the engine's jit. A Mosaic kernel is never
+    partitioned automatically (jax refuses to lower one whose sharding context
+    spans several devices), so over a mesh each device runs the kernel on its
+    own batch rows and heads inside a shard_map, like the ulysses and ring
+    backends. The sequence stays whole: this backend has no sequence
+    parallelism."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_auto
+    kernel = partial(flash_attention_auto, causal=causal, window=window)
+    mesh = mesh_lib.get_global_mesh()
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v, segment_ids=segment_ids)
+    region = jax.sharding.get_abstract_mesh()
+    manual = set(region.manual_axes)
+    auto = set(mesh.axis_names) - manual
+    # fitted on k: it has the fewest heads, and q's are a multiple of them
+    spec = PartitionSpec(*_fit_spec(
+        k.shape, (BATCH_AXES, None, HEADS_AXIS, None), mesh, auto))
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args += (jnp.asarray(segment_ids, jnp.int32),)
+        in_specs += (PartitionSpec(spec[0], None),)
+    return jax.shard_map(
+        lambda q, k, v, seg=None: kernel(q, k, v, segment_ids=seg),
+        mesh=region if manual else mesh, in_specs=in_specs, out_specs=spec,
+        axis_names=frozenset(auto), check_vma=False)(*args)
+
+
 def _dispatch_attention(backend: str, q, k, v, causal=True, segment_ids=None,
                         mesh=None, window=None):
     if window is not None and backend != "flash":
@@ -205,9 +238,7 @@ def _dispatch_attention(backend: str, q, k, v, causal=True, segment_ids=None,
     if backend == "xla":
         return _xla_attention(q, k, v, causal, segment_ids)
     if backend == "flash":
-        from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_auto
-        return flash_attention_auto(q, k, v, causal=causal, window=window,
-                                    segment_ids=segment_ids)
+        return _sharded_flash_attention(q, k, v, causal, window, segment_ids)
     if backend == "ulysses":
         from deepspeed_tpu.sequence.ulysses import ulysses_attention
         return ulysses_attention(q, k, v, causal=causal,

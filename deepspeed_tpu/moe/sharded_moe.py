@@ -152,11 +152,7 @@ def _quantized_wire_axes(mesh):
     in the surrounding context (the qgZ gradient phase may already hold the
     data axis manual): (token-reduction axes, expert axis active)."""
     from deepspeed_tpu.comm import mesh as mesh_lib
-    manual = set()
-    try:
-        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-    except AttributeError:
-        pass
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     tok = tuple(a for a in mesh_lib.batch_axes(mesh)
                 if mesh.shape.get(a, 1) > 1 and a not in manual)
     ep = mesh.shape.get("expert", 1) > 1 and "expert" not in manual
@@ -167,13 +163,8 @@ def _region_mesh(mesh):
     """Mesh to hand a nested shard_map: inside a partial-manual region
     (e.g. the qgZ gradient phase) jax requires the *context* abstract mesh
     (whose outer axes are already Manual), not the concrete one."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if getattr(am, "manual_axes", ()):
-            return am
-    except AttributeError:
-        pass
-    return mesh
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.manual_axes else mesh
 
 
 def _quantized_dispatch_sum(mesh, tok_axes, dispatch, tokens):

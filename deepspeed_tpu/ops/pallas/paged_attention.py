@@ -14,8 +14,11 @@ and carry across pages, flash-style.
 GQA/T folding: the kernel processes one KV head per grid cell; the q rows for
 that cell are the (group × chunk) fold — ``rep`` query heads that share the KV
 head times ``T`` chunk tokens — zero-padded to a multiple of 8 sublanes. Decode
-is T=1; prefill is B=1, T=chunk. Pages entirely above the causal horizon (or
-entirely below the sliding window) are predicated out with ``pl.when``.
+is T=1; prefill is B=1, T=chunk. A fold too tall for the compiler's scoped VMEM
+(a 2048-token chunk of a 4-way group is 8192 rows) is cut into row blocks on a
+grid axis of their own (``_MAX_FOLD_ELEMS``). Pages entirely above a row
+block's causal horizon (or entirely below its sliding window) are predicated
+out with ``pl.when``.
 
 Cache layout is head-major ``[Hkv, num_blocks, block_size, d]`` so one page of
 one KV head is a contiguous ``(block_size, d)`` tile (legal TPU block shape).
@@ -31,8 +34,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# Tallest q fold one grid cell takes, in rows x head_dim elements. The kernel
+# sets no compiler parameters, so it lives inside the default 16 MiB of scoped
+# VMEM: on a v5e (libtpu 0.0.34) 4096 rows x d128 still compiles and 8192 rows
+# are refused at 28 MiB. Half the largest size that fits leaves the margin.
+_MAX_FOLD_ELEMS = 2048 * 128
 
-def _paged_kernel(*refs, block_size, num_pages, chunk, rep,
+
+def _paged_kernel(*refs, block_size, num_pages, chunk, rows,
                   window, softcap, num_blocks=0):
     if num_blocks:      # fp8 pages with per-(head, page) scales prefetched
         (tables_ref, start_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
@@ -43,7 +52,8 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rep,
         kscale_ref = vscale_ref = None
     b = pl.program_id(0)
     hi = pl.program_id(1)
-    j = pl.program_id(2)
+    i = pl.program_id(2)                   # row block of the q fold
+    j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -52,7 +62,13 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rep,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     start = start_ref[b]
-    max_qpos = start + chunk - 1
+    if chunk % rows == 0:
+        # the row block is a run of consecutive tokens of one q head
+        min_qpos = start + (i * rows) % chunk
+        max_qpos = min_qpos + rows - 1
+    else:                                  # it spans heads: the whole chunk
+        min_qpos = start
+        max_qpos = start + chunk - 1
 
     def _compute():
         q = q_ref[0, 0]                    # [Gp, d]
@@ -71,7 +87,7 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rep,
         if softcap:                        # gemma2 attn_logit_softcapping
             s = softcap * jnp.tanh(s / softcap)
         # row r of the fold is (q-head r // chunk, chunk token r % chunk)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        row = i * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         qpos = start + row % chunk
         kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= qpos                # causal == context-length mask
@@ -90,7 +106,8 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rep,
 
     live = j * block_size <= max_qpos      # page overlaps the causal horizon
     if window is not None:
-        live = jnp.logical_and(live, (j + 1) * block_size - 1 > start - window)
+        live = jnp.logical_and(
+            live, (j + 1) * block_size - 1 > min_qpos - window)
     pl.when(live)(_compute)
 
     @pl.when(j == num_pages - 1)
@@ -118,7 +135,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
     hkv, nb, bs, _ = k_pages.shape
     rep = h // hkv
     g = rep * t
-    gp = -(-g // 8) * 8                    # pad fold rows to sublane multiple
+    # fold rows per grid cell: a sublane multiple, capped for scoped VMEM
+    rows = min(-(-g // 8) * 8, max(_MAX_FOLD_ELEMS // d // 16 * 16, 16))
+    gp = -(-g // rows) * rows
     mb = block_tables.shape[1]
     scaled = k_scales is not None
 
@@ -128,21 +147,21 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if scaled else 2,
-        grid=(b, hkv, mb),
+        grid=(b, hkv, gp // rows, mb),
         in_specs=[
-            pl.BlockSpec((1, 1, gp, d), lambda bi, hi, j, *pf:
-                         (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, j, *pf, mb=mb:
+            pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
+                         (bi, hi, i, 0)),
+            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
                          (hi, pf[0][bi * mb + j], 0, 0)),
-            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, j, *pf, mb=mb:
+            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
                          (hi, pf[0][bi * mb + j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, gp, d), lambda bi, hi, j, *pf:
-                               (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
+                               (bi, hi, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, d), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
     prefetch = [block_tables.reshape(-1).astype(jnp.int32),
@@ -152,7 +171,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
                      v_scales.reshape(-1).astype(jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, num_pages=mb,
-                          chunk=t, rep=rep, window=window, softcap=softcap,
+                          chunk=t, rows=rows, window=window, softcap=softcap,
                           num_blocks=nb if scaled else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),

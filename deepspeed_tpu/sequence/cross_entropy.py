@@ -117,7 +117,7 @@ def chunked_cross_entropy(hidden, labels, mask, *, kernel=None, embedding=None,
         # XLA sees nc copies of one fused matmul+CE block instead of a
         # scan-of-checkpoint — the structure suspected of the pathological
         # XLA:TPU compile time when this scan nests inside the engine's gas
-        # scan (>20 min observed; see VERDICT round 2). Same memory bound:
+        # scan (>20 min observed in round 2). Same memory bound:
         # each chunk's logits are rematerialized in the backward.
         total = jnp.zeros((), jnp.float32)
         for i in range(nc):

@@ -942,6 +942,8 @@ def main(argv=None) -> int:
         print(json.dumps(verifications, indent=2, default=str))
         return 0
 
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scenario = SCENARIOS[args.scenario]
     patch = {}
     if args.requests is not None:

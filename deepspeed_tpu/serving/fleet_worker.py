@@ -46,6 +46,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     # the chaos monkey + replica identity see it)
     from deepspeed_tpu.serving.bench_serve import build_tiny_server
     from deepspeed_tpu.serving.frontend import ServingFrontend
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     overrides = (json.loads(args.serving_overrides)
                  if args.serving_overrides else {})

@@ -11,7 +11,7 @@ One place for the client-side discipline every fleet component needs:
   idiom), so a drill's retry schedule replays bit-identically while
   still de-synchronizing real fleets; a server-sent ``Retry-After`` is a
   FLOOR over the schedule (the replica's own hint wins);
-* **the comm-guard outcome taxonomy, reused** — transport failures are
+* **the comm-guard outcome classes, reused** — transport failures are
   classified by ``comm.guard.classify_exception``: TRANSIENT retries,
   auth/fatal raises immediately (an auth failure retried is an account
   lockout, not resilience);
@@ -135,7 +135,7 @@ def request_json(method: str, url: str, payload: Optional[dict] = None,
 
     Transport failures retry only when ``classify_exception`` says
     TRANSIENT (auth/fatal raises immediately — reusing the comm-guard
-    taxonomy, satellite contract). Statuses in ``retry_status`` (e.g.
+    classes, satellite contract). Statuses in ``retry_status`` (e.g.
     ``(429,)`` for bench lanes) retry with ``Retry-After`` honored as the
     backoff floor. A non-GET without ``idempotency_key`` is clamped to
     ONE attempt no matter what ``retry`` says: retrying a submit the

@@ -1131,7 +1131,7 @@ class InferenceServer:
                                  clean_steps=self.config.recover_clean_steps)
 
     def _on_step_fault(self, err: _EngineStepError) -> None:
-        """Classify an engine-step exception through the PR 6 taxonomy:
+        """Classify an engine-step exception through the PR 6 classes:
         FATAL latches the sticky degraded 503 (the only thing that
         should); TRANSIENT/TIMEOUT evicts a suspect request — retried with
         its KV recomputed, quarantined past its retry budget — so one bad

@@ -107,24 +107,27 @@ class CompileWatched:
     straight through; a jit-cache growth marks the call as a compile and
     emits the ``xla/compile`` instant. Attribute access (``.lower``,
     ``.clear_cache``...) delegates to the wrapped function."""
-    __slots__ = ("_fn", "_name", "_probe")
+    __slots__ = ("_fn", "_name", "_watched")
 
     def __init__(self, fn: Callable, name: str):
         self._fn = fn
         self._name = name
         # jax.jit functions expose the compiled-signature cache size; a
-        # callable without it (plain python fn, exotic jax version) is
-        # passed through unwatched rather than broken
-        self._probe = getattr(fn, "_cache_size", None)
+        # callable without it (a plain python fn) is passed through
+        # unwatched rather than broken. Only the fact is kept: the bound
+        # ``fn._cache_size`` is a native method object the cycle collector
+        # cannot look through, and holding it would pin the jitted function
+        # — and with it whatever its closure reaches, an engine and all its
+        # device state — for the life of the process
+        self._watched = hasattr(fn, "_cache_size")
 
     def __call__(self, *args, **kwargs):
-        probe = self._probe
-        if probe is None:
+        if not self._watched:
             return self._fn(*args, **kwargs)
-        before = probe()
+        before = self._fn._cache_size()
         t0 = time.monotonic()
         out = self._fn(*args, **kwargs)
-        if probe() > before:
+        if self._fn._cache_size() > before:
             record_compile(self._name, signature_of(args, kwargs),
                            time.monotonic() - t0)
         return out

@@ -30,21 +30,15 @@ import importlib, os, sys
 for p in os.environ.get("DSTPU_TEST_PATH", "").split(os.pathsep):
     if p and p not in sys.path:
         sys.path.insert(0, p)
-# fresh interpreter: env-var device forcing still works here, and doubles as
-# the fallback for jax versions without the jax_num_cpu_devices option (the
-# parent pytest env carries conftest's =8 flag — replace it with this rank's)
+# the parent pytest env carries conftest's 8-device XLA flag; this rank's own
+# device count replaces it
 ndev = os.environ["DSTPU_TEST_LOCAL_DEVICES"]
 os.environ["JAX_PLATFORMS"] = "cpu"
-flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-         if "xla_force_host_platform_device_count" not in f]
-flags.append("--xla_force_host_platform_device_count=" + ndev)
-os.environ["XLA_FLAGS"] = " ".join(flags)
+os.environ["XLA_FLAGS"] = " ".join(
+    f for f in os.environ.get("XLA_FLAGS", "").split()
+    if "xla_force_host_platform_device_count" not in f)
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", int(ndev))
-except AttributeError:
-    pass   # older jax: XLA_FLAGS above already forced the device count
+jax.config.update("jax_num_cpu_devices", int(ndev))
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 from deepspeed_tpu.comm.mesh import init_distributed
 # the wedge-proof rendezvous: deadline + transient-retry (comm/guard.py

@@ -15,8 +15,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# DSTPU_FORCE_CPU=1: run on virtual CPU devices (jax is pre-imported on some
-# hosts, so env vars are too late — config updates still work pre-backend-init)
+# DSTPU_FORCE_CPU=1: run on 8 virtual CPU devices
 if os.environ.get("DSTPU_FORCE_CPU"):
     import jax
     jax.config.update("jax_platforms", "cpu")

@@ -21,8 +21,6 @@ runs in tier-1 by default (``chaos`` marker) and asserts exact behavior:
 
 import json
 import os
-import subprocess
-import sys
 import time
 
 import pytest
@@ -109,7 +107,7 @@ def _write_peer(path, rank, age_s=0.0, beat=1):
 # ---------------------------------------------------------------------------
 # outcome classification
 # ---------------------------------------------------------------------------
-def test_classify_exception_taxonomy():
+def test_classify_exception_classes():
     assert classify_exception(ConnectionRefusedError("refused")) \
         is CommOutcome.TRANSIENT
     assert classify_exception(RuntimeError("UNAVAILABLE: channel down")) \
@@ -677,99 +675,6 @@ def test_agent_exports_init_budget_env_from_config():
     assert env[INIT_DEADLINE_ENV] == "30.0"
     assert env[INIT_BACKOFF_ENV] == "0.5"
     assert env[INIT_RETRIES_ENV] == "9"
-
-
-# ---------------------------------------------------------------------------
-# bench bounded discovery: classified rc + one-line diagnosis
-# ---------------------------------------------------------------------------
-def _run_discovery(tmp_path, body, extra_env=None):
-    env = dict(os.environ)
-    env.pop("DSTPU_STALE_REPLAY_RC0", None)
-    env.update(DSTPU_BENCH_LOGS=str(tmp_path / "bench_logs"),
-               **(extra_env or {}))
-    return subprocess.run(
-        [sys.executable, "-c",
-         "from bench_util import bounded_device_discovery\n" + body],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
-
-
-def test_discovery_wedge_stale_replay_rc_unchanged(tmp_path):
-    """A wedged discovery with a banked headline still replays it stale at
-    rc 7 (rc 0 under DSTPU_STALE_REPLAY_RC0) — behavior unchanged."""
-    from bench_util import STALE_REPLAY_EXIT_CODE
-    logs = tmp_path / "bench_logs"
-    logs.mkdir()
-    (logs / "latest_headline.json").write_text(json.dumps(
-        {"metric": "llama_train_tokens_per_sec_per_chip", "value": 5000.0,
-         "unit": "tokens/s/chip"}) + "\n")
-    body = ("bounded_device_discovery('bench', timeout=0.2, retries=0,\n"
-            "    stale_metric='llama_train_tokens_per_sec_per_chip',\n"
-            "    devices_fn=lambda: __import__('time').sleep(60))\n")
-    out = _run_discovery(tmp_path, body)
-    assert out.returncode == STALE_REPLAY_EXIT_CODE, out.stderr
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    assert rec["stale"] is True and rec["value"] == 5000.0
-    assert "tunnel wedge" in out.stderr
-
-    out0 = _run_discovery(tmp_path, body,
-                          extra_env={"DSTPU_STALE_REPLAY_RC0": "1"})
-    assert out0.returncode == 0, out0.stderr
-
-
-def test_discovery_wedge_nothing_banked_rc3(tmp_path):
-    body = ("bounded_device_discovery('bench', timeout=0.2, retries=0,\n"
-            "    stale_metric='llama_train_tokens_per_sec_per_chip',\n"
-            "    devices_fn=lambda: __import__('time').sleep(60))\n")
-    out = _run_discovery(tmp_path, body)
-    assert out.returncode == 3, out.stderr
-    assert "tunnel wedge" in out.stderr
-
-
-def test_discovery_auth_distinct_rc_never_replayed(tmp_path):
-    """Auth failures get their own rc and are never papered over with a
-    stale replay — the banked headline would hide a revoked credential."""
-    from bench_util import DISCOVERY_AUTH_EXIT_CODE
-    logs = tmp_path / "bench_logs"
-    logs.mkdir()
-    (logs / "latest_headline.json").write_text(json.dumps(
-        {"metric": "llama_train_tokens_per_sec_per_chip", "value": 5000.0,
-         "unit": "tokens/s/chip"}) + "\n")
-    body = ("def f():\n"
-            "    raise RuntimeError('PERMISSION_DENIED: bad credential')\n"
-            "bounded_device_discovery('bench', timeout=5, retries=3,\n"
-            "    stale_metric='llama_train_tokens_per_sec_per_chip',\n"
-            "    devices_fn=f)\n")
-    out = _run_discovery(tmp_path, body)
-    assert out.returncode == DISCOVERY_AUTH_EXIT_CODE, out.stderr
-    assert "auth" in out.stderr
-    assert not out.stdout.strip()                # no stale replay line
-
-
-def test_discovery_no_devices_distinct_rc(tmp_path):
-    from bench_util import DISCOVERY_NO_DEVICES_EXIT_CODE
-    body = ("bounded_device_discovery('bench', timeout=5, retries=0,\n"
-            "    devices_fn=lambda: [])\n")
-    out = _run_discovery(tmp_path, body)
-    assert out.returncode == DISCOVERY_NO_DEVICES_EXIT_CODE, out.stderr
-    assert "no devices" in out.stderr
-
-
-def test_discovery_transient_retried_then_succeeds(tmp_path):
-    body = ("import tempfile, os\n"
-            "marker = os.path.join(os.environ['DSTPU_BENCH_LOGS'], 'tries')\n"
-            "def f():\n"
-            "    n = int(open(marker).read()) if os.path.exists(marker) else 0\n"
-            "    os.makedirs(os.path.dirname(marker), exist_ok=True)\n"
-            "    open(marker, 'w').write(str(n + 1))\n"
-            "    if n < 2:\n"
-            "        raise ConnectionRefusedError('tunnel not up')\n"
-            "    return ['cpu:0']\n"
-            "devs = bounded_device_discovery('bench', timeout=5, retries=3,\n"
-            "    backoff_s=0.01, devices_fn=f)\n"
-            "print('DEVICES', devs)\n")
-    out = _run_discovery(tmp_path, body)
-    assert out.returncode == 0, out.stderr
-    assert "DEVICES ['cpu:0']" in out.stdout
 
 
 # ---------------------------------------------------------------------------

@@ -223,7 +223,7 @@ def test_request_json_transport_classification(monkeypatch):
         raise PermissionError("UNAUTHENTICATED: bad credentials")
 
     monkeypatch.setattr(http_util, "_one_request", fatal)
-    # auth-shaped failures are FATAL in the comm-guard taxonomy: never
+    # auth-shaped failures are FATAL in the comm-guard classification: never
     # retried (an auth failure retried is an account lockout)
     with pytest.raises(PermissionError):
         http_util.request_json("GET", "http://127.0.0.1:1/x", retry=pol)

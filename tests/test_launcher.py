@@ -267,29 +267,3 @@ def test_discover_cluster_env_chains(monkeypatch):
     assert d["num_processes"] == 16 and d["process_id"] == 3
     assert d["coordinator_address"].startswith("tpu-pod-node1:")
     monkeypatch.delenv("DSTPU_AUTO_MPI_DISCOVERY")
-
-
-@pytest.mark.slow
-def test_bench_decode_smoke_reports_mixed_load(tmp_path):
-    """bench_decode.py end-to-end on the tiny CPU config: one JSON line with
-    the decode + mixed-load (TTFT) fields — guards the round-end bench
-    artifact against silent breakage."""
-    import json
-    import subprocess
-    import sys
-
-    code = (
-        "import jax; jax.config.update('jax_platforms', 'cpu');"
-        "import bench_decode; bench_decode.main()")
-    env = dict(os.environ, DSTPU_DECODE_TINY="1", DSTPU_DECODE_BATCH="2",
-               DSTPU_DECODE_PROMPT="32", DSTPU_DECODE_STEPS="4",
-               DSTPU_DECODE_MIXED_STEPS="16")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=600,
-                       cwd=os.path.dirname(os.path.dirname(
-                           os.path.abspath(__file__))))
-    assert r.returncode == 0, r.stderr[-2000:]
-    row = json.loads(r.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "llama_decode_tokens_per_sec"
-    for key in ("mixed_tokens_per_sec", "ttft_p50_ms", "ttft_p95_ms"):
-        assert key in row["extra"], row["extra"]

@@ -24,7 +24,7 @@ Contracts pinned here:
   loop         : Autotuner(plan=...) executes ONLY the plan's proposals
                  and verifies the readback-transfer prediction by exact
                  span counting (the telemetry->plan->config acceptance)
-  live         : a real `bench.py micro` run under DSTPU_TRACE attributes
+  live         : a real micro training run under DSTPU_TRACE attributes
                  end to end
 """
 
@@ -663,20 +663,42 @@ def test_autotuner_load_plan_accepts_trace_and_artifact(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# live round-trip: bench.py micro under DSTPU_TRACE (acceptance)
+# live round-trip: a micro training run under DSTPU_TRACE (acceptance)
 # ---------------------------------------------------------------------------
-def test_bench_micro_trace_roundtrip(tmp_path):
-    trace = tmp_path / "bench_trace.json"
-    env = dict(os.environ, DSTPU_BENCH_MODEL="micro", DSTPU_TRACE=str(trace),
-               JAX_PLATFORMS="cpu")
-    proc = subprocess.run([sys.executable, "bench.py"], cwd=REPO, env=env,
-                          capture_output=True, text=True, timeout=300)
+_MICRO_RUN = """
+import jax, jax.numpy as jnp
+import deepspeed_tpu
+from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, random_tokens
+cfg = LlamaConfig(vocab_size=2048, hidden_size=64, intermediate_size=172,
+                  num_layers=2, num_heads=4, num_kv_heads=2, max_seq_len=64,
+                  dtype=jnp.float32)
+engine, _, _, _ = deepspeed_tpu.initialize(
+    model=LlamaForCausalLM(cfg),
+    config={"train_batch_size": 8,
+            "optimizer": {"type": "AdamW", "params": {"lr": 3e-4}},
+            "steps_per_print": 10 ** 9},
+    example_batch=random_tokens(2, 64, vocab_size=2048))
+for i in range(11):          # the per-step readback loop of a plain script
+    loss = engine.train_batch(
+        batch=random_tokens(8, 64, vocab_size=2048, seed=i, gas=1),
+        stacked=True)
+    float(jax.device_get(loss))
+"""
+
+
+def test_micro_run_trace_roundtrip(tmp_path):
+    trace = tmp_path / "run_trace.json"
+    env = dict(os.environ, DSTPU_TRACE=str(trace), JAX_PLATFORMS="cpu",
+               XLA_FLAGS="")
+    proc = subprocess.run([sys.executable, "-c", _MICRO_RUN], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     rep = attribution.analyze_path(str(trace))
-    assert rep["mode"] == "sync"                  # bench default: no pipeline
-    assert rep["steps_total"] >= 10               # the timed loop
+    assert rep["mode"] == "sync"                  # no pipeline configured
+    assert rep["steps_total"] >= 10               # the training loop
     for w in rep["windows"]:
         assert _stage_sum_us(w) == pytest.approx(w["dur_us"], abs=0.01)
         assert w["tie_out_error"] <= attribution.TIE_OUT_TOLERANCE
-    # the plan knows what to do about a per-step-readback bench
+    # the plan knows what to do about a per-step-readback loop
     assert any(p["id"] == "enable_async_pipeline" for p in rep["proposals"])
