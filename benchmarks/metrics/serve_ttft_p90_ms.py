@@ -1,0 +1,13 @@
+"""90th percentile, over the requests due in the window, of first token
+received minus the time the request was due on the schedule: ISSUE 23's
+definition. Recorded, not judged: over the chat cell's 51 requests it is the
+fifth-largest value, and it spread by 8-21% across seeds (PERF.md, PR 23)."""
+
+import numpy as np
+
+from benchmarks.harness import readers
+
+
+def read(obs):
+    values = readers.ttft_s(obs)
+    return float(np.percentile(values, 90) * 1e3) if len(values) else None

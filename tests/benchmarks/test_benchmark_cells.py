@@ -1,0 +1,247 @@
+"""The harness end to end at a tiny size on the CPU: the same runners, metric
+readers and result line as ``benchmarks/run.py``, on cells that are dropped
+into a temporary copy of the benchmark as files and entries alone."""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+import benchmark_rehearsal as rehearsal
+from benchmarks.harness import cells, run_serve
+
+REPO = rehearsal.REPO
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return rehearsal.tiny_root(tmp_path_factory.mktemp("bench"))
+
+
+@pytest.fixture(scope="module")
+def runs(root):
+    """Every tiny cell once untraced and once traced."""
+    out = {}
+    for name, _, _ in rehearsal.TINY_CELLS:
+        for traced in (False, True):
+            lines = []
+            obs, line = rehearsal.run_cell(root, name, 2.0, traced,
+                                           lines=lines)
+            out[name, traced] = (obs, line, lines)
+    return out
+
+
+CASES = [(name, traced) for name, _, _ in rehearsal.TINY_CELLS
+         for traced in (False, True)]
+
+
+@pytest.mark.parametrize("name,traced", CASES)
+def test_last_line_has_exactly_the_contract_keys(runs, name, traced):
+    _, line, _ = runs[name, traced]
+    assert set(line) - {"breakdown"} == CONTRACT_KEYS
+    assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])
+    if traced:
+        assert {"busy_s", "window_s"} <= set(line["device"])
+    for metric in line["metrics"].values():
+        assert set(metric) == {"value", "unit"}
+        assert isinstance(metric["value"], float)
+
+
+@pytest.mark.parametrize("name,traced", CASES)
+def test_tiny_cell_is_correct_and_compiles_nothing_in_the_window(runs, name,
+                                                                 traced):
+    obs, line, _ = runs[name, traced]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert obs.counters["compiles_in_window"] == 0
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("tiny-train", {"train_tokens_per_s_per_chip", "setup_s"}),
+    ("tiny-train-x4", {"train_tokens_per_s_per_chip", "setup_s"}),
+    ("tiny-chat", {"serve_tpot_p75_ms", "setup_s"}),
+    ("tiny-rag", {"serve_tokens_per_s", "setup_s"}),
+])
+def test_untraced_run_reports_the_cells_end_to_end_metrics(runs, name, expected):
+    _, line, _ = runs[name, False]
+    assert set(line["metrics"]) == expected
+    assert all(m["value"] > 0 for m in line["metrics"].values())
+
+
+@pytest.mark.parametrize("name,expected", [
+    ("tiny-chat", {"serve_ttft_upper_quartile_ms", "serve_ttft_p90_ms",
+                   "serve_tpot_request_p90_ms",
+                   "serve_tpot_mean_ms", "loadgen_late_p99_ms",
+                   "serve_queue_wait_p50_ms", "engine_step_p50_ms",
+                   "decode_batch_mean"}),
+    ("tiny-rag", {"prefill_tokens_per_tick"}),
+])
+def test_traced_run_reports_host_side_per_layer_metrics(runs, name, expected):
+    """Device-trace metrics need a TPU plane; their readers find nothing on
+    the CPU and the harness leaves them out of the line."""
+    _, line, _ = runs[name, True]
+    assert expected <= set(line["metrics"])
+    assert not any(k.endswith("_roofline") or k.endswith("_share")
+                   for k in line["metrics"])
+
+
+def test_chat_serves_the_scheduled_multiset(runs):
+    obs, line, _ = runs["tiny-chat", False]
+    measured = [r for r in obs.requests if r.measured]
+    assert line["attempted"] == len(measured) == round(
+        obs.cell.traffic["rate_rps"] * 2.0)
+    assert all(r.ok and len(r.stamps) == r.asked for r in measured)
+    assert all(r.stamps == sorted(r.stamps) and r.stamps[0] >= r.sent
+               for r in measured)
+    assert obs.counters["ladder_transitions"] == 0
+    assert obs.counters["requests_shed"] == obs.counters["requests_rejected"] == 0
+
+
+def test_traced_serving_run_excludes_the_profilers_stall(runs):
+    obs, _, _ = runs["tiny-chat", True]
+    (a, b), = obs.host_exclude
+    assert obs.window[0] < a < b
+    assert not obs.outside_stall((a + b) / 2) and obs.outside_stall(a - 1)
+    assert obs.trace.window[0] >= a and obs.trace.window[1] <= b
+    assert obs.ticks and all(t[1] >= t[0] for t in obs.ticks)
+
+
+def test_training_cell_checks_loss_against_the_reference(runs):
+    obs, _, lines = runs["tiny-train", False]
+    t = obs.train
+    assert abs(t["first_loss"] - t["reference_loss"]) < 0.02 * t["reference_loss"]
+    assert t["last_loss"] < t["first_loss"]
+    assert t["tokens_per_step"] == 4 * 128
+    assert any("float32 reference" in text for text in lines)
+
+
+def test_four_chip_cell_shards_the_state_and_matches_the_reference(runs):
+    """ZeRO-3 over four (virtual) devices: one row a chip, the same tokens a
+    step, the first loss against the reference on sharded weights."""
+    obs, line, _ = runs["tiny-train-x4", False]
+    assert line["device"]["count"] == 4 and obs.train["chips"] == 4
+    assert obs.train["tokens_per_step"] == 4 * 128
+    solo = runs["tiny-train", False][0].train
+    assert obs.train["first_loss"] == pytest.approx(solo["first_loss"], rel=2e-2)
+    assert obs.train["reference_loss"] == pytest.approx(solo["reference_loss"],
+                                                        rel=1e-5)
+
+
+def test_warm_up_enumerates_the_reachable_programs(root):
+    """The sets come from the engine's own ladders and the traffic's bounds."""
+    from deepspeed_tpu.inference.v2.engine_v2 import V2EngineConfig
+    bench = cells.load_benchmark(REPO)
+    chat = cells.find_cell(bench, "mistral7b-serve-chat", REPO).traffic
+    prefill, decode = run_serve.reachable_shapes(V2EngineConfig(), chat)
+    # prompts to 2048 tokens reach context buckets 4..32; the largest chunk
+    # bucket only ends past 1024 tokens
+    assert (128, 4) in prefill and (128, 32) in prefill
+    assert (2048, 32) in prefill and (2048, 16) not in prefill
+    assert all(m <= 32 for _, m in prefill)
+    # contexts to 2560 tokens reach the 64-block table; batches to the
+    # traffic file's max_concurrency (32)
+    assert {(1, 4), (32, 64), (16, 16)} <= set(decode) and len(decode) == 30
+    assert max(d for d, _ in decode) == 32
+    rag = cells.find_cell(bench, "mixtral8x7b-serve-batch-rag", REPO).traffic
+    prefill, decode = run_serve.reachable_shapes(V2EngineConfig(), rag)
+    assert {m for _, m in decode} == {32, 64}
+    assert max(d for d, _ in decode) == 32          # 32 callers
+    assert (128, 64) in prefill
+
+
+@pytest.mark.parametrize("noise,agrees", [(0.0, True), (1.0, False)])
+def test_reference_check_holds_every_wave_sequence_to_the_tolerance(
+        root, noise, agrees):
+    """The check passes against the plain reference and fails against one
+    whose logits are off by more than the tolerance: it has the power it is
+    there for, over every sequence of the wave and not the first alone."""
+    import types
+
+    import jax
+    bench = cells.load_benchmark(root)
+    cell = cells.find_cell(bench, "tiny-chat", root)
+    server, family, _ = run_serve.build_server(cell, bench, 3)
+    plain = cells.load_module(root, bench, "reference",
+                              cell.config["model_type"])
+
+    def logits(weights, hf, seq):
+        out = plain.logits(weights, hf, seq)
+        key = jax.random.PRNGKey(len(seq))
+        return out + noise * jax.random.normal(key, out.shape)
+    said = []
+    ok = run_serve.check_against_reference(
+        server.engine, family, types.SimpleNamespace(logits=logits),
+        cell.config, 3, said.append)
+    assert ok is agrees
+    check = cell.config["serve"]["check"]
+    positions = (2 + len(check["others"])) * (1 + check["new_tokens"])
+    assert f"{positions} positions of {2 + len(check['others'])} sequences" in said[-1]
+    assert ("agree" if agrees else "DIFFER") in said[-1]
+
+
+def test_a_dropped_in_metric_file_is_found_and_read(root, runs):
+    """A later PR adds a per-layer metric as one file and one entry."""
+    from benchmarks.harness import result
+    (root / "benchmarks" / "metrics" / "tokens_streamed.new.py").write_text(
+        "def read(obs):\n"
+        "    return float(sum(len(r.stamps) for r in obs.requests))\n")
+    bench = cells.load_benchmark(root)
+    bench["per_layer"].append({
+        "name": "tokens_streamed.new", "unit": "tokens", "better": "higher",
+        "source": "program_counter", "layer": "benchmark load generator",
+        "moves": "serve_tpot_p75_ms", "workloads": ["tiny-chat"]})
+    cell = cells.find_cell(bench, "tiny-chat", root)
+    obs, _, _ = runs["tiny-chat", True]
+    got = result.read_metrics(cell, bench, obs, True, lambda text: None)
+    assert got["tokens_streamed.new"]["value"] > 0
+    # nothing that was there was edited
+    for rel in ("harness/run_serve.py", "harness/result.py", "run.py"):
+        assert (root / "benchmarks" / rel).read_bytes() == \
+            (REPO / "benchmarks" / rel).read_bytes()
+
+
+def test_a_metric_without_a_reader_or_a_reading_is_left_out(root, runs):
+    from benchmarks.harness import result
+    (root / "benchmarks" / "metrics" / "reads_nothing.py").write_text(
+        "def read(obs):\n    return None\n")
+    bench = cells.load_benchmark(root)
+    for name in ("reads_nothing", "has_no_file"):
+        bench["per_layer"].append({
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "program_span", "layer": "engine tick",
+            "moves": "serve_tpot_p75_ms", "workloads": ["tiny-chat"]})
+    cell = cells.find_cell(bench, "tiny-chat", root)
+    said = []
+    got = result.read_metrics(cell, bench, runs["tiny-chat", True][0], True,
+                              said.append)
+    assert "reads_nothing" not in got and "has_no_file" not in got
+    assert len([s for s in said if "left out" in s]) >= 2
+
+
+def test_run_py_refuses_the_cpu_with_nothing_on_stdout():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, str(REPO / "benchmarks" / "run.py"), "--workload",
+         "mistral7b-train-8k", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode != 0
+    assert done.stdout.strip() == ""
+    assert "no TPU" in done.stderr
+
+
+def test_run_py_fails_where_the_program_is_missing(tmp_path):
+    """Alone with BENCHMARK.json and its own paths the command has no system
+    to test: nonzero exit, no result line."""
+    import shutil
+    shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    shutil.copytree(REPO / "benchmarks", tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    done = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload",
+         "mistral7b-train-8k", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert done.returncode != 0 and done.stdout.strip() == ""
