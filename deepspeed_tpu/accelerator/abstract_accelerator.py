@@ -91,9 +91,9 @@ class Accelerator(abc.ABC):
 
     # --- profiler / tracing ------------------------------------------------
     def range_push(self, name: str):
-        """Named trace annotation (reference: nvtx range_push). Routed
-        through ``utils.nvtx.annotate`` so the range also lands in the
-        dstrace timeline when tracing is on."""
+        """Named trace annotation (reference: nvtx range_push): a span of
+        the dstrace tracer (``utils.nvtx.annotate``), on the ring and in
+        the profiler's trace while tracing is on."""
         from deepspeed_tpu.utils.nvtx import annotate
         return annotate(name)
 

@@ -161,6 +161,11 @@ class InferenceEngineV2:
         self.sched_ledger = TickLedger()
         self.last_step_counters = {"prefill_tokens": 0, "chunks": 0,
                                    "decode_tokens": 0}
+        # number of the step about to run, carried by every span of that
+        # step; a serving loop overwrites it with its own tick's number
+        # before each step so that its spans and the engine's share it
+        self.tick = 0
+        self._window = spec.window     # sliding window in tokens, or None
         # speculative-decoding counters (speculative_stats)
         self._spec_steps = 0
         self._spec_proposed = 0
@@ -312,16 +317,23 @@ class InferenceEngineV2:
         return matched
 
     def step(self) -> Dict[int, int]:
-        cap = self.config.scheduler.prefill_chunk_tokens
-        plan = plan_step(self.state.decoding(), self.state.prefilling(),
-                         self.config.scheduler,
-                         block_tokens=self.kv.cfg.block_size)
-        out: Dict[int, int] = {}
-        # scaled fp8 pages carry their per-(head, page) scales through the
-        # jitted steps as a (pages, scales) tuple
-        cache = self.kv.data if self.kv.scales is None else \
-            (self.kv.data, self.kv.scales)
         tracer = get_tracer()
+        # every span of this step carries the tick's number (see ``tick``).
+        # The phases below tile the step: the device's idle gaps are named
+        # by the phase their middle falls in, and between two ticks those
+        # middles cluster where one step ends and the next begins, so what
+        # lies between two phases there is kept to a few microseconds.
+        tick = self.tick
+        with tracer.span("serve/plan", cat="serve", tick=tick):
+            cap = self.config.scheduler.prefill_chunk_tokens
+            plan = plan_step(self.state.decoding(), self.state.prefilling(),
+                             self.config.scheduler,
+                             block_tokens=self.kv.cfg.block_size)
+            out: Dict[int, int] = {}
+            # scaled fp8 pages carry their per-(head, page) scales through
+            # the jitted steps as a (pages, scales) tuple
+            cache = self.kv.data if self.kv.scales is None else \
+                (self.kv.data, self.kv.scales)
         t_prefill = t_decode = 0.0
         t0 = time.monotonic()
 
@@ -329,115 +341,141 @@ class InferenceEngineV2:
         for chunk in plan.prefill_chunks:
             seq = chunk.seq
             end = chunk.start + chunk.length
-            self._ensure_blocks(seq, end)
-            bucket = chunk.bucket
-            tokens = np.zeros((bucket,), np.int32)
-            tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
-            mb = self._ctx_bucket_blocks(end)
-            table = self._block_table(seq, mb)
-            t_chunk = time.monotonic()
-            logits, cache = prefill_chunk_g(
-                self.params, cache, jnp.asarray(tokens), chunk.start,
-                jnp.asarray(table), chunk.length,
-                policy=self.policy, cfg=self.model_config,
-                block_size=self.kv.cfg.block_size,
-                attn_impl=self.config.attn_impl)
-            if cap > 0:
-                # per-chunk sub-span (nested inside serve/step_prefill, same
-                # exclusive stage) — only with chunking on, so cap-off trace
-                # streams stay bit-identical to pre-cap serving
-                tracer.complete("serve/prefill_chunk",
-                                time.monotonic() - t_chunk, cat="serve",
-                                uid=seq.uid, tokens=chunk.length,
-                                bucket=chunk.bucket)
-            seq.seen_tokens = end
-            self._prefill_computed += chunk.length
-            if self.prefix_cache is not None:
-                # register the freshly materialized FULL prompt blocks so
-                # concurrent arrivals with the same prefix reuse them
-                # (pinned for this sequence's lifetime — the pin is what
-                # keeps a shared page safe from release/demotion)
-                self.prefix_cache.insert_from_seq(
-                    seq.uid, seq.prompt_tokens, seq.blocks,
-                    min(seq.seen_tokens, len(seq.prompt_tokens)))
-            if not seq.in_prefill:
-                tok = int(self._sample_batch(logits[None])[0])
-                seq.generated.append(tok)
-                out[seq.uid] = tok
+            # per-chunk sub-span, nested inside serve/step_prefill (same
+            # exclusive stage): building the chunk, its dispatch, and the
+            # bookkeeping after it
+            with tracer.span("serve/prefill_chunk", cat="serve", tick=tick,
+                             uid=seq.uid, tokens=chunk.length,
+                             bucket=chunk.bucket, start=chunk.start):
+                self._ensure_blocks(seq, end)
+                tokens = np.zeros((chunk.bucket,), np.int32)
+                tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
+                mb = self._ctx_bucket_blocks(end)
+                table = self._block_table(seq, mb)
+                logits, cache = prefill_chunk_g(
+                    self.params, cache, jnp.asarray(tokens), chunk.start,
+                    jnp.asarray(table), chunk.length,
+                    policy=self.policy, cfg=self.model_config,
+                    block_size=self.kv.cfg.block_size,
+                    attn_impl=self.config.attn_impl)
+                seq.seen_tokens = end
+                self._prefill_computed += chunk.length
+                if self.prefix_cache is not None:
+                    # register the freshly materialized FULL prompt blocks
+                    # so concurrent arrivals with the same prefix reuse them
+                    # (pinned for this sequence's lifetime — the pin is what
+                    # keeps a shared page safe from release/demotion)
+                    self.prefix_cache.insert_from_seq(
+                        seq.uid, seq.prompt_tokens, seq.blocks,
+                        min(seq.seen_tokens, len(seq.prompt_tokens)))
+                if not seq.in_prefill:
+                    sampled = self._sample_dispatch(logits[None])
+                    with tracer.span("serve/decode_wait", cat="serve",
+                                     tick=tick):
+                        tok = int(np.asarray(sampled)[0])
+                    seq.generated.append(tok)
+                    out[seq.uid] = tok
         if plan.prefill_chunks:
             t_prefill = time.monotonic() - t0
             tracer.complete("serve/step_prefill", t_prefill, cat="serve",
+                            end_ts=t0 + t_prefill, tick=tick,
                             chunks=len(plan.prefill_chunks))
 
         # --- decode batch ---
         t0 = time.monotonic()
         if plan.decode_seqs:
-            seqs = plan.decode_seqs
-            b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
-            max_ctx = max(s.total_tokens for s in seqs)
-            mb = self._ctx_bucket_blocks(max_ctx)
-            tokens = np.zeros((b,), np.int32)
-            positions = np.zeros((b,), np.int32)
-            valid = np.zeros((b,), bool)
-            for j, seq in enumerate(seqs):
-                self._ensure_blocks(seq, seq.total_tokens)
-                tokens[j] = seq.generated[-1] if seq.generated else \
-                    seq.prompt_tokens[-1]
-                positions[j] = seq.total_tokens - 1
-                valid[j] = True
-            # signature covers the actual block ids: uid reuse after flush()
-            # can hand a same-shaped batch different pages
-            sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
-            if sig != self._table_sig:
-                tables = np.full((b, mb), self.kv.cfg.num_blocks - 1, np.int32)
+            with tracer.span("serve/decode_build", cat="serve",
+                             tick=tick) as build:
+                seqs = plan.decode_seqs
+                b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
+                contexts = [s.total_tokens for s in seqs]
+                mb = self._ctx_bucket_blocks(max(contexts))
+                tokens = np.zeros((b,), np.int32)
+                positions = np.zeros((b,), np.int32)
+                valid = np.zeros((b,), bool)
                 for j, seq in enumerate(seqs):
-                    tables[j] = self._block_table(seq, mb)
-                self._dev_tables = jnp.asarray(tables)
-                self._table_sig = sig
-            logits, cache = decode_step_g(
-                self.params, cache, jnp.asarray(tokens), jnp.asarray(positions),
-                self._dev_tables, jnp.asarray(valid),
-                policy=self.policy, cfg=self.model_config,
-                block_size=self.kv.cfg.block_size,
-                attn_impl=self.config.attn_impl)
-            # sample on device; only [B] token ids cross to the host — the
-            # [B, vocab] logits D2H fetch would dominate the decode loop
-            toks = self._sample_batch(logits)
-            for j, seq in enumerate(seqs):
-                tok = int(toks[j])
-                seq.seen_tokens = seq.total_tokens
-                seq.generated.append(tok)
-                out[seq.uid] = tok
-                if self.config.eos_token_id is not None and \
-                        tok == self.config.eos_token_id:
-                    seq.done = True
+                    self._ensure_blocks(seq, seq.total_tokens)
+                    tokens[j] = seq.generated[-1] if seq.generated else \
+                        seq.prompt_tokens[-1]
+                    positions[j] = seq.total_tokens - 1
+                    valid[j] = True
+                # signature covers the actual block ids: uid reuse after
+                # flush() can hand a same-shaped batch different pages
+                sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
+                rebuilt = sig != self._table_sig
+                if rebuilt:
+                    tables = np.full((b, mb), self.kv.cfg.num_blocks - 1,
+                                     np.int32)
+                    for j, seq in enumerate(seqs):
+                        tables[j] = self._block_table(seq, mb)
+                    self._dev_tables = jnp.asarray(tables)
+                    self._table_sig = sig
+                build.note(tables_rebuilt=rebuilt)
+            with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
+                logits, cache = decode_step_g(
+                    self.params, cache, jnp.asarray(tokens),
+                    jnp.asarray(positions), self._dev_tables,
+                    jnp.asarray(valid),
+                    policy=self.policy, cfg=self.model_config,
+                    block_size=self.kv.cfg.block_size,
+                    attn_impl=self.config.attn_impl)
+                # sample on device; only [B] token ids cross to the host —
+                # the [B, vocab] logits D2H fetch would dominate the loop
+                sampled = self._sample_dispatch(logits)
+            with tracer.span("serve/decode_wait", cat="serve", tick=tick):
+                toks = np.asarray(sampled)
+            with tracer.span("serve/decode_commit", cat="serve", tick=tick):
+                for j, seq in enumerate(seqs):
+                    tok = int(toks[j])
+                    seq.seen_tokens = seq.total_tokens
+                    seq.generated.append(tok)
+                    out[seq.uid] = tok
+                    if self.config.eos_token_id is not None and \
+                            tok == self.config.eos_token_id:
+                        seq.done = True
             t_decode = time.monotonic() - t0
-            tracer.complete("serve/step_decode", t_decode, cat="serve",
-                            batch=len(plan.decode_seqs))
+            if tracer.enabled:
+                # what the scheduler decided, as plain host ints the step
+                # already holds: the batch and the bucket it was padded to,
+                # and the context the paged kernel had to read (whole, and
+                # cut to the sliding window where the model has one)
+                window, whole = self._window, sum(contexts)
+                tracer.complete(
+                    "serve/step_decode", t_decode, cat="serve",
+                    end_ts=t0 + t_decode, tick=tick,
+                    batch=len(seqs), bucket=b, ctx_tokens=whole,
+                    ctx_tokens_windowed=sum(min(c, window) for c in contexts)
+                    if window else whole, ctx_blocks=mb)
 
-        if self.kv.scales is None:
-            self.kv.data = cache
-        else:
-            self.kv.data, self.kv.scales = cache
-        # the serve tick's stage clocks read these (serve/tick_stage_share
-        # gauges + `dstpu plan --serve` prefill/decode attribution)
-        self.last_step_timing = {"prefill_s": t_prefill,
-                                 "decode_s": t_decode}
-        prefill_tokens = sum(c.length for c in plan.prefill_chunks)
-        decode_tokens = len(plan.decode_seqs)
-        self.last_step_counters = {"prefill_tokens": prefill_tokens,
-                                   "chunks": len(plan.prefill_chunks),
-                                   "decode_tokens": decode_tokens}
-        if not plan.empty:
-            self.sched_ledger.observe_tick(prefill_tokens,
-                                           len(plan.prefill_chunks),
-                                           decode_tokens, cap=cap)
+        with tracer.span("serve/step_finish", cat="serve", tick=tick):
+            if self.kv.scales is None:
+                self.kv.data = cache
+            else:
+                self.kv.data, self.kv.scales = cache
+            self.tick = tick + 1
+            # the serve tick's stage clocks read these
+            # (serve/tick_stage_share gauges + `dstpu plan --serve`
+            # prefill/decode attribution)
+            self.last_step_timing = {"prefill_s": t_prefill,
+                                     "decode_s": t_decode}
+            prefill_tokens = sum(c.length for c in plan.prefill_chunks)
+            decode_tokens = len(plan.decode_seqs)
+            self.last_step_counters = {"prefill_tokens": prefill_tokens,
+                                       "chunks": len(plan.prefill_chunks),
+                                       "decode_tokens": decode_tokens}
+            if not plan.empty:
+                self.sched_ledger.observe_tick(prefill_tokens,
+                                               len(plan.prefill_chunks),
+                                               decode_tokens, cap=cap)
         return out
 
-    def _sample_batch(self, logits) -> np.ndarray:
-        """[B, V] device logits -> [B] host token ids (one small D2H)."""
+    def _sample_dispatch(self, logits):
+        """[B, V] device logits -> [B] device token ids: dispatch only. The
+        caller's ``np.asarray`` of the result (one small D2H, inside a
+        ``serve/decode_wait`` span) is where the host waits for the
+        device."""
         self._rng, key = jax.random.split(self._rng)
-        return np.asarray(sample_tokens(logits, key, self.config.sampling))
+        return sample_tokens(logits, key, self.config.sampling)
 
     # ------------------------------------------------------------------
     # lifecycle (reference: engine_v2.flush)

@@ -5,6 +5,12 @@ Reference analog: ``inference/v2/model_implementations/inference_transformer_bas
 skeleton is jitted pure functions over (policy, config) static args; the
 policy (``modules.py``) contributes embed/block/unembed and the loop owns KV
 cache writes + the Pallas paged attention (``llama_decode._paged_attn``).
+
+Every phase of a step sits under a ``jax.named_scope`` whose name reaches the
+device trace (an operation's ``tf_op``): ``embed``, ``attn/kv_write``,
+``attn/paged`` and ``lm_head`` here, ``attn/qkv``, ``attn/out``, ``mlp``,
+``moe/router`` and ``moe/experts`` in the policies. Metadata only: the
+compiled program is the same.
 """
 
 from functools import partial
@@ -49,7 +55,8 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
             jnp.arange((tb + block_size - 2) // block_size + 1), mb - 1)
         touched = block_table[touch_idx]
 
-    x = policy.embed(params, tokens, safe_pos, cfg)
+    with jax.named_scope("embed"):
+        x = policy.embed(params, tokens, safe_pos, cfg)
 
     cache = cache_data
     for i in range(spec.num_layers):
@@ -58,22 +65,26 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
             win = spec.window if window == "spec" else window
             if scaled:
                 data, scales = cache
-                data, scales = write_kv_scaled(data, scales, i, 0, k,
-                                               tok_block, tok_off, touched)
-                data, scales = write_kv_scaled(data, scales, i, 1, v,
-                                               tok_block, tok_off, touched)
+                with jax.named_scope("attn/kv_write"):
+                    data, scales = write_kv_scaled(
+                        data, scales, i, 0, k, tok_block, tok_off, touched)
+                    data, scales = write_kv_scaled(
+                        data, scales, i, 1, v, tok_block, tok_off, touched)
                 cache = (data, scales)
-                return _paged_attn(q[None], data, i, block_table[None],
+                with jax.named_scope("attn/paged"):
+                    return _paged_attn(q[None], data, i, block_table[None],
+                                       jnp.asarray(start).reshape(1), win,
+                                       attn_impl, softcap=softcap,
+                                       scales=scales)[0]
+            with jax.named_scope("attn/kv_write"):
+                cache = cache.at[i, 0, :, tok_block, tok_off].set(
+                    cast_to_page_dtype(k, cache.dtype))
+                cache = cache.at[i, 1, :, tok_block, tok_off].set(
+                    cast_to_page_dtype(v, cache.dtype))
+            with jax.named_scope("attn/paged"):
+                return _paged_attn(q[None], cache, i, block_table[None],
                                    jnp.asarray(start).reshape(1), win,
-                                   attn_impl, softcap=softcap,
-                                   scales=scales)[0]
-            cache = cache.at[i, 0, :, tok_block, tok_off].set(
-                cast_to_page_dtype(k, cache.dtype))
-            cache = cache.at[i, 1, :, tok_block, tok_off].set(
-                cast_to_page_dtype(v, cache.dtype))
-            return _paged_attn(q[None], cache, i, block_table[None],
-                               jnp.asarray(start).reshape(1), win,
-                               attn_impl, softcap=softcap)[0]
+                                   attn_impl, softcap=softcap)[0]
         x = policy.block(params, i, x, attend, safe_pos, cfg)
     return x, cache
 
@@ -88,7 +99,8 @@ def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
     x, cache = _chunk_states(params, cache_data, tokens, start, block_table,
                              true_len, policy, cfg, block_size, attn_impl)
     last = x[jnp.maximum(true_len - 1, 0)]
-    logits = policy.unembed(params, last[None], cfg)[0]
+    with jax.named_scope("lm_head"):
+        logits = policy.unembed(params, last[None], cfg)[0]
     return logits, cache
 
 
@@ -105,7 +117,8 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
     doubles as the context-length mask) until a later step overwrites them."""
     x, cache = _chunk_states(params, cache_data, tokens, start, block_table,
                              true_len, policy, cfg, block_size, attn_impl)
-    return policy.unembed(params, x, cfg), cache
+    with jax.named_scope("lm_head"):
+        return policy.unembed(params, x, cfg), cache
 
 
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"))
@@ -128,7 +141,8 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                     pool.shape[3] - 1)
     off = safe_pos % block_size
 
-    x = policy.embed(params, tokens, safe_pos, cfg)
+    with jax.named_scope("embed"):
+        x = policy.embed(params, tokens, safe_pos, cfg)
 
     cache = cache_data
     for i in range(spec.num_layers):
@@ -139,23 +153,29 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                 # each token touches exactly its own page (invalid rows all
                 # write the trash page with identical per-page updates)
                 data, scales = cache
-                data, scales = write_kv_scaled(data, scales, i, 0, k,
-                                               blk, off, blk)
-                data, scales = write_kv_scaled(data, scales, i, 1, v,
-                                               blk, off, blk)
+                with jax.named_scope("attn/kv_write"):
+                    data, scales = write_kv_scaled(data, scales, i, 0, k,
+                                                   blk, off, blk)
+                    data, scales = write_kv_scaled(data, scales, i, 1, v,
+                                                   blk, off, blk)
                 cache = (data, scales)
-                return _paged_attn(q[:, None], data, i, block_tables,
+                with jax.named_scope("attn/paged"):
+                    return _paged_attn(q[:, None], data, i, block_tables,
+                                       safe_pos, win, attn_impl,
+                                       softcap=softcap, scales=scales)[:, 0]
+            with jax.named_scope("attn/kv_write"):
+                cache = cache.at[i, 0, :, blk, off].set(
+                    cast_to_page_dtype(k, cache.dtype))
+                cache = cache.at[i, 1, :, blk, off].set(
+                    cast_to_page_dtype(v, cache.dtype))
+            with jax.named_scope("attn/paged"):
+                return _paged_attn(q[:, None], cache, i, block_tables,
                                    safe_pos, win, attn_impl,
-                                   softcap=softcap, scales=scales)[:, 0]
-            cache = cache.at[i, 0, :, blk, off].set(
-                cast_to_page_dtype(k, cache.dtype))
-            cache = cache.at[i, 1, :, blk, off].set(
-                cast_to_page_dtype(v, cache.dtype))
-            return _paged_attn(q[:, None], cache, i, block_tables, safe_pos,
-                               win, attn_impl, softcap=softcap)[:, 0]
+                                   softcap=softcap)[:, 0]
         x = policy.block(params, i, x, attend, safe_pos, cfg)
 
-    logits = policy.unembed(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        logits = policy.unembed(params, x, cfg)
     return logits, cache
 
 

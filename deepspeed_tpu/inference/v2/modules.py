@@ -125,15 +125,18 @@ class LlamaPolicy:
         dtype = cfg.dtype
         ns = LlamaPolicy._norm_scale
         cos, sin = _rope_tables(cfg.head_dim_, cfg.max_seq_len, cfg.rope_theta)
-        h = _rms(x, ns(lp["attn_norm"]["scale"], cfg), cfg.rms_norm_eps)
-        q, k, v = _qkv(lp, h, dtype)
-        q = _rope_rows(q, cos, sin, positions)
-        k = _rope_rows(k, cos, sin, positions)
+        with jax.named_scope("attn/qkv"):
+            h = _rms(x, ns(lp["attn_norm"]["scale"], cfg), cfg.rms_norm_eps)
+            q, k, v = _qkv(lp, h, dtype)
+            q = _rope_rows(q, cos, sin, positions)
+            k = _rope_rows(k, cos, sin, positions)
         attn = attend(q, k, v)
-        x = x + jnp.einsum("thk,hkd->td", attn,
-                           lp["attn"]["wo"]["kernel"].astype(dtype))
-        h2 = _rms(x, ns(lp["mlp_norm"]["scale"], cfg), cfg.rms_norm_eps)
-        return x + _mlp(lp, h2, dtype, act=cfg.hidden_act)
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thk,hkd->td", attn,
+                               lp["attn"]["wo"]["kernel"].astype(dtype))
+        with jax.named_scope("mlp"):
+            h2 = _rms(x, ns(lp["mlp_norm"]["scale"], cfg), cfg.rms_norm_eps)
+            return x + _mlp(lp, h2, dtype, act=cfg.hidden_act)
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -259,21 +262,23 @@ def _dense_moe_combine(moe, h2, top_k, dtype, norm_topk_prob=True):
     equivalent to the training dispatch when no token drops). With
     ``norm_topk_prob`` the kept probs are renormalized to sum to 1
     (GShard/Mixtral); HF Qwen2-MoE runs with it off."""
-    gate_logits = h2.astype(jnp.float32) @ moe["gate"]["wg"]["kernel"]
-    probs = jax.nn.softmax(gate_logits, axis=-1)              # [T, E]
-    topv, topi = jax.lax.top_k(probs, top_k)                  # [T, K]
-    if norm_topk_prob:
-        w = topv / jnp.maximum(jnp.sum(topv, -1, keepdims=True), 1e-9)
-    else:
-        w = topv
-    ex = moe["experts"]
-    g = jnp.einsum("td,edf->etf", h2, ex["w_gate"].astype(dtype))
-    u = jnp.einsum("td,edf->etf", h2, ex["w_up"].astype(dtype))
-    eo = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
-                    ex["w_down"].astype(dtype))               # [E, T, D]
-    t_idx = jnp.arange(h2.shape[0])[:, None]                  # [T, 1]
-    picked = eo[topi, t_idx]                                  # [T, K, D]
-    return jnp.einsum("tk,tkd->td", w.astype(dtype), picked)
+    with jax.named_scope("moe/router"):
+        gate_logits = h2.astype(jnp.float32) @ moe["gate"]["wg"]["kernel"]
+        probs = jax.nn.softmax(gate_logits, axis=-1)          # [T, E]
+        topv, topi = jax.lax.top_k(probs, top_k)              # [T, K]
+        if norm_topk_prob:
+            w = topv / jnp.maximum(jnp.sum(topv, -1, keepdims=True), 1e-9)
+        else:
+            w = topv
+    with jax.named_scope("moe/experts"):
+        ex = moe["experts"]
+        g = jnp.einsum("td,edf->etf", h2, ex["w_gate"].astype(dtype))
+        u = jnp.einsum("td,edf->etf", h2, ex["w_up"].astype(dtype))
+        eo = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                        ex["w_down"].astype(dtype))           # [E, T, D]
+        t_idx = jnp.arange(h2.shape[0])[:, None]              # [T, 1]
+        picked = eo[topi, t_idx]                              # [T, K, D]
+        return jnp.einsum("tk,tkd->td", w.astype(dtype), picked)
 
 
 # ---------------------------------------------------------------------------
@@ -306,13 +311,15 @@ class MixtralPolicy:
         dtype = base.dtype
         lp = params[f"layer_{i}"]
         cos, sin = _rope_tables(base.head_dim_, base.max_seq_len, base.rope_theta)
-        h = _rms(x, lp["attn_norm"]["scale"], base.rms_norm_eps)
-        q, k, v = _qkv({"attn": lp["attn"]}, h, dtype)
-        q = _rope_rows(q, cos, sin, positions)
-        k = _rope_rows(k, cos, sin, positions)
+        with jax.named_scope("attn/qkv"):
+            h = _rms(x, lp["attn_norm"]["scale"], base.rms_norm_eps)
+            q, k, v = _qkv({"attn": lp["attn"]}, h, dtype)
+            q = _rope_rows(q, cos, sin, positions)
+            k = _rope_rows(k, cos, sin, positions)
         attn = attend(q, k, v)
-        x = x + jnp.einsum("thk,hkd->td", attn,
-                           lp["attn"]["wo"]["kernel"].astype(dtype))
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thk,hkd->td", attn,
+                               lp["attn"]["wo"]["kernel"].astype(dtype))
         h2 = _rms(x, lp["mlp_norm"]["scale"], base.rms_norm_eps)
         return x + _dense_moe_combine(lp["moe"], h2, cfg.moe.top_k, dtype,
                                       cfg.moe.norm_topk_prob)
@@ -523,23 +530,26 @@ class Qwen2MoEPolicy:
         lp = params[f"layer_{i}"]
         cos, sin = _rope_tables(base.head_dim_, base.max_seq_len,
                                 base.rope_theta)
-        h = _rms(x, lp["attn_norm"]["scale"], base.rms_norm_eps)
-        q, k, v = _qkv({"attn": lp["attn"]}, h, dtype)
-        q = _rope_rows(q, cos, sin, positions)
-        k = _rope_rows(k, cos, sin, positions)
+        with jax.named_scope("attn/qkv"):
+            h = _rms(x, lp["attn_norm"]["scale"], base.rms_norm_eps)
+            q, k, v = _qkv({"attn": lp["attn"]}, h, dtype)
+            q = _rope_rows(q, cos, sin, positions)
+            k = _rope_rows(k, cos, sin, positions)
         attn = attend(q, k, v)
-        x = x + jnp.einsum("thk,hkd->td", attn,
-                           lp["attn"]["wo"]["kernel"].astype(dtype))
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thk,hkd->td", attn,
+                               lp["attn"]["wo"]["kernel"].astype(dtype))
         h2 = _rms(x, lp["mlp_norm"]["scale"], base.rms_norm_eps)
         moe_out = _dense_moe_combine(lp["moe"], h2, cfg.moe.top_k, dtype,
                                      cfg.moe.norm_topk_prob)
-        se = lp["shared_expert"]
-        g = jax.nn.silu(h2 @ se["w_gate"]["kernel"].astype(dtype))
-        u = h2 @ se["w_up"]["kernel"].astype(dtype)
-        shared = (g * u) @ se["w_down"]["kernel"].astype(dtype)
-        gate = jax.nn.sigmoid(
-            (h2 @ se["gate"]["kernel"].astype(dtype)).astype(jnp.float32))
-        return x + moe_out + shared * gate.astype(dtype)
+        with jax.named_scope("mlp"):       # the dense shared expert
+            se = lp["shared_expert"]
+            g = jax.nn.silu(h2 @ se["w_gate"]["kernel"].astype(dtype))
+            u = h2 @ se["w_up"]["kernel"].astype(dtype)
+            shared = (g * u) @ se["w_down"]["kernel"].astype(dtype)
+            gate = jax.nn.sigmoid(
+                (h2 @ se["gate"]["kernel"].astype(dtype)).astype(jnp.float32))
+            return x + moe_out + shared * gate.astype(dtype)
 
     @staticmethod
     def unembed(params, x, cfg):

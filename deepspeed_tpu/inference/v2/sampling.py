@@ -27,6 +27,11 @@ class SamplingConfig:
 @partial(jax.jit, static_argnames=("cfg",))
 def sample_tokens(logits, key, cfg: SamplingConfig):
     """logits: [B, V] fp32 -> [B] int32 sampled token ids (device-side)."""
+    with jax.named_scope("sample"):
+        return _sample(logits, key, cfg)
+
+
+def _sample(logits, key, cfg: SamplingConfig):
     if cfg.temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits.astype(jnp.float32) / cfg.temperature

@@ -397,20 +397,24 @@ class LlamaModel(nn.Module):
                     scale_offset=cfg.rms_scale_offset, name="final_norm")(x)
         # head matmul in compute dtype (bf16 on the MXU, fp32 accumulation);
         # downstream softmax casts to fp32 — an fp32 head matmul is ~8x slower
-        if cfg.tie_embeddings:
-            if return_hidden:
-                return x, embed.embedding
-            logits = embed.attend(x)
-        else:
-            head = LMHead(cfg.hidden_size, cfg.vocab_size, cfg.dtype,
-                          name="lm_head")
-            if return_hidden:
-                return x, head.kernel
-            logits = head(x)
-        logits = logits.astype(jnp.float32)
-        if cfg.logits_soft_cap:
-            logits = cfg.logits_soft_cap * jnp.tanh(logits / cfg.logits_soft_cap)
-        return logits
+        # device-trace scope: the output head and the loss behind it share
+        # one name (the layers' are their modules' names, attn and mlp)
+        with jax.named_scope("lm_head_loss"):
+            if cfg.tie_embeddings:
+                if return_hidden:
+                    return x, embed.embedding
+                logits = embed.attend(x)
+            else:
+                head = LMHead(cfg.hidden_size, cfg.vocab_size, cfg.dtype,
+                              name="lm_head")
+                if return_hidden:
+                    return x, head.kernel
+                logits = head(x)
+            logits = logits.astype(jnp.float32)
+            if cfg.logits_soft_cap:
+                logits = cfg.logits_soft_cap * \
+                    jnp.tanh(logits / cfg.logits_soft_cap)
+            return logits
 
 
 class LlamaForCausalLM(nn.Module):
@@ -436,10 +440,11 @@ class LlamaForCausalLM(nn.Module):
             mask = mask[:, 1:] if mask is not None else jnp.ones_like(labels)
         else:
             mask = batch.get("loss_mask", jnp.ones_like(labels))
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        mask = mask.astype(jnp.float32)
-        return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("lm_head_loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            mask = mask.astype(jnp.float32)
+            return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
     def _chunked_loss(self, batch):
         """Same loss as the dense path, via chunked head-matmul + CE fusion.
@@ -462,10 +467,12 @@ class LlamaForCausalLM(nn.Module):
                                   segment_ids=batch.get("segment_ids"),
                                   return_hidden=True)
         kw = {"embedding": head} if self.cfg.tie_embeddings else {"kernel": head}
-        return chunked_cross_entropy(
-            hidden, labels, mask, chunk_size=self.cfg.loss_chunk_size,
-            soft_cap=self.cfg.logits_soft_cap, compute_dtype=self.cfg.dtype,
-            unroll=self.cfg.loss_chunk_unroll, **kw)
+        with jax.named_scope("lm_head_loss"):
+            return chunked_cross_entropy(
+                hidden, labels, mask, chunk_size=self.cfg.loss_chunk_size,
+                soft_cap=self.cfg.logits_soft_cap,
+                compute_dtype=self.cfg.dtype,
+                unroll=self.cfg.loss_chunk_unroll, **kw)
 
     def logits(self, batch):
         return self.model(batch["input_ids"], positions=batch.get("positions"),

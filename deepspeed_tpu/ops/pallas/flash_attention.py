@@ -230,6 +230,7 @@ def _pallas_flash_fwd_impl(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q2, k2, v2, qs, ks)
 
     return _unfold(out, b, h, sq), lse
@@ -365,6 +366,7 @@ def _pallas_flash_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q2, k2, v2, qs, ks, do2, lse, delta)
 
     # dKV: GQA group folded into the innermost grid axis → in-kernel accumulation
@@ -415,6 +417,7 @@ def _pallas_flash_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q2, k2, v2, qs, ks, do2, lse, delta)
 
     return (_unfold(dq, b, h, sq), _unfold(dk, b, hkv, sk),

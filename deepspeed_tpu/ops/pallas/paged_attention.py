@@ -176,6 +176,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*prefetch, qf, k_pages, v_pages)
 
     out = out[:, :, :g].reshape(b, hkv, rep, t, d)

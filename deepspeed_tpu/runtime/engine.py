@@ -1015,6 +1015,7 @@ class DeepSpeedTPUEngine:
             out_shardings=(self.state_shardings, None),
         ), "engine.train_batch_step")
 
+    @jax.named_scope("optimizer")      # the update's name in a device trace
     def _update(self, state: EngineState, grads, tx, lr_schedule, clip,
                 fp16) -> Tuple[EngineState, StepOutput]:
         """Optimizer update with overflow skip + dynamic loss scale + clipping.

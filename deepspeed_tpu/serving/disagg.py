@@ -125,6 +125,15 @@ class DisaggregatedEngine:
     def admit(self, uid: int, prompt_tokens: Sequence[int]):
         return self.prefill.admit(uid, prompt_tokens)
 
+    @property
+    def tick(self) -> int:
+        return self.prefill.tick
+
+    @tick.setter
+    def tick(self, n: int) -> None:
+        # both role engines step once a tick: their spans share its number
+        self.prefill.tick = self.decode.tick = n
+
     # -- the step: prefill role, handoff, decode role ------------------
     def step(self) -> Dict[int, int]:
         t0 = time.perf_counter()

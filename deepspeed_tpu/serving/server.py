@@ -84,9 +84,13 @@ _TICK_STAGES = ("admission", "prefill", "decode", "demote", "promote",
                 "drain")
 
 #: stage -> retro-span name for the server-timed segments (prefill/decode
-#: spans are emitted by the engine inside serve/engine_step)
+#: spans are emitted by the engine inside serve/engine_step). The drain
+#: stage has three parts, each marked under its own name (``_mark(span=)``):
+#: serve/drain_expire, serve/drain_fanout, serve/drain_reap; what follows
+#: the engine step and the tick's tail after the reap are serve/bookkeep,
+#: which belongs to no stage (it stays in the ledger's residual)
 _TICK_SPAN_NAMES = {"admission": "serve/admit", "demote": "serve/demote",
-                    "promote": "serve/promote", "drain": "serve/drain"}
+                    "promote": "serve/promote"}
 
 
 class BackpressureError(RuntimeError):
@@ -669,7 +673,7 @@ class InferenceServer:
                 return False
         t0 = time.monotonic()
         self._expire_and_cancel()
-        self._mark(marks, "drain", t0)
+        self._mark(marks, "drain", t0, span="serve/drain_expire")
         stolen_frac = (self.chaos.serve_kv_pressure(self._tick)
                        if self.chaos is not None else 0.0)
         moved = 0
@@ -688,11 +692,14 @@ class InferenceServer:
             try:
                 if self.chaos is not None:
                     self.chaos.maybe_poison_serve(self._active_uids())
+                # the engine's own spans carry this tick's number too
+                self.engine.tick = self._tick
                 with get_tracer().span("serve/engine_step", cat="serve",
                                        tick=self._tick):
                     out = self.engine.step()
             except Exception as e:
                 raise _EngineStepError(str(e)) from e
+            t0 = time.monotonic()
             self.metrics.on_step()
             # role-split engines time each prefill->decode KV handoff;
             # drain those stamps into the SLO histogram every tick (plain
@@ -712,9 +719,11 @@ class InferenceServer:
                             uid=uid)
             self._note_clean_step()
             worked = True
-            t0 = time.monotonic()
+            # what follows the step belongs to no stage; its mark ends
+            # where the fan-out's begins, so the two tile
+            t0 = self._mark(marks, None, t0, span="serve/bookkeep")
             self._fan_out(out)
-            self._mark(marks, "drain", t0)
+            self._mark(marks, "drain", t0, span="serve/drain_fanout")
         elif self._fault_episode:
             # an idle server is trivially clean: age the fault episode out
             # on empty ticks too, or a drained replica would advertise
@@ -728,7 +737,8 @@ class InferenceServer:
                 self._maybe_recover()
         t0 = time.monotonic()
         self._reap()
-        self._mark(marks, "drain", t0)
+        self._mark(marks, "drain", t0, span="serve/drain_reap")
+        t0 = time.monotonic()
         with self._lock:
             queued, inflight = len(self._queue), len(self._inflight)
             # the admission model's worst-case projection, re-derived at
@@ -751,40 +761,55 @@ class InferenceServer:
         if worked or moved:
             # only ticks that did something land in the ring: an idle
             # server polling its queue must not flood the bounded trace
-            self._emit_tick_spans(marks, t_tick0, worked, queued, inflight)
+            self._emit_tick_spans(marks, t_tick0, t0, worked, queued,
+                                  inflight)
         return worked
 
-    def _mark(self, marks: list, stage: str, t0: float, **args) -> None:
-        """Record one tick-timeline segment ``(stage, t0, now, args)`` —
-        pure host bookkeeping; the retro-spans are emitted in one batch by
-        ``_emit_tick_spans`` at tick end (and only for working ticks)."""
-        marks.append((stage, t0, time.monotonic(), args or None))
+    def _mark(self, marks: list, stage: Optional[str], t0: float,
+              span: Optional[str] = None, **args) -> float:
+        """Record one tick-timeline segment ``(stage, span name, t0, now,
+        args)`` — pure host bookkeeping; the retro-spans are emitted in one
+        batch by ``_emit_tick_spans`` at tick end (and only for working
+        ticks). ``span`` names a part of a stage; ``stage`` None is a
+        segment that belongs to no stage clock. Returns ``now``, for a
+        segment that starts where this one ends."""
+        t1 = time.monotonic()
+        marks.append((stage, span or _TICK_SPAN_NAMES[stage], t0, t1,
+                      args or None))
+        return t1
 
-    def _emit_tick_spans(self, marks: list, t_tick0: float, worked: bool,
-                         queued: int, inflight: int) -> None:
+    def _emit_tick_spans(self, marks: list, t_tick0: float, t_tail0: float,
+                         worked: bool, queued: int, inflight: int) -> None:
         """Emit the tick's stage timeline as dstrace retro-spans plus the
         ``serve/tick`` window span (the unit ``dstpu plan --serve``
-        attributes: the stage ledger provably sums to this window), then
-        fold the durations into the cumulative stage clocks."""
+        attributes: the stage ledger provably sums to this window), and
+        fold the durations into the cumulative stage clocks. The window and
+        the tick's tail (``serve/bookkeep`` from ``t_tail0``, no stage) are
+        stamped last, so this emission lies inside both and the tick's
+        spans tile it to its end."""
         stage_s = {s: 0.0 for s in _TICK_STAGES}
         timing = getattr(self.engine, "last_step_timing", None)
         if worked and timing:
             # the engine timed (and trace-spanned) its own step interior
             stage_s["prefill"] = timing.get("prefill_s", 0.0)
             stage_s["decode"] = timing.get("decode_s", 0.0)
-        for stage, t0, t1, _args in marks:
-            stage_s[stage] += t1 - t0
-        t_end = time.monotonic()
+        for stage, _name, t0, t1, _args in marks:
+            if stage is not None:
+                stage_s[stage] += t1 - t0
         tracer = get_tracer()
         if tracer.enabled:
-            for stage, t0, t1, args in marks:
-                tracer.complete(_TICK_SPAN_NAMES[stage], t1 - t0,
+            for _stage, name, t0, t1, args in marks:
+                tracer.complete(name, t1 - t0,
                                 cat="serve", end_ts=t1, tick=self._tick,
                                 **(args or {}))
+        self._tick_stage_gauges(stage_s, time.monotonic() - t_tick0, tracer)
+        if tracer.enabled:
+            t_end = time.monotonic()
+            tracer.complete("serve/bookkeep", t_end - t_tail0, cat="serve",
+                            end_ts=t_end, tick=self._tick)
             tracer.complete("serve/tick", t_end - t_tick0, cat="serve",
                             end_ts=t_end, tick=self._tick, worked=worked,
                             queued=queued, inflight=inflight)
-        self._tick_stage_gauges(stage_s, t_end - t_tick0, tracer)
 
     def _tick_stage_gauges(self, stage_s: dict, tick_s: float,
                            tracer) -> None:

@@ -90,10 +90,23 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/admit": ("span",),
     "serve/demote": ("span",),
     "serve/promote": ("span",),
-    "serve/drain": ("span",),
+    "serve/drain": ("span",),               # dumps older than the parts
+    # the drain stage's three parts and the tick's tail (server retro-spans)
+    "serve/drain_expire": ("complete",),
+    "serve/drain_fanout": ("complete",),
+    "serve/drain_reap": ("complete",),
+    "serve/bookkeep": ("complete",),
     "serve/step_prefill": ("complete",),
     "serve/step_decode": ("complete",),
-    "serve/prefill_chunk": ("complete",),
+    # the phases of one engine step, where the work happens: live spans,
+    # mirrored into the profiler's trace (tracer.py), each carrying `tick`
+    "serve/plan": ("span",),
+    "serve/prefill_chunk": ("span",),
+    "serve/decode_build": ("span",),
+    "serve/decode_dispatch": ("span",),
+    "serve/decode_wait": ("span",),
+    "serve/decode_commit": ("span",),
+    "serve/step_finish": ("span",),         # the step's tail: no stage
     "serve/queued": ("complete",),
     "serve/prefill": ("complete",),
     "serve/decode": ("complete",),
@@ -179,9 +192,21 @@ SERVE_STAGE_OF: Dict[str, str] = {
     # is on — same stage, so the exclusive sweep still ties out
     "serve/prefill_chunk": "prefill",
     "serve/step_decode": "decode",
+    # the decode phases nest inside step_decode (serve/decode_wait also
+    # inside a prompt's last prefill chunk, where the higher-priority
+    # prefill stage owns the time): same stage, same sums. serve/plan,
+    # serve/step_finish and serve/bookkeep have no stage: engine_step's and
+    # the tick's own time outside the stages stays residual
+    "serve/decode_build": "decode",
+    "serve/decode_dispatch": "decode",
+    "serve/decode_wait": "decode",
+    "serve/decode_commit": "decode",
     "serve/demote": "demote",
     "serve/promote": "promote",
     "serve/drain": "drain",
+    "serve/drain_expire": "drain",
+    "serve/drain_fanout": "drain",
+    "serve/drain_reap": "drain",
 }
 
 #: per-request tracing namespace (reqtrace.py file-loads this module

@@ -100,6 +100,12 @@ _PRIORITY = {"demote": 6, "promote": 5, "prefill": 4, "decode": 3,
 #: skew between the retro tick window and the stage spans inside it).
 TIE_OUT_TOLERANCE = 0.05
 
+#: share of tick time the prefill stage must hold before the
+#: ``prefill_chunk_tokens`` rule calls prefill dominant. A share of host
+#: time: it moves with the host's load, unlike the counters the rule's
+#: prediction is judged by.
+PREFILL_DOMINANT_SHARE = 0.35
+
 _TICK_NAME = _NAMES.SERVE_TICK_NAME
 
 #: span name -> exclusive stage key: the names come from the
@@ -652,7 +658,8 @@ def propose_serve(report: Dict[str, Any]) -> List[Dict[str, Any]]:
     cur_chunk = int(sched_cfg.get("prefill_chunk_tokens", 0) or 0)
     maxp = sig["max_prefill_tokens_per_tick"]
     prefill_share = agg["prefill"]["share"]
-    if maxp > 0 and prefill_share >= 0.35 and agg["decode"]["share"] > 0 \
+    if maxp > 0 and prefill_share >= PREFILL_DOMINANT_SHARE \
+            and agg["decode"]["share"] > 0 \
             and (cur_chunk == 0 or maxp > cur_chunk // 2):
         # decode-first starvation: prefill dominates the tick while decodes
         # wait behind it (the p99 prefill tick IS the TPOT spike a long

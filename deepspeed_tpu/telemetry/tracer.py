@@ -10,9 +10,20 @@ summary API.
 Design constraints (all load-bearing):
 
 - **Never a host sync.** Emission reads ``time.monotonic()`` and appends a
-  tuple — no jax calls, no ``float()`` on device arrays, no transfers. The
-  emit helpers are registered DS002 hot paths, so the linter *proves* the
-  tracer cannot regrow a sync (``tools/dslint/hotpath.py``).
+  tuple — no ``float()`` on device arrays, no transfers, no wait for the
+  device. The one jax call is the profiler mirror below, which touches no
+  array. The emit helpers are registered DS002 hot paths, so the linter
+  *proves* the tracer cannot regrow a sync (``tools/dslint/hotpath.py``).
+- **One clock with the device trace.** While tracing is on, every ``with
+  tracer.span(...)`` also opens a ``jax.profiler.TraceAnnotation`` of the
+  same name, so whenever a profiler trace is being taken (by a benchmark or
+  by ``engine.start_profile_trace()``) the program's spans sit in that trace
+  beside the device operations; when none is, the annotation costs a flag
+  test. The step-level spans of ``STEP_SPANS`` open a
+  ``StepTraceAnnotation`` numbered by their tick or step. Enabled means
+  mirrored: there is no second switch. With tracing off ``span()`` returns
+  the shared no-op and jax is never imported from here. Retro-emitted
+  events (``complete``) have no live extent and stay on the ring alone.
 - **Lock-free emit.** ``deque.append`` and ``itertools.count.__next__`` are
   GIL-atomic; the only lock guards export/reconfiguration. Producers on the
   serve loop, prefetch worker, watchdog monitor, and main thread never
@@ -94,15 +105,44 @@ class _NoopSpan:
     def __exit__(self, exc_type, exc, tb):
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 _NOOP_SPAN = _NoopSpan()
+
+#: step-level spans -> the arg that numbers them: these mirror into the
+#: profiler as a ``StepTraceAnnotation`` (``step_num``), which is what the
+#: profiler's per-step analysis and the device's "Steps" line key on
+STEP_SPANS = {"serve/engine_step": "tick", "engine/train_step": "step"}
+
+_annotations = None     # (TraceAnnotation, StepTraceAnnotation), on first use
+
+
+def _profiler_annotation(name: str, args):
+    """The profiler's twin of one span. jax is imported here, on the first
+    span opened with tracing on, and never when tracing is off."""
+    global _annotations
+    if _annotations is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        _annotations = (TraceAnnotation, StepTraceAnnotation)
+    key = STEP_SPANS.get(name)
+    if key is not None and args and key in args:
+        return _annotations[1](name, step_num=args[key])
+    return _annotations[0](name)
 
 
 class _Span:
     """A live span: enter stamps t0, exit appends one complete ("X") event.
     Nesting falls out of Chrome-trace semantics — same-thread spans nest by
-    ts/dur containment, which the with-statement guarantees."""
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    ts/dur containment, which the with-statement guarantees. The profiler's
+    annotation starts when it is made. It is made after the ring's start
+    stamp and left before the ring's end stamp, so whatever the two calls
+    into the profiler cost (the thread may lose the interpreter lock there
+    for milliseconds) lies inside the span and not in a hole before it; the
+    two starts are microseconds apart (the benchmark's
+    ``trace_clock_skew_us``)."""
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_mirror")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -110,12 +150,21 @@ class _Span:
         self._cat = cat
         self._args = args
         self._t0 = 0.0
+        self._mirror = None
 
     def __enter__(self):
         self._t0 = time.monotonic()
+        self._mirror = _profiler_annotation(self._name, self._args)
+        self._mirror.__enter__()
         return self
 
+    def note(self, **args) -> None:
+        """Add args that are known only inside the span (``with
+        tracer.span(...) as sp: ...; sp.note(rebuilt=True)``)."""
+        self._args = dict(self._args or (), **args)
+
     def __exit__(self, exc_type, exc, tb):
+        self._mirror.__exit__(exc_type, exc, tb)
         t0 = self._t0
         self._tracer._emit(self._name, self._cat, "X", t0,
                            time.monotonic() - t0,

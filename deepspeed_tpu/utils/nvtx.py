@@ -4,18 +4,14 @@ Reference analog: ``deepspeed/utils/nvtx.py`` (``instrument_w_nvtx`` pushes an
 NVTX range via ``get_accelerator().range_push/pop`` around hot functions, e.g.
 every ZeRO-3 coordinator method).
 
-TPU redesign: ranges are ``jax.named_scope`` (names land in the HLO and show up
-in XLA/TPU profiler traces under the op hierarchy) plus
-``jax.profiler.TraceAnnotation`` for host-side spans (visible in perfetto
-traces captured by ``jax.profiler.trace``). One decorator serves both: inside
-jit the named_scope tags the emitted ops; outside it the TraceAnnotation times
-the Python call.
-
-Single source of span truth: every range ALSO lands in the dstrace tracer
-(``deepspeed_tpu.telemetry``) when tracing is on, so annotated hot functions
-show up in the same Chrome-trace timeline as the engine's dispatch/drain/
-checkpoint spans — without a second capture mechanism. When tracing is off
-the extra cost is one attribute read (the no-op fast path).
+TPU redesign: a range is a span of the dstrace tracer
+(``deepspeed_tpu.telemetry.tracer``), which is the repo's one bridge to the
+profiler: while tracing is on, every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so annotated functions sit
+beside the engine's spans on the ring and beside the device operations in a
+profiler trace. ``instrument`` adds a ``jax.named_scope``, so that inside jit
+the name also lands on the emitted ops. With tracing off a range is the
+tracer's shared no-op (one attribute read).
 """
 
 import functools
@@ -25,8 +21,14 @@ import jax
 from deepspeed_tpu.telemetry.tracer import get_tracer
 
 
+def annotate(name: str):
+    """``with annotate("step"): ...`` — a host-side range: the tracer's span,
+    mirrored into the profiler while tracing is on."""
+    return get_tracer().span(name, cat="annotate")
+
+
 def instrument(fn=None, *, name: str = None):
-    """Decorator: wrap ``fn`` in a profiler range named after it (reference
+    """Decorator: wrap ``fn`` in a range named after it (reference
     ``instrument_w_nvtx``). Usable bare (``@instrument``) or with a name
     (``@instrument(name="fetch")``)."""
     if fn is None:
@@ -35,8 +37,7 @@ def instrument(fn=None, *, name: str = None):
 
     @functools.wraps(fn)
     def wrapped(*args, **kwargs):
-        with get_tracer().span(label, cat="annotate"), \
-                jax.profiler.TraceAnnotation(label), jax.named_scope(label):
+        with annotate(label), jax.named_scope(label):
             return fn(*args, **kwargs)
 
     return wrapped
@@ -46,43 +47,9 @@ def instrument(fn=None, *, name: str = None):
 instrument_w_nvtx = instrument
 
 
-class _Annotation:
-    """``annotate``/``range_push`` context: one jax TraceAnnotation + (when
-    tracing is on) one dstrace span, entered and exited together."""
-    __slots__ = ("_name", "_jax_ctx", "_span")
-
-    def __init__(self, name: str):
-        self._name = name
-        self._jax_ctx = None
-        self._span = None
-
-    def __enter__(self):
-        tracer = get_tracer()
-        self._span = tracer.span(self._name, cat="annotate") \
-            if tracer.enabled else None
-        self._jax_ctx = jax.profiler.TraceAnnotation(self._name)
-        self._jax_ctx.__enter__()
-        if self._span is not None:
-            self._span.__enter__()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        if self._span is not None:
-            self._span.__exit__(exc_type, exc, tb)
-            self._span = None
-        ctx, self._jax_ctx = self._jax_ctx, None
-        return ctx.__exit__(exc_type, exc, tb)
-
-
-def annotate(name: str):
-    """``with annotate("step"): ...`` — host-side profiler span (jax
-    TraceAnnotation + dstrace span when tracing is enabled)."""
-    return _Annotation(name)
-
-
 def range_push(name: str):
-    """Manual range begin (reference accelerator.range_push). Returns a context
-    object; prefer ``with annotate(name):``."""
+    """Manual range begin (reference accelerator.range_push). Returns the
+    entered context for ``range_pop``; prefer ``with annotate(name):``."""
     ctx = annotate(name)
     ctx.__enter__()
     return ctx

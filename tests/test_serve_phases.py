@@ -1,0 +1,173 @@
+"""The tick's phases as the program names them: nested spans inside
+``engine.step`` that keep ``serve/step_prefill`` and ``serve/step_decode`` as
+they were, counts on the same spans, one ``tick`` on every span of a tick, and
+a stage ledger that still sums as before."""
+
+import dataclasses
+
+import jax
+import pytest
+
+from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
+                                                  V2EngineConfig)
+from deepspeed_tpu.models.llama import (TINY_LLAMA, LlamaForCausalLM,
+                                        random_tokens)
+from deepspeed_tpu.telemetry import names
+from deepspeed_tpu.telemetry.tracer import get_tracer
+
+PHASES = ("serve/decode_build", "serve/decode_dispatch", "serve/decode_wait",
+          "serve/decode_commit")
+WINDOW = 8
+
+
+@pytest.fixture(scope="module")
+def params():
+    cfg = dataclasses.replace(TINY_LLAMA, sliding_window=WINDOW)
+    model = LlamaForCausalLM(cfg)
+    tokens = random_tokens(1, 8, vocab_size=cfg.vocab_size)
+    return cfg, model.init(jax.random.PRNGKey(0), tokens)["params"]
+
+
+@pytest.fixture
+def tracing():
+    t = get_tracer()
+    was = t.enabled
+    t.configure(enabled=True)
+    t.clear()
+    yield t
+    t.configure(enabled=was)
+    t.clear()
+
+
+def _spans(tracer):
+    return [e for e in tracer.events_snapshot() if e[3] == "X"]
+
+
+def _inside(inner, outer, slack=1e-6):
+    return outer[4] - slack <= inner[4] and \
+        inner[4] + inner[5] <= outer[4] + outer[5] + slack
+
+
+def test_one_tick_emits_the_phases_nested_in_step_decode(params, tracing):
+    cfg, p = params
+    eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32))
+    prompts = [[1] * 5, [2] * 12, [3] * 20]
+    eng.put([1, 2, 3], prompts)
+    planned = [s.total_tokens for s in eng.state.decoding()]
+    tracing.clear()
+    eng.tick = 41
+    eng.step()
+    spans = _spans(tracing)
+    by_name = {e[1]: e for e in spans}
+    assert set(by_name) == {"serve/plan", "serve/step_decode",
+                            "serve/step_finish", *PHASES}
+    decode = by_name["serve/step_decode"]
+    for name in PHASES:
+        assert _inside(by_name[name], decode), name
+    starts = [by_name[n][4] for n in PHASES]
+    assert starts == sorted(starts)
+    assert by_name["serve/plan"][4] + by_name["serve/plan"][5] <= decode[4]
+    args = decode[7]
+    assert args["batch"] == 3 and args["batch"] <= args["bucket"]
+    assert args["bucket"] in eng.config.decode_batch_buckets
+    assert args["ctx_tokens"] == sum(planned) == sum(len(x) + 1
+                                                     for x in prompts)
+    assert args["ctx_tokens_windowed"] == sum(min(c, WINDOW)
+                                              for c in planned)
+    assert args["ctx_blocks"] in eng.config.ctx_block_buckets
+    assert by_name["serve/decode_build"][7]["tables_rebuilt"] is True
+    assert all(e[7]["tick"] == 41 for e in spans)
+    assert eng.tick == 42
+    # the same pages and shapes again: the device tables are kept
+    tracing.clear()
+    eng.step()
+    again = {e[1]: e for e in _spans(tracing)}
+    assert again["serve/decode_build"][7] == {"tick": 42,
+                                              "tables_rebuilt": False}
+    # the phases tile the step: what no phase holds is microseconds
+    held = sum(again[n][5]
+               for n in ("serve/plan", "serve/step_finish", *PHASES))
+    whole = again["serve/step_finish"][4] + again["serve/step_finish"][5] \
+        - again["serve/plan"][4]
+    assert whole - held < 5e-4
+
+
+def test_every_prefill_chunk_has_its_span_with_counts_and_the_wait(
+        params, tracing):
+    """No chunk cap set: the per-chunk span is there all the same, and the
+    blocking read after a prompt's last chunk is a ``serve/decode_wait``."""
+    cfg, p = params
+    eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32))
+    assert eng.config.scheduler.prefill_chunk_tokens == 0
+    eng.admit(7, [5] * 11)
+    eng.step()
+    spans = _spans(tracing)
+    (chunk,) = [e for e in spans if e[1] == "serve/prefill_chunk"]
+    (prefill,) = [e for e in spans if e[1] == "serve/step_prefill"]
+    (wait,) = [e for e in spans if e[1] == "serve/decode_wait"]
+    assert _inside(wait, chunk) and _inside(chunk, prefill)
+    assert chunk[7]["tokens"] == 11 and chunk[7]["start"] == 0
+    assert chunk[7]["bucket"] >= 11 and chunk[7]["uid"] == 7
+    assert {e[7]["tick"] for e in spans} == {0}
+
+
+def test_phase_names_are_registered_with_their_parents_stage():
+    for name in PHASES:
+        assert names.TRACE_NAMES[name] == ("span",)
+        assert names.SERVE_STAGE_OF[name] == \
+            names.SERVE_STAGE_OF["serve/step_decode"]
+    assert names.SERVE_STAGE_OF["serve/prefill_chunk"] == \
+        names.SERVE_STAGE_OF["serve/step_prefill"]
+    for part in ("serve/drain_expire", "serve/drain_fanout",
+                 "serve/drain_reap"):
+        assert names.SERVE_STAGE_OF[part] == names.SERVE_STAGE_OF["serve/drain"]
+    # no stage: engine_step's and the tick's own time stays residual
+    assert "serve/plan" not in names.SERVE_STAGE_OF
+    assert "serve/bookkeep" not in names.SERVE_STAGE_OF
+    assert names.TRACE_NAMES["serve/plan"] == ("span",)
+    assert names.TRACE_NAMES["serve/bookkeep"] == ("complete",)
+    assert names.TRACE_NAMES["serve/step_finish"] == ("span",)
+    assert "serve/step_finish" not in names.SERVE_STAGE_OF
+
+
+def test_a_served_tick_shares_one_number_and_the_ledger_sums_as_before(
+        tracing):
+    from deepspeed_tpu.serving.bench_serve import build_tiny_server
+    from deepspeed_tpu.telemetry import serve_attribution as sa
+    server = build_tiny_server().start()
+    try:
+        reqs = [server.submit([1, 2, 3, 4 + i], max_new_tokens=6)
+                for i in range(3)]
+        for r in reqs:
+            r.result(timeout=120)
+    finally:
+        server.stop(drain_timeout=10.0)
+    events = tracing.events_snapshot()
+    spans = [e for e in events if e[3] == "X"]
+    ticks = {e[7]["tick"]: e for e in spans if e[1] == "serve/tick"}
+    assert ticks
+    loop = {e[6] for e in spans if e[1] == "serve/tick"}
+    emitted = {e[1] for e in spans if e[6] in loop}
+    assert {"serve/plan", "serve/bookkeep", "serve/step_finish",
+            "serve/drain_expire",
+            "serve/drain_fanout", "serve/drain_reap", "serve/engine_step",
+            *PHASES} <= emitted
+    assert "serve/drain" not in emitted
+    # every span of the serve loop carries the number of the tick it lies in
+    for e in spans:
+        if e[6] not in loop or e[1] == "serve/tick":
+            continue
+        assert e[7] and "tick" in e[7], e[1]
+        assert _inside(e, ticks[e[7]["tick"]]), (e[1], e[7]["tick"])
+    # the stage ledger: every window ties out, and the decode stage is what
+    # the step_decode spans alone give (the phases inside add nothing)
+    chrome = tracing.to_chrome(events)
+    report = sa.attribute_serve(sa.events_from_chrome(chrome))
+    assert report["ticks_total"] == len(ticks)
+    assert all(w["tie_out_error"] <= sa.TIE_OUT_TOLERANCE
+               for w in report["windows"])
+    decode_ms = sum(e[5] for e in spans if e[1] == "serve/step_decode") * 1e3
+    assert report["aggregate"]["decode"]["total_ms"] == \
+        pytest.approx(decode_ms, rel=1e-3, abs=1e-2)
+    bookkeep_ms = sum(e[5] for e in spans if e[1] == "serve/bookkeep") * 1e3
+    assert report["aggregate"]["residual"]["total_ms"] >= bookkeep_ms * 0.99
