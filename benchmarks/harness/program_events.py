@@ -113,12 +113,15 @@ def fill(evs: Sequence[Event], name: str, used: str, padded: str):
 def attributed_idle_share(gaps: Sequence[Tuple[float, float]],
                           evs: Sequence[Event]):
     """Seconds of the idle ``gaps`` whose midpoint lies in a leaf phase span
-    (the shortest span of ``evs`` that holds it, ``trace.span_at``, is not
+    (the shortest span of ``evs`` that holds it, ``trace.spans_at``, is not
     one of ``CONTAINERS``) over all idle seconds, in percent; None when
-    there is no idle time or no span."""
+    there is no idle time or no span. ``evs`` may be the spans of the whole
+    run: the sweep passes over those that end before the first midpoint or
+    start after the last."""
     whole = sum(b - a for a, b in gaps)
     if not whole or not evs:
         return None
-    named = sum(b - a for a, b in gaps
-                if tr.span_at(evs, (a + b) / 2) not in CONTAINERS)
+    names = tr.spans_at(evs, [(a + b) / 2 for a, b in gaps])
+    named = sum(b - a for (a, b), name in zip(gaps, names)
+                if name not in CONTAINERS)
     return 100.0 * named / whole

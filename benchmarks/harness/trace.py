@@ -6,11 +6,15 @@ Times are seconds on the profiler's clock. An ``Op`` is one event of a
 device's "XLA Ops" line; a ``Span`` is a host interval on the same clock.
 """
 
+import bisect
 import dataclasses
 import glob
+import heapq
+import itertools
 import os
 import re
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 Interval = Tuple[float, float]
 
@@ -101,6 +105,22 @@ def subtract(intervals: Sequence[Interval],
     return out
 
 
+def held_whole(intervals: Iterable[Interval]) -> Callable[[float, float], bool]:
+    """A test ``(a, b)``: does one of ``intervals`` hold ``[a, b]`` whole,
+    ends inclusive? What ``any(s <= a and b <= e for s, e in intervals)``
+    answers, for intervals that may overlap, by one bisection: of the
+    intervals that start at or before ``a`` the one that reaches furthest
+    decides."""
+    ordered = sorted(intervals)
+    starts = [s for s, _ in ordered]
+    reach = list(itertools.accumulate((e for _, e in ordered), max))
+
+    def test(a: float, b: float) -> bool:
+        i = bisect.bisect_right(starts, a)
+        return i > 0 and b <= reach[i - 1]
+    return test
+
+
 # --- reductions -------------------------------------------------------------
 
 def leaf_ops(ops: Sequence[Op]) -> List[Op]:
@@ -161,8 +181,39 @@ def idle_gaps(trace: Trace, window: Interval, device: int) -> List[Interval]:
 
 def span_at(spans: Sequence[Span], t: float) -> str:
     """Name of the shortest host span that holds instant ``t``."""
-    holding = [s for s in spans if s.start <= t <= s.end]
-    return min(holding, key=lambda s: s.dur).name if holding else "(no span)"
+    return spans_at(spans, [t])[0]
+
+
+def spans_at(spans: Sequence[Span], instants: Sequence[float]) -> List[str]:
+    """For each instant the name of the shortest span that holds it (ends
+    inclusive; of equally short ones the first in ``spans``), or
+    ``"(no span)"``. One sweep: the instants in rising order over the spans
+    by start, the spans that have opened on a heap by (duration, place in
+    ``spans``). A span on top that has ended is dropped for good, since no
+    later instant can fall in it, so what stays on top is the shortest one
+    that holds the instant. Spans that lie wholly before the first or after
+    the last instant are never looked at. O((n + m) log m) for n instants and
+    m spans, where a scan of the spans for each instant is n x m; the spans
+    need not nest."""
+    out = ["(no span)"] * len(instants)
+    if not out or not spans:
+        return out
+    lo, hi = min(instants), max(instants)
+    opening = sorted((s.start, i) for i, s in enumerate(spans)
+                     if s.start <= hi and s.end >= lo)
+    heap: List[Tuple[float, int]] = []
+    j = 0
+    for k in sorted(range(len(instants)), key=instants.__getitem__):
+        t = instants[k]
+        while j < len(opening) and opening[j][0] <= t:
+            i = opening[j][1]
+            heapq.heappush(heap, (spans[i].dur, i))
+            j += 1
+        while heap and spans[heap[0][1]].end < t:
+            heapq.heappop(heap)
+        if heap:
+            out[k] = spans[heap[0][1]].name
+    return out
 
 
 def longest_gaps(trace: Trace, window: Interval, device: int,
@@ -171,7 +222,8 @@ def longest_gaps(trace: Trace, window: Interval, device: int,
     its middle falls in."""
     gaps = sorted(idle_gaps(trace, window, device),
                   key=lambda g: g[0] - g[1])[:n]
-    return [(span_at(trace.spans, (a + b) / 2), b - a) for a, b in gaps]
+    names = spans_at(trace.spans, [(a + b) / 2 for a, b in gaps])
+    return [(name, b - a) for name, (a, b) in zip(names, gaps)]
 
 
 def op_seconds(ops: Iterable[Op]) -> Dict[str, float]:

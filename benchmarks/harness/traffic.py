@@ -13,11 +13,14 @@ the arrivals (sorted uniform draws over the span, i.e. a Poisson process
 given its count) and makes the token ids: two seeds are two hours of the same
 traffic, and a metric is judged only if it repeats across them (PERF.md,
 PR 23: over the chat cell's 51 requests the upper quartile of the token gaps
-does, no statistic of the time to first token does). In a closed loop the
-order comes
-from the traffic file (each block of requests is the whole multiset, so any
-prefix the callers get through holds the same mix) and ``--seed`` makes the
-token ids.
+does, no statistic of the time to first token does). In a closed loop each
+block of requests is the whole multiset (so any prefix the callers get
+through holds the same mix); ``--seed`` makes the token ids and, unless the
+traffic file fixes an ``order_seed``, the order inside every block. With one
+order for every seed a quiet host replays one schedule token for token and a
+busy one leaves it, so the spread a check reads is 0 or 0.5% by the host,
+and neither is the 1% by which two schedules of this loop differ (PERF.md,
+PR 27); with the seed's order every run is another deal on any host.
 """
 
 import dataclasses
@@ -100,10 +103,12 @@ def open_loop_schedule(traffic: dict, rate_rps: float, seconds: float,
 def closed_loop_schedule(traffic: dict, seed: int, vocab: int) -> Schedule:
     """``traffic["blocks"]`` blocks of ``traffic["block_requests"]`` requests.
     Each block is the whole quantile multiset in an order of its own (from
-    the traffic file's seed), so any prefix the clients get through holds the
-    same mix to within one block."""
+    ``seed``, or from the traffic file's ``order_seed`` where it has one), so
+    any prefix the clients get through holds the same mix to within one
+    block."""
     rng = np.random.default_rng([int(seed), 2])
-    order = np.random.default_rng(traffic["order_seed"])
+    fixed = traffic.get("order_seed")
+    order = np.random.default_rng([int(seed), 6] if fixed is None else fixed)
     size = traffic["block_requests"]
     base = length_pairs(traffic, size)
     pairs = np.concatenate([base[order.permutation(size)]

@@ -6,6 +6,7 @@ bound by bytes, not operations. Taken over the ticks that ran wholly inside
 the traced sub-window."""
 
 from benchmarks.harness import costs, peaks, readers
+from benchmarks.harness import trace as tr
 
 
 def read(obs):
@@ -16,9 +17,9 @@ def read(obs):
     need = hf["num_hidden_layers"] * sum(costs.paged_decode_bytes(
         t[3], hf["num_key_value_heads"], readers.head_dim(hf),
         readers.itemsize(hf), hf.get("sliding_window")) for t in ticks)
-    calls = [o for o in readers.kernels(readers.compute_ops(obs, "decode_step"))
-             if any(t[0] <= o.start and o.end <= t[1] for t in ticks)]
-    spent = sum(o.dur for o in calls)
+    in_a_tick = tr.held_whole((t[0], t[1]) for t in ticks)
+    spent = sum(o.dur for o in readers.kernels(
+        readers.compute_ops(obs, "decode_step")) if in_a_tick(o.start, o.end))
     if not spent:
         return None
     least = need / peaks.peak(obs.device_kind, "hbm_bytes_per_s")

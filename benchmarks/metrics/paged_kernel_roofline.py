@@ -9,6 +9,7 @@ those ticks' spans."""
 
 from benchmarks.harness import costs, named_readers, peaks, readers
 from benchmarks.harness import program_events as pe
+from benchmarks.harness import trace as tr
 from benchmarks.harness import xplane_names as xn
 
 
@@ -24,11 +25,11 @@ def read(obs):
     need = hf["num_hidden_layers"] * costs.paged_decode_bytes(
         [e.arg("ctx_tokens_windowed") for e in ticks],
         hf["num_key_value_heads"], readers.head_dim(hf), readers.itemsize(hf))
+    in_a_tick = tr.held_whole((t.start, t.end) for t in ticks)
     spent = sum(o.dur for o in ops
                 if xn.kernel_of(o) == "paged_attention"
                 and "decode_step" in o.program
-                and any(t.start <= o.start + shift and o.end + shift <= t.end
-                        for t in ticks))
+                and in_a_tick(o.start + shift, o.end + shift))
     if not spent:
         return None
     least = need / peaks.peak(obs.device_kind, "hbm_bytes_per_s")

@@ -87,8 +87,12 @@ def test_closed_loop_blocks_hold_the_same_multiset(seed):
     assert s.prompt_len.max() + s.output_len.max() <= 4096
 
 
-def test_closed_loop_seed_changes_only_the_tokens():
-    rag = load("batch-rag")
+DATA = pathlib.Path(__file__).resolve().parent / "data"
+
+
+def test_closed_loop_fixed_order_seed_changes_only_the_tokens():
+    rag = json.loads((DATA / "tiny-rag.json").read_text())
+    assert rag["order_seed"] is not None
     a = traffic.closed_loop_schedule(rag, 1, 32000)
     b = traffic.closed_loop_schedule(rag, 2, 32000)
     assert np.array_equal(a.prompt_len, b.prompt_len)
@@ -96,6 +100,25 @@ def test_closed_loop_seed_changes_only_the_tokens():
     assert not np.array_equal(a.prompts[0], b.prompts[0])
     size = rag["block_requests"]      # each block in an order of its own
     assert not np.array_equal(a.prompt_len[:size], a.prompt_len[size:2 * size])
+
+
+@pytest.mark.parametrize("seeds", [(1, 2), (7, 2 ** 31 + 11), (0, 3000000019)])
+def test_closed_loop_seed_deals_the_order_of_the_same_multiset(seeds):
+    """batch-rag has no ``order_seed``: two seeds are two deals of one
+    multiset, block by block; one seed is one deal."""
+    rag = load("batch-rag")
+    assert rag.get("order_seed") is None
+    a, b = (traffic.closed_loop_schedule(rag, s, 32000) for s in seeds)
+    size = rag["block_requests"]
+    for k in (0, 1, rag["blocks"] - 1):
+        block = (np.arange(len(a)) // size) == k
+        assert pairs(a, block) == pairs(b, block)
+        assert not np.array_equal(a.prompt_len[block], b.prompt_len[block])
+    assert not np.array_equal(a.prompt_len[:size], a.prompt_len[size:2 * size])
+    again = traffic.closed_loop_schedule(rag, seeds[0], 32000)
+    assert np.array_equal(a.prompt_len, again.prompt_len)
+    assert np.array_equal(a.output_len, again.output_len)
+    assert all(np.array_equal(x, y) for x, y in zip(a.prompts, again.prompts))
 
 
 @pytest.mark.parametrize("chips,gas", [(1, 4), (4, 1), (2, 2)])
