@@ -330,10 +330,6 @@ class InferenceEngineV2:
                              self.config.scheduler,
                              block_tokens=self.kv.cfg.block_size)
             out: Dict[int, int] = {}
-            # scaled fp8 pages carry their per-(head, page) scales through
-            # the jitted steps as a (pages, scales) tuple
-            cache = self.kv.data if self.kv.scales is None else \
-                (self.kv.data, self.kv.scales)
         t_prefill = t_decode = 0.0
         t0 = time.monotonic()
 
@@ -352,8 +348,13 @@ class InferenceEngineV2:
                 tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
                 mb = self._ctx_bucket_blocks(end)
                 table = self._block_table(seq, mb)
-                logits, cache = prefill_chunk_g(
-                    self.params, cache, jnp.asarray(tokens), chunk.start,
+                # the step programs consume the pool they are given: what
+                # comes back is bound at once, so that a fault later in the
+                # tick (and the server's next step after it) finds the
+                # engine on a live pool
+                logits, self.kv.pool = prefill_chunk_g(
+                    self.params, self.kv.pool, jnp.asarray(tokens),
+                    chunk.start,
                     jnp.asarray(table), chunk.length,
                     policy=self.policy, cfg=self.model_config,
                     block_size=self.kv.cfg.block_size,
@@ -412,8 +413,8 @@ class InferenceEngineV2:
                     self._table_sig = sig
                 build.note(tables_rebuilt=rebuilt)
             with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
-                logits, cache = decode_step_g(
-                    self.params, cache, jnp.asarray(tokens),
+                logits, self.kv.pool = decode_step_g(
+                    self.params, self.kv.pool, jnp.asarray(tokens),
                     jnp.asarray(positions), self._dev_tables,
                     jnp.asarray(valid),
                     policy=self.policy, cfg=self.model_config,
@@ -448,10 +449,6 @@ class InferenceEngineV2:
                     if window else whole, ctx_blocks=mb)
 
         with tracer.span("serve/step_finish", cat="serve", tick=tick):
-            if self.kv.scales is None:
-                self.kv.data = cache
-            else:
-                self.kv.data, self.kv.scales = cache
             self.tick = tick + 1
             # the serve tick's stage clocks read these
             # (serve/tick_stage_share gauges + `dstpu plan --serve`
@@ -937,18 +934,12 @@ class InferenceEngineV2:
         tokens = np.zeros((bucket,), np.int32)
         tokens[0] = last
         tokens[1:true_len] = proposed
-        cache = self.kv.data if self.kv.scales is None else \
-            (self.kv.data, self.kv.scales)
-        logits, cache = verify_chunk_g(
-            self.params, cache, jnp.asarray(tokens), ctx - 1,
+        logits, self.kv.pool = verify_chunk_g(
+            self.params, self.kv.pool, jnp.asarray(tokens), ctx - 1,
             jnp.asarray(self._block_table(seq, mb)), true_len,
             policy=self.policy, cfg=self.model_config,
             block_size=self.kv.cfg.block_size,
             attn_impl=self.config.attn_impl)
-        if self.kv.scales is None:
-            self.kv.data = cache
-        else:
-            self.kv.data, self.kv.scales = cache
         preds = np.asarray(jnp.argmax(logits[:true_len], axis=-1))
         emitted = []
         for i, p in enumerate(proposed):

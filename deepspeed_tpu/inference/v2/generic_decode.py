@@ -18,8 +18,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.kv_cache import (cast_to_page_dtype,
-                                                 write_kv_scaled)
+from deepspeed_tpu.inference.v2.kv_cache import write_kv, write_kv_scaled
 from deepspeed_tpu.inference.v2.llama_decode import _paged_attn
 
 
@@ -77,10 +76,7 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
                                        attn_impl, softcap=softcap,
                                        scales=scales)[0]
             with jax.named_scope("attn/kv_write"):
-                cache = cache.at[i, 0, :, tok_block, tok_off].set(
-                    cast_to_page_dtype(k, cache.dtype))
-                cache = cache.at[i, 1, :, tok_block, tok_off].set(
-                    cast_to_page_dtype(v, cache.dtype))
+                cache = write_kv(cache, i, k, v, tok_block, tok_off)
             with jax.named_scope("attn/paged"):
                 return _paged_attn(q[None], cache, i, block_table[None],
                                    jnp.asarray(start).reshape(1), win,
@@ -89,7 +85,8 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
     return x, cache
 
 
-@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"))
+@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
+         donate_argnames=("cache_data",))
 def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
                     policy, cfg, block_size: int, attn_impl: str = "auto"):
     """One sequence, one bucket-padded chunk; returns (last-token logits [V],
@@ -104,7 +101,8 @@ def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
     return logits, cache
 
 
-@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"))
+@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
+         donate_argnames=("cache_data",))
 def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
                    policy, cfg, block_size: int, attn_impl: str = "auto"):
     """Speculative-decoding verifier: the same cache-writing chunk forward
@@ -121,7 +119,8 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
         return policy.unembed(params, x, cfg), cache
 
 
-@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"))
+@partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
+         donate_argnames=("cache_data",))
 def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                   policy, cfg, block_size: int, attn_impl: str = "auto"):
     """Batched single-token decode; returns (logits [B, V], updated
@@ -164,10 +163,7 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                                        safe_pos, win, attn_impl,
                                        softcap=softcap, scales=scales)[:, 0]
             with jax.named_scope("attn/kv_write"):
-                cache = cache.at[i, 0, :, blk, off].set(
-                    cast_to_page_dtype(k, cache.dtype))
-                cache = cache.at[i, 1, :, blk, off].set(
-                    cast_to_page_dtype(v, cache.dtype))
+                cache = write_kv(cache, i, k, v, blk, off)
             with jax.named_scope("attn/paged"):
                 return _paged_attn(q[:, None], cache, i, block_tables,
                                    safe_pos, win, attn_impl,
@@ -184,7 +180,36 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
 # process compile counter — bench_serve asserts ZERO compiles inside the
 # measured window after warmup (telemetry/compiles.py)
 from deepspeed_tpu.telemetry.compiles import watch_jit  # noqa: E402
+from deepspeed_tpu.telemetry.tracer import get_tracer  # noqa: E402
 
-prefill_chunk_g = watch_jit(prefill_chunk_g, "generic_decode.prefill_chunk_g")
-verify_chunk_g = watch_jit(verify_chunk_g, "generic_decode.verify_chunk_g")
-decode_step_g = watch_jit(decode_step_g, "generic_decode.decode_step_g")
+
+def _report_kv_alias(fn, name, args, kwargs, out):
+    """One ``serve/kv_alias`` instant per compiled step program: whether the
+    pool handed in was consumed (``donated``: every leaf reads deleted) and
+    how many bytes the program updates in place (``alias_bytes``, of
+    ``pool_bytes``). Everything here is free once the call has compiled:
+    ``is_deleted`` and ``nbytes`` read no device memory, and lowering the
+    same call again (with the pool that came back in the place of the one
+    consumed) finds the computation jit has just built, whose ``compile()``
+    hands back the executable it holds — no second compile, no load from
+    the compile cache, no wait on the device. The pool is the step
+    functions' second positional argument and second result."""
+    tracer = get_tracer()
+    if not tracer.enabled:
+        return
+    pool_in, pool_out = args[1], out[1]
+    stats = fn.lower(args[0], pool_out, *args[2:],
+                     **kwargs).compile().memory_analysis()
+    tracer.instant(
+        "serve/kv_alias", cat="serve", fn=name,
+        donated=all(x.is_deleted() for x in jax.tree.leaves(pool_in)),
+        alias_bytes=int(stats.alias_size_in_bytes),
+        pool_bytes=sum(int(x.nbytes) for x in jax.tree.leaves(pool_out)))
+
+
+prefill_chunk_g = watch_jit(prefill_chunk_g, "generic_decode.prefill_chunk_g",
+                            _report_kv_alias)
+verify_chunk_g = watch_jit(verify_chunk_g, "generic_decode.verify_chunk_g",
+                           _report_kv_alias)
+decode_step_g = watch_jit(decode_step_g, "generic_decode.decode_step_g",
+                          _report_kv_alias)

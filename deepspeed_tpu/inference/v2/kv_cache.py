@@ -12,6 +12,7 @@ scalar prefetch) or gather a contiguous context window (CPU fallback).
 """
 
 import dataclasses
+from functools import partial
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -51,6 +52,23 @@ class BlockedKVCache:
             jnp.float32) if cfg.dtype == jnp.float8_e4m3fn else None)
 
     @property
+    def pool(self):
+        """What the step programs take and give back: ``data``, or
+        ``(data, scales)`` for scaled fp8 pages. They consume what they are
+        given (``generic_decode`` donates it and updates it in place), so
+        the pool that comes back is assigned here right after the call:
+        ``logits, kv.pool = decode_step_g(params, kv.pool, ...)``. Read the
+        pool afresh after a step; an array kept across one is deleted."""
+        return self.data if self.scales is None else (self.data, self.scales)
+
+    @pool.setter
+    def pool(self, cache) -> None:
+        if self.scales is None:
+            self.data = cache
+        else:
+            self.data, self.scales = cache
+
+    @property
     def free_blocks(self) -> int:
         return self.allocator.free_blocks
 
@@ -77,8 +95,7 @@ class BlockedKVCache:
             # reset released pages' scales: a page freed by a sequence with
             # outlier K/V must not impose its grown scale (= lost precision)
             # on the next sequence the allocator hands it to
-            self.scales = self.scales.at[
-                :, :, :, jnp.asarray(blocks)].set(1.0)
+            self.scales = _set_blocks(self.scales, jnp.asarray(blocks), 1.0)
 
     # ------------------------------------------------------------------
     # host offload tier (serving demotion/promotion; see kv_offload.py)
@@ -102,11 +119,19 @@ class BlockedKVCache:
         so a promoted sequence's quantization state is bit-identical to
         what it was at demotion."""
         idx = jnp.asarray(np.asarray(blocks, np.int32))
-        self.data = self.data.at[:, :, :, idx].set(
-            jnp.asarray(data, self.cfg.dtype))
+        self.data = _set_blocks(self.data, idx,
+                                jnp.asarray(data, self.cfg.dtype))
         if self.scales is not None and scales is not None:
-            self.scales = self.scales.at[:, :, :, idx].set(
-                jnp.asarray(scales, jnp.float32))
+            self.scales = _set_blocks(self.scales, idx,
+                                      jnp.asarray(scales, jnp.float32))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _set_blocks(pool, idx, vals):
+    """``pool[:, :, :, idx] = vals`` in place (pages or scales; ``vals``
+    whole blocks or one number). Outside a jit the same update writes a
+    second pool and holds both until the first is dropped."""
+    return pool.at[:, :, :, idx].set(vals)
 
 
 FP8_MAX = 448.0     # float8_e4m3fn max finite; overflow casts become NaN
@@ -150,41 +175,46 @@ def write_kv_scaled(cache_data, scales, layer: int, kv: int, vals,
     page_max = page_max.at[:, -1].set(0.0)
     new_s = jnp.maximum(old_s, page_max / FP8_MAX)              # [H, NB]
 
+    # the head is an index like the page and the offset (see ``write_kv``):
+    # the pool keeps its layout through both scatters
+    heads = jnp.arange(cache_data.shape[2])[None, :]
+
     # requantize touched pages under the grown scale — predicated: in
     # steady-state decode no scale grows and the full-page read-modify-write
     # would be pure wasted HBM bandwidth in the hot path
     def requant(data):
-        old_tile = data[layer, kv, :, touched_pages]            # [P, H, bs, D]
+        pages = touched_pages[:, None]
+        old_tile = data[layer, kv, heads, pages]                # [P, H, bs, D]
         ratio = (old_s / new_s)[:, touched_pages].T             # [P, H]
         tile = old_tile.astype(f32) * ratio[..., None, None]
-        return data.at[layer, kv, :, touched_pages].set(
-            tile.astype(data.dtype))
+        return data.at[layer, kv, heads, pages].set(tile.astype(data.dtype))
 
     cache_data = jax.lax.cond(jnp.any(new_s > old_s), requant,
                               lambda data: data, cache_data)
     # write the new tokens under the new scale
     tok_scale = new_s[:, block_ids].T                           # [T, H]
-    cache_data = cache_data.at[layer, kv, :, block_ids, offsets].set(
-        cast_to_page_dtype(vals.astype(f32) / tok_scale[..., None],
-                           cache_data.dtype))
+    cache_data = cache_data.at[
+        layer, kv, heads, block_ids[:, None], offsets[:, None]].set(
+            cast_to_page_dtype(vals.astype(f32) / tok_scale[..., None],
+                               cache_data.dtype))
     return cache_data, scales.at[layer, kv].set(new_s)
 
 
-def write_kv_block_tokens(cache_data, layer: int, k_new, v_new, block_ids,
-                          start_pos: int, block_size: int):
-    """Scatter new K/V tokens into their blocks (jit-friendly building block).
+def write_kv(cache_data, layer: int, k_new, v_new, block_ids, offsets):
+    """Scatter new K/V tokens into their page slots, in place where the pool
+    is donated (``generic_decode``).
 
-    k_new/v_new: [T, H, D]; block_ids: [T] target block per token;
-    offsets derived from positions. Used by the engine's compiled step via
-    flat (block, offset) indices.
+    cache_data: [L, 2, H, NB, bs, D]; k_new/v_new: [T, H, D]; block_ids/
+    offsets: [T], the slot of each token. The head is an index like the block
+    and the offset, so that one update is one D row of the pool as it lies in
+    memory. Written ``[layer, kv, :, block_ids, offsets]`` an update is an
+    [H, D] window strided over the heads, and XLA on the TPU then transposes
+    the whole pool to token-major before the scatter and back after it, and
+    every layer's pages back for the kernel: pool-sized copies, each step.
     """
-    t = k_new.shape[0]
-    positions = start_pos + jnp.arange(t)
-    offsets = positions % block_size
-    # head-major pages: advanced (block, offset) dims land first, so the
-    # indexed view is [T, H, D] — matching k_new directly
-    cache_data = cache_data.at[layer, 0, :, block_ids, offsets].set(
-        cast_to_page_dtype(k_new, cache_data.dtype))
-    cache_data = cache_data.at[layer, 1, :, block_ids, offsets].set(
-        cast_to_page_dtype(v_new, cache_data.dtype))
+    heads = jnp.arange(cache_data.shape[2])[None, :]
+    blk, off = block_ids[:, None], offsets[:, None]
+    for kv, new in enumerate((k_new, v_new)):
+        cache_data = cache_data.at[layer, kv, heads, blk, off].set(
+            cast_to_page_dtype(new, cache_data.dtype))
     return cache_data

@@ -35,7 +35,7 @@ import numpy as np
 
 from deepspeed_tpu.models.llama import LlamaConfig
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    paged_attention, paged_attention_reference)
+    paged_attention_pool, paged_attention_reference)
 
 ATTN_IMPLS = ("auto", "kernel", "kernel_interpret", "gather")
 
@@ -48,20 +48,21 @@ def _paged_attn(q, cache_data, layer, block_tables, start_pos, window,
     pages) dequantizes per (head, page) on load in both paths."""
     if attn_impl not in ATTN_IMPLS:
         raise ValueError(f"unknown attn_impl {attn_impl!r}; one of {ATTN_IMPLS}")
-    k_pages, v_pages = cache_data[layer, 0], cache_data[layer, 1]
-    ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
-        else (None, None)
     impl = attn_impl
     if impl == "auto":
         impl = "kernel" if jax.default_backend() == "tpu" else "gather"
     if impl == "gather":
-        return paged_attention_reference(q, k_pages, v_pages, block_tables,
+        ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
+            else (None, None)
+        return paged_attention_reference(q, cache_data[layer, 0],
+                                         cache_data[layer, 1], block_tables,
                                          start_pos, window=window,
                                          softcap=softcap, k_scales=ks,
                                          v_scales=vs)
-    return paged_attention(q, k_pages, v_pages, block_tables, start_pos,
-                           window=window, softcap=softcap, k_scales=ks,
-                           v_scales=vs, interpret=impl == "kernel_interpret")
+    # the kernel takes the pool whole: a slice of it is a copy of it
+    return paged_attention_pool(q, cache_data, layer, block_tables, start_pos,
+                                window=window, softcap=softcap, scales=scales,
+                                interpret=impl == "kernel_interpret")
 
 
 def _rms(x, scale, eps):

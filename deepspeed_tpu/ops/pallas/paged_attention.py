@@ -131,8 +131,36 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
     doubles as the context-length mask, so trash-padded table slots and stale
     tail entries of the last page are never visible.
     """
+    return _paged_call(q, k_pages, v_pages, 0, 0, k_pages.shape[0],
+                       block_tables, start_pos, window, softcap, k_scales,
+                       v_scales, interpret)
+
+
+def paged_attention_pool(q, pool, layer: int, block_tables, start_pos,
+                         window=None, softcap=None, scales=None,
+                         interpret: bool = False):
+    """``paged_attention`` over layer ``layer`` (a Python int) of the whole
+    KV pool [L, 2, Hkv, NB, block_size, d] (``scales``: [L, 2, Hkv, NB]).
+    The pool goes to the kernel as it lies in memory, its leading dimensions
+    merged, and the index maps start at the layer's K and V heads: a step
+    program that handed ``pool[layer, 0]`` and ``pool[layer, 1]`` to the
+    kernel copied each out first, the whole pool once a step."""
+    hkv = pool.shape[2]
+    pages = pool.reshape((-1,) + pool.shape[3:])
+    ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
+        else (None, None)
+    return _paged_call(q, pages, pages, 2 * layer * hkv,
+                       (2 * layer + 1) * hkv, hkv, block_tables, start_pos,
+                       window, softcap, ks, vs, interpret)
+
+
+def _paged_call(q, k_pages, v_pages, k_head0: int, v_head0: int, hkv: int,
+                block_tables, start_pos, window, softcap, k_scales, v_scales,
+                interpret: bool):
+    """The kernel call: KV head ``hi`` of the grid reads row ``k_head0 + hi``
+    of ``k_pages`` and row ``v_head0 + hi`` of ``v_pages`` ([X, NB, bs, d])."""
     b, t, h, d = q.shape
-    hkv, nb, bs, _ = k_pages.shape
+    _, nb, bs, _ = k_pages.shape
     rep = h // hkv
     g = rep * t
     # fold rows per grid cell: a sublane multiple, capped for scoped VMEM
@@ -152,9 +180,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
             pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
                          (bi, hi, i, 0)),
             pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
-                         (hi, pf[0][bi * mb + j], 0, 0)),
+                         (k_head0 + hi, pf[0][bi * mb + j], 0, 0)),
             pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
-                         (hi, pf[0][bi * mb + j], 0, 0)),
+                         (v_head0 + hi, pf[0][bi * mb + j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
                                (bi, hi, i, 0)),

@@ -107,11 +107,13 @@ class CompileWatched:
     straight through; a jit-cache growth marks the call as a compile and
     emits the ``xla/compile`` instant. Attribute access (``.lower``,
     ``.clear_cache``...) delegates to the wrapped function."""
-    __slots__ = ("_fn", "_name", "_watched")
+    __slots__ = ("_fn", "_name", "_watched", "_on_compile")
 
-    def __init__(self, fn: Callable, name: str):
+    def __init__(self, fn: Callable, name: str,
+                 on_compile: Optional[Callable] = None):
         self._fn = fn
         self._name = name
+        self._on_compile = on_compile
         # jax.jit functions expose the compiled-signature cache size; a
         # callable without it (a plain python fn) is passed through
         # unwatched rather than broken. Only the fact is kept: the bound
@@ -130,15 +132,20 @@ class CompileWatched:
         if self._fn._cache_size() > before:
             record_compile(self._name, signature_of(args, kwargs),
                            time.monotonic() - t0)
+            if self._on_compile is not None:
+                self._on_compile(self._fn, self._name, args, kwargs, out)
         return out
 
     def __getattr__(self, item):
         return getattr(self._fn, item)
 
 
-def watch_jit(fn: Callable, name: str) -> CompileWatched:
+def watch_jit(fn: Callable, name: str,
+              on_compile: Optional[Callable] = None) -> CompileWatched:
     """Wrap a jitted callable so its compiles land in the ledger. The
     contract every engine/serving jit dispatch site follows: the wrapper
     is shape-transparent (same args, same return, donation semantics
-    untouched) and adds one int probe per dispatch."""
-    return CompileWatched(fn, name)
+    untouched) and adds one int probe per dispatch. ``on_compile(fn, name,
+    args, kwargs, out)`` runs after a call that compiled, on that slow
+    path only: what a site wants to say about the program it just built."""
+    return CompileWatched(fn, name, on_compile)

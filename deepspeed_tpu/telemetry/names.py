@@ -120,6 +120,7 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/kv_demote": ("instant",),
     "serve/kv_promote": ("instant",),
     "serve/kv_recalibrate": ("instant",),
+    "serve/kv_alias": ("instant",),         # one per compiled step program
     "serve/kv_drift": ("instant",),
     "serve/ladder": ("instant",),
     "serve/prefix_evict": ("instant",),

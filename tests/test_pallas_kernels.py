@@ -184,6 +184,35 @@ def test_paged_attention_kernel(window, softcap):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("pages", ["plain", "fp8-scaled"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_attention_over_the_whole_pool(layer, pages):
+    """``paged_attention_pool`` finds a layer's pages through its index maps:
+    the same floats as the kernel on that layer's K and V pages sliced out."""
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_attention, paged_attention_pool)
+    rng = np.random.default_rng(layer)
+    layers, hkv, nb, bs, d = 3, 2, 16, 16, 32
+    pool = jnp.asarray(rng.normal(size=(layers, 2, hkv, nb, bs, d)),
+                       jnp.float32)
+    scales = None
+    if pages == "fp8-scaled":
+        pool = pool.astype(jnp.float8_e4m3fn)
+        scales = jnp.asarray(rng.uniform(0.5, 2.0, size=(layers, 2, hkv, nb)),
+                             jnp.float32)
+    q = jnp.asarray(rng.normal(size=(3, 1, 8, d)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb - 1)[:12].reshape(3, 4), jnp.int32)
+    start = jnp.asarray([37, 5, 63], jnp.int32)
+    whole = paged_attention_pool(q, pool, layer, tables, start, window=24,
+                                 scales=scales, interpret=True)
+    ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
+        else (None, None)
+    sliced = paged_attention(q, pool[layer, 0], pool[layer, 1], tables, start,
+                             window=24, k_scales=ks, v_scales=vs,
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(sliced))
+
+
 def test_quantized_psum_scatter(mesh_dp8):
     """qgZ reduce-scatter building block: int8-wire sum matches psum_scatter
     within quantization error."""

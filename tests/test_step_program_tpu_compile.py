@@ -1,0 +1,111 @@
+"""The served step programs, compiled for a described v5e chip (no chip is
+attached and nothing runs): the KV pool is aliased in the executable and no
+operation of it copies, slices out or re-lays-out a pool-sized or
+page-set-sized buffer. Donation is only the permission; this is the proof.
+
+Mistral-7B widths with 2 of 32 layers and the chat cell's pool of 1216
+blocks, so that a compile takes seconds. What the parent of PR 28 compiled
+to at these shapes: two whole-pool ``copy`` operations (a change of layout
+around the K/V scatter), one sliced and re-laid-out page set for each
+kernel call, and temporaries larger than the pool.
+
+All in one file and the topology in a fixture: one process at a time may
+load the TPU's library (the ``on-chip-measurement`` guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.inference.v2 import generic_decode as gd
+from deepspeed_tpu.inference.v2.modules import policy_for
+from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from deepspeed_tpu.runtime.precision import cast_to_compute
+
+CFG = LlamaConfig(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+                  num_layers=2, num_heads=32, num_kv_heads=8,
+                  max_seq_len=32768, rope_theta=10000.0, sliding_window=4096)
+NUM_BLOCKS, BLOCK = 1216, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a chip that is not attached cannot be read back
+    # from the persistent cache: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # the matmul precision of the chip's runs, not the "highest" that
+    # conftest.py sets for the CPU (Mosaic refuses it on bfloat16 operands)
+    with jax.default_matmul_precision("default"):
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(one_chip, fn_name, pages):
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    # weights in the type they are served in, as a deployment holds them
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(LlamaForCausalLM(CFG).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            CFG.dtype), jax.random.PRNGKey(0)))
+    spec = policy_for(CFG).cache_spec(CFG)
+    dtype = jnp.float8_e4m3fn if pages == "fp8-scaled" else spec.dtype
+    pool = jax.ShapeDtypeStruct(
+        (spec.num_layers, 2, spec.num_kv_heads, NUM_BLOCKS, BLOCK,
+         spec.head_dim), dtype, sharding=one_chip)
+    cache = pool if pages == "plain" else (pool, jax.ShapeDtypeStruct(
+        pool.shape[:4], jnp.float32, sharding=one_chip))
+    if fn_name == "decode_step_g":
+        tail = (ints(16), ints(16), ints(16, 16), jax.ShapeDtypeStruct(
+            (16,), jnp.bool_, sharding=one_chip))
+    else:
+        tail = (ints(512), ints(), ints(16), ints())
+    return (params, cache) + tail, pool
+
+
+@pytest.mark.parametrize("pages", ["plain", "fp8-scaled"])
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     "verify_chunk_g"])
+def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
+                                                         pages):
+    args, pool = _shapes(one_chip, fn_name, pages)
+    compiled = getattr(gd, fn_name).lower(
+        *args, policy=policy_for(CFG), cfg=CFG, block_size=BLOCK,
+        attn_impl="kernel").compile()
+    text = compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    stats = compiled.memory_analysis()
+
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    # what the program holds beside its arguments: activations of a 512-token
+    # chunk, nowhere near a second pool (the parent: 1.25 pools)
+    assert stats.temp_size_in_bytes < pool_bytes // 4
+    assert "tpu_custom_call" in text and "paged_attention" in text
+
+    entry = text[text.index("\nENTRY"):]
+    whole = ",".join(str(d) for d in pool.shape)
+    page_set = ",".join(str(d) for d in pool.shape[2:])
+    moved = [line.strip()[:160] for line in entry.splitlines()
+             if re.search(r"= \(?\w+\[(1,1,)?(%s|%s)\]\S* (copy|fusion)\("
+                          % (whole, page_set), line)
+             and ("copy(" in line or "kind=kLoop" in line)]
+    assert moved == []
