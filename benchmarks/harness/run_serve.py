@@ -14,6 +14,7 @@ import math
 import queue
 import threading
 import time
+import typing
 from typing import List, Optional
 
 import numpy as np
@@ -43,15 +44,50 @@ class Record:
 
 # --- building the server ----------------------------------------------------
 
+def _dataclass_from(cls, group: dict, where: str):
+    """``cls`` with the fields ``group`` names: a field that is a dataclass
+    takes an object of its fields, a field declared a tuple takes a JSON
+    list, anything else the value as it is."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    if not set(group) <= fields:
+        raise cells.CellError(f"{where} has {sorted(set(group) - fields)}, "
+                              f"which {cls.__name__} lacks; it has "
+                              f"{sorted(fields)}")
+    hints = typing.get_type_hints(cls)
+    given = {}
+    for key, value in group.items():
+        hint = hints[key]
+        if dataclasses.is_dataclass(hint):
+            if not isinstance(value, dict):
+                raise cells.CellError(f"{where}.{key} is a group of "
+                                      f"{hint.__name__}'s fields, not {value!r}")
+            value = _dataclass_from(hint, value, f"{where}.{key}")
+        elif typing.get_origin(hint) is tuple:
+            if not isinstance(value, list):
+                raise cells.CellError(f"{where}.{key} is a list, not {value!r}")
+            value = tuple(value)
+        given[key] = value
+    return cls(**given)
+
+
+def engine_config(group: dict):
+    """A configuration file's ``serve.engine`` as the engine's whole
+    ``V2EngineConfig``: what the group leaves out stays at its default,
+    nested groups (``scheduler``, ``sampling``) become their dataclasses, and
+    a key the engine lacks is a ``CellError`` that names it."""
+    from deepspeed_tpu.inference.v2.engine_v2 import V2EngineConfig
+    return _dataclass_from(V2EngineConfig, group, "serve.engine")
+
+
 def build_server(cell: cells.Cell, bench: dict, seed: int):
     """(server, family module, program config): weights made on the device in
     one jitted call from the seed, in the type they are served in; every
     serving and engine option at its default except what the configuration
-    file's ``serve.engine`` group sets (the KV pool's size)."""
+    file's ``serve.engine`` group sets (the KV pool's size, and where a cell
+    needs them the bucket ladders and the scheduler's budgets)."""
     import jax
 
-    from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
-                                                      V2EngineConfig)
+    from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.runtime.precision import cast_to_compute
     from deepspeed_tpu.serving.server import InferenceServer, ServingConfig
 
@@ -64,22 +100,28 @@ def build_server(cell: cells.Cell, bench: dict, seed: int):
         model.init(key, example)["params"], dtype))(
             jax.random.PRNGKey(device.device_seed(seed)))
     engine = InferenceEngineV2(params, cfg,
-                               V2EngineConfig(**hf["serve"]["engine"]))
+                               engine_config(hf["serve"]["engine"]))
     return InferenceServer(engine, ServingConfig()), family, cfg
 
 
 # --- correctness ------------------------------------------------------------
 
 def _decode_alone_and_in_a_wave(engine, prompts, new_tokens):
-    """The engine's greedy tokens: for ``prompts[0]`` alone, then for every
-    prompt admitted together (other batch and chunk buckets)."""
+    """The engine's greedy tokens, ``new_tokens`` + 1 a prompt: for
+    ``prompts[0]`` alone, then for every prompt admitted together (other
+    batch and chunk buckets). Where the step's token budget is smaller than
+    the wave's prompts their first tokens come over several steps, and a
+    sequence that is ahead decodes on until the last has its tokens."""
     def run(uids, batch):
+        got = {u: [] for u in uids}
         out = engine.put(uids, batch)
-        got = {u: [out[u]] for u in uids}
-        for _ in range(new_tokens):
-            out = engine.step()
+        while True:
             for u in uids:
-                got[u].append(out[u])
+                if u in out and len(got[u]) <= new_tokens:
+                    got[u].append(out[u])
+            if all(len(tokens) > new_tokens for tokens in got.values()):
+                break
+            out = engine.step()
         for u in uids:
             engine.flush(u)
         return [got[u] for u in uids]

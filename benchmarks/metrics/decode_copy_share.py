@@ -1,6 +1,9 @@
 """Device time of ``copy`` operations inside the decode step programs over
-all device time of those programs. ``generic_decode``'s jits donate nothing,
-so XLA copies the whole KV pool inside every step; this is that cost."""
+all device time of those programs. Until PR 28 two pool-sized copies a step
+(changes of layout around the KV scatter) took 33-39% of it. Since then the
+step programs are given the pool to consume and update it in place, and this
+reads 0.04%: it stays as the guard that a copy of the pool has not come
+back."""
 
 from benchmarks.harness import readers
 

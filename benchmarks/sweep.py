@@ -9,8 +9,10 @@ ladder made no transition, the queue at the window's close was no longer than
 at its opening, and the generator's lateness p99 stayed under one engine
 step. R* is the highest sustained rate of the grid; the cell's offered rate
 (``cells/<cell>.json``, ``rate_rps``) is a stated share of it. Prints one
-line per rate and a last line with R*; it is a tool for the PR that defines
-or re-centres a cell, not part of a measured run.
+line per rate (with the run's ``latencies (ms)`` candidates, so that a rate
+given several times shows how each statistic spreads from seed to seed) and a
+last line with R*; it is a tool for the PR that defines or re-centres a cell,
+not part of a measured run.
 """
 
 import argparse
@@ -39,7 +41,7 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     sys.path.insert(0, str(ROOT))
-    from benchmarks.harness import cells, device, run_serve
+    from benchmarks.harness import cells, device, readers, run_serve
     bench = cells.load_benchmark(ROOT)
     cell = cells.find_cell(bench, args.workload, ROOT)
     devices = device.require_chips(cell.chips, "benchmarks/sweep.py")
@@ -81,7 +83,8 @@ def main(argv=None) -> int:
                 "queue_open_close": [c["queue_depth_at_open"],
                                      c["queue_depth_at_close"]],
                 "compiles": c["compiles_in_window"],
-                "drain_s": c["run_end"] - obs.window[1]}))
+                "drain_s": c["run_end"] - obs.window[1],
+                "latencies": readers.latency_summary(obs)}))
             if sustained:
                 best = rate if best is None else max(best, rate)
             time.sleep(2.0)

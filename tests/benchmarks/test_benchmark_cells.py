@@ -2,6 +2,7 @@
 readers and result line as ``benchmarks/run.py``, on cells that are dropped
 into a temporary copy of the benchmark as files and entries alone."""
 
+import json
 import os
 import subprocess
 import sys
@@ -9,6 +10,7 @@ import sys
 import pytest
 
 import benchmark_rehearsal as rehearsal
+import test_benchmark_contract as contract
 from benchmarks.harness import cells, run_serve
 
 REPO = rehearsal.REPO
@@ -24,7 +26,7 @@ def root(tmp_path_factory):
 def runs(root):
     """Every tiny cell once untraced and once traced."""
     out = {}
-    for name, _, _ in rehearsal.TINY_CELLS:
+    for name, *_ in rehearsal.TINY_CELLS:
         for traced in (False, True):
             lines = []
             obs, line = rehearsal.run_cell(root, name, 2.0, traced,
@@ -33,7 +35,7 @@ def runs(root):
     return out
 
 
-CASES = [(name, traced) for name, _, _ in rehearsal.TINY_CELLS
+CASES = [(name, traced) for name, *_ in rehearsal.TINY_CELLS
          for traced in (False, True)]
 
 
@@ -61,8 +63,9 @@ def test_tiny_cell_is_correct_and_compiles_nothing_in_the_window(runs, name,
 @pytest.mark.parametrize("name,expected", [
     ("tiny-train", {"train_tokens_per_s_per_chip", "setup_s"}),
     ("tiny-train-x4", {"train_tokens_per_s_per_chip", "setup_s"}),
-    ("tiny-chat", {"serve_tpot_p75_ms", "setup_s"}),
+    ("tiny-chat", {"serve_tpot_p50_ms", "setup_s"}),
     ("tiny-rag", {"serve_tokens_per_s", "setup_s"}),
+    ("tiny-moe-shared", {"serve_tokens_per_s", "setup_s"}),
 ])
 def test_untraced_run_reports_the_cells_end_to_end_metrics(runs, name, expected):
     _, line, _ = runs[name, False]
@@ -72,11 +75,13 @@ def test_untraced_run_reports_the_cells_end_to_end_metrics(runs, name, expected)
 
 @pytest.mark.parametrize("name,expected", [
     ("tiny-chat", {"serve_ttft_upper_quartile_ms", "serve_ttft_p90_ms",
-                   "serve_tpot_request_p90_ms",
+                   "serve_tpot_request_p90_ms", "serve_tpot_upper_quartile_ms",
                    "serve_tpot_mean_ms", "loadgen_late_p99_ms",
                    "serve_queue_wait_p50_ms", "engine_step_p50_ms",
                    "decode_batch_mean"}),
     ("tiny-rag", {"prefill_tokens_per_tick"}),
+    ("tiny-moe-shared", {"prefill_tokens_per_tick",
+                         "prefill_chunks_per_tick"}),
 ])
 def test_traced_run_reports_host_side_per_layer_metrics(runs, name, expected):
     """Device-trace metrics need a TPU plane; their readers find nothing on
@@ -151,6 +156,129 @@ def test_warm_up_enumerates_the_reachable_programs(root):
     assert (128, 64) in prefill
 
 
+SERVED_CONFIGS = ["mistral-7b-serve-d8", "mixtral-8x7b-serve-d3"]
+
+
+@pytest.mark.parametrize("config", SERVED_CONFIGS)
+def test_engine_group_of_a_served_cell_builds_what_the_flat_keys_built(config):
+    """``{"kv_num_blocks": n}`` gives the engine configuration that
+    ``V2EngineConfig(**group)`` gave before the group reached nested fields."""
+    from deepspeed_tpu.inference.v2.engine_v2 import V2EngineConfig
+    data = json.loads((REPO / "benchmarks" / "configs" /
+                       f"{config}.json").read_text())
+    group = data["serve"]["engine"]
+    assert set(group) == {"kv_num_blocks"}
+    assert run_serve.engine_config(group) == V2EngineConfig(**group)
+
+
+def test_engine_group_reaches_nested_groups_and_ladders():
+    from deepspeed_tpu.inference.v2.engine_v2 import V2EngineConfig
+    from deepspeed_tpu.inference.v2.sampling import SamplingConfig
+    from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig
+    ecfg = run_serve.engine_config({
+        "kv_num_blocks": 2048, "ctx_block_buckets": [4, 8, 16, 32, 64, 80],
+        "scheduler": {"max_tokens_per_step": 4096,
+                      "prefill_buckets": [256, 1024, 4096]},
+        "sampling": {"temperature": 0.7, "top_k": 40}})
+    assert ecfg == V2EngineConfig(
+        kv_num_blocks=2048, ctx_block_buckets=(4, 8, 16, 32, 64, 80),
+        scheduler=SchedulerConfig(max_tokens_per_step=4096,
+                                  prefill_buckets=(256, 1024, 4096)),
+        sampling=SamplingConfig(temperature=0.7, top_k=40))
+    # the cell ISSUE 29 sized: contexts to 5,120 tokens = 80 blocks
+    mix = {"prompt_tokens": {"min": 1024, "max": 4096},
+           "output_tokens": {"min": 256, "max": 1024}, "max_concurrency": 64}
+    prefill, decode = run_serve.reachable_shapes(ecfg, mix)
+    assert {b for b, _ in prefill} == {256, 1024, 4096}
+    assert max(m for _, m in prefill) == 64           # 4,096 tokens
+    assert {m for _, m in decode} == {32, 64, 80}     # 1,025 .. 5,120 tokens
+    assert max(d for d, _ in decode) == 64
+
+
+@pytest.mark.parametrize("group,names", [
+    ({"kv_blocks": 8}, "kv_blocks"),
+    ({"scheduler": {"chunk_budget": 8}}, "chunk_budget"),
+    ({"scheduler": 2048}, "serve.engine.scheduler"),
+    ({"ctx_block_buckets": 64}, "serve.engine.ctx_block_buckets"),
+])
+def test_engine_group_refuses_what_the_engine_lacks(group, names):
+    with pytest.raises(cells.CellError, match=names):
+        run_serve.engine_config(group)
+
+
+def test_third_family_arrives_as_files_and_entries_alone(root):
+    """``model_type: qwen2_moe``: a configuration that cuts depth, experts
+    and vocabulary, its family and reference files, a cell and a per-layer
+    metric, with no edit to a file that was there; the contract's rule reads
+    the cut from the file."""
+    assert rehearsal.files_that_differ(root) == []
+    bench = cells.load_benchmark(root)
+    entry, = [c for c in bench["configs"] if c["name"] == "tiny-qwen2-moe"]
+    data = json.loads((root / entry["file"]).read_text())
+    assert data["model_type"] == "qwen2_moe"
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts",
+                                "vocab_size"]
+    assert contract.configuration_faults(entry, data) == []
+    assert (data["num_experts"], data["num_hidden_layers"],
+            data["vocab_size"] * 8) == (8, 4, data["published"]["vocab_size"])
+    for folder in ("families", "reference"):
+        assert not (REPO / "benchmarks" / folder / "qwen2_moe.py").exists()
+        assert cells.load_module(root, bench, folder, "qwen2_moe") is not None
+    repo_bench = cells.load_benchmark(REPO)
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        was = repo_bench[key]
+        now = [dict(e, workloads=[w for w in e["workloads"]
+                                  if not w.startswith("tiny-")])
+               if "workloads" in e else e for e in bench[key][:len(was)]]
+        assert now == was                   # entries appended, none edited
+
+
+def test_warm_up_follows_the_ladders_the_third_familys_file_gave(root, runs):
+    """``serve.engine`` sets the scheduler's budget and both ladders; the
+    engine holds them as its dataclasses and tuples, and the warm-up
+    enumerates them and nothing of the defaults'."""
+    bench = cells.load_benchmark(root)
+    cell = cells.find_cell(bench, "tiny-moe-shared", root)
+    group = cell.config["serve"]["engine"]
+    ecfg = run_serve.engine_config(group)
+    assert ecfg.scheduler.max_tokens_per_step == 160
+    assert ecfg.scheduler.prefill_buckets == (32, 96, 160)
+    assert ecfg.ctx_block_buckets == (4, 8, 24)
+    prefill, decode = run_serve.reachable_shapes(ecfg, cell.traffic)
+    assert {b for b, _ in prefill} == set(group["scheduler"]["prefill_buckets"])
+    assert {m for _, m in prefill + decode} <= set(group["ctx_block_buckets"])
+    assert (160, 24) in prefill and (4, 24) in decode
+    _, _, lines = runs["tiny-moe-shared", False]
+    assert any(f"warmed {len(prefill)} prefill and {len(decode)} decode"
+               in text for text in lines)
+
+
+@pytest.mark.parametrize("shared_expert,agrees", [(True, True), (False, False)])
+def test_reference_check_fails_without_the_shared_expert(root, shared_expert,
+                                                         agrees):
+    """The third family's reference check has the power it is there for: a
+    reference that leaves the shared expert out differs from the engine by
+    more than the tolerance."""
+    import types
+    bench = cells.load_benchmark(root)
+    cell = cells.find_cell(bench, "tiny-moe-shared", root)
+    server, family, _ = run_serve.build_server(cell, bench, 3)
+    plain = cells.load_module(root, bench, "reference", "qwen2_moe")
+
+    def weights(params):
+        view = family.reference_weights(params)
+        if not shared_expert:
+            for layer in view["layers"]:
+                layer["shared"] = dict(layer["shared"],
+                                       down=0.0 * layer["shared"]["down"])
+        return view
+    said = []
+    ok = run_serve.check_against_reference(
+        server.engine, types.SimpleNamespace(reference_weights=weights),
+        plain, cell.config, 3, said.append)
+    assert ok is agrees, said[-1]
+
+
 @pytest.mark.parametrize("noise,agrees", [(0.0, True), (1.0, False)])
 def test_reference_check_holds_every_wave_sequence_to_the_tolerance(
         root, noise, agrees):
@@ -191,7 +319,7 @@ def test_a_dropped_in_metric_file_is_found_and_read(root, runs):
     bench["per_layer"].append({
         "name": "tokens_streamed.new", "unit": "tokens", "better": "higher",
         "source": "program_counter", "layer": "benchmark load generator",
-        "moves": "serve_tpot_p75_ms", "workloads": ["tiny-chat"]})
+        "moves": "serve_tpot_p50_ms", "workloads": ["tiny-chat"]})
     cell = cells.find_cell(bench, "tiny-chat", root)
     obs, _, _ = runs["tiny-chat", True]
     got = result.read_metrics(cell, bench, obs, True, lambda text: None)
@@ -211,7 +339,7 @@ def test_a_metric_without_a_reader_or_a_reading_is_left_out(root, runs):
         bench["per_layer"].append({
             "name": name, "unit": "ms", "better": "lower",
             "source": "program_span", "layer": "engine tick",
-            "moves": "serve_tpot_p75_ms", "workloads": ["tiny-chat"]})
+            "moves": "serve_tpot_p50_ms", "workloads": ["tiny-chat"]})
     cell = cells.find_cell(bench, "tiny-chat", root)
     said = []
     got = result.read_metrics(cell, bench, runs["tiny-chat", True][0], True,

@@ -14,7 +14,6 @@ BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH_SUFFIXES = ("_size", "_dim", "_rank", "_per_tok")
 
 
 def test_top_level_keys_and_size():
@@ -32,24 +31,209 @@ def test_run_seconds_fits_the_full_check_with_24_cells():
     assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
 
 
+# --- the rule for a configuration -------------------------------------------
+# What may be cut from the source (model-configs guide, section 4): how many
+# layers, and the chip's share of a stated deployment: how many routed
+# experts, heads or rows of the vocabulary are held here. Never a width.
+COUNT_SUFFIXES = ("_layers", "_experts", "_heads")
+WIDTH_SUFFIXES = ("_size", "_dim", "_rank", "_per_tok", "_width")
+DEPTH_KEYS = ("num_hidden_layers", "num_layers", "n_layers")
+LEADING_DENSE_KEYS = ("first_k_dense_replace", "num_dense_layers",
+                      "n_dense_first_layers")
+MIN_EXPERTS_HELD, MIN_VOCAB_SHARE, MIN_LAYERS_AFTER_DENSE = 8, 8, 4
+
+#: The sources whose widths this test can hold to numbers. For any other
+#: source it compares nothing with numbers it cannot have: that the widths
+#: are the source's is then the reviewer's to check against the catalog.
+MISTRAL_WIDTHS = {"hidden_size": 4096, "intermediate_size": 14336,
+                  "num_attention_heads": 32, "num_key_value_heads": 8,
+                  "vocab_size": 32000}
+PUBLISHED_WIDTHS = {
+    "https://huggingface.co/mistralai/Mistral-7B-v0.1/blob/main/config.json":
+        MISTRAL_WIDTHS,
+    "https://huggingface.co/mistralai/Mixtral-8x7B-v0.1/blob/main/config.json":
+        MISTRAL_WIDTHS,
+}
+
+#: The three configurations of PR 23 predate the floors (2, 8 and 3 layers:
+#: as deep as their weights, optimizer state and KV pool leave room for on
+#: one chip); their cells' bounds stand on those depths, so they stay.
+PREDATE_THE_FLOORS = {"mistral-7b-train-d2", "mistral-7b-serve-d8",
+                      "mixtral-8x7b-serve-d3"}
+
+
+def is_width(key: str) -> bool:
+    return key.endswith(WIDTH_SUFFIXES) and key != "vocab_size"
+
+
+def may_be_cut(key: str) -> bool:
+    return key.endswith(COUNT_SUFFIXES) or key == "vocab_size"
+
+
+def configuration_faults(entry: dict, data: dict, floors: bool = True) -> list:
+    """The ways in which a ``configs`` entry and its file's data break the
+    rule, as sentences; empty where they keep it."""
+    faults = []
+    if set(entry) != {"name", "source", "file", "reduced", "why"}:
+        faults.append(f"entry keys: {sorted(entry)}")
+        return faults
+    reduced = entry["reduced"]
+    if not NAME.match(entry["name"]):
+        faults.append(f"name: {entry['name']!r}")
+    if len(reduced) > 16 or not all(NAME.match(k) for k in reduced):
+        faults.append("reduced: over 16 keys, or a key that is no name")
+    if data.get("source") != entry["source"]:
+        faults.append("source: the file's differs from the entry's")
+    if data.get("reduced") != reduced:
+        faults.append("reduced: the file's differs from the entry's")
+    if "deployment" not in data:
+        faults.append("deployment: the file does not say what it stands for")
+    published = data.get("published")
+    if not isinstance(published, dict):
+        faults.append("published: missing; the file states the source's "
+                      "number for every key of reduced")
+        published = {}
+    elif set(published) != set(reduced):
+        faults.append(f"published: keys {sorted(published)} are not exactly "
+                      f"reduced's {sorted(reduced)}")
+    for key in sorted(set(reduced) | set(published)):
+        if is_width(key):
+            faults.append(f"width: {key} is a width, and no width is cut")
+        elif not may_be_cut(key):
+            faults.append(f"outside the list: {key} counts neither layers, "
+                          f"experts, heads nor the vocabulary")
+    for key in reduced:
+        here, there = data.get(key), published.get(key)
+        if key in published and not (
+                isinstance(here, int) and isinstance(there, int)
+                and 0 < here < there):
+            faults.append(f"not smaller: {key} is {here!r} here and "
+                          f"{there!r} published")
+    depth_key = next((k for k in DEPTH_KEYS if k in data), None)
+    if "layer_types" in data and depth_key is not None and \
+            len(data["layer_types"]) != data[depth_key]:
+        faults.append(f"layer_types: {len(data['layer_types'])} entries for "
+                      f"{data[depth_key]} layers")
+    share = [k for k in reduced if not k.endswith("_layers")]
+    if share and not (isinstance(data.get("deployment_chips"), int)
+                      and data["deployment_chips"] >= 2):
+        faults.append(f"deployment_chips: {share} are a chip's share, and the "
+                      f"file does not say of how many chips")
+    if faults or not floors:
+        return faults       # the floors judge a cut that is otherwise sound
+    for key in reduced:
+        if key.endswith("_experts") and data[key] < MIN_EXPERTS_HELD:
+            faults.append(f"floor: {data[key]} experts held ({key}), under "
+                          f"{MIN_EXPERTS_HELD}")
+    if "vocab_size" in reduced and \
+            data["vocab_size"] * MIN_VOCAB_SHARE < published["vocab_size"]:
+        faults.append(f"floor: {data['vocab_size']} rows are under an "
+                      f"eighth of the vocabulary's {published['vocab_size']}")
+    if depth_key in reduced:
+        dense = next((data[k] for k in LEADING_DENSE_KEYS if k in data), 0)
+        if data[depth_key] - dense < MIN_LAYERS_AFTER_DENSE:
+            faults.append(f"floor: {data[depth_key] - dense} layers after "
+                          f"{dense} leading dense, under "
+                          f"{MIN_LAYERS_AFTER_DENSE}")
+    return faults
+
+
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_entry_and_file(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert NAME.match(config["name"])
     assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
-    assert len(config["reduced"]) <= 16
-    for key in config["reduced"]:
-        assert NAME.match(key)
-        assert not key.endswith(WIDTH_SUFFIXES)     # depth only, no width
     data = json.loads((REPO / config["file"]).read_text())
-    assert data["source"] == config["source"]
-    assert data["reduced"] == config["reduced"]
-    # widths as published (Mistral-7B / Mixtral-8x7B config.json)
-    assert (data["hidden_size"], data["intermediate_size"],
-            data["num_attention_heads"], data["num_key_value_heads"],
-            data["vocab_size"]) == (4096, 14336, 32, 8, 32000)
-    assert data["num_hidden_layers"] < 32 and "deployment" in data
+    assert configuration_faults(
+        config, data, floors=config["name"] not in PREDATE_THE_FLOORS) == []
+    # widths as published, where the table has the source's numbers
+    for key, value in PUBLISHED_WIDTHS.get(config["source"], {}).items():
+        assert key in config["reduced"] or data[key] == value, key
     assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+
+
+def made_up(**changes):
+    """(entry, file's data) of a share cut that keeps every floor: one chip
+    of eight that share each layer of a model with a leading dense layer, 64
+    routed experts and 102,400 rows. ``changes`` are laid over the data
+    (None takes a key out); ``reduced`` and ``source`` go to the entry too."""
+    data = {"source": "https://example.org/made-up/config.json",
+            "reduced": ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+            "published": {"num_hidden_layers": 60, "n_routed_experts": 64,
+                          "vocab_size": 102400},
+            "deployment": "one chip of eight that share each layer",
+            "deployment_chips": 8, "hidden_size": 5120, "kv_lora_rank": 512,
+            "moe_intermediate_size": 1536, "num_attention_heads": 128,
+            "num_experts_per_tok": 6, "first_k_dense_replace": 1,
+            "num_hidden_layers": 5, "n_routed_experts": 8,
+            "vocab_size": 12800}
+    data.update(changes)
+    data = {k: v for k, v in data.items() if v is not None}
+    entry = {"name": "made-up", "file": "benchmarks/configs/made-up.json",
+             "why": "a case of the rule", "source": data["source"],
+             "reduced": data["reduced"]}
+    return entry, data
+
+
+def reduce_also(key, here, there):
+    """Changes that cut one more key, from ``there`` to ``here``."""
+    _, data = made_up()
+    return {"reduced": data["reduced"] + [key], key: here,
+            "published": dict(data["published"], **{key: there})}
+
+
+MADE_UP_CASES = [
+    # changes to the sound case, the one fault it must show or None
+    pytest.param({}, None, id="share-cut-keeping-the-floors"),
+    pytest.param({"reduced": ["num_hidden_layers"], "deployment_chips": None,
+                  "published": {"num_hidden_layers": 60}}, None,
+                 id="depth-alone-needs-no-deployment-chips"),
+    pytest.param(reduce_also("num_attention_heads", 32, 128), None,
+                 id="heads-may-be-a-share"),
+    pytest.param(reduce_also("kv_lora_rank", 256, 512), "width:",
+                 id="width-in-reduced-rank"),
+    pytest.param(reduce_also("moe_intermediate_size", 768, 1536), "width:",
+                 id="width-in-reduced-size"),
+    pytest.param(reduce_also("num_experts_per_tok", 2, 6), "width:",
+                 id="width-in-reduced-per-tok"),
+    pytest.param({"published": None}, "published: missing",
+                 id="published-missing"),
+    pytest.param({"published": {"num_hidden_layers": 60,
+                                "n_routed_experts": 64}}, "published: keys",
+                 id="published-lacks-a-key"),
+    pytest.param({"published": {"num_hidden_layers": 60, "n_routed_experts": 8,
+                                "vocab_size": 102400}}, "not smaller:",
+                 id="published-not-larger"),
+    pytest.param({"n_routed_experts": 4}, "floor: 4 experts",
+                 id="four-experts-held"),
+    pytest.param({"vocab_size": 10240}, "floor: 10240 rows",
+                 id="a-tenth-of-the-vocabulary"),
+    pytest.param({"num_hidden_layers": 4},
+                 "floor: 3 layers after 1 leading dense",
+                 id="three-layers-after-one-dense"),
+    pytest.param(reduce_also("max_position_embeddings", 4096, 163840),
+                 "outside the list:", id="key-outside-the-list"),
+    pytest.param({"deployment_chips": None}, "deployment_chips:",
+                 id="share-without-deployment-chips"),
+    pytest.param({"layer_types": ["full_attention"] * 4}, "layer_types:",
+                 id="layer-types-of-another-depth"),
+]
+
+
+@pytest.mark.parametrize("changes,fault", MADE_UP_CASES)
+def test_rule_on_made_up_configurations(changes, fault):
+    """Each made-up case passes, or fails by exactly the fault it names."""
+    faults = configuration_faults(*made_up(**changes))
+    if fault is None:
+        assert faults == []
+    else:
+        assert len(faults) == 1 and faults[0].startswith(fault), faults
+
+
+def test_rule_holds_the_file_to_its_entry():
+    entry, data = made_up()
+    faults = configuration_faults(dict(entry, source="https://example.org/x",
+                                       reduced=["num_hidden_layers"]), data)
+    assert [f.split(":")[0] for f in faults] == ["source", "reduced",
+                                                 "published"]
 
 
 def test_configuration_files_are_distinct():

@@ -48,9 +48,12 @@ def test_token_gaps_are_all_gaps_of_the_measured_requests(obs):
     gaps = readers.token_gaps_s(obs)
     assert sorted(np.round(gaps, 6)) == [0.05] * 4 + [0.1] * 3 + [0.2, 0.4]
     assert metric("serve_tpot_mean_ms")(obs) == pytest.approx(1100 / 9)
-    assert metric("serve_tpot_p75_ms")(obs) == pytest.approx(
+    assert metric("serve_tpot_upper_quartile_ms")(obs) == pytest.approx(
         np.percentile(gaps, 75) * 1e3)
-    assert metric("serve_tpot_p75_ms")(obs) == pytest.approx(100.0)
+    assert metric("serve_tpot_upper_quartile_ms")(obs) == pytest.approx(100.0)
+    assert metric("serve_tpot_p50_ms")(obs) == pytest.approx(
+        np.percentile(gaps, 50) * 1e3)
+    assert metric("serve_tpot_p50_ms")(obs) == pytest.approx(100.0)
 
 
 def test_per_request_statistics(obs):
@@ -77,8 +80,10 @@ def test_a_failed_request_enters_every_statistic_at_the_largest_value(
     assert readers.tpot_s(obs)[-1] == pytest.approx(17.0)
     gaps = readers.token_gaps_s(obs)
     assert len(gaps) == 9 + 3 and np.sum(np.isclose(gaps, 17.0)) == 3
-    assert metric("serve_tpot_p75_ms")(obs) > 4_000
+    assert metric("serve_tpot_upper_quartile_ms")(obs) > 4_000
     assert metric("serve_ttft_upper_quartile_ms")(obs) > 4_000
+    # three of twelve gaps at the largest value move the p75, not the median
+    assert metric("serve_tpot_p50_ms")(obs) == pytest.approx(100.0)
 
 
 def test_requests_due_in_the_profilers_stall_are_left_out(obs):
@@ -98,7 +103,8 @@ def test_summary_line_holds_every_candidate_statistic(obs):
 def test_nothing_to_read_gives_nothing():
     empty = Observations(kind="serve", cell=None, devices=[])
     empty.counters = {"run_end": 1.0}
-    for name in ("serve_tpot_p75_ms", "serve_tpot_mean_ms",
+    for name in ("serve_tpot_p50_ms", "serve_tpot_upper_quartile_ms",
+                 "serve_tpot_mean_ms",
                  "serve_ttft_upper_quartile_ms", "serve_ttft_p90_ms",
                  "serve_tpot_request_p90_ms"):
         assert metric(name)(empty) is None
