@@ -39,6 +39,7 @@ from deepspeed_tpu.inference.v2.scheduler import (
 )
 from deepspeed_tpu.models.llama import LlamaConfig
 from deepspeed_tpu.runtime.sched import TickLedger
+from deepspeed_tpu.telemetry.names import STEP_COUNTER_ARGS
 from deepspeed_tpu.telemetry.tracer import get_tracer
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -89,8 +90,9 @@ class V2EngineConfig:
 
 class InferenceEngineV2:
     """Serves any registered arch (llama family incl. mistral/qwen2/phi3,
-    falcon, opt, mixtral) — the policy registry picks the decode implementation
-    from the model config type (reference: engine_factory + heuristics)."""
+    falcon, opt, mixtral, joyai_llm_flash over its latent cache, ...) — the
+    policy registry picks the decode implementation from the model config
+    type (reference: engine_factory + heuristics)."""
 
     def _page_dtype(self, spec):
         kinds = {"model": spec.dtype, "fp8": jnp.float8_e4m3fn}
@@ -120,7 +122,8 @@ class InferenceEngineV2:
             head_dim=spec.head_dim,
             block_size=self.config.kv_block_size,
             num_blocks=self.config.kv_num_blocks,
-            dtype=self._page_dtype(spec)))
+            dtype=self._page_dtype(spec),
+            latent_dim=spec.latent_dim))
         self.state = StateManager(
             max_tracked_sequences=self.config.max_tracked_sequences,
             max_context_length=spec.max_seq_len)
@@ -165,6 +168,10 @@ class InferenceEngineV2:
         # step; a serving loop overwrites it with its own tick's number
         # before each step so that its spans and the engine's share it
         self.tick = 0
+        # counts the step programs handed out beside the logits (device
+        # vectors, where the policy counts), kept while tracing until the
+        # next wait for a sampled token reads them with it
+        self._pending_counts: List[jax.Array] = []
         self._window = spec.window     # sliding window in tokens, or None
         # speculative-decoding counters (speculative_stats)
         self._spec_steps = 0
@@ -342,7 +349,8 @@ class InferenceEngineV2:
             # bookkeeping after it
             with tracer.span("serve/prefill_chunk", cat="serve", tick=tick,
                              uid=seq.uid, tokens=chunk.length,
-                             bucket=chunk.bucket, start=chunk.start):
+                             bucket=chunk.bucket,
+                             start=chunk.start) as chunk_span:
                 self._ensure_blocks(seq, end)
                 tokens = np.zeros((chunk.bucket,), np.int32)
                 tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
@@ -352,13 +360,14 @@ class InferenceEngineV2:
                 # comes back is bound at once, so that a fault later in the
                 # tick (and the server's next step after it) finds the
                 # engine on a live pool
-                logits, self.kv.pool = prefill_chunk_g(
+                logits, self.kv.pool, counts = prefill_chunk_g(
                     self.params, self.kv.pool, jnp.asarray(tokens),
                     chunk.start,
                     jnp.asarray(table), chunk.length,
                     policy=self.policy, cfg=self.model_config,
                     block_size=self.kv.cfg.block_size,
                     attn_impl=self.config.attn_impl)
+                self._keep_counts(counts)
                 seq.seen_tokens = end
                 self._prefill_computed += chunk.length
                 if self.prefix_cache is not None:
@@ -373,7 +382,9 @@ class InferenceEngineV2:
                     sampled = self._sample_dispatch(logits[None])
                     with tracer.span("serve/decode_wait", cat="serve",
                                      tick=tick):
-                        tok = int(np.asarray(sampled)[0])
+                        toks, counts = self._read_with_counts(sampled)
+                        tok = int(toks[0])
+                    chunk_span.note(**counts)
                     seq.generated.append(tok)
                     out[seq.uid] = tok
         if plan.prefill_chunks:
@@ -413,18 +424,19 @@ class InferenceEngineV2:
                     self._table_sig = sig
                 build.note(tables_rebuilt=rebuilt)
             with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
-                logits, self.kv.pool = decode_step_g(
+                logits, self.kv.pool, counts = decode_step_g(
                     self.params, self.kv.pool, jnp.asarray(tokens),
                     jnp.asarray(positions), self._dev_tables,
                     jnp.asarray(valid),
                     policy=self.policy, cfg=self.model_config,
                     block_size=self.kv.cfg.block_size,
                     attn_impl=self.config.attn_impl)
+                self._keep_counts(counts)
                 # sample on device; only [B] token ids cross to the host —
                 # the [B, vocab] logits D2H fetch would dominate the loop
                 sampled = self._sample_dispatch(logits)
             with tracer.span("serve/decode_wait", cat="serve", tick=tick):
-                toks = np.asarray(sampled)
+                toks, counts = self._read_with_counts(sampled)
             with tracer.span("serve/decode_commit", cat="serve", tick=tick):
                 for j, seq in enumerate(seqs):
                     tok = int(toks[j])
@@ -446,7 +458,7 @@ class InferenceEngineV2:
                     end_ts=t0 + t_decode, tick=tick,
                     batch=len(seqs), bucket=b, ctx_tokens=whole,
                     ctx_tokens_windowed=sum(min(c, window) for c in contexts)
-                    if window else whole, ctx_blocks=mb)
+                    if window else whole, ctx_blocks=mb, **counts)
 
         with tracer.span("serve/step_finish", cat="serve", tick=tick):
             self.tick = tick + 1
@@ -465,6 +477,27 @@ class InferenceEngineV2:
                                                len(plan.prefill_chunks),
                                                decode_tokens, cap=cap)
         return out
+
+    def _keep_counts(self, counts) -> None:
+        """Keep what a step program counted (nothing where its policy counts
+        nothing) while a tracer is there to read it."""
+        if counts.size and get_tracer().enabled:
+            self._pending_counts.append(counts)
+
+    def _read_with_counts(self, sampled):
+        """(the sampled tokens on the host, the kept counts summed under
+        their ``STEP_COUNTER_ARGS`` names or {}): one wait for both. The
+        counts are results of step programs that ran before the sampling,
+        so once the tokens are here they are too; a chunk that ended no
+        prompt was not waited for, and its counts are read here, with the
+        next token that is."""
+        if not self._pending_counts:
+            return np.asarray(sampled), {}
+        kept, self._pending_counts = self._pending_counts, []
+        # dslint: disable=DS002 -- this IS the step's one wait (serve/decode_wait): the sampled tokens' readback, with vectors that were ready before them
+        toks, *counts = jax.device_get([sampled] + kept)
+        return toks, dict(zip(STEP_COUNTER_ARGS,
+                              (int(v) for v in np.sum(counts, axis=0))))
 
     def _sample_dispatch(self, logits):
         """[B, V] device logits -> [B] device token ids: dispatch only. The
@@ -695,7 +728,7 @@ class InferenceEngineV2:
                 scales = z[f"scales_{i}"] if f"scales_{i}" in z.files else None
                 qscales = (z[f"qscales_{i}"] if f"qscales_{i}" in z.files
                            else None)
-                nb = int(stored.shape[3])
+                nb = int(stored.shape[self.kv.block_axis])
                 if (cache is None or bs != self.config.kv_block_size
                         or nb > self.kv.free_blocks):
                     out["skipped"] += 1
@@ -934,7 +967,7 @@ class InferenceEngineV2:
         tokens = np.zeros((bucket,), np.int32)
         tokens[0] = last
         tokens[1:true_len] = proposed
-        logits, self.kv.pool = verify_chunk_g(
+        logits, self.kv.pool, _ = verify_chunk_g(
             self.params, self.kv.pool, jnp.asarray(tokens), ctx - 1,
             jnp.asarray(self._block_table(seq, mb)), true_len,
             policy=self.policy, cfg=self.model_config,

@@ -11,6 +11,17 @@ device trace (an operation's ``tf_op``): ``embed``, ``attn/kv_write``,
 ``attn/paged`` and ``lm_head`` here, ``attn/qkv``, ``attn/out``, ``mlp``,
 ``moe/router`` and ``moe/experts`` in the policies. Metadata only: the
 compiled program is the same.
+
+Over a latent cache (``KVCacheSpec.latent_dim``: one row a token, no heads)
+the block hands ``attend`` its queries in two parts, the token's row and the
+key-value up-projection, and the loop writes the row (``attn/latent_write``)
+and reads it the way its program needs: a chunk attends unfolded over keys
+and values up-projected from the gathered rows (``attn/latent_prefill``), a
+decode batch folded over the pages themselves (``attn/latent_q``,
+``attn/latent_paged``). A block returns ``(x, counts or None)``; the three
+step programs return ``(logits, cache, counts)``, the counts one int32 vector
+of ``telemetry/names.py`` ``STEP_COUNTER_ARGS`` summed over the layers that
+count (sums over what a router has anyway), and empty where none does.
 """
 
 from functools import partial
@@ -18,17 +29,34 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.kv_cache import write_kv, write_kv_scaled
-from deepspeed_tpu.inference.v2.llama_decode import _paged_attn
+from deepspeed_tpu.inference.v2.kv_cache import (write_kv, write_kv_scaled,
+                                                write_latent)
+from deepspeed_tpu.inference.v2.llama_decode import (_latent_paged_attn,
+                                                     _latent_prefill_attn,
+                                                     _paged_attn)
+
+
+def _trash_block(pool, spec):
+    """The pool's last block, where padding rows are written."""
+    return pool.shape[1 if spec.latent_dim else 3] - 1
+
+
+def _summed(counted):
+    """The layers' counts summed: [len(STEP_COUNTER_ARGS)] int32, or [0]
+    where no layer counted."""
+    if not counted:
+        return jnp.zeros((0,), jnp.int32)
+    return sum(counted[1:], counted[0])
 
 
 def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
                   policy, cfg, block_size: int, attn_impl: str):
     """Shared chunk forward: embeds a bucket-padded token chunk, scatters
     each layer's K/V into the pages, attends over the paged context, and
-    returns (per-row hidden states [Tb, D], updated cache). ``cache_data``
-    may be the plain page pool [L, 2, H, NB, bs, D] or a ``(pages, scales)``
-    tuple for scaled fp8 pages (``BlockedKVCache.scales``)."""
+    returns (per-row hidden states [Tb, D], updated cache, the counts handed
+    out). ``cache_data`` may be the plain page pool [L, 2, H, NB, bs, D], a
+    ``(pages, scales)`` tuple for scaled fp8 pages
+    (``BlockedKVCache.scales``), or a latent pool [L, NB, bs, W]."""
     spec = policy.cache_spec(cfg)
     tb = tokens.shape[0]
     mb = block_table.shape[0]
@@ -37,9 +65,10 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
 
     positions = start + jnp.arange(tb)
     safe_pos = jnp.minimum(positions, spec.max_seq_len - 1)
-    tok_block = jnp.where(jnp.arange(tb) < true_len,
+    valid = jnp.arange(tb) < true_len
+    tok_block = jnp.where(valid,
                           block_table[jnp.minimum(safe_pos // block_size, mb - 1)],
-                          pool.shape[3] - 1)
+                          _trash_block(pool, spec))
     tok_off = safe_pos % block_size
     touched = None
     if scaled:
@@ -58,7 +87,17 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
         x = policy.embed(params, tokens, safe_pos, cfg)
 
     cache = cache_data
+    counted = []
     for i in range(spec.num_layers):
+        def attend_latent(q_nope, q_rope, row, w_ukv, scale, i=i):
+            nonlocal cache
+            with jax.named_scope("attn/latent_write"):
+                cache = write_latent(cache, i, row, tok_block, tok_off)
+            with jax.named_scope("attn/latent_prefill"):
+                return _latent_prefill_attn(q_nope, q_rope, cache, i,
+                                            block_table, start, w_ukv, scale,
+                                            attn_impl)
+
         def attend(q, k, v, i=i, window="spec", softcap=None):
             nonlocal cache
             win = spec.window if window == "spec" else window
@@ -81,8 +120,12 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
                 return _paged_attn(q[None], cache, i, block_table[None],
                                    jnp.asarray(start).reshape(1), win,
                                    attn_impl, softcap=softcap)[0]
-        x = policy.block(params, i, x, attend, safe_pos, cfg)
-    return x, cache
+        x, counts = policy.block(
+            params, i, x, attend_latent if spec.latent_dim else attend,
+            safe_pos, cfg, valid)
+        if counts is not None:
+            counted.append(counts)
+    return x, cache, _summed(counted)
 
 
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
@@ -90,15 +133,16 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
 def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
                     policy, cfg, block_size: int, attn_impl: str = "auto"):
     """One sequence, one bucket-padded chunk; returns (last-token logits [V],
-    updated cache_data). See llama_decode.prefill_chunk for the argument
+    updated cache_data, counts: module docstring). See llama_decode.prefill_chunk for the argument
     contract — this is the arch-generic version; cache structure in ==
     structure out (plain pool or (pages, scales))."""
-    x, cache = _chunk_states(params, cache_data, tokens, start, block_table,
-                             true_len, policy, cfg, block_size, attn_impl)
+    x, cache, counts = _chunk_states(params, cache_data, tokens, start,
+                                     block_table, true_len, policy, cfg,
+                                     block_size, attn_impl)
     last = x[jnp.maximum(true_len - 1, 0)]
     with jax.named_scope("lm_head"):
         logits = policy.unembed(params, last[None], cfg)[0]
-    return logits, cache
+    return logits, cache, counts
 
 
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
@@ -113,10 +157,11 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
     FastGen has no speculative decoding). Rejected rows' K/V writes land at
     positions beyond the accepted context and are invisible (causal masking
     doubles as the context-length mask) until a later step overwrites them."""
-    x, cache = _chunk_states(params, cache_data, tokens, start, block_table,
-                             true_len, policy, cfg, block_size, attn_impl)
+    x, cache, counts = _chunk_states(params, cache_data, tokens, start,
+                                     block_table, true_len, policy, cfg,
+                                     block_size, attn_impl)
     with jax.named_scope("lm_head"):
-        return policy.unembed(params, x, cfg), cache
+        return policy.unembed(params, x, cfg), cache, counts
 
 
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
@@ -124,7 +169,8 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
 def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                   policy, cfg, block_size: int, attn_impl: str = "auto"):
     """Batched single-token decode; returns (logits [B, V], updated
-    cache_data). See llama_decode.decode_step for the argument contract.
+    cache_data, counts: module docstring).
+    See llama_decode.decode_step for the argument contract.
     ``cache_data``: plain pool or ``(pages, scales)`` like prefill_chunk_g."""
     spec = policy.cache_spec(cfg)
     mb = block_tables.shape[1]
@@ -137,14 +183,22 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                         block_tables,
                         jnp.minimum(safe_pos // block_size, mb - 1)[:, None],
                         axis=1)[:, 0],
-                    pool.shape[3] - 1)
+                    _trash_block(pool, spec))
     off = safe_pos % block_size
 
     with jax.named_scope("embed"):
         x = policy.embed(params, tokens, safe_pos, cfg)
 
     cache = cache_data
+    counted = []
     for i in range(spec.num_layers):
+        def attend_latent(q_nope, q_rope, row, w_ukv, scale, i=i):
+            nonlocal cache
+            with jax.named_scope("attn/latent_write"):
+                cache = write_latent(cache, i, row, blk, off)
+            return _latent_paged_attn(q_nope, q_rope, cache, i, block_tables,
+                                      safe_pos, w_ukv, scale, attn_impl)
+
         def attend(q, k, v, i=i, window="spec", softcap=None):
             nonlocal cache
             win = spec.window if window == "spec" else window
@@ -168,11 +222,15 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                 return _paged_attn(q[:, None], cache, i, block_tables,
                                    safe_pos, win, attn_impl,
                                    softcap=softcap)[:, 0]
-        x = policy.block(params, i, x, attend, safe_pos, cfg)
+        x, counts = policy.block(
+            params, i, x, attend_latent if spec.latent_dim else attend,
+            safe_pos, cfg, valid)
+        if counts is not None:
+            counted.append(counts)
 
     with jax.named_scope("lm_head"):
         logits = policy.unembed(params, x, cfg)
-    return logits, cache
+    return logits, cache, _summed(counted)
 
 
 # compile-event ledger: every XLA compile of the serving step fns emits an

@@ -30,6 +30,24 @@ class KVCacheConfig:
     block_size: int = 64
     num_blocks: int = 256
     dtype: any = jnp.bfloat16
+    # a latent (MLA) page kind: one plane of ``latent_dim`` values a token
+    # with no heads (``num_kv_heads`` and ``head_dim`` then say nothing), its
+    # rows padded with zero lanes to ``latent_row_width``
+    latent_dim: int = 0
+
+
+def latent_row_width(latent_dim: int) -> int:
+    """A latent page's row as the pool stores it: ``latent_dim`` values and
+    zero lanes up to the next multiple of the TPU's 128. A last axis that is
+    no multiple of 128 gets a device layout with the block index minor, and
+    the kernel, which needs the row minor, then has the whole pool copied in
+    and out of every step; the tiled row-major layout pads the row in memory
+    anyway, so the lanes cost no bytes that layout would not."""
+    return -(-latent_dim // 128) * 128
+
+
+class LatentPageDtypeError(ValueError):
+    """A page dtype that a latent (MLA) pool cannot hold."""
 
 
 class BlockedKVCache:
@@ -38,8 +56,17 @@ class BlockedKVCache:
         # last block reserved as the trash target for padding-token writes
         # (see llama_decode.py); never handed out by the allocator
         self.allocator = BlockedAllocator(cfg.num_blocks - 1)
-        # [L, 2(kv), H_kv, num_blocks, block_size, D] (head-major pages)
+        # [L, 2(kv), H_kv, num_blocks, block_size, D] (head-major pages), or
+        # for a latent cache one plane [L, num_blocks, block_size, W]
+        self.block_axis = 1 if cfg.latent_dim else 3
+        if cfg.latent_dim and cfg.dtype == jnp.float8_e4m3fn:
+            raise LatentPageDtypeError(
+                "fp8 scaled pages are not supported over a latent cache: "
+                "their per-(head, page) scales and K and V planes have no "
+                "place on one headless plane; use kv_cache_dtype='model'")
         self.data = jnp.zeros(
+            (cfg.num_layers, cfg.num_blocks, cfg.block_size,
+             latent_row_width(cfg.latent_dim)) if cfg.latent_dim else
             (cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks,
              cfg.block_size, cfg.head_dim), cfg.dtype)
         # fp8 pages carry a per-(layer, k/v, head, page) fp32 scale: stored
@@ -57,7 +84,7 @@ class BlockedKVCache:
         ``(data, scales)`` for scaled fp8 pages. They consume what they are
         given (``generic_decode`` donates it and updates it in place), so
         the pool that comes back is assigned here right after the call:
-        ``logits, kv.pool = decode_step_g(params, kv.pool, ...)``. Read the
+        ``logits, kv.pool, _ = decode_step_g(params, kv.pool, ...)``. Read the
         pool afresh after a step; an array kept across one is deleted."""
         return self.data if self.scales is None else (self.data, self.scales)
 
@@ -103,11 +130,12 @@ class BlockedKVCache:
     def gather_blocks(self, blocks: List[int]
                       ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Copy the listed blocks' pages (and, for fp8, their scales) to
-        host ndarrays ``[L, 2, H_kv, len(blocks), bs, D]``. A deliberate
+        host ndarrays ``[L, 2, H_kv, len(blocks), bs, D]`` (a latent pool:
+        ``[L, len(blocks), bs, W]``). A deliberate
         device->host transfer — demotion runs OFF the per-tick fast path,
         only when the serving tier policy decides to spill."""
         idx = np.asarray(blocks, np.int32)
-        data = np.asarray(self.data[:, :, :, idx])
+        data = np.asarray(jnp.take(self.data, idx, axis=self.block_axis))
         scales = (np.asarray(self.scales[:, :, :, idx])
                   if self.scales is not None else None)
         return data, scales
@@ -120,18 +148,20 @@ class BlockedKVCache:
         what it was at demotion."""
         idx = jnp.asarray(np.asarray(blocks, np.int32))
         self.data = _set_blocks(self.data, idx,
-                                jnp.asarray(data, self.cfg.dtype))
+                                jnp.asarray(data, self.cfg.dtype),
+                                self.block_axis)
         if self.scales is not None and scales is not None:
             self.scales = _set_blocks(self.scales, idx,
                                       jnp.asarray(scales, jnp.float32))
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _set_blocks(pool, idx, vals):
-    """``pool[:, :, :, idx] = vals`` in place (pages or scales; ``vals``
-    whole blocks or one number). Outside a jit the same update writes a
-    second pool and holds both until the first is dropped."""
-    return pool.at[:, :, :, idx].set(vals)
+@partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
+def _set_blocks(pool, idx, vals, axis: int = 3):
+    """``pool[..., idx] = vals`` along the pool's block ``axis`` in place
+    (pages or scales; ``vals`` whole blocks or one number). Outside a jit
+    the same update writes a second pool and holds both until the first is
+    dropped."""
+    return pool.at[(slice(None),) * axis + (idx,)].set(vals)
 
 
 FP8_MAX = 448.0     # float8_e4m3fn max finite; overflow casts become NaN
@@ -218,3 +248,15 @@ def write_kv(cache_data, layer: int, k_new, v_new, block_ids, offsets):
         cache_data = cache_data.at[layer, kv, heads, blk, off].set(
             cast_to_page_dtype(new, cache_data.dtype))
     return cache_data
+
+
+def write_latent(cache_data, layer: int, rows, block_ids, offsets):
+    """Scatter new tokens' latent rows into their page slots, in place where
+    the pool is donated. cache_data: [L, NB, bs, W]; rows: [T, C] with C <= W
+    (zero lanes fill the rest); block_ids/offsets: [T]. One update is one W
+    row of the pool as it lies in memory, as in ``write_kv``."""
+    pad = cache_data.shape[-1] - rows.shape[-1]
+    if pad:
+        rows = jnp.pad(rows, ((0, 0), (0, pad)))
+    return cache_data.at[layer, block_ids, offsets].set(
+        cast_to_page_dtype(rows, cache_data.dtype))

@@ -11,11 +11,17 @@ Pallas paged-attention call; the policy contributes exactly the three
 arch-specific pieces:
 
 - ``embed(params, tokens, positions, cfg)``          -> [N, D] hidden states
-- ``block(params, i, x, attend, positions, cfg)``    -> [N, D] (one layer;
-  calls ``attend(q, k, v)`` for cache write + paged attention)
+- ``block(params, i, x, attend, positions, cfg, valid)`` -> ([N, D], counts)
+  (one layer; calls ``attend(q, k, v)`` for cache write + paged attention;
+  ``valid`` [N] marks the rows that are no bucket padding; ``counts`` is
+  ``None``, or for a layer that counts inside the step program an int32
+  vector of ``telemetry/names.py`` ``STEP_COUNTER_ARGS``, which the loop sums
+  over the layers and hands out beside the logits)
 - ``unembed(params, x, cfg)``                        -> [N, V] fp32 logits
 
-plus ``cache_spec(cfg)`` so the engine can size the paged KV pool. Policies are
+plus ``cache_spec(cfg)`` so the engine can size the paged KV pool, and says
+what a page is. Over a latent cache (``latent_dim``) the block calls
+``attend(q_nope, q_rope, row, w_ukv, scale)`` with one row a token. Policies are
 keyed both by name and by config dataclass type; ``policy_for`` is the
 heuristic (reference heuristics.py) that picks the implementation for a model
 config. mistral/qwen2/phi3 are LlamaConfig variants and route to LlamaPolicy.
@@ -44,6 +50,11 @@ class KVCacheSpec:
     max_seq_len: int
     dtype: Any
     window: Any = None       # sliding-window width or None
+    # a latent (MLA) page kind: one plane with no heads, one row of
+    # ``latent_dim`` values a token (``[ckv ; k_rope]``), all of it the key
+    # of every query head and its leading compressed part the value
+    # (``num_kv_heads`` 1, ``head_dim`` the row). 0: K and V planes
+    latent_dim: int = 0
 
 
 def register_policy(name: str, config_type: type):
@@ -120,7 +131,7 @@ class LlamaPolicy:
         return x
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         ns = LlamaPolicy._norm_scale
@@ -136,7 +147,7 @@ class LlamaPolicy:
                                lp["attn"]["wo"]["kernel"].astype(dtype))
         with jax.named_scope("mlp"):
             h2 = _rms(x, ns(lp["mlp_norm"]["scale"], cfg), cfg.rms_norm_eps)
-            return x + _mlp(lp, h2, dtype, act=cfg.hidden_act)
+            return x + _mlp(lp, h2, dtype, act=cfg.hidden_act), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -172,7 +183,7 @@ class FalconPolicy:
         return params["model"]["embed"]["embedding"].astype(cfg.dtype)[tokens]
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.layer_norm_eps
@@ -193,7 +204,7 @@ class FalconPolicy:
                               lp["wo"]["kernel"].astype(dtype))
         mlp = jax.nn.gelu(h_mlp @ lp["mlp_up"]["kernel"].astype(dtype))
         mlp_out = mlp @ lp["mlp_down"]["kernel"].astype(dtype)
-        return x + attn_out + mlp_out        # parallel residual
+        return x + attn_out + mlp_out, None  # parallel residual
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -227,7 +238,7 @@ class OPTPolicy:
         return x + pos
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.layer_norm_eps
@@ -246,7 +257,7 @@ class OPTPolicy:
         m = jax.nn.relu(h2 @ lp["fc1"]["kernel"].astype(dtype) +
                         lp["fc1"]["bias"].astype(dtype))
         return x + m @ lp["fc2"]["kernel"].astype(dtype) + \
-            lp["fc2"]["bias"].astype(dtype)
+            lp["fc2"]["bias"].astype(dtype), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -306,7 +317,7 @@ class MixtralPolicy:
         return params["embed"]["embedding"].astype(cfg.base.dtype)[tokens]
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         base = cfg.base
         dtype = base.dtype
         lp = params[f"layer_{i}"]
@@ -322,7 +333,7 @@ class MixtralPolicy:
                                lp["attn"]["wo"]["kernel"].astype(dtype))
         h2 = _rms(x, lp["mlp_norm"]["scale"], base.rms_norm_eps)
         return x + _dense_moe_combine(lp["moe"], h2, cfg.moe.top_k, dtype,
-                                      cfg.moe.norm_topk_prob)
+                                      cfg.moe.norm_topk_prob), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -359,7 +370,7 @@ class BloomPolicy:
                           cfg.layer_norm_eps)
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.layer_norm_eps
@@ -381,7 +392,7 @@ class BloomPolicy:
         m = jax.nn.gelu(h2 @ lp["mlp_up"]["kernel"].astype(dtype) +
                         lp["mlp_up"]["bias"].astype(dtype))
         return x + m @ lp["mlp_down"]["kernel"].astype(dtype) + \
-            lp["mlp_down"]["bias"].astype(dtype)
+            lp["mlp_down"]["bias"].astype(dtype), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -413,7 +424,7 @@ class GPTNeoXPolicy:
         return params["model"]["embed"]["embedding"].astype(cfg.dtype)[tokens]
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.layer_norm_eps
@@ -439,8 +450,8 @@ class GPTNeoXPolicy:
                         lp["mlp_up"]["bias"].astype(dtype))
         mlp_out = m @ lp["mlp_down"]["kernel"].astype(dtype) + \
             lp["mlp_down"]["bias"].astype(dtype)
-        return (x + attn_out + mlp_out) if cfg.parallel_residual \
-            else h2_src + mlp_out
+        return ((x + attn_out + mlp_out) if cfg.parallel_residual
+                else h2_src + mlp_out), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -472,7 +483,7 @@ class GPT2Policy:
             m["pos_embed"][positions].astype(cfg.dtype)
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params["model"][f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.layer_norm_eps
@@ -491,7 +502,7 @@ class GPT2Policy:
         m = jax.nn.gelu(h2 @ lp["mlp_up"]["kernel"].astype(dtype) +
                         lp["mlp_up"]["bias"].astype(dtype))
         return x + m @ lp["mlp_down"]["kernel"].astype(dtype) + \
-            lp["mlp_down"]["bias"].astype(dtype)
+            lp["mlp_down"]["bias"].astype(dtype), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -524,7 +535,7 @@ class Qwen2MoEPolicy:
         return params["embed"]["embedding"].astype(cfg.base.dtype)[tokens]
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         base = cfg.base
         dtype = base.dtype
         lp = params[f"layer_{i}"]
@@ -549,7 +560,7 @@ class Qwen2MoEPolicy:
             shared = (g * u) @ se["w_down"]["kernel"].astype(dtype)
             gate = jax.nn.sigmoid(
                 (h2 @ se["gate"]["kernel"].astype(dtype)).astype(jnp.float32))
-            return x + moe_out + shared * gate.astype(dtype)
+            return x + moe_out + shared * gate.astype(dtype), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -585,7 +596,7 @@ class Gemma2Policy:
                                         jnp.float32)).astype(x.dtype)
 
     @staticmethod
-    def block(params, i, x, attend, positions, cfg):
+    def block(params, i, x, attend, positions, cfg, valid):
         lp = params[f"layer_{i}"]
         dtype = cfg.dtype
         eps = cfg.rms_norm_eps
@@ -606,7 +617,7 @@ class Gemma2Policy:
         x = x + _rms(h, lp["post_attn_norm"]["scale"] + 1.0, eps)
         h2 = _rms(x, lp["pre_ffw_norm"]["scale"] + 1.0, eps)
         m = _mlp(lp, h2, dtype, act="gelu_tanh")
-        return x + _rms(m, lp["post_ffw_norm"]["scale"] + 1.0, eps)
+        return x + _rms(m, lp["post_ffw_norm"]["scale"] + 1.0, eps), None
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -615,3 +626,76 @@ class Gemma2Policy:
         logits = x.astype(jnp.float32) @ \
             params["embed"]["embedding"].astype(jnp.float32).T     # tied
         return softcap_logits(logits, cfg.final_logit_softcap)
+
+
+# ---------------------------------------------------------------------------
+# JoyAI-LLM-Flash (latent attention over one cached row a token, sigmoid
+# routing with a correction bias through a grouped matmul, a shared expert,
+# a leading dense layer)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.models.joyai_llm_flash import (  # noqa: E402
+    JoyAIFlashConfig, apply_rope_pairs, route)
+from deepspeed_tpu.moe.grouped_experts import grouped_expert_ffn  # noqa: E402
+
+
+@register_policy("joyai_llm_flash", JoyAIFlashConfig)
+class JoyAIFlashPolicy:
+    """models/joyai_llm_flash.py's serving twin. The page row of a token is
+    ``[RMS(c_kv) ; rope(k_r)]``; the loop (``generic_decode``) reads it
+    unfolded in a prefill chunk and folded in a decode batch. The chosen
+    experts alone compute (``moe/grouped_experts.py``), and the step programs
+    hand out how many rows they took and how many experts they touched."""
+
+    @staticmethod
+    def cache_spec(cfg: JoyAIFlashConfig) -> KVCacheSpec:
+        return KVCacheSpec(cfg.num_layers, 1, cfg.latent_dim, cfg.max_seq_len,
+                           cfg.dtype, None, latent_dim=cfg.latent_dim)
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        ap = lp["attn"]
+        dtype, eps = cfg.dtype, cfg.rms_norm_eps
+        d_n, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        h = _rms(x, lp["attn_norm"]["scale"], eps)
+        with jax.named_scope("attn/latent_q"):
+            cq = _rms(h @ ap["wq_a"]["kernel"].astype(dtype),
+                      ap["q_norm"]["scale"], eps)
+            q = jnp.einsum("tr,rhk->thk", cq,
+                           ap["wq_b"]["kernel"].astype(dtype))
+            q_rope = apply_rope_pairs(q[..., d_n:], positions, cfg)
+            ckv = h @ ap["wkv_a"]["kernel"].astype(dtype)
+            row = jnp.concatenate(
+                [_rms(ckv[:, :rank], ap["kv_norm"]["scale"], eps),
+                 apply_rope_pairs(ckv[:, rank:], positions, cfg)], -1)
+        o = attend(q[..., :d_n], q_rope, row,
+                   ap["wkv_b"]["kernel"].astype(dtype), cfg.softmax_scale)
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thv,hvd->td", o,
+                               ap["wo"]["kernel"].astype(dtype))
+        h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
+        if cfg.is_dense(i):
+            with jax.named_scope("mlp"):
+                return x + _mlp(lp, h2, dtype), None
+        moe = lp["moe"]
+        with jax.named_scope("moe/router"):
+            weights, ids = route(h2, moe, cfg)
+        with jax.named_scope("moe/experts"):
+            y, rows = grouped_expert_ffn(h2, moe["experts"], weights, ids,
+                                         valid)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe/shared"):
+                y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
+        # two sums over counts the grouped matmul needs anyway
+        return x + y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0)]
+                                ).astype(jnp.int32)
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return x.astype(jnp.float32) @ \
+            params["lm_head"]["kernel"].astype(jnp.float32)

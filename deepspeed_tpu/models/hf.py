@@ -135,6 +135,10 @@ _REGISTRY = {
                              "MixtralForCausalLM", "convert_hf_mixtral"),
     "qwen2_moe": _family_entry("qwen2_moe", "qwen2_moe_config_from_hf",
                                "Qwen2MoEForCausalLM", "convert_hf_qwen2_moe"),
+    "joyai_llm_flash": _family_entry("joyai_llm_flash",
+                                     "joyai_flash_config_from_hf",
+                                     "JoyAIFlashForCausalLM",
+                                     "convert_hf_joyai_flash"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

@@ -1,0 +1,61 @@
+"""Routed experts through a grouped matmul: only the chosen experts compute.
+
+Reference analog: ``deepspeed/inference/v2/kernels/cutlass_ops/moe_gemm`` with
+``ragged_ops/moe_scatter`` and ``moe_gather`` (rows sorted by expert, one
+grouped GEMM a weight, rows gathered back). Here the T x K assignments are
+sorted by expert and multiplied by ``jax.lax.ragged_dot`` (XLA:TPU lowers it
+to its grouped-matmul call; a group's tiles are visited for the rows it has),
+so no ``[E, T, F]`` intermediate exists and an expert nobody chose is not
+read. ``models/joyai_llm_flash.py`` (the weights that train) and
+``inference/v2/modules.py`` (the weights that serve) both call this.
+"""
+
+import jax
+import jax.numpy as jnp
+
+
+def sigmoid_route(h, gate_kernel, bias, top_k: int, scaling: float):
+    """(weights [T, K] float32, expert ids [T, K]) for ``h`` [T, D]:
+    ``s = sigmoid(W_g h)`` in float32 at full matmul precision (the choice
+    must not hang on a bfloat16 pass), the ``top_k`` experts with the
+    largest ``s + bias`` (``noaux_tc``'s ``e_score_correction_bias``; it
+    steers the choice and does not enter the weights), weights ``s`` of the
+    chosen renormalised to sum to 1, times ``scaling``. Ties go to the lower
+    id (``jax.lax.top_k`` is stable)."""
+    logits = jnp.dot(h.astype(jnp.float32), gate_kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scaling, ids
+
+
+def grouped_expert_ffn(h, experts, weights, ids, valid=None):
+    """``sum_k weights[t, k] * E_ids[t, k](h[t])`` with each ``E`` a gated
+    MLP of the stacked weights ``experts`` (``w_gate``, ``w_up`` [E, D, F];
+    ``w_down`` [E, F, D]). ``h``: [T, D] in the compute type; ``weights``,
+    ``ids``: [T, K]; ``valid``: [T] bool, rows to leave out (bucket padding).
+    Returns (y [T, D], rows on each expert [E] int32).
+
+    Every shape is static: the T*K assignments are sorted by expert with
+    those of rows left out last, the grouped matmul visits only the rows its
+    group sizes cover, and the rows past them (uninitialised in its output)
+    are zeroed before they are gathered back."""
+    t, k = ids.shape
+    e = experts["w_gate"].shape[0]
+    keep = jnp.ones((t, 1), bool) if valid is None else valid[:, None]
+    key = jnp.where(keep, ids, e).reshape(-1)                    # [T*K]
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+    dtype = h.dtype
+    xs = h[order // k]                                           # [T*K, D]
+    g = jax.lax.ragged_dot(xs, experts["w_gate"].astype(dtype), counts)
+    u = jax.lax.ragged_dot(xs, experts["w_up"].astype(dtype), counts)
+    out = jax.lax.ragged_dot(jax.nn.silu(g) * u,
+                             experts["w_down"].astype(dtype), counts)
+    computed = jnp.arange(t * k) < jnp.sum(counts)
+    out = jnp.where(computed[:, None], out, 0)
+    back = out[jnp.argsort(order)].reshape(t, k, -1)
+    w = jnp.where(keep, weights, 0.0).astype(dtype)
+    return jnp.einsum("tk,tkd->td", w, back), counts

@@ -1,0 +1,293 @@
+"""Pallas attention over a latent (MLA) cache: a paged decode kernel over the
+cached rows themselves, and a prefill kernel over keys and values up-projected
+from them.
+
+Reference analog: ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash``
+has no latent form; the mechanism is DeepSeek-V2's (arXiv:2405.04434,
+section 2.1).
+
+A page holds ``block_size`` rows of ``[ckv (rank) ; k_rope]`` and has no
+heads: every query head reads the same row. The pool's rows are padded with
+zero lanes to a multiple of 128 (``row_width``): a last axis that is no
+multiple of the TPU's lanes gets a device layout with the block index minor,
+and a kernel that needs the row minor then has the whole pool copied in and
+out of every step; the tiled row-major layout pads the row in memory anyway.
+
+**Decode** (``latent_paged_attention``): the key up-projection is folded into
+the query (``q~ = W_uk^T q_nope``) and the value up-projection into the
+output, so a head scores against the whole row and sums its first ``rank``
+values: one tile read serves both matmuls and all heads. Grid (sequence, page
+group), page groups innermost, online-softmax accumulators in VMEM scratch,
+block tables and positions in scalar prefetch as in ``paged_attention.py``.
+Two things differ from that kernel: ``pages`` pages are fetched a grid step
+(the pool is handed to the call that many times, each with its own index
+map), so the per-step overhead is paid once for ``pages * block_size`` keys;
+and a page past a sequence's last live one maps to the last live one, whose
+block index equals the step before's, so the pipeline fetches nothing and a
+short sequence in a long table costs its own pages only.
+
+**Prefill** (``latent_prefill_attention``): a chunk's queries against
+per-head keys ``[k_nope_i ; k_rope]`` and values of head dims 192 and 128,
+2 x (192 + 128) operations a pair a head where the folded form costs
+2 x (576 + 512). A flash forward over grid (head, query block, key block)
+with the chunk's start in scalar prefetch; the rope part of a score is a
+second small matmul against the one rotated key all heads share, so nothing
+is concatenated or broadcast. Key blocks past a query block's causal horizon
+are skipped, and map to the last live block so that they are not fetched.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NEG_INF = -1e30
+
+#: Keys a decode grid step multiplies against, at most.
+_KEYS_PER_STEP = 1024
+#: Query rows and keys of one prefill grid cell, at most.
+PREFILL_BLOCK_Q = 512
+PREFILL_BLOCK_K = 512
+
+
+def _online_softmax_step(s, mask, v, m_scr, l_scr, acc_scr):
+    """One flash step on masked scores ``s`` [rows, keys] against ``v``."""
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    m_scr[:] = m_new
+    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _init_scratch(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+# ---------------------------------------------------------------------------
+# decode: folded queries over the cached rows
+# ---------------------------------------------------------------------------
+def _decode_kernel(tables_ref, pos_ref, q_ref, *refs, block_size, pages,
+                   steps, rank, scale):
+    page_refs = refs[:pages]
+    o_ref, m_scr, l_scr, acc_scr = refs[pages:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    keys = pages * block_size
+
+    pl.when(j == 0)(lambda: _init_scratch(m_scr, l_scr, acc_scr))
+    qpos = pos_ref[b]
+
+    def _compute():
+        q = q_ref[0]                                   # [rows, W]
+        kv = page_refs[0][0] if pages == 1 else jnp.concatenate(
+            [p[0] for p in page_refs], axis=0)         # [keys, W]
+        kv = kv.astype(q.dtype)
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        kpos = j * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # causal == context-length mask
+        _online_softmax_step(s, kpos <= qpos, kv[:, :rank], m_scr, l_scr,
+                             acc_scr)
+
+    pl.when(j * keys <= qpos)(_compute)
+
+    @pl.when(j == steps - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, pool, layer: int, block_tables, positions,
+                           scale: float, rank: int, interpret: bool = False):
+    """q: [B, H, W] folded queries of one token a sequence, each
+    ``[W_uk^T q_nope ; rope(q_rope) ; 0...]`` at the pool's row width; pool:
+    the whole latent pool [L, NB, block_size, W] (it goes to the kernel as it
+    lies in memory: a slice of it is a copy of it); block_tables: [B, MB]
+    int32 (trash-padded); positions: [B] int32, each token's own position
+    (it attends keys at positions <= its own). Returns [B, H, rank]: the
+    probabilities times the first ``rank`` values of each row, before the
+    value up-projection.
+
+    The tokens' own rows must already be in the pages; causal masking then
+    doubles as the context-length mask."""
+    b, h, w = q.shape
+    nb, bs = pool.shape[1], pool.shape[2]
+    mb = block_tables.shape[1]
+    pages = max(min(_KEYS_PER_STEP // bs, mb), 1)
+    steps = -(-mb // pages)
+    if steps * pages != mb:     # dead slots: never live, so never fetched
+        block_tables = jnp.pad(block_tables,
+                               ((0, 0), (0, steps * pages - mb)),
+                               constant_values=nb - 1)
+        mb = steps * pages
+    rows = -(-h // 8) * 8
+    if rows != h:
+        q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
+    flat = pool.reshape((-1,) + pool.shape[2:])        # [L * NB, bs, W]
+
+    def page_map(n):
+        def index(bi, j, tables, pos):
+            # past the sequence's last live page: that page again, which
+            # the pipeline does not fetch twice
+            live = jnp.minimum(j * pages + n,
+                               jnp.minimum(pos[bi] // bs, mb - 1))
+            return (layer * nb + tables[bi * mb + live], 0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, steps),
+        in_specs=[pl.BlockSpec((1, rows, w), lambda bi, j, *pf: (bi, 0, 0))]
+        + [pl.BlockSpec((1, bs, w), page_map(n)) for n in range(pages)],
+        out_specs=pl.BlockSpec((1, rows, rank), lambda bi, j, *pf:
+                               (bi, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, rank), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_size=bs, pages=pages,
+                          steps=steps, rank=rank, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_paged_attention",
+    )(block_tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
+      q, *([flat] * pages))
+    return out[:, :h]
+
+
+def latent_paged_attention_reference(q, pages, block_tables, positions,
+                                     scale: float, rank: int):
+    """Gather-based jnp path with the decode kernel's semantics (the CPU's
+    ``attn_impl`` and the kernel tests' oracle). pages: one layer's
+    [NB, block_size, W]."""
+    b = q.shape[0]
+    mb, bs = block_tables.shape[1], pages.shape[1]
+    ctx = pages[block_tables].reshape(b, mb * bs, -1).astype(q.dtype)
+    s = jnp.einsum("bhd,bkd->bhk", q, ctx,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(mb * bs)[None, :] <= positions[:, None]    # [B, S]
+    s = jnp.where(mask[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(ctx.dtype)
+    return jnp.einsum("bhk,bkv->bhv", p, ctx[..., :rank])
+
+
+# ---------------------------------------------------------------------------
+# prefill: a chunk's queries over up-projected keys and values
+# ---------------------------------------------------------------------------
+def _prefill_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                    m_scr, l_scr, acc_scr, *, block_q, block_k, steps, scale):
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+
+    pl.when(j == 0)(lambda: _init_scratch(m_scr, l_scr, acc_scr))
+    start = start_ref[0]
+
+    def _compute():
+        contract = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
+                                preferred_element_type=jnp.float32)
+        s = s + jax.lax.dot_general(qr_ref[0], kr_ref[...], contract,
+                                    preferred_element_type=jnp.float32)
+        s = s * scale
+        qpos = start + i * block_q + \
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        _online_softmax_step(s, kpos <= qpos, v_ref[0], m_scr, l_scr, acc_scr)
+
+    # the block's last query sees keys up to its own position
+    pl.when(j * block_k <= start + (i + 1) * block_q - 1)(_compute)
+
+    @pl.when(j == steps - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
+                             scale: float, interpret: bool = False):
+    """One sequence's chunk. q_nope: [H, T, d_n], q_rope: [H, T, d_r] (rows
+    t at positions ``start + t``); k_nope: [H, S, d_n], k_rope: [S, d_r] (the
+    one rotated key all heads share), v: [H, S, d_v], rows s at positions s,
+    the chunk's own among them; start: int32 scalar. Row t attends keys at
+    positions <= start + t. Returns [H, T, d_v]."""
+    h, t, d_n = q_nope.shape
+    s, d_r, d_v = k_nope.shape[1], q_rope.shape[2], v.shape[2]
+    block_q = min(t, PREFILL_BLOCK_Q)
+    tp = -(-t // block_q) * block_q
+    if tp != t:
+        q_nope = jnp.pad(q_nope, ((0, 0), (0, tp - t), (0, 0)))
+        q_rope = jnp.pad(q_rope, ((0, 0), (0, tp - t), (0, 0)))
+    block_k = min(s, PREFILL_BLOCK_K)
+    if s % block_k:
+        raise ValueError(f"{s} keys are no multiple of the key block "
+                         f"{block_k}: gather a table padded to whole blocks")
+    steps = s // block_k
+
+    def key_block(i, j, start):
+        # past the query block's horizon: the last live block again, which
+        # the pipeline does not fetch twice
+        return jnp.minimum(j, (start[0] + (i + 1) * block_q - 1) // block_k)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(h, tp // block_q, steps),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d_n), lambda hi, i, j, st: (hi, i, 0)),
+            pl.BlockSpec((1, block_q, d_r), lambda hi, i, j, st: (hi, i, 0)),
+            pl.BlockSpec((1, block_k, d_n), lambda hi, i, j, st:
+                         (hi, key_block(i, j, st), 0)),
+            pl.BlockSpec((block_k, d_r), lambda hi, i, j, st:
+                         (key_block(i, j, st), 0)),
+            pl.BlockSpec((1, block_k, d_v), lambda hi, i, j, st:
+                         (hi, key_block(i, j, st), 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, d_v), lambda hi, i, j, st:
+                               (hi, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, block_q=block_q, block_k=block_k,
+                          steps=steps, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((h, tp, d_v), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_prefill_attention",
+    )(jnp.asarray(start, jnp.int32).reshape(1), q_nope, q_rope, k_nope,
+      k_rope, v)
+    return out[:, :t]
+
+
+def latent_prefill_attention_reference(q_nope, q_rope, k_nope, k_rope, v,
+                                       start, scale: float):
+    """jnp path with the prefill kernel's semantics (the CPU's ``attn_impl``
+    and the kernel tests' oracle)."""
+    t, s = q_nope.shape[1], k_nope.shape[1]
+    scores = (jnp.einsum("htd,hsd->hts", q_nope, k_nope,
+                         preferred_element_type=jnp.float32) +
+              jnp.einsum("htd,sd->hts", q_rope, k_rope,
+                         preferred_element_type=jnp.float32)) * scale
+    mask = jnp.arange(s)[None, :] <= start + jnp.arange(t)[:, None]
+    scores = jnp.where(mask[None], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("hts,hsv->htv", p, v)

@@ -210,6 +210,27 @@ SERVE_STAGE_OF: Dict[str, str] = {
     "serve/drain_reap": "drain",
 }
 
+#: ``jax.named_scope`` names of the served step programs: what an operation's
+#: ``tf_op`` holds in the device trace, and what the benchmark's per-layer
+#: readers match (``generic_decode.py``, ``modules.py``, ``llama_decode.py``).
+#: The ``attn/latent_*`` four are a latent (MLA) cache's: the low-rank
+#: projections and the fold, the row's write, the paged decode kernel with
+#: the value unfold, a chunk's gather, up-projection and prefill kernel
+SERVED_SCOPES: Tuple[str, ...] = (
+    "embed", "attn/qkv", "attn/kv_write", "attn/paged", "attn/out",
+    "attn/latent_q", "attn/latent_write", "attn/latent_paged",
+    "attn/latent_prefill", "mlp", "moe/router", "moe/experts", "moe/shared",
+    "lm_head", "sample")
+
+#: counts a step program computes on the device where its policy's layers
+#: count (``generic_decode.py``), in the order of the int32 vector it hands
+#: out: token-expert pairs of the step and experts with at least one row,
+#: each summed over the expert layers. The engine reads them with the sampled
+#: token and puts them on ``serve/prefill_chunk`` and ``serve/step_decode``
+#: as args of these names; a chunk that ends no prompt is not waited for, so
+#: its counts ride on the next of these spans that is
+STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched")
+
 #: per-request tracing namespace (reqtrace.py file-loads this module
 #: standalone, same contract as the tables above). Spans carrying a
 #: ``trace_id`` arg under REQ_PREFIX are the stitch join; REQ_STAGE_OF

@@ -305,7 +305,7 @@ def test_fp8_scaled_prefill_logit_error_bound(model_and_params):
             head_dim=cfg.head_dim_, block_size=16, num_blocks=32,
             dtype=dtype))
         cache = kv.data if kv.scales is None else (kv.data, kv.scales)
-        logits, _ = prefill_chunk_g(
+        logits, _, _ = prefill_chunk_g(
             big, cache, jnp.asarray(tokens), 0, table, 80,
             policy=LlamaPolicy, cfg=cfg, block_size=16, attn_impl="gather")
         return np.asarray(logits)
@@ -458,7 +458,7 @@ def test_fp8_scaled_cache_tuple_fast(model_and_params):
     assert kv.scales is not None
     tokens = np.zeros(16, np.int32)
     tokens[:10] = np.random.default_rng(2).integers(0, cfg.vocab_size, 10)
-    logits, (data, scales) = prefill_chunk_g(
+    logits, (data, scales), _ = prefill_chunk_g(
         params, (kv.data, kv.scales), jnp.asarray(tokens), 0,
         jnp.asarray(np.arange(4), np.int32), 10, policy=LlamaPolicy,
         cfg=cfg, block_size=16, attn_impl="gather")

@@ -112,7 +112,7 @@ def test_step_program_aliases_and_consumes_the_pool(built, fn_name, pages):
     assert header.count("may-alias") + header.count("must-alias") \
         == len(leaves), header
 
-    out, pool = fn(*args, **kw)
+    out, pool, _ = fn(*args, **kw)
     assert all(x.is_deleted() for x in leaves)
     got = jax.tree.leaves(pool)
     assert [(x.shape, x.dtype) for x in got] == \

@@ -109,3 +109,77 @@ def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
                           % (whole, page_set), line)
              and ("copy(" in line or "kind=kLoop" in line)]
     assert moved == []
+
+
+# --- the latent (MLA) pool ----------------------------------------------------
+# JoyAI-LLM-Flash at its published widths, cut to the dense layer and one
+# expert layer of all 256 experts, so that a compile takes seconds.
+
+def _latent_shapes(one_chip, fn_name):
+    from deepspeed_tpu.inference.v2.kv_cache import latent_row_width
+    from deepspeed_tpu.models.joyai_llm_flash import (JoyAIFlashConfig,
+                                                      JoyAIFlashForCausalLM)
+    cfg = JoyAIFlashConfig(num_layers=2, max_seq_len=8448)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(JoyAIFlashForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    spec = policy_for(cfg).cache_spec(cfg)
+    pool = jax.ShapeDtypeStruct(
+        (spec.num_layers, NUM_BLOCKS, BLOCK, latent_row_width(spec.latent_dim)),
+        spec.dtype, sharding=one_chip)
+    if fn_name == "decode_step_g":
+        tail = (ints(32), ints(32), ints(32, 132), jax.ShapeDtypeStruct(
+            (32,), jnp.bool_, sharding=one_chip))
+    else:
+        tail = (ints(2048), ints(), ints(132), ints())
+    return cfg, (params, pool) + tail, pool
+
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     "verify_chunk_g"])
+def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(one_chip,
+                                                                fn_name):
+    """One plane of 640-lane rows (576 values and the zero lanes that keep the
+    row minor in the pool's device layout), at the cell's largest shapes (32
+    sequences or a 2,048-token chunk over 132 blocks): aliased, no operation
+    copies or re-lays-out the pool, the latent kernel of the program's phase
+    and the grouped expert matmul are in the program, and the counts leave
+    beside the logits."""
+    cfg, args, pool = _latent_shapes(one_chip, fn_name)
+    assert pool.shape == (2, NUM_BLOCKS, BLOCK, 640)
+    compiled = getattr(gd, fn_name).lower(
+        *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+        attn_impl="kernel").compile()
+    text = compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    # activations of a 2,048-token chunk (the context's up-projected keys and
+    # values are 138 MB; the verifier's logits over 129,280 rows 1.06 GB),
+    # not a second pool: nothing pool-shaped is made anew
+    kernel = "latent_paged_attention" if fn_name == "decode_step_g" \
+        else "latent_prefill_attention"
+    assert "tpu_custom_call" in text and kernel in text
+    assert "ragged-dot" in text
+    entry = text[text.index("\nENTRY"):]
+    whole = ",".join(str(d) for d in pool.shape)
+    moved = [line.strip()[:160] for line in entry.splitlines()
+             if re.search(r"= \(?\w+\[%s\]\S* (copy|fusion|transpose)\("
+                          % whole, line)
+             and ("copy(" in line or "transpose(" in line
+                  or "kind=kLoop" in line)]
+    assert moved == []
+    if fn_name != "verify_chunk_g":
+        # under one [E, T, F] intermediate of the chunk (256 x 2048 x 768
+        # bfloat16 = 805 MB, which all-experts-then-pick makes twice a layer)
+        assert stats.temp_size_in_bytes < 256 * 2048 * 768 * 2 // 2
+    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts

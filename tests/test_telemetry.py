@@ -50,11 +50,12 @@ def tracing():
     t = get_tracer()
     t.clear()
     t.detach_sink()
-    t.configure(enabled=True)
+    capacity = t.capacity       # a test may shrink the ring: later files
+    t.configure(enabled=True)   # on this worker need it whole
     try:
         yield t
     finally:
-        t.configure(enabled=False)
+        t.configure(enabled=False, capacity=capacity)
         t.detach_sink()
         t.clear()
 
