@@ -37,6 +37,8 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.llama_decode import _mlp, _qkv, _rms
 from deepspeed_tpu.models.llama import LlamaConfig, rope_freqs
+from deepspeed_tpu.moe.grouped_experts import (grouped_expert_ffn,
+                                               softmax_route)
 
 DECODE_POLICIES: Dict[str, type] = {}
 _CONFIG_TO_POLICY: Dict[type, type] = {}
@@ -268,28 +270,67 @@ class OPTPolicy:
             m["embed"]["embedding"].astype(jnp.float32).T   # tied
 
 
-def _dense_moe_combine(moe, h2, top_k, dtype, norm_topk_prob=True):
-    """Dense all-expert compute + top-k combine (serving-side MoE;
-    equivalent to the training dispatch when no token drops). With
-    ``norm_topk_prob`` the kept probs are renormalized to sum to 1
-    (GShard/Mixtral); HF Qwen2-MoE runs with it off."""
-    with jax.named_scope("moe/router"):
-        gate_logits = h2.astype(jnp.float32) @ moe["gate"]["wg"]["kernel"]
-        probs = jax.nn.softmax(gate_logits, axis=-1)          # [T, E]
-        topv, topi = jax.lax.top_k(probs, top_k)              # [T, K]
-        if norm_topk_prob:
-            w = topv / jnp.maximum(jnp.sum(topv, -1, keepdims=True), 1e-9)
-        else:
-            w = topv
+def _step_counts(rows):
+    """A layer's counts of ``STEP_COUNTER_ARGS`` from the rows on each expert
+    [E]: two sums over what the router has anyway."""
+    return jnp.stack([jnp.sum(rows), jnp.sum(rows > 0)]).astype(jnp.int32)
+
+
+def _chosen_experts(experts, h2, weights, ids, valid):
+    """The routed sum of ``moe/grouped_experts.py`` (the chosen experts alone
+    compute, bucket padding rows take none) and the layer's counts."""
     with jax.named_scope("moe/experts"):
-        ex = moe["experts"]
-        g = jnp.einsum("td,edf->etf", h2, ex["w_gate"].astype(dtype))
-        u = jnp.einsum("td,edf->etf", h2, ex["w_up"].astype(dtype))
-        eo = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
-                        ex["w_down"].astype(dtype))           # [E, T, D]
-        t_idx = jnp.arange(h2.shape[0])[:, None]              # [T, 1]
-        picked = eo[topi, t_idx]                              # [T, K, D]
-        return jnp.einsum("tk,tkd->td", w.astype(dtype), picked)
+        y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid)
+    return y, _step_counts(rows)
+
+
+def _all_experts_then_pick(experts, h2, weights, ids, valid):
+    """The same routed sum and counts with every row through every expert
+    and the chosen picked afterwards: ``E / K`` times the multiplications,
+    which cost nothing while reading the experts bounds the step."""
+    e, dtype = experts["w_gate"].shape[0], h2.dtype
+    with jax.named_scope("moe/experts"):
+        g = jnp.einsum("td,edf->etf", h2, experts["w_gate"].astype(dtype))
+        u = jnp.einsum("td,edf->etf", h2, experts["w_up"].astype(dtype))
+        every = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
+                           experts["w_down"].astype(dtype))      # [E, T, D]
+        picked = every[ids, jnp.arange(h2.shape[0])[:, None]]    # [T, K, D]
+        w = jnp.where(valid[:, None], weights, 0.0).astype(dtype)
+        rows = jnp.zeros((e + 1,), jnp.int32).at[
+            jnp.where(valid[:, None], ids, e)].add(1)[:e]
+        return jnp.einsum("tk,tkd->td", w, picked), _step_counts(rows)
+
+
+#: rows of one tile of XLA:TPU's grouped-matmul call at Mixtral's widths
+#: (its metadata for 4,096 sorted rows on 8 experts names 4096 / 512 + 8 - 1
+#: tiles). The call multiplies whole tiles, one more for every expert whose
+#: rows end inside one, so a step of up to a tile's rows costs it as much as
+#: every row through every expert, plus the sort and a cost an expert
+_GROUPED_TILE_ROWS = 512
+
+
+def _softmax_moe(moe, h2, cfg, valid):
+    """Mixtral's and Qwen2-MoE's routed experts and their counts: softmax
+    top-k in float32, then the chosen experts alone. Equivalent to the
+    training dispatch when no token drops; no capacity here, so none does.
+
+    Which form computes them hangs on the step program's static row count
+    against the expert count, by chip runs at Mixtral's widths (PERF.md
+    section 6, PR 32; one layer, ms, grouped / every expert): a bucket so
+    small that some expert is likely left unread takes the grouped call
+    (8 rows on 8 experts top-2: 3.11 / 3.78, two live rows 1.58 / 3.78);
+    from there up to one tile of the grouped call every expert is read
+    either way and every-expert-then-pick stays at the memory roofline
+    (32 rows: 4.38 / 3.78; 256: 9.04 / 4.52; 512: 10.28 / 8.54); beyond a
+    tile only the grouped call's work grows with ``top_k`` and not with the
+    expert count (1,024: 12.55 / 16.96; 2,048: 17.75 / 33.88)."""
+    t, e, k = h2.shape[0], cfg.moe.num_experts, cfg.moe.top_k
+    with jax.named_scope("moe/router"):
+        weights, ids = softmax_route(h2, moe["gate"]["wg"]["kernel"], k,
+                                     cfg.moe.norm_topk_prob)
+    if 2 * e < t * k and t <= _GROUPED_TILE_ROWS:
+        return _all_experts_then_pick(moe["experts"], h2, weights, ids, valid)
+    return _chosen_experts(moe["experts"], h2, weights, ids, valid)
 
 
 # ---------------------------------------------------------------------------
@@ -300,11 +341,14 @@ from deepspeed_tpu.models.mixtral import MixtralConfig  # noqa: E402
 
 @register_policy("mixtral", MixtralConfig)
 class MixtralPolicy:
-    """reference: model_implementations/mixtral (+ qwen_v2_moe shape). Serving
-    MoE runs all experts densely on the (small) token batch and combines the
-    renormalized top-k gate weights — equivalent to the training dispatch when
-    no token is dropped (eval capacity factor keeps that true at decode sizes).
-    """
+    """reference: model_implementations/mixtral (+ qwen_v2_moe shape). The
+    softmax router's top-k experts of a row compute and no other
+    (``moe/grouped_experts.py``: rows sorted by expert, a grouped matmul a
+    weight) wherever that is less work on the chip; a step program whose
+    rows fit one tile of that call reads every expert either way and keeps
+    every-expert-then-pick (``_softmax_moe``). No token is dropped in either
+    form, and the step programs hand out how many rows the experts took and
+    how many were touched."""
 
     @staticmethod
     def cache_spec(cfg: MixtralConfig) -> KVCacheSpec:
@@ -332,8 +376,8 @@ class MixtralPolicy:
             x = x + jnp.einsum("thk,hkd->td", attn,
                                lp["attn"]["wo"]["kernel"].astype(dtype))
         h2 = _rms(x, lp["mlp_norm"]["scale"], base.rms_norm_eps)
-        return x + _dense_moe_combine(lp["moe"], h2, cfg.moe.top_k, dtype,
-                                      cfg.moe.norm_topk_prob), None
+        y, counts = _softmax_moe(lp["moe"], h2, cfg, valid)
+        return x + y, counts
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -521,8 +565,9 @@ from deepspeed_tpu.models.qwen2_moe import Qwen2MoEConfig  # noqa: E402
 
 @register_policy("qwen2_moe", Qwen2MoEConfig)
 class Qwen2MoEPolicy:
-    """reference: model_implementations/qwen_v2_moe — Mixtral serving plus a
-    dense shared expert whose output is scaled by a per-token sigmoid gate."""
+    """reference: model_implementations/qwen_v2_moe — Mixtral serving (the
+    chosen experts alone, counted) plus a dense shared expert whose output is
+    scaled by a per-token sigmoid gate."""
 
     @staticmethod
     def cache_spec(cfg: Qwen2MoEConfig) -> KVCacheSpec:
@@ -551,8 +596,7 @@ class Qwen2MoEPolicy:
             x = x + jnp.einsum("thk,hkd->td", attn,
                                lp["attn"]["wo"]["kernel"].astype(dtype))
         h2 = _rms(x, lp["mlp_norm"]["scale"], base.rms_norm_eps)
-        moe_out = _dense_moe_combine(lp["moe"], h2, cfg.moe.top_k, dtype,
-                                     cfg.moe.norm_topk_prob)
+        moe_out, counts = _softmax_moe(lp["moe"], h2, cfg, valid)
         with jax.named_scope("mlp"):       # the dense shared expert
             se = lp["shared_expert"]
             g = jax.nn.silu(h2 @ se["w_gate"]["kernel"].astype(dtype))
@@ -560,7 +604,7 @@ class Qwen2MoEPolicy:
             shared = (g * u) @ se["w_down"]["kernel"].astype(dtype)
             gate = jax.nn.sigmoid(
                 (h2 @ se["gate"]["kernel"].astype(dtype)).astype(jnp.float32))
-            return x + moe_out + shared * gate.astype(dtype), None
+            return x + moe_out + shared * gate.astype(dtype), counts
 
     @staticmethod
     def unembed(params, x, cfg):
@@ -635,7 +679,6 @@ class Gemma2Policy:
 # ---------------------------------------------------------------------------
 from deepspeed_tpu.models.joyai_llm_flash import (  # noqa: E402
     JoyAIFlashConfig, apply_rope_pairs, route)
-from deepspeed_tpu.moe.grouped_experts import grouped_expert_ffn  # noqa: E402
 
 
 @register_policy("joyai_llm_flash", JoyAIFlashConfig)
@@ -684,15 +727,11 @@ class JoyAIFlashPolicy:
         moe = lp["moe"]
         with jax.named_scope("moe/router"):
             weights, ids = route(h2, moe, cfg)
-        with jax.named_scope("moe/experts"):
-            y, rows = grouped_expert_ffn(h2, moe["experts"], weights, ids,
-                                         valid)
+        y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid)
         if cfg.n_shared_experts:
             with jax.named_scope("moe/shared"):
                 y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
-        # two sums over counts the grouped matmul needs anyway
-        return x + y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0)]
-                                ).astype(jnp.int32)
+        return x + y, counts
 
     @staticmethod
     def unembed(params, x, cfg):

@@ -7,7 +7,8 @@ sorted by expert and multiplied by ``jax.lax.ragged_dot`` (XLA:TPU lowers it
 to its grouped-matmul call; a group's tiles are visited for the rows it has),
 so no ``[E, T, F]`` intermediate exists and an expert nobody chose is not
 read. ``models/joyai_llm_flash.py`` (the weights that train) and
-``inference/v2/modules.py`` (the weights that serve) both call this.
+``inference/v2/modules.py`` (the weights that serve: the JoyAI-LLM-Flash,
+Mixtral and Qwen2-MoE policies, each behind its own router) call this.
 """
 
 import jax
@@ -29,6 +30,19 @@ def sigmoid_route(h, gate_kernel, bias, top_k: int, scaling: float):
     w = jnp.take_along_axis(scores, ids, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * scaling, ids
+
+
+def softmax_route(h, gate_kernel, top_k: int, norm_topk_prob: bool):
+    """(weights [T, K] float32, expert ids [T, K]) for ``h`` [T, D]: softmax
+    in float32 over all experts' logits, the ``top_k`` largest (ties to the
+    lower id: ``jax.lax.top_k`` is stable), with ``norm_topk_prob`` the kept
+    probabilities renormalised to sum to 1 (GShard/Mixtral; HF Qwen2-MoE
+    runs with it off)."""
+    logits = h.astype(jnp.float32) @ gate_kernel
+    w, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
+    return w, ids
 
 
 def grouped_expert_ffn(h, experts, weights, ids, valid=None):

@@ -81,6 +81,18 @@ def _shapes(one_chip, fn_name, pages):
     return (params, cache) + tail, pool
 
 
+def _pool_shaped_moves(text, pool):
+    """The entry computation's copies and loop fusions that make a value of
+    the K/V pool's shape or of one layer's page set."""
+    entry = text[text.index("\nENTRY"):]
+    whole = ",".join(str(d) for d in pool.shape)
+    page_set = ",".join(str(d) for d in pool.shape[2:])
+    return [line.strip()[:160] for line in entry.splitlines()
+            if re.search(r"= \(?\w+\[(1,1,)?(%s|%s)\]\S* (copy|fusion)\("
+                         % (whole, page_set), line)
+            and ("copy(" in line or "kind=kLoop" in line)]
+
+
 @pytest.mark.parametrize("pages", ["plain", "fp8-scaled"])
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
                                      "verify_chunk_g"])
@@ -101,14 +113,7 @@ def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
     assert stats.temp_size_in_bytes < pool_bytes // 4
     assert "tpu_custom_call" in text and "paged_attention" in text
 
-    entry = text[text.index("\nENTRY"):]
-    whole = ",".join(str(d) for d in pool.shape)
-    page_set = ",".join(str(d) for d in pool.shape[2:])
-    moved = [line.strip()[:160] for line in entry.splitlines()
-             if re.search(r"= \(?\w+\[(1,1,)?(%s|%s)\]\S* (copy|fusion)\("
-                          % (whole, page_set), line)
-             and ("copy(" in line or "kind=kLoop" in line)]
-    assert moved == []
+    assert _pool_shaped_moves(text, pool) == []
 
 
 # --- the latent (MLA) pool ----------------------------------------------------
@@ -182,4 +187,76 @@ def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(one_chip,
         # under one [E, T, F] intermediate of the chunk (256 x 2048 x 768
         # bfloat16 = 805 MB, which all-experts-then-pick makes twice a layer)
         assert stats.temp_size_in_bytes < 256 * 2048 * 768 * 2 // 2
+    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+
+
+# --- the softmax-routed experts -----------------------------------------------
+# Mixtral-8x7B as the batch-rag cell serves it: 3 layers, 8 experts of
+# 4096 x 14336 top-2, a pool of 1472 blocks, a 2,048-token chunk or 32
+# sequences over 49 blocks (3,136 tokens).
+
+def _mixtral_shapes(one_chip, fn_name):
+    from deepspeed_tpu.models.mixtral import (MixtralForCausalLM,
+                                              mixtral_config_from_hf)
+    cfg = mixtral_config_from_hf({
+        "model_type": "mixtral", "vocab_size": 32000, "hidden_size": 4096,
+        "intermediate_size": 14336, "num_hidden_layers": 3,
+        "num_attention_heads": 32, "num_key_value_heads": 8,
+        "num_local_experts": 8, "num_experts_per_tok": 2,
+        "max_position_embeddings": 32768, "rope_theta": 1000000.0,
+        "rms_norm_eps": 1e-05, "sliding_window": None,
+        "torch_dtype": "bfloat16"})
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(MixtralForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.base.dtype), jax.random.PRNGKey(0)))
+    spec = policy_for(cfg).cache_spec(cfg)
+    pool = jax.ShapeDtypeStruct(
+        (spec.num_layers, 2, spec.num_kv_heads, 1472, BLOCK, spec.head_dim),
+        spec.dtype, sharding=one_chip)
+    if fn_name == "decode_step_g":
+        tail = (ints(32), ints(32), ints(32, 49), jax.ShapeDtypeStruct(
+            (32,), jnp.bool_, sharding=one_chip))
+    else:
+        tail = (ints(2048), ints(), ints(49), ints())
+    return cfg, (params, pool) + tail, pool
+
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     "verify_chunk_g"])
+def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
+        one_chip, fn_name):
+    """A 2,048-token chunk: the grouped matmul is in the program and no
+    ``[8, T, 14336]`` value is (all-experts-then-pick made two a layer, 470
+    MB each). A decode batch of 32 fits one tile of that call and keeps
+    every expert for every row (``modules._softmax_moe``): 7 MB a layer. In
+    all three the temporaries stay under one such intermediate of a chunk,
+    the pool is still aliased whole, nothing pool-shaped is copied, and the
+    counts leave beside the logits."""
+    cfg, args, pool = _mixtral_shapes(one_chip, fn_name)
+    assert cfg.moe.num_experts == 8 and cfg.base.dtype == jnp.bfloat16
+    compiled = getattr(gd, fn_name).lower(
+        *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+        attn_impl="kernel").compile()
+    text = compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    rows = args[2].shape[0]                     # 2,048 a chunk, 32 a batch
+    grouped = "ragged-dot" in text
+    every = re.search(r"\[8,%d,14336\]" % rows, text) is not None
+    assert (grouped, every) == ((False, True) if fn_name == "decode_step_g"
+                                else (True, False))
+    # 259 MB a chunk, 142 MB the verifier
+    assert stats.temp_size_in_bytes < 8 * 2048 * 14336 * 2
+    assert _pool_shaped_moves(text, pool) == []
     assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
