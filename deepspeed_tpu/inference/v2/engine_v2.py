@@ -6,9 +6,10 @@ forward; ``query``/``can_schedule`` gate admission on free KV blocks; the state
 manager + blocked KV cache hold per-sequence context.
 
 TPU adaptation: per step, the SplitFuse plan becomes (a) one bucketed
-``prefill_chunk`` call per admitted chunk and (b) one padded ``decode_step`` call
-for all running decodes — every shape from a small bucket ladder, so steady-state
-serving runs entirely from compiled programs.
+``prefill_chunk_g`` call per admitted chunk and (b) one padded
+``decode_step_g`` call for all running decodes (``generic_decode.py``) — every
+shape from a small bucket ladder, so steady-state serving runs entirely from
+compiled programs. What a KV page is, the engine leaves to ``kv_cache.py``.
 """
 
 import dataclasses
@@ -22,7 +23,7 @@ import numpy as np
 from deepspeed_tpu.inference.v2.generic_decode import (decode_step_g,
                                                        prefill_chunk_g,
                                                        verify_chunk_g)
-from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache, KVCacheConfig
+from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.kv_offload import (HostKVEntry, HostKVStore,
                                                    dequantize_pages,
                                                    quantize_pages)
@@ -56,7 +57,7 @@ class V2EngineConfig:
     greedy: bool = True            # back-compat; sampling is the full control
     sampling: SamplingConfig = dataclasses.field(default_factory=SamplingConfig)
     # attention implementation: auto (Pallas kernel on TPU, gather elsewhere),
-    # kernel, kernel_interpret, gather — see llama_decode._paged_attn
+    # kernel, kernel_interpret, gather — see kv_cache.ATTN_IMPLS
     attn_impl: str = "auto"
     # KV page dtype: "model" stores pages in the model compute dtype; "fp8"
     # stores float8_e4m3 pages — HALF the KV memory vs bf16 (2x capacity:
@@ -94,14 +95,6 @@ class InferenceEngineV2:
     policy registry picks the decode implementation from the model config
     type (reference: engine_factory + heuristics)."""
 
-    def _page_dtype(self, spec):
-        kinds = {"model": spec.dtype, "fp8": jnp.float8_e4m3fn}
-        kvd = self.config.kv_cache_dtype
-        if kvd not in kinds:
-            raise ValueError(f"unknown kv_cache_dtype {kvd!r}; one of "
-                             f"{sorted(kinds)}")
-        return kinds[kvd]
-
     def __init__(self, params, model_config,
                  config: Optional[V2EngineConfig] = None):
         self.params = params
@@ -116,14 +109,10 @@ class InferenceEngineV2:
                 "acceptance compares argmax chains, which sampling breaks")
         self.policy = policy_for(model_config)
         spec = self.policy.cache_spec(model_config)
-        self.kv = BlockedKVCache(KVCacheConfig(
-            num_layers=spec.num_layers,
-            num_kv_heads=spec.num_kv_heads,
-            head_dim=spec.head_dim,
+        self.kv = BlockedKVCache.for_spec(
+            spec, self.config.kv_cache_dtype,
             block_size=self.config.kv_block_size,
-            num_blocks=self.config.kv_num_blocks,
-            dtype=self._page_dtype(spec),
-            latent_dim=spec.latent_dim))
+            num_blocks=self.config.kv_num_blocks)
         self.state = StateManager(
             max_tracked_sequences=self.config.max_tracked_sequences,
             max_context_length=spec.max_seq_len)
@@ -627,37 +616,6 @@ class InferenceEngineV2:
         self._table_sig = None
         return entry.nbytes
 
-    def adopt_kv_handoff(self, uid: int, prompt_tokens: Sequence[int],
-                         generated: Sequence[int],
-                         entry: HostKVEntry) -> bool:
-        """In-process disaggregation adoption (serving/disagg.py): continue
-        a sequence whose KV a prefill-role engine demoted into a
-        ``HostKVEntry`` — create it here with its history, reserve device
-        blocks, scatter the dequantized pages, and let the planner pick it
-        up as a running decode. Prefix admission is bypassed: the prefill
-        work was done (and conservation-counted) on the donor engine.
-        Returns False with NOTHING mutated when this engine can't cover
-        the entry right now (capacity / slots / uid collision) — the
-        caller retries next tick. The PR 17 handoff-file path generalized
-        to in-process adoption: same codec round-trip, no filesystem."""
-        if uid in self.state or \
-                len(self.state) >= self.state.max_tracked_sequences or \
-                entry.blocks > self.kv.free_blocks + self._evictable_blocks():
-            return False
-        seq = self.state.create(uid, prompt_tokens)
-        seq.generated = list(generated)
-        blocks = self._reserve(entry.blocks)
-        if entry.blocks:
-            data = dequantize_pages(entry.data, entry.qscales, entry.codec,
-                                    np.dtype(np.float32)
-                                    if entry.codec != "none"
-                                    else entry.data.dtype)
-            self.kv.scatter_blocks(blocks, data, entry.scales)
-        seq.blocks = list(blocks)
-        seq.seen_tokens = int(entry.seen_tokens)
-        self._table_sig = None
-        return True
-
     # ------------------------------------------------------------------
     # fleet prefix handoff (drain-time export / adopt-time import)
     # ------------------------------------------------------------------
@@ -728,7 +686,7 @@ class InferenceEngineV2:
                 scales = z[f"scales_{i}"] if f"scales_{i}" in z.files else None
                 qscales = (z[f"qscales_{i}"] if f"qscales_{i}" in z.files
                            else None)
-                nb = int(stored.shape[self.kv.block_axis])
+                nb = self.kv.gathered_blocks(stored)
                 if (cache is None or bs != self.config.kv_block_size
                         or nb > self.kv.free_blocks):
                     out["skipped"] += 1

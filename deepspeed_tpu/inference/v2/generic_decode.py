@@ -3,25 +3,25 @@
 Reference analog: ``inference/v2/model_implementations/inference_transformer_base.py``
 — the shared ragged forward skeleton that per-arch containers plug into. Here the
 skeleton is jitted pure functions over (policy, config) static args; the
-policy (``modules.py``) contributes embed/block/unembed and the loop owns KV
-cache writes + the Pallas paged attention (``llama_decode._paged_attn``).
+policy (``modules.py``) contributes embed/block/unembed and the pool's page
+kind (``kv_cache.page_kind``) everything that knows what a page is: where a
+step's rows land, their write, and the attention over the pages. The loop
+binds the two: each layer's block is handed one ``attend``, which passes what
+the block computed on to the kind with the layer and the step's slots.
 
 Every phase of a step sits under a ``jax.named_scope`` whose name reaches the
-device trace (an operation's ``tf_op``): ``embed``, ``attn/kv_write``,
-``attn/paged`` and ``lm_head`` here, ``attn/qkv``, ``attn/out``, ``mlp``,
-``moe/router`` and ``moe/experts`` in the policies. Metadata only: the
-compiled program is the same.
+device trace (an operation's ``tf_op``): ``embed`` and ``lm_head`` here,
+``attn/kv_write``, ``attn/paged`` and the ``attn/latent_*`` scopes in the
+kinds, ``attn/qkv``, ``attn/out``, ``mlp``, ``moe/router`` and
+``moe/experts`` in the policies. Metadata only: the compiled program is the
+same.
 
-Over a latent cache (``KVCacheSpec.latent_dim``: one row a token, no heads)
-the block hands ``attend`` its queries in two parts, the token's row and the
-key-value up-projection, and the loop writes the row (``attn/latent_write``)
-and reads it the way its program needs: a chunk attends unfolded over keys
-and values up-projected from the gathered rows (``attn/latent_prefill``), a
-decode batch folded over the pages themselves (``attn/latent_q``,
-``attn/latent_paged``). A block returns ``(x, counts or None)``; the three
-step programs return ``(logits, cache, counts)``, the counts one int32 vector
-of ``telemetry/names.py`` ``STEP_COUNTER_ARGS`` summed over the layers that
+A block returns ``(x, counts or None)``; the three step programs return
+``(logits, cache, counts)``, the counts one int32 vector of
+``telemetry/names.py`` ``STEP_COUNTER_ARGS`` summed over the layers that
 count (sums over what a router has anyway), and empty where none does.
+``cache_data`` is whatever the kind's pool is (``BlockedKVCache.pool``): the
+structure that goes in comes out.
 """
 
 from functools import partial
@@ -29,16 +29,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.kv_cache import (write_kv, write_kv_scaled,
-                                                write_latent)
-from deepspeed_tpu.inference.v2.llama_decode import (_latent_paged_attn,
-                                                     _latent_prefill_attn,
-                                                     _paged_attn)
-
-
-def _trash_block(pool, spec):
-    """The pool's last block, where padding rows are written."""
-    return pool.shape[1 if spec.latent_dim else 3] - 1
+from deepspeed_tpu.inference.v2.kv_cache import page_kind
 
 
 def _summed(counted):
@@ -51,37 +42,19 @@ def _summed(counted):
 
 def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
                   policy, cfg, block_size: int, attn_impl: str):
-    """Shared chunk forward: embeds a bucket-padded token chunk, scatters
-    each layer's K/V into the pages, attends over the paged context, and
-    returns (per-row hidden states [Tb, D], updated cache, the counts handed
-    out). ``cache_data`` may be the plain page pool [L, 2, H, NB, bs, D], a
-    ``(pages, scales)`` tuple for scaled fp8 pages
-    (``BlockedKVCache.scales``), or a latent pool [L, NB, bs, W]."""
+    """Shared chunk forward: embeds a bucket-padded token chunk, has the page
+    kind write each layer's new rows into the pages and attend over the paged
+    context, and returns (per-row hidden states [Tb, D], updated cache, the
+    counts handed out)."""
     spec = policy.cache_spec(cfg)
+    kind = page_kind(spec, cache_data)
     tb = tokens.shape[0]
-    mb = block_table.shape[0]
-    scaled = isinstance(cache_data, tuple)
-    pool = cache_data[0] if scaled else cache_data
 
     positions = start + jnp.arange(tb)
     safe_pos = jnp.minimum(positions, spec.max_seq_len - 1)
     valid = jnp.arange(tb) < true_len
-    tok_block = jnp.where(valid,
-                          block_table[jnp.minimum(safe_pos // block_size, mb - 1)],
-                          _trash_block(pool, spec))
-    tok_off = safe_pos % block_size
-    touched = None
-    if scaled:
-        # pages the chunk's valid tokens can land on: a contiguous table
-        # slice (clamp duplicates repeat the same slot — identical updates,
-        # safe for write_kv_scaled's requantize scatter). Static worst-case
-        # page count: offsets start%bs .. start%bs+tb-1 span up to
-        # (tb + bs - 2)//bs + 1 pages — a chunk smaller than a page that
-        # crosses a boundary still touches TWO pages (tb//bs+1 missed that)
-        touch_idx = jnp.minimum(
-            start // block_size +
-            jnp.arange((tb + block_size - 2) // block_size + 1), mb - 1)
-        touched = block_table[touch_idx]
+    slots = kind.chunk_slots(cache_data, block_table, start, safe_pos, valid,
+                             block_size)
 
     with jax.named_scope("embed"):
         x = policy.embed(params, tokens, safe_pos, cfg)
@@ -89,40 +62,12 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
     cache = cache_data
     counted = []
     for i in range(spec.num_layers):
-        def attend_latent(q_nope, q_rope, row, w_ukv, scale, i=i):
+        def attend(*computed, i=i, **how):
             nonlocal cache
-            with jax.named_scope("attn/latent_write"):
-                cache = write_latent(cache, i, row, tok_block, tok_off)
-            with jax.named_scope("attn/latent_prefill"):
-                return _latent_prefill_attn(q_nope, q_rope, cache, i,
-                                            block_table, start, w_ukv, scale,
-                                            attn_impl)
-
-        def attend(q, k, v, i=i, window="spec", softcap=None):
-            nonlocal cache
-            win = spec.window if window == "spec" else window
-            if scaled:
-                data, scales = cache
-                with jax.named_scope("attn/kv_write"):
-                    data, scales = write_kv_scaled(
-                        data, scales, i, 0, k, tok_block, tok_off, touched)
-                    data, scales = write_kv_scaled(
-                        data, scales, i, 1, v, tok_block, tok_off, touched)
-                cache = (data, scales)
-                with jax.named_scope("attn/paged"):
-                    return _paged_attn(q[None], data, i, block_table[None],
-                                       jnp.asarray(start).reshape(1), win,
-                                       attn_impl, softcap=softcap,
-                                       scales=scales)[0]
-            with jax.named_scope("attn/kv_write"):
-                cache = write_kv(cache, i, k, v, tok_block, tok_off)
-            with jax.named_scope("attn/paged"):
-                return _paged_attn(q[None], cache, i, block_table[None],
-                                   jnp.asarray(start).reshape(1), win,
-                                   attn_impl, softcap=softcap)[0]
-        x, counts = policy.block(
-            params, i, x, attend_latent if spec.latent_dim else attend,
-            safe_pos, cfg, valid)
+            out, cache = kind.attend_chunk(cache, i, slots, block_table,
+                                           start, attn_impl, *computed, **how)
+            return out
+        x, counts = policy.block(params, i, x, attend, safe_pos, cfg, valid)
         if counts is not None:
             counted.append(counts)
     return x, cache, _summed(counted)
@@ -132,10 +77,10 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
          donate_argnames=("cache_data",))
 def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
                     policy, cfg, block_size: int, attn_impl: str = "auto"):
-    """One sequence, one bucket-padded chunk; returns (last-token logits [V],
-    updated cache_data, counts: module docstring). See llama_decode.prefill_chunk for the argument
-    contract — this is the arch-generic version; cache structure in ==
-    structure out (plain pool or (pages, scales))."""
+    """One sequence, one chunk. tokens: [Tb] (bucket-padded); start: the
+    chunk's offset in the sequence; block_table: [MB] block ids
+    (trash-padded); true_len: real chunk tokens. Returns (last-token logits
+    [V], updated cache_data, counts: module docstring)."""
     x, cache, counts = _chunk_states(params, cache_data, tokens, start,
                                      block_table, true_len, policy, cfg,
                                      block_size, attn_impl)
@@ -168,23 +113,15 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
          donate_argnames=("cache_data",))
 def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
                   policy, cfg, block_size: int, attn_impl: str = "auto"):
-    """Batched single-token decode; returns (logits [B, V], updated
-    cache_data, counts: module docstring).
-    See llama_decode.decode_step for the argument contract.
-    ``cache_data``: plain pool or ``(pages, scales)`` like prefill_chunk_g."""
+    """Batched single-token decode. tokens/positions/valid: [B] (the rows
+    that are batch padding not ``valid``); block_tables: [B, MB]. Returns
+    (logits [B, V], updated cache_data, counts: module docstring)."""
     spec = policy.cache_spec(cfg)
-    mb = block_tables.shape[1]
-    scaled = isinstance(cache_data, tuple)
-    pool = cache_data[0] if scaled else cache_data
+    kind = page_kind(spec, cache_data)
 
     safe_pos = jnp.minimum(positions, spec.max_seq_len - 1)
-    blk = jnp.where(valid,
-                    jnp.take_along_axis(
-                        block_tables,
-                        jnp.minimum(safe_pos // block_size, mb - 1)[:, None],
-                        axis=1)[:, 0],
-                    _trash_block(pool, spec))
-    off = safe_pos % block_size
+    slots = kind.decode_slots(cache_data, block_tables, safe_pos, valid,
+                              block_size)
 
     with jax.named_scope("embed"):
         x = policy.embed(params, tokens, safe_pos, cfg)
@@ -192,39 +129,13 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
     cache = cache_data
     counted = []
     for i in range(spec.num_layers):
-        def attend_latent(q_nope, q_rope, row, w_ukv, scale, i=i):
+        def attend(*computed, i=i, **how):
             nonlocal cache
-            with jax.named_scope("attn/latent_write"):
-                cache = write_latent(cache, i, row, blk, off)
-            return _latent_paged_attn(q_nope, q_rope, cache, i, block_tables,
-                                      safe_pos, w_ukv, scale, attn_impl)
-
-        def attend(q, k, v, i=i, window="spec", softcap=None):
-            nonlocal cache
-            win = spec.window if window == "spec" else window
-            if scaled:
-                # each token touches exactly its own page (invalid rows all
-                # write the trash page with identical per-page updates)
-                data, scales = cache
-                with jax.named_scope("attn/kv_write"):
-                    data, scales = write_kv_scaled(data, scales, i, 0, k,
-                                                   blk, off, blk)
-                    data, scales = write_kv_scaled(data, scales, i, 1, v,
-                                                   blk, off, blk)
-                cache = (data, scales)
-                with jax.named_scope("attn/paged"):
-                    return _paged_attn(q[:, None], data, i, block_tables,
-                                       safe_pos, win, attn_impl,
-                                       softcap=softcap, scales=scales)[:, 0]
-            with jax.named_scope("attn/kv_write"):
-                cache = write_kv(cache, i, k, v, blk, off)
-            with jax.named_scope("attn/paged"):
-                return _paged_attn(q[:, None], cache, i, block_tables,
-                                   safe_pos, win, attn_impl,
-                                   softcap=softcap)[:, 0]
-        x, counts = policy.block(
-            params, i, x, attend_latent if spec.latent_dim else attend,
-            safe_pos, cfg, valid)
+            out, cache = kind.attend_decode(cache, i, slots, block_tables,
+                                            safe_pos, attn_impl, *computed,
+                                            **how)
+            return out
+        x, counts = policy.block(params, i, x, attend, safe_pos, cfg, valid)
         if counts is not None:
             counted.append(counts)
 
@@ -235,8 +146,9 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
 
 # compile-event ledger: every XLA compile of the serving step fns emits an
 # ``xla/compile`` instant (fn + shape signature + wall ms) and bumps the
-# process compile counter — bench_serve asserts ZERO compiles inside the
-# measured window after warmup (telemetry/compiles.py)
+# process compile counter (``telemetry/compiles.py`` ``compiles_total``),
+# which the benchmark reads around its window: after the warm-up there are
+# to be none inside it
 from deepspeed_tpu.telemetry.compiles import watch_jit  # noqa: E402
 from deepspeed_tpu.telemetry.tracer import get_tracer  # noqa: E402
 

@@ -1,14 +1,26 @@
-"""Paged (blocked) KV cache on device.
+"""Paged (blocked) KV cache on device, and what a page of it is.
 
 Reference analog: ``deepspeed/inference/v2/ragged/kv_cache.py:40``
 (``BlockedKVCache``) — a pool of fixed-size KV blocks per layer, reserved through a
-``BlockedAllocator``. TPU layout is **head-major**
-[kv_heads, num_blocks, block_size, head_dim], so one page of one KV head is a
-contiguous (block_size, head_dim) tile — the shape the Pallas paged-attention
-kernel DMAs per grid step (``ops/pallas/paged_attention.py``); shard over
-``tensor`` on the leading heads dim. Block writes are ``.at[].set`` scatters
-inside the jitted step; reads either go through the kernel (block table in
-scalar prefetch) or gather a contiguous context window (CPU fallback).
+``BlockedAllocator``.
+
+The pool's format is a *page kind*, chosen once from the cache's config and
+found again inside a step program from the policy's spec and the pool handed
+in (``page_kind``): head-major K and V planes (``_HeadPages``), the same in
+fp8 under per-(head, page) scales (``_ScaledHeadPages``), one headless
+latent plane (``_LatentPages``). A kind owns, and nothing outside this
+module knows: the pool's shape and block axis, the trash block, whether the
+step programs carry an array or ``(pages, scales)``, the slots a step's rows
+land in, their write, and the chunk and decode attention over its pages,
+Pallas kernel or gather path (``attn_impl``). The served loop
+(``generic_decode.py``) hands a kind the step's positions and block tables
+and each layer's ``attend`` arguments. A further kind is a class here and
+the policy that calls its ``attend``.
+
+Padding rows are written to the trash block (the pool's last, never handed
+out by the allocator), so the write path needs no mask; on the read path
+causal masking doubles as padding masking: a gathered position at or past
+the context length can never satisfy qpos >= kpos.
 """
 
 import dataclasses
@@ -20,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
+from deepspeed_tpu.ops.pallas.paged_attention import (
+    paged_attention_pool, paged_attention_reference)
 
 
 @dataclasses.dataclass
@@ -53,30 +67,29 @@ class LatentPageDtypeError(ValueError):
 class BlockedKVCache:
     def __init__(self, cfg: KVCacheConfig):
         self.cfg = cfg
-        # last block reserved as the trash target for padding-token writes
-        # (see llama_decode.py); never handed out by the allocator
+        self.kind = _kind_for(cfg.latent_dim,
+                              cfg.dtype == jnp.float8_e4m3fn)()
+        # the last block is the kind's trash block, the target of
+        # padding-token writes: never handed out by the allocator
         self.allocator = BlockedAllocator(cfg.num_blocks - 1)
-        # [L, 2(kv), H_kv, num_blocks, block_size, D] (head-major pages), or
-        # for a latent cache one plane [L, num_blocks, block_size, W]
-        self.block_axis = 1 if cfg.latent_dim else 3
-        if cfg.latent_dim and cfg.dtype == jnp.float8_e4m3fn:
-            raise LatentPageDtypeError(
-                "fp8 scaled pages are not supported over a latent cache: "
-                "their per-(head, page) scales and K and V planes have no "
-                "place on one headless plane; use kv_cache_dtype='model'")
-        self.data = jnp.zeros(
-            (cfg.num_layers, cfg.num_blocks, cfg.block_size,
-             latent_row_width(cfg.latent_dim)) if cfg.latent_dim else
-            (cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks,
-             cfg.block_size, cfg.head_dim), cfg.dtype)
-        # fp8 pages carry a per-(layer, k/v, head, page) fp32 scale: stored
-        # value = real / scale, grown monotonically as outliers arrive (the
-        # whole page is requantized under the new scale on growth). The
-        # reference fp quantizer is group-scaled the same way
-        # (csrc/fp_quantizer/fp_quantize.cu, group absmax).
-        self.scales = (jnp.ones(
-            (cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks),
-            jnp.float32) if cfg.dtype == jnp.float8_e4m3fn else None)
+        # ``scales`` is None unless the kind's pages carry them
+        self.data, self.scales = self.kind.new_pool(cfg)
+
+    @classmethod
+    def for_spec(cls, spec, kv_cache_dtype: str, block_size: int,
+                 num_blocks: int) -> "BlockedKVCache":
+        """The cache a policy's ``KVCacheSpec`` asks for, its pages stored as
+        the engine's ``kv_cache_dtype`` says: ``"model"`` (the spec's compute
+        dtype) or ``"fp8"`` (float8_e4m3 pages under scales)."""
+        dtypes = {"model": spec.dtype, "fp8": jnp.float8_e4m3fn}
+        if kv_cache_dtype not in dtypes:
+            raise ValueError(f"unknown kv_cache_dtype {kv_cache_dtype!r}; "
+                             f"one of {sorted(dtypes)}")
+        return cls(KVCacheConfig(
+            num_layers=spec.num_layers, num_kv_heads=spec.num_kv_heads,
+            head_dim=spec.head_dim, block_size=block_size,
+            num_blocks=num_blocks, dtype=dtypes[kv_cache_dtype],
+            latent_dim=spec.latent_dim))
 
     @property
     def pool(self):
@@ -130,15 +143,19 @@ class BlockedKVCache:
     def gather_blocks(self, blocks: List[int]
                       ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Copy the listed blocks' pages (and, for fp8, their scales) to
-        host ndarrays ``[L, 2, H_kv, len(blocks), bs, D]`` (a latent pool:
-        ``[L, len(blocks), bs, W]``). A deliberate
+        host ndarrays: the pool's shape with ``len(blocks)`` on its block
+        axis. A deliberate
         device->host transfer — demotion runs OFF the per-tick fast path,
         only when the serving tier policy decides to spill."""
         idx = np.asarray(blocks, np.int32)
-        data = np.asarray(jnp.take(self.data, idx, axis=self.block_axis))
+        data = np.asarray(jnp.take(self.data, idx, axis=self.kind.block_axis))
         scales = (np.asarray(self.scales[:, :, :, idx])
                   if self.scales is not None else None)
         return data, scales
+
+    def gathered_blocks(self, data: np.ndarray) -> int:
+        """How many blocks a ``gather_blocks`` payload holds."""
+        return int(data.shape[self.kind.block_axis])
 
     def scatter_blocks(self, blocks: List[int], data: np.ndarray,
                        scales: Optional[np.ndarray] = None) -> None:
@@ -149,7 +166,7 @@ class BlockedKVCache:
         idx = jnp.asarray(np.asarray(blocks, np.int32))
         self.data = _set_blocks(self.data, idx,
                                 jnp.asarray(data, self.cfg.dtype),
-                                self.block_axis)
+                                self.kind.block_axis)
         if self.scales is not None and scales is not None:
             self.scales = _set_blocks(self.scales, idx,
                                       jnp.asarray(scales, jnp.float32))
@@ -260,3 +277,281 @@ def write_latent(cache_data, layer: int, rows, block_ids, offsets):
         rows = jnp.pad(rows, ((0, 0), (0, pad)))
     return cache_data.at[layer, block_ids, offsets].set(
         cast_to_page_dtype(rows, cache_data.dtype))
+
+
+# --- attention over the pages: kernel or gather path --------------------
+ATTN_IMPLS = ("auto", "kernel", "kernel_interpret", "gather")
+
+
+def _resolve_impl(attn_impl: str) -> str:
+    """``auto`` to the Pallas kernel on a TPU and the gather path elsewhere."""
+    if attn_impl not in ATTN_IMPLS:
+        raise ValueError(f"unknown attn_impl {attn_impl!r}; one of {ATTN_IMPLS}")
+    if attn_impl == "auto":
+        return "kernel" if jax.default_backend() == "tpu" else "gather"
+    return attn_impl
+
+
+def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
+                       w_ukv, scale, attn_impl: str):
+    """Decode over a latent pool [L, NB, bs, W], one token a sequence, with
+    the up-projections folded: ``q~_i = W_uk_i^T q_nope_i`` scores against
+    the cached rows themselves and ``W_uv_i`` is applied to the summed rows
+    (``ops/pallas/latent_attention.py``). q_nope: [B, H, d_n]; q_rope:
+    [B, H, d_r], rotated; w_ukv: [rank, H, d_n + d_v]. Returns [B, H, d_v].
+    Kernel against gather path, as ``_HeadPages._read``."""
+    from deepspeed_tpu.ops.pallas.latent_attention import (
+        latent_paged_attention, latent_paged_attention_reference)
+    rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
+    with jax.named_scope("attn/latent_q"):
+        q = jnp.concatenate(
+            [jnp.einsum("bhk,rhk->bhr", q_nope, w_ukv[..., :d_n]), q_rope], -1)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
+    impl = _resolve_impl(attn_impl)
+    with jax.named_scope("attn/latent_paged"):
+        if impl == "gather":
+            o = latent_paged_attention_reference(
+                q, pool[layer], block_tables, positions, scale, rank)
+        else:
+            o = latent_paged_attention(
+                q, pool, layer, block_tables, positions, scale, rank,
+                interpret=impl == "kernel_interpret")
+        return jnp.einsum("bhr,rhv->bhv", o, w_ukv[..., d_n:])
+
+
+def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
+                         w_ukv, scale, attn_impl: str):
+    """One sequence's chunk over a latent pool, unfolded: the context's rows
+    (the chunk's own, already written, among them) are gathered from the
+    pages and every head's keys and values up-projected from them, 2 x (192 +
+    128) operations a pair a head at the published sizes where the folded
+    form costs 2 x (576 + 512). q_nope: [T, H, d_n]; q_rope: [T, H, d_r],
+    rotated; block_table: [MB]; start: the chunk's first position. Returns
+    [T, H, d_v]."""
+    from deepspeed_tpu.ops.pallas.latent_attention import (
+        PREFILL_BLOCK_K, latent_prefill_attention,
+        latent_prefill_attention_reference)
+    rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
+    d_r = q_rope.shape[-1]
+    nb, bs = pool.shape[1], pool.shape[2]
+    # whole key blocks for the kernel: dead slots read the trash page, which
+    # no query's horizon reaches
+    mb = block_table.shape[0]
+    keys = mb * bs
+    if keys > PREFILL_BLOCK_K:
+        keys = -(-keys // PREFILL_BLOCK_K) * PREFILL_BLOCK_K
+    table = jnp.pad(block_table, (0, -(-keys // bs) - mb),
+                    constant_values=nb - 1)
+    rows = pool[layer, table].reshape(-1, pool.shape[-1])[:keys]
+    # keys and values each from their own half of the up-projection: one
+    # product sliced afterwards is two more copies of the context
+    ckv = rows[:, :rank]
+    args = (q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2),
+            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., :d_n]),
+            rows[:, rank:rank + d_r],
+            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., d_n:]), start, scale)
+    impl = _resolve_impl(attn_impl)
+    if impl == "gather":
+        out = latent_prefill_attention_reference(*args)
+    else:
+        out = latent_prefill_attention(*args,
+                                       interpret=impl == "kernel_interpret")
+    return out.transpose(1, 0, 2)
+
+
+# --- the page kinds ------------------------------------------------------
+class _Pages:
+    """What the kinds share: the trash block and the slots a step's rows land
+    in, one (block, offset) a row through the sequence's block table."""
+    block_axis: int
+
+    def __init__(self, window=None):
+        self.window = window
+
+    def trash_block(self, cache) -> int:
+        """The pool's last block, where padding rows are written. The pages
+        are the first leaf of what the step programs carry."""
+        return jax.tree.leaves(cache)[0].shape[self.block_axis] - 1
+
+    def chunk_slots(self, cache, block_table, start, safe_pos, valid,
+                    block_size: int):
+        """Where one sequence's chunk lands: each row's (block, offset), the
+        trash block for the rows that are bucket padding. block_table: [MB];
+        start: the chunk's first position; safe_pos, valid: [T]."""
+        mb = block_table.shape[0]
+        blk = jnp.where(
+            valid, block_table[jnp.minimum(safe_pos // block_size, mb - 1)],
+            self.trash_block(cache))
+        return blk, safe_pos % block_size
+
+    def decode_slots(self, cache, block_tables, safe_pos, valid,
+                     block_size: int):
+        """Where a decode batch's tokens land, one a sequence. block_tables:
+        [B, MB]; safe_pos, valid: [B]."""
+        mb = block_tables.shape[1]
+        blk = jnp.where(
+            valid,
+            jnp.take_along_axis(
+                block_tables,
+                jnp.minimum(safe_pos // block_size, mb - 1)[:, None],
+                axis=1)[:, 0],
+            self.trash_block(cache))
+        return blk, safe_pos % block_size
+
+
+class _HeadPages(_Pages):
+    """K and V planes of head-major pages, ``[L, 2, H_kv, NB, bs, D]``: one
+    page of one KV head is a contiguous (bs, D) tile, the shape the paged
+    kernel DMAs per grid step (``ops/pallas/paged_attention.py``); shard over
+    ``tensor`` on the heads. A block hands its ``attend`` ``(q, k, v,
+    window=, softcap=)``: q [N, H, D], k and v [N, H_kv, D], one row a token;
+    ``window`` left out is the spec's."""
+    block_axis = 3
+
+    @staticmethod
+    def new_pool(cfg: KVCacheConfig):
+        """``(pages, scales or None)`` of an empty pool."""
+        return jnp.zeros((cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks,
+                          cfg.block_size, cfg.head_dim), cfg.dtype), None
+
+    def _write(self, cache, layer, k, v, slots):
+        return write_kv(cache, layer, k, v, *slots)
+
+    def _read(self, pages, layer, q, block_tables, start_pos, attn_impl,
+              window="spec", softcap=None, scales=None):
+        """q: [B, T, H, D]; kernel or gather reference, both with ``softcap``
+        (gemma2) and, for fp8 pages, ``scales`` applied per (head, page) on
+        load."""
+        window = self.window if window == "spec" else window
+        impl = _resolve_impl(attn_impl)
+        if impl == "gather":
+            ks, vs = (scales[layer, 0], scales[layer, 1]) \
+                if scales is not None else (None, None)
+            return paged_attention_reference(
+                q, pages[layer, 0], pages[layer, 1], block_tables, start_pos,
+                window=window, softcap=softcap, k_scales=ks, v_scales=vs)
+        # the kernel takes the pool whole: a slice of it is a copy of it
+        return paged_attention_pool(
+            q, pages, layer, block_tables, start_pos, window=window,
+            softcap=softcap, scales=scales,
+            interpret=impl == "kernel_interpret")
+
+    def attend_chunk(self, cache, layer, slots, block_table, start,
+                     attn_impl, q, k, v, **how):
+        """Write one chunk's rows, then attend over the sequence's pages from
+        position ``start``. Returns (out [T, H, D], the pool)."""
+        with jax.named_scope("attn/kv_write"):
+            cache = self._write(cache, layer, k, v, slots)
+        with jax.named_scope("attn/paged"):
+            return self._read(cache, layer, q[None], block_table[None],
+                              jnp.asarray(start).reshape(1), attn_impl,
+                              **how)[0], cache
+
+    def attend_decode(self, cache, layer, slots, block_tables, positions,
+                      attn_impl, q, k, v, **how):
+        """Write one token a sequence, then attend over each sequence's
+        pages. Returns (out [B, H, D], the pool)."""
+        with jax.named_scope("attn/kv_write"):
+            cache = self._write(cache, layer, k, v, slots)
+        with jax.named_scope("attn/paged"):
+            return self._read(cache, layer, q[:, None], block_tables,
+                              positions, attn_impl, **how)[:, 0], cache
+
+
+class _ScaledHeadPages(_HeadPages):
+    """Head pages in fp8 under per-(layer, k/v, head, page) fp32 scales
+    ``[L, 2, H_kv, NB]``: stored value = real / scale, the scale grown
+    monotonically as outliers arrive and the whole page requantized under
+    it (``write_kv_scaled``; the reference fp quantizer is group-scaled the
+    same way, csrc/fp_quantizer/fp_quantize.cu, group absmax). The step
+    programs carry ``(pages, scales)`` and both attention paths dequantize
+    on load."""
+
+    @staticmethod
+    def new_pool(cfg: KVCacheConfig):
+        return _HeadPages.new_pool(cfg)[0], jnp.ones(
+            (cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks), jnp.float32)
+
+    def chunk_slots(self, cache, block_table, start, safe_pos, valid,
+                    block_size: int):
+        # and the pages the chunk's valid tokens can land on: a contiguous
+        # table slice (clamp duplicates repeat the same slot — identical
+        # updates, safe for write_kv_scaled's requantize scatter). Static
+        # worst-case page count: offsets start%bs .. start%bs+tb-1 span up to
+        # (tb + bs - 2)//bs + 1 pages — a chunk smaller than a page that
+        # crosses a boundary still touches TWO pages (tb//bs+1 missed that)
+        blk, off = super().chunk_slots(cache, block_table, start, safe_pos,
+                                       valid, block_size)
+        tb, mb = safe_pos.shape[0], block_table.shape[0]
+        touch_idx = jnp.minimum(
+            start // block_size +
+            jnp.arange((tb + block_size - 2) // block_size + 1), mb - 1)
+        return blk, off, block_table[touch_idx]
+
+    def decode_slots(self, cache, block_tables, safe_pos, valid,
+                     block_size: int):
+        # each token touches exactly its own page (invalid rows all write
+        # the trash page with identical per-page updates)
+        blk, off = super().decode_slots(cache, block_tables, safe_pos, valid,
+                                        block_size)
+        return blk, off, blk
+
+    def _write(self, cache, layer, k, v, slots):
+        data, scales = cache
+        for kv, new in enumerate((k, v)):
+            data, scales = write_kv_scaled(data, scales, layer, kv, new,
+                                           *slots)
+        return data, scales
+
+    def _read(self, cache, *args, **how):
+        return super()._read(cache[0], *args, scales=cache[1], **how)
+
+
+class _LatentPages(_Pages):
+    """One headless plane ``[L, NB, bs, W]`` of latent (MLA) rows, ``[ckv ;
+    k_rope]`` and zero lanes up to ``latent_row_width``: all of a row the key
+    of every query head, its leading compressed part the value. A block hands
+    its ``attend`` ``(q_nope, q_rope, row, w_ukv, scale)``, one row a token
+    and the key-value up-projection; a chunk attends unfolded over keys and
+    values up-projected from the gathered rows, a decode batch folded over
+    the pages themselves."""
+    block_axis = 1
+
+    @staticmethod
+    def new_pool(cfg: KVCacheConfig):
+        if cfg.dtype == jnp.float8_e4m3fn:
+            raise LatentPageDtypeError(
+                "fp8 scaled pages are not supported over a latent cache: "
+                "their per-(head, page) scales and K and V planes have no "
+                "place on one headless plane; use kv_cache_dtype='model'")
+        return jnp.zeros((cfg.num_layers, cfg.num_blocks, cfg.block_size,
+                          latent_row_width(cfg.latent_dim)), cfg.dtype), None
+
+    def attend_chunk(self, cache, layer, slots, block_table, start,
+                     attn_impl, q_nope, q_rope, row, w_ukv, scale):
+        with jax.named_scope("attn/latent_write"):
+            cache = write_latent(cache, layer, row, *slots)
+        with jax.named_scope("attn/latent_prefill"):
+            return _latent_prefill_attn(q_nope, q_rope, cache, layer,
+                                        block_table, start, w_ukv, scale,
+                                        attn_impl), cache
+
+    def attend_decode(self, cache, layer, slots, block_tables, positions,
+                      attn_impl, q_nope, q_rope, row, w_ukv, scale):
+        with jax.named_scope("attn/latent_write"):
+            cache = write_latent(cache, layer, row, *slots)
+        return _latent_paged_attn(q_nope, q_rope, cache, layer, block_tables,
+                                  positions, w_ukv, scale, attn_impl), cache
+
+
+def _kind_for(latent_dim: int, scaled: bool) -> type:
+    if latent_dim:
+        return _LatentPages
+    return _ScaledHeadPages if scaled else _HeadPages
+
+
+def page_kind(spec, cache):
+    """The kind of the pool a step program was handed, from the policy's
+    ``KVCacheSpec`` and the pool's structure: ``(pages, scales)`` or pages
+    alone."""
+    return _kind_for(spec.latent_dim, isinstance(cache, tuple))(spec.window)

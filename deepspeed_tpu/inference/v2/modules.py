@@ -6,9 +6,9 @@ and ``model_implementations/{llama_v2,mistral,mixtral,opt,phi3,qwen_v2,falcon}``
 
 TPU shape: a *policy* is a small class of pure static methods over the training
 model's param pytree — no module surgery, no containers. The generic paged
-serving loop (``generic_decode.py``) owns the KV cache, block tables, and the
-Pallas paged-attention call; the policy contributes exactly the three
-arch-specific pieces:
+serving loop (``generic_decode.py``) owns the step, the pool's page kind
+(``kv_cache.py``) the cache writes, block tables and paged attention; the
+policy contributes exactly the three arch-specific pieces:
 
 - ``embed(params, tokens, positions, cfg)``          -> [N, D] hidden states
 - ``block(params, i, x, attend, positions, cfg, valid)`` -> ([N, D], counts)
@@ -19,8 +19,9 @@ arch-specific pieces:
   over the layers and hands out beside the logits)
 - ``unembed(params, x, cfg)``                        -> [N, V] fp32 logits
 
-plus ``cache_spec(cfg)`` so the engine can size the paged KV pool, and says
-what a page is. Over a latent cache (``latent_dim``) the block calls
+plus ``cache_spec(cfg)`` so the engine can size the paged KV pool, and which
+selects the page kind. What ``attend`` takes is the kind's contract: over a
+latent cache (``latent_dim``) the block calls
 ``attend(q_nope, q_rope, row, w_ukv, scale)`` with one row a token. Policies are
 keyed both by name and by config dataclass type; ``policy_for`` is the
 heuristic (reference heuristics.py) that picks the implementation for a model
@@ -684,7 +685,7 @@ from deepspeed_tpu.models.joyai_llm_flash import (  # noqa: E402
 @register_policy("joyai_llm_flash", JoyAIFlashConfig)
 class JoyAIFlashPolicy:
     """models/joyai_llm_flash.py's serving twin. The page row of a token is
-    ``[RMS(c_kv) ; rope(k_r)]``; the loop (``generic_decode``) reads it
+    ``[RMS(c_kv) ; rope(k_r)]``; the latent page kind (``kv_cache``) reads it
     unfolded in a prefill chunk and folded in a decode batch. The chosen
     experts alone compute (``moe/grouped_experts.py``), and the step programs
     hand out how many rows they took and how many experts they touched."""

@@ -287,24 +287,6 @@ class TickLedger:
         when the request was never attributed or already aged out)."""
         return self.request_ticks.pop(uid, None)
 
-    def merge_from(self, other: "TickLedger") -> None:
-        """Fold another ledger in (the disaggregated pair sums its role
-        engines' ledgers into one proof set)."""
-        self.ticks += other.ticks
-        self.prefill_ticks += other.prefill_ticks
-        self.decode_ticks += other.decode_ticks
-        self.chunk_tokens_total += other.chunk_tokens_total
-        self.chunks_total += other.chunks_total
-        self.decode_tokens_total += other.decode_tokens_total
-        self.capped_chunk_ticks += other.capped_chunk_ticks
-        self.window_prefill_ticks += other.window_prefill_ticks
-        self.window_chunk_tokens += other.window_chunk_tokens
-        self.max_prefill_tokens_per_tick = max(
-            self.max_prefill_tokens_per_tick,
-            other.max_prefill_tokens_per_tick)
-        self.max_decode_stall_tokens = max(
-            self.max_decode_stall_tokens, other.max_decode_stall_tokens)
-
     def snapshot(self, cap: int = 0, gap_unit_tokens: int = 0
                  ) -> Dict[str, Any]:
         """The scheduler proof set. ``max_decode_gap_ticks`` states the

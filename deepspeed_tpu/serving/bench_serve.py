@@ -852,11 +852,10 @@ def build_tiny_server(kv_num_blocks: int = 64, kv_block_size: int = 16,
     model = LlamaForCausalLM(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         {"input_ids": np.zeros((1, 8), np.int32)})["params"]
-    v2cfg = V2EngineConfig(
+    engine = InferenceEngineV2(params, cfg, V2EngineConfig(
         kv_block_size=kv_block_size, kv_num_blocks=kv_num_blocks,
         scheduler=SchedulerConfig(max_tokens_per_step=64,
-                                  prefill_buckets=(16, 32, 64)))
-    engine = InferenceEngineV2(params, cfg, v2cfg)
+                                  prefill_buckets=(16, 32, 64))))
     overrides = {"max_queue_depth": 32, "kv_offload_enabled": kv_offload,
                  "kv_demote_watermark": 0.5,
                  "kv_demote_watermark_brownout": 0.3,
@@ -865,15 +864,6 @@ def build_tiny_server(kv_num_blocks: int = 64, kv_block_size: int = 16,
                                       else "none"),
                  "idle_poll_s": 0.001}
     overrides.update(serving_overrides or {})
-    sched_group = dict((serving_overrides or {}).get("scheduler") or {})
-    if sched_group.get("role_split"):
-        # prefill-role/decode-role pair sharing the tiny params; each role
-        # gets its own KV pool at the configured geometry, and the server
-        # drives the pair through the single-engine surface
-        from deepspeed_tpu.serving.disagg import DisaggregatedEngine
-        engine = DisaggregatedEngine(
-            engine, InferenceEngineV2(params, cfg, v2cfg),
-            handoff_quantize=sched_group.get("handoff_quantize", "none"))
     return InferenceServer(engine, ServingConfig(**overrides))
 
 

@@ -31,8 +31,6 @@ REQ_HIST_FAMILIES = (
      "time per output token (decode phase)"),
     ("dstpu_req_queue_wait_seconds", "hist_queue_wait",
      "admission queue wait"),
-    ("dstpu_req_handoff_seconds", "hist_handoff",
-     "prefill->decode KV handoff latency (role-split engines)"),
 )
 
 
@@ -123,7 +121,6 @@ class ServingMetrics:
         self.hist_ttft = dshist.LogHistogram()
         self.hist_tpot = dshist.LogHistogram()
         self.hist_queue_wait = dshist.LogHistogram()
-        self.hist_handoff = dshist.LogHistogram()
         # gauges (set each serve-loop tick)
         self.queue_depth = 0
         self.inflight = 0
@@ -180,12 +177,6 @@ class ServingMetrics:
                 self.tpot.add(req.tpot_s)
                 self.hist_tpot.observe(req.tpot_s)
         self.request_rate.add(1)
-
-    def on_handoff_latency(self, lat_s: float):
-        """Fold one completed prefill->decode KV handoff's latency in
-        (role-split engines; the serve loop drains these each tick)."""
-        with self._lock:
-            self.hist_handoff.observe(lat_s)
 
     def set_gauges(self, queue_depth: int, inflight: int, kv_occupancy: float):
         with self._lock:

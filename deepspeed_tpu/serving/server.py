@@ -58,7 +58,7 @@ from deepspeed_tpu.serving.kv_tier import (effective_usable_blocks,
                                            plan_promotions, tier_pressure)
 from deepspeed_tpu.serving.metrics import ServingMetrics
 from deepspeed_tpu.serving.request import Request, RequestState
-from deepspeed_tpu.telemetry.tracer import get_tracer, request_tid
+from deepspeed_tpu.telemetry.tracer import get_tracer
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -109,20 +109,13 @@ class ServerClosedError(RuntimeError):
 
 #: the ``serving.scheduler`` sub-group (a nested dict so partial user
 #: configs merge over these and ``from_ds_config`` passes the group
-#: through verbatim): decode-first chunked prefill + the prefill/decode
-#: role split. Every default = today's semantics (cap off, one engine).
+#: through verbatim): decode-first chunked prefill. The default = the
+#: uncapped plan.
 SCHEDULER_DEFAULTS = {
     # per-tick prefill-token cap: chunked prefill interleaves with decode
     # so TPOT never spikes behind a long prompt. 0 = uncapped (pre-cap
     # planning, bit-identical). Must cover >= 1 KV block when set.
     "prefill_chunk_tokens": 0,
-    # prefill-role/decode-role engine pair in one process with
-    # block-granular KV handoff (serving/disagg.py); consumed by the
-    # server builder, not the tick
-    "role_split": False,
-    # page codec for the in-process KV handoff ("none" | "int8" | "fp8");
-    # "none" = full-width, bit-identical adoption
-    "handoff_quantize": "none",
 }
 
 
@@ -199,11 +192,6 @@ class ServingConfig:
             raise ValueError(
                 f"serving.scheduler.prefill_chunk_tokens must be >= 0, "
                 f"got {merged['prefill_chunk_tokens']}")
-        from deepspeed_tpu.inference.v2.kv_offload import KV_CODECS
-        if merged["handoff_quantize"] not in KV_CODECS:
-            raise ValueError(
-                f"serving.scheduler.handoff_quantize must be one of "
-                f"{KV_CODECS}, got {merged['handoff_quantize']!r}")
 
     @classmethod
     def from_ds_config(cls, ds_config: dict) -> "ServingConfig":
@@ -701,22 +689,6 @@ class InferenceServer:
                 raise _EngineStepError(str(e)) from e
             t0 = time.monotonic()
             self.metrics.on_step()
-            # role-split engines time each prefill->decode KV handoff;
-            # drain those stamps into the SLO histogram every tick (plain
-            # float handover — no host sync, nothing when absent). Traced
-            # requests also get a req/handoff span here: the engine knows
-            # the uid, only the server knows the trace id.
-            pop_handoff = getattr(self.engine, "pop_handoff_latencies", None)
-            if pop_handoff is not None:
-                for uid, lat_s in pop_handoff():
-                    self.metrics.on_handoff_latency(lat_s)
-                    with self._lock:
-                        req = self._inflight.get(uid)
-                    if req is not None and req.trace_id is not None:
-                        get_tracer().complete(
-                            "req/handoff", lat_s, cat="serve",
-                            tid=request_tid(uid), trace_id=req.trace_id,
-                            uid=uid)
             self._note_clean_step()
             worked = True
             # what follows the step belongs to no stage; its mark ends

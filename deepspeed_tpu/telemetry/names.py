@@ -134,12 +134,8 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "req/queue": ("complete",),
     "req/prefill": ("complete",),
     "req/decode": ("complete",),
-    "req/handoff": ("complete",),
     "req/reroute": ("complete",),
     "req/wall": ("complete",),
-    # -- disaggregated prefill/decode -------------------------------------
-    "disagg/tick": ("complete",),
-    "disagg/handoff": ("instant",),
     # -- fleet router ------------------------------------------------------
     "fleet/poll_tick": ("span",),
     "fleet/rotation": ("counter",),
@@ -212,7 +208,7 @@ SERVE_STAGE_OF: Dict[str, str] = {
 
 #: ``jax.named_scope`` names of the served step programs: what an operation's
 #: ``tf_op`` holds in the device trace, and what the benchmark's per-layer
-#: readers match (``generic_decode.py``, ``modules.py``, ``llama_decode.py``).
+#: readers match (``generic_decode.py``, ``modules.py``, ``kv_cache.py``).
 #: The ``attn/latent_*`` four are a latent (MLA) cache's: the low-rank
 #: projections and the fold, the row's write, the paged decode kernel with
 #: the value unfold, a chunk's gather, up-projection and prefill kernel
@@ -241,11 +237,9 @@ REQ_PREFIX = "req/"
 REQ_TRACE_ARG = "trace_id"
 REQ_WALL_NAME = "req/wall"
 REQ_REROUTE_NAME = "req/reroute"
-REQ_HANDOFF_NAME = "req/handoff"
 REQ_STAGE_OF: Dict[str, str] = {
     "req/queue": "queue",
     "req/prefill": "prefill",
     "req/decode": "decode",
-    "req/handoff": "handoff",
     "req/reroute": "reroute",
 }

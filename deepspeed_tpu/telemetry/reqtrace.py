@@ -32,9 +32,7 @@ wall_dur``. In a clean stitch the spans nest disjointly inside the
 envelope and the error is ~0; clock misalignment or a broken trace-id
 join pushes spans outside the envelope (or on top of each other) and
 the error grows past ``TIE_OUT_TOLERANCE`` — the row is flagged, not
-trusted. ``req/handoff`` is deliberately OUTSIDE the conservation sum:
-it sub-spans the prefill->decode boundary inside time the phase spans
-already cover (including it would double-count by construction).
+trusted.
 
 Clock alignment reuses crossrank's wall-anchor rule: each dump's
 process-identity header pins monotonic ts to wall time; dumps without a
@@ -76,7 +74,6 @@ _crossrank = _load_sibling("dstpu_crossrank", "crossrank.py")
 REQ_PREFIX = _names.REQ_PREFIX
 REQ_TRACE_ARG = _names.REQ_TRACE_ARG
 REQ_WALL_NAME = _names.REQ_WALL_NAME
-REQ_HANDOFF_NAME = _names.REQ_HANDOFF_NAME
 REQ_REROUTE_NAME = _names.REQ_REROUTE_NAME
 REQ_STAGE_OF = _names.REQ_STAGE_OF
 
@@ -92,11 +89,6 @@ DEFAULT_REQTRACE_ARTIFACT = "reqtrace.json"
 #: the wall envelope without overlap, as a fraction of the envelope —
 #: the same 5% alignment-sanity bar crossrank's windows use
 TIE_OUT_TOLERANCE = 0.05
-
-#: the conservation sum's members: phase chains + router-attributed
-#: gaps. req/wall is the denominator, req/handoff is a sub-span of time
-#: the phases already cover (counting it would double-book).
-_CONSERVED = frozenset(n for n in REQ_STAGE_OF if n != REQ_HANDOFF_NAME)
 
 
 class ReqTraceError(Exception):
@@ -263,8 +255,10 @@ def stitch_requests(paths: List[str]) -> Dict[str, Any]:
         wall = walls[0]
         w0, w1 = wall["start_us"], wall["end_us"]
         wall_dur = max(wall["dur_us"], 0.0)
+        # the conservation sum's members: phase chains + router-attributed
+        # gaps. req/wall is the denominator.
         phases = [s for s in spans
-                  if s["name"] in _CONSERVED and s is not wall]
+                  if s["name"] in REQ_STAGE_OF and s is not wall]
         # per-source visit chains, ordered by first span start — "which
         # replicas served this request, in what order". Reroute spans are
         # router-side gap attribution, not a replica visit.

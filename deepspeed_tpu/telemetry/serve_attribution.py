@@ -130,8 +130,7 @@ SERVING_DEFAULTS = {
     "shed_pressure": 0.97,
     "ladder_hysteresis": 0.10,
     "ladder_cooldown_ticks": 20,
-    "scheduler": {"prefill_chunk_tokens": 0, "role_split": False,
-                  "handoff_quantize": "none"},
+    "scheduler": {"prefill_chunk_tokens": 0},
 }
 
 

@@ -126,11 +126,6 @@ HOT_ROOTS: Tuple[HotRoot, ...] = (
         reason="the /healthz payload: polled by the fleet router every "
                "poll tick, so its gauge reads must never touch the device"),
     HotRoot(
-        path="deepspeed_tpu/serving/disagg.py",
-        qualname="DisaggregatedEngine.step",
-        reason="the role-split tick: prefill/decode pair step + "
-               "block-granular KV handoff run every tick"),
-    HotRoot(
         path="deepspeed_tpu/inference/v2/engine_v2.py",
         qualname="InferenceEngineV2.step",
         reason="the v2 engine dispatch: scheduler planning, KV/prefix "
@@ -296,8 +291,8 @@ ESCAPE_HATCHES: Tuple[EscapeHatch, ...] = (
         path="deepspeed_tpu/inference/v2/kv_cache.py",
         qualname="BlockedKVCache.scatter_blocks",
         mode="sync_ok",
-        reason="THE designated page H2D staging (promotion/handoff "
-               "adopt): ditto"),
+        reason="THE designated page H2D staging (promotion, prefix "
+               "handoff adopt): ditto"),
     EscapeHatch(
         path="deepspeed_tpu/monitor/monitor.py",
         qualname="MonitorMaster.write_events",

@@ -149,7 +149,9 @@ def test_paged_kernel_matches_gather_decode(model_and_params):
     """The Pallas paged-attention decode path (interpret mode) produces the same
     logits as the gather reference path."""
     from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache, KVCacheConfig
-    from deepspeed_tpu.inference.v2.llama_decode import decode_step, prefill_chunk
+    from deepspeed_tpu.inference.v2.generic_decode import (decode_step_g,
+                                                           prefill_chunk_g)
+    from deepspeed_tpu.inference.v2.modules import LlamaPolicy
     cfg, model, params = model_and_params
     kv = BlockedKVCache(KVCacheConfig(
         num_layers=cfg.num_layers, num_kv_heads=cfg.num_kv_heads,
@@ -162,12 +164,13 @@ def test_paged_kernel_matches_gather_decode(model_and_params):
     tokens[:20] = prompt
     # the step programs consume the pool they are given: a pool that two
     # implementations are to start from goes to the first as a copy
-    logits_g, cache_g = prefill_chunk(
+    kw = dict(policy=LlamaPolicy, cfg=cfg, block_size=16)
+    logits_g, cache_g, _ = prefill_chunk_g(
         params, jnp.copy(kv.data), jnp.asarray(tokens), 0, jnp.asarray(table),
-        20, cfg=cfg, block_size=16, attn_impl="gather")
-    logits_k, cache_k = prefill_chunk(
+        20, attn_impl="gather", **kw)
+    logits_k, cache_k, _ = prefill_chunk_g(
         params, kv.data, jnp.asarray(tokens), 0, jnp.asarray(table), 20,
-        cfg=cfg, block_size=16, attn_impl="kernel_interpret")
+        attn_impl="kernel_interpret", **kw)
     np.testing.assert_allclose(np.asarray(logits_k), np.asarray(logits_g),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(cache_k), np.asarray(cache_g),
@@ -177,10 +180,10 @@ def test_paged_kernel_matches_gather_decode(model_and_params):
     dpos = jnp.asarray([20, 0], jnp.int32)
     tables = jnp.asarray([[0, 1, 2, 3], [31, 31, 31, 31]], jnp.int32)
     valid = jnp.asarray([True, False])
-    out_g, _ = decode_step(params, jnp.copy(cache_g), dtok, dpos, tables,
-                           valid, cfg=cfg, block_size=16, attn_impl="gather")
-    out_k, _ = decode_step(params, cache_g, dtok, dpos, tables, valid,
-                           cfg=cfg, block_size=16, attn_impl="kernel_interpret")
+    out_g, _, _ = decode_step_g(params, jnp.copy(cache_g), dtok, dpos, tables,
+                                valid, attn_impl="gather", **kw)
+    out_k, _, _ = decode_step_g(params, cache_g, dtok, dpos, tables, valid,
+                                attn_impl="kernel_interpret", **kw)
     np.testing.assert_allclose(np.asarray(out_k)[0], np.asarray(out_g)[0],
                                atol=1e-4, rtol=1e-4)
 

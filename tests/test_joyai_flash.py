@@ -19,7 +19,7 @@ import pytest
 
 from benchmarks.families import joyai_llm_flash as family
 from benchmarks.reference import joyai_llm_flash as reference
-from deepspeed_tpu.inference.v2 import llama_decode
+from deepspeed_tpu.inference.v2 import kv_cache
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   V2EngineConfig)
 from deepspeed_tpu.inference.v2.generic_decode import (decode_step_g,
@@ -287,10 +287,10 @@ def test_folded_decode_equals_unfolded_attention():
     q_rope = jax.random.normal(key[3], (1, heads, d_r))
     table = jnp.arange(4, dtype=jnp.int32)
     pos = 21
-    folded = llama_decode._latent_paged_attn(
+    folded = kv_cache._latent_paged_attn(
         q_nope, q_rope, pool, 0, table[None], jnp.asarray([pos]), w_ukv,
         0.29, "gather")
-    unfolded = llama_decode._latent_prefill_attn(
+    unfolded = kv_cache._latent_prefill_attn(
         q_nope, q_rope, pool, 0, table, pos, w_ukv, 0.29, "gather")
     np.testing.assert_allclose(folded, unfolded, atol=1e-5)
 

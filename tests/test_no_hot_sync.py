@@ -53,10 +53,6 @@ LEGACY_COVERAGE = tuple(
          ("observe_tick", "reset_window")),
         ("deepspeed_tpu/inference/v2/scheduler.py", None,
          ("snap_bucket", "plan_step")),
-        ("deepspeed_tpu/serving/disagg.py", "DisaggregatedEngine",
-         ("step", "_handoff", "can_schedule", "has_work")),
-        ("deepspeed_tpu/inference/v2/engine_v2.py", "InferenceEngineV2",
-         ("adopt_kv_handoff",)),
         ("deepspeed_tpu/serving/server.py", "InferenceServer",
          ("_serve_once", "_admit_from_queue", "_fan_out", "_reap",
           "_settle_reaped", "_rebalance_kv_tiers", "_observe_ladder",
@@ -130,9 +126,8 @@ def test_declared_roots_still_cover_the_load_bearing_surfaces():
     """The declaration content IS the contract: shrinking it is loud."""
     by_qn = {r.qualname: r for r in HOT_ROOTS}
     for qn in ("DeepSpeedTPUEngine.train_batch", "FaultTolerantRunner.step",
-               "InferenceServer._serve_once", "DisaggregatedEngine.step",
-               "InferenceEngineV2.step", "FleetRouter.route_generate",
-               "FleetRouter._poll_once"):
+               "InferenceServer._serve_once", "InferenceEngineV2.step",
+               "FleetRouter.route_generate", "FleetRouter._poll_once"):
         assert qn in by_qn, f"hot root {qn} was dropped from HOT_ROOTS"
     hatches = {(h.qualname, h.mode) for h in ESCAPE_HATCHES}
     assert ("DispatchRing.drain", "sync_ok") in hatches

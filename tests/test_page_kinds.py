@@ -1,0 +1,177 @@
+"""The seam between the served loop and what a KV page is
+(``inference/v2/kv_cache.py``): each page kind against the allocator, the
+slots its writes land in, and the host's gather and scatter of its blocks.
+What the kinds compute in a step program is pinned elsewhere
+(tests/test_inference_v2.py, test_joyai_flash.py, test_kv_donation.py,
+test_step_program_tpu_compile.py); here only the layout contract a further
+kind has to meet, read from outside through the pool's documented shape.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                 KVCacheConfig, page_kind)
+from deepspeed_tpu.inference.v2.modules import KVCacheSpec
+
+L, H, D, NB, BS = 2, 2, 8, 12, 4
+RANK, D_R, D_N, D_V = 16, 4, 8, 8          # the latent row is RANK + D_R wide
+TRASH = NB - 1
+FP8 = jnp.float8_e4m3fn
+
+#: name -> (page dtype, latent_dim, the pool's leaves as documented, the
+#: block axis of each, tolerance of a value read back through the pages)
+KINDS = {
+    "heads": (jnp.float32, 0, [(L, 2, H, NB, BS, D)], [3], 0.0),
+    "heads_fp8": (FP8, 0, [(L, 2, H, NB, BS, D), (L, 2, H, NB)], [3, 3],
+                  0.07),
+    "latent": (jnp.float32, RANK + D_R, [(L, NB, BS, 128)], [1], 0.0),
+}
+
+
+def other_blocks(name, pool, named):
+    """Each leaf of the pool without the ``named`` blocks, as float32."""
+    rest = np.delete(np.arange(NB), named)
+    return [np.take(np.asarray(x, np.float32), rest, axis)
+            for x, axis in zip(jax.tree.leaves(pool), KINDS[name][3])]
+
+
+def make(name):
+    dtype, latent_dim, _, _, _ = KINDS[name]
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=L, num_kv_heads=H, head_dim=D, block_size=BS,
+        num_blocks=NB, dtype=dtype, latent_dim=latent_dim))
+    spec = KVCacheSpec(L, H, D, 64, jnp.float32, None, latent_dim=latent_dim)
+    return kv, page_kind(spec, kv.pool)
+
+
+def rows_of(name, pool, layer):
+    """One layer's pages as real values ``[NB, BS, features]``, a token's
+    K then V over the heads (or its latent row) flattened."""
+    if name == "latent":
+        return np.asarray(pool[layer], np.float32)
+    pages = pool if name == "heads" else pool[0]
+    real = np.asarray(pages[layer], np.float32)            # [2, H, NB, BS, D]
+    if name == "heads_fp8":
+        real = real * np.asarray(pool[1][layer])[..., None, None]
+    return real.transpose(2, 3, 0, 1, 4).reshape(NB, BS, 2 * H * D)
+
+
+def computed(name, n, seed):
+    """What a block hands ``attend`` for ``n`` rows, and each row as
+    ``rows_of`` should read it back."""
+    key = jax.random.split(jax.random.PRNGKey(seed), 4)
+    if name == "latent":
+        row = jax.random.normal(key[0], (n, RANK + D_R))
+        args = (jax.random.normal(key[1], (n, H, D_N)),
+                jax.random.normal(key[2], (n, H, D_R)), row,
+                jax.random.normal(key[3], (RANK, H, D_N + D_V)), 0.3)
+        return args, np.pad(np.asarray(row), ((0, 0), (0, 128 - RANK - D_R)))
+    k = jax.random.normal(key[0], (n, H, D))
+    v = jax.random.normal(key[1], (n, H, D))
+    if name == "heads_fp8":
+        # one outlier beyond the page's committed range, in a row that is no
+        # padding: its page's scale grows and the page is requantized under
+        # it (the trash page's scale never grows)
+        k = k.at[0, 0, 0].set(900.0)
+    args = (jax.random.normal(key[2], (n, 2 * H, D)), k, v)
+    return args, np.stack([np.asarray(k), np.asarray(v)], 1).reshape(n, -1)
+
+
+@pytest.mark.parametrize("name", sorted(KINDS))
+def test_pool_shape_block_axis_and_trash_block_agree_with_the_allocator(name):
+    dtype, _, shapes, axes, _ = KINDS[name]
+    kv, kind = make(name)
+    assert type(kind) is type(kv.kind)
+    leaves = jax.tree.leaves(kv.pool)
+    assert [x.shape for x in leaves] == shapes
+    assert leaves[0].dtype == dtype
+    assert (kv.scales is None) == (len(shapes) == 1)
+    assert kind.block_axis == axes[0]
+    # the trash block is the pool's last and is never handed out
+    assert kind.trash_block(kv.pool) == TRASH == kv.allocator.total_blocks
+    assert TRASH not in kv.reserve(kv.free_blocks) and kv.free_blocks == 0
+
+
+@pytest.mark.parametrize("name", sorted(KINDS))
+def test_a_write_lands_in_the_named_slots_and_nowhere_else(name):
+    tol = KINDS[name][4]
+    kv, kind = make(name)
+    # a pool of a value that is no row's, so that a stray write shows; fp8
+    # pages hold it under a scale of 0.5
+    pool = jax.tree.map(lambda x: jnp.full_like(x, 0.5), kv.pool)
+    old = 0.25 if name == "heads_fp8" else 0.5
+
+    def check(pool, before, layer, slots, want):
+        """``slots``: (block, offset) of each row of ``want``; every other
+        slot of ``layer`` reads ``old``, every other layer and every page
+        not named is bit for bit what it was."""
+        expect = np.full((NB, BS, want.shape[-1]), old, np.float32)
+        for (b, o), row in zip(slots, want):
+            expect[b, o] = row
+        np.testing.assert_allclose(rows_of(name, pool, layer), expect,
+                                   rtol=tol, atol=tol * 0.25)
+        named = sorted({b for b, _ in slots})
+        for got, was in zip(other_blocks(name, pool, named),
+                            other_blocks(name, before, named)):
+            np.testing.assert_array_equal(got, was)
+        for got, was in zip(other_blocks(name, pool, []),
+                            other_blocks(name, before, [])):
+            np.testing.assert_array_equal(np.delete(got, layer, 0),
+                                          np.delete(was, layer, 0))
+
+    # a chunk of 6 rows, 5 of them real, from position 6: slots 2-3 of the
+    # table's second block, 0-2 of its third; the padding row to the trash
+    table = jnp.asarray([7, 2, 9, TRASH], jnp.int32)
+    start, rows, real = 6, 6, 5
+    safe_pos = start + jnp.arange(rows)
+    valid = jnp.arange(rows) < real
+    slots = kind.chunk_slots(pool, table, start, safe_pos, valid, BS)
+    args, want = computed(name, rows, seed=0)
+    before = jax.tree.map(jnp.copy, pool)
+    out, pool = kind.attend_chunk(pool, 0, slots, table, start, "gather",
+                                  *args)
+    assert out.shape[0] == rows and np.isfinite(np.asarray(out)[:real]).all()
+    check(pool, before, 0,
+          [(2, 2), (2, 3), (9, 0), (9, 1), (9, 2), (TRASH, 3)], want)
+
+    # a decode batch of 3, the middle one batch padding: one token each at
+    # positions 5 and 8 of their own tables
+    tables = jnp.asarray([[3, 4, 5], [TRASH] * 3, [6, 1, 8]], jnp.int32)
+    positions = jnp.asarray([5, 0, 8], jnp.int32)
+    valid = jnp.asarray([True, False, True])
+    slots = kind.decode_slots(pool, tables, positions, valid, BS)
+    args, want = computed(name, 3, seed=1)
+    before = jax.tree.map(jnp.copy, pool)
+    out, pool = kind.attend_decode(pool, 1, slots, tables, positions,
+                                   "gather", *args)
+    assert out.shape[0] == 3 and np.isfinite(np.asarray(out)[[0, 2]]).all()
+    check(pool, before, 1, [(4, 1), (TRASH, 0), (8, 0)], want)
+
+
+@pytest.mark.parametrize("name", sorted(KINDS))
+def test_gather_then_scatter_into_other_blocks_is_bit_identical(name):
+    src, _ = make(name)
+    dst, _ = make(name)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    # pages of noise, and scales (the second leaf, where there is one) of 0.5-1.5
+    filled = [jax.random.uniform(k, x.shape) + 0.5 if i else
+              jax.random.normal(k, x.shape).astype(x.dtype)
+              for i, (k, x) in enumerate(zip(keys, jax.tree.leaves(src.pool)))]
+    src.pool = filled[0] if len(filled) == 1 else tuple(filled)
+    empty = jax.tree.map(jnp.copy, dst.pool)
+
+    data, scales = src.gather_blocks([2, 5, 7])
+    assert src.gathered_blocks(data) == 3
+    assert (scales is None) == (src.scales is None)
+    dst.scatter_blocks([9, 1, 4], data, scales)
+    back, back_scales = dst.gather_blocks([9, 1, 4])
+    np.testing.assert_array_equal(back.view(np.uint8), data.view(np.uint8))
+    if scales is not None:
+        np.testing.assert_array_equal(back_scales, scales)
+    # and nothing else of the second pool moved
+    for got, was in zip(other_blocks(name, dst.pool, [9, 1, 4]),
+                        other_blocks(name, empty, [9, 1, 4])):
+        np.testing.assert_array_equal(got, was)

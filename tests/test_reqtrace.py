@@ -123,7 +123,6 @@ def test_serving_metrics_slo_histograms_and_families():
     m = ServingMetrics()
     # queue_wait=0.25, ttft=0.5, tpot = 1.0/(3-1) = 0.5 — all exact bounds
     m.on_finish(_finished_request())
-    m.on_handoff_latency(0.125)
     snap = m.slo_snapshot()
     assert set(snap) == {f for f, _a, _h in REQ_HIST_FAMILIES}
     ttft = dshist.LogHistogram.from_snapshot(
@@ -136,9 +135,6 @@ def test_serving_metrics_slo_histograms_and_families():
     tpot = dshist.LogHistogram.from_snapshot(
         snap["dstpu_req_tpot_seconds"])
     assert tpot.counts[tpot.bounds.index(0.5)] == 1
-    hand = dshist.LogHistogram.from_snapshot(
-        snap["dstpu_req_handoff_seconds"])
-    assert hand.counts[hand.bounds.index(0.125)] == 1
 
 
 def test_serving_metrics_prometheus_exports_req_families():
@@ -239,7 +235,6 @@ def _drill_dumps(tmp_path):
         _ev("req/queue", 0, 100, trace_id="t1", uid=7),
         _ev("req/prefill", 100, 200, trace_id="t1", uid=7),
         _ev("req/decode", 300, 250, trace_id="t1", uid=7),
-        _ev("req/handoff", 320, 50, trace_id="t1", uid=7),
         _ev("req/decode", 0, 100, trace_id="nobody-minted-me", uid=9),
     ])
     return [_write(tmp_path, "router.json", router),
@@ -257,8 +252,7 @@ def test_stitch_failover_timeline_exact(tmp_path):
     assert t1["wall"]["outcome"] == "finished"
     # the surviving replica's visit chain, on the shared wall axis
     assert [v["pid"] for v in t1["visits"]] == [30]
-    # handoff sub-spans decode and reroute is router-side, so neither
-    # appears as a visit stage
+    # reroute is router-side, so it does not appear as a visit stage
     assert t1["visits"][0]["stages"] == ["queue", "prefill", "decode"]
     # req/reroute links the dead replica to the survivor
     assert t1["reroutes"] == 1
@@ -367,7 +361,7 @@ def test_env_report_reqtrace_rows(tmp_path, monkeypatch):
     assert "1 flight dumps" in rows["reqtrace"]
     assert "slo histograms" in rows
     assert "ttft" in rows["slo histograms"]
-    assert "handoff" in rows["slo histograms"]
+    assert "queue_wait" in rows["slo histograms"]
 
 
 def test_env_report_reqtrace_hint_without_artifact(tmp_path, monkeypatch):

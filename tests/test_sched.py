@@ -1,6 +1,5 @@
 """The async serve core (PR 18): the extracted host-orchestration
-scheduler (``runtime/sched.py``), decode-first chunked prefill, and the
-prefill/decode role split.
+scheduler (``runtime/sched.py``) and decode-first chunked prefill.
 
 Proof obligations, all deterministic counters (no wall-clock judgments):
 
@@ -13,10 +12,6 @@ Proof obligations, all deterministic counters (no wall-clock judgments):
   * `serving.scheduler` off => bit-identical pre-PR planning (the config
     group defaults pin) and chunk shapes add ZERO compiles after warmup
     (chunk buckets stay inside the compile-ledger ladder)
-  * disaggregation: the block-granular KV handoff round-trips pages
-    bit-identical (full-width codec) / tolerance-pinned (int8), and a
-    handed-off sequence continues decode to the same tokens as a
-    single-engine run
   * the seeded ``long_prompt`` A/B: every chunked tick's prefill tokens
     <= cap, the worst decode gap strictly smaller than unchunked over the
     SAME seeded arrivals (common gap-unit normalizer), and the
@@ -34,8 +29,6 @@ import pytest
 
 from deepspeed_tpu.inference.v2.engine_v2 import (InferenceEngineV2,
                                                   V2EngineConfig)
-from deepspeed_tpu.inference.v2.kv_offload import (quantize_error_bound,
-                                                   quantize_pages)
 from deepspeed_tpu.inference.v2.ragged_manager import StateManager
 from deepspeed_tpu.inference.v2.scheduler import SchedulerConfig, plan_step
 from deepspeed_tpu.models.llama import (TINY_LLAMA, LlamaConfig,
@@ -146,12 +139,6 @@ def test_tick_ledger_counters_and_gap():
     assert snap["chunk_tokens_total"] == 159        # cumulative survived
     assert snap["capped_chunk_ticks"] == 1
     assert snap["prefill_cap_utilization"] == 1.0
-    # merge: the disagg pair folds both role ledgers into one proof set
-    other = TickLedger()
-    other.observe_tick(48, 2, 1, cap=0)
-    led.merge_from(other)
-    assert led.chunk_tokens_total == 207
-    assert led.max_decode_stall_tokens == 48
 
 
 # ---------------------------------------------------------------------------
@@ -319,13 +306,13 @@ def test_serving_scheduler_group_validation():
     # partial dicts merge over the defaults (config-file ergonomics)
     cfg = ServingConfig(scheduler={"prefill_chunk_tokens": 32})
     assert cfg.scheduler["prefill_chunk_tokens"] == 32
-    assert cfg.scheduler["role_split"] is False
     with pytest.raises(ValueError, match="unknown"):
         ServingConfig(scheduler={"chunk_cap": 32})
     with pytest.raises(ValueError, match="prefill_chunk_tokens"):
         ServingConfig(scheduler={"prefill_chunk_tokens": -1})
-    with pytest.raises(ValueError, match="handoff_quantize"):
-        ServingConfig(scheduler={"handoff_quantize": "zstd"})
+    # the role-split engine pair went with PR 33: its key is unknown now
+    with pytest.raises(ValueError, match="unknown"):
+        ServingConfig(scheduler={"role_split": True})
 
 
 def test_scheduler_defaults_pinned_across_modules():
@@ -335,99 +322,6 @@ def test_scheduler_defaults_pinned_across_modules():
     from deepspeed_tpu.serving.server import SCHEDULER_DEFAULTS
     from deepspeed_tpu.telemetry.serve_attribution import SERVING_DEFAULTS
     assert SERVING_DEFAULTS["scheduler"] == SCHEDULER_DEFAULTS
-
-
-# ---------------------------------------------------------------------------
-# disaggregation: the role split + block-granular KV handoff
-# ---------------------------------------------------------------------------
-def _disagg_pair(params, cfg, handoff_quantize="none"):
-    from deepspeed_tpu.serving.disagg import DisaggregatedEngine
-    return DisaggregatedEngine(_make_engine(params, cfg),
-                               _make_engine(params, cfg),
-                               handoff_quantize=handoff_quantize)
-
-
-def test_disagg_handoff_roundtrip_bit_identical(model_and_params):
-    cfg, _model, params = model_and_params
-    prompt = list(np.random.default_rng(7).integers(0, cfg.vocab_size, 50))
-    pair = _disagg_pair(params, cfg)
-    pair.prefill.put([7], [prompt])
-    while pair.prefill.state.get(7).in_prefill:
-        pair.prefill.step()
-    donor = pair.prefill.state.get(7)
-    ref_data, ref_scales = pair.prefill.kv.gather_blocks(donor.blocks)
-    first_token = list(donor.generated)
-
-    pair._handoff()
-    assert pair.handoff_stats["handoffs"] == 1
-    assert 7 not in pair.prefill.state and pair.prefill.host_kv.get(7) is None
-    adopted = pair.decode.state.get(7)
-    assert adopted is not None and list(adopted.generated) == first_token
-    got_data, got_scales = pair.decode.kv.gather_blocks(adopted.blocks)
-    # full-width codec: the pages land on the decode engine BIT-identical
-    assert np.array_equal(np.asarray(ref_data), np.asarray(got_data))
-    if ref_scales is not None:
-        assert np.array_equal(np.asarray(ref_scales), np.asarray(got_scales))
-    # donor residue fully released
-    assert pair.prefill.kv.free_blocks == \
-        pair.prefill.kv.allocator.total_blocks
-
-
-def test_disagg_handoff_quantized_tolerance_pinned(model_and_params):
-    cfg, _model, params = model_and_params
-    prompt = list(np.random.default_rng(8).integers(0, cfg.vocab_size, 40))
-    pair = _disagg_pair(params, cfg, handoff_quantize="int8")
-    pair.prefill.put([9], [prompt])
-    while pair.prefill.state.get(9).in_prefill:
-        pair.prefill.step()
-    ref_data, _ = pair.prefill.kv.gather_blocks(
-        pair.prefill.state.get(9).blocks)
-    ref = np.asarray(ref_data, np.float32)
-    _q, qscales = quantize_pages(ref, "int8")
-    bound = quantize_error_bound(qscales, "int8")
-
-    pair._handoff()
-    assert pair.handoff_stats["handoffs"] == 1
-    # int8 travels at ~1/4 width; the wire accounting proves it
-    assert pair.handoff_stats["handoff_bytes"] < \
-        pair.handoff_stats["handoff_raw_bytes"]
-    got, _ = pair.decode.kv.gather_blocks(pair.decode.state.get(9).blocks)
-    err = float(np.max(np.abs(np.asarray(got, np.float32) - ref)))
-    assert err <= bound, (err, bound)
-
-
-@pytest.mark.parametrize("handoff_quantize", ["none", "int8"])
-def test_disagg_continues_to_single_engine_tokens(model_and_params,
-                                                  handoff_quantize):
-    """The acceptance round-trip: sequences handed across the role
-    boundary continue decode to the SAME tokens as a single-engine run
-    (greedy argmax; the int8 path holds on this fp32 tiny model because
-    the perturbation sits below every argmax margin on these seeds)."""
-    cfg, _model, params = model_and_params
-    rng = np.random.default_rng(9)
-    prompts = [list(rng.integers(0, cfg.vocab_size, int(n)))
-               for n in rng.integers(20, 60, 4)]
-
-    solo = _make_engine(params, cfg)
-    solo.put(list(range(4)), prompts)
-    for _ in range(40):
-        solo.step()
-        if all(len(solo.state.get(u).generated) >= 8 for u in range(4)):
-            break
-    want = {u: solo.flush(u)[:8] for u in range(4)}
-
-    pair = _disagg_pair(params, cfg, handoff_quantize=handoff_quantize)
-    pair.prefill.put(list(range(4)), prompts)
-    for _ in range(60):
-        pair.step()
-        if all((s := pair.state.get(u)) and len(s.generated) >= 8
-               for u in range(4)):
-            break
-    got = {u: pair.flush(u)[:8] for u in range(4)}
-    assert got == want
-    assert pair.handoff_stats["handoffs"] == 4      # every uid crossed
-    # the handoff store drains: no KV bytes stranded on the boundary
-    assert pair.host_kv_bytes() == 0
 
 
 # ---------------------------------------------------------------------------
@@ -571,34 +465,3 @@ def test_long_prompt_chunk_proposal_verify_loop(tmp_path, monkeypatch):
     assert observed <= new_cap
     results = json.load(open(tmp_path / "autotuning_results.json"))
     assert results["plan"]["serve_verifications"] == verdicts
-
-
-def test_role_split_server_token_parity(model_and_params):
-    """`serving.scheduler.role_split` through the real server: the pair
-    serves the same seeded prompts to the same tokens as a single-engine
-    server, with every sequence crossing the handoff boundary."""
-    del model_and_params    # ordering only: reuse the compiled tiny shapes
-    from deepspeed_tpu.serving import bench_serve
-
-    def serve(serving_overrides):
-        rng = np.random.default_rng(10)
-        prompts = [list(map(int, rng.integers(0, 128, int(n))))
-                   for n in rng.integers(20, 70, 6)]
-        server = bench_serve.build_tiny_server(
-            serving_overrides=serving_overrides).start()
-        try:
-            reqs = [server.submit(p, max_new_tokens=6, timeout_s=120.0)
-                    for p in prompts]
-            for r in reqs:
-                r.wait(timeout=120.0)
-            return [list(r.tokens) for r in reqs], server.engine
-        finally:
-            server.stop(drain_timeout=30.0)
-
-    solo, _ = serve(None)
-    split, engine = serve({"scheduler": {"role_split": True,
-                                         "prefill_chunk_tokens": 32}})
-    assert split == solo
-    assert engine.handoff_stats["handoffs"] == 6
-    assert engine.host_kv_bytes() == 0               # boundary drained
-    assert engine.sched_stats()["max_prefill_tokens_per_tick"] <= 32
