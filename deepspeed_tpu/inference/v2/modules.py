@@ -271,43 +271,47 @@ class OPTPolicy:
             m["embed"]["embedding"].astype(jnp.float32).T   # tied
 
 
-def _step_counts(rows):
-    """A layer's counts of ``STEP_COUNTER_ARGS`` from the rows on each expert
-    [E]: two sums over what the router has anyway."""
-    return jnp.stack([jnp.sum(rows), jnp.sum(rows > 0)]).astype(jnp.int32)
+def _expert_matmul_impl() -> str:
+    """The grouped matmul under the served experts: the Pallas kernels on a
+    TPU, ``jax.lax.ragged_dot`` elsewhere (as ``kv_cache._resolve_impl``
+    resolves the attention kernels; ``kernel_interpret`` is the tests')."""
+    return "kernel" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def _routed_sum(experts, h2, weights, ids, valid, impl):
+    """``_chosen_experts`` by one ``impl``. A function of its own under
+    ``jit`` so that a step program traces and lowers it once and not once a
+    layer (its layers' shapes are the same)."""
+    if impl == "ragged_dot":
+        y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid)
+        tile_rows = jnp.sum(rows)        # no tiles: every row fills its own
+    else:
+        from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
+        interpret = impl == "kernel_interpret"
+        y, rows = grouped_expert_ffn(
+            h2, experts, weights, ids, valid,
+            matmul=functools.partial(gmm.grouped_matmul, interpret=interpret),
+            gate_up=functools.partial(gmm.grouped_gate_up,
+                                      interpret=interpret))
+        tm = gmm.tiling(ids.size, *experts["w_gate"].shape, h2.dtype, 2)[0]
+        tile_rows = gmm.visited_tile_rows(rows, ids.size, tm)
+    # the layer's counts, in the order of ``STEP_COUNTER_ARGS``
+    return y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0),
+                         tile_rows]).astype(jnp.int32)
 
 
 def _chosen_experts(experts, h2, weights, ids, valid):
     """The routed sum of ``moe/grouped_experts.py`` (the chosen experts alone
-    compute, bucket padding rows take none) and the layer's counts."""
+    compute, bucket padding rows take none; on a TPU through the Pallas
+    grouped matmuls, gate and up in one pass) and the layer's counts of
+    ``STEP_COUNTER_ARGS``: two sums over the rows on each expert, which the
+    router has anyway, and the rows of the tiles the grouped matmul visited,
+    from what the kernel is given. The scope is opened around the call: the
+    callee's operations are lowered once, without their caller's names."""
     with jax.named_scope("moe/experts"):
-        y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid)
-    return y, _step_counts(rows)
-
-
-def _all_experts_then_pick(experts, h2, weights, ids, valid):
-    """The same routed sum and counts with every row through every expert
-    and the chosen picked afterwards: ``E / K`` times the multiplications,
-    which cost nothing while reading the experts bounds the step."""
-    e, dtype = experts["w_gate"].shape[0], h2.dtype
-    with jax.named_scope("moe/experts"):
-        g = jnp.einsum("td,edf->etf", h2, experts["w_gate"].astype(dtype))
-        u = jnp.einsum("td,edf->etf", h2, experts["w_up"].astype(dtype))
-        every = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u,
-                           experts["w_down"].astype(dtype))      # [E, T, D]
-        picked = every[ids, jnp.arange(h2.shape[0])[:, None]]    # [T, K, D]
-        w = jnp.where(valid[:, None], weights, 0.0).astype(dtype)
-        rows = jnp.zeros((e + 1,), jnp.int32).at[
-            jnp.where(valid[:, None], ids, e)].add(1)[:e]
-        return jnp.einsum("tk,tkd->td", w, picked), _step_counts(rows)
-
-
-#: rows of one tile of XLA:TPU's grouped-matmul call at Mixtral's widths
-#: (its metadata for 4,096 sorted rows on 8 experts names 4096 / 512 + 8 - 1
-#: tiles). The call multiplies whole tiles, one more for every expert whose
-#: rows end inside one, so a step of up to a tile's rows costs it as much as
-#: every row through every expert, plus the sort and a cost an expert
-_GROUPED_TILE_ROWS = 512
+        return _routed_sum(experts, h2, weights, ids, valid,
+                           impl=_expert_matmul_impl())
 
 
 def _softmax_moe(moe, h2, cfg, valid):
@@ -315,22 +319,19 @@ def _softmax_moe(moe, h2, cfg, valid):
     top-k in float32, then the chosen experts alone. Equivalent to the
     training dispatch when no token drops; no capacity here, so none does.
 
-    Which form computes them hangs on the step program's static row count
-    against the expert count, by chip runs at Mixtral's widths (PERF.md
-    section 6, PR 32; one layer, ms, grouped / every expert): a bucket so
-    small that some expert is likely left unread takes the grouped call
-    (8 rows on 8 experts top-2: 3.11 / 3.78, two live rows 1.58 / 3.78);
-    from there up to one tile of the grouped call every expert is read
-    either way and every-expert-then-pick stays at the memory roofline
-    (32 rows: 4.38 / 3.78; 256: 9.04 / 4.52; 512: 10.28 / 8.54); beyond a
-    tile only the grouped call's work grows with ``top_k`` and not with the
-    expert count (1,024: 12.55 / 16.96; 2,048: 17.75 / 33.88)."""
-    t, e, k = h2.shape[0], cfg.moe.num_experts, cfg.moe.top_k
+    One form at every row count since the grouped matmul is the Pallas
+    kernel (chip runs at Mixtral's widths, PERF.md section 6, PR 34; one
+    layer's routed sum, ms, the kernel / every row through every expert then
+    pick, the form that step programs of up to 512 rows kept while XLA's
+    grouped call multiplied whole 512-row tiles): 8 rows 3.42 / 3.79, 32
+    rows 3.81 / 3.80, 64 rows 3.85 / 3.82, 128 rows 3.89 / 4.02, 256 rows
+    3.96 / 4.53, 512 rows 4.73 / 8.53, 1,024 rows 6.74 / 16.98, 2,048 rows
+    11.47 / 34.03. Both read every expert once at the memory roofline in a
+    small step (within 1% of each other at 32 and 64 rows), and only the
+    kernel leaves an expert nobody chose unread."""
     with jax.named_scope("moe/router"):
-        weights, ids = softmax_route(h2, moe["gate"]["wg"]["kernel"], k,
-                                     cfg.moe.norm_topk_prob)
-    if 2 * e < t * k and t <= _GROUPED_TILE_ROWS:
-        return _all_experts_then_pick(moe["experts"], h2, weights, ids, valid)
+        weights, ids = softmax_route(h2, moe["gate"]["wg"]["kernel"],
+                                     cfg.moe.top_k, cfg.moe.norm_topk_prob)
     return _chosen_experts(moe["experts"], h2, weights, ids, valid)
 
 
@@ -345,11 +346,9 @@ class MixtralPolicy:
     """reference: model_implementations/mixtral (+ qwen_v2_moe shape). The
     softmax router's top-k experts of a row compute and no other
     (``moe/grouped_experts.py``: rows sorted by expert, a grouped matmul a
-    weight) wherever that is less work on the chip; a step program whose
-    rows fit one tile of that call reads every expert either way and keeps
-    every-expert-then-pick (``_softmax_moe``). No token is dropped in either
-    form, and the step programs hand out how many rows the experts took and
-    how many were touched."""
+    weight; ``_softmax_moe``). No token is dropped, and the step programs
+    hand out how many rows the experts took, how many were touched and how
+    many rows the grouped matmul's tiles held."""
 
     @staticmethod
     def cache_spec(cfg: MixtralConfig) -> KVCacheSpec:

@@ -3,12 +3,13 @@
 Reference analog: ``deepspeed/inference/v2/kernels/cutlass_ops/moe_gemm`` with
 ``ragged_ops/moe_scatter`` and ``moe_gather`` (rows sorted by expert, one
 grouped GEMM a weight, rows gathered back). Here the T x K assignments are
-sorted by expert and multiplied by ``jax.lax.ragged_dot`` (XLA:TPU lowers it
-to its grouped-matmul call; a group's tiles are visited for the rows it has),
-so no ``[E, T, F]`` intermediate exists and an expert nobody chose is not
-read. ``models/joyai_llm_flash.py`` (the weights that train) and
+sorted by expert and multiplied by a grouped matmul (a group's tiles are
+visited for the rows it has), so no ``[E, T, F]`` intermediate exists and an
+expert nobody chose is not read. ``models/joyai_llm_flash.py`` (the weights
+that train: ``jax.lax.ragged_dot``, which differentiates) and
 ``inference/v2/modules.py`` (the weights that serve: the JoyAI-LLM-Flash,
-Mixtral and Qwen2-MoE policies, each behind its own router) call this.
+Mixtral and Qwen2-MoE policies, each behind its own router; on a TPU through
+``ops/pallas/grouped_matmul.py``) call this.
 """
 
 import jax
@@ -45,12 +46,16 @@ def softmax_route(h, gate_kernel, top_k: int, norm_topk_prob: bool):
     return w, ids
 
 
-def grouped_expert_ffn(h, experts, weights, ids, valid=None):
+def grouped_expert_ffn(h, experts, weights, ids, valid=None,
+                       matmul=jax.lax.ragged_dot, gate_up=None):
     """``sum_k weights[t, k] * E_ids[t, k](h[t])`` with each ``E`` a gated
     MLP of the stacked weights ``experts`` (``w_gate``, ``w_up`` [E, D, F];
     ``w_down`` [E, F, D]). ``h``: [T, D] in the compute type; ``weights``,
     ``ids``: [T, K]; ``valid``: [T] bool, rows to leave out (bucket padding).
-    Returns (y [T, D], rows on each expert [E] int32).
+    ``matmul(rows, stacked weights, counts)`` is the grouped matmul, with
+    ``jax.lax.ragged_dot``'s meaning; ``gate_up(rows, w_gate, w_up, counts)``,
+    where given, computes ``silu(rows @ w_gate) * (rows @ w_up)`` a group in
+    one pass. Returns (y [T, D], rows on each expert [E] int32).
 
     Every shape is static: the T*K assignments are sorted by expert with
     those of rows left out last, the grouped matmul visits only the rows its
@@ -64,10 +69,14 @@ def grouped_expert_ffn(h, experts, weights, ids, valid=None):
     counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
     dtype = h.dtype
     xs = h[order // k]                                           # [T*K, D]
-    g = jax.lax.ragged_dot(xs, experts["w_gate"].astype(dtype), counts)
-    u = jax.lax.ragged_dot(xs, experts["w_up"].astype(dtype), counts)
-    out = jax.lax.ragged_dot(jax.nn.silu(g) * u,
-                             experts["w_down"].astype(dtype), counts)
+    w_gate = experts["w_gate"].astype(dtype)
+    w_up = experts["w_up"].astype(dtype)
+    if gate_up is None:
+        act = jax.nn.silu(matmul(xs, w_gate, counts)) * \
+            matmul(xs, w_up, counts)
+    else:
+        act = gate_up(xs, w_gate, w_up, counts)
+    out = matmul(act, experts["w_down"].astype(dtype), counts)
     computed = jnp.arange(t * k) < jnp.sum(counts)
     out = jnp.where(computed[:, None], out, 0)
     back = out[jnp.argsort(order)].reshape(t, k, -1)

@@ -220,12 +220,16 @@ SERVED_SCOPES: Tuple[str, ...] = (
 
 #: counts a step program computes on the device where its policy's layers
 #: count (``generic_decode.py``), in the order of the int32 vector it hands
-#: out: token-expert pairs of the step and experts with at least one row,
-#: each summed over the expert layers. The engine reads them with the sampled
-#: token and puts them on ``serve/prefill_chunk`` and ``serve/step_decode``
-#: as args of these names; a chunk that ends no prompt is not waited for, so
-#: its counts ride on the next of these spans that is
-STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched")
+#: out: token-expert pairs of the step, experts with at least one row, and
+#: rows of the tiles the grouped matmul visited (``grouped_matmul.py`` under
+#: ``ops/pallas``; ``expert_rows`` over it is the tiles' fill, 1 where
+#: ``jax.lax.ragged_dot`` runs and there are no tiles to count), each summed
+#: over the expert layers. The engine reads them with the sampled token and
+#: puts them on ``serve/prefill_chunk`` and ``serve/step_decode`` as args of
+#: these names; a chunk that ends no prompt is not waited for, so its counts
+#: ride on the next of these spans that is
+STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched",
+                                      "expert_tile_rows")
 
 #: per-request tracing namespace (reqtrace.py file-loads this module
 #: standalone, same contract as the tables above). Spans carrying a

@@ -536,7 +536,7 @@ def test_latent_pool_is_donated_and_aliased(f32, fn_name):
     assert "may-alias" in compiled.as_text().splitlines()[0]
     out, back, counts = fn(params, pool, *tail, **kw)
     assert pool.is_deleted() and not back.is_deleted()
-    assert back.shape == (4, 16, 16, 128) and counts.shape == (2,)
+    assert back.shape == (4, 16, 16, 128) and counts.shape == (3,)
     assert np.isfinite(np.asarray(out)).all()
 
 
@@ -554,7 +554,8 @@ def test_counts_ride_on_the_spans_that_wait(f32):
         events = tracer.events_snapshot()
     finally:
         tracer.configure(enabled=was)
-    assert names.STEP_COUNTER_ARGS == ("expert_rows", "experts_touched")
+    assert names.STEP_COUNTER_ARGS == ("expert_rows", "experts_touched",
+                                       "expert_tile_rows")
     # the registry is what the served modules open, no more and no less
     import pathlib
     import re
@@ -573,8 +574,14 @@ def test_counts_ride_on_the_spans_that_wait(f32):
     assert "expert_rows" not in chunks[0]
     assert chunks[1]["expert_rows"] == 40 * 4 * 3
     assert 3 * 4 <= chunks[1]["experts_touched"] <= 2 * 3 * 16
+    # rows of the grouped matmul's tiles: no fewer than the rows in them
+    # (as many where ``ragged_dot`` runs and there are no tiles to count)
+    assert "expert_tile_rows" not in chunks[0]
+    assert chunks[1]["expert_tile_rows"] >= chunks[1]["expert_rows"]
     assert decodes and all(d["expert_rows"] == 4 * 3 and
-                           d["experts_touched"] == 4 * 3 for d in decodes)
+                           d["experts_touched"] == 4 * 3 and
+                           d["expert_tile_rows"] >= d["expert_rows"]
+                           for d in decodes)
     assert eng._pending_counts == []
 
 

@@ -1,8 +1,9 @@
 """Mixtral's and Qwen2-MoE's served experts through the grouped matmul: the
 softmax router and the chosen experts against all-experts-then-pick written
 out here (the formula the served path had before), each policy's step
-programs against its model's own forward, and the counts they hand out.
-Float32 on the CPU throughout.
+programs against its model's own forward with ``jax.lax.ragged_dot`` and
+with the Pallas kernel (interpret mode) for the matmul, and the counts they
+hand out. Float32 on the CPU throughout.
 """
 
 import dataclasses
@@ -78,48 +79,6 @@ def test_softmax_route_ties_go_to_the_lower_id():
     assert ids.tolist() == [[0, 1]] * 3
 
 
-def _record_forms(patch):
-    """The list that names each form of the routed sum as ``_softmax_moe``
-    (or a policy's ``block``) calls it, while it is traced."""
-    called = []
-    for name in ("_chosen_experts", "_all_experts_then_pick"):
-        patch.setattr(modules, name, lambda *a, _f=getattr(modules, name),
-                      _n=name: called.append(_n) or _f(*a))
-    return called
-
-
-@pytest.mark.parametrize("t,form", [
-    (1, "grouped"), (2, "grouped"), (8, "grouped"),     # t * K <= 2 * E
-    (9, "every"), (33, "every"), (512, "every"),        # up to a tile's rows
-    (513, "grouped")])
-def test_the_form_is_chosen_by_the_row_count_and_both_give_the_same(
-        t, form, monkeypatch):
-    """``_softmax_moe`` takes the grouped call for a bucket so small that an
-    expert is likely left unread and for more rows than one of its tiles,
-    and every-expert-then-pick between; both give the same sum, zeros for
-    the rows left out, and the same counts."""
-    taken = _record_forms(monkeypatch)
-    keys = jax.random.split(jax.random.PRNGKey(t), 5)
-    h = jax.random.normal(keys[0], (t, D))
-    moe = {"gate": {"wg": {"kernel": jax.random.normal(keys[1], (D, E))}},
-           "experts": {"w_gate": jax.random.normal(keys[2], (E, D, F)) * 0.3,
-                       "w_up": jax.random.normal(keys[3], (E, D, F)) * 0.3,
-                       "w_down": jax.random.normal(keys[4], (E, F, D)) * 0.3}}
-    cfg = dataclasses.replace(TINY_MIXTRAL, moe=dataclasses.replace(
-        TINY_MIXTRAL.moe, num_experts=E, top_k=K, dtype=jnp.float32))
-    valid = jnp.arange(t) % 4 != 1
-    y, counts = modules._softmax_moe(moe, h, cfg, valid)
-    assert taken == ["_chosen_experts" if form == "grouped"
-                     else "_all_experts_then_pick"]
-    weights, ids = softmax_route(h, moe["gate"]["wg"]["kernel"], K, True)
-    for fn in (modules._chosen_experts, modules._all_experts_then_pick):
-        other, other_counts = fn(moe["experts"], h, weights, ids, valid)
-        np.testing.assert_allclose(y, other, atol=1e-5)
-        np.testing.assert_array_equal(counts, other_counts)
-    assert not np.asarray(y)[~np.asarray(valid)].any()
-    assert int(counts[0]) == int(valid.sum()) * K and 0 <= int(counts[1]) <= E
-
-
 # --- the policies against their models ---------------------------------------
 
 def _float32(cfg):
@@ -136,21 +95,21 @@ FAMILIES = {
 
 
 @pytest.fixture(scope="module", params=[
-    (name, form) for name in sorted(FAMILIES)
-    for form in ("as-chosen", "grouped-everywhere")], ids="-".join)
+    (name, matmul) for name in sorted(FAMILIES)
+    for matmul in ("ragged-dot", "kernel-interpret")], ids="-".join)
 def family(request):
-    """(config, parameters, the model's logits). A toy's chunks of 16 and 32
-    rows take every-expert-then-pick and its decode batch of one the grouped
-    call; with no tile to fit into, every step program takes the grouped
-    call, as a 2,048-token chunk does."""
-    name, form = request.param
+    """(config, parameters, the model's logits, the matmul), with the
+    grouped matmul the CPU's (``jax.lax.ragged_dot``) or the TPU's (the
+    Pallas kernel of ``ops/pallas/grouped_matmul.py``, here in interpret
+    mode) under every step program's expert layers."""
+    name, matmul = request.param
     cfg, model_cls, policy = FAMILIES[name]
     assert policy_for(cfg) is policy
     patch = pytest.MonkeyPatch()
     request.addfinalizer(patch.undo)
-    traced = _record_forms(patch)
-    if form == "grouped-everywhere":
-        patch.setattr(modules, "_GROUPED_TILE_ROWS", 0)
+    if matmul == "kernel-interpret":
+        patch.setattr(modules, "_expert_matmul_impl",
+                      lambda: "kernel_interpret")
         # another static argument of the step programs (a shorter rope
         # table, the same results), so that jit traces them anew under the
         # patch and nothing traced under it is found by a later test
@@ -165,10 +124,7 @@ def family(request):
         return np.asarray(model.apply(
             {"params": params}, {"input_ids": np.asarray([ids], np.int32)},
             method=model_cls.logits))[0]
-    yield cfg, params, logits
-    want = {"_chosen_experts"} if form == "grouped-everywhere" else \
-        {"_chosen_experts", "_all_experts_then_pick"}
-    assert set(traced) == want
+    yield cfg, params, logits, matmul
 
 
 def _engine(cfg, params):
@@ -187,7 +143,7 @@ def test_served_tokens_are_the_models_own_forward(family):
     """A prompt of two chunks (32 and a padded 7) and four decode steps
     through the policy's ``block``: the greedy tokens of the model's
     full forward over the growing sequence."""
-    cfg, params, logits = family
+    cfg, params, logits, _ = family
     prompt = _prompt(cfg, 39)
     got = _engine(cfg, params).generate(list(prompt), max_new_tokens=4)
     ids = list(prompt)
@@ -201,7 +157,7 @@ def test_chunk_logits_are_the_models_and_padding_rows_take_no_expert(family):
     row's logits are the model's to float32 rounding, and the counts are
     those of the real rows alone."""
     from deepspeed_tpu.inference.v2 import generic_decode as gd
-    cfg, params, logits = family
+    cfg, params, logits, matmul = family
     policy = policy_for(cfg)
     spec = policy.cache_spec(cfg)
     prompt = _prompt(cfg, 11, seed=5)
@@ -213,18 +169,26 @@ def test_chunk_logits_are_the_models_and_padding_rows_take_no_expert(family):
         jnp.int32(11), policy=policy, cfg=cfg, block_size=16,
         attn_impl="gather")
     np.testing.assert_allclose(got, logits(prompt)[-1], atol=2e-4)
-    rows, touched = np.asarray(counts).tolist()
+    rows, touched, tile_rows = np.asarray(counts).tolist()
+    if matmul == "ragged-dot":
+        assert tile_rows == rows      # no tiles to count: a row fills its own
+    else:                             # whole tiles, no fewer than the rows
+        from deepspeed_tpu.ops.pallas.grouped_matmul import tiling
+        e, d, f = params["layer_0"]["moe"]["experts"]["w_gate"].shape
+        tm = tiling(16 * cfg.moe.top_k, e, d, f, jnp.float32, 2)[0]
+        assert tile_rows >= rows and tile_rows % tm == 0
     assert rows == 11 * cfg.moe.top_k * spec.num_layers
     assert cfg.moe.top_k * spec.num_layers <= touched \
         <= cfg.moe.num_experts * spec.num_layers
 
 
 def test_counts_ride_on_the_spans_that_wait(family):
-    """A traced toy engine: ``expert_rows`` = valid rows x top_k x layers and
-    ``experts_touched`` <= experts x layers, on ``serve/prefill_chunk`` and
-    ``serve/step_decode``."""
+    """A traced toy engine: ``expert_rows`` = valid rows x top_k x layers,
+    ``experts_touched`` <= experts x layers and ``expert_tile_rows`` >=
+    ``expert_rows`` (equal where ``ragged_dot`` runs and no tile is counted),
+    on ``serve/prefill_chunk`` and ``serve/step_decode``."""
     from deepspeed_tpu.telemetry.tracer import get_tracer
-    cfg, params, _ = family
+    cfg, params, _, _ = family
     layers, k, e = cfg.base.num_layers, cfg.moe.top_k, cfg.moe.num_experts
     tracer = get_tracer()
     was = tracer.enabled
@@ -244,7 +208,11 @@ def test_counts_ride_on_the_spans_that_wait(family):
     assert "expert_rows" not in chunks[0]
     assert chunks[1]["expert_rows"] == 39 * k * layers
     assert k * layers <= chunks[1]["experts_touched"] <= 2 * e * layers
+    assert "expert_tile_rows" not in chunks[0]
+    assert chunks[1]["expert_tile_rows"] >= chunks[1]["expert_rows"]
     # one live stream in a decode batch bucket: its padding rows count nothing
     assert decodes and all(d["expert_rows"] == k * layers and
-                           d["experts_touched"] == k * layers for d in decodes)
+                           d["experts_touched"] == k * layers and
+                           d["expert_tile_rows"] >= d["expert_rows"]
+                           for d in decodes)
     assert eng._pending_counts == []

@@ -116,6 +116,15 @@ def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
     assert _pool_shaped_moves(text, pool) == []
 
 
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """The expert layers take the Pallas grouped matmul where the backend is
+    a TPU (``modules._chosen_experts``); this process's is the CPU, so the
+    test answers for it while a step program is traced."""
+    from deepspeed_tpu.inference.v2 import modules
+    monkeypatch.setattr(modules, "_expert_matmul_impl", lambda: "kernel")
+
+
 # --- the latent (MLA) pool ----------------------------------------------------
 # JoyAI-LLM-Flash at its published widths, cut to the dense layer and one
 # expert layer of all 256 experts, so that a compile takes seconds.
@@ -150,14 +159,14 @@ def _latent_shapes(one_chip, fn_name):
 
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
                                      "verify_chunk_g"])
-def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(one_chip,
-                                                                fn_name):
+def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(
+        one_chip, as_on_a_tpu, fn_name):
     """One plane of 640-lane rows (576 values and the zero lanes that keep the
     row minor in the pool's device layout), at the cell's largest shapes (32
     sequences or a 2,048-token chunk over 132 blocks): aliased, no operation
     copies or re-lays-out the pool, the latent kernel of the program's phase
-    and the grouped expert matmul are in the program, and the counts leave
-    beside the logits."""
+    and the grouped expert matmul's kernel are in the program (none of XLA's
+    grouped calls is), and the counts leave beside the logits."""
     cfg, args, pool = _latent_shapes(one_chip, fn_name)
     assert pool.shape == (2, NUM_BLOCKS, BLOCK, 640)
     compiled = getattr(gd, fn_name).lower(
@@ -174,7 +183,7 @@ def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(one_chip,
     kernel = "latent_paged_attention" if fn_name == "decode_step_g" \
         else "latent_prefill_attention"
     assert "tpu_custom_call" in text and kernel in text
-    assert "ragged-dot" in text
+    assert "grouped_matmul" in text and "ragged-dot" not in text
     entry = text[text.index("\nENTRY"):]
     whole = ",".join(str(d) for d in pool.shape)
     moved = [line.strip()[:160] for line in entry.splitlines()
@@ -232,13 +241,13 @@ def _mixtral_shapes(one_chip, fn_name):
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
                                      "verify_chunk_g"])
 def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
-        one_chip, fn_name):
-    """A 2,048-token chunk: the grouped matmul is in the program and no
+        one_chip, as_on_a_tpu, fn_name):
+    """A 2,048-token chunk and a decode batch of 32 alike: the grouped
+    matmul's kernel is in the program, and neither XLA's grouped call nor an
     ``[8, T, 14336]`` value is (all-experts-then-pick made two a layer, 470
-    MB each). A decode batch of 32 fits one tile of that call and keeps
-    every expert for every row (``modules._softmax_moe``): 7 MB a layer. In
-    all three the temporaries stay under one such intermediate of a chunk,
-    the pool is still aliased whole, nothing pool-shaped is copied, and the
+    MB each for a chunk; decode batches kept that form until PR 34). In all
+    three the temporaries stay under one such intermediate of a chunk, the
+    pool is still aliased whole, nothing pool-shaped is copied, and the
     counts leave beside the logits."""
     cfg, args, pool = _mixtral_shapes(one_chip, fn_name)
     assert cfg.moe.num_experts == 8 and cfg.base.dtype == jnp.bfloat16
@@ -252,10 +261,8 @@ def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
     assert "may-alias" in text.splitlines()[0]
     assert "tpu_custom_call" in text and "paged_attention" in text
     rows = args[2].shape[0]                     # 2,048 a chunk, 32 a batch
-    grouped = "ragged-dot" in text
-    every = re.search(r"\[8,%d,14336\]" % rows, text) is not None
-    assert (grouped, every) == ((False, True) if fn_name == "decode_step_g"
-                                else (True, False))
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+    assert re.search(r"\[8,%d,14336\]" % rows, text) is None
     # 259 MB a chunk, 142 MB the verifier
     assert stats.temp_size_in_bytes < 8 * 2048 * 14336 * 2
     assert _pool_shaped_moves(text, pool) == []
