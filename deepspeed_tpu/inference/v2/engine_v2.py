@@ -49,6 +49,9 @@ from deepspeed_tpu.utils.logging import log_dist
 class V2EngineConfig:
     kv_block_size: int = 64
     kv_num_blocks: int = 512
+    # over a model that mixes full and windowed layers this is the full
+    # layers' pool; the windowed layers' follows from the scheduler's limits
+    # (``_window_pool_blocks``)
     max_tracked_sequences: int = 256
     scheduler: SchedulerConfig = dataclasses.field(default_factory=SchedulerConfig)
     decode_batch_buckets: Tuple[int, ...] = (1, 2, 4, 8, 16, 32, 64)
@@ -112,7 +115,8 @@ class InferenceEngineV2:
         self.kv = BlockedKVCache.for_spec(
             spec, self.config.kv_cache_dtype,
             block_size=self.config.kv_block_size,
-            num_blocks=self.config.kv_num_blocks)
+            num_blocks=self.config.kv_num_blocks,
+            window_blocks=self._window_pool_blocks(spec))
         self.state = StateManager(
             max_tracked_sequences=self.config.max_tracked_sequences,
             max_context_length=spec.max_seq_len)
@@ -135,10 +139,9 @@ class InferenceEngineV2:
         # host-RAM KV offload tier (serving demotion target; kv_offload.py)
         self.host_kv = HostKVStore()
         # radix prefix cache over KV pages (prefix_cache.py); None = off
-        self.prefix_cache: Optional[PrefixCache] = (
-            PrefixCache(self.config.kv_block_size,
-                        self.config.prefix_cache_max_blocks)
-            if self.config.prefix_cache_enabled else None)
+        self.prefix_cache: Optional[PrefixCache] = None
+        if self.config.prefix_cache_enabled:
+            self.enable_prefix_cache(self.config.prefix_cache_max_blocks)
         # prefill-work conservation counters (prefix_stats): at drain,
         # saved + computed == total exactly (never-prefilled remainders
         # of cancelled sequences are subtracted from total at flush)
@@ -153,6 +156,7 @@ class InferenceEngineV2:
         self.sched_ledger = TickLedger()
         self.last_step_counters = {"prefill_tokens": 0, "chunks": 0,
                                    "decode_tokens": 0}
+        self.last_step_counters.update(self._kv_page_counters())
         # number of the step about to run, carried by every span of that
         # step; a serving loop overwrites it with its own tick's number
         # before each step so that its spans and the engine's share it
@@ -161,16 +165,41 @@ class InferenceEngineV2:
         # vectors, where the policy counts), kept while tracing until the
         # next wait for a sampled token reads them with it
         self._pending_counts: List[jax.Array] = []
-        self._window = spec.window     # sliding window in tokens, or None
+        # what a windowed layer's decode reads of a context, in tokens (the
+        # one window of all layers, or the windowed kind's), or None
+        self._window = self.kv.kind.window if self.kv.two_kinds \
+            else spec.window
         # speculative-decoding counters (speculative_stats)
         self._spec_steps = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
 
+    def _window_pool_blocks(self, spec) -> int:
+        """The windowed layers' pool in blocks (0 where the spec has one
+        kind of page): what ``max_decode_batch`` sequences hold between
+        steps, one chunk of the longest prefill bucket beside them, and the
+        trash block. ``can_schedule`` admits no more than that, so a larger
+        pool would never fill."""
+        from deepspeed_tpu.inference.v2.kv_cache import (mixes_layer_kinds,
+                                                         windowed_table_blocks)
+        if not mixes_layer_kinds(spec.layer_windows):
+            return 0
+        window, = set(spec.layer_windows) - {None}
+        bs, sched = self.config.kv_block_size, self.config.scheduler
+        return (sched.max_decode_batch - 1) \
+            * windowed_table_blocks(1, window, bs) \
+            + windowed_table_blocks(sched.prefill_buckets[-1], window, bs) + 1
+
+    def require_one_page_kind(self, what: str) -> None:
+        """Raise ``TwoPageKindsError`` naming ``what`` over a cache that
+        keeps pages by layer kind."""
+        self.kv.require_one_kind(what)
+
     def enable_prefix_cache(self, max_cached_blocks: int = 0) -> None:
         """Turn the radix prefix cache on (idempotent) — the serving
         layer's wiring point for the ``serving.prefix_cache_enabled``
         config key when the engine wasn't constructed with it."""
+        self.require_one_page_kind("the prefix cache")
         if self.prefix_cache is None:
             self.prefix_cache = PrefixCache(self.config.kv_block_size,
                                             max_cached_blocks)
@@ -205,23 +234,35 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------
     # admission control (reference: engine_v2.py:158 query, :184 can_schedule)
     # ------------------------------------------------------------------
-    def query(self, uid: int, max_request_length: int) -> Tuple[int, int]:
-        """Returns (max_new_blocks_needed, free_blocks)."""
+    def _lacking(self, uid: int, max_request_length: int) -> Tuple[int, int]:
+        """(full, windowed) blocks ``uid`` lacks for ``max_request_length``
+        more tokens; the second is 0 where all pages are of one kind."""
         seq = self.state.get(uid)
         tracked = seq.total_tokens if seq else 0
-        needed = self.kv.blocks_needed(tracked + max_request_length) - \
-            (len(seq.blocks) if seq else 0)
-        return needed, self.kv.free_blocks
+        return self.kv.lacking(seq, tracked + max_request_length)
+
+    def query(self, uid: int, max_request_length: int) -> Tuple[int, int]:
+        """Returns (max_new_blocks_needed, free_blocks), each over both
+        kinds of page where the cache keeps two."""
+        return sum(self._lacking(uid, max_request_length)), \
+            self.kv.free_blocks
 
     def can_schedule(self, uids: Sequence[int],
                      lengths: Sequence[int]) -> bool:
-        total = 0
-        for uid, n in zip(uids, lengths):
-            needed, _ = self.query(uid, n)
-            total += needed
+        lacking = [self._lacking(uid, n) for uid, n in zip(uids, lengths)]
+        full = sum(f for f, _ in lacking)
         # unpinned cached prefix blocks count as schedulable capacity:
         # they are evicted on demand the moment a reservation needs them
-        return total <= self.kv.free_blocks + self._evictable_blocks() and \
+        fits = full <= self.kv.allocator.free_blocks + self._evictable_blocks()
+        if self.kv.two_kinds:
+            # the windowed pool keeps one chunk's blocks free beside what
+            # sequences hold between steps (a chunk's are given back when
+            # it ends)
+            spare = self.kv.window_step_blocks(
+                self.config.scheduler.prefill_buckets[-1])
+            fits = fits and sum(w for _, w in lacking) + spare <= \
+                self.kv.window_allocator.free_blocks
+        return fits and \
             len(self.state) + len([u for u in uids if u not in self.state]) <= \
             self.state.max_tracked_sequences
 
@@ -236,9 +277,9 @@ class InferenceEngineV2:
         """Reserve device blocks, reclaiming unpinned prefix-cache blocks
         on demand when the free list alone can't cover the request —
         cached-but-unreferenced pages are capacity, not occupancy."""
-        if self.prefix_cache is not None and \
-                num_blocks > self.kv.free_blocks:
-            self.evict_prefix_blocks(num_blocks - self.kv.free_blocks)
+        free = self.kv.allocator.free_blocks
+        if self.prefix_cache is not None and num_blocks > free:
+            self.evict_prefix_blocks(num_blocks - free)
         return self.kv.reserve(num_blocks)
 
     def evict_prefix_blocks(self, want: int) -> int:
@@ -258,9 +299,11 @@ class InferenceEngineV2:
         return len(blocks)
 
     def _ensure_blocks(self, seq: SequenceDescriptor, up_to_tokens: int):
-        need = self.kv.blocks_needed(up_to_tokens) - len(seq.blocks)
+        need = self.kv.blocks_of(up_to_tokens) - len(seq.blocks)
         if need > 0:
             seq.blocks.extend(self._reserve(need))
+        if self.kv.two_kinds:
+            self.kv.ensure_window(seq, up_to_tokens)
 
     def _block_table(self, seq: SequenceDescriptor, bucket_blocks: int) -> np.ndarray:
         trash = self.kv.cfg.num_blocks - 1
@@ -269,8 +312,50 @@ class InferenceEngineV2:
         table[:n] = seq.blocks[:n]
         return table
 
+    def _step_tables(self, seq: SequenceDescriptor, bucket_blocks: int,
+                     first_query: int, rows: int):
+        """What a step program takes as ``seq``'s block table: its blocks
+        padded to the context bucket, and over pages by layer kind the
+        windowed layers' table beside it (``BlockedKVCache.window_table``)."""
+        table = self._block_table(seq, bucket_blocks)
+        if not self.kv.two_kinds:
+            return table
+        return {"full": table,
+                "window": self.kv.window_table(seq, first_query, rows)}
+
+    def _decode_tables(self, seqs, batch: int, bucket_blocks: int):
+        """The decode batch's block tables on the device, [batch, ...] a
+        kind, the rows that are batch padding all trash."""
+        rows = [self._step_tables(seq, bucket_blocks, seq.total_tokens - 1, 1)
+                for seq in seqs]
+
+        def stacked(tables, trash):
+            out = np.full((batch,) + tables[0].shape, trash, np.int32)
+            out[:len(tables)] = tables
+            return jnp.asarray(out)
+        cfg = self.kv.cfg
+        if not self.kv.two_kinds:
+            return stacked(rows, cfg.num_blocks - 1)
+        return {"full": stacked([r["full"] for r in rows],
+                                cfg.num_blocks - 1),
+                "window": stacked([r["window"] for r in rows],
+                                  cfg.window_blocks - 1)}
+
+    def _advanced(self, seq: SequenceDescriptor) -> None:
+        """``seq.seen_tokens`` moved on: over pages by layer kind, give back
+        the windowed blocks that fell behind its window."""
+        if self.kv.two_kinds and self.kv.give_back_behind_window(seq):
+            self._table_sig = None
+
+    def _release_blocks(self, seq: SequenceDescriptor, blocks) -> None:
+        """``blocks`` of ``seq``'s full pages, and all its windowed ones,
+        back to their allocators."""
+        self.kv.release(blocks)
+        if self.kv.two_kinds:
+            self.kv.release_window(seq)
+
     def _ctx_bucket_blocks(self, tokens: int) -> int:
-        blocks = self.kv.blocks_needed(max(tokens, 1))
+        blocks = self.kv.blocks_of(max(tokens, 1))
         return snap_bucket(blocks, self.config.ctx_block_buckets)
 
     # ------------------------------------------------------------------
@@ -344,7 +429,7 @@ class InferenceEngineV2:
                 tokens = np.zeros((chunk.bucket,), np.int32)
                 tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
                 mb = self._ctx_bucket_blocks(end)
-                table = self._block_table(seq, mb)
+                table = self._step_tables(seq, mb, chunk.start, chunk.bucket)
                 # the step programs consume the pool they are given: what
                 # comes back is bound at once, so that a fault later in the
                 # tick (and the server's next step after it) finds the
@@ -352,12 +437,13 @@ class InferenceEngineV2:
                 logits, self.kv.pool, counts = prefill_chunk_g(
                     self.params, self.kv.pool, jnp.asarray(tokens),
                     chunk.start,
-                    jnp.asarray(table), chunk.length,
+                    jax.tree.map(jnp.asarray, table), chunk.length,
                     policy=self.policy, cfg=self.model_config,
                     block_size=self.kv.cfg.block_size,
                     attn_impl=self.config.attn_impl)
                 self._keep_counts(counts)
                 seq.seen_tokens = end
+                self._advanced(seq)
                 self._prefill_computed += chunk.length
                 if self.prefix_cache is not None:
                     # register the freshly materialized FULL prompt blocks
@@ -402,14 +488,12 @@ class InferenceEngineV2:
                     valid[j] = True
                 # signature covers the actual block ids: uid reuse after
                 # flush() can hand a same-shaped batch different pages
+                # (a windowed block given back resets the signature:
+                # ``_advanced``)
                 sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
                 rebuilt = sig != self._table_sig
                 if rebuilt:
-                    tables = np.full((b, mb), self.kv.cfg.num_blocks - 1,
-                                     np.int32)
-                    for j, seq in enumerate(seqs):
-                        tables[j] = self._block_table(seq, mb)
-                    self._dev_tables = jnp.asarray(tables)
+                    self._dev_tables = self._decode_tables(seqs, b, mb)
                     self._table_sig = sig
                 build.note(tables_rebuilt=rebuilt)
             with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
@@ -430,6 +514,7 @@ class InferenceEngineV2:
                 for j, seq in enumerate(seqs):
                     tok = int(toks[j])
                     seq.seen_tokens = seq.total_tokens
+                    self._advanced(seq)
                     seq.generated.append(tok)
                     out[seq.uid] = tok
                     if self.config.eos_token_id is not None and \
@@ -458,14 +543,28 @@ class InferenceEngineV2:
                                      "decode_s": t_decode}
             prefill_tokens = sum(c.length for c in plan.prefill_chunks)
             decode_tokens = len(plan.decode_seqs)
+            pages = self._kv_page_counters()
             self.last_step_counters = {"prefill_tokens": prefill_tokens,
                                        "chunks": len(plan.prefill_chunks),
-                                       "decode_tokens": decode_tokens}
+                                       "decode_tokens": decode_tokens,
+                                       **pages}
+            if tracer.enabled and not plan.empty:
+                tracer.counter("serve/kv_pages", cat="mem", **pages)
             if not plan.empty:
                 self.sched_ledger.observe_tick(prefill_tokens,
                                                len(plan.prefill_chunks),
                                                decode_tokens, cap=cap)
         return out
+
+    def _kv_page_counters(self) -> Dict[str, int]:
+        """The pool after a step, as host ints: blocks sequences hold by
+        kind of page (``kv_full_blocks``; over pages by layer kind
+        ``kv_window_blocks`` and, running, ``kv_window_blocks_given_back``),
+        their bytes, and the tokens whose keys and values they hold."""
+        held = self.kv.pages_held()
+        return {"kv_live_tokens": sum(s.seen_tokens for s in self.state.all()
+                                      if not s.paused),
+                **{f"kv_{name}": n for name, n in held.items()}}
 
     def _keep_counts(self, counts) -> None:
         """Keep what a step program counted (nothing where its policy counts
@@ -529,7 +628,7 @@ class InferenceEngineV2:
             # remains the contract for callers without a partition
             self.kv.release([b for b in seq.blocks if not cache.owns(b)])
         else:
-            self.kv.release(seq.blocks)
+            self._release_blocks(seq, seq.blocks)
         self.host_kv.pop(uid)     # no-op unless the sequence was demoted
         return seq.generated
 
@@ -555,6 +654,7 @@ class InferenceEngineV2:
         on device for the surviving readers (or evictable at refcount 0)
         AND travel to the host tier inside this entry, so promotion is
         self-sufficient even if the cached copies get evicted meanwhile."""
+        self.require_one_page_kind("the host KV offload tier (demote_kv)")
         seq = self.state.get(uid)
         if seq is None or seq.paused or seq.done:
             # a done sequence is about to be reaped — gathering its pages
@@ -630,6 +730,7 @@ class InferenceEngineV2:
         successor adopts the file and the shared prefixes survive the
         retirement instead of being recomputed fleet-wide. A deliberate
         device->host gather — drain-time only, never on the serve tick."""
+        self.require_one_page_kind("the prefix handoff (export)")
         cache = self.prefix_cache
         out = {"chains": 0, "blocks": 0, "stored_bytes": 0, "raw_bytes": 0}
         payload: Dict[str, np.ndarray] = {}
@@ -674,6 +775,7 @@ class InferenceEngineV2:
         copies — wire it through ``InferenceServer.adopt_prefix_handoff``
         so the serve-loop thread (the engine's owner) runs it between
         ticks."""
+        self.require_one_page_kind("the prefix handoff (import)")
         out = {"chains": 0, "blocks": 0, "skipped": 0, "bytes": 0}
         cache = self.prefix_cache
         with np.load(path, allow_pickle=False) as z:
@@ -729,7 +831,8 @@ class InferenceEngineV2:
     def kv_held_blocks(self, uid: int) -> int:
         """Device blocks a sequence holds right now (0 when demoted)."""
         seq = self.state.get(uid)
-        return len(seq.blocks) if seq is not None else 0
+        return len(seq.blocks) + len(seq.window_blocks) \
+            if seq is not None else 0
 
     def host_kv_bytes(self) -> int:
         return self.host_kv.total_bytes
@@ -837,7 +940,7 @@ class InferenceEngineV2:
     def kv_usable_blocks(self) -> int:
         """Blocks available to sequences (the last block is the permanent
         trash page for padding writes and never allocates)."""
-        return self.kv.cfg.num_blocks - 1
+        return self.kv.usable_blocks
 
     def kv_occupancy(self) -> float:
         """Fraction of usable KV cache blocks currently reserved (0..1)."""
@@ -854,10 +957,9 @@ class InferenceEngineV2:
         arithmetic on the cache array — never a transfer): the conversion
         the serving gauges use to state occupancy in bytes instead of
         blocks."""
-        nbytes = int(getattr(self.kv.data, "nbytes", 0))
-        if self.kv.scales is not None:
-            nbytes += int(getattr(self.kv.scales, "nbytes", 0))
-        return nbytes // max(self.kv.cfg.num_blocks, 1)
+        nbytes = sum(int(x.nbytes) for x in jax.tree.leaves(self.kv.pool))
+        return nbytes // max(self.kv.cfg.num_blocks
+                             + self.kv.cfg.window_blocks, 1)
 
     def generate(self, prompt_tokens: Sequence[int], max_new_tokens: int = 32,
                  uid: int = 0) -> List[int]:
@@ -927,7 +1029,9 @@ class InferenceEngineV2:
         tokens[1:true_len] = proposed
         logits, self.kv.pool, _ = verify_chunk_g(
             self.params, self.kv.pool, jnp.asarray(tokens), ctx - 1,
-            jnp.asarray(self._block_table(seq, mb)), true_len,
+            jax.tree.map(jnp.asarray,
+                         self._step_tables(seq, mb, ctx - 1, bucket)),
+            true_len,
             policy=self.policy, cfg=self.model_config,
             block_size=self.kv.cfg.block_size,
             attn_impl=self.config.attn_impl)
@@ -953,6 +1057,7 @@ class InferenceEngineV2:
         self._spec_accepted += min(appended, len(emitted) - 1)
         self._spec_steps += 1
         seq.seen_tokens = seq.total_tokens - 1    # last emitted has no KV yet
+        self._advanced(seq)
 
     def speculative_stats(self) -> Dict[str, float]:
         """{steps, proposed, accepted, tokens_per_step} over this engine's

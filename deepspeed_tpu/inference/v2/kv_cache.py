@@ -8,7 +8,10 @@ The pool's format is a *page kind*, chosen once from the cache's config and
 found again inside a step program from the policy's spec and the pool handed
 in (``page_kind``): head-major K and V planes (``_HeadPages``), the same in
 fp8 under per-(head, page) scales (``_ScaledHeadPages``), one headless
-latent plane (``_LatentPages``). A kind owns, and nothing outside this
+latent plane (``_LatentPages``), head pages held by LAYER kind
+(``_LayerKindPages``: a pool of the full layers' pages and a pool of the
+windowed layers', each with its block tables, for a model that mixes the
+two). A kind owns, and nothing outside this
 module knows: the pool's shape and block axis, the trash block, whether the
 step programs carry an array or ``(pages, scales)``, the slots a step's rows
 land in, their write, and the chunk and decode attention over its pages,
@@ -48,6 +51,12 @@ class KVCacheConfig:
     # with no heads (``num_kv_heads`` and ``head_dim`` then say nothing), its
     # rows padded with zero lanes to ``latent_row_width``
     latent_dim: int = 0
+    # pages by layer kind: every layer's window (None: a full layer), given
+    # where a model mixes full and windowed layers; ``window_blocks`` is the
+    # windowed kind's pool (``num_blocks`` the full kind's). Left empty, all
+    # layers' pages are of one kind in one pool
+    layer_windows: Tuple[Optional[int], ...] = ()
+    window_blocks: int = 0
 
 
 def latent_row_width(latent_dim: int) -> int:
@@ -64,23 +73,65 @@ class LatentPageDtypeError(ValueError):
     """A page dtype that a latent (MLA) pool cannot hold."""
 
 
+class TwoPageKindsError(NotImplementedError):
+    """Something that moves block ids of one pool (prefix reuse, the host
+    offload tier, the prefix handoff, fp8 scaled pages) was asked of a cache
+    that keeps pages of two kinds."""
+
+
+def windowed_table_blocks(rows: int, window: int, block_size: int) -> int:
+    """Entries of a windowed layer's block table for a step of ``rows``
+    queries a sequence: the blocks that hold the first query's window and
+    the rows themselves, wherever the first falls in its block."""
+    return (rows + window - 2) // block_size + 2
+
+
+def blocks_behind_window(first_query: int, window: int, block_size: int):
+    """Leading blocks of a sequence that no query from position
+    ``first_query`` on can see through ``window``: key ``j`` is seen by query
+    ``t`` only if ``j > t - window``. The host gives these back; a step
+    program starts a windowed layer's table at this block (ints or jnp)."""
+    lead = (first_query - window + 1) // block_size
+    return lead * (lead > 0)
+
+
 class BlockedKVCache:
     def __init__(self, cfg: KVCacheConfig):
         self.cfg = cfg
-        self.kind = _kind_for(cfg.latent_dim,
-                              cfg.dtype == jnp.float8_e4m3fn)()
+        scaled = cfg.dtype == jnp.float8_e4m3fn
+        if mixes_layer_kinds(cfg.layer_windows):
+            self.kind = _LayerKindPages(cfg.layer_windows)
+        else:
+            self.kind = _kind_for(cfg.latent_dim, scaled)()
         # the last block is the kind's trash block, the target of
         # padding-token writes: never handed out by the allocator
         self.allocator = BlockedAllocator(cfg.num_blocks - 1)
-        # ``scales`` is None unless the kind's pages carry them
+        # ``scales`` is None unless the kind's pages carry them; with pages
+        # by layer kind ``data`` is {"full": pool, "window": pool}
         self.data, self.scales = self.kind.new_pool(cfg)
+        # the windowed kind's own pool, trash block and allocator, and how
+        # many of its blocks sequences have given back from behind their
+        # windows; None where all pages are of one kind
+        self.window_allocator = None
+        self.window_blocks_given_back = 0
+        if self.two_kinds:
+            self.window_allocator = BlockedAllocator(cfg.window_blocks - 1)
+            self._block_bytes = {
+                "full": int(self.data["full"].nbytes) // cfg.num_blocks,
+                "window": int(self.data["window"].nbytes)
+                // cfg.window_blocks}
+        else:
+            self._block_bytes = {"full": int(jax.tree.leaves(
+                self.data)[0].nbytes) // cfg.num_blocks}
 
     @classmethod
     def for_spec(cls, spec, kv_cache_dtype: str, block_size: int,
-                 num_blocks: int) -> "BlockedKVCache":
+                 num_blocks: int, window_blocks: int = 0) -> "BlockedKVCache":
         """The cache a policy's ``KVCacheSpec`` asks for, its pages stored as
         the engine's ``kv_cache_dtype`` says: ``"model"`` (the spec's compute
-        dtype) or ``"fp8"`` (float8_e4m3 pages under scales)."""
+        dtype) or ``"fp8"`` (float8_e4m3 pages under scales). Where the spec
+        mixes full and windowed layers, ``num_blocks`` is the full layers'
+        pool and ``window_blocks`` the windowed layers'."""
         dtypes = {"model": spec.dtype, "fp8": jnp.float8_e4m3fn}
         if kv_cache_dtype not in dtypes:
             raise ValueError(f"unknown kv_cache_dtype {kv_cache_dtype!r}; "
@@ -89,7 +140,107 @@ class BlockedKVCache:
             num_layers=spec.num_layers, num_kv_heads=spec.num_kv_heads,
             head_dim=spec.head_dim, block_size=block_size,
             num_blocks=num_blocks, dtype=dtypes[kv_cache_dtype],
-            latent_dim=spec.latent_dim))
+            latent_dim=spec.latent_dim,
+            layer_windows=tuple(spec.layer_windows or ()),
+            window_blocks=window_blocks))
+
+    # ------------------------------------------------------------------
+    # pages by layer kind: the windowed pool's host side
+    # ------------------------------------------------------------------
+    @property
+    def two_kinds(self) -> bool:
+        return isinstance(self.kind, _LayerKindPages)
+
+    def require_one_kind(self, what: str) -> None:
+        """Raise by name where ``what`` moves block ids of one pool and this
+        cache keeps two."""
+        if self.two_kinds:
+            raise TwoPageKindsError(
+                f"{what} moves block ids of one pool, and this cache keeps "
+                f"pages of two kinds (full layers' and windowed layers', each "
+                f"with its own block tables): not supported over it")
+
+    @property
+    def window_steady_blocks(self) -> int:
+        """Most windowed blocks a sequence holds between two steps: those of
+        its next query's window."""
+        return windowed_table_blocks(1, self.kind.window, self.cfg.block_size)
+
+    def window_step_blocks(self, rows: int) -> int:
+        """Most windowed blocks one sequence holds while a step of ``rows``
+        of its tokens runs, over what it holds between steps."""
+        return windowed_table_blocks(rows, self.kind.window,
+                                     self.cfg.block_size) \
+            - self.window_steady_blocks
+
+    def blocks_of(self, num_tokens: int) -> int:
+        """Blocks that hold ``num_tokens`` tokens of one sequence."""
+        return -(-int(num_tokens) // self.cfg.block_size)
+
+    def lacking(self, seq, num_tokens: int) -> Tuple[int, int]:
+        """(full, windowed) blocks that ``seq`` (None: a sequence not yet
+        admitted) lacks to hold ``num_tokens`` tokens between steps."""
+        whole = self.blocks_of(num_tokens)
+        full = whole - (len(seq.blocks) if seq is not None else 0)
+        if not self.two_kinds:
+            return full, 0
+        held = len(seq.window_blocks) if seq is not None else 0
+        return full, max(min(whole, self.window_steady_blocks) - held, 0)
+
+    def ensure_window(self, seq, up_to_tokens: int) -> None:
+        """Windowed blocks for ``seq`` up to ``up_to_tokens`` tokens."""
+        need = self.blocks_of(up_to_tokens) - seq.window_base \
+            - len(seq.window_blocks)
+        if need > 0:
+            seq.window_blocks.extend(self.window_allocator.allocate(need))
+
+    def give_back_behind_window(self, seq) -> int:
+        """Give back the windowed blocks no query of ``seq`` from its next
+        position (``seen_tokens``) on can see. Returns how many."""
+        dead = int(blocks_behind_window(seq.seen_tokens, self.kind.window,
+                                        self.cfg.block_size)) \
+            - seq.window_base
+        if dead <= 0:
+            return 0
+        self.window_allocator.free(seq.window_blocks[:dead])
+        del seq.window_blocks[:dead]
+        seq.window_base += dead
+        self.window_blocks_given_back += dead
+        return dead
+
+    def release_window(self, seq) -> None:
+        """All of ``seq``'s windowed blocks back to their allocator."""
+        self.window_allocator.free(seq.window_blocks)
+        seq.window_blocks = []
+        seq.window_base = 0
+
+    def window_table(self, seq, first_query: int, rows: int) -> np.ndarray:
+        """A windowed layer's block table for a step whose first query of
+        ``seq`` is at ``first_query``, ``rows`` queries long: entry ``k`` is
+        the block that holds tokens ``(base + k) * block_size ..``, ``base``
+        the blocks behind the first query's window (the step program derives
+        the same from the position); the trash block where ``seq`` holds
+        none."""
+        n = windowed_table_blocks(rows, self.kind.window, self.cfg.block_size)
+        table = np.full((n,), self.cfg.window_blocks - 1, np.int32)
+        skip = int(blocks_behind_window(first_query, self.kind.window,
+                                        self.cfg.block_size)) - seq.window_base
+        live = seq.window_blocks[skip:skip + n]
+        table[:len(live)] = live
+        return table
+
+    def pages_held(self) -> dict:
+        """Blocks sequences hold now, by kind, and their bytes."""
+        full = self.allocator.total_blocks - self.allocator.free_blocks
+        if not self.two_kinds:
+            return {"full_blocks": full,
+                    "held_bytes": full * self._block_bytes["full"]}
+        win = self.window_allocator.total_blocks \
+            - self.window_allocator.free_blocks
+        return {"full_blocks": full, "window_blocks": win,
+                "window_blocks_given_back": self.window_blocks_given_back,
+                "held_bytes": full * self._block_bytes["full"]
+                + win * self._block_bytes["window"]}
 
     @property
     def pool(self):
@@ -110,10 +261,22 @@ class BlockedKVCache:
 
     @property
     def free_blocks(self) -> int:
+        """Free blocks, of both kinds where there are two."""
+        if self.window_allocator is not None:
+            return self.allocator.free_blocks + \
+                self.window_allocator.free_blocks
         return self.allocator.free_blocks
 
+    @property
+    def usable_blocks(self) -> int:
+        """Blocks sequences can hold (each kind's last is its trash block)."""
+        return self.cfg.num_blocks - 1 + (
+            self.cfg.window_blocks - 1 if self.two_kinds else 0)
+
     def blocks_needed(self, num_tokens: int) -> int:
-        return int(np.ceil(num_tokens / self.cfg.block_size))
+        """Blocks a sequence of ``num_tokens`` tokens holds between steps:
+        with two kinds, its full layers' and its windowed layers'."""
+        return sum(self.lacking(None, num_tokens))
 
     def reserve(self, num_blocks: int) -> List[int]:
         """reference: kv_cache.py:144 reserve."""
@@ -147,6 +310,8 @@ class BlockedKVCache:
         axis. A deliberate
         device->host transfer — demotion runs OFF the per-tick fast path,
         only when the serving tier policy decides to spill."""
+        self.require_one_kind("gathering a sequence's blocks (KV offload, "
+                              "prefix handoff)")
         idx = np.asarray(blocks, np.int32)
         data = np.asarray(jnp.take(self.data, idx, axis=self.kind.block_axis))
         scales = (np.asarray(self.scales[:, :, :, idx])
@@ -544,6 +709,111 @@ class _LatentPages(_Pages):
                                   positions, w_ukv, scale, attn_impl), cache
 
 
+def mixes_layer_kinds(layer_windows) -> bool:
+    """Whether a spec's per-layer windows name full layers and windowed ones
+    (then the cache keeps pages by layer kind)."""
+    kinds = set(layer_windows or ())
+    if len(kinds - {None}) > 1:
+        raise ValueError(f"layers of several window widths "
+                         f"{sorted(kinds - {None})}: one windowed kind only")
+    return None in kinds and len(kinds) == 2
+
+
+class _LayerKindPages:
+    """Head pages held by layer kind, for a model that mixes full and
+    windowed layers: ``{"full": [L_full, 2, H_kv, NB, bs, D], "window":
+    [L_window, 2, H_kv, NB_w, bs, D]}``, each pool with its own trash block,
+    allocator and block tables (the step programs carry ``{"full": table,
+    "window": table}`` likewise). A full layer's table names every block of
+    the sequence. A windowed layer's names the blocks from the one that
+    holds the first query's window on (``blocks_behind_window``, which host
+    and program both derive from the position: nothing else is handed in)
+    and is ``windowed_table_blocks`` long whatever the context, so its
+    kernel's grid is the window's pages and the blocks behind can be given
+    back. Inside, each kind is ``_HeadPages`` over its own pool with
+    positions counted from its table's first block."""
+
+    def __init__(self, layer_windows):
+        self.window, = set(layer_windows) - {None}
+        self.kinds = tuple("window" if w else "full" for w in layer_windows)
+        # a layer's index in its kind's pool
+        self.local = tuple(self.kinds[:i].count(k)
+                           for i, k in enumerate(self.kinds))
+        self.pages = {"full": _HeadPages(None),
+                      "window": _HeadPages(self.window)}
+
+    def new_pool(self, cfg: KVCacheConfig):
+        if cfg.dtype == jnp.float8_e4m3fn:
+            raise TwoPageKindsError(
+                "fp8 scaled pages are not supported over a cache that keeps "
+                "pages of two kinds (full layers' and windowed layers'); use "
+                "kv_cache_dtype='model'")
+        if cfg.window_blocks < 2:
+            raise ValueError(f"window_blocks {cfg.window_blocks}: the "
+                             f"windowed layers' pool needs its trash block "
+                             f"and at least one more")
+
+        def pool(kind, blocks):
+            return jnp.zeros((self.kinds.count(kind), 2, cfg.num_kv_heads,
+                              blocks, cfg.block_size, cfg.head_dim), cfg.dtype)
+        return {"full": pool("full", cfg.num_blocks),
+                "window": pool("window", cfg.window_blocks)}, None
+
+    def _behind(self, first_query, block_size: int):
+        """Tokens before a windowed table's first block."""
+        return blocks_behind_window(first_query, self.window,
+                                    block_size) * block_size
+
+    def chunk_slots(self, cache, block_table, start, safe_pos, valid,
+                    block_size: int):
+        behind = self._behind(start, block_size)
+        return {"full": self.pages["full"].chunk_slots(
+                    cache["full"], block_table["full"], start, safe_pos,
+                    valid, block_size),
+                "window": self.pages["window"].chunk_slots(
+                    cache["window"], block_table["window"], start - behind,
+                    safe_pos - behind, valid, block_size),
+                "behind": behind}
+
+    def decode_slots(self, cache, block_tables, safe_pos, valid,
+                     block_size: int):
+        behind = self._behind(safe_pos, block_size)
+        return {"full": self.pages["full"].decode_slots(
+                    cache["full"], block_tables["full"], safe_pos, valid,
+                    block_size),
+                "window": self.pages["window"].decode_slots(
+                    cache["window"], block_tables["window"],
+                    safe_pos - behind, valid, block_size),
+                "behind": behind}
+
+    def _attend(self, how, cache, layer, slots, tables, positions, *args,
+                **kwargs):
+        kind = self.kinds[layer]
+        attend = getattr(self.pages[kind], how)
+        if kind == "window":
+            with jax.named_scope("attn/window"):
+                out, pool = attend(
+                    cache[kind], self.local[layer], slots[kind],
+                    tables[kind], positions - slots["behind"], *args,
+                    **kwargs)
+        else:
+            with jax.named_scope("attn/full"):
+                out, pool = attend(cache[kind], self.local[layer],
+                                   slots[kind], tables[kind], positions,
+                                   *args, **kwargs)
+        return out, {**cache, kind: pool}
+
+    def attend_chunk(self, cache, layer, slots, block_table, start, *args,
+                     **how):
+        return self._attend("attend_chunk", cache, layer, slots, block_table,
+                            start, *args, **how)
+
+    def attend_decode(self, cache, layer, slots, block_tables, positions,
+                      *args, **how):
+        return self._attend("attend_decode", cache, layer, slots,
+                            block_tables, positions, *args, **how)
+
+
 def _kind_for(latent_dim: int, scaled: bool) -> type:
     if latent_dim:
         return _LatentPages
@@ -552,6 +822,8 @@ def _kind_for(latent_dim: int, scaled: bool) -> type:
 
 def page_kind(spec, cache):
     """The kind of the pool a step program was handed, from the policy's
-    ``KVCacheSpec`` and the pool's structure: ``(pages, scales)`` or pages
-    alone."""
+    ``KVCacheSpec`` and the pool's structure: ``(pages, scales)``, pages
+    alone, or a pool a layer kind."""
+    if isinstance(cache, dict):
+        return _LayerKindPages(spec.layer_windows)
     return _kind_for(spec.latent_dim, isinstance(cache, tuple))(spec.window)

@@ -58,6 +58,11 @@ class KVCacheSpec:
     # of every query head and its leading compressed part the value
     # (``num_kv_heads`` 1, ``head_dim`` the row). 0: K and V planes
     latent_dim: int = 0
+    # every layer's window (None: a full layer) for a model that mixes full
+    # and windowed layers: the cache then keeps pages by layer kind
+    # (``kv_cache._LayerKindPages``) and ``window`` says nothing. None: all
+    # layers' pages are of one kind
+    layer_windows: Any = None
 
 
 def register_policy(name: str, config_type: type):
@@ -89,22 +94,29 @@ def _layernorm(x, scale, bias, eps):
     return ((x32 - mu) * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
 
 
-def _rope_tables(head_dim, max_seq_len, theta):
-    """Rope tables as trace-local jnp constants. The numpy compute is cached in
+def _rope_tables(head_dim, max_seq_len, theta, yarn=None):
+    """Rope tables as trace-local jnp constants: ``head_dim`` is the width
+    that rotates (a partial rotary part passes its own), ``yarn`` a
+    ``models.llama.YarnScaling`` or None. The numpy compute is cached in
     ``rope_freqs`` (identical ndarray objects across layers → XLA CSEs the
     constants); the jnp conversion must NOT be cached — a jnp array created
     under one jit trace is a tracer and may not leak into the next trace."""
-    cos, sin = rope_freqs(head_dim, max_seq_len, theta)
+    cos, sin = rope_freqs(head_dim, max_seq_len, theta, yarn)
     return jnp.asarray(cos), jnp.asarray(sin)
 
 
 def _rope_rows(x, cos, sin, positions):
-    """x: [N, H, d]; positions: [N] — rotary on per-row absolute positions."""
+    """x: [N, H, d]; positions: [N] — rotary on per-row absolute positions,
+    rotate-half over the leading ``2 * cos.shape[-1]`` dims; the dims past
+    them (a partial rotary part) pass through."""
+    rot = 2 * cos.shape[-1]
     cos_p = cos[positions][:, None, :]
     sin_p = sin[positions][:, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p], -1)
-    return out.astype(x.dtype)
+    x1, x2 = jnp.split(x[..., :rot].astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos_p - x2 * sin_p, x2 * cos_p + x1 * sin_p],
+                          -1).astype(x.dtype)
+    return out if rot == x.shape[-1] else \
+        jnp.concatenate([out, x[..., rot:]], -1)
 
 
 # ---------------------------------------------------------------------------
@@ -278,13 +290,14 @@ def _expert_matmul_impl() -> str:
     return "kernel" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def _routed_sum(experts, h2, weights, ids, valid, impl):
+@functools.partial(jax.jit, static_argnames=("impl", "first"))
+def _routed_sum(experts, h2, weights, ids, valid, impl, first=0):
     """``_chosen_experts`` by one ``impl``. A function of its own under
     ``jit`` so that a step program traces and lowers it once and not once a
     layer (its layers' shapes are the same)."""
     if impl == "ragged_dot":
-        y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid)
+        y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid,
+                                     first=first)
         tile_rows = jnp.sum(rows)        # no tiles: every row fills its own
     else:
         from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
@@ -293,25 +306,31 @@ def _routed_sum(experts, h2, weights, ids, valid, impl):
             h2, experts, weights, ids, valid,
             matmul=functools.partial(gmm.grouped_matmul, interpret=interpret),
             gate_up=functools.partial(gmm.grouped_gate_up,
-                                      interpret=interpret))
+                                      interpret=interpret), first=first)
         tm = gmm.tiling(ids.size, *experts["w_gate"].shape, h2.dtype, 2)[0]
         tile_rows = gmm.visited_tile_rows(rows, ids.size, tm)
-    # the layer's counts, in the order of ``STEP_COUNTER_ARGS``
-    return y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0),
-                         tile_rows]).astype(jnp.int32)
+    # the layer's counts, in the order of ``STEP_COUNTER_ARGS``; the
+    # assignments of rows that are no padding to experts held elsewhere are
+    # what is left of the router's choices
+    absent = jnp.sum(valid) * ids.shape[1] - jnp.sum(rows)
+    return y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0), tile_rows,
+                         absent]).astype(jnp.int32)
 
 
-def _chosen_experts(experts, h2, weights, ids, valid):
+def _chosen_experts(experts, h2, weights, ids, valid, first=0):
     """The routed sum of ``moe/grouped_experts.py`` (the chosen experts alone
     compute, bucket padding rows take none; on a TPU through the Pallas
     grouped matmuls, gate and up in one pass) and the layer's counts of
     ``STEP_COUNTER_ARGS``: two sums over the rows on each expert, which the
-    router has anyway, and the rows of the tiles the grouped matmul visited,
-    from what the kernel is given. The scope is opened around the call: the
-    callee's operations are lowered once, without their caller's names."""
+    router has anyway, the rows of the tiles the grouped matmul visited,
+    from what the kernel is given, and the assignments left out because
+    their expert is not among the stacked ones (``first``: these are the
+    router's experts ``first ..``, one chip's share; 0 for a layer held
+    whole). The scope is opened around the call: the callee's operations are
+    lowered once, without their caller's names."""
     with jax.named_scope("moe/experts"):
         return _routed_sum(experts, h2, weights, ids, valid,
-                           impl=_expert_matmul_impl())
+                           impl=_expert_matmul_impl(), first=first)
 
 
 def _softmax_moe(moe, h2, cfg, valid):
@@ -731,6 +750,78 @@ class JoyAIFlashPolicy:
         if cfg.n_shared_experts:
             with jax.named_scope("moe/shared"):
                 y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
+        return x + y, counts
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return x.astype(jnp.float32) @ \
+            params["lm_head"]["kernel"].astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Laguna (full and sliding layers of different head counts over pages by
+# layer kind, a per-head output gate, YaRN partial rope in full layers, a
+# held share of softmax-routed experts, a shared expert, a leading dense
+# layer)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.models import laguna as _laguna  # noqa: E402
+
+
+@register_policy("laguna", _laguna.LagunaConfig)
+class LagunaPolicy:
+    """models/laguna.py's serving twin. ``block`` decides heads, rope and
+    window by the layer's index; the cache keeps the full layers' pages and
+    the sliding layers' in a pool each (``cache_spec``'s ``layer_windows``),
+    and the page kind attends a sliding layer over its window's blocks. The
+    experts stacked here are the router's ``first_expert ..`` (one chip's
+    share): the router keeps its width and top-k, what falls on an absent
+    expert is left out and counted (``expert_rows_absent``)."""
+
+    @staticmethod
+    def cache_spec(cfg) -> KVCacheSpec:
+        return KVCacheSpec(
+            cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.max_seq_len,
+            cfg.dtype, None,
+            layer_windows=tuple(cfg.window(i) for i in range(cfg.num_layers)))
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        ap = lp["attn"]
+        dtype, eps = cfg.dtype, cfg.rms_norm_eps
+        rope = cfg.rope(i)
+        cos, sin = _rope_tables(
+            int(cfg.head_dim * rope.partial_rotary_factor), cfg.max_seq_len,
+            rope.theta, rope.yarn)
+        with jax.named_scope("attn/qkv"):
+            h = _rms(x, lp["attn_norm"]["scale"], eps)
+            q, k, v = _qkv(lp, h, dtype)
+            q = _rope_rows(q, cos, sin, positions)
+            k = _rope_rows(k, cos, sin, positions)
+        attn = attend(q, k, v)
+        with jax.named_scope("attn/gate"):
+            gate = jax.nn.sigmoid(
+                (h @ ap["wg"]["kernel"].astype(dtype)).astype(jnp.float32))
+            attn = attn * gate[..., None].astype(dtype)
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thk,hkd->td", attn,
+                               ap["wo"]["kernel"].astype(dtype))
+        h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
+        if cfg.is_dense(i):
+            with jax.named_scope("mlp"):
+                return x + _mlp(lp, h2, dtype), None
+        moe = lp["moe"]
+        with jax.named_scope("moe/router"):
+            weights, ids = _laguna.route(h2, moe, cfg)
+        y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid,
+                                    first=cfg.first_expert)
+        with jax.named_scope("moe/shared"):
+            y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
         return x + y, counts
 
     @staticmethod

@@ -16,6 +16,11 @@ class SequenceDescriptor:
     uid: int
     prompt_tokens: np.ndarray                 # full prompt (host)
     blocks: List[int] = dataclasses.field(default_factory=list)
+    # over a cache that keeps pages by layer kind: the windowed layers' live
+    # blocks, the first of them the sequence's block ``window_base`` (those
+    # behind were given back as the window moved on)
+    window_blocks: List[int] = dataclasses.field(default_factory=list)
+    window_base: int = 0
     seen_tokens: int = 0                      # tokens whose KV is in cache
     generated: List[int] = dataclasses.field(default_factory=list)
     done: bool = False

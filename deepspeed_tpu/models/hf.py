@@ -139,6 +139,8 @@ _REGISTRY = {
                                      "joyai_flash_config_from_hf",
                                      "JoyAIFlashForCausalLM",
                                      "convert_hf_joyai_flash"),
+    "laguna": _family_entry("laguna", "laguna_config_from_hf",
+                            "LagunaForCausalLM", "convert_hf_laguna"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

@@ -143,15 +143,61 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(orig_dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN (arXiv:2309.00071) over a rotary part: frequencies whose
+    wavelength fits ``original_max_position`` fewer than ``beta_slow`` times
+    are divided by ``factor``, those that fit it more than ``beta_fast``
+    times are kept, a linear ramp over the dims between blends the two, and
+    ``cos`` and ``sin`` are multiplied by ``attention_factor`` (0.1 ln
+    ``factor`` + 1 where the config gives none)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+def yarn_inv_freq(rotary_dim: int, theta: float,
+                  yarn: YarnScaling) -> np.ndarray:
+    """[rotary_dim / 2] float64 inverse frequencies under ``yarn``."""
+    pos_freqs = theta ** (np.arange(0, rotary_dim, 2, dtype=np.float64)
+                          / rotary_dim)
+
+    def correction_dim(rotations):
+        return rotary_dim * np.log(yarn.original_max_position
+                                   / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+    low = max(np.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(np.ceil(correction_dim(yarn.beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rotary_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    return (1 / (yarn.factor * pos_freqs)) * ramp + (1 / pos_freqs) * (1 - ramp)
+
+
 @lru_cache(maxsize=32)
-def rope_freqs(head_dim: int, max_len: int, theta: float) -> Tuple[np.ndarray, np.ndarray]:
+def rope_freqs(head_dim: int, max_len: int, theta: float,
+               yarn: Optional[YarnScaling] = None
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """(cos, sin) [max_len, head_dim / 2] float32. ``head_dim`` is the width
+    that rotates: a model with a partial rotary part passes that part's, and
+    ``_rope_rows`` lets the dims past it through."""
     # cached: serving policies call this per layer per trace; the cache also
     # keeps the returned ndarrays identical objects so tracers embed one
     # constant instead of num_layers copies
-    inv = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if yarn is None:
+        inv = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+        scale = 1.0
+    else:
+        inv = yarn_inv_freq(head_dim, theta, yarn)
+        scale = yarn.attention_factor if yarn.attention_factor is not None \
+            else 0.1 * np.log(yarn.factor) + 1.0
     t = np.arange(max_len, dtype=np.float64)
     freqs = np.outer(t, inv)
-    return np.cos(freqs).astype(np.float32), np.sin(freqs).astype(np.float32)
+    return (np.cos(freqs) * scale).astype(np.float32), \
+        (np.sin(freqs) * scale).astype(np.float32)
 
 
 def apply_rope(x, cos, sin, positions):

@@ -47,7 +47,8 @@ def softmax_route(h, gate_kernel, top_k: int, norm_topk_prob: bool):
 
 
 def grouped_expert_ffn(h, experts, weights, ids, valid=None,
-                       matmul=jax.lax.ragged_dot, gate_up=None):
+                       matmul=jax.lax.ragged_dot, gate_up=None,
+                       first: int = 0):
     """``sum_k weights[t, k] * E_ids[t, k](h[t])`` with each ``E`` a gated
     MLP of the stacked weights ``experts`` (``w_gate``, ``w_up`` [E, D, F];
     ``w_down`` [E, F, D]). ``h``: [T, D] in the compute type; ``weights``,
@@ -57,14 +58,24 @@ def grouped_expert_ffn(h, experts, weights, ids, valid=None,
     where given, computes ``silu(rows @ w_gate) * (rows @ w_up)`` a group in
     one pass. Returns (y [T, D], rows on each expert [E] int32).
 
+    ``experts`` may be a share of the router's: the ``E`` stacked here are the
+    router's experts ``first .. first + E - 1`` (expert parallelism's share
+    of one chip; the router keeps its full width and its top-k). An
+    assignment to an expert that is not held is left out like a padding
+    row's, its part of the sum is the absent chip's to add, and ``counts``
+    are of the held experts alone.
+
     Every shape is static: the T*K assignments are sorted by expert with
-    those of rows left out last, the grouped matmul visits only the rows its
-    group sizes cover, and the rows past them (uninitialised in its output)
-    are zeroed before they are gathered back."""
+    those left out last, the grouped matmul visits only the rows its group
+    sizes cover, and the rows past them (uninitialised in its output) are
+    zeroed before they are gathered back."""
     t, k = ids.shape
     e = experts["w_gate"].shape[0]
-    keep = jnp.ones((t, 1), bool) if valid is None else valid[:, None]
-    key = jnp.where(keep, ids, e).reshape(-1)                    # [T*K]
+    held = ids - first
+    keep = (held >= 0) & (held < e)
+    if valid is not None:
+        keep = keep & valid[:, None]
+    key = jnp.where(keep, held, e).reshape(-1)                   # [T*K]
     order = jnp.argsort(key, stable=True)
     counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
     dtype = h.dtype

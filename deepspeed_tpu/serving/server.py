@@ -258,6 +258,9 @@ class InferenceServer:
         # them; minimal doubles in tests may not)
         self._tier_capable = (self.config.kv_offload_enabled
                               and hasattr(engine, "demote_kv"))
+        if self._tier_capable and hasattr(engine, "require_one_page_kind"):
+            # refused by name here, not at the first demotion under load
+            engine.require_one_page_kind("the host KV offload tier")
         from deepspeed_tpu.inference.v2.kv_offload import KV_CODECS
         if self.config.host_kv_quantize not in KV_CODECS:
             raise ValueError(

@@ -111,6 +111,7 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/prefill": ("complete",),
     "serve/decode": ("complete",),
     "serve/kv_bytes": ("counter",),
+    "serve/kv_pages": ("counter",),         # blocks held by kind of page, a tick
     "serve/tick_stage_share": ("counter",),
     "serve/kv_tier": ("counter",),
     "serve/prefix_cache": ("counter",),
@@ -211,10 +212,13 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: readers match (``generic_decode.py``, ``modules.py``, ``kv_cache.py``).
 #: The ``attn/latent_*`` four are a latent (MLA) cache's: the low-rank
 #: projections and the fold, the row's write, the paged decode kernel with
-#: the value unfold, a chunk's gather, up-projection and prefill kernel
+#: the value unfold, a chunk's gather, up-projection and prefill kernel.
+#: ``attn/full`` and ``attn/window`` hold a layer's write and paged attention
+#: where a cache keeps pages by layer kind, ``attn/gate`` a per-head output
+#: gate
 SERVED_SCOPES: Tuple[str, ...] = (
     "embed", "attn/qkv", "attn/kv_write", "attn/paged", "attn/out",
-    "attn/latent_q", "attn/latent_write", "attn/latent_paged",
+    "attn/full", "attn/window", "attn/gate", "attn/latent_q", "attn/latent_write", "attn/latent_paged",
     "attn/latent_prefill", "mlp", "moe/router", "moe/experts", "moe/shared",
     "lm_head", "sample")
 
@@ -223,13 +227,16 @@ SERVED_SCOPES: Tuple[str, ...] = (
 #: out: token-expert pairs of the step, experts with at least one row, and
 #: rows of the tiles the grouped matmul visited (``grouped_matmul.py`` under
 #: ``ops/pallas``; ``expert_rows`` over it is the tiles' fill, 1 where
-#: ``jax.lax.ragged_dot`` runs and there are no tiles to count), each summed
-#: over the expert layers. The engine reads them with the sampled token and
+#: ``jax.lax.ragged_dot`` runs and there are no tiles to count), and the
+#: assignments left out because their expert is held on another chip (0
+#: where a layer's experts are held whole), each summed over the expert
+#: layers. The engine reads them with the sampled token and
 #: puts them on ``serve/prefill_chunk`` and ``serve/step_decode`` as args of
 #: these names; a chunk that ends no prompt is not waited for, so its counts
 #: ride on the next of these spans that is
 STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched",
-                                      "expert_tile_rows")
+                                      "expert_tile_rows",
+                                      "expert_rows_absent")
 
 #: per-request tracing namespace (reqtrace.py file-loads this module
 #: standalone, same contract as the tables above). Spans carrying a

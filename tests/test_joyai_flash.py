@@ -536,7 +536,7 @@ def test_latent_pool_is_donated_and_aliased(f32, fn_name):
     assert "may-alias" in compiled.as_text().splitlines()[0]
     out, back, counts = fn(params, pool, *tail, **kw)
     assert pool.is_deleted() and not back.is_deleted()
-    assert back.shape == (4, 16, 16, 128) and counts.shape == (3,)
+    assert back.shape == (4, 16, 16, 128) and counts.shape == (4,)
     assert np.isfinite(np.asarray(out)).all()
 
 
@@ -555,7 +555,8 @@ def test_counts_ride_on_the_spans_that_wait(f32):
     finally:
         tracer.configure(enabled=was)
     assert names.STEP_COUNTER_ARGS == ("expert_rows", "experts_touched",
-                                       "expert_tile_rows")
+                                       "expert_tile_rows",
+                                       "expert_rows_absent")
     # the registry is what the served modules open, no more and no less
     import pathlib
     import re

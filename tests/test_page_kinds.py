@@ -175,3 +175,84 @@ def test_gather_then_scatter_into_other_blocks_is_bit_identical(name):
     for got, was in zip(other_blocks(name, dst.pool, [9, 1, 4]),
                         other_blocks(name, empty, [9, 1, 4])):
         np.testing.assert_array_equal(got, was)
+
+
+# --- pages by layer kind ------------------------------------------------------
+
+def test_windowed_kind_gives_blocks_back_and_refuses_what_moves_one_pools_ids(
+        tmp_path):
+    """A cache over full and windowed layers (window 6 over blocks of 4): a
+    pool a kind with its own trash block and allocator; a windowed layer's
+    table starts at the block that holds the first query's window and is as
+    long as window + rows need, whatever the context; the blocks behind are
+    given back as the sequence advances, and a release makes both allocators
+    whole. Prefix reuse, the host offload tier and the prefix handoff move
+    block ids of one pool and refuse this cache by name."""
+    from deepspeed_tpu.inference.v2.kv_cache import (TwoPageKindsError,
+                                                     blocks_behind_window,
+                                                     windowed_table_blocks)
+    from deepspeed_tpu.inference.v2.ragged_manager import SequenceDescriptor
+    windows = (None, 6, 6)
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+        num_blocks=NB, dtype=jnp.float32, layer_windows=windows,
+        window_blocks=7))
+    spec = KVCacheSpec(3, H, D, 64, jnp.float32, None, layer_windows=windows)
+    kind = page_kind(spec, kv.pool)
+    assert type(kind) is type(kv.kind) and kv.two_kinds
+    assert {k: v.shape for k, v in kv.pool.items()} == {
+        "full": (1, 2, H, NB, BS, D), "window": (2, 2, H, 7, BS, D)}
+    assert (kv.allocator.total_blocks, kv.window_allocator.total_blocks) \
+        == (NB - 1, 6)
+    assert kv.usable_blocks == kv.free_blocks == NB - 1 + 6
+    # a sequence between steps holds the window's blocks: 6 tokens end in at
+    # most three blocks of four
+    assert kv.window_steady_blocks == windowed_table_blocks(1, 6, BS) == 3
+    assert kv.blocks_needed(40) == 10 + 3 and kv.blocks_needed(5) == 2 + 2
+    assert [int(blocks_behind_window(q, 6, BS)) for q in (0, 5, 8, 9, 13)] \
+        == [0, 0, 0, 1, 2]
+
+    seq = SequenceDescriptor(uid=1, prompt_tokens=np.zeros(30, np.int32))
+    seq.blocks = kv.reserve(3)
+    kv.ensure_window(seq, 10)                     # a chunk of 10 from 0
+    assert len(seq.window_blocks) == 3 and seq.window_base == 0
+    table = kv.window_table(seq, 0, 10)
+    # five entries for ten rows behind a window of six; 6 is the trash block
+    assert table.tolist() == seq.window_blocks + [6, 6]
+    seq.seen_tokens = 10
+    assert kv.give_back_behind_window(seq) == 1   # block 0: tokens 0-3
+    assert (seq.window_base, len(seq.window_blocks)) == (1, 2)
+    assert kv.window_blocks_given_back == 1
+    # one token at position 10: its window is tokens 5-10, blocks 1 and 2
+    assert kv.window_table(seq, 10, 1).tolist() == seq.window_blocks + [6]
+    slots = kind.decode_slots(kv.pool, {
+        "full": jnp.asarray([seq.blocks + [TRASH]], jnp.int32),
+        "window": jnp.asarray([kv.window_table(seq, 10, 1)])},
+        jnp.asarray([10]), jnp.asarray([True]), BS)
+    assert int(slots["behind"][0]) == 4
+    assert (int(slots["full"][0][0]), int(slots["full"][1][0])) \
+        == (seq.blocks[2], 2)
+    assert (int(slots["window"][0][0]), int(slots["window"][1][0])) \
+        == (seq.window_blocks[1], 2)
+    held = kv.pages_held()
+    assert (held["full_blocks"], held["window_blocks"]) == (3, 2)
+    assert held["held_bytes"] == 3 * kv.data["full"].nbytes // NB \
+        + 2 * kv.data["window"].nbytes // 7
+    kv.release(seq.blocks)
+    kv.release_window(seq)
+    assert kv.free_blocks == kv.usable_blocks and seq.window_blocks == []
+
+    for moved in (lambda: kv.gather_blocks([0]),
+                  lambda: kv.require_one_kind("the prefix cache")):
+        with pytest.raises(TwoPageKindsError, match="two kinds"):
+            moved()
+    with pytest.raises(TwoPageKindsError, match="fp8"):
+        BlockedKVCache(KVCacheConfig(
+            num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+            num_blocks=NB, dtype=FP8, layer_windows=windows, window_blocks=7))
+    # one window for all layers is one kind, as before
+    one = BlockedKVCache(KVCacheConfig(
+        num_layers=2, num_kv_heads=H, head_dim=D, block_size=BS,
+        num_blocks=NB, dtype=jnp.float32, layer_windows=(6, 6)))
+    assert not one.two_kinds and one.window_allocator is None
+    assert one.blocks_needed(40) == 10 and one.usable_blocks == NB - 1

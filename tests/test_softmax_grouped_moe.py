@@ -169,7 +169,8 @@ def test_chunk_logits_are_the_models_and_padding_rows_take_no_expert(family):
         jnp.int32(11), policy=policy, cfg=cfg, block_size=16,
         attn_impl="gather")
     np.testing.assert_allclose(got, logits(prompt)[-1], atol=2e-4)
-    rows, touched, tile_rows = np.asarray(counts).tolist()
+    rows, touched, tile_rows, absent = np.asarray(counts).tolist()
+    assert absent == 0               # every expert of the layer is held
     if matmul == "ragged-dot":
         assert tile_rows == rows      # no tiles to count: a row fills its own
     else:                             # whole tiles, no fewer than the rows

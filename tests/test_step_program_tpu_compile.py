@@ -267,3 +267,75 @@ def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
     assert stats.temp_size_in_bytes < 8 * 2048 * 14336 * 2
     assert _pool_shaped_moves(text, pool) == []
     assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+
+
+# --- pages by layer kind --------------------------------------------------------
+# Laguna-S-2.1 at its published widths, cut to the leading dense full layer
+# and one sliding expert layer of the chip's 128 experts, so that a compile
+# takes seconds: a pool a kind, 6 and 9 query heads to a KV head.
+
+def _laguna_shapes(one_chip, fn_name):
+    from deepspeed_tpu.inference.v2.kv_cache import windowed_table_blocks
+    from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaConfig,
+                                             LagunaForCausalLM)
+    cfg = LagunaConfig(layer_types=(FULL, SLIDING), heads_per_layer=(48, 72),
+                       experts_held=128, max_seq_len=16640)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(LagunaForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    spec = policy_for(cfg).cache_spec(cfg)
+    assert spec.layer_windows == (None, 512)
+    pool = {kind: jax.ShapeDtypeStruct(
+        (1, 2, spec.num_kv_heads, blocks, BLOCK, spec.head_dim), spec.dtype,
+        sharding=one_chip)
+        for kind, blocks in (("full", NUM_BLOCKS), ("window", 355))}
+    if fn_name == "decode_step_g":
+        tables = {"full": ints(32, 260),
+                  "window": ints(32, windowed_table_blocks(1, 512, BLOCK))}
+        tail = (ints(32), ints(32), tables, jax.ShapeDtypeStruct(
+            (32,), jnp.bool_, sharding=one_chip))
+    else:
+        tables = {"full": ints(260),
+                  "window": ints(windowed_table_blocks(4096, 512, BLOCK))}
+        tail = (ints(4096), ints(), tables, ints())
+    return cfg, (params, pool) + tail, pool
+
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+def test_layer_kind_step_program_updates_both_pools_in_place_on_a_v5e(
+        one_chip, as_on_a_tpu, fn_name):
+    """A pool a layer kind at the cell's largest shapes (32 sequences or a
+    4,096-token chunk over 260 blocks; the windowed layer's table 9 and 73
+    blocks whatever the context): both pools aliased whole, nothing
+    pool-shaped copied, the paged kernel once a layer at 6 and 9 query heads
+    a KV head, the grouped matmul's kernel over the 128 held experts, and
+    four counts beside the logits."""
+    cfg, args, pool = _laguna_shapes(one_chip, fn_name)
+    assert args[4]["window"].shape[-1] == (9 if fn_name == "decode_step_g"
+                                           else 73)
+    compiled = getattr(gd, fn_name).lower(
+        *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+        attn_impl="kernel").compile()
+    text = compiled.as_text()
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize
+                     for p in pool.values())
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert text.splitlines()[0].count("may-alias") >= 2
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+    for p in pool.values():
+        assert _pool_shaped_moves(text, p) == []
+    # a 4,096-token chunk's activations (72 heads of queries are 75 MB)
+    assert stats.temp_size_in_bytes < 1 << 30
+    logits, _, counts = compiled.out_info
+    assert counts.shape == (4,)
+    assert len(jax.tree.leaves(compiled.out_info)) == 4   # two pools
