@@ -524,15 +524,18 @@ class InferenceEngineV2:
             if tracer.enabled:
                 # what the scheduler decided, as plain host ints the step
                 # already holds: the batch and the bucket it was padded to,
-                # and the context the paged kernel had to read (whole, and
-                # cut to the sliding window where the model has one)
+                # the context the paged kernel had to read (whole, and cut
+                # to the sliding window where the model has one) and the
+                # keys of the tiles it read them in
                 window, whole = self._window, sum(contexts)
                 tracer.complete(
                     "serve/step_decode", t_decode, cat="serve",
                     end_ts=t0 + t_decode, tick=tick,
                     batch=len(seqs), bucket=b, ctx_tokens=whole,
                     ctx_tokens_windowed=sum(min(c, window) for c in contexts)
-                    if window else whole, ctx_blocks=mb, **counts)
+                    if window else whole, ctx_blocks=mb,
+                    **self.kv.decode_tile_keys(contexts, mb, window),
+                    **counts)
 
         with tracer.span("serve/step_finish", cat="serve", tick=tick):
             self.tick = tick + 1

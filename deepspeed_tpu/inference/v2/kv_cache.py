@@ -36,7 +36,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    paged_attention_pool, paged_attention_reference)
+    decode_tile_keys, paged_attention_pool, paged_attention_reference)
 
 
 @dataclasses.dataclass
@@ -228,6 +228,30 @@ class BlockedKVCache:
         live = seq.window_blocks[skip:skip + n]
         table[:len(live)] = live
         return table
+
+    def decode_tile_keys(self, contexts, table_blocks: int, window) -> dict:
+        """Keys the paged kernel's live steps cover for a decode batch of
+        ``contexts`` tokens a sequence over full tables of ``table_blocks``:
+        ``tile_keys`` with every context read whole, ``tile_keys_windowed``
+        with the layers that have a ``window`` reading theirs (a windowed
+        kind's table starts behind the window and is as long as it). Each
+        over ``ctx_tokens`` / ``ctx_tokens_windowed`` is the tiles' fill.
+        Empty over a latent pool, which the paged kernel does not read."""
+        bs = self.cfg.block_size
+        if self.cfg.latent_dim:
+            return {}
+        whole = decode_tile_keys(contexts, table_blocks, bs)
+        if window is None:
+            windowed = whole
+        elif self.two_kinds:
+            behind = [int(blocks_behind_window(c - 1, window, bs)) * bs
+                      for c in contexts]
+            windowed = decode_tile_keys(
+                [c - b for c, b in zip(contexts, behind)],
+                self.window_steady_blocks, bs, window)
+        else:
+            windowed = decode_tile_keys(contexts, table_blocks, bs, window)
+        return {"tile_keys": whole, "tile_keys_windowed": windowed}
 
     def pages_held(self) -> dict:
         """Blocks sequences hold now, by kind, and their bytes."""

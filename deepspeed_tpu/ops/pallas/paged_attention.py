@@ -4,21 +4,55 @@ Reference analog: ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash``
 (flash attention over paged KV) + ``atom_builder`` (ragged batch splitting).
 
 TPU design: the block table rides as a **scalar-prefetch** argument
-(``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index map dereferences it
+(``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index maps dereference it
 and the kernel DMAs each sequence's KV pages *directly out of the paged pool in
 HBM* — the gather fallback's [B, MB*bs, H, d] context re-materialization (plus
-rep-times KV expansion for GQA) never exists. Grid (batch, kv_head, page) with
-the page dimension innermost: online-softmax accumulators live in VMEM scratch
-and carry across pages, flash-style.
+rep-times KV expansion for GQA) never exists.
+
+The grid is ``(batch, kv_head, row block, key tile)`` with the key tile
+innermost: online-softmax accumulators live in VMEM scratch and carry across
+tiles, flash-style. **A grid step reads ``P`` consecutive entries of the
+sequence's block table**: ``P`` K slots and ``P`` V slots, each a BlockSpec of
+one ``(block_size, d)`` page whose index map reads its own table entry, so the
+pages still come straight out of the pool. In the kernel the slots are joined
+into ONE ``[P * block_size, d]`` key tile and one value tile: one ``q k^T``,
+one softmax update and one ``p v`` a step. A page a step (what this kernel
+took until PR 36) costs the latency of those two small dependent products
+through the softmax's scratch, not its bytes: a decode fold of 8 rows over a
+260-block table ran at a sixteenth of its memory roofline. The table is padded
+to whole steps with block 0; a padded slot's positions lie above every
+query's, so the causal mask hides it as it hides trash entries. Tiles
+entirely above a row block's causal horizon (or entirely below its sliding
+window) are predicated out with ``pl.when``; the mask itself is per position.
 
 GQA/T folding: the kernel processes one KV head per grid cell; the q rows for
 that cell are the (group × chunk) fold — ``rep`` query heads that share the KV
 head times ``T`` chunk tokens — zero-padded to a multiple of 8 sublanes. Decode
 is T=1; prefill is B=1, T=chunk. A fold too tall for the compiler's scoped VMEM
 (a 2048-token chunk of a 4-way group is 8192 rows) is cut into row blocks on a
-grid axis of their own (``_MAX_FOLD_ELEMS``). Pages entirely above a row
-block's causal horizon (or entirely below its sliding window) are predicated
-out with ``pl.when``.
+grid axis of their own.
+
+**The tile follows the work** (``_tile``; static shapes alone choose, there is
+no option). The key tile is as wide as the table up to ``_MAX_PAGES`` = 8
+pages (512 keys), a power of two; the row block is the fold, or for a taller
+fold the largest power of two of rows that the scoped VMEM holds beside that
+key tile: 2,048 rows beside 8 pages (``_SCOPED_VMEM_BYTES`` says what is
+counted). Swept on a v5e (PERF.md section 6, PR 36): a decode fold of 8 rows
+over a 260-block table takes 11.8 ms a layer at one page a step, 6.3 at 8, 6.1
+at 16 and 6.4 at 32; a 4,096-token chunk of six heads a KV head 73.5 ms at one
+page, 15.0 at 2,048 rows x 8 pages and 12.7 at 1,024 x 16: each step pays a
+pass over the accumulator whatever its keys, so fewer, wider steps win. What
+holds the tile at 8 pages is the host: a slot is an operand of the call, and
+every operand costs a step program's first call (the served cells' `setup_s`).
+A row block cut down to a sliding window's 512 rows loses to 1,024 and 2,048
+(6.6 against 5.9 and 6.1 ms for Laguna's sliding chunk at 8 pages): a narrower
+block multiplies fewer masked pairs, but every row block walks the whole table
+and its dead steps still fetch.
+
+The call sits under a ``jit`` of its own and what differs between the layers
+of a pool (where the layer's K and V heads start) is a prefetched scalar, not a
+constant of the trace: a step program traces and lowers ONE kernel a layer kind
+and calls it once a layer.
 
 Cache layout is head-major ``[Hkv, num_blocks, block_size, d]`` so one page of
 one KV head is a contiguous ``(block_size, d)`` tile (legal TPU block shape).
@@ -34,26 +68,76 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Tallest q fold one grid cell takes, in rows x head_dim elements. The kernel
-# sets no compiler parameters, so it lives inside the default 16 MiB of scoped
-# VMEM: on a v5e (libtpu 0.0.34) 4096 rows x d128 still compiles and 8192 rows
-# are refused at 28 MiB. Half the largest size that fits leaves the margin.
+# The kernel sets no compiler parameters, so it lives inside the default 16 MiB
+# of scoped VMEM. What a grid step holds there, by the sizes the v5e's compiler
+# refused and took (libtpu 0.0.34; PERF.md section 6, PR 36): a row of the
+# fold keeps q and the output twice (the pipeline's two buffers), the float32
+# accumulator, and the running maximum and sum at a lane tile each; a page of
+# the key tile its K and V slots twice and once more joined; and every row of
+# every page a float32 score and a float32 probability. 2,048 rows x 16 pages
+# come to 22.5 MiB by that count and were refused at 22.2; 2,048 x 8 (13.8) and
+# 1,024 x 16 (12.0) compile.
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+# Tallest q fold one grid cell takes whatever the key tile, in rows x head_dim
+# elements: 4096 rows x d128 over one page still compile and 8192 rows are
+# refused. Half the largest size that fits leaves the margin.
 _MAX_FOLD_ELEMS = 2048 * 128
+# Most pages a step joins. From 8 to 16 a decode fold of 8 rows gains 3-17%
+# and a chunk 15-25% (and from 16 to 32 nothing), but every slot is an operand
+# of the call, and a step program's first call costs the host 12 ms a slot a
+# program before its first result (warm `setup_s`: +8.6% at 16 in the
+# Mixtral cell, +4.8% at 8; PERF.md section 6, PR 36), and a context rounds up
+# to whole tiles.
+_MAX_PAGES = 8
 
 
-def _paged_kernel(*refs, block_size, num_pages, chunk, rows,
-                  window, softcap, num_blocks=0):
+def _tile_pages(mb: int) -> int:
+    """Pages of a key tile over a table of ``mb`` blocks: a power of two, so
+    that a tile's keys are whole lane tiles; a table shorter than
+    ``_MAX_PAGES`` is one step."""
+    return 1 << (min(mb, _MAX_PAGES) - 1).bit_length()
+
+
+def _tile(g: int, mb: int, bs: int, d: int, itemsize: int):
+    """``(rows, pages)`` of one grid step for a fold of ``g`` rows over a
+    table of ``mb`` blocks: the key tile as wide as the table and
+    ``_MAX_PAGES`` allow, then the row block as tall as the scoped VMEM holds
+    beside it, a power of two so that it divides the chunk buckets."""
+    pages = _tile_pages(mb)
+    a_row = d * (4 * itemsize + 4) + 2 * 128 * 4 + 8 * pages * bs
+    room = (_SCOPED_VMEM_BYTES - 6 * pages * bs * d * itemsize) // a_row
+    rows = min(1 << (room.bit_length() - 1),
+               max(_MAX_FOLD_ELEMS // d // 16 * 16, 16))
+    # the fold itself where it is shorter: a sublane multiple
+    return min(-(-g // 8) * 8, rows), pages
+
+
+def decode_tile_keys(contexts, mb: int, bs: int, window=None) -> int:
+    """Keys the kernel's live steps cover for a decode batch over tables of
+    ``mb`` blocks: each context rounded out to whole key tiles (from the tile
+    that holds the start of its window, where there is one). ``contexts``
+    over it is the tiles' fill."""
+    tile = _tile_pages(mb) * bs
+    return sum((-(-c // tile) - (max(c - window, 0) // tile if window else 0))
+               * tile for c in contexts)
+
+
+def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, window,
+                  softcap, num_blocks=0):
+    tables_ref, start_ref, _ = refs[:3]
+    refs = refs[3:]
+    kscale_ref = vscale_ref = None
     if num_blocks:      # fp8 pages with per-(head, page) scales prefetched
-        (tables_ref, start_ref, kscale_ref, vscale_ref, q_ref, k_ref, v_ref,
-         o_ref, m_scr, l_scr, acc_scr) = refs
-    else:
-        (tables_ref, start_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-         acc_scr) = refs
-        kscale_ref = vscale_ref = None
+        kscale_ref, vscale_ref = refs[:2]
+        refs = refs[2:]
+    q_ref = refs[0]
+    k_refs, v_refs = refs[1:1 + pages], refs[1 + pages:1 + 2 * pages]
+    o_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * pages:]
+    tile = pages * block_size
     b = pl.program_id(0)
     hi = pl.program_id(1)
     i = pl.program_id(2)                   # row block of the q fold
-    j = pl.program_id(3)
+    j = pl.program_id(3)                   # key tile
 
     @pl.when(j == 0)
     def _init():
@@ -70,17 +154,21 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rows,
         min_qpos = start
         max_qpos = start + chunk - 1
 
+    def _joined(slot_refs, scale_ref, dtype):
+        slots = [r[0, 0] for r in slot_refs]         # [bs, d] each
+        if scale_ref is not None:
+            # fp8 pages dequantize on load, slot by slot: each page's scale
+            # rides in SMEM next to the block table
+            entry = (b * steps + j) * pages
+            slots = [s.astype(jnp.float32) * scale_ref[
+                hi * num_blocks + tables_ref[entry + p]]
+                for p, s in enumerate(slots)]
+        return jnp.concatenate([s.astype(dtype) for s in slots], axis=0)
+
     def _compute():
-        q = q_ref[0, 0]                    # [Gp, d]
-        k = k_ref[0, 0]                    # [bs, d] (fp8 pages dequantize
-        v = v_ref[0, 0]                    # on load; no-op otherwise)
-        if kscale_ref is not None:
-            # per-(head, page) scale rides in SMEM next to the block table
-            page = tables_ref[b * num_pages + j]
-            k = k.astype(jnp.float32) * kscale_ref[hi * num_blocks + page]
-            v = v.astype(jnp.float32) * vscale_ref[hi * num_blocks + page]
-        k = k.astype(q.dtype)
-        v = v.astype(q.dtype)
+        q = q_ref[0, 0]                    # [rows, d]
+        k = _joined(k_refs, kscale_ref, q.dtype)     # [P * bs, d]
+        v = _joined(v_refs, vscale_ref, q.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / np.sqrt(q.shape[-1]))
@@ -89,7 +177,7 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rows,
         # row r of the fold is (q-head r // chunk, chunk token r % chunk)
         row = i * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         qpos = start + row % chunk
-        kpos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= qpos                # causal == context-length mask
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
@@ -104,13 +192,12 @@ def _paged_kernel(*refs, block_size, num_pages, chunk, rows,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = j * block_size <= max_qpos      # page overlaps the causal horizon
+    live = j * tile <= max_qpos            # tile overlaps the causal horizon
     if window is not None:
-        live = jnp.logical_and(
-            live, (j + 1) * block_size - 1 > min_qpos - window)
+        live = jnp.logical_and(live, (j + 1) * tile - 1 > min_qpos - window)
     pl.when(live)(_compute)
 
-    @pl.when(j == num_pages - 1)
+    @pl.when(j == steps - 1)
     def _finalize():
         o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
                        ).astype(o_ref.dtype)
@@ -131,81 +218,93 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
     doubles as the context-length mask, so trash-padded table slots and stale
     tail entries of the last page are never visible.
     """
-    return _paged_call(q, k_pages, v_pages, 0, 0, k_pages.shape[0],
-                       block_tables, start_pos, window, softcap, k_scales,
-                       v_scales, interpret)
+    return _paged_call(q, k_pages, v_pages, jnp.zeros((2,), jnp.int32),
+                       block_tables, start_pos, k_scales, v_scales,
+                       hkv=k_pages.shape[0], window=window, softcap=softcap,
+                       interpret=interpret)
 
 
-def paged_attention_pool(q, pool, layer: int, block_tables, start_pos,
+def paged_attention_pool(q, pool, layer, block_tables, start_pos,
                          window=None, softcap=None, scales=None,
                          interpret: bool = False):
-    """``paged_attention`` over layer ``layer`` (a Python int) of the whole
-    KV pool [L, 2, Hkv, NB, block_size, d] (``scales``: [L, 2, Hkv, NB]).
-    The pool goes to the kernel as it lies in memory, its leading dimensions
-    merged, and the index maps start at the layer's K and V heads: a step
-    program that handed ``pool[layer, 0]`` and ``pool[layer, 1]`` to the
-    kernel copied each out first, the whole pool once a step."""
+    """``paged_attention`` over layer ``layer`` of the whole KV pool
+    [L, 2, Hkv, NB, block_size, d] (``scales``: [L, 2, Hkv, NB]). The pool
+    goes to the kernel as it lies in memory, its leading dimensions merged,
+    and the index maps start at the layer's K and V heads: a step program
+    that handed ``pool[layer, 0]`` and ``pool[layer, 1]`` to the kernel
+    copied each out first, the whole pool once a step. Where those heads
+    start is a value handed to the kernel, so the layers of a pool share one
+    traced and lowered call."""
     hkv = pool.shape[2]
     pages = pool.reshape((-1,) + pool.shape[3:])
     ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
         else (None, None)
-    return _paged_call(q, pages, pages, 2 * layer * hkv,
-                       (2 * layer + 1) * hkv, hkv, block_tables, start_pos,
-                       window, softcap, ks, vs, interpret)
+    heads0 = jnp.asarray([2 * layer * hkv, (2 * layer + 1) * hkv], jnp.int32)
+    return _paged_call(q, pages, pages, heads0, block_tables, start_pos, ks,
+                       vs, hkv=hkv, window=window, softcap=softcap,
+                       interpret=interpret)
 
 
-def _paged_call(q, k_pages, v_pages, k_head0: int, v_head0: int, hkv: int,
-                block_tables, start_pos, window, softcap, k_scales, v_scales,
+@functools.partial(jax.jit, static_argnames=("hkv", "window", "softcap",
+                                             "interpret"))
+def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
+                k_scales, v_scales, *, hkv: int, window, softcap,
                 interpret: bool):
-    """The kernel call: KV head ``hi`` of the grid reads row ``k_head0 + hi``
-    of ``k_pages`` and row ``v_head0 + hi`` of ``v_pages`` ([X, NB, bs, d])."""
+    """The kernel call: KV head ``hi`` of the grid reads row ``heads0[0] +
+    hi`` of ``k_pages`` and row ``heads0[1] + hi`` of ``v_pages``
+    ([X, NB, bs, d]). A function of its own under ``jit`` so that a step
+    program traces and lowers it once a layer kind and not once a layer."""
     b, t, h, d = q.shape
     _, nb, bs, _ = k_pages.shape
     rep = h // hkv
     g = rep * t
-    # fold rows per grid cell: a sublane multiple, capped for scoped VMEM
-    rows = min(-(-g // 8) * 8, max(_MAX_FOLD_ELEMS // d // 16 * 16, 16))
-    gp = -(-g // rows) * rows
     mb = block_tables.shape[1]
+    rows, pages = _tile(g, mb, bs, d, k_pages.dtype.itemsize)
+    gp = -(-g // rows) * rows
+    steps = -(-mb // pages)
     scaled = k_scales is not None
 
     qf = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, d)
     if gp != g:
         qf = jnp.pad(qf, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
+    # whole steps: a padded entry's positions lie above every query's
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, steps * pages - mb)))
 
+    def slot(kv, p):
+        # slot p of a step reads its own entry of the sequence's table
+        return pl.BlockSpec(
+            (1, 1, bs, d), lambda bi, hi, i, j, *pf:
+            (pf[2][kv] + hi, pf[0][(bi * steps + j) * pages + p], 0, 0))
+
+    rows_spec = pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
+                             (bi, hi, i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 if scaled else 2,
-        grid=(b, hkv, gp // rows, mb),
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
-                         (bi, hi, i, 0)),
-            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
-                         (k_head0 + hi, pf[0][bi * mb + j], 0, 0)),
-            pl.BlockSpec((1, 1, bs, d), lambda bi, hi, i, j, *pf, mb=mb:
-                         (v_head0 + hi, pf[0][bi * mb + j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
-                               (bi, hi, i, 0)),
+        num_scalar_prefetch=5 if scaled else 3,
+        grid=(b, hkv, gp // rows, steps),
+        in_specs=[rows_spec] + [slot(kv, p) for kv in (0, 1)
+                                for p in range(pages)],
+        out_specs=rows_spec,
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, d), jnp.float32),
         ],
     )
-    prefetch = [block_tables.reshape(-1).astype(jnp.int32),
-                start_pos.astype(jnp.int32)]
+    prefetch = [tables.reshape(-1), start_pos.astype(jnp.int32),
+                heads0.astype(jnp.int32)]
     if scaled:
         prefetch += [k_scales.reshape(-1).astype(jnp.float32),
                      v_scales.reshape(-1).astype(jnp.float32)]
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=bs, num_pages=mb,
-                          chunk=t, rows=rows, window=window, softcap=softcap,
-                          num_blocks=nb if scaled else 0),
+        functools.partial(_paged_kernel, block_size=bs, pages=pages,
+                          steps=steps, chunk=t, rows=rows, window=window,
+                          softcap=softcap, num_blocks=nb if scaled else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(*prefetch, qf, k_pages, v_pages)
+    )(*prefetch, qf, *[k_pages] * pages, *[v_pages] * pages)
 
     out = out[:, :, :g].reshape(b, hkv, rep, t, d)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)

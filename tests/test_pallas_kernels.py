@@ -213,6 +213,121 @@ def test_paged_attention_over_the_whole_pool(layer, pages):
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(sliced))
 
 
+# What a key tile of several pages can get wrong. Blocks of 16 and heads of
+# 32 unless said: a tile is 8 pages = 128 keys, or the table where that is
+# shorter. ``starts``: the queries' first positions, a batch row each;
+# ``tile``: the (rows, pages) the kernel's rule has to choose for the case to
+# test what it says.
+def _case(b, t, h, mb, starts, tile, window=None, softcap=None, fp8=False,
+          d=32):
+    return dict(b=b, t=t, h=h, mb=mb, starts=starts, tile=tile,
+                window=window, softcap=softcap, fp8=fp8, d=d)
+
+
+_JOINED_TILE_CASES = {
+    # 5 blocks: one step of 8 slots, three of them padding; a context that
+    # ends inside the tile, one of a single token, one that fills the table
+    "table-5-of-8": _case(3, 1, 8, 5, [37, 0, 79], (8, 8)),
+    # 12 blocks: two steps, half of the second padding; a context that ends
+    # on the tile's edge, one a key past it
+    "table-12-of-16": _case(4, 1, 8, 12, [127, 128, 0, 191], (8, 8)),
+    # 20 blocks: three steps; contexts that end on the second tile's edge,
+    # one key past it, and in the table's last page
+    "table-20-of-24": _case(4, 1, 8, 20, [255, 256, 0, 319], (8, 8)),
+    # 6 and 9 query heads a KV head: folds of 8 and 16 rows
+    "rep-6": _case(3, 1, 12, 20, [300, 3, 100], (8, 8)),
+    "rep-9": _case(3, 1, 18, 20, [300, 3, 100], (16, 8), window=40),
+    # a window that spans a tile's edge (keys 201-300 over tiles of 128),
+    # one wholly inside a tile, one that ends on an edge
+    "window-over-tile-edge": _case(3, 1, 8, 24, [300, 380, 256], (8, 8),
+                                   window=100),
+    # a chunk that starts inside a tile and ends inside the next
+    "chunk-over-tiles": _case(1, 72, 4, 20, [230], (144, 8)),
+    "chunk-window-over-tiles": _case(1, 72, 4, 20, [230], (144, 8),
+                                     window=50),
+    # a fold cut into row blocks (heads of 128: 2,048 rows a block, one q
+    # head's whole chunk each) behind a window narrower than a block
+    "chunk-cut-window-under-rows": _case(1, 2048, 4, 132, [50], (2048, 8),
+                                         window=300, d=128),
+    # ... and cut into blocks that span heads, from position 0
+    "chunk-cut-over-heads": _case(1, 512, 16, 32, [0], (2048, 8),
+                                  window=200, d=128),
+    # fp8 pages: every slot of a tile under its own (head, page) scale
+    "fp8-scales-a-slot": _case(3, 1, 8, 20, [300, 0, 319], (8, 8),
+                               fp8=True),
+    "fp8-chunk-window": _case(1, 72, 4, 20, [230], (144, 8), window=50,
+                              fp8=True),
+    "softcap": _case(3, 1, 8, 20, [300, 0, 319], (8, 8), softcap=20.0),
+    "softcap-chunk-window": _case(1, 72, 4, 20, [230], (144, 8), window=50,
+                                  softcap=20.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_JOINED_TILE_CASES))
+def test_paged_attention_joined_key_tile(name):
+    """The kernel's grid step joins several pages into one key tile: against
+    the gather reference where the table is no whole number of tiles, where
+    contexts and windows end inside a tile, at folds of 8 and 16 rows, where
+    the fold is cut into row blocks taller than the window, with fp8 slots
+    under different scales, with softcap."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    c = _JOINED_TILE_CASES[name]
+    b, t, h, mb, d = c["b"], c["t"], c["h"], c["mb"], c["d"]
+    hkv, nb, bs = 2, 192, 16
+    assert pa._tile(h // hkv * t, mb, bs, d, 1 if c["fp8"] else 4) \
+        == c["tile"]
+    rng = np.random.default_rng(sorted(_JOINED_TILE_CASES).index(name))
+    kp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
+    scales = {}
+    if c["fp8"]:
+        kp, vp = kp.astype(jnp.float8_e4m3fn), vp.astype(jnp.float8_e4m3fn)
+        scales = {"k_scales": jnp.asarray(rng.uniform(0.25, 4.0, (hkv, nb)),
+                                          jnp.float32),
+                  "v_scales": jnp.asarray(rng.uniform(0.25, 4.0, (hkv, nb)),
+                                          jnp.float32)}
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+    # every row's blocks its own; what lies past a context is the trash block
+    tables = np.full((b, mb), nb - 1, np.int32)
+    free = rng.permutation(nb - 1)
+    for r, first in enumerate(c["starts"]):
+        n = -(-(first + t) // bs)
+        assert n <= mb
+        tables[r, :n], free = free[:n], free[n:]
+    tables = jnp.asarray(tables)
+    start = jnp.asarray(c["starts"], jnp.int32)
+    how = dict(window=c["window"], softcap=c["softcap"], **scales)
+    out = pa.paged_attention(q, kp, vp, tables, start, interpret=True, **how)
+    ref = pa.paged_attention_reference(q, kp, vp, tables, start, **how)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_tile_follows_the_fold_and_the_table():
+    """``_tile`` at the served shapes (blocks of 64, heads of 128, bfloat16):
+    the key tile is 8 pages or the table, a decode fold is its own rows, a
+    tall fold is cut where the scoped VMEM ends beside the tile (2,048 rows
+    beside 8 pages; 1,024 beside the 16 that were swept), by powers of two."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    shape = (64, 128, 2)
+    assert pa._MAX_PAGES == 8
+    assert pa._tile(6, 260, *shape) == (8, 8)           # Laguna, full decode
+    assert pa._tile(9, 9, *shape) == (16, 8)            # ... sliding decode
+    assert pa._tile(6 * 4096, 260, *shape) == (2048, 8)
+    assert pa._tile(9 * 4096, 73, *shape) == (2048, 8)
+    assert pa._tile(4 * 2048, 64, *shape) == (2048, 8)  # Mixtral's chunk
+    assert pa._tile(4, 4, *shape) == (8, 4)      # a table shorter than a tile
+    assert pa._tile(4, 5, *shape) == (8, 8)
+    assert pa._tile(4 * 512, 16, 64, 128, 1) == (2048, 8)        # fp8 pages
+    assert pa._tile(8 * 1024, 32, 64, 256, 2) == (1024, 8)       # heads of 256
+    # the module's count of the scoped VMEM: what compiled and what did not
+    def counted(rows, pages):
+        return rows * (128 * 12 + 1024) + 6 * pages * 64 * 128 * 2 \
+            + 8 * rows * pages * 64
+    assert counted(2048, 8) <= pa._SCOPED_VMEM_BYTES < counted(2048, 16)
+    assert counted(1024, 16) <= pa._SCOPED_VMEM_BYTES
+
+
 def test_quantized_psum_scatter(mesh_dp8):
     """qgZ reduce-scatter building block: int8-wire sum matches psum_scatter
     within quantization error."""

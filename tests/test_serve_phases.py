@@ -171,3 +171,79 @@ def test_a_served_tick_shares_one_number_and_the_ledger_sums_as_before(
         pytest.approx(decode_ms, rel=1e-3, abs=1e-2)
     bookkeep_ms = sum(e[5] for e in spans if e[1] == "serve/bookkeep") * 1e3
     assert report["aggregate"]["residual"]["total_ms"] >= bookkeep_ms * 0.99
+
+
+def _decode_args(tracing, eng):
+    tracing.clear()
+    eng.step()
+    (decode,) = [e for e in _spans(tracing) if e[1] == "serve/step_decode"]
+    return decode[7]
+
+
+def test_step_decode_counts_the_keys_of_the_tiles_the_kernel_read(
+        params, tracing):
+    """``tile_keys`` beside ``ctx_tokens``: every context rounded out to the
+    key tiles of the paged kernel (several pages a grid step), so that the
+    tiles' fill can be read from a trace; the windowed one counts from the
+    tile that holds the window's first key."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    cfg, p = params
+    eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32,
+                                                   kv_block_size=4))
+    eng.put([1, 2, 3], [[1] * 5, [2] * 12, [3] * 20])
+    contexts = [s.total_tokens for s in eng.state.decoding()]
+    args = _decode_args(tracing, eng)
+    tile = 4 * pa._tile_pages(args["ctx_blocks"])
+    assert args["ctx_tokens"] == sum(contexts) == 6 + 13 + 21
+    assert args["tile_keys"] == sum(-(-c // tile) * tile for c in contexts)
+    assert args["tile_keys"] >= args["ctx_tokens"]
+    # behind the window of 8 a context reads from the tile that holds key
+    # ``context - 8``
+    assert args["tile_keys_windowed"] == sum(
+        (-(-c // tile) - max(c - WINDOW, 0) // tile) * tile for c in contexts)
+    assert args["ctx_tokens_windowed"] <= args["tile_keys_windowed"] \
+        <= args["tile_keys"]
+
+
+@pytest.mark.parametrize("case,contexts,window,want", [
+    # blocks of 16 over a 32-block table: a tile is 8 pages = 128 keys
+    ("whole tiles", [128, 256, 1280], None, (1664, 1664)),
+    ("ends inside a tile", [1, 129, 300], None, (128 + 256 + 384,) * 2),
+    # window 100: context 300 reads keys 200-299 (tiles 1 and 2 of three),
+    # context 129 keys 29-128 (both its tiles), context 520 keys 420-519
+    # (tiles 3 and 4 of five), context 100 keys 0-99 (its one tile)
+    ("windowed", [300, 129, 520, 100], 100,
+     (384 + 256 + 640 + 128, 256 + 256 + 256 + 128)),
+])
+def test_decode_tile_keys_by_hand(case, contexts, window, want):
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     KVCacheConfig)
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    assert pa._tile_pages(32) == 8
+    kv = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=2,
+                                      head_dim=32, block_size=16,
+                                      num_blocks=8, dtype=jax.numpy.float32))
+    got = kv.decode_tile_keys(contexts, 32, window)
+    assert (got["tile_keys"], got["tile_keys_windowed"]) == want
+    assert (got["tile_keys"] == sum(contexts)) == (case == "whole tiles")
+
+
+def test_decode_tile_keys_of_a_windowed_kind_count_from_its_table():
+    """Pages by layer kind: a windowed layer's table starts behind the window
+    and is nine blocks long at window 512 over blocks of 64, so context 5,000
+    reads keys 4,488-4,999 as positions 8-519 of a table that starts at
+    block 70: two tiles of 8 slots, 1,024 keys for the window's 512; a
+    context of 300 is one tile of 512 keys for either kind of layer."""
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     KVCacheConfig)
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=2, num_kv_heads=1, head_dim=128, block_size=64,
+        num_blocks=4, layer_windows=(None, 512), window_blocks=4))
+    got = kv.decode_tile_keys([5000, 300], 128, 512)
+    assert got["tile_keys_windowed"] == 1024 + 512
+    assert got["tile_keys"] == 5120 + 512
+    # and nothing over a latent pool, which the paged kernel does not read
+    latent = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=1,
+                                          head_dim=64, latent_dim=48,
+                                          num_blocks=4))
+    assert latent.decode_tile_keys([100], 4, None) == {}

@@ -74,7 +74,7 @@ def _shapes(one_chip, fn_name, pages):
     cache = pool if pages == "plain" else (pool, jax.ShapeDtypeStruct(
         pool.shape[:4], jnp.float32, sharding=one_chip))
     if fn_name == "decode_step_g":
-        tail = (ints(16), ints(16), ints(16, 16), jax.ShapeDtypeStruct(
+        tail = (ints(16), ints(16), ints(16, 8), jax.ShapeDtypeStruct(
             (16,), jnp.bool_, sharding=one_chip))
     else:
         tail = (ints(512), ints(), ints(16), ints())
@@ -114,6 +114,66 @@ def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
     assert "tpu_custom_call" in text and "paged_attention" in text
 
     assert _pool_shaped_moves(text, pool) == []
+
+
+def test_layers_of_one_kind_share_one_paged_kernel_body(one_chip):
+    """What holds ``setup_s``: the kernel's call sits under a ``jit`` of its
+    own and where a layer's heads start is a value, so a program of two
+    layers of one kind traces and lowers ONE ``paged_attention`` body and
+    calls it a layer (it was lowered to Mosaic anew for each)."""
+    args, _ = _shapes(one_chip, "decode_step_g", "plain")
+    text = gd.decode_step_g.lower(
+        *args, policy=policy_for(CFG), cfg=CFG, block_size=BLOCK,
+        attn_impl="kernel").as_text()
+    assert text.count('kernel_name = "paged_attention"') == 1
+    assert len(re.findall(r"call @_paged_call\b", text)) == CFG.num_layers
+
+
+# The tallest folds and longest tables the cells hand the kernel (blocks of
+# 64, heads of 128, bfloat16): batch, chunk tokens, query heads, table
+# blocks, window; then the (rows, pages) of a grid step.
+_TALLEST_FOLDS = {
+    "laguna-full-chunk": (1, 4096, 48, 260, None, (2048, 8)),
+    "laguna-sliding-chunk": (1, 4096, 72, 73, 512, (2048, 8)),
+    "laguna-full-decode-32": (32, 1, 48, 260, None, (8, 8)),
+    "laguna-sliding-decode-32": (32, 1, 72, 9, 512, (16, 8)),
+    "mixtral-chunk": (1, 2048, 32, 64, None, (2048, 8)),
+    "mixtral-decode-32": (32, 1, 32, 64, None, (8, 8)),
+    # a row block that spans the heads of a group (the masks then reckon a
+    # row's token by a remainder), behind a window
+    "mistral-chunk-over-heads": (1, 512, 32, 16, 4096, (2048, 8)),
+    "mistral-first-chunk-2048-rows": (1, 512, 32, 8, 4096, (2048, 8)),
+    "mistral-decode-64-fp8": (64, 1, 32, 64, 4096, (8, 8)),
+}
+
+
+@pytest.mark.parametrize("fold", sorted(_TALLEST_FOLDS))
+def test_paged_kernel_fits_the_scoped_vmem_at_the_tallest_folds(one_chip,
+                                                                fold):
+    """The tile ``_tile`` chooses compiles for the v5e inside the default
+    16 MiB of scoped VMEM (the kernel sets no compiler parameter): 2,048 rows
+    beside 8 pages, a 260-block table, decode buckets of 32 and 64, fp8 pages
+    dequantized a slot at a time."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    b, t, h, mb, window, tile = _TALLEST_FOLDS[fold]
+    fp8 = fold.endswith("fp8")
+    dtype = jnp.float8_e4m3fn if fp8 else jnp.bfloat16
+    assert pa._tile(h // 8 * t, mb, BLOCK, 128,
+                    jnp.dtype(dtype).itemsize) == tile
+
+    def on_chip(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = on_chip((2, 2, 8, NUM_BLOCKS, BLOCK, 128), dtype)
+    scales = on_chip(pool.shape[:4], jnp.float32) if fp8 else None
+    compiled = jax.jit(
+        lambda q, pool, scales, tables, start: pa.paged_attention_pool(
+            q, pool, 1, tables, start, window=window, scales=scales)).lower(
+        on_chip((b, t, h, 128), jnp.bfloat16), pool, scales,
+        on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    # the pool goes in whole, once a slot: nothing of its size is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.fixture
