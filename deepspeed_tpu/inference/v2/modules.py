@@ -10,14 +10,18 @@ serving loop (``generic_decode.py``) owns the step, the pool's page kind
 (``kv_cache.py``) the cache writes, block tables and paged attention; the
 policy contributes exactly the three arch-specific pieces:
 
-- ``embed(params, tokens, positions, cfg)``          -> [N, D] hidden states
-- ``block(params, i, x, attend, positions, cfg, valid)`` -> ([N, D], counts)
+- ``embed(params, tokens, positions, cfg)``          -> [N, ...] the state
+  (hidden states [N, D] in most policies; opaque to the loop, which hands
+  it from block to block and indexes its leading axis alone: the policy owns
+  the residual, and ``Xing4Policy`` keeps several streams a row, [N, n, D])
+- ``block(params, i, x, attend, positions, cfg, valid)`` -> (state, counts)
   (one layer; calls ``attend(q, k, v)`` for cache write + paged attention;
   ``valid`` [N] marks the rows that are no bucket padding; ``counts`` is
   ``None``, or for a layer that counts inside the step program an int32
   vector of ``telemetry/names.py`` ``STEP_COUNTER_ARGS``, which the loop sums
   over the layers and hands out beside the logits)
 - ``unembed(params, x, cfg)``                        -> [N, V] fp32 logits
+  from the state's rows
 
 plus ``cache_spec(cfg)`` so the engine can size the paged KV pool, and which
 selects the page kind. What ``attend`` takes is the kind's contract: over a
@@ -700,6 +704,48 @@ from deepspeed_tpu.models.joyai_llm_flash import (  # noqa: E402
     JoyAIFlashConfig, apply_rope_pairs, route)
 
 
+def _latent_attention(lp, x, attend, positions, cfg):
+    """A layer's latent attention of ``x`` [N, D], its own pre-norm inside:
+    what the sublayer adds, the residual left to the caller."""
+    ap = lp["attn"]
+    dtype, eps = cfg.dtype, cfg.rms_norm_eps
+    d_n, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    h = _rms(x, lp["attn_norm"]["scale"], eps)
+    with jax.named_scope("attn/latent_q"):
+        cq = _rms(h @ ap["wq_a"]["kernel"].astype(dtype),
+                  ap["q_norm"]["scale"], eps)
+        q = jnp.einsum("tr,rhk->thk", cq,
+                       ap["wq_b"]["kernel"].astype(dtype))
+        q_rope = apply_rope_pairs(q[..., d_n:], positions, cfg)
+        ckv = h @ ap["wkv_a"]["kernel"].astype(dtype)
+        row = jnp.concatenate(
+            [_rms(ckv[:, :rank], ap["kv_norm"]["scale"], eps),
+             apply_rope_pairs(ckv[:, rank:], positions, cfg)], -1)
+    o = attend(q[..., :d_n], q_rope, row,
+               ap["wkv_b"]["kernel"].astype(dtype), cfg.softmax_scale)
+    with jax.named_scope("attn/out"):
+        return jnp.einsum("thv,hvd->td", o, ap["wo"]["kernel"].astype(dtype))
+
+
+def _dense_or_experts(lp, i, x, cfg, valid):
+    """(what layer ``i``'s second sublayer adds to ``x`` [N, D], its own
+    pre-norm inside, the layer's counts or None): the gated MLP of a leading
+    dense layer, else the chosen routed experts and the shared one."""
+    dtype = cfg.dtype
+    h2 = _rms(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps)
+    if cfg.is_dense(i):
+        with jax.named_scope("mlp"):
+            return _mlp(lp, h2, dtype), None
+    moe = lp["moe"]
+    with jax.named_scope("moe/router"):
+        weights, ids = route(h2, moe, cfg)
+    y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe/shared"):
+            y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
+    return y, counts
+
+
 @register_policy("joyai_llm_flash", JoyAIFlashConfig)
 class JoyAIFlashPolicy:
     """models/joyai_llm_flash.py's serving twin. The page row of a token is
@@ -720,36 +766,8 @@ class JoyAIFlashPolicy:
     @staticmethod
     def block(params, i, x, attend, positions, cfg, valid):
         lp = params[f"layer_{i}"]
-        ap = lp["attn"]
-        dtype, eps = cfg.dtype, cfg.rms_norm_eps
-        d_n, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-        h = _rms(x, lp["attn_norm"]["scale"], eps)
-        with jax.named_scope("attn/latent_q"):
-            cq = _rms(h @ ap["wq_a"]["kernel"].astype(dtype),
-                      ap["q_norm"]["scale"], eps)
-            q = jnp.einsum("tr,rhk->thk", cq,
-                           ap["wq_b"]["kernel"].astype(dtype))
-            q_rope = apply_rope_pairs(q[..., d_n:], positions, cfg)
-            ckv = h @ ap["wkv_a"]["kernel"].astype(dtype)
-            row = jnp.concatenate(
-                [_rms(ckv[:, :rank], ap["kv_norm"]["scale"], eps),
-                 apply_rope_pairs(ckv[:, rank:], positions, cfg)], -1)
-        o = attend(q[..., :d_n], q_rope, row,
-                   ap["wkv_b"]["kernel"].astype(dtype), cfg.softmax_scale)
-        with jax.named_scope("attn/out"):
-            x = x + jnp.einsum("thv,hvd->td", o,
-                               ap["wo"]["kernel"].astype(dtype))
-        h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
-        if cfg.is_dense(i):
-            with jax.named_scope("mlp"):
-                return x + _mlp(lp, h2, dtype), None
-        moe = lp["moe"]
-        with jax.named_scope("moe/router"):
-            weights, ids = route(h2, moe, cfg)
-        y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid)
-        if cfg.n_shared_experts:
-            with jax.named_scope("moe/shared"):
-                y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
+        x = x + _latent_attention(lp, x, attend, positions, cfg)
+        y, counts = _dense_or_experts(lp, i, x, cfg, valid)
         return x + y, counts
 
     @staticmethod
@@ -757,6 +775,42 @@ class JoyAIFlashPolicy:
         x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
         return x.astype(jnp.float32) @ \
             params["lm_head"]["kernel"].astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Xing4.0 (JoyAI's sublayers with YaRN, several residual streams mixed round
+# each by hyper-connections: the state between layers is [N, n, D])
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.inference.v2 import hyper_connection as _hc  # noqa: E402
+from deepspeed_tpu.models.xing4 import Xing4Config  # noqa: E402
+
+
+@register_policy("xing4_0", Xing4Config)
+class Xing4Policy(JoyAIFlashPolicy):
+    """models/xing4.py's serving twin: JoyAI's cache, attention and experts
+    (the same functions), each sublayer between ``hyper_connection.pre_mix``
+    and ``post_mix``. The policy owns the residual: ``embed`` hands the loop
+    ``hc_mult`` streams a row ([N, n, D]), which it passes from block to
+    block unread, and ``unembed`` sums them."""
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return _hc.expand(JoyAIFlashPolicy.embed(params, tokens, positions,
+                                                 cfg), cfg.hc)
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp, hc = params[f"layer_{i}"], cfg.hc
+        u, mix = _hc.pre_mix(x, lp["hc_attn"], hc)
+        x = _hc.post_mix(
+            x, _latent_attention(lp, u, attend, positions, cfg), mix, hc)
+        u, mix = _hc.pre_mix(x, lp["hc_mlp"], hc)
+        y, counts = _dense_or_experts(lp, i, u, cfg, valid)
+        return _hc.post_mix(x, y, mix, hc), counts
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        return JoyAIFlashPolicy.unembed(params, _hc.collapse(x), cfg)
 
 
 # ---------------------------------------------------------------------------

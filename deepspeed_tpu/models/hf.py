@@ -141,6 +141,8 @@ _REGISTRY = {
                                      "convert_hf_joyai_flash"),
     "laguna": _family_entry("laguna", "laguna_config_from_hf",
                             "LagunaForCausalLM", "convert_hf_laguna"),
+    "xing4_0": _family_entry("xing4", "xing4_config_from_hf",
+                             "Xing4ForCausalLM", "convert_hf_xing4"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

@@ -24,10 +24,15 @@ Reference analog: none in ``deepspeed/inference/v2/model_implementations``
 
 The multi-token-prediction module (``num_nextn_predict_layers``) adds nothing
 to the model's logits and is not built or loaded. Group-limited routing
-(``n_group`` > 1) and rope scaling are refused by name.
+(``n_group`` > 1) is refused by name. ``rope_scaling`` of type ``yarn`` is
+DeepSeek-V3's: the rotary part's frequencies are YaRN's (``models/llama.py``
+``YarnScaling``), cos and sin are multiplied by ``mscale(mscale) /
+mscale(mscale_all_dim)`` and the softmax scale by ``mscale(mscale_all_dim)``
+squared, ``mscale(m) = 0.1 m ln(factor) + 1``.
 """
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -35,7 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.llama import LMHead, RMSNorm, rope_freqs
+from deepspeed_tpu.models.llama import (LMHead, RMSNorm, YarnScaling,
+                                        rope_freqs)
 from deepspeed_tpu.moe.grouped_experts import grouped_expert_ffn, sigmoid_route
 
 
@@ -61,6 +67,11 @@ class JoyAIFlashConfig:
     # admits (every step program embeds the tables: 512 bytes a position)
     max_seq_len: int = 131072
     rope_theta: float = 32e6
+    # YaRN over the rotary part (``rope_scaling``), its ``attention_factor``
+    # what multiplies cos and sin; ``yarn_mscale_all_dim`` enters the softmax
+    # scale (module docstring)
+    rope_yarn: Optional[YarnScaling] = None
+    yarn_mscale_all_dim: float = 0.0
     rms_norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
 
@@ -75,10 +86,19 @@ class JoyAIFlashConfig:
 
     @property
     def softmax_scale(self) -> float:
-        return self.qk_head_dim ** -0.5
+        scale = self.qk_head_dim ** -0.5
+        if self.rope_yarn is None:
+            return scale
+        return scale * yarn_mscale(self.rope_yarn.factor,
+                                   self.yarn_mscale_all_dim) ** 2
 
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V3's ``yarn_get_mscale``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 TINY_JOYAI_FLASH = JoyAIFlashConfig(
@@ -91,10 +111,12 @@ TINY_JOYAI_FLASH = JoyAIFlashConfig(
 
 def apply_rope_pairs(x, positions, cfg: JoyAIFlashConfig):
     """Rotate the pairs ``(2j, 2j+1)`` of ``x``'s last axis (``rope_interleave``)
-    by ``positions * theta ** (-2j / d)``. ``positions`` has ``x``'s leading
-    axes, or those up to a heads axis that is broadcast (``x`` [..., H, d],
-    positions [...])."""
-    cos, sin = rope_freqs(x.shape[-1], cfg.max_seq_len, cfg.rope_theta)
+    by ``positions * theta ** (-2j / d)``, or by YaRN's frequencies where the
+    config has ``rope_yarn``. ``positions`` has ``x``'s leading axes, or those
+    up to a heads axis that is broadcast (``x`` [..., H, d], positions
+    [...])."""
+    cos, sin = rope_freqs(x.shape[-1], cfg.max_seq_len, cfg.rope_theta,
+                          cfg.rope_yarn)
     cos, sin = jnp.asarray(cos)[positions], jnp.asarray(sin)[positions]
     while cos.ndim < x.ndim:
         cos, sin = cos[..., None, :], sin[..., None, :]
@@ -283,12 +305,32 @@ class JoyAIFlashForCausalLM(nn.Module):
 # ---------------------------------------------------------------------------
 # HF interop (weight names follow the DeepSeek-V3 layout)
 # ---------------------------------------------------------------------------
-def joyai_flash_config_from_hf(hf: dict,
-                               max_seq_len: Optional[int] = None
-                               ) -> JoyAIFlashConfig:
-    """A ``JoyAIFlashConfig`` from the published ``config.json`` keys.
-    ``max_seq_len`` bounds the positions the rope tables cover (a server's
-    longest context); the published ``max_position_embeddings`` otherwise."""
+def _yarn_from_hf(scaling: Optional[dict], family: str):
+    """(``YarnScaling`` or None, ``mscale_all_dim``) from ``rope_scaling``."""
+    if scaling is None:
+        return None, 0.0
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn" or "original_max_position_embeddings" not in scaling:
+        raise ValueError(
+            f"unsupported {family} config: rope_scaling {scaling!r} (yarn "
+            f"with factor and original_max_position_embeddings only)")
+    factor = float(scaling["factor"])
+    all_dim = float(scaling.get("mscale_all_dim", 0.0))
+    return YarnScaling(
+        factor, int(scaling["original_max_position_embeddings"]),
+        float(scaling.get("beta_fast", 32.0)),
+        float(scaling.get("beta_slow", 1.0)),
+        yarn_mscale(factor, float(scaling.get("mscale", 1.0)))
+        / yarn_mscale(factor, all_dim)), all_dim
+
+
+def latent_moe_fields(hf: dict, max_seq_len: Optional[int] = None,
+                      family: str = "joyai_llm_flash") -> dict:
+    """The fields of a ``JoyAIFlashConfig`` from the published
+    ``config.json`` keys (DeepSeek-V3's), for this family and for the ones
+    that extend its config. ``max_seq_len`` bounds the positions the rope
+    tables cover (a server's longest context); the published
+    ``max_position_embeddings`` otherwise."""
     refused = [
         (hf.get("moe_layer_freq", 1) != 1, "moe_layer_freq other than 1"),
         (hf.get("scoring_func", "sigmoid") != "sigmoid",
@@ -298,7 +340,6 @@ def joyai_flash_config_from_hf(hf: dict,
         ((hf.get("n_group") or 1) != 1 or (hf.get("topk_group") or 1) != 1,
          "group-limited routing (n_group, topk_group other than 1)"),
         (not hf.get("norm_topk_prob", True), "norm_topk_prob false"),
-        (hf.get("rope_scaling") is not None, "rope_scaling (YaRN)"),
         (not hf.get("rope_interleave", True), "rope_interleave false"),
         (not hf.get("q_lora_rank"), "a full-rank query (no q_lora_rank)"),
         (hf.get("attention_bias", False), "attention_bias"),
@@ -306,8 +347,9 @@ def joyai_flash_config_from_hf(hf: dict,
     ]
     for bad, what in refused:
         if bad:
-            raise ValueError(f"unsupported joyai_llm_flash config: {what}")
-    return JoyAIFlashConfig(
+            raise ValueError(f"unsupported {family} config: {what}")
+    yarn, all_dim = _yarn_from_hf(hf.get("rope_scaling"), family)
+    return dict(
         vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
         intermediate_size=hf["intermediate_size"],
         moe_intermediate_size=hf["moe_intermediate_size"],
@@ -325,7 +367,16 @@ def joyai_flash_config_from_hf(hf: dict,
         max_seq_len=int(max_seq_len or
                         hf.get("max_position_embeddings", 4096)),
         rope_theta=float(hf.get("rope_theta", 10000.0)),
+        rope_yarn=yarn, yarn_mscale_all_dim=all_dim,
         rms_norm_eps=hf.get("rms_norm_eps", 1e-6))
+
+
+def joyai_flash_config_from_hf(hf: dict,
+                               max_seq_len: Optional[int] = None
+                               ) -> JoyAIFlashConfig:
+    """A ``JoyAIFlashConfig`` from the published ``config.json`` keys
+    (``latent_moe_fields``)."""
+    return JoyAIFlashConfig(**latent_moe_fields(hf, max_seq_len))
 
 
 #: (our name in a gated MLP, the checkpoint's)

@@ -215,12 +215,13 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: the value unfold, a chunk's gather, up-projection and prefill kernel.
 #: ``attn/full`` and ``attn/window`` hold a layer's write and paged attention
 #: where a cache keeps pages by layer kind, ``attn/gate`` a per-head output
-#: gate
+#: gate, ``hc/pre``, ``hc/post`` and ``hc/head`` the mixing of several
+#: residual streams round a sublayer (``inference/v2/hyper_connection.py``)
 SERVED_SCOPES: Tuple[str, ...] = (
     "embed", "attn/qkv", "attn/kv_write", "attn/paged", "attn/out",
     "attn/full", "attn/window", "attn/gate", "attn/latent_q", "attn/latent_write", "attn/latent_paged",
     "attn/latent_prefill", "mlp", "moe/router", "moe/experts", "moe/shared",
-    "lm_head", "sample")
+    "lm_head", "sample", "hc/pre", "hc/post", "hc/head")
 
 #: counts a step program computes on the device where its policy's layers
 #: count (``generic_decode.py``), in the order of the int32 vector it hands

@@ -399,3 +399,70 @@ def test_layer_kind_step_program_updates_both_pools_in_place_on_a_v5e(
     logits, _, counts = compiled.out_info
     assert counts.shape == (4,)
     assert len(jax.tree.leaves(compiled.out_info)) == 4   # two pools
+
+
+# --- several residual streams ---------------------------------------------------
+# Xing4.0 at its published widths, cut to one dense and one expert layer of
+# all 64 experts, so that a compile takes seconds: JoyAI's latent pool, the
+# state between layers [rows, 4, 3584].
+
+def _xing4_shapes(one_chip, fn_name):
+    from deepspeed_tpu.inference.v2.kv_cache import latent_row_width
+    from deepspeed_tpu.models.xing4 import Xing4Config, Xing4ForCausalLM
+    cfg = Xing4Config(num_layers=2, first_k_dense_replace=1, max_seq_len=4160)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(Xing4ForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    spec = policy_for(cfg).cache_spec(cfg)
+    pool = jax.ShapeDtypeStruct(
+        (spec.num_layers, NUM_BLOCKS, BLOCK, latent_row_width(spec.latent_dim)),
+        spec.dtype, sharding=one_chip)
+    if fn_name == "decode_step_g":
+        tail = (ints(64), ints(64), ints(64, 65), jax.ShapeDtypeStruct(
+            (64,), jnp.bool_, sharding=one_chip))
+    else:
+        tail = (ints(1024), ints(), ints(32), ints())
+    return cfg, (params, pool) + tail, pool
+
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     "verify_chunk_g"])
+def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
+        one_chip, as_on_a_tpu, fn_name):
+    """The reasoning cell's largest shapes (64 sequences over 65 blocks, a
+    1,024-token chunk over 32): the policy owns a state of four streams a row
+    and the loop compiles it unread; the latent pool is aliased whole, the
+    latent kernel of the program's phase and the grouped matmul are in the
+    program, the mixing's operations carry the ``hc`` scopes, and the counts
+    leave beside the logits."""
+    cfg, args, pool = _xing4_shapes(one_chip, fn_name)
+    assert (cfg.hc_mult, cfg.hidden_size, cfg.n_routed_experts) == (4, 3584,
+                                                                    64)
+    compiled = getattr(gd, fn_name).lower(
+        *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+        attn_impl="kernel").compile()
+    text = compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    kernel = "latent_paged_attention" if fn_name == "decode_step_g" \
+        else "latent_prefill_attention"
+    assert "tpu_custom_call" in text and kernel in text
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+    for scope in ("/hc/pre/", "/hc/post/", "/hc/head/"):
+        assert scope in text
+    # a chunk's streams are 1,024 x 4 x 3,584 bfloat16 = 29 MB, in float32
+    # inside a fusion at most: nowhere near a second pool (the verifier's
+    # logits over 131,072 rows are 0.54 GB)
+    if fn_name != "verify_chunk_g":
+        assert stats.temp_size_in_bytes < pool_bytes
+    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
