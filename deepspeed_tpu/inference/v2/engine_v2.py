@@ -10,11 +10,19 @@ TPU adaptation: per step, the SplitFuse plan becomes (a) one bucketed
 ``decode_step_g`` call for all running decodes (``generic_decode.py``) — every
 shape from a small bucket ladder, so steady-state serving runs entirely from
 compiled programs. What a KV page is, the engine leaves to ``kv_cache.py``.
+
+A step is dispatched (``_dispatch``: plan, build, hand the programs to the
+device; lengths decide everything there) and later collected
+(``_collect_oldest``: read the sampled tokens, the one place the host waits
+for the device; values decide there). ``step`` does both; how many
+dispatched steps it leaves uncollected is ``depth``, which a serving loop
+sets to 1 so that its host work between two ticks runs while the device does.
 """
 
+import collections
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,14 +31,16 @@ import numpy as np
 from deepspeed_tpu.inference.v2.generic_decode import (decode_step_g,
                                                        prefill_chunk_g,
                                                        verify_chunk_g)
-from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache
+from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                 blocks_behind_window)
 from deepspeed_tpu.inference.v2.kv_offload import (HostKVEntry, HostKVStore,
                                                    dequantize_pages,
                                                    quantize_pages)
 from deepspeed_tpu.inference.v2.modules import policy_for
 from deepspeed_tpu.inference.v2.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.v2.ragged_manager import SequenceDescriptor, StateManager
-from deepspeed_tpu.inference.v2.sampling import SamplingConfig, sample_tokens
+from deepspeed_tpu.inference.v2.sampling import (SamplingConfig, feed_tokens,
+                                                 sample_into)
 from deepspeed_tpu.inference.v2.scheduler import (
     PrefillChunk,
     SchedulerConfig,
@@ -92,6 +102,35 @@ class V2EngineConfig:
     prefix_cache_max_blocks: int = 0
 
 
+@dataclasses.dataclass
+class _PendingStep:
+    """One dispatched step whose sampled tokens are still on the device.
+    Put on ``_pending`` before its first program is dispatched and filled as
+    they are, so that a fault in the middle of a dispatch leaves what was
+    dispatched on record."""
+    uids: Tuple[int, ...]                    # every sequence of the plan
+    # (sequence, the chunk's start and length, the [1] token sampled where
+    # the chunk ended its prompt)
+    chunks: List[tuple] = dataclasses.field(default_factory=list)
+    decode_seqs: Sequence[SequenceDescriptor] = ()
+    decode_sampled: Optional[jax.Array] = None
+    # what the step's programs counted (``_keep_counts``)
+    counts: List[jax.Array] = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class _Dispatched:
+    """What ``_dispatch`` hands ``step`` for the spans it stamps once the
+    collection is over."""
+    plan: StepPlan
+    ahead: int = 0
+    chunk_marks: List[tuple] = dataclasses.field(default_factory=list)
+    prefill_t0: float = 0.0                  # where serve/step_prefill opens
+    t_prefill: float = 0.0
+    decode_t0: Optional[float] = None        # where serve/step_decode opens
+    decode_args: Optional[dict] = None
+
+
 class InferenceEngineV2:
     """Serves any registered arch (llama family incl. mistral/qwen2/phi3,
     falcon, opt, mixtral, joyai_llm_flash over its latent cache, ...) — the
@@ -127,7 +166,35 @@ class InferenceEngineV2:
                 sampling=dataclasses.replace(self.config.sampling,
                                              temperature=1.0))
         self._rng = jax.random.PRNGKey(self.config.sampling.seed)
-        self._pending_logits: Dict[int, np.ndarray] = {}
+        # dispatched steps whose tokens the host has not read, oldest first,
+        # and how many of them ``step`` leaves so when it returns: 0 hands
+        # every caller this step's tokens (``put``, ``generate``, a test, a
+        # benchmark's check); a serving loop sets 1 for as long as it runs
+        # and fans out at tick k what tick k-1 dispatched. Not a
+        # configuration key: nothing but the order of the host's work
+        # depends on it
+        self.depth = 0
+        self._pending: Deque[_PendingStep] = collections.deque()
+        # tokens collected outside a ``step`` (``collect``), which the next
+        # ``step`` returns with its own
+        self._ready: Dict[int, int] = {}
+        # every sequence's last sampled token by ``SequenceDescriptor.slot``,
+        # on the device: ``_sample_dispatch`` stores there what the next
+        # decode row reads (``sampling.feed_tokens``), so a token's value
+        # crosses to the host for the client alone. The slot past the last
+        # takes the stores of batch padding
+        self._pad_slot = self.state.max_tracked_sequences
+        self._last_tokens = jnp.zeros((self._pad_slot + 1,), jnp.int32)
+        # rows computed for a sequence that had ended by the time their
+        # token was read (``eos_token_id`` read a tick late, a cancel, a
+        # deadline), over the engine's life; never delivered
+        self.rows_dropped = 0
+        # after a step whose collection raised: {"uids": the failed step's
+        # sequences, "lost": those that cannot go on} (``_abandon_pending``);
+        # None after any other step
+        self.last_fault: Optional[Dict[str, List[int]]] = None
+        # sequences of the steps the last ``step`` call collected
+        self.last_collected_uids: Tuple[int, ...] = ()
         # persistent device-side decode tables: in steady-state decode the
         # block tables only change when a sequence crosses a block boundary,
         # so the [B, MB] table upload is skipped while the allocation
@@ -155,15 +222,17 @@ class InferenceEngineV2:
         # in BOTH modes so an uncapped run yields the A/B baseline counters
         self.sched_ledger = TickLedger()
         self.last_step_counters = {"prefill_tokens": 0, "chunks": 0,
-                                   "decode_tokens": 0}
+                                   "decode_tokens": 0, "ahead": 0,
+                                   "rows_dropped": 0}
         self.last_step_counters.update(self._kv_page_counters())
         # number of the step about to run, carried by every span of that
         # step; a serving loop overwrites it with its own tick's number
         # before each step so that its spans and the engine's share it
         self.tick = 0
-        # counts the step programs handed out beside the logits (device
-        # vectors, where the policy counts), kept while tracing until the
-        # next wait for a sampled token reads them with it
+        # counts of step programs that were waited for by nothing (device
+        # vectors, where the policy counts and a tracer is on: a chunk that
+        # ended no prompt), kept until the next read of a sampled token
+        # reads them with it
         self._pending_counts: List[jax.Array] = []
         # what a windowed layer's decode reads of a context, in tokens (the
         # one window of all layers, or the windowed kind's), or None
@@ -366,6 +435,7 @@ class InferenceEngineV2:
         """Admit new/continued sequences and run ONE engine step
         (reference: engine_v2.put engine_v2.py:107). Returns {uid: next_token}
         for every sequence that produced a token this step."""
+        self.collect()    # a continuation extends a prompt: lengths settle first
         if do_checks and not self.can_schedule(
                 batch_uids, [len(t) for t in batch_tokens]):
             raise RuntimeError("cannot schedule batch: out of KV blocks or slots")
@@ -398,144 +468,71 @@ class InferenceEngineV2:
         return matched
 
     def step(self) -> Dict[int, int]:
+        """One tick: dispatch this step's programs, then collect pending
+        steps, oldest first, until ``depth`` are left. Returns {uid: token}
+        of what was collected: this step's tokens at depth 0, the step
+        before's at depth 1 (same programs, same code; the depths differ in
+        when the collection runs). A step that dispatched nothing collects
+        everything: there is nothing to run beside the wait. Speculative
+        decoding proposes from the values, so it keeps depth 0."""
         tracer = get_tracer()
-        # every span of this step carries the tick's number (see ``tick``).
-        # The phases below tile the step: the device's idle gaps are named
-        # by the phase their middle falls in, and between two ticks those
-        # middles cluster where one step ends and the next begins, so what
-        # lies between two phases there is kept to a few microseconds.
+        # every span of this call carries the tick's number (see ``tick``):
+        # the spans of the dispatch, and the wait and the commit of the
+        # collection made in this call, whichever step that one is for. The
+        # device's work between the ends of two waits is then one step's
+        # programs, at either depth. The phases tile the call: the device's
+        # idle gaps are named by the phase their middle falls in, so what
+        # lies between two phases is kept to a few microseconds.
         tick = self.tick
-        with tracer.span("serve/plan", cat="serve", tick=tick):
-            cap = self.config.scheduler.prefill_chunk_tokens
-            plan = plan_step(self.state.decoding(), self.state.prefilling(),
-                             self.config.scheduler,
-                             block_tokens=self.kv.cfg.block_size)
-            out: Dict[int, int] = {}
-        t_prefill = t_decode = 0.0
+        self.last_fault = None
+        d = self._dispatch(tick, tracer)
+        plan = d.plan
+        keep = 0 if plan.empty or self.config.speculative_k > 0 \
+            else self.depth
         t0 = time.monotonic()
-
-        # --- prefill chunks (SplitFuse) ---
-        for chunk in plan.prefill_chunks:
-            seq = chunk.seq
-            end = chunk.start + chunk.length
-            # per-chunk sub-span, nested inside serve/step_prefill (same
-            # exclusive stage): building the chunk, its dispatch, and the
-            # bookkeeping after it
-            with tracer.span("serve/prefill_chunk", cat="serve", tick=tick,
-                             uid=seq.uid, tokens=chunk.length,
-                             bucket=chunk.bucket,
-                             start=chunk.start) as chunk_span:
-                self._ensure_blocks(seq, end)
-                tokens = np.zeros((chunk.bucket,), np.int32)
-                tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
-                mb = self._ctx_bucket_blocks(end)
-                table = self._step_tables(seq, mb, chunk.start, chunk.bucket)
-                # the step programs consume the pool they are given: what
-                # comes back is bound at once, so that a fault later in the
-                # tick (and the server's next step after it) finds the
-                # engine on a live pool
-                logits, self.kv.pool, counts = prefill_chunk_g(
-                    self.params, self.kv.pool, jnp.asarray(tokens),
-                    chunk.start,
-                    jax.tree.map(jnp.asarray, table), chunk.length,
-                    policy=self.policy, cfg=self.model_config,
-                    block_size=self.kv.cfg.block_size,
-                    attn_impl=self.config.attn_impl)
-                self._keep_counts(counts)
-                seq.seen_tokens = end
-                self._advanced(seq)
-                self._prefill_computed += chunk.length
-                if self.prefix_cache is not None:
-                    # register the freshly materialized FULL prompt blocks
-                    # so concurrent arrivals with the same prefix reuse them
-                    # (pinned for this sequence's lifetime — the pin is what
-                    # keeps a shared page safe from release/demotion)
-                    self.prefix_cache.insert_from_seq(
-                        seq.uid, seq.prompt_tokens, seq.blocks,
-                        min(seq.seen_tokens, len(seq.prompt_tokens)))
-                if not seq.in_prefill:
-                    sampled = self._sample_dispatch(logits[None])
-                    with tracer.span("serve/decode_wait", cat="serve",
-                                     tick=tick):
-                        toks, counts = self._read_with_counts(sampled)
-                        tok = int(toks[0])
-                    chunk_span.note(**counts)
-                    seq.generated.append(tok)
-                    out[seq.uid] = tok
-        if plan.prefill_chunks:
-            t_prefill = time.monotonic() - t0
-            tracer.complete("serve/step_prefill", t_prefill, cat="serve",
-                            end_ts=t0 + t_prefill, tick=tick,
-                            chunks=len(plan.prefill_chunks))
-
-        # --- decode batch ---
-        t0 = time.monotonic()
-        if plan.decode_seqs:
-            with tracer.span("serve/decode_build", cat="serve",
-                             tick=tick) as build:
-                seqs = plan.decode_seqs
-                b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
-                contexts = [s.total_tokens for s in seqs]
-                mb = self._ctx_bucket_blocks(max(contexts))
-                tokens = np.zeros((b,), np.int32)
-                positions = np.zeros((b,), np.int32)
-                valid = np.zeros((b,), bool)
-                for j, seq in enumerate(seqs):
-                    self._ensure_blocks(seq, seq.total_tokens)
-                    tokens[j] = seq.generated[-1] if seq.generated else \
-                        seq.prompt_tokens[-1]
-                    positions[j] = seq.total_tokens - 1
-                    valid[j] = True
-                # signature covers the actual block ids: uid reuse after
-                # flush() can hand a same-shaped batch different pages
-                # (a windowed block given back resets the signature:
-                # ``_advanced``)
-                sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
-                rebuilt = sig != self._table_sig
-                if rebuilt:
-                    self._dev_tables = self._decode_tables(seqs, b, mb)
-                    self._table_sig = sig
-                build.note(tables_rebuilt=rebuilt)
-            with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
-                logits, self.kv.pool, counts = decode_step_g(
-                    self.params, self.kv.pool, jnp.asarray(tokens),
-                    jnp.asarray(positions), self._dev_tables,
-                    jnp.asarray(valid),
-                    policy=self.policy, cfg=self.model_config,
-                    block_size=self.kv.cfg.block_size,
-                    attn_impl=self.config.attn_impl)
-                self._keep_counts(counts)
-                # sample on device; only [B] token ids cross to the host —
-                # the [B, vocab] logits D2H fetch would dominate the loop
-                sampled = self._sample_dispatch(logits)
-            with tracer.span("serve/decode_wait", cat="serve", tick=tick):
-                toks, counts = self._read_with_counts(sampled)
-            with tracer.span("serve/decode_commit", cat="serve", tick=tick):
-                for j, seq in enumerate(seqs):
-                    tok = int(toks[j])
-                    seq.seen_tokens = seq.total_tokens
-                    self._advanced(seq)
-                    seq.generated.append(tok)
-                    out[seq.uid] = tok
-                    if self.config.eos_token_id is not None and \
-                            tok == self.config.eos_token_id:
-                        seq.done = True
-            t_decode = time.monotonic() - t0
-            if tracer.enabled:
-                # what the scheduler decided, as plain host ints the step
-                # already holds: the batch and the bucket it was padded to,
-                # the context the paged kernel had to read (whole, and cut
-                # to the sliding window where the model has one) and the
-                # keys of the tiles it read them in
-                window, whole = self._window, sum(contexts)
-                tracer.complete(
-                    "serve/step_decode", t_decode, cat="serve",
-                    end_ts=t0 + t_decode, tick=tick,
-                    batch=len(seqs), bucket=b, ctx_tokens=whole,
-                    ctx_tokens_windowed=sum(min(c, window) for c in contexts)
-                    if window else whole, ctx_blocks=mb,
-                    **self.kv.decode_tile_keys(contexts, mb, window),
-                    **counts)
+        counts: Dict[str, int] = {}
+        dropped_before = self.rows_dropped
+        collected: List[int] = []
+        while len(self._pending) > keep:
+            collected.extend(self._pending[0].uids)
+            for name, n in self._collect_oldest(tick, tracer).items():
+                counts[name] = counts.get(name, 0) + n
+        self.last_collected_uids = tuple(collected)
+        dropped = self.rows_dropped - dropped_before
+        t_collect = time.monotonic() - t0
+        t_prefill, t_decode = d.t_prefill, 0.0
+        if d.decode_t0 is not None:
+            t_decode = t0 + t_collect - d.decode_t0
+        elif plan.prefill_chunks:
+            t_prefill = t0 + t_collect - d.prefill_t0
+        else:
+            t_decode = t_collect
+        if tracer.enabled:
+            # what the collection read rides on this tick's decode span, or
+            # where it decoded nothing on its last chunk's, which then
+            # holds the wait as the decode span would
+            read = dict(counts, rows_dropped=dropped)
+            marks = d.chunk_marks
+            if marks and d.decode_t0 is None:
+                c0, _, args = marks[-1]
+                marks[-1] = (c0, t0 + t_collect, dict(args, **read))
+            for c0, c1, args in marks:
+                tracer.complete("serve/prefill_chunk", c1 - c0, cat="serve",
+                                end_ts=c1, tick=tick, ahead=d.ahead, **args)
+            if marks:
+                tracer.complete("serve/step_prefill", t_prefill, cat="serve",
+                                end_ts=d.prefill_t0 + t_prefill, tick=tick,
+                                chunks=len(marks))
+            if d.decode_t0 is not None:
+                tracer.complete("serve/step_decode", t_decode, cat="serve",
+                                end_ts=d.decode_t0 + t_decode, tick=tick,
+                                ahead=d.ahead, **d.decode_args, **read)
+            elif collected and not marks:
+                # nothing left to dispatch: the tick is the wait for the
+                # last step and its commit, the decode stage's all the same
+                tracer.complete("serve/step_decode", t_decode, cat="serve",
+                                end_ts=t0 + t_decode, tick=tick, ahead=0,
+                                **read)
 
         with tracer.span("serve/step_finish", cat="serve", tick=tick):
             self.tick = tick + 1
@@ -550,14 +547,249 @@ class InferenceEngineV2:
             self.last_step_counters = {"prefill_tokens": prefill_tokens,
                                        "chunks": len(plan.prefill_chunks),
                                        "decode_tokens": decode_tokens,
+                                       "ahead": d.ahead,
+                                       "rows_dropped": dropped,
                                        **pages}
             if tracer.enabled and not plan.empty:
                 tracer.counter("serve/kv_pages", cat="mem", **pages)
             if not plan.empty:
-                self.sched_ledger.observe_tick(prefill_tokens,
-                                               len(plan.prefill_chunks),
-                                               decode_tokens, cap=cap)
+                self.sched_ledger.observe_tick(
+                    prefill_tokens, len(plan.prefill_chunks), decode_tokens,
+                    cap=self.config.scheduler.prefill_chunk_tokens)
+            out, self._ready = self._ready, {}
         return out
+
+    def _dispatch(self, tick: int, tracer) -> _Dispatched:
+        """Plan a step and hand its programs to the device; nothing here
+        reads a value back. The bookkeeping that lengths decide happens now:
+        blocks, positions, ``seen_tokens``, the windowed blocks a sequence
+        gives back as it advances, the tokens it has in flight. Blocks may
+        be freed and handed to another sequence by this bookkeeping while a
+        program that reads them is still queued: the device runs programs
+        in the order they were dispatched, and every reader and writer of
+        the pool is such a program, so whatever the new holder writes, it
+        writes after the old one has read."""
+        with tracer.span("serve/plan", cat="serve", tick=tick):
+            plan = plan_step(self.state.decoding(), self.state.prefilling(),
+                             self.config.scheduler,
+                             block_tokens=self.kv.cfg.block_size)
+            d = _Dispatched(plan)
+            if plan.empty:
+                return d
+            d.ahead = int(bool(self._pending))
+            rec = _PendingStep(uids=tuple(
+                [s.uid for s in plan.decode_seqs]
+                + [c.seq.uid for c in plan.prefill_chunks]))
+            self._pending.append(rec)
+
+        # --- prefill chunks (SplitFuse) ---
+        d.prefill_t0 = time.monotonic()
+        for chunk in plan.prefill_chunks:
+            seq = chunk.seq
+            end = chunk.start + chunk.length
+            # per-chunk sub-span, nested inside serve/step_prefill (same
+            # exclusive stage): building the chunk, its dispatch, and the
+            # bookkeeping after it; ``step`` stamps it from these marks
+            c0 = time.monotonic()
+            self._ensure_blocks(seq, end)
+            tokens = np.zeros((chunk.bucket,), np.int32)
+            tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
+            mb = self._ctx_bucket_blocks(end)
+            table = self._step_tables(seq, mb, chunk.start, chunk.bucket)
+            # the step programs consume the pool they are given: what
+            # comes back is bound at once, so that a fault later in the
+            # tick (and the server's next step after it) finds the
+            # engine on a live pool
+            logits, self.kv.pool, counts = prefill_chunk_g(
+                self.params, self.kv.pool, jnp.asarray(tokens),
+                chunk.start,
+                jax.tree.map(jnp.asarray, table), chunk.length,
+                policy=self.policy, cfg=self.model_config,
+                block_size=self.kv.cfg.block_size,
+                attn_impl=self.config.attn_impl)
+            self._keep_counts(rec, counts)
+            seq.seen_tokens = end
+            self._advanced(seq)
+            self._prefill_computed += chunk.length
+            rec.chunks.append((seq, chunk.start, chunk.length, None))
+            if self.prefix_cache is not None:
+                # register the freshly materialized FULL prompt blocks
+                # so concurrent arrivals with the same prefix reuse them
+                # (pinned for this sequence's lifetime — the pin is what
+                # keeps a shared page safe from release/demotion)
+                self.prefix_cache.insert_from_seq(
+                    seq.uid, seq.prompt_tokens, seq.blocks,
+                    min(seq.seen_tokens, len(seq.prompt_tokens)))
+            if not seq.in_prefill:
+                rows = np.full((2, 1), -1, np.int32)
+                rows[0] = seq.slot
+                sampled = self._sample_dispatch(logits[None],
+                                                jnp.asarray(rows))
+                seq.in_flight += 1
+                seq.token_on_device = True
+                rec.chunks[-1] = (seq, chunk.start, chunk.length, sampled)
+            d.chunk_marks.append((c0, time.monotonic(), dict(
+                uid=seq.uid, tokens=chunk.length, bucket=chunk.bucket,
+                start=chunk.start)))
+        if plan.prefill_chunks:
+            d.t_prefill = time.monotonic() - d.prefill_t0
+
+        # --- decode batch ---
+        if plan.decode_seqs:
+            d.decode_t0 = time.monotonic()
+            with tracer.span("serve/decode_build", cat="serve",
+                             tick=tick) as build:
+                seqs = plan.decode_seqs
+                b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
+                contexts = [s.total_tokens for s in seqs]
+                mb = self._ctx_bucket_blocks(max(contexts))
+                # row j's slot in the last-token vector, and its token
+                # where the host holds it (-1: read the slot)
+                rows = np.full((2, b), -1, np.int32)
+                rows[0] = self._pad_slot
+                positions = np.zeros((b,), np.int32)
+                valid = np.zeros((b,), bool)
+                for j, seq in enumerate(seqs):
+                    self._ensure_blocks(seq, seq.total_tokens)
+                    rows[0, j] = seq.slot
+                    if not seq.token_on_device:
+                        rows[1, j] = seq.generated[-1] if seq.generated \
+                            else seq.prompt_tokens[-1]
+                    positions[j] = seq.total_tokens - 1
+                    valid[j] = True
+                # signature covers the actual block ids: uid reuse after
+                # flush() can hand a same-shaped batch different pages
+                # (a windowed block given back resets the signature:
+                # ``_advanced``)
+                sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
+                rebuilt = sig != self._table_sig
+                if rebuilt:
+                    self._dev_tables = self._decode_tables(seqs, b, mb)
+                    self._table_sig = sig
+                build.note(tables_rebuilt=rebuilt)
+            with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
+                rows = jnp.asarray(rows)
+                logits, self.kv.pool, counts = decode_step_g(
+                    self.params, self.kv.pool,
+                    feed_tokens(self._last_tokens, rows),
+                    jnp.asarray(positions), self._dev_tables,
+                    jnp.asarray(valid),
+                    policy=self.policy, cfg=self.model_config,
+                    block_size=self.kv.cfg.block_size,
+                    attn_impl=self.config.attn_impl)
+                self._keep_counts(rec, counts)
+                # sample on device; only [B] token ids cross to the host —
+                # the [B, vocab] logits D2H fetch would dominate the loop
+                rec.decode_sampled = self._sample_dispatch(logits, rows)
+                rec.decode_seqs = seqs
+                for seq in seqs:
+                    seq.seen_tokens = seq.total_tokens
+                    self._advanced(seq)
+                    seq.in_flight += 1
+                    seq.token_on_device = True
+            # what the scheduler decided, as plain host ints the step
+            # already holds: the batch and the bucket it was padded to,
+            # the context the paged kernel had to read (whole, and cut
+            # to the sliding window where the model has one) and the
+            # keys of the tiles it read them in
+            if tracer.enabled:
+                window, whole = self._window, sum(contexts)
+                d.decode_args = dict(
+                    batch=len(seqs), bucket=b, ctx_tokens=whole,
+                    ctx_tokens_windowed=sum(min(c, window) for c in contexts)
+                    if window else whole, ctx_blocks=mb,
+                    **self.kv.decode_tile_keys(contexts, mb, window))
+        return d
+
+    def _collect_oldest(self, tick: int, tracer) -> Dict[str, int]:
+        """Read the oldest pending step's sampled tokens and commit them:
+        the one place the engine waits for the device, and where values
+        decide (``generated``, what ``step`` returns, ``eos_token_id``).
+        Returns what the step programs counted, under their
+        ``STEP_COUNTER_ARGS`` names, or {}. The counts are results of
+        programs that ran before the sampling, so once the tokens are here
+        they are too; a step that sampled nothing (chunks that ended no
+        prompt) is waited for by nothing, and its counts are read with the
+        next token that is. A read that raises abandons every pending step
+        (``_abandon_pending``) and says so in ``last_fault``."""
+        rec = self._pending[0]
+        rows = [([seq], sampled, False) for seq, _, _, sampled in rec.chunks
+                if sampled is not None]
+        if rec.decode_sampled is not None:
+            rows.append((rec.decode_seqs, rec.decode_sampled, True))
+        if not rows:
+            self._pending.popleft()
+            self._pending_counts += rec.counts
+            return {}
+        kept = self._pending_counts + rec.counts
+        try:
+            with tracer.span("serve/decode_wait", cat="serve", tick=tick):
+                # the step's one wait: the sampled tokens' readback, with
+                # vectors that were ready before them (hotpath.py declares
+                # this function the served step's readback)
+                values = jax.device_get([r[1] for r in rows] + kept)
+        except Exception:
+            self.last_fault = {"uids": list(rec.uids),
+                               "lost": self._abandon_pending()}
+            raise
+        self._pending.popleft()
+        self._pending_counts = []
+        with tracer.span("serve/decode_commit", cat="serve", tick=tick):
+            for (seqs, _, decode), toks in zip(rows, values):
+                for seq, tok in zip(seqs, toks):
+                    seq.in_flight -= 1
+                    if seq.done:
+                        # ended while this row was queued: dropped
+                        self.rows_dropped += 1
+                        continue
+                    tok = int(tok)
+                    seq.generated.append(tok)
+                    self._ready[seq.uid] = tok
+                    if decode and self.config.eos_token_id is not None \
+                            and tok == self.config.eos_token_id:
+                        seq.done = True
+            if not kept:
+                return {}
+            return dict(zip(STEP_COUNTER_ARGS, (
+                int(v) for v in np.sum(values[len(rows):], axis=0))))
+
+    def _abandon_pending(self) -> List[int]:
+        """Take back every pending step, newest first, after a read that
+        raised: its rows and chunks count as not run, so the next step
+        dispatches them again with the tokens the host holds. Returns the
+        uids that cannot go on: over pages by layer kind, a sequence whose
+        windowed blocks were given back past where it now stands (the
+        server recomputes it from its tokens)."""
+        touched = {}
+        while self._pending:
+            rec = self._pending.pop()
+            for seq in rec.decode_seqs:
+                seq.in_flight -= 1
+                seq.seen_tokens -= 1
+                touched[seq.uid] = seq
+            for seq, start, length, sampled in reversed(rec.chunks):
+                seq.in_flight -= sampled is not None
+                seq.seen_tokens = start
+                self._prefill_computed -= length
+                touched[seq.uid] = seq
+        self._pending_counts = []
+        self._table_sig = None
+        lost = []
+        for uid, seq in touched.items():
+            seq.token_on_device = False
+            if self.kv.two_kinds and seq.window_base > blocks_behind_window(
+                    seq.seen_tokens, self.kv.kind.window,
+                    self.kv.cfg.block_size):
+                lost.append(uid)
+        return lost
+
+    def collect(self) -> None:
+        """Collect every pending step now; the next ``step`` returns the
+        tokens with its own. For whoever is about to touch a sequence that
+        may have a row in flight, or to stop stepping."""
+        tracer = get_tracer()
+        while self._pending:
+            self._collect_oldest(self.tick, tracer)
 
     def _kv_page_counters(self) -> Dict[str, int]:
         """The pool after a step, as host ints: blocks sequences hold by
@@ -569,34 +801,22 @@ class InferenceEngineV2:
                                       if not s.paused),
                 **{f"kv_{name}": n for name, n in held.items()}}
 
-    def _keep_counts(self, counts) -> None:
+    def _keep_counts(self, rec: _PendingStep, counts) -> None:
         """Keep what a step program counted (nothing where its policy counts
-        nothing) while a tracer is there to read it."""
+        nothing) with its step, while a tracer is there to read it."""
         if counts.size and get_tracer().enabled:
-            self._pending_counts.append(counts)
+            rec.counts.append(counts)
 
-    def _read_with_counts(self, sampled):
-        """(the sampled tokens on the host, the kept counts summed under
-        their ``STEP_COUNTER_ARGS`` names or {}): one wait for both. The
-        counts are results of step programs that ran before the sampling,
-        so once the tokens are here they are too; a chunk that ended no
-        prompt was not waited for, and its counts are read here, with the
-        next token that is."""
-        if not self._pending_counts:
-            return np.asarray(sampled), {}
-        kept, self._pending_counts = self._pending_counts, []
-        # dslint: disable=DS002 -- this IS the step's one wait (serve/decode_wait): the sampled tokens' readback, with vectors that were ready before them
-        toks, *counts = jax.device_get([sampled] + kept)
-        return toks, dict(zip(STEP_COUNTER_ARGS,
-                              (int(v) for v in np.sum(counts, axis=0))))
-
-    def _sample_dispatch(self, logits):
-        """[B, V] device logits -> [B] device token ids: dispatch only. The
-        caller's ``np.asarray`` of the result (one small D2H, inside a
+    def _sample_dispatch(self, logits, rows):
+        """[B, V] device logits -> [B] device token ids, stored into the
+        last-token vector at ``rows[0]`` too: dispatch only.
+        ``_collect_oldest``'s read of the result (one small D2H, inside a
         ``serve/decode_wait`` span) is where the host waits for the
         device."""
         self._rng, key = jax.random.split(self._rng)
-        return sample_tokens(logits, key, self.config.sampling)
+        tokens, self._last_tokens = sample_into(
+            logits, key, self._last_tokens, rows, self.config.sampling)
+        return tokens
 
     # ------------------------------------------------------------------
     # lifecycle (reference: engine_v2.flush)
@@ -609,8 +829,14 @@ class InferenceEngineV2:
         win: the next turn's prompt starts with exactly these tokens —
         and blocks the cache owns are excluded from the allocator
         release (pinned pages additionally excluded from the fp8 scale
-        reset inside ``BlockedKVCache.release``)."""
+        reset inside ``BlockedKVCache.release``). A sequence with a row in
+        flight is collected first (``reap_finished`` waits a tick for it
+        instead)."""
+        seq = self.state.get(uid)
+        if seq is not None and seq.in_flight:
+            self.collect()
         seq = self.state.pop(uid)
+        self._ready.pop(uid, None)
         if seq.in_prefill:
             # cancelled mid-prefill: the never-computed remainder leaves
             # the conservation identity (saved + computed == total) exact
@@ -619,7 +845,7 @@ class InferenceEngineV2:
         if self.prefix_cache is not None:
             history = np.concatenate(
                 [seq.prompt_tokens,
-                 np.asarray(seq.generated, np.int32)]) if seq.generated \
+                 np.fromiter(seq.generated, np.int32)]) if seq.generated \
                 else seq.prompt_tokens
             self.prefix_cache.insert_from_seq(
                 uid, history, seq.blocks, seq.seen_tokens, pin=False)
@@ -659,8 +885,11 @@ class InferenceEngineV2:
         self-sufficient even if the cached copies get evicted meanwhile."""
         self.require_one_page_kind("the host KV offload tier (demote_kv)")
         seq = self.state.get(uid)
-        if seq is None or seq.paused or seq.done:
-            # a done sequence is about to be reaped — gathering its pages
+        if seq is not None and seq.in_flight:
+            self.collect()    # a paused sequence has nothing in flight
+        if seq is None or seq.paused or seq.done or seq.budget_spent:
+            # a done sequence is about to be reaped (one whose budget is
+            # spent, once its last token is read) — gathering its pages
             # would be a pure wasted device->host copy
             return 0
         if seq.blocks:
@@ -734,6 +963,7 @@ class InferenceEngineV2:
         retirement instead of being recomputed fleet-wide. A deliberate
         device->host gather — drain-time only, never on the serve tick."""
         self.require_one_page_kind("the prefix handoff (export)")
+        self.collect()
         cache = self.prefix_cache
         out = {"chains": 0, "blocks": 0, "stored_bytes": 0, "raw_bytes": 0}
         payload: Dict[str, np.ndarray] = {}
@@ -909,14 +1139,19 @@ class InferenceEngineV2:
     # admits without stepping, steps in its own cadence, and reaps
     # finished sequences between steps)
     # ------------------------------------------------------------------
-    def admit(self, uid: int, prompt_tokens: Sequence[int]) -> SequenceDescriptor:
+    def admit(self, uid: int, prompt_tokens: Sequence[int],
+              max_new_tokens: Optional[int] = None) -> SequenceDescriptor:
         """Admission-only: create sequence state WITHOUT running a step.
         ``put`` couples admission to stepping; a serving loop needs them
-        apart so a burst of arrivals lands in one SplitFuse plan."""
+        apart so a burst of arrivals lands in one SplitFuse plan.
+        ``max_new_tokens`` is the request's budget: the planner gives the
+        sequence no row past it, so a step dispatched before the last
+        token's value is read never computes one for nothing."""
         if not self.can_schedule([uid], [len(prompt_tokens)]):
             raise RuntimeError(
                 "cannot admit: out of KV blocks or sequence slots")
         seq = self.state.create(uid, prompt_tokens)
+        seq.max_new_tokens = max_new_tokens
         self._prefix_admit(seq)
         return seq
 
@@ -928,7 +1163,10 @@ class InferenceEngineV2:
             seq.done = True
 
     def finished_uids(self) -> List[int]:
-        return [s.uid for s in self.state.all() if s.done]
+        """Done sequences with no row in flight: one that ended with a row
+        already queued (``eos_token_id`` read a tick late, a cancel) is
+        reaped a tick later, once that row's token has been dropped."""
+        return [s.uid for s in self.state.all() if s.done and not s.in_flight]
 
     def reap_finished(self) -> Dict[int, List[int]]:
         """Flush every done sequence (releasing its KV blocks); returns
@@ -937,8 +1175,11 @@ class InferenceEngineV2:
 
     def has_work(self) -> bool:
         """Any sequence the next step plan could advance — demoted (paused)
-        sequences don't count until the tier policy promotes them."""
-        return any(not s.done and not s.paused for s in self.state.all())
+        sequences don't count until the tier policy promotes them — or
+        tokens the next ``step`` has to collect or to hand over."""
+        return bool(self._pending or self._ready) or any(
+            not s.done and not s.paused and not s.budget_spent
+            for s in self.state.all())
 
     def kv_usable_blocks(self) -> int:
         """Blocks available to sequences (the last block is the permanent
@@ -1059,6 +1300,7 @@ class InferenceEngineV2:
         self._spec_proposed += len(proposed)
         self._spec_accepted += min(appended, len(emitted) - 1)
         self._spec_steps += 1
+        seq.token_on_device = False               # the host chose these
         seq.seen_tokens = seq.total_tokens - 1    # last emitted has no KV yet
         self._advanced(seq)
 

@@ -27,10 +27,33 @@ class SequenceDescriptor:
     # demoted to the host KV tier: holds no device blocks, invisible to the
     # step planner until promoted back (engine_v2.demote_kv/promote_kv)
     paused: bool = False
+    # tokens a dispatched step program has sampled for this sequence and the
+    # host has not read yet (engine_v2 ``_collect`` appends them to
+    # ``generated``): lengths count them, values wait for the read
+    in_flight: int = 0
+    # the request's budget of new tokens where the engine was told it
+    # (``admit``): the planner dispatches no row past it. None: unbounded
+    max_new_tokens: Optional[int] = None
+    # place in the engine's device-resident last-token vector, held from
+    # ``create`` to ``pop``; ``token_on_device`` says that the vector has
+    # this sequence's last token (a step program sampled it), so the next
+    # decode row reads it there and the host uploads nothing
+    slot: int = 0
+    token_on_device: bool = False
+
+    @property
+    def dispatched(self) -> int:
+        """New tokens sampled for this sequence, read back or not."""
+        return len(self.generated) + self.in_flight
 
     @property
     def total_tokens(self) -> int:
-        return len(self.prompt_tokens) + len(self.generated)
+        return len(self.prompt_tokens) + self.dispatched
+
+    @property
+    def budget_spent(self) -> bool:
+        return self.max_new_tokens is not None \
+            and self.dispatched >= self.max_new_tokens
 
     @property
     def in_prefill(self) -> bool:
@@ -48,6 +71,9 @@ class StateManager:
         self.max_tracked_sequences = max_tracked_sequences
         self.max_context_length = max_context_length
         self._seqs: Dict[int, SequenceDescriptor] = {}
+        # slots of the last-token vector not held by a sequence; the one
+        # past them all (``max_tracked_sequences``) is batch padding's
+        self._free_slots = list(range(max_tracked_sequences - 1, -1, -1))
 
     def __contains__(self, uid: int) -> bool:
         return uid in self._seqs
@@ -67,12 +93,15 @@ class StateManager:
         if len(prompt) > self.max_context_length:
             raise ValueError(f"prompt length {len(prompt)} > max context "
                              f"{self.max_context_length}")
-        seq = SequenceDescriptor(uid=uid, prompt_tokens=prompt)
+        seq = SequenceDescriptor(uid=uid, prompt_tokens=prompt,
+                                 slot=self._free_slots.pop())
         self._seqs[uid] = seq
         return seq
 
     def pop(self, uid: int) -> SequenceDescriptor:
-        return self._seqs.pop(uid)
+        seq = self._seqs.pop(uid)
+        self._free_slots.append(seq.slot)
+        return seq
 
     def all(self) -> List[SequenceDescriptor]:
         return list(self._seqs.values())
@@ -81,8 +110,12 @@ class StateManager:
         return [s for s in self._seqs.values() if not s.done]
 
     def decoding(self) -> List[SequenceDescriptor]:
+        """Sequences the next step may give a decode row: a budget that is
+        spent by what was dispatched ends a sequence for the planner before
+        the last token's value is read."""
         return [s for s in self._seqs.values()
-                if not s.done and not s.paused and not s.in_prefill]
+                if not s.done and not s.paused and not s.in_prefill
+                and not s.budget_spent]
 
     def prefilling(self) -> List[SequenceDescriptor]:
         return [s for s in self._seqs.values()

@@ -31,6 +31,26 @@ def sample_tokens(logits, key, cfg: SamplingConfig):
         return _sample(logits, key, cfg)
 
 
+@partial(jax.jit, static_argnames=("cfg",))
+def sample_into(logits, key, last, rows, cfg: SamplingConfig):
+    """``sample_tokens``, with the tokens also left on the device where the
+    next step reads them: ``last`` [slots] int32 is a last-token vector by
+    sequence slot, ``rows`` [2, B] int32 carries each row's slot
+    in ``rows[0]``. Returns (the [B] token ids, the vector with them
+    stored)."""
+    with jax.named_scope("sample"):
+        tokens = _sample(logits, key, cfg)
+    return tokens, last.at[rows[0]].set(tokens)
+
+
+@jax.jit
+def feed_tokens(last, rows):
+    """The [B] token ids a decode step takes: row ``j`` reads its sequence's
+    last token at ``last[rows[0, j]]``, unless the host holds it and sent it
+    as ``rows[1, j]`` (>= 0)."""
+    return jnp.where(rows[1] >= 0, rows[1], last[rows[0]])
+
+
 def _sample(logits, key, cfg: SamplingConfig):
     if cfg.temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -58,3 +78,5 @@ def _sample(logits, key, cfg: SamplingConfig):
 from deepspeed_tpu.telemetry.compiles import watch_jit  # noqa: E402
 
 sample_tokens = watch_jit(sample_tokens, "sampling.sample_tokens")
+sample_into = watch_jit(sample_into, "sampling.sample_into")
+feed_tokens = watch_jit(feed_tokens, "sampling.feed_tokens")

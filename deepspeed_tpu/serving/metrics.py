@@ -79,6 +79,11 @@ class ServingMetrics:
         self.requests_quarantined = 0    # poison requests past retry budget
         self.tokens_generated = 0
         self.engine_steps = 0
+        # steps dispatched while the step before was still on the device
+        # (the loop keeps one in flight), and rows computed for a sequence
+        # that had ended by the time their token was read (never delivered)
+        self.ticks_dispatched_ahead = 0
+        self.rows_dropped = 0
         # request-level fault isolation (non-fatal engine-step failures)
         self.engine_step_faults = 0
         self.fault_recoveries = 0        # clean-tick recovery episodes
@@ -151,9 +156,11 @@ class ServingMetrics:
             self.tokens_generated += n
         self.token_rate.add(n)
 
-    def on_step(self):
+    def on_step(self, ahead: int = 0, rows_dropped: int = 0):
         with self._lock:
             self.engine_steps += 1
+            self.ticks_dispatched_ahead += ahead
+            self.rows_dropped += rows_dropped
 
     def on_finish(self, req):
         """Fold a terminal request's latency samples in (any terminal state)."""
@@ -289,6 +296,8 @@ class ServingMetrics:
                 "requests_quarantined": self.requests_quarantined,
                 "tokens_generated": self.tokens_generated,
                 "engine_steps": self.engine_steps,
+                "ticks_dispatched_ahead": self.ticks_dispatched_ahead,
+                "rows_dropped": self.rows_dropped,
                 "engine_step_faults": self.engine_step_faults,
                 "fault_recoveries": self.fault_recoveries,
                 "recomputed_tokens": self.recomputed_tokens,
@@ -359,7 +368,9 @@ class ServingMetrics:
                     "requests_shed", "requests_completed",
                     "requests_cancelled", "requests_timed_out",
                     "requests_failed", "requests_quarantined",
-                    "tokens_generated", "engine_steps", "kv_drift_events",
+                    "tokens_generated", "engine_steps",
+                    "ticks_dispatched_ahead", "rows_dropped",
+                    "kv_drift_events",
                     "engine_step_faults", "fault_recoveries",
                     "recomputed_tokens", "degraded_latches",
                     "kv_demotions", "kv_promotions", "kv_demoted_bytes",

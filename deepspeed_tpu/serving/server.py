@@ -6,6 +6,9 @@ Architecture:
 
   submit() threads --> bounded admission queue --> serve loop (ONE thread)
                                                      |-- engine.admit / step
+                                                     |   (one step in flight:
+                                                     |   dispatch k, collect
+                                                     |   k-1)
                                                      |-- KV tier rebalance
                                                      |   (demote/promote)
                                                      |-- degradation ladder
@@ -282,6 +285,14 @@ class InferenceServer:
         cap = int(self.config.scheduler.get("prefill_chunk_tokens", 0) or 0)
         if cap > 0 and hasattr(engine, "configure_chunked_prefill"):
             engine.configure_chunked_prefill(cap)
+        # one step in flight: while the loop runs, ``engine.step`` dispatches
+        # tick k and collects tick k-1, so the fan-out, the reap and the
+        # admission between two ticks run while the device does. The depth
+        # is the loop's own (``_serve_loop`` sets and clears it), not a
+        # configuration key; an engine double without ``collect`` runs as
+        # before. Such an engine is told the request's budget at admission,
+        # so that it dispatches no row past it
+        self._pipelined = hasattr(engine, "collect")
         self._block_bytes_cache: Optional[int] = None
         # serving-tick stage clocks (serve-loop-private): cumulative busy
         # seconds per stage + cumulative tick seconds, feeding the
@@ -627,6 +638,27 @@ class InferenceServer:
     # the serve loop (single thread; sole owner of the engine)
     # ------------------------------------------------------------------
     def _serve_loop(self):
+        if self._pipelined:
+            self.engine.depth = 1
+        try:
+            self._serve_ticks()
+        finally:
+            if self._pipelined:
+                # the engine goes back to whoever calls it next as it
+                # came: nothing pending, every step's tokens from its call
+                self._collect_pending()
+                self.engine.depth = 0
+
+    def _collect_pending(self) -> None:
+        """Collect what the engine has in flight (its next ``step`` returns
+        the tokens). A read that raises has already taken the pending steps
+        back (``engine.last_fault`` names them)."""
+        try:
+            self.engine.collect()
+        except Exception:
+            logger.exception("serve loop: collecting the pending step failed")
+
+    def _serve_ticks(self):
         while True:
             if self._stopped:
                 return
@@ -642,7 +674,9 @@ class InferenceServer:
                 worked = False
             if not worked:
                 # nothing to do: block until a submit() nudge (bounded so
-                # deadline expiry of QUEUED requests is still noticed)
+                # deadline expiry of QUEUED requests is still noticed). No
+                # step is pending here: ``has_work`` counts one, and a step
+                # that dispatches nothing collects all
                 self._wake.wait(timeout=self.config.idle_poll_s * 10)
                 self._wake.clear()
 
@@ -687,12 +721,17 @@ class InferenceServer:
                 self.engine.tick = self._tick
                 with get_tracer().span("serve/engine_step", cat="serve",
                                        tick=self._tick):
+                    # dispatches this tick; ``out`` is what the tick before
+                    # dispatched (this tick's, from an engine double)
                     out = self.engine.step()
             except Exception as e:
                 raise _EngineStepError(str(e)) from e
             t0 = time.monotonic()
-            self.metrics.on_step()
-            self._note_clean_step()
+            counters = getattr(self.engine, "last_step_counters", None) or {}
+            self.metrics.on_step(ahead=counters.get("ahead", 0),
+                                 rows_dropped=counters.get("rows_dropped", 0))
+            self._note_clean_step(
+                getattr(self.engine, "last_collected_uids", None))
             worked = True
             # what follows the step belongs to no stage; its mark ends
             # where the fan-out's begins, so the two tile
@@ -840,13 +879,15 @@ class InferenceServer:
             snapshot = list(self._inflight.items())
         # demotion candidates: engine-resident, not already demoted, and
         # not done (a done sequence is reaped this tick — gathering its
-        # pages would be a wasted copy that skews the demotion counters)
+        # pages would be a wasted copy that skews the demotion counters),
+        # nor with its whole budget dispatched (its last token is on the
+        # device or on its way out: it is done once that is fanned out)
         active = []
         for u, r in snapshot:
             if u in dem:
                 continue
             seq = self.engine.state.get(u)
-            if seq is None or seq.done:
+            if seq is None or seq.done or getattr(seq, "budget_spent", False):
                 continue
             active.append(r)
         worst = [self._blocks_for(r) for r in active]
@@ -1110,13 +1151,21 @@ class InferenceServer:
     # ------------------------------------------------------------------
     # request-level fault isolation
     # ------------------------------------------------------------------
-    def _note_clean_step(self) -> None:
+    def _note_clean_step(self, collected=None) -> None:
         """A successful engine step: reset the fault window; after N clean
         steps a fault episode is declared over (health auto-recovery — the
-        anti-sticky-503 half of the isolation story)."""
+        anti-sticky-503 half of the isolation story). ``collected`` names
+        the sequences of the steps whose tokens came back (a step is read a
+        tick after its dispatch, and only then has a request survived it);
+        None, from an engine double: every admitted request."""
         self._consecutive_faults = 0
         if self._admitted_since_clean:
-            self._admitted_since_clean.clear()
+            if collected is None:
+                self._admitted_since_clean.clear()
+            else:
+                self._admitted_since_clean = [
+                    u for u in self._admitted_since_clean
+                    if u not in collected]
         if self._fault_episode:
             self._clean_steps += 1
             self._maybe_recover()
@@ -1137,6 +1186,17 @@ class InferenceServer:
         its KV recomputed, quarantined past its retry budget — so one bad
         request cannot take the replica down."""
         cause = err.__cause__ if err.__cause__ is not None else err
+        # a fault surfaces where the step's tokens are read, one tick after
+        # its dispatch: the engine names that step's sequences, which are
+        # not the ones just planned. Where the dispatch itself raised, the
+        # step before is still pending and is collected now, so that
+        # whatever the handler evicts has no row in flight
+        fault = None
+        if self._pipelined:
+            fault = getattr(self.engine, "last_fault", None)
+            if fault is None:
+                self._collect_pending()
+                fault = getattr(self.engine, "last_fault", None)
         outcome = classify_exception(cause)
         self.metrics.on_step_fault()
         self._consecutive_faults += 1
@@ -1166,7 +1226,15 @@ class InferenceServer:
         # a raw count mid-search would 503 the replica over one bad
         # request with a deep batch. The 4x backstop still bounds
         # pathological churn absolutely.
-        suspect = self._pick_suspect()
+        suspect = self._pick_suspect(fault and set(fault["uids"]))
+        for uid in fault["lost"] if fault else ():
+            # taken back past what its pages still hold: recomputed from
+            # its tokens, like a suspect
+            with self._lock:
+                req = self._inflight.get(uid)
+            if req is not None and req is not suspect \
+                    and not req.state.terminal:
+                self._evict_for_retry(req, cause)
         if suspect is None or self._consecutive_faults >= \
                 4 * max(self.config.max_consecutive_step_faults, 1):
             if self._consecutive_faults >= \
@@ -1180,24 +1248,27 @@ class InferenceServer:
             return
         self._evict_for_retry(suspect, cause)
 
-    def _pick_suspect(self) -> Optional[Request]:
+    def _pick_suspect(self, among=None) -> Optional[Request]:
         """The most recently admitted ACTIVE request that has never
         survived a clean step — the request whose arrival correlates with
         the engine starting to fault. Falls back to the most recent active
         admission. Demoted (paused) requests are never suspects: they are
         not in the step plan, so they cannot have caused the fault —
         blaming one would quarantine an innocent while the real poison
-        keeps faulting."""
+        keeps faulting. ``among``: the sequences of the step that failed,
+        where the engine named them; no other request is a suspect then."""
         with self._lock:
             dem = set(self._demoted)
             for uid in reversed(self._admitted_since_clean):
                 req = self._inflight.get(uid)
                 if (req is not None and uid not in dem
-                        and not req.state.terminal):
+                        and not req.state.terminal
+                        and (among is None or uid in among)):
                     return req
             for uid in reversed(list(self._inflight)):
                 req = self._inflight[uid]
-                if uid not in dem and not req.state.terminal:
+                if uid not in dem and not req.state.terminal \
+                        and (among is None or uid in among):
                     return req
         return None
 
@@ -1381,7 +1452,14 @@ class InferenceServer:
                 self._inflight[req.uid] = req
                 self._admitted_since_clean.append(req.uid)
             try:
-                self.engine.admit(req.uid, req.engine_prompt())
+                if self._pipelined:
+                    # what is left of the budget: a retry's sent tokens
+                    # have become prompt
+                    self.engine.admit(
+                        req.uid, req.engine_prompt(),
+                        max_new_tokens=req.max_new_tokens - len(req.tokens))
+                else:
+                    self.engine.admit(req.uid, req.engine_prompt())
             except Exception as e:
                 # fail THIS request, not the batch (e.g. prompt longer than
                 # the engine's max context)

@@ -96,12 +96,20 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/drain_fanout": ("complete",),
     "serve/drain_reap": ("complete",),
     "serve/bookkeep": ("complete",),
+    # stamped by the engine once the tick's collection is over, so that
+    # they can carry what it read: `ahead` (1: dispatched while the step
+    # before was still pending on the device, 0: nothing was), and on the
+    # tick's decode span, or its last chunk's where it decoded nothing,
+    # `rows_dropped` (rows whose sequence had ended by the time their token
+    # was read) beside the STEP_COUNTER_ARGS. Every span of a tick carries
+    # the number of the host loop's tick it lies in, whichever step its
+    # wait and commit are for
     "serve/step_prefill": ("complete",),
     "serve/step_decode": ("complete",),
+    "serve/prefill_chunk": ("complete",),
     # the phases of one engine step, where the work happens: live spans,
     # mirrored into the profiler's trace (tracer.py), each carrying `tick`
     "serve/plan": ("span",),
-    "serve/prefill_chunk": ("span",),
     "serve/decode_build": ("span",),
     "serve/decode_dispatch": ("span",),
     "serve/decode_wait": ("span",),
@@ -231,10 +239,11 @@ SERVED_SCOPES: Tuple[str, ...] = (
 #: ``jax.lax.ragged_dot`` runs and there are no tiles to count), and the
 #: assignments left out because their expert is held on another chip (0
 #: where a layer's experts are held whole), each summed over the expert
-#: layers. The engine reads them with the sampled token and
-#: puts them on ``serve/prefill_chunk`` and ``serve/step_decode`` as args of
-#: these names; a chunk that ends no prompt is not waited for, so its counts
-#: ride on the next of these spans that is
+#: layers. The engine reads them with the sampled tokens of the step they
+#: belong to and puts them on the ``serve/step_decode`` of the tick that
+#: read them (its last ``serve/prefill_chunk`` where it decoded nothing) as
+#: args of these names; a chunk that ends no prompt is not waited for, so
+#: its counts ride with the next step's that is
 STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched",
                                       "expert_tile_rows",
                                       "expert_rows_absent")

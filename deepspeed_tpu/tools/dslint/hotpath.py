@@ -67,7 +67,6 @@ HOST_NUMPY_FILES: Tuple[str, ...] = (
     "deepspeed_tpu/runtime/engine.py",
     "deepspeed_tpu/runtime/dataloader.py",
     "deepspeed_tpu/serving/server.py",
-    "deepspeed_tpu/inference/v2/engine_v2.py",
     "deepspeed_tpu/inference/v2/kv_offload.py",
     # host token tables: prompt ids arrive as python lists and are staged
     # into numpy before the single H2D
@@ -196,6 +195,15 @@ ESCAPE_HATCHES: Tuple[EscapeHatch, ...] = (
         reason="THE designated readback: one batched device_get over "
                "every pending payload — its bookkeeping callees stay "
                "covered"),
+    EscapeHatch(
+        path="deepspeed_tpu/inference/v2/engine_v2.py",
+        qualname="InferenceEngineV2._collect_oldest",
+        mode="sync_ok",
+        reason="serving's designated readback, as DispatchRing.drain is "
+               "training's: one device_get of a pending step's sampled "
+               "tokens (serve/decode_wait). The dispatch half of the step "
+               "(plan, build, the step programs) reads nothing back, so a "
+               "step can stay in flight while the next is dispatched"),
     EscapeHatch(
         path="deepspeed_tpu/runtime/engine.py",
         qualname="DeepSpeedTPUEngine._drain_metric_ring",

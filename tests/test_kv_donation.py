@@ -202,11 +202,11 @@ def _fault_once(eng, monkeypatch, at_call):
     consumed the pool, before the step's end."""
     real, calls = eng._sample_dispatch, []
 
-    def sampler(logits):
+    def sampler(logits, rows):
         calls.append(1)
         if len(calls) == at_call:
             raise RuntimeError("connection reset by peer")
-        return real(logits)
+        return real(logits, rows)
     monkeypatch.setattr(eng, "_sample_dispatch", sampler)
     return calls
 

@@ -115,9 +115,9 @@ def served(eng, prompts, new_tokens):
     records = []
     sample = eng._sample_dispatch
 
-    def recording(logits):
+    def recording(logits, rows):
         records.append(np.asarray(logits, np.float32))
-        return sample(logits)
+        return sample(logits, rows)
     eng._sample_dispatch = recording
     uids = list(range(1, len(prompts) + 1))
     got = {u: ([], []) for u in uids}

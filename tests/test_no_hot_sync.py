@@ -193,3 +193,47 @@ def test_every_root_is_necessary(package_callgraph):
             f"it from HOT_ROOTS changes no coverage, so either a new "
             f"root subsumed it (delete the stale one deliberately and "
             f"update this proof) or the declaration drifted")
+
+
+# ----------------------------------------------------------------------
+# the served step: dispatch reads nothing back, collection is THE readback
+# ----------------------------------------------------------------------
+ENGINE_V2 = "deepspeed_tpu/inference/v2/engine_v2.py"
+COLLECT = "InferenceEngineV2._collect_oldest"
+
+
+def test_the_served_steps_readback_is_one_declared_function():
+    """``engine_v2.py`` is no longer exempt file-wide: numpy copies and
+    device syncs are forbidden in everything ``step`` reaches, except in the
+    one function that collects a pending step (as ``DispatchRing.drain`` is
+    for training)."""
+    from deepspeed_tpu.tools.dslint.hotpath import HOST_NUMPY_FILES
+    assert ENGINE_V2 not in HOST_NUMPY_FILES
+    hatches = {(h.path, h.qualname): h.mode for h in ESCAPE_HATCHES}
+    assert hatches.get((ENGINE_V2, COLLECT)) == "sync_ok"
+    assert not any(path == ENGINE_V2 and qn != COLLECT
+                   for path, qn in hatches)
+
+
+@pytest.mark.parametrize("half", ["dispatch", "collect"])
+def test_the_steps_dispatch_half_reads_nothing_back(half):
+    """With the hatch taken away, every finding under ``step`` lies in the
+    collecting function: ``step`` itself, ``_dispatch`` and whatever they
+    call (planning, block bookkeeping, building the batch, the step
+    programs' dispatch) hold no sync, so a step can stay in flight while
+    the next one is dispatched."""
+    roots = tuple(r for r in HOT_ROOTS
+                  if r.qualname == "InferenceEngineV2.step")
+    assert roots
+    result = lint_paths(
+        [str(REPO / "deepspeed_tpu")], root=str(REPO),
+        rules=[HotPathSyncRule(roots=roots, hatches=())])
+    found = [f for f in result.findings if f.path == ENGINE_V2]
+    where = {f.anchor.split(":")[0] for f in found}
+    if half == "dispatch":
+        assert where <= {COLLECT}, (
+            "the dispatch half of engine.step gained a host sync:\n  "
+            + "\n  ".join(f.render() for f in found))
+    else:
+        assert where == {COLLECT}, "the step's one wait has moved: " \
+            "declare where the pending step is read in hotpath.py"
