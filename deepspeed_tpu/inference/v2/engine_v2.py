@@ -124,11 +124,21 @@ class _Dispatched:
     collection is over."""
     plan: StepPlan
     ahead: int = 0
+    # where ``ahead`` is 1: had the step before already finished when this
+    # one's first program was dispatched (None: not to be told)
+    starved: Optional[int] = None
     chunk_marks: List[tuple] = dataclasses.field(default_factory=list)
     prefill_t0: float = 0.0                  # where serve/step_prefill opens
     t_prefill: float = 0.0
     decode_t0: Optional[float] = None        # where serve/step_decode opens
     decode_args: Optional[dict] = None
+
+    def flight_args(self) -> Dict[str, int]:
+        """``ahead``, and ``starved`` where it could be told: what the
+        step's spans and ``last_step_counters`` say of the step in flight."""
+        if self.starved is None:
+            return {"ahead": self.ahead}
+        return {"ahead": self.ahead, "starved": self.starved}
 
 
 class InferenceEngineV2:
@@ -507,6 +517,7 @@ class InferenceEngineV2:
             t_prefill = t0 + t_collect - d.prefill_t0
         else:
             t_decode = t_collect
+        flight = d.flight_args()
         if tracer.enabled:
             # what the collection read rides on this tick's decode span, or
             # where it decoded nothing on its last chunk's, which then
@@ -518,7 +529,7 @@ class InferenceEngineV2:
                 marks[-1] = (c0, t0 + t_collect, dict(args, **read))
             for c0, c1, args in marks:
                 tracer.complete("serve/prefill_chunk", c1 - c0, cat="serve",
-                                end_ts=c1, tick=tick, ahead=d.ahead, **args)
+                                end_ts=c1, tick=tick, **flight, **args)
             if marks:
                 tracer.complete("serve/step_prefill", t_prefill, cat="serve",
                                 end_ts=d.prefill_t0 + t_prefill, tick=tick,
@@ -526,7 +537,7 @@ class InferenceEngineV2:
             if d.decode_t0 is not None:
                 tracer.complete("serve/step_decode", t_decode, cat="serve",
                                 end_ts=d.decode_t0 + t_decode, tick=tick,
-                                ahead=d.ahead, **d.decode_args, **read)
+                                **flight, **d.decode_args, **read)
             elif collected and not marks:
                 # nothing left to dispatch: the tick is the wait for the
                 # last step and its commit, the decode stage's all the same
@@ -547,7 +558,7 @@ class InferenceEngineV2:
             self.last_step_counters = {"prefill_tokens": prefill_tokens,
                                        "chunks": len(plan.prefill_chunks),
                                        "decode_tokens": decode_tokens,
-                                       "ahead": d.ahead,
+                                       **flight,
                                        "rows_dropped": dropped,
                                        **pages}
             if tracer.enabled and not plan.empty:
@@ -596,6 +607,8 @@ class InferenceEngineV2:
             tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
             mb = self._ctx_bucket_blocks(end)
             table = self._step_tables(seq, mb, chunk.start, chunk.bucket)
+            if d.ahead and chunk is plan.prefill_chunks[0]:
+                d.starved = self._device_ran_dry()
             # the step programs consume the pool they are given: what
             # comes back is bound at once, so that a fault later in the
             # tick (and the server's next step after it) finds the
@@ -668,6 +681,8 @@ class InferenceEngineV2:
                     self._table_sig = sig
                 build.note(tables_rebuilt=rebuilt)
             with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
+                if d.ahead and not plan.prefill_chunks:
+                    d.starved = self._device_ran_dry()
                 rows = jnp.asarray(rows)
                 logits, self.kv.pool, counts = decode_step_g(
                     self.params, self.kv.pool,
@@ -700,6 +715,23 @@ class InferenceEngineV2:
                     if window else whole, ctx_blocks=mb,
                     **self.kv.decode_tile_keys(contexts, mb, window))
         return d
+
+    def _device_ran_dry(self) -> Optional[int]:
+        """Asked right before this step's first program goes to the device,
+        with a step pending before it: are that step's sampled tokens (its
+        decode's, or its last sampled chunk's where it decoded nothing)
+        already there? 1: the device finished it and has sat idle since, for
+        want of the host; 0: it is still running, and this step queues
+        behind it. ``is_ready`` asks the runtime and waits for nothing. None
+        where the step sampled nothing or its array cannot say: left out,
+        not guessed."""
+        rec = self._pending[-2]
+        sampled = rec.decode_sampled
+        if sampled is None:
+            sampled = next((c[3] for c in reversed(rec.chunks)
+                            if c[3] is not None), None)
+        is_ready = getattr(sampled, "is_ready", None)
+        return None if is_ready is None else int(bool(is_ready()))
 
     def _collect_oldest(self, tick: int, tracer) -> Dict[str, int]:
         """Read the oldest pending step's sampled tokens and commit them:

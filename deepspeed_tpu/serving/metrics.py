@@ -84,6 +84,13 @@ class ServingMetrics:
         # that had ended by the time their token was read (never delivered)
         self.ticks_dispatched_ahead = 0
         self.rows_dropped = 0
+        # of the steps dispatched ahead, those that found the step before
+        # already done: the device had run dry for want of the host (over
+        # ``ticks_dispatched_ahead``: the share of steps the host paced)
+        self.ticks_device_starved = 0
+        # seconds the loop spent with nothing to do, between the ticks that
+        # did something (1 - its rate is the loop's utilisation)
+        self.loop_idle_seconds = 0.0
         # request-level fault isolation (non-fatal engine-step failures)
         self.engine_step_faults = 0
         self.fault_recoveries = 0        # clean-tick recovery episodes
@@ -156,11 +163,17 @@ class ServingMetrics:
             self.tokens_generated += n
         self.token_rate.add(n)
 
-    def on_step(self, ahead: int = 0, rows_dropped: int = 0):
+    def on_step(self, ahead: int = 0, rows_dropped: int = 0,
+                starved: int = 0):
         with self._lock:
             self.engine_steps += 1
             self.ticks_dispatched_ahead += ahead
             self.rows_dropped += rows_dropped
+            self.ticks_device_starved += starved
+
+    def on_loop_idle(self, seconds: float):
+        with self._lock:
+            self.loop_idle_seconds += seconds
 
     def on_finish(self, req):
         """Fold a terminal request's latency samples in (any terminal state)."""
@@ -298,6 +311,8 @@ class ServingMetrics:
                 "engine_steps": self.engine_steps,
                 "ticks_dispatched_ahead": self.ticks_dispatched_ahead,
                 "rows_dropped": self.rows_dropped,
+                "ticks_device_starved": self.ticks_device_starved,
+                "loop_idle_seconds": self.loop_idle_seconds,
                 "engine_step_faults": self.engine_step_faults,
                 "fault_recoveries": self.fault_recoveries,
                 "recomputed_tokens": self.recomputed_tokens,
@@ -370,6 +385,7 @@ class ServingMetrics:
                     "requests_failed", "requests_quarantined",
                     "tokens_generated", "engine_steps",
                     "ticks_dispatched_ahead", "rows_dropped",
+                    "ticks_device_starved", "loop_idle_seconds",
                     "kv_drift_events",
                     "engine_step_faults", "fault_recoveries",
                     "recomputed_tokens", "degraded_latches",

@@ -95,6 +95,70 @@ _TICK_STAGES = ("admission", "prefill", "decode", "demote", "promote",
 _TICK_SPAN_NAMES = {"admission": "serve/admit", "demote": "serve/demote",
                     "promote": "serve/promote"}
 
+#: a stretch of the loop's no-work wait is stamped in ``serve/idle`` pieces
+#: of at most this long: a stretch still open when the ring is read is
+#: covered to within a piece (and a poll), and an idle server writes at most
+#: four events a second
+IDLE_PIECE_S = 0.25
+
+
+class _LoopIdle:
+    """The serve loop's no-work wait, told from a stalled loop: from the end
+    of the last thing the loop did (``busy_end``: a tick that stamped a
+    ``serve/tick``, or a fault's handling) to the start of the next such
+    tick, as ``serve/idle`` retro-spans on the loop's thread and, tracing on
+    or off, as ``ServingMetrics.loop_idle_seconds``. The ticks that found
+    nothing to do lie inside the stretch: they stamp nothing of their own.
+    Serve-loop-private."""
+
+    def __init__(self, metrics):
+        self.metrics = metrics
+        self.busy_end = time.monotonic()
+        self.since: Optional[float] = None   # the open stretch's next piece
+        self.polls = 0                       # wake-ups since the last piece
+        self.queued = self.inflight = 0      # at the last no-work tick
+        self.woke = "timeout"                # what ended the last wait
+
+    def saw(self, queued: int, inflight: int) -> None:
+        """What a tick that found nothing to do left waiting: requests
+        queued (admission refused at the watermark) or in flight (every
+        stream demoted) make it idle with work, none plain idle."""
+        self.queued, self.inflight = queued, inflight
+
+    def wait(self, wake: threading.Event, timeout: float) -> None:
+        """Block until a ``submit`` nudge or the poll's timeout, inside the
+        stretch (opened here if the last tick closed one)."""
+        now = time.monotonic()
+        if self.since is None:
+            self.since = min(self.busy_end, now)
+        self._stamp(now, whole=True)
+        woke = wake.wait(timeout=timeout)
+        wake.clear()
+        self.polls += 1
+        self.woke = "submit" if woke else "timeout"
+
+    def close(self, end: float) -> None:
+        """The stretch ends where a tick that does something starts."""
+        if self.since is not None:
+            self._stamp(end, whole=False)
+            self.since = None
+
+    def _stamp(self, upto: float, whole: bool) -> None:
+        t0 = self.since
+        while upto - t0 > IDLE_PIECE_S:
+            t0 = self._piece(t0, t0 + IDLE_PIECE_S)
+        if not whole and upto > t0:
+            t0 = self._piece(t0, upto)
+        self.since = t0
+
+    def _piece(self, t0: float, t1: float) -> float:
+        self.metrics.on_loop_idle(t1 - t0)
+        get_tracer().complete("serve/idle", t1 - t0, cat="serve", end_ts=t1,
+                              polls=self.polls, queued=self.queued,
+                              inflight=self.inflight, woke=self.woke)
+        self.polls = 0
+        return t1
+
 
 class BackpressureError(RuntimeError):
     """Admission rejected: queue full, projected KV occupancy over the
@@ -299,6 +363,10 @@ class InferenceServer:
         # serve/tick_stage_share counter track (/metrics + dstrace)
         self._tick_stage_cum = {s: 0.0 for s in _TICK_STAGES}
         self._tick_cum_s = 0.0
+        # the loop's no-work wait (``serve/idle``, ``loop_idle_seconds``)
+        # and where the tick under way began, for a tick that raises
+        self._idle = _LoopIdle(self.metrics)
+        self._tick_t0 = 0.0
         # fleet identity: set by the fleet launcher on replica workers
         # (-1 standalone); reported on /healthz so the router can key
         # affinity/retirement by replica, and matched by the chaos
@@ -659,29 +727,37 @@ class InferenceServer:
             logger.exception("serve loop: collecting the pending step failed")
 
     def _serve_ticks(self):
+        idle = self._idle
+        idle.busy_end = time.monotonic()
         while True:
             if self._stopped:
+                idle.close(time.monotonic())
                 return
             try:
                 worked = self._serve_once()
             except _EngineStepError as e:
                 self._on_step_fault(e)
-                worked = False
+                worked = None
             except Exception:
                 # non-engine bookkeeping glitch: requests are still healthy,
                 # log and keep serving
                 logger.exception("serve loop: non-fatal tick error")
-                worked = False
+                worked = None
+            if worked is None:
+                # what a tick did before it raised, and the handling, are
+                # no idling: the stretch closes where that tick began and
+                # the next opens here
+                idle.close(self._tick_t0)
+                idle.busy_end = time.monotonic()
             if not worked:
                 # nothing to do: block until a submit() nudge (bounded so
                 # deadline expiry of QUEUED requests is still noticed). No
                 # step is pending here: ``has_work`` counts one, and a step
                 # that dispatches nothing collects all
-                self._wake.wait(timeout=self.config.idle_poll_s * 10)
-                self._wake.clear()
+                idle.wait(self._wake, self.config.idle_poll_s * 10)
 
     def _serve_once(self) -> bool:
-        t_tick0 = time.monotonic()
+        self._tick_t0 = t_tick0 = time.monotonic()
         self._tick += 1
         marks: List[tuple] = []     # the tick's stage timeline (see _mark)
         if self.chaos is not None:
@@ -729,7 +805,8 @@ class InferenceServer:
             t0 = time.monotonic()
             counters = getattr(self.engine, "last_step_counters", None) or {}
             self.metrics.on_step(ahead=counters.get("ahead", 0),
-                                 rows_dropped=counters.get("rows_dropped", 0))
+                                 rows_dropped=counters.get("rows_dropped", 0),
+                                 starved=counters.get("starved", 0))
             self._note_clean_step(
                 getattr(self.engine, "last_collected_uids", None))
             worked = True
@@ -775,8 +852,12 @@ class InferenceServer:
         if worked or moved:
             # only ticks that did something land in the ring: an idle
             # server polling its queue must not flood the bounded trace
+            # (the stretch they lie in is stamped whole, ``_LoopIdle``)
+            self._idle.close(t_tick0)
             self._emit_tick_spans(marks, t_tick0, t0, worked, queued,
                                   inflight)
+        else:
+            self._idle.saw(queued, inflight)
         return worked
 
     def _mark(self, marks: list, stage: Optional[str], t0: float,
@@ -816,7 +897,8 @@ class InferenceServer:
                 tracer.complete(name, t1 - t0,
                                 cat="serve", end_ts=t1, tick=self._tick,
                                 **(args or {}))
-        self._tick_stage_gauges(stage_s, time.monotonic() - t_tick0, tracer)
+        t_end = time.monotonic()
+        self._tick_stage_gauges(stage_s, t_end - t_tick0, tracer)
         if tracer.enabled:
             t_end = time.monotonic()
             tracer.complete("serve/bookkeep", t_end - t_tail0, cat="serve",
@@ -824,6 +906,8 @@ class InferenceServer:
             tracer.complete("serve/tick", t_end - t_tick0, cat="serve",
                             end_ts=t_end, tick=self._tick, worked=worked,
                             queued=queued, inflight=inflight)
+        # a ``serve/idle`` that follows opens where this tick's window ends
+        self._idle.busy_end = t_end
 
     def _tick_stage_gauges(self, stage_s: dict, tick_s: float,
                            tracer) -> None:

@@ -46,6 +46,12 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "prefetch/next": ("span",),
     "prefetch/stage": ("span",),
     "xla/compile": ("instant",),
+    # one collection of the interpreter's, "start" to "stop", stamped by the
+    # tracer's own entry in ``gc.callbacks`` on a track of its own
+    # (``tracer.HOST_GC_TID``): `generation`, `collected`, the `thread` it
+    # ran on, and the youngest generation's collections shorter than 1 ms
+    # since the last one emitted (`gen0`, `gen0_s`)
+    "host/gc": ("complete",),
     # -- memory telemetry --------------------------------------------------
     "mem/oom": ("instant",),
     "mem/see_memory_usage": ("instant",),
@@ -96,9 +102,18 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/drain_fanout": ("complete",),
     "serve/drain_reap": ("complete",),
     "serve/bookkeep": ("complete",),
+    # the loop's no-work wait, from the end of the last thing it did to the
+    # start of the next tick that stamps a ``serve/tick``, in pieces of at
+    # most 0.25 s: `polls` (wake-ups in the piece), `queued` and `inflight`
+    # at the last of them, `woke` ("submit" or "timeout"). It lies outside
+    # every ``serve/tick`` and has no stage in SERVE_STAGE_OF
+    "serve/idle": ("complete",),
     # stamped by the engine once the tick's collection is over, so that
     # they can carry what it read: `ahead` (1: dispatched while the step
-    # before was still pending on the device, 0: nothing was), and on the
+    # before was still pending on the device, 0: nothing was), `starved`
+    # beside it where `ahead` is 1 (1: that pending step's tokens were
+    # already there when this one was dispatched, so the device had run dry
+    # for want of the host; 0: it was still running), and on the
     # tick's decode span, or its last chunk's where it decoded nothing,
     # `rows_dropped` (rows whose sequence had ended by the time their token
     # was read) beside the STEP_COUNTER_ARGS. Every span of a tick carries

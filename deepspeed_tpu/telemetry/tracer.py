@@ -24,6 +24,10 @@ Design constraints (all load-bearing):
   mirrored: there is no second switch. With tracing off ``span()`` returns
   the shared no-op and jax is never imported from here. Retro-emitted
   events (``complete``) have no live extent and stay on the ring alone.
+- **The interpreter's pauses are spans too.** While enabled through
+  ``configure`` (or ``DSTPU_TRACE``) the tracer holds one entry in
+  ``gc.callbacks`` and stamps every collection as ``host/gc`` on a track of
+  its own (``HOST_GC_TID``); switched off, the entry is removed.
 - **Lock-free emit.** ``deque.append`` and ``itertools.count.__next__`` are
   GIL-atomic; the only lock guards export/reconfiguration. Producers on the
   serve loop, prefetch worker, watchdog monitor, and main thread never
@@ -42,12 +46,14 @@ in the environment — tracing starts at first use and the trace is dumped to
 
 import atexit
 import collections
+import gc
 import itertools
 import json
 import os
 import socket
 import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from deepspeed_tpu.utils.logging import logger
@@ -74,6 +80,17 @@ REQUEST_TID_SPAN = 10_000_000
 #: the contract ``dstpu plan`` relies on: off-main-track spans attribute as
 #: overlapped work — the prefetch-worker treatment — never as step cost.
 COMM_OVERLAP_TID = 900_000
+
+#: synthetic track for the interpreter's collections (``host/gc``). A
+#: collection holds the interpreter lock whichever thread set it off (a
+#: caller's or a load generator's as much as the serve loop's), so it stalls
+#: every thread and belongs to none: one track, the thread it ran on an arg.
+HOST_GC_TID = 900_001
+
+#: a collection of the youngest generation shorter than this is counted
+#: (``gen0``, ``gen0_s`` on the next ``host/gc`` emitted) and not emitted: a
+#: busy process makes thousands a second, tens of microseconds each
+GC_GEN0_EMIT_S = 1e-3
 
 
 def request_tid(uid: int) -> int:
@@ -110,6 +127,24 @@ class _NoopSpan:
 
 
 _NOOP_SPAN = _NoopSpan()
+
+
+def _gc_hook(tracer_ref):
+    """The entry a tracer keeps in ``gc.callbacks`` while it is enabled. It
+    holds the tracer weakly: the list outlives every tracer, and a strong
+    reference from it would too."""
+    def on_gc(phase, info):
+        tracer = tracer_ref()
+        if tracer is not None:
+            tracer._on_gc(phase, info)
+    return on_gc
+
+
+def _drop_gc_hook(hook) -> None:
+    try:
+        gc.callbacks.remove(hook)
+    except ValueError:
+        pass
 
 #: step-level spans -> the arg that numbers them: these mirror into the
 #: profiler as a ``StepTraceAnnotation`` (``step_num``), which is what the
@@ -184,6 +219,14 @@ class Tracer:
         self._lock = threading.Lock()         # export/config only, never emit
         self._cleared = 0                     # events discarded by clear()
         self._sink: Optional[Callable[[str, int], None]] = None
+        # the interpreter's collections (``host/gc``): this tracer's entry in
+        # ``gc.callbacks`` (there while enabled through ``configure``), the
+        # start of the collection under way, and the short young collections
+        # counted since the last one emitted
+        self._gc_hook: Optional[Callable] = None
+        self._gc_t0 = 0.0
+        self._gc_gen0 = 0
+        self._gc_gen0_s = 0.0
         # process identity for cross-rank merge (``dstpu trace merge``):
         # rank/world default from env, re-stampable at rendezvous time
         try:
@@ -210,7 +253,50 @@ class Tracer:
                 new.extend(e for e in list(old) if e[_EID] > last)
             if enabled is not None:
                 self.enabled = bool(enabled)
+                self._watch_gc(self.enabled)
         return self
+
+    def _watch_gc(self, on: bool) -> None:
+        """Hold one entry in ``gc.callbacks`` while tracing is on, none
+        while it is off (the cost of ``host/gc`` with tracing off is nil)."""
+        if on == (self._gc_hook in gc.callbacks):
+            return
+        if not on:
+            _drop_gc_hook(self._gc_hook)
+            self._gc_t0, self._gc_gen0, self._gc_gen0_s = 0.0, 0, 0.0
+            return
+        if self._gc_hook is None:
+            self._gc_hook = _gc_hook(weakref.ref(self))
+            weakref.finalize(self, _drop_gc_hook, self._gc_hook)
+        gc.callbacks.append(self._gc_hook)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` entry: a ``host/gc`` span from a collection's
+        "start" to its "stop", on the ring alone (retro: the profiler's
+        annotation would land on whichever thread the collection ran on, and
+        its first use imports jax, here inside a collection). Collections do
+        not nest and run under the interpreter lock, so one start stamp
+        serves. Same contract as ``_emit``: a clock read and an append."""
+        now = time.monotonic()
+        if phase == "start":
+            self._gc_t0 = now
+            return
+        t0, generation = self._gc_t0, info.get("generation", -1)
+        if not t0:
+            return
+        self._gc_t0 = 0.0
+        dur = now - t0
+        if generation == 0 and dur < GC_GEN0_EMIT_S:
+            self._gc_gen0 += 1
+            self._gc_gen0_s += dur
+            return
+        gen0, gen0_s = self._gc_gen0, self._gc_gen0_s
+        self._gc_gen0, self._gc_gen0_s = 0, 0.0
+        self.complete("host/gc", dur, cat="host", end_ts=now,
+                      tid=HOST_GC_TID, generation=generation,
+                      collected=info.get("collected", 0),
+                      thread=threading.get_ident(), gen0=gen0,
+                      gen0_s=round(gen0_s, 6))
 
     @property
     def capacity(self) -> int:
@@ -372,6 +458,8 @@ class Tracer:
                     seen_tids[tid] = thread_names[tid]
                 elif tid == COMM_OVERLAP_TID:
                     seen_tids[tid] = "comm-overlap"
+                elif tid == HOST_GC_TID:
+                    seen_tids[tid] = "host-gc"
                 elif REQUEST_TID_BASE <= tid < REQUEST_TID_BASE + \
                         REQUEST_TID_SPAN:
                     seen_tids[tid] = f"request-{tid - REQUEST_TID_BASE}"
@@ -576,7 +664,7 @@ def get_tracer() -> Tracer:
             t = Tracer(capacity=cap)
             path = os.environ.get(TRACE_ENV)
             if path:
-                t.enabled = True
+                t.configure(enabled=True)
                 atexit.register(_dump_at_exit, t, path)
                 logger.info(f"dstrace: tracing enabled ({TRACE_ENV}); dump "
                             f"at exit -> {path}")

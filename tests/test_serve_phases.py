@@ -40,7 +40,10 @@ def tracing():
 
 
 def _spans(tracer):
-    return [e for e in tracer.events_snapshot() if e[3] == "X"]
+    """The ring's spans but the interpreter's collections (``host/gc``, a
+    track of their own), which land wherever one happens to run."""
+    return [e for e in tracer.events_snapshot()
+            if e[3] == "X" and e[1] != "host/gc"]
 
 
 def _inside(inner, outer, slack=1e-6):
@@ -153,9 +156,14 @@ def test_a_served_tick_shares_one_number_and_the_ledger_sums_as_before(
             "serve/drain_fanout", "serve/drain_reap", "serve/engine_step",
             *PHASES} <= emitted
     assert "serve/drain" not in emitted
-    # every span of the serve loop carries the number of the tick it lies in
+    # every span of the serve loop carries the number of the tick it lies
+    # in; the loop's no-work wait (``serve/idle``) lies between ticks
+    idles = [e for e in spans if e[1] == "serve/idle"]
+    assert idles and all(e[6] in loop for e in idles)
+    assert not any(_inside(i, t, slack=-1e-6) for i in idles
+                   for t in ticks.values())
     for e in spans:
-        if e[6] not in loop or e[1] == "serve/tick":
+        if e[6] not in loop or e[1] in ("serve/tick", "serve/idle"):
             continue
         assert e[7] and "tick" in e[7], e[1]
         assert _inside(e, ticks[e[7]["tick"]]), (e[1], e[7]["tick"])

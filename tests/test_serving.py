@@ -146,6 +146,13 @@ def test_timeout_and_cancel(model_and_params):
         assert timed.finish_reason == "timeout"
         assert len(timed.tokens) < 500
 
+        # its blocks count against admission until the loop has reaped it,
+        # which with a step in flight is a wait for that step later (the
+        # request is settled first): two budgets of 500 pass the watermark
+        deadline = time.monotonic() + 30
+        while server.metrics.snapshot()["requests_timed_out"] < 1 \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
         cancelled = server.submit([2, 7, 1, 8], max_new_tokens=500)
         it = cancelled.stream(timeout=60)
         first = next(it)                      # wait for decode to start

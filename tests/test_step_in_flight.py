@@ -574,3 +574,131 @@ def test_the_threaded_loop_stamps_the_same_spans(built, tracing):
     assert all(e.arg("rows_dropped") == 0 for e in decodes)
     bubbles = pe.decode_bubbles(pe.loop_thread(evs))
     assert bubbles and all(b >= 0 for b in bubbles)
+
+
+# --- (f) starved: had the device run dry when the next step was queued ---------
+
+class _Sampled:
+    """A pending step's sampled tokens behind a double that says what the
+    test tells it to when asked whether they are there yet."""
+
+    def __init__(self, array, ready):
+        self.array, self.ready = array, ready
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, *args, **kwargs):
+        return np.asarray(self.array)
+
+
+class _Mute:
+    """The same with no ``is_ready`` to ask."""
+
+    def __init__(self, array):
+        self.array = array
+
+    def __array__(self, *args, **kwargs):
+        return np.asarray(self.array)
+
+
+def _hold(rec, double):
+    """Put what the step sampled last behind ``double(array)``: its decode's
+    tokens, or its last chunk's where it decoded nothing."""
+    if rec.decode_sampled is not None:
+        rec.decode_sampled = double(rec.decode_sampled)
+    else:
+        seq, start, length, sampled = rec.chunks[-1]
+        rec.chunks[-1] = (seq, start, length, double(sampled))
+
+
+@pytest.mark.parametrize("case,double,want", [
+    ("finished", lambda a: _Sampled(a, True), 1),
+    ("running", lambda a: _Sampled(a, False), 0),
+    ("cannot_say", _Mute, None)])
+def test_starved_says_whether_the_pending_step_had_finished(
+        built, tracing, case, double, want):
+    cfg, params = built("dense")
+    prompt = _prompts((5,))[0]
+    alone = _engine(cfg, params).generate(prompt, max_new_tokens=8, uid=0)
+    tracing.clear()
+    t = Ticked(_engine(cfg, params))
+    req = t.server.submit(prompt, max_new_tokens=8)
+    t.tick()        # the prompt's chunk: nothing was pending before it
+    first = t.engine.last_step_counters
+    assert first["ahead"] == 0 and "starved" not in first
+    held = 4        # the first of them finds a chunk's token, the rest a decode's
+    for _ in range(held):
+        _hold(t.engine._pending[-1], double)
+        t.tick()
+        counters = t.engine.last_step_counters
+        assert counters["ahead"] == 1
+        assert counters.get("starved") == want
+    assert t.server.metrics.snapshot()["ticks_device_starved"] == \
+        (held if want == 1 else 0)
+    assert t.server.metrics.snapshot()["ticks_dispatched_ahead"] == held
+    assert t.run([req]) == [alone]      # asking changed no token
+    t.close()
+    from benchmarks.harness import program_events as pe
+    decodes = sorted((e for e in tracing.events_snapshot()
+                      if e[1] == pe.STEP_DECODE and (e[7] or {}).get("batch")),
+                     key=lambda e: e[4])
+    assert len(decodes) >= held
+    for e in decodes[:held]:
+        assert e[7]["ahead"] == 1 and e[7].get("starved") == want
+
+
+def test_a_real_array_that_has_been_waited_for_reads_starved(built):
+    """What the engine hands itself can be asked: ``jax.Array.is_ready``."""
+    cfg, params = built("dense")
+    t = Ticked(_engine(cfg, params))
+    req = t.server.submit(_prompts((5,))[0], max_new_tokens=6)
+    t.tick(2)
+    for _ in range(3):
+        rec = t.engine._pending[-1]
+        assert rec.decode_sampled.is_ready() in (True, False)
+        jax.block_until_ready(rec.decode_sampled)
+        t.tick()
+        assert t.engine.last_step_counters["starved"] == 1
+    assert t.server.metrics.snapshot()["ticks_device_starved"] >= 3
+    t.run([req])
+    t.close()
+
+
+def test_starved_is_absent_at_depth_0(built, tracing):
+    cfg, params = built("dense")
+    got, t = _served(_engine(cfg, params), _prompts(), BUDGETS, depth=0)
+    assert [len(g) for g in got] == list(BUDGETS)
+    spans = [e for e in tracing.events_snapshot()
+             if e[1] in ("serve/step_decode", "serve/prefill_chunk")]
+    assert spans and all(e[7]["ahead"] == 0 and "starved" not in e[7]
+                         for e in spans)
+    assert "starved" not in t.engine.last_step_counters
+    m = t.server.metrics.snapshot()
+    assert m["ticks_device_starved"] == 0 == m["ticks_dispatched_ahead"]
+
+
+def test_starved_rides_on_the_chunks_of_a_tick_that_decodes_nothing(
+        built, tracing):
+    """Two prompts of one chunk each, a tick apart: the second's tick
+    dispatches a chunk and a decode, a third prompt's a chunk alone."""
+    cfg, params = built("dense")
+    eng = _engine(cfg, params, scheduler=SchedulerConfig(
+        max_tokens_per_step=8, max_decode_batch=8, prefill_buckets=(8,)))
+    t = Ticked(eng)
+    req = t.server.submit(_prompts((20,))[0], max_new_tokens=2)
+    t.tick()                    # chunk 1 of 3: ends no prompt, samples nothing
+    assert "starved" not in eng.last_step_counters
+    t.tick()                    # chunk 2: the step before it sampled nothing
+    assert eng.last_step_counters["ahead"] == 1
+    assert "starved" not in eng.last_step_counters
+    t.tick()                    # chunk 3 ends the prompt and samples
+    _hold(eng._pending[-1], lambda a: _Sampled(a, True))
+    t.tick()                    # the decode finds chunk 3's token there
+    assert eng.last_step_counters["starved"] == 1
+    t.run([req])
+    t.close()
+    chunks = [e[7] for e in tracing.events_snapshot()
+              if e[1] == "serve/prefill_chunk"]
+    assert len(chunks) == 3 and all("starved" not in c for c in chunks)
+    assert [c["ahead"] for c in chunks] == [0, 1, 1]

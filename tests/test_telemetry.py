@@ -33,7 +33,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.models.simple import SimpleModel, random_batch
 from deepspeed_tpu.telemetry import get_tracer, request_tid
-from deepspeed_tpu.telemetry.tracer import Tracer
+from deepspeed_tpu.telemetry.tracer import HOST_GC_TID, Tracer
 
 pytestmark = pytest.mark.telemetry
 
@@ -577,7 +577,111 @@ def test_tracer_emit_is_thread_safe(tracing):
         t.start()
     for t in threads:
         t.join()
+    # the emitters' tuples set the interpreter collecting; its ``host/gc``
+    # spans share the ring and the ids
     snap = tracing.events_snapshot()
-    assert len(snap) == n_threads * per
+    assert sum(e[6] != HOST_GC_TID for e in snap) == n_threads * per
     ids = [e[0] for e in snap]
     assert len(set(ids)) == len(ids)
+
+
+# ---------------------------------------------------------------------------
+# host/gc: the interpreter's collections as spans on a track of their own
+# ---------------------------------------------------------------------------
+def _gc_spans(tracer):
+    return [e for e in tracer.events_snapshot() if e[1] == "host/gc"]
+
+
+def test_a_full_collection_is_one_host_gc_span_on_the_gc_track():
+    import gc
+    t = Tracer().configure(enabled=True)
+    t0 = time.monotonic()
+    gc.collect()
+    t1 = time.monotonic()
+    full = [e for e in _gc_spans(t) if e[7]["generation"] == 2]
+    assert len(full) == 1
+    (_, name, cat, ph, ts, dur, tid, args), = full
+    assert (cat, ph, tid) == ("host", "X", HOST_GC_TID)
+    assert t0 <= ts and ts + dur <= t1 and dur > 0
+    assert args["thread"] == threading.get_ident()
+    assert args["collected"] >= 0
+    assert set(args) == {"generation", "collected", "thread", "gen0",
+                         "gen0_s"}
+    # every host/gc event sits on that one track, whichever thread ran it
+    done = threading.Thread(target=gc.collect)
+    done.start()
+    done.join()
+    spans = _gc_spans(t)
+    assert {e[6] for e in spans} == {HOST_GC_TID}
+    assert {e[7]["thread"] for e in spans} >= {threading.get_ident(),
+                                               done.ident}
+    # the export labels the track
+    names = {e["args"]["name"] for e in t.to_chrome()["traceEvents"]
+             if e.get("ph") == "M" and e["name"] == "thread_name"}
+    assert "host-gc" in names
+    t.configure(enabled=False)
+
+
+def test_short_young_collections_are_counted_not_emitted():
+    import gc
+    t = Tracer().configure(enabled=True)
+    was = gc.get_threshold()
+    gc.collect()
+    t.clear()
+    try:
+        # no older generation joins in: every collection is the youngest's
+        gc.set_threshold(50, 1_000_000, 1_000_000)
+        keep = [[] for _ in range(5000)]
+    finally:
+        gc.set_threshold(*was)
+    young = [e for e in _gc_spans(t) if e[7]["generation"] == 0]
+    # some hundred ran (one every 50 lists); only one that took over a
+    # millisecond would have been emitted
+    assert all(e[5] >= 1e-3 for e in young)
+    assert len(young) < 10
+    gc.collect()
+    last = _gc_spans(t)[-1]
+    assert last[7]["generation"] == 2
+    counted = sum(e[7]["gen0"] for e in _gc_spans(t))
+    assert counted + len(young) >= 0.9 * (5000 // 50)
+    assert 0.0 < sum(e[7]["gen0_s"] for e in _gc_spans(t)) < 1.0
+    # what rode on one span is not counted again on the next
+    gc.collect()
+    assert _gc_spans(t)[-1][7]["gen0"] <= 1
+    assert len(keep) == 5000
+    t.configure(enabled=False)
+
+
+def test_the_gc_callback_is_there_only_while_tracing_is_on():
+    import gc
+    before = list(gc.callbacks)
+    t = Tracer()
+    assert gc.callbacks == before          # made, not enabled: nothing
+    t.configure(enabled=True)
+    t.configure(enabled=True)              # twice on: still one entry
+    assert len(gc.callbacks) == len(before) + 1
+    assert t._gc_hook in gc.callbacks
+    t.configure(enabled=False)
+    assert gc.callbacks == before
+    gc.collect()
+    assert _gc_spans(t) == []
+    # a tracer that goes away while on takes its entry with it
+    t.configure(enabled=True)
+    hook = t._gc_hook
+    del t
+    gc.collect()
+    assert hook not in gc.callbacks and gc.callbacks == before
+
+
+def test_the_process_tracer_watches_collections_while_enabled(tracing):
+    import gc
+    assert tracing._gc_hook in gc.callbacks
+    gc.collect()
+    assert any(e[7]["generation"] == 2 for e in _gc_spans(tracing))
+    # registered like any other name, as a retro event
+    from deepspeed_tpu.telemetry.names import SERVE_STAGE_OF, TRACE_NAMES
+    assert TRACE_NAMES["host/gc"] == ("complete",)
+    assert TRACE_NAMES["serve/idle"] == ("complete",)
+    assert "serve/idle" not in SERVE_STAGE_OF
+    tracing.configure(enabled=False)
+    assert tracing._gc_hook not in gc.callbacks
