@@ -705,15 +705,17 @@ class InferenceEngineV2:
             # what the scheduler decided, as plain host ints the step
             # already holds: the batch and the bucket it was padded to,
             # the context the paged kernel had to read (whole, and cut
-            # to the sliding window where the model has one) and the
-            # keys of the tiles it read them in
+            # to the sliding window where the model has one), the keys of
+            # the tiles it read them in and the slot copies a layer's call
+            # issued for them
             if tracer.enabled:
                 window, whole = self._window, sum(contexts)
                 d.decode_args = dict(
                     batch=len(seqs), bucket=b, ctx_tokens=whole,
                     ctx_tokens_windowed=sum(min(c, window) for c in contexts)
                     if window else whole, ctx_blocks=mb,
-                    **self.kv.decode_tile_keys(contexts, mb, window))
+                    **self.kv.decode_tile_keys(contexts, mb, window),
+                    **self.kv.decode_slot_copies(b, mb))
         return d
 
     def _device_ran_dry(self) -> Optional[int]:

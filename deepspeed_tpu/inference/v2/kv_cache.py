@@ -36,7 +36,8 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    decode_tile_keys, paged_attention_pool, paged_attention_reference)
+    decode_slot_copies, decode_tile_keys, paged_attention_pool,
+    paged_attention_reference)
 
 
 @dataclasses.dataclass
@@ -252,6 +253,26 @@ class BlockedKVCache:
         else:
             windowed = decode_tile_keys(contexts, table_blocks, bs, window)
         return {"tile_keys": whole, "tile_keys_windowed": windowed}
+
+    def decode_slot_copies(self, bucket: int, table_blocks: int) -> dict:
+        """Slot copies one layer's paged kernel call issues for a decode
+        batch padded to ``bucket`` over full tables of ``table_blocks``:
+        ``slot_copies`` a call of a layer that walks the full table (every
+        layer of a pool of one kind, behind a window too), and
+        ``slot_copies_windowed`` a call of a windowed kind's layer over its
+        own table. Empty over a latent pool."""
+        cfg = self.cfg
+        if cfg.latent_dim:
+            return {}
+
+        def copies(mb):
+            return decode_slot_copies(
+                bucket, cfg.num_kv_heads, mb, cfg.block_size, cfg.head_dim,
+                jnp.dtype(cfg.dtype).itemsize)
+        whole = copies(table_blocks)
+        return {"slot_copies": whole,
+                "slot_copies_windowed": copies(self.window_steady_blocks)
+                if self.two_kinds else whole}
 
     def pages_held(self) -> dict:
         """Blocks sequences hold now, by kind, and their bytes."""
@@ -591,8 +612,9 @@ class _Pages:
 class _HeadPages(_Pages):
     """K and V planes of head-major pages, ``[L, 2, H_kv, NB, bs, D]``: one
     page of one KV head is a contiguous (bs, D) tile, the shape the paged
-    kernel DMAs per grid step (``ops/pallas/paged_attention.py``); shard over
-    ``tensor`` on the heads. A block hands its ``attend`` ``(q, k, v,
+    kernel DMAs per grid step, a table entry's page of every head in one
+    strided copy for a decode fold (``ops/pallas/paged_attention.py``); shard
+    over ``tensor`` on the heads. A block hands its ``attend`` ``(q, k, v,
     window=, softcap=)``: q [N, H, D], k and v [N, H_kv, D], one row a token;
     ``window`` left out is the spec's."""
     block_axis = 3

@@ -9,45 +9,66 @@ and the kernel DMAs each sequence's KV pages *directly out of the paged pool in
 HBM* — the gather fallback's [B, MB*bs, H, d] context re-materialization (plus
 rep-times KV expansion for GQA) never exists.
 
-The grid is ``(batch, kv_head, row block, key tile)`` with the key tile
+The grid is ``(batch, KV-head block, row block, key tile)`` with the key tile
 innermost: online-softmax accumulators live in VMEM scratch and carry across
 tiles, flash-style. **A grid step reads ``P`` consecutive entries of the
 sequence's block table**: ``P`` K slots and ``P`` V slots, each a BlockSpec of
-one ``(block_size, d)`` page whose index map reads its own table entry, so the
-pages still come straight out of the pool. In the kernel the slots are joined
-into ONE ``[P * block_size, d]`` key tile and one value tile: one ``q k^T``,
-one softmax update and one ``p v`` a step. A page a step (what this kernel
-took until PR 36) costs the latency of those two small dependent products
-through the softmax's scratch, not its bytes: a decode fold of 8 rows over a
-260-block table ran at a sixteenth of its memory roofline. The table is padded
-to whole steps with block 0; a padded slot's positions lie above every
+one ``(block_size, d)`` page a KV head whose index map reads its own table
+entry, so the pages still come straight out of the pool. In the kernel the
+slots are joined into ONE ``[P * block_size, d]`` key tile and one value tile:
+one ``q k^T``, one softmax update and one ``p v`` a step. A page a step (what
+this kernel took until PR 36) costs the latency of those two small dependent
+products through the softmax's scratch, not its bytes: a decode fold of 8 rows
+over a 260-block table ran at a sixteenth of its memory roofline. The table is
+padded to whole steps with block 0; a padded slot's positions lie above every
 query's, so the causal mask hides it as it hides trash entries. Tiles
 entirely above a row block's causal horizon (or entirely below its sliding
 window) are predicated out with ``pl.when``; the mask itself is per position.
 
-GQA/T folding: the kernel processes one KV head per grid cell; the q rows for
-that cell are the (group × chunk) fold — ``rep`` query heads that share the KV
-head times ``T`` chunk tokens — zero-padded to a multiple of 8 sublanes. Decode
-is T=1; prefill is B=1, T=chunk. A fold too tall for the compiler's scoped VMEM
-(a 2048-token chunk of a 4-way group is 8192 rows) is cut into row blocks on a
-grid axis of their own.
+GQA/T folding: the q rows of one KV head are the (group × chunk) fold —
+``rep`` query heads that share the KV head times ``T`` chunk tokens —
+zero-padded to a multiple of 8 sublanes. Decode is T=1; prefill is B=1,
+T=chunk. A fold too tall for the compiler's scoped VMEM (a 2048-token chunk of
+a 4-way group is 8192 rows) is cut into row blocks on a grid axis of their own.
+
+**A short fold takes every KV head of a key tile in one grid step** (PR 40).
+The cache is head-major, so one table entry's page of ALL the layer's KV
+heads is one strided block ``(Hkv, 1, block_size, d)``: one operand, one copy,
+``Hkv`` times the bytes. The q and output blocks are ``(1, Hkv, rows, d)``, the
+scratch ``[Hkv, rows, ...]``, the products batched over the heads, and the
+grid's head axis has one step; a head's mathematics is what it was (the same
+tile, the same order of the softmax's sums: the outputs agree bit for bit on
+the chip). What it buys is the count of copies: with a head a step a decode
+fold paid 47-56 ns for each 16 KB slot copy whatever the table held, its
+index map, its read of the table in SMEM and its wait, 43% of the bandwidth
+at best, and a dead slot cost what a live one cost.
 
 **The tile follows the work** (``_tile``; static shapes alone choose, there is
 no option). The key tile is as wide as the table up to ``_MAX_PAGES`` = 8
 pages (512 keys), a power of two; the row block is the fold, or for a taller
 fold the largest power of two of rows that the scoped VMEM holds beside that
 key tile: 2,048 rows beside 8 pages (``_SCOPED_VMEM_BYTES`` says what is
-counted). Swept on a v5e (PERF.md section 6, PR 36): a decode fold of 8 rows
-over a 260-block table takes 11.8 ms a layer at one page a step, 6.3 at 8, 6.1
-at 16 and 6.4 at 32; a 4,096-token chunk of six heads a KV head 73.5 ms at one
-page, 15.0 at 2,048 rows x 8 pages and 12.7 at 1,024 x 16: each step pays a
-pass over the accumulator whatever its keys, so fewer, wider steps win. What
-holds the tile at 8 pages is the host: a slot is an operand of the call, and
-every operand costs a step program's first call (the served cells' `setup_s`).
+counted). A fold that is one row block takes all ``Hkv`` heads a step where
+that many folds and tiles fit the scoped VMEM by the same count (every decode
+fold: 8 heads x 8-16 rows beside 8 pages are 6.7 MiB; up to 192 rows), else
+one head, which is the kernel of PR 36 to the letter (every chunk of the
+served cells: 2,048 rows a head). Swept on a v5e (PERF.md section 6, PR 36):
+a decode fold of 8 rows over a 260-block table takes 11.8 ms a layer at one
+page a step, 6.3 at 8, 6.1 at 16 and 6.4 at 32; a 4,096-token chunk of six
+heads a KV head 73.5 ms at one page, 15.0 at 2,048 rows x 8 pages and 12.7 at
+1,024 x 16: each step pays a pass over the accumulator whatever its keys, so
+fewer, wider steps win. And (PR 40, trash-padded tables as the engine pads
+them, 1 / 2 / 4 / 8 heads a step): that decode fold 6.40 / 3.38 / 2.01 / 1.45
+ms (random tables: 6.39 at one head, 3.02 at eight, where the dead slots'
+2.2 GB are fetched: a trash-padded dead slot repeats its block index and is
+not copied again); 32 x 4 heads over 64 blocks 1.80 / 1.03 / 0.72 / 0.61;
+16 x 4 over 32 blocks 0.52 / 0.33 / 0.25 / 0.22; 32 x 9 over 9 blocks behind
+a window 0.65 / 0.39 / 0.28 / 0.26. What holds the tile at 8 pages is the
+host: a slot is an operand of the call, and every operand costs a step
+program's first call (the served cells' `setup_s`); heads a step add none.
 A row block cut down to a sliding window's 512 rows loses to 1,024 and 2,048
 (6.6 against 5.9 and 6.1 ms for Laguna's sliding chunk at 8 pages): a narrower
-block multiplies fewer masked pairs, but every row block walks the whole table
-and its dead steps still fetch.
+block multiplies fewer masked pairs, but every row block walks the whole table.
 
 The call sits under a ``jit`` of its own and what differs between the layers
 of a pool (where the layer's K and V heads start) is a prefetched scalar, not a
@@ -55,7 +76,8 @@ constant of the trace: a step program traces and lowers ONE kernel a layer kind
 and calls it once a layer.
 
 Cache layout is head-major ``[Hkv, num_blocks, block_size, d]`` so one page of
-one KV head is a contiguous ``(block_size, d)`` tile (legal TPU block shape).
+one KV head is a contiguous ``(block_size, d)`` tile (legal TPU block shape),
+and one table entry's page of every head ``Hkv`` such tiles a stride apart.
 """
 
 import functools
@@ -76,7 +98,8 @@ NEG_INF = -1e30
 # the key tile its K and V slots twice and once more joined; and every row of
 # every page a float32 score and a float32 probability. 2,048 rows x 16 pages
 # come to 22.5 MiB by that count and were refused at 22.2; 2,048 x 8 (13.8) and
-# 1,024 x 16 (12.0) compile.
+# 1,024 x 16 (12.0) compile. A step of several KV heads holds all of it a
+# head: 8 heads x 192 rows x 8 pages (15.9) compile (PR 40).
 _SCOPED_VMEM_BYTES = 16 * 2 ** 20
 # Tallest q fold one grid cell takes whatever the key tile, in rows x head_dim
 # elements: 4096 rows x d128 over one page still compile and 8192 rows are
@@ -98,18 +121,25 @@ def _tile_pages(mb: int) -> int:
     return 1 << (min(mb, _MAX_PAGES) - 1).bit_length()
 
 
-def _tile(g: int, mb: int, bs: int, d: int, itemsize: int):
-    """``(rows, pages)`` of one grid step for a fold of ``g`` rows over a
-    table of ``mb`` blocks: the key tile as wide as the table and
-    ``_MAX_PAGES`` allow, then the row block as tall as the scoped VMEM holds
-    beside it, a power of two so that it divides the chunk buckets."""
+def _tile(g: int, mb: int, bs: int, d: int, itemsize: int, hkv: int = 1):
+    """``(rows, pages, heads)`` of one grid step for a fold of ``g`` rows a
+    KV head, ``hkv`` of them, over a table of ``mb`` blocks: the key tile as
+    wide as the table and ``_MAX_PAGES`` allow, then the row block as tall as
+    the scoped VMEM holds beside it, a power of two so that it divides the
+    chunk buckets; then every KV head in the step where the fold is one row
+    block and ``hkv`` such blocks and their tiles fit the scoped VMEM by the
+    same count, else one."""
     pages = _tile_pages(mb)
     a_row = d * (4 * itemsize + 4) + 2 * 128 * 4 + 8 * pages * bs
-    room = (_SCOPED_VMEM_BYTES - 6 * pages * bs * d * itemsize) // a_row
+    a_tile = 6 * pages * bs * d * itemsize
+    room = (_SCOPED_VMEM_BYTES - a_tile) // a_row
     rows = min(1 << (room.bit_length() - 1),
                max(_MAX_FOLD_ELEMS // d // 16 * 16, 16))
-    # the fold itself where it is shorter: a sublane multiple
-    return min(-(-g // 8) * 8, rows), pages
+    fold = -(-g // 8) * 8                  # a sublane multiple
+    if fold > rows:
+        return rows, pages, 1
+    fits = hkv * (fold * a_row + a_tile) <= _SCOPED_VMEM_BYTES
+    return fold, pages, hkv if fits else 1
 
 
 def decode_tile_keys(contexts, mb: int, bs: int, window=None) -> int:
@@ -122,8 +152,21 @@ def decode_tile_keys(contexts, mb: int, bs: int, window=None) -> int:
                * tile for c in contexts)
 
 
-def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, window,
-                  softcap, num_blocks=0):
+def decode_slot_copies(batch: int, hkv: int, mb: int, bs: int, d: int,
+                       itemsize: int, group: int = 1) -> int:
+    """Slot copies one layer's decode call issues for ``batch`` rows of
+    ``group`` query heads a KV head over tables of ``mb`` blocks: a K and a V
+    slot a page of every step of the grid ``(batch, hkv // heads, 1,
+    steps)``, dead steps too (a copy costs what it costs whether its page is
+    live: what a decode call's seconds divide by). ``group`` decides only
+    whether ``hkv`` folds still fit a step: left out, a fold of one sublane
+    tile."""
+    _, pages, heads = _tile(group, mb, bs, d, itemsize, hkv)
+    return batch * (hkv // heads) * -(-mb // pages) * pages * 2
+
+
+def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, heads,
+                  window, softcap, num_blocks=0):
     tables_ref, start_ref, _ = refs[:3]
     refs = refs[3:]
     kscale_ref = vscale_ref = None
@@ -135,9 +178,14 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, window,
     o_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * pages:]
     tile = pages * block_size
     b = pl.program_id(0)
-    hi = pl.program_id(1)
+    hi = pl.program_id(1)                  # block of ``heads`` KV heads
     i = pl.program_id(2)                   # row block of the q fold
     j = pl.program_id(3)                   # key tile
+    # one KV head a step works on [rows, d] and [keys, d] as it always has;
+    # several carry them as the leading axis of every array and product
+    many = heads > 1
+    head = slice(None) if many else 0      # of a q, slot or output block
+    batch = ((0,), (0,)) if many else ((), ())
 
     @pl.when(j == 0)
     def _init():
@@ -155,41 +203,48 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, window,
         max_qpos = start + chunk - 1
 
     def _joined(slot_refs, scale_ref, dtype):
-        slots = [r[0, 0] for r in slot_refs]         # [bs, d] each
+        # [bs, d] each, or [heads, bs, d]: a table entry's page of every head
+        slots = [r[head, 0] for r in slot_refs]
         if scale_ref is not None:
-            # fp8 pages dequantize on load, slot by slot: each page's scale
-            # rides in SMEM next to the block table
+            # fp8 pages dequantize on load, slot by slot and head by head:
+            # each page's scale rides in SMEM next to the block table
             entry = (b * steps + j) * pages
-            slots = [s.astype(jnp.float32) * scale_ref[
-                hi * num_blocks + tables_ref[entry + p]]
-                for p, s in enumerate(slots)]
-        return jnp.concatenate([s.astype(dtype) for s in slots], axis=0)
+
+            def scaled(s, h, p):
+                return s.astype(jnp.float32) * scale_ref[
+                    (hi * heads + h) * num_blocks + tables_ref[entry + p]]
+            slots = [jnp.stack([scaled(s[h], h, p) for h in range(heads)])
+                     if many else scaled(s, 0, p)
+                     for p, s in enumerate(slots)]
+        return jnp.concatenate([s.astype(dtype) for s in slots], axis=-2)
 
     def _compute():
-        q = q_ref[0, 0]                    # [rows, d]
-        k = _joined(k_refs, kscale_ref, q.dtype)     # [P * bs, d]
+        q = q_ref[0, head]                           # [(heads,) rows, d]
+        k = _joined(k_refs, kscale_ref, q.dtype)     # [(heads,) P * bs, d]
         v = _joined(v_refs, vscale_ref, q.dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q, k, (((q.ndim - 1,), (k.ndim - 1,)), batch),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / np.sqrt(q.shape[-1]))
         if softcap:                        # gemma2 attn_logit_softcapping
             s = softcap * jnp.tanh(s / softcap)
         # row r of the fold is (q-head r // chunk, chunk token r % chunk)
-        row = i * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        row = i * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                  s.ndim - 2)
         qpos = start + row % chunk
-        kpos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   s.ndim - 1)
         mask = kpos <= qpos                # causal == context-length mask
         if window is not None:
             mask = jnp.logical_and(mask, kpos > qpos - window)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((p.ndim - 1,), (v.ndim - 2,)), batch),
             preferred_element_type=jnp.float32)
 
     live = j * tile <= max_qpos            # tile overlaps the causal horizon
@@ -199,8 +254,8 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, window,
 
     @pl.when(j == steps - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0, head] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
+                          ).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
@@ -250,16 +305,18 @@ def paged_attention_pool(q, pool, layer, block_tables, start_pos,
 def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
                 k_scales, v_scales, *, hkv: int, window, softcap,
                 interpret: bool):
-    """The kernel call: KV head ``hi`` of the grid reads row ``heads0[0] +
-    hi`` of ``k_pages`` and row ``heads0[1] + hi`` of ``v_pages``
-    ([X, NB, bs, d]). A function of its own under ``jit`` so that a step
-    program traces and lowers it once a layer kind and not once a layer."""
+    """The kernel call: step ``hi`` of the grid's head axis reads the
+    ``heads`` rows of ``k_pages`` from ``heads0[0] + hi * heads`` and those of
+    ``v_pages`` from ``heads0[1] + hi * heads`` ([X, NB, bs, d]; ``heads`` is
+    ``_tile``'s: ``hkv`` or 1). A function of its own under ``jit`` so that a
+    step program traces and lowers it once a layer kind and not once a
+    layer."""
     b, t, h, d = q.shape
     _, nb, bs, _ = k_pages.shape
     rep = h // hkv
     g = rep * t
     mb = block_tables.shape[1]
-    rows, pages = _tile(g, mb, bs, d, k_pages.dtype.itemsize)
+    rows, pages, heads = _tile(g, mb, bs, d, k_pages.dtype.itemsize, hkv)
     gp = -(-g // rows) * rows
     steps = -(-mb // pages)
     scaled = k_scales is not None
@@ -272,34 +329,41 @@ def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
                      ((0, 0), (0, steps * pages - mb)))
 
     def slot(kv, p):
-        # slot p of a step reads its own entry of the sequence's table
+        # slot p of a step reads its own entry of the sequence's table: that
+        # block of ``heads`` KV heads, one strided copy for all of them
         return pl.BlockSpec(
-            (1, 1, bs, d), lambda bi, hi, i, j, *pf:
+            (heads, 1, bs, d), lambda bi, hi, i, j, *pf:
             (pf[2][kv] + hi, pf[0][(bi * steps + j) * pages + p], 0, 0))
 
-    rows_spec = pl.BlockSpec((1, 1, rows, d), lambda bi, hi, i, j, *pf:
+    rows_spec = pl.BlockSpec((1, heads, rows, d), lambda bi, hi, i, j, *pf:
                              (bi, hi, i, 0))
+    lead = (heads,) if heads > 1 else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 if scaled else 3,
-        grid=(b, hkv, gp // rows, steps),
+        grid=(b, hkv // heads, gp // rows, steps),
         in_specs=[rows_spec] + [slot(kv, p) for kv in (0, 1)
                                 for p in range(pages)],
         out_specs=rows_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM(lead + (rows, 1), jnp.float32),
+            pltpu.VMEM(lead + (rows, 1), jnp.float32),
+            pltpu.VMEM(lead + (rows, d), jnp.float32),
         ],
     )
-    prefetch = [tables.reshape(-1), start_pos.astype(jnp.int32),
-                heads0.astype(jnp.int32)]
+    heads0 = heads0.astype(jnp.int32)
+    if heads > 1:
+        # in blocks of ``heads``: a layer's K and V heads start at a multiple
+        # of ``hkv``
+        heads0 = jax.lax.div(heads0, jnp.int32(heads))
+    prefetch = [tables.reshape(-1), start_pos.astype(jnp.int32), heads0]
     if scaled:
         prefetch += [k_scales.reshape(-1).astype(jnp.float32),
                      v_scales.reshape(-1).astype(jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
-                          steps=steps, chunk=t, rows=rows, window=window,
-                          softcap=softcap, num_blocks=nb if scaled else 0),
+                          steps=steps, chunk=t, rows=rows, heads=heads,
+                          window=window, softcap=softcap,
+                          num_blocks=nb if scaled else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         interpret=interpret,

@@ -583,6 +583,10 @@ def test_counts_ride_on_the_spans_that_wait(f32):
                            d["experts_touched"] == 4 * 3 and
                            d["expert_tile_rows"] >= d["expert_rows"]
                            for d in decodes)
+    # a latent pool is not the paged kernel's: no tiles, no slot copies
+    assert not any(k in d for d in decodes
+                   for k in ("tile_keys", "slot_copies",
+                             "slot_copies_windowed"))
     assert eng._pending_counts == []
 
 

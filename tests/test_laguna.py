@@ -438,6 +438,15 @@ def test_counts_of_held_and_absent_rows_ride_on_the_spans(half):
     # what a windowed layer's decode reads is cut to the window
     assert all(d["ctx_tokens_windowed"] == 24 < d["ctx_tokens"]
                for d in decodes)
+    # ... over a table of its own: fewer slot copies a call than a full
+    # layer's, and both the kernel's grid times its slots
+    kv = eng.kv
+    assert all(
+        {k: d[k] for k in ("slot_copies", "slot_copies_windowed")}
+        == kv.decode_slot_copies(d["bucket"], d["ctx_blocks"])
+        for d in decodes)
+    assert all(0 < d["slot_copies_windowed"] <= d["slot_copies"]
+               for d in decodes)
     pages = [e for e in events if e[1] == "serve/kv_pages"]
     assert pages and {"full_blocks", "window_blocks",
                       "window_blocks_given_back", "live_tokens",

@@ -213,53 +213,87 @@ def test_paged_attention_over_the_whole_pool(layer, pages):
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(sliced))
 
 
-# What a key tile of several pages can get wrong. Blocks of 16 and heads of
-# 32 unless said: a tile is 8 pages = 128 keys, or the table where that is
-# shorter. ``starts``: the queries' first positions, a batch row each;
-# ``tile``: the (rows, pages) the kernel's rule has to choose for the case to
-# test what it says.
+# What a key tile of several pages can get wrong. Blocks of 16, heads of 32
+# and two KV heads unless said: a tile is 8 pages = 128 keys, or the table
+# where that is shorter. ``starts``: the queries' first positions, a batch row
+# each; ``tile``: the (rows, pages, heads) the kernel's rule has to choose for
+# the case to test what it says: a fold that is one row block takes a table
+# entry's page of every KV head in one copy (PR 40), a fold cut into row
+# blocks one head.
 def _case(b, t, h, mb, starts, tile, window=None, softcap=None, fp8=False,
-          d=32):
+          d=32, hkv=2, trash=True):
     return dict(b=b, t=t, h=h, mb=mb, starts=starts, tile=tile,
-                window=window, softcap=softcap, fp8=fp8, d=d)
+                window=window, softcap=softcap, fp8=fp8, d=d, hkv=hkv,
+                trash=trash)
 
 
 _JOINED_TILE_CASES = {
     # 5 blocks: one step of 8 slots, three of them padding; a context that
     # ends inside the tile, one of a single token, one that fills the table
-    "table-5-of-8": _case(3, 1, 8, 5, [37, 0, 79], (8, 8)),
+    "table-5-of-8": _case(3, 1, 8, 5, [37, 0, 79], (8, 8, 2)),
     # 12 blocks: two steps, half of the second padding; a context that ends
     # on the tile's edge, one a key past it
-    "table-12-of-16": _case(4, 1, 8, 12, [127, 128, 0, 191], (8, 8)),
+    "table-12-of-16": _case(4, 1, 8, 12, [127, 128, 0, 191], (8, 8, 2)),
     # 20 blocks: three steps; contexts that end on the second tile's edge,
     # one key past it, and in the table's last page
-    "table-20-of-24": _case(4, 1, 8, 20, [255, 256, 0, 319], (8, 8)),
+    "table-20-of-24": _case(4, 1, 8, 20, [255, 256, 0, 319], (8, 8, 2)),
     # 6 and 9 query heads a KV head: folds of 8 and 16 rows
-    "rep-6": _case(3, 1, 12, 20, [300, 3, 100], (8, 8)),
-    "rep-9": _case(3, 1, 18, 20, [300, 3, 100], (16, 8), window=40),
+    "rep-6": _case(3, 1, 12, 20, [300, 3, 100], (8, 8, 2)),
+    "rep-9": _case(3, 1, 18, 20, [300, 3, 100], (16, 8, 2), window=40),
     # a window that spans a tile's edge (keys 201-300 over tiles of 128),
     # one wholly inside a tile, one that ends on an edge
-    "window-over-tile-edge": _case(3, 1, 8, 24, [300, 380, 256], (8, 8),
+    "window-over-tile-edge": _case(3, 1, 8, 24, [300, 380, 256], (8, 8, 2),
                                    window=100),
     # a chunk that starts inside a tile and ends inside the next
-    "chunk-over-tiles": _case(1, 72, 4, 20, [230], (144, 8)),
-    "chunk-window-over-tiles": _case(1, 72, 4, 20, [230], (144, 8),
+    "chunk-over-tiles": _case(1, 72, 4, 20, [230], (144, 8, 2)),
+    "chunk-window-over-tiles": _case(1, 72, 4, 20, [230], (144, 8, 2),
                                      window=50),
     # a fold cut into row blocks (heads of 128: 2,048 rows a block, one q
     # head's whole chunk each) behind a window narrower than a block
-    "chunk-cut-window-under-rows": _case(1, 2048, 4, 132, [50], (2048, 8),
+    "chunk-cut-window-under-rows": _case(1, 2048, 4, 132, [50], (2048, 8, 1),
                                          window=300, d=128),
     # ... and cut into blocks that span heads, from position 0
-    "chunk-cut-over-heads": _case(1, 512, 16, 32, [0], (2048, 8),
+    "chunk-cut-over-heads": _case(1, 512, 16, 32, [0], (2048, 8, 1),
                                   window=200, d=128),
     # fp8 pages: every slot of a tile under its own (head, page) scale
-    "fp8-scales-a-slot": _case(3, 1, 8, 20, [300, 0, 319], (8, 8),
+    "fp8-scales-a-slot": _case(3, 1, 8, 20, [300, 0, 319], (8, 8, 2),
                                fp8=True),
-    "fp8-chunk-window": _case(1, 72, 4, 20, [230], (144, 8), window=50,
+    "fp8-chunk-window": _case(1, 72, 4, 20, [230], (144, 8, 2), window=50,
                               fp8=True),
-    "softcap": _case(3, 1, 8, 20, [300, 0, 319], (8, 8), softcap=20.0),
-    "softcap-chunk-window": _case(1, 72, 4, 20, [230], (144, 8), window=50,
-                                  softcap=20.0),
+    "softcap": _case(3, 1, 8, 20, [300, 0, 319], (8, 8, 2), softcap=20.0),
+    "softcap-chunk-window": _case(1, 72, 4, 20, [230], (144, 8, 2),
+                                  window=50, softcap=20.0),
+    # eight KV heads a step (the served models'), at the groups of the cells:
+    # 4 (Mistral, Mixtral), 6 and 9 (Laguna's full and sliding layers)
+    "heads-8-group-4": _case(3, 1, 32, 20, [300, 0, 319], (8, 8, 8), hkv=8),
+    "heads-8-group-6": _case(3, 1, 48, 20, [255, 256, 100], (8, 8, 8), hkv=8),
+    "heads-8-group-9-window": _case(3, 1, 72, 9, [130, 40, 143], (16, 8, 8),
+                                    hkv=8, window=40),
+    "heads-8-softcap-window": _case(2, 1, 32, 12, [190, 77], (8, 8, 8),
+                                    hkv=8, window=100, softcap=20.0),
+    # every (head, page) of a slot of eight heads under its own scale
+    "heads-8-fp8-scales": _case(3, 1, 32, 20, [300, 0, 319], (8, 8, 8),
+                                hkv=8, fp8=True),
+    "heads-8-fp8-window-group-6": _case(2, 1, 48, 12, [191, 64], (8, 8, 8),
+                                        hkv=8, fp8=True, window=70),
+    # a table that is no whole number of tiles, and a table of random blocks
+    # where the cells' are trash-padded: a dead slot's page is another page
+    "heads-8-table-5-of-8": _case(3, 1, 32, 5, [37, 0, 79], (8, 8, 8), hkv=8),
+    "heads-8-random-table": _case(3, 1, 32, 20, [300, 0, 319], (8, 8, 8),
+                                  hkv=8, trash=False),
+    # four heads, and the one head that is the kernel as it was
+    "heads-4": _case(3, 1, 16, 12, [127, 128, 5], (8, 8, 4), hkv=4),
+    "heads-1": _case(3, 1, 4, 12, [127, 128, 5], (8, 8, 1), hkv=1),
+    "heads-1-window-fp8": _case(3, 1, 6, 12, [127, 128, 5], (8, 8, 1), hkv=1,
+                                window=50, fp8=True),
+    # a short chunk of eight KV heads is one row block too: its rows' tokens
+    # are reckoned by the same remainder under every head
+    "heads-8-short-chunk-window": _case(1, 24, 16, 20, [270], (48, 8, 8),
+                                        hkv=8, window=50),
+    # a chunk cut into row blocks still takes one KV head a step, whatever
+    # the heads: the chunk programs of the cells are the parent's
+    "chunk-cut-of-8-heads-takes-one": _case(1, 1024, 32, 80, [200],
+                                            (2048, 8, 1), hkv=8, d=128),
 }
 
 
@@ -269,12 +303,15 @@ def test_paged_attention_joined_key_tile(name):
     the gather reference where the table is no whole number of tiles, where
     contexts and windows end inside a tile, at folds of 8 and 16 rows, where
     the fold is cut into row blocks taller than the window, with fp8 slots
-    under different scales, with softcap."""
+    under different scales, with softcap; and where a step takes a table
+    entry's page of every KV head at once (8, 4 and 2 heads, groups of 4, 6
+    and 9, each head's page under its own scale), while a fold cut into row
+    blocks takes one."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     c = _JOINED_TILE_CASES[name]
-    b, t, h, mb, d = c["b"], c["t"], c["h"], c["mb"], c["d"]
-    hkv, nb, bs = 2, 192, 16
-    assert pa._tile(h // hkv * t, mb, bs, d, 1 if c["fp8"] else 4) \
+    b, t, h, mb, d, hkv = c["b"], c["t"], c["h"], c["mb"], c["d"], c["hkv"]
+    nb, bs = 192, 16
+    assert pa._tile(h // hkv * t, mb, bs, d, 1 if c["fp8"] else 4, hkv) \
         == c["tile"]
     rng = np.random.default_rng(sorted(_JOINED_TILE_CASES).index(name))
     kp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
@@ -288,7 +325,8 @@ def test_paged_attention_joined_key_tile(name):
                                           jnp.float32)}
     q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
     # every row's blocks its own; what lies past a context is the trash block
-    tables = np.full((b, mb), nb - 1, np.int32)
+    tables = np.full((b, mb), nb - 1, np.int32) if c["trash"] \
+        else rng.integers(0, nb, (b, mb)).astype(np.int32)
     free = rng.permutation(nb - 1)
     for r, first in enumerate(c["starts"]):
         n = -(-(first + t) // bs)
@@ -304,28 +342,48 @@ def test_paged_attention_joined_key_tile(name):
 
 
 def test_paged_tile_follows_the_fold_and_the_table():
-    """``_tile`` at the served shapes (blocks of 64, heads of 128, bfloat16):
-    the key tile is 8 pages or the table, a decode fold is its own rows, a
-    tall fold is cut where the scoped VMEM ends beside the tile (2,048 rows
-    beside 8 pages; 1,024 beside the 16 that were swept), by powers of two."""
+    """``_tile`` at the served shapes (blocks of 64, heads of 128, bfloat16,
+    eight KV heads): the key tile is 8 pages or the table, a decode fold is
+    its own rows, a tall fold is cut where the scoped VMEM ends beside the
+    tile (2,048 rows beside 8 pages; 1,024 beside the 16 that were swept), by
+    powers of two; a fold that is one row block takes every KV head a step
+    where that many fit by the same count (every decode fold of the cells),
+    a fold cut into row blocks one (every chunk of the cells)."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
-    shape = (64, 128, 2)
+    shape = (64, 128, 2, 8)
     assert pa._MAX_PAGES == 8
-    assert pa._tile(6, 260, *shape) == (8, 8)           # Laguna, full decode
-    assert pa._tile(9, 9, *shape) == (16, 8)            # ... sliding decode
-    assert pa._tile(6 * 4096, 260, *shape) == (2048, 8)
-    assert pa._tile(9 * 4096, 73, *shape) == (2048, 8)
-    assert pa._tile(4 * 2048, 64, *shape) == (2048, 8)  # Mixtral's chunk
-    assert pa._tile(4, 4, *shape) == (8, 4)      # a table shorter than a tile
-    assert pa._tile(4, 5, *shape) == (8, 8)
-    assert pa._tile(4 * 512, 16, 64, 128, 1) == (2048, 8)        # fp8 pages
-    assert pa._tile(8 * 1024, 32, 64, 256, 2) == (1024, 8)       # heads of 256
+    assert pa._tile(6, 260, *shape) == (8, 8, 8)        # Laguna, full decode
+    assert pa._tile(9, 9, *shape) == (16, 8, 8)         # ... sliding decode
+    assert pa._tile(4, 64, *shape) == (8, 8, 8)         # Mixtral's decode
+    assert pa._tile(4, 32, *shape) == (8, 8, 8)         # chat's decode
+    assert pa._tile(4, 64, 64, 128, 1, 8) == (8, 8, 8)  # ... over fp8 pages
+    assert pa._tile(6 * 4096, 260, *shape) == (2048, 8, 1)
+    assert pa._tile(9 * 4096, 73, *shape) == (2048, 8, 1)
+    assert pa._tile(4 * 2048, 64, *shape) == (2048, 8, 1)   # Mixtral's chunk
+    # chat's chunks: one row block of 2,048 rows, and eight do not fit
+    assert pa._tile(4 * 512, 16, *shape) == (2048, 8, 1)
+    assert pa._tile(4 * 512, 8, *shape) == (2048, 8, 1)
+    assert pa._tile(4, 4, *shape) == (8, 4, 8)   # a table shorter than a tile
+    assert pa._tile(4, 5, *shape) == (8, 8, 8)
+    assert pa._tile(4 * 512, 16, 64, 128, 1) == (2048, 8, 1)     # fp8 pages
+    assert pa._tile(8 * 1024, 32, 64, 256, 2) == (1024, 8, 1)    # heads of 256
+    # the heads a step takes are all of them or one, and one KV head is the
+    # kernel as it was; a fold of one row block too tall for eight takes one
+    assert pa._tile(4, 64, 64, 128, 2) == (8, 8, 1)
+    assert pa._tile(4, 64, 64, 128, 2, 2) == (8, 8, 2)
+    assert pa._tile(192, 64, *shape) == (192, 8, 8)
+    assert pa._tile(200, 64, *shape) == (200, 8, 1)
+
     # the module's count of the scoped VMEM: what compiled and what did not
-    def counted(rows, pages):
-        return rows * (128 * 12 + 1024) + 6 * pages * 64 * 128 * 2 \
-            + 8 * rows * pages * 64
+    def counted(rows, pages, heads=1):
+        return heads * (rows * (128 * 12 + 1024) + 6 * pages * 64 * 128 * 2
+                        + 8 * rows * pages * 64)
     assert counted(2048, 8) <= pa._SCOPED_VMEM_BYTES < counted(2048, 16)
     assert counted(1024, 16) <= pa._SCOPED_VMEM_BYTES
+    assert counted(192, 8, 8) <= pa._SCOPED_VMEM_BYTES < counted(200, 8, 8)
+    # what the decode call's seconds divide by: code-mixed's full layer
+    assert pa.decode_slot_copies(32, 8, 260, 64, 128, 2, group=6) == 16896
+    assert pa.decode_slot_copies(32, 1, 260, 64, 128, 2) * 8 == 135168
 
 
 def test_quantized_psum_scatter(mesh_dp8):

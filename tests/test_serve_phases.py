@@ -213,6 +213,64 @@ def test_step_decode_counts_the_keys_of_the_tiles_the_kernel_read(
         <= args["tile_keys"]
 
 
+def test_step_decode_counts_the_slot_copies_of_a_layers_call(params, tracing):
+    """``slot_copies`` beside ``tile_keys``: the K and V page copies one
+    layer's paged kernel call issued for the tick's decode batch, the
+    kernel's own grid times its slots (dead steps and padding rows too: a
+    copy costs what it costs). One kind of page: a windowed layer walks the
+    same table, so both counts are one."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    cfg, p = params
+    eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32,
+                                                   kv_block_size=4))
+    eng.put([1, 2, 3], [[1] * 5, [2] * 12, [3] * 20])
+    args = _decode_args(tracing, eng)
+    kv = eng.kv.cfg
+    _, pages, heads = pa._tile(
+        cfg.num_heads // kv.num_kv_heads, args["ctx_blocks"], kv.block_size,
+        kv.head_dim, jax.numpy.dtype(kv.dtype).itemsize, kv.num_kv_heads)
+    assert heads == kv.num_kv_heads > 1
+    grid = (args["bucket"], kv.num_kv_heads // heads, 1,
+            -(-args["ctx_blocks"] // pages))
+    assert args["slot_copies"] == grid[0] * grid[1] * grid[2] * grid[3] \
+        * pages * 2
+    assert args["slot_copies_windowed"] == args["slot_copies"]
+    assert args["slot_copies"] * kv.block_size >= 2 * args["tile_keys"]
+
+
+@pytest.mark.parametrize("case,kv_heads,windows,bucket,blocks,want", [
+    # code-mixed's decode: 32 rows over 260 blocks of the full kind, 33 steps
+    # of 8 slots for all eight heads at once; 9 blocks of the windowed kind
+    # are two steps
+    ("two kinds", 8, (None, 512), 32, 260, (16896, 32 * 2 * 8 * 2)),
+    # batch-rag's and chat's: one kind, every layer walks the full table
+    ("one kind", 8, (), 32, 64, (4096, 4096)),
+    ("one kind, a full bucket of chat's", 8, (), 16, 32, (1024, 1024)),
+    # one KV head is a copy a head a page, as every fold was before PR 40
+    ("one head", 1, (), 32, 260, (16896, 16896)),
+    ("a table shorter than a tile", 2, (), 4, 3, (4 * 4 * 2,) * 2),
+])
+def test_decode_slot_copies_by_hand(case, kv_heads, windows, bucket, blocks,
+                                    want):
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     KVCacheConfig)
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=2, num_kv_heads=kv_heads, head_dim=128, block_size=64,
+        num_blocks=4, layer_windows=windows,
+        window_blocks=4 if windows else 0))
+    got = kv.decode_slot_copies(bucket, blocks)
+    assert (got["slot_copies"], got["slot_copies_windowed"]) == want
+
+
+def test_decode_slot_copies_are_absent_over_a_latent_pool():
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     KVCacheConfig)
+    latent = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=1,
+                                          head_dim=64, latent_dim=48,
+                                          num_blocks=4))
+    assert latent.decode_slot_copies(8, 4) == {}
+
+
 @pytest.mark.parametrize("case,contexts,window,want", [
     # blocks of 16 over a 32-block table: a tile is 8 pages = 128 keys
     ("whole tiles", [128, 256, 1280], None, (1664, 1664)),

@@ -130,20 +130,27 @@ def test_layers_of_one_kind_share_one_paged_kernel_body(one_chip):
 
 
 # The tallest folds and longest tables the cells hand the kernel (blocks of
-# 64, heads of 128, bfloat16): batch, chunk tokens, query heads, table
-# blocks, window; then the (rows, pages) of a grid step.
+# 64, heads of 128, bfloat16, 8 KV heads): batch, chunk tokens, query heads,
+# table blocks, window; then the (rows, pages, heads) of a grid step: a decode
+# fold takes a table entry's page of all eight KV heads in one copy (PR 40),
+# a chunk's fold one head.
 _TALLEST_FOLDS = {
-    "laguna-full-chunk": (1, 4096, 48, 260, None, (2048, 8)),
-    "laguna-sliding-chunk": (1, 4096, 72, 73, 512, (2048, 8)),
-    "laguna-full-decode-32": (32, 1, 48, 260, None, (8, 8)),
-    "laguna-sliding-decode-32": (32, 1, 72, 9, 512, (16, 8)),
-    "mixtral-chunk": (1, 2048, 32, 64, None, (2048, 8)),
-    "mixtral-decode-32": (32, 1, 32, 64, None, (8, 8)),
+    "laguna-full-chunk": (1, 4096, 48, 260, None, (2048, 8, 1)),
+    "laguna-sliding-chunk": (1, 4096, 72, 73, 512, (2048, 8, 1)),
+    "laguna-full-decode-32": (32, 1, 48, 260, None, (8, 8, 8)),
+    "laguna-sliding-decode-32": (32, 1, 72, 9, 512, (16, 8, 8)),
+    "mixtral-chunk": (1, 2048, 32, 64, None, (2048, 8, 1)),
+    "mixtral-decode-32": (32, 1, 32, 64, None, (8, 8, 8)),
     # a row block that spans the heads of a group (the masks then reckon a
     # row's token by a remainder), behind a window
-    "mistral-chunk-over-heads": (1, 512, 32, 16, 4096, (2048, 8)),
-    "mistral-first-chunk-2048-rows": (1, 512, 32, 8, 4096, (2048, 8)),
-    "mistral-decode-64-fp8": (64, 1, 32, 64, 4096, (8, 8)),
+    "mistral-chunk-over-heads": (1, 512, 32, 16, 4096, (2048, 8, 1)),
+    "mistral-first-chunk-2048-rows": (1, 512, 32, 8, 4096, (2048, 8, 1)),
+    "mistral-decode-64-fp8": (64, 1, 32, 64, 4096, (8, 8, 8)),
+    "mistral-decode-16": (16, 1, 32, 32, 4096, (8, 8, 8)),
+    # the tallest fold of one row block that takes all eight heads by the
+    # module's count, behind a window; and over fp8 pages
+    "short-chunk-8-heads-192-rows": (1, 48, 32, 16, 512, (192, 8, 8)),
+    "short-chunk-8-heads-272-rows-fp8": (1, 68, 32, 16, 512, (272, 8, 8)),
 }
 
 
@@ -153,13 +160,16 @@ def test_paged_kernel_fits_the_scoped_vmem_at_the_tallest_folds(one_chip,
     """The tile ``_tile`` chooses compiles for the v5e inside the default
     16 MiB of scoped VMEM (the kernel sets no compiler parameter): 2,048 rows
     beside 8 pages, a 260-block table, decode buckets of 32 and 64, fp8 pages
-    dequantized a slot at a time."""
+    dequantized a slot at a time; and a decode fold with all eight KV heads a
+    step (one strided copy a table entry, the products batched over the
+    heads) at Laguna's full and sliding shapes, Mixtral's and chat's, up to
+    the tallest fold the rule gives eight heads."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     b, t, h, mb, window, tile = _TALLEST_FOLDS[fold]
     fp8 = fold.endswith("fp8")
     dtype = jnp.float8_e4m3fn if fp8 else jnp.bfloat16
     assert pa._tile(h // 8 * t, mb, BLOCK, 128,
-                    jnp.dtype(dtype).itemsize) == tile
+                    jnp.dtype(dtype).itemsize, 8) == tile
 
     def on_chip(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
