@@ -260,10 +260,11 @@ class InferenceEngineV2:
         trash block. ``can_schedule`` admits no more than that, so a larger
         pool would never fill."""
         from deepspeed_tpu.inference.v2.kv_cache import (mixes_layer_kinds,
+                                                         one_window,
                                                          windowed_table_blocks)
         if not mixes_layer_kinds(spec.layer_windows):
             return 0
-        window, = set(spec.layer_windows) - {None}
+        window = one_window(spec.layer_windows)
         bs, sched = self.config.kv_block_size, self.config.scheduler
         return (sched.max_decode_batch - 1) \
             * windowed_table_blocks(1, window, bs) \
@@ -829,7 +830,9 @@ class InferenceEngineV2:
         """The pool after a step, as host ints: blocks sequences hold by
         kind of page (``kv_full_blocks``; over pages by layer kind
         ``kv_window_blocks`` and, running, ``kv_window_blocks_given_back``),
-        their bytes, and the tokens whose keys and values they hold."""
+        their bytes (``kv_held_bytes``, and by kind ``kv_full_bytes`` and
+        ``kv_window_bytes``, each from a block of the kind's own pool), and
+        the tokens whose keys and values they hold."""
         held = self.kv.pages_held()
         return {"kv_live_tokens": sum(s.seen_tokens for s in self.state.all()
                                       if not s.paused),

@@ -10,7 +10,8 @@ in (``page_kind``): head-major K and V planes (``_HeadPages``), the same in
 fp8 under per-(head, page) scales (``_ScaledHeadPages``), one headless
 latent plane (``_LatentPages``), head pages held by LAYER kind
 (``_LayerKindPages``: a pool of the full layers' pages and a pool of the
-windowed layers', each with its block tables, for a model that mixes the
+windowed layers', each with its block tables and, where the model says so,
+its own KV head count and key and value widths, for a model that mixes the
 two). A kind owns, and nothing outside this
 module knows: the pool's shape and block axis, the trash block, whether the
 step programs carry an array or ``(pages, scales)``, the slots a step's rows
@@ -40,8 +41,26 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_attention_reference)
 
 
+@dataclasses.dataclass(frozen=True)
+class HeadPageShape:
+    """What a kind of head pages holds of one token in one layer:
+    ``num_kv_heads`` keys of ``key_dim`` and as many values of
+    ``value_dim``."""
+    num_kv_heads: int
+    key_dim: int
+    value_dim: int
+
+    @property
+    def square(self) -> bool:
+        """Keys and values of one width: K and V planes of one array."""
+        return self.key_dim == self.value_dim
+
+
 @dataclasses.dataclass
 class KVCacheConfig:
+    """What a page holds is stated once: a head page ``num_kv_heads`` keys
+    and values of ``head_dim`` a token a layer, unless ``kind_pages`` states
+    each layer kind's own; a latent page one row of ``latent_dim``."""
     num_layers: int
     num_kv_heads: int
     head_dim: int
@@ -49,8 +68,7 @@ class KVCacheConfig:
     num_blocks: int = 256
     dtype: any = jnp.bfloat16
     # a latent (MLA) page kind: one plane of ``latent_dim`` values a token
-    # with no heads (``num_kv_heads`` and ``head_dim`` then say nothing), its
-    # rows padded with zero lanes to ``latent_row_width``
+    # with no heads, its rows padded with zero lanes to ``latent_row_width``
     latent_dim: int = 0
     # pages by layer kind: every layer's window (None: a full layer), given
     # where a model mixes full and windowed layers; ``window_blocks`` is the
@@ -58,10 +76,21 @@ class KVCacheConfig:
     # layers' pages are of one kind in one pool
     layer_windows: Tuple[Optional[int], ...] = ()
     window_blocks: int = 0
+    # {"full": HeadPageShape, "window": HeadPageShape} where the two kinds
+    # of layer differ in KV heads, or keys and values in width; None: both
+    # kinds hold ``num_kv_heads`` x ``head_dim`` keys and values
+    kind_pages: Optional[dict] = None
+
+    @property
+    def page_shape(self) -> HeadPageShape:
+        """What a head page holds where no kind states its own."""
+        return HeadPageShape(self.num_kv_heads, self.head_dim, self.head_dim)
 
 
 def latent_row_width(latent_dim: int) -> int:
-    """A latent page's row as the pool stores it: ``latent_dim`` values and
+    """A page's row as a pool stores it where its width need not be whole
+    lane tiles (a latent row; a key or a value of a width of its own):
+    ``latent_dim`` values and
     zero lanes up to the next multiple of the TPU's 128. A last axis that is
     no multiple of 128 gets a device layout with the block index minor, and
     the kernel, which needs the row minor, then has the whole pool copied in
@@ -78,6 +107,16 @@ class TwoPageKindsError(NotImplementedError):
     """Something that moves block ids of one pool (prefix reuse, the host
     offload tier, the prefix handoff, fp8 scaled pages) was asked of a cache
     that keeps pages of two kinds."""
+
+
+def one_window(layer_windows) -> Optional[int]:
+    """The window of a model's windowed layers (None where it has none); a
+    ``ValueError`` that says so where they have more than one size."""
+    sizes = set(layer_windows or ()) - {None}
+    if len(sizes) > 1:
+        raise ValueError(f"more than one window size {sorted(sizes)}: the "
+                         f"cache keeps one windowed kind of page")
+    return next(iter(sizes), None)
 
 
 def windowed_table_blocks(rows: int, window: int, block_size: int) -> int:
@@ -101,7 +140,7 @@ class BlockedKVCache:
         self.cfg = cfg
         scaled = cfg.dtype == jnp.float8_e4m3fn
         if mixes_layer_kinds(cfg.layer_windows):
-            self.kind = _LayerKindPages(cfg.layer_windows)
+            self.kind = _LayerKindPages(cfg.layer_windows, cfg.kind_pages)
         else:
             self.kind = _kind_for(cfg.latent_dim, scaled)()
         # the last block is the kind's trash block, the target of
@@ -115,15 +154,19 @@ class BlockedKVCache:
         # windows; None where all pages are of one kind
         self.window_allocator = None
         self.window_blocks_given_back = 0
+        def block_bytes(pool, blocks):
+            return sum(int(x.nbytes) for x in jax.tree.leaves(pool)) // blocks
         if self.two_kinds:
             self.window_allocator = BlockedAllocator(cfg.window_blocks - 1)
+            # each kind's block as its own pool stores it: its heads, its
+            # widths, its rows' padding
             self._block_bytes = {
-                "full": int(self.data["full"].nbytes) // cfg.num_blocks,
-                "window": int(self.data["window"].nbytes)
-                // cfg.window_blocks}
+                "full": block_bytes(self.data["full"], cfg.num_blocks),
+                "window": block_bytes(self.data["window"],
+                                      cfg.window_blocks)}
         else:
-            self._block_bytes = {"full": int(jax.tree.leaves(
-                self.data)[0].nbytes) // cfg.num_blocks}
+            self._block_bytes = {"full": block_bytes(self.data,
+                                                     cfg.num_blocks)}
 
     @classmethod
     def for_spec(cls, spec, kv_cache_dtype: str, block_size: int,
@@ -143,7 +186,7 @@ class BlockedKVCache:
             num_blocks=num_blocks, dtype=dtypes[kv_cache_dtype],
             latent_dim=spec.latent_dim,
             layer_windows=tuple(spec.layer_windows or ()),
-            window_blocks=window_blocks))
+            window_blocks=window_blocks, kind_pages=spec.kind_pages))
 
     # ------------------------------------------------------------------
     # pages by layer kind: the windowed pool's host side
@@ -260,32 +303,41 @@ class BlockedKVCache:
         ``slot_copies`` a call of a layer that walks the full table (every
         layer of a pool of one kind, behind a window too), and
         ``slot_copies_windowed`` a call of a windowed kind's layer over its
-        own table. Empty over a latent pool."""
+        own table, each from the heads and the row widths of the kind's own
+        pages. Empty over a latent pool."""
         cfg = self.cfg
         if cfg.latent_dim:
             return {}
 
-        def copies(mb):
+        def copies(mb, kind):
+            at = self.kind.stored(kind, cfg) if self.two_kinds else \
+                cfg.page_shape
             return decode_slot_copies(
-                bucket, cfg.num_kv_heads, mb, cfg.block_size, cfg.head_dim,
-                jnp.dtype(cfg.dtype).itemsize)
-        whole = copies(table_blocks)
+                bucket, at.num_kv_heads, mb, cfg.block_size, at.key_dim,
+                jnp.dtype(cfg.dtype).itemsize, dv=at.value_dim)
+        whole = copies(table_blocks, "full")
         return {"slot_copies": whole,
-                "slot_copies_windowed": copies(self.window_steady_blocks)
+                "slot_copies_windowed":
+                copies(self.window_steady_blocks, "window")
                 if self.two_kinds else whole}
 
     def pages_held(self) -> dict:
-        """Blocks sequences hold now, by kind, and their bytes."""
+        """Blocks sequences hold now, by kind, and their bytes, each kind's
+        from a block of its own pool (``full_bytes`` over the live tokens is
+        what a token costs the full layers, their rows' padding with it;
+        ``window_bytes`` is a constant a sequence)."""
         full = self.allocator.total_blocks - self.allocator.free_blocks
+        full_bytes = full * self._block_bytes["full"]
         if not self.two_kinds:
-            return {"full_blocks": full,
-                    "held_bytes": full * self._block_bytes["full"]}
+            return {"full_blocks": full, "full_bytes": full_bytes,
+                    "held_bytes": full_bytes}
         win = self.window_allocator.total_blocks \
             - self.window_allocator.free_blocks
+        win_bytes = win * self._block_bytes["window"]
         return {"full_blocks": full, "window_blocks": win,
                 "window_blocks_given_back": self.window_blocks_given_back,
-                "held_bytes": full * self._block_bytes["full"]
-                + win * self._block_bytes["window"]}
+                "full_bytes": full_bytes, "window_bytes": win_bytes,
+                "held_bytes": full_bytes + win_bytes}
 
     @property
     def pool(self):
@@ -620,19 +672,28 @@ class _HeadPages(_Pages):
     block_axis = 3
 
     @staticmethod
+    def empty(layers: int, shape: HeadPageShape, blocks: int,
+              block_size: int, dtype):
+        """An empty pool of ``layers`` layers' pages of ``shape``."""
+        return jnp.zeros((layers, 2, shape.num_kv_heads, blocks, block_size,
+                          shape.key_dim), dtype)
+
+    @staticmethod
     def new_pool(cfg: KVCacheConfig):
         """``(pages, scales or None)`` of an empty pool."""
-        return jnp.zeros((cfg.num_layers, 2, cfg.num_kv_heads, cfg.num_blocks,
-                          cfg.block_size, cfg.head_dim), cfg.dtype), None
+        return _HeadPages.empty(cfg.num_layers, cfg.page_shape,
+                                cfg.num_blocks, cfg.block_size,
+                                cfg.dtype), None
 
     def _write(self, cache, layer, k, v, slots):
         return write_kv(cache, layer, k, v, *slots)
 
     def _read(self, pages, layer, q, block_tables, start_pos, attn_impl,
-              window="spec", softcap=None, scales=None):
+              window="spec", softcap=None, scales=None, sinks=None):
         """q: [B, T, H, D]; kernel or gather reference, both with ``softcap``
-        (gemma2) and, for fp8 pages, ``scales`` applied per (head, page) on
-        load."""
+        (gemma2), ``sinks`` (a logit a query head in the softmax's
+        denominator) and, for fp8 pages, ``scales`` applied per (head, page)
+        on load."""
         window = self.window if window == "spec" else window
         impl = _resolve_impl(attn_impl)
         if impl == "gather":
@@ -640,11 +701,12 @@ class _HeadPages(_Pages):
                 if scales is not None else (None, None)
             return paged_attention_reference(
                 q, pages[layer, 0], pages[layer, 1], block_tables, start_pos,
-                window=window, softcap=softcap, k_scales=ks, v_scales=vs)
+                window=window, softcap=softcap, k_scales=ks, v_scales=vs,
+                sinks=sinks)
         # the kernel takes the pool whole: a slice of it is a copy of it
         return paged_attention_pool(
             q, pages, layer, block_tables, start_pos, window=window,
-            softcap=softcap, scales=scales,
+            softcap=softcap, scales=scales, sinks=sinks,
             interpret=impl == "kernel_interpret")
 
     def attend_chunk(self, cache, layer, slots, block_table, start,
@@ -667,6 +729,64 @@ class _HeadPages(_Pages):
         with jax.named_scope("attn/paged"):
             return self._read(cache, layer, q[:, None], block_tables,
                               positions, attn_impl, **how)[:, 0], cache
+
+
+class _SplitHeadPages(_HeadPages):
+    """Head pages whose keys and values have widths of their own: a pool
+    ``{"k": [L, H_kv, NB, bs, W_k], "v": [L, H_kv, NB, bs, W_v]}``, each row
+    its ``key_dim`` or ``value_dim`` values and zero lanes up to
+    ``latent_row_width`` (a 192-wide key lies in 256 lanes: the device's
+    tiled layout pads a row to whole lane tiles whatever the shape says, so
+    the shape says it, and a block's bytes are what it costs). The kernel
+    scores a q padded with zeros to ``W_k`` at ``key_dim``'s scale and its
+    output is cut to ``value_dim``. A block hands its ``attend`` ``(q [N, H,
+    key_dim], k [N, H_kv, key_dim], v [N, H_kv, value_dim])``."""
+    block_axis = 2
+
+    def __init__(self, window, shape: HeadPageShape):
+        super().__init__(window)
+        self.shape = shape
+
+    @staticmethod
+    def empty(layers: int, shape: HeadPageShape, blocks: int,
+              block_size: int, dtype):
+        return {name: jnp.zeros((layers, shape.num_kv_heads, blocks,
+                                 block_size, latent_row_width(dim)), dtype)
+                for name, dim in (("k", shape.key_dim),
+                                  ("v", shape.value_dim))}
+
+    def _write(self, cache, layer, k, v, slots):
+        # the head is an index like the block and the offset, one update one
+        # row of the pool as it lies in memory (``write_kv``)
+        blk, off = slots[0][:, None], slots[1][:, None]
+        heads = jnp.arange(k.shape[1])[None, :]
+        return {name: cache[name].at[layer, heads, blk, off].set(
+                    cast_to_page_dtype(_lanes(new, cache[name].shape[-1]),
+                                       cache[name].dtype))
+                for name, new in (("k", k), ("v", v))}
+
+    def _read(self, pages, layer, q, block_tables, start_pos, attn_impl,
+              window="spec", sinks=None):
+        window = self.window if window == "spec" else window
+        how = dict(window=window, sinks=sinks,
+                   scale=self.shape.key_dim ** -0.5)
+        q = _lanes(q, pages["k"].shape[-1])
+        impl = _resolve_impl(attn_impl)
+        if impl == "gather":
+            out = paged_attention_reference(
+                q, pages["k"][layer], pages["v"][layer], block_tables,
+                start_pos, **how)
+        else:
+            out = paged_attention_pool(
+                q, (pages["k"], pages["v"]), layer, block_tables, start_pos,
+                interpret=impl == "kernel_interpret", **how)
+        return out[..., :self.shape.value_dim]
+
+
+def _lanes(x, width: int):
+    """``x`` with zero lanes up to ``width`` on its last axis."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, pad),)) if pad else x
 
 
 class _ScaledHeadPages(_HeadPages):
@@ -758,11 +878,8 @@ class _LatentPages(_Pages):
 def mixes_layer_kinds(layer_windows) -> bool:
     """Whether a spec's per-layer windows name full layers and windowed ones
     (then the cache keeps pages by layer kind)."""
-    kinds = set(layer_windows or ())
-    if len(kinds - {None}) > 1:
-        raise ValueError(f"layers of several window widths "
-                         f"{sorted(kinds - {None})}: one windowed kind only")
-    return None in kinds and len(kinds) == 2
+    return one_window(layer_windows) is not None \
+        and None in set(layer_windows)
 
 
 class _LayerKindPages:
@@ -770,7 +887,11 @@ class _LayerKindPages:
     windowed layers: ``{"full": [L_full, 2, H_kv, NB, bs, D], "window":
     [L_window, 2, H_kv, NB_w, bs, D]}``, each pool with its own trash block,
     allocator and block tables (the step programs carry ``{"full": table,
-    "window": table}`` likewise). A full layer's table names every block of
+    "window": table}`` likewise). ``kind_pages`` states each kind's own KV
+    heads and key and value widths where the kinds differ (left out, both
+    hold the config's ``num_kv_heads`` x ``head_dim``); a kind whose keys
+    and values differ in width keeps them in a pool of two arrays
+    (``_SplitHeadPages``). A full layer's table names every block of
     the sequence. A windowed layer's names the blocks from the one that
     holds the first query's window on (``blocks_behind_window``, which host
     and program both derive from the position: nothing else is handed in)
@@ -779,14 +900,31 @@ class _LayerKindPages:
     back. Inside, each kind is ``_HeadPages`` over its own pool with
     positions counted from its table's first block."""
 
-    def __init__(self, layer_windows):
-        self.window, = set(layer_windows) - {None}
+    def __init__(self, layer_windows, kind_pages=None):
+        self.window = one_window(layer_windows)
         self.kinds = tuple("window" if w else "full" for w in layer_windows)
         # a layer's index in its kind's pool
         self.local = tuple(self.kinds[:i].count(k)
                            for i, k in enumerate(self.kinds))
-        self.pages = {"full": _HeadPages(None),
-                      "window": _HeadPages(self.window)}
+        self.shapes = kind_pages
+
+        def pages(kind, window):
+            shape = kind_pages and kind_pages[kind]
+            return _HeadPages(window) if not shape or shape.square \
+                else _SplitHeadPages(window, shape)
+        self.pages = {"full": pages("full", None),
+                      "window": pages("window", self.window)}
+
+    def shape(self, kind: str, cfg: KVCacheConfig) -> HeadPageShape:
+        """What ``kind``'s page holds of a token a layer."""
+        return self.shapes[kind] if self.shapes else cfg.page_shape
+
+    def stored(self, kind: str, cfg: KVCacheConfig) -> HeadPageShape:
+        """``shape`` with the widths of the rows the pool stores."""
+        at = self.shape(kind, cfg)
+        return at if at.square else dataclasses.replace(
+            at, key_dim=latent_row_width(at.key_dim),
+            value_dim=latent_row_width(at.value_dim))
 
     def new_pool(self, cfg: KVCacheConfig):
         if cfg.dtype == jnp.float8_e4m3fn:
@@ -800,8 +938,9 @@ class _LayerKindPages:
                              f"and at least one more")
 
         def pool(kind, blocks):
-            return jnp.zeros((self.kinds.count(kind), 2, cfg.num_kv_heads,
-                              blocks, cfg.block_size, cfg.head_dim), cfg.dtype)
+            return self.pages[kind].empty(
+                self.kinds.count(kind), self.shape(kind, cfg), blocks,
+                cfg.block_size, cfg.dtype)
         return {"full": pool("full", cfg.num_blocks),
                 "window": pool("window", cfg.window_blocks)}, None
 
@@ -871,5 +1010,5 @@ def page_kind(spec, cache):
     ``KVCacheSpec`` and the pool's structure: ``(pages, scales)``, pages
     alone, or a pool a layer kind."""
     if isinstance(cache, dict):
-        return _LayerKindPages(spec.layer_windows)
+        return _LayerKindPages(spec.layer_windows, spec.kind_pages)
     return _kind_for(spec.latent_dim, isinstance(cache, tuple))(spec.window)

@@ -51,6 +51,10 @@ _CONFIG_TO_POLICY: Dict[type, type] = {}
 
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
+    """What a policy's cache holds, stated once: ``num_kv_heads`` keys and
+    values of ``head_dim`` a token a layer behind one ``window``, unless
+    ``kind_pages`` (with ``layer_windows``) states each layer kind's own, or
+    ``latent_dim`` a headless row."""
     num_layers: int
     num_kv_heads: int
     head_dim: int
@@ -64,9 +68,14 @@ class KVCacheSpec:
     latent_dim: int = 0
     # every layer's window (None: a full layer) for a model that mixes full
     # and windowed layers: the cache then keeps pages by layer kind
-    # (``kv_cache._LayerKindPages``) and ``window`` says nothing. None: all
-    # layers' pages are of one kind
+    # (``kv_cache._LayerKindPages``), the windowed kind behind its own
+    # window. None: all layers' pages are of one kind behind ``window``
     layer_windows: Any = None
+    # {"full": kv_cache.HeadPageShape, "window": ...}: each kind's own KV
+    # heads and key and value widths, where the kinds differ or a key is
+    # not as wide as a value. None: both kinds hold ``num_kv_heads`` x
+    # ``head_dim``
+    kind_pages: Any = None
 
 
 def register_policy(name: str, config_type: type):
@@ -729,8 +738,9 @@ def _latent_attention(lp, x, attend, positions, cfg):
 
 def _dense_or_experts(lp, i, x, cfg, valid):
     """(what layer ``i``'s second sublayer adds to ``x`` [N, D], its own
-    pre-norm inside, the layer's counts or None): the gated MLP of a leading
-    dense layer, else the chosen routed experts and the shared one."""
+    pre-norm inside, the layer's counts or None): the gated MLP of a dense
+    layer, else the chosen routed experts of those held here (the router's
+    ``first_expert ..``) and the shared one where there is one."""
     dtype = cfg.dtype
     h2 = _rms(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps)
     if cfg.is_dense(i):
@@ -739,7 +749,8 @@ def _dense_or_experts(lp, i, x, cfg, valid):
     moe = lp["moe"]
     with jax.named_scope("moe/router"):
         weights, ids = route(h2, moe, cfg)
-    y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid)
+    y, counts = _chosen_experts(moe["experts"], h2, weights, ids, valid,
+                                first=cfg.first_expert)
     if cfg.n_shared_experts:
         with jax.named_scope("moe/shared"):
             y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
@@ -876,6 +887,70 @@ class LagunaPolicy:
                                     first=cfg.first_expert)
         with jax.named_scope("moe/shared"):
             y = y + _mlp({"mlp": moe["shared"]}, h2, dtype)
+        return x + y, counts
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return x.astype(jnp.float32) @ \
+            params["lm_head"]["kernel"].astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# MiMo-V2 (full and windowed layers with KV head counts of their own, keys
+# wider than values, a sink logit a head in the windowed layers' softmax, a
+# rope base a kind over a part of the head, scaled values, a held share of
+# sigmoid-routed experts, a leading dense layer)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.inference.v2.kv_cache import HeadPageShape  # noqa: E402
+from deepspeed_tpu.models import mimo_v2 as _mimo  # noqa: E402
+
+
+@register_policy("mimo_v2", _mimo.MiMoV2Config)
+class MiMoV2Policy:
+    """models/mimo_v2.py's serving twin. ``block`` decides KV heads, rope
+    base, sink and window by the layer's index; ``cache_spec`` states each
+    kind's pages (``kind_pages``: 4 KV heads in a full layer, 8 in a windowed
+    one, keys of 192 beside values of 128 at the published sizes), and the
+    page kind attends a windowed layer over its window's blocks with the
+    layer's sinks in the denominator. Values are cached scaled. The experts
+    stacked here are the router's ``first_expert ..`` (``_dense_or_experts``,
+    JoyAI's)."""
+
+    @staticmethod
+    def cache_spec(cfg) -> KVCacheSpec:
+        kinds = {name: HeadPageShape(at.num_kv_heads, at.head_dim,
+                                     at.v_head_dim)
+                 for name, at in (("full", cfg.full), ("window", cfg.swa))}
+        return KVCacheSpec(
+            cfg.num_layers, cfg.full.num_kv_heads, cfg.full.head_dim,
+            cfg.max_seq_len, cfg.dtype, None,
+            layer_windows=tuple(cfg.window(i) for i in range(cfg.num_layers)),
+            kind_pages=kinds)
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        ap = lp["attn"]
+        dtype = cfg.dtype
+        cos, sin = _rope_tables(cfg.rotary_dim(i), cfg.max_seq_len,
+                                cfg.rope_base(i))
+        with jax.named_scope("attn/qkv"):
+            h = _rms(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps)
+            q, k, v = _qkv(lp, h, dtype)
+            q = _rope_rows(q, cos, sin, positions)
+            k = _rope_rows(k, cos, sin, positions)
+            v = v * jnp.asarray(cfg.attention_value_scale, dtype)
+        how = {"sinks": ap["sink"]} if cfg.has_sink(i) else {}
+        attn = attend(q, k, v, **how)
+        with jax.named_scope("attn/out"):
+            x = x + jnp.einsum("thv,hvd->td", attn,
+                               ap["wo"]["kernel"].astype(dtype))
+        y, counts = _dense_or_experts(lp, i, x, cfg, valid)
         return x + y, counts
 
     @staticmethod

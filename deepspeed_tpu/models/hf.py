@@ -143,6 +143,8 @@ _REGISTRY = {
                             "LagunaForCausalLM", "convert_hf_laguna"),
     "xing4_0": _family_entry("xing4", "xing4_config_from_hf",
                              "Xing4ForCausalLM", "convert_hf_xing4"),
+    "mimo_v2": _family_entry("mimo_v2", "mimo_v2_config_from_hf",
+                             "MiMoV2ForCausalLM", "convert_hf_mimo_v2"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

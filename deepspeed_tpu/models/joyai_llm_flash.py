@@ -92,6 +92,17 @@ class JoyAIFlashConfig:
         return scale * yarn_mscale(self.rope_yarn.factor,
                                    self.yarn_mscale_all_dim) ** 2
 
+    # every routed expert is stacked here. ``_Experts`` and the served
+    # ``_dense_or_experts`` read these two from whichever config they are
+    # given (``MiMoV2Config`` states a held share of its own)
+    @property
+    def first_expert(self) -> int:
+        return 0
+
+    @property
+    def held(self) -> int:
+        return self.n_routed_experts
+
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
 
@@ -224,19 +235,19 @@ def _upcycled(key, shape, dtype=jnp.float32):
 
 
 class _Experts(nn.Module):
-    """The stacked weights of the routed experts."""
+    """The stacked weights of the routed experts held here."""
     cfg: JoyAIFlashConfig
 
     @nn.compact
     def __call__(self, h, weights, ids):
         cfg = self.cfg
-        e, d, f = (cfg.n_routed_experts, cfg.hidden_size,
-                   cfg.moe_intermediate_size)
+        e, d, f = cfg.held, cfg.hidden_size, cfg.moe_intermediate_size
         experts = {
             "w_gate": self.param("w_gate", _upcycled, (e, d, f), jnp.float32),
             "w_up": self.param("w_up", _upcycled, (e, d, f), jnp.float32),
             "w_down": self.param("w_down", _upcycled, (e, f, d), jnp.float32)}
-        return grouped_expert_ffn(h, experts, weights, ids)[0]
+        return grouped_expert_ffn(h, experts, weights, ids,
+                                  first=cfg.first_expert)[0]
 
 
 class JoyAIFlashMoE(nn.Module):

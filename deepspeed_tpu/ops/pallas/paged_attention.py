@@ -78,6 +78,18 @@ and calls it once a layer.
 Cache layout is head-major ``[Hkv, num_blocks, block_size, d]`` so one page of
 one KV head is a contiguous ``(block_size, d)`` tile (legal TPU block shape),
 and one table entry's page of every head ``Hkv`` such tiles a stride apart.
+
+**Keys and values of their own widths, and a sink.** The K pages' rows are as
+wide as q (``q k^T`` over ``dk``) and the V pages' as wide as the output
+(``p v`` over ``dv``): two static shapes, one where a model's heads are square
+(every tile and buffer count below takes both). ``scale`` is the softmax
+scale where ``dk`` is not what it follows from: a key stored in rows padded
+with zero lanes scores at its own width. ``sinks`` is a learned logit a query
+head that joins the softmax's denominator and has no value: the online
+softmax starts its running maximum at the row's sink and its running sum at
+1 (``exp(sink - sink)``) where it otherwise starts them at -inf and 0, which
+is exact and costs no pass. With neither, the kernel is traced and lowered
+as it was.
 """
 
 import functools
@@ -93,8 +105,9 @@ NEG_INF = -1e30
 # The kernel sets no compiler parameters, so it lives inside the default 16 MiB
 # of scoped VMEM. What a grid step holds there, by the sizes the v5e's compiler
 # refused and took (libtpu 0.0.34; PERF.md section 6, PR 36): a row of the
-# fold keeps q and the output twice (the pipeline's two buffers), the float32
-# accumulator, and the running maximum and sum at a lane tile each; a page of
+# fold keeps q (at the keys' width) and the output (at the values') twice
+# (the pipeline's two buffers), the float32 accumulator, and the running
+# maximum and sum at a lane tile each; a page of
 # the key tile its K and V slots twice and once more joined; and every row of
 # every page a float32 score and a float32 probability. 2,048 rows x 16 pages
 # come to 22.5 MiB by that count and were refused at 22.2; 2,048 x 8 (13.8) and
@@ -121,17 +134,23 @@ def _tile_pages(mb: int) -> int:
     return 1 << (min(mb, _MAX_PAGES) - 1).bit_length()
 
 
-def _tile(g: int, mb: int, bs: int, d: int, itemsize: int, hkv: int = 1):
+def _tile(g: int, mb: int, bs: int, d: int, itemsize: int, hkv: int = 1,
+          dv: int = 0):
     """``(rows, pages, heads)`` of one grid step for a fold of ``g`` rows a
-    KV head, ``hkv`` of them, over a table of ``mb`` blocks: the key tile as
-    wide as the table and ``_MAX_PAGES`` allow, then the row block as tall as
-    the scoped VMEM holds beside it, a power of two so that it divides the
-    chunk buckets; then every KV head in the step where the fold is one row
-    block and ``hkv`` such blocks and their tiles fit the scoped VMEM by the
-    same count, else one."""
+    KV head, ``hkv`` of them, over a table of ``mb`` blocks, keys ``d`` wide
+    and values ``dv`` (left out: ``d``): the key tile as wide as the table
+    and ``_MAX_PAGES`` allow, then the row block as tall as the scoped VMEM
+    holds beside it, a power of two so that it divides the chunk buckets;
+    then every KV head in the step where the fold is one row block and
+    ``hkv`` such blocks and their tiles fit the scoped VMEM by the same
+    count, else one. The count (``_SCOPED_VMEM_BYTES``): a row keeps q
+    (``d``) and the output (``dv``) twice and the accumulator (``dv``) in
+    float32; a page its K slot (``d``) and its V slot (``dv``) twice and
+    once more joined."""
+    dv = dv or d
     pages = _tile_pages(mb)
-    a_row = d * (4 * itemsize + 4) + 2 * 128 * 4 + 8 * pages * bs
-    a_tile = 6 * pages * bs * d * itemsize
+    a_row = 2 * (d + dv) * itemsize + 4 * dv + 2 * 128 * 4 + 8 * pages * bs
+    a_tile = 3 * pages * bs * (d + dv) * itemsize
     room = (_SCOPED_VMEM_BYTES - a_tile) // a_row
     rows = min(1 << (room.bit_length() - 1),
                max(_MAX_FOLD_ELEMS // d // 16 * 16, 16))
@@ -153,7 +172,7 @@ def decode_tile_keys(contexts, mb: int, bs: int, window=None) -> int:
 
 
 def decode_slot_copies(batch: int, hkv: int, mb: int, bs: int, d: int,
-                       itemsize: int, group: int = 1) -> int:
+                       itemsize: int, group: int = 1, dv: int = 0) -> int:
     """Slot copies one layer's decode call issues for ``batch`` rows of
     ``group`` query heads a KV head over tables of ``mb`` blocks: a K and a V
     slot a page of every step of the grid ``(batch, hkv // heads, 1,
@@ -161,21 +180,24 @@ def decode_slot_copies(batch: int, hkv: int, mb: int, bs: int, d: int,
     live: what a decode call's seconds divide by). ``group`` decides only
     whether ``hkv`` folds still fit a step: left out, a fold of one sublane
     tile."""
-    _, pages, heads = _tile(group, mb, bs, d, itemsize, hkv)
+    _, pages, heads = _tile(group, mb, bs, d, itemsize, hkv, dv)
     return batch * (hkv // heads) * -(-mb // pages) * pages * 2
 
 
 def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, heads,
-                  window, softcap, num_blocks=0):
+                  window, softcap, scale, sunk=False, num_blocks=0):
     tables_ref, start_ref, _ = refs[:3]
     refs = refs[3:]
-    kscale_ref = vscale_ref = None
+    kscale_ref = vscale_ref = sink_ref = None
     if num_blocks:      # fp8 pages with per-(head, page) scales prefetched
         kscale_ref, vscale_ref = refs[:2]
         refs = refs[2:]
     q_ref = refs[0]
     k_refs, v_refs = refs[1:1 + pages], refs[1 + pages:1 + 2 * pages]
-    o_ref, m_scr, l_scr, acc_scr = refs[1 + 2 * pages:]
+    refs = refs[1 + 2 * pages:]
+    if sunk:            # a sink logit a row of the fold, [(heads,) rows, 1]
+        sink_ref, refs = refs[0], refs[1:]
+    o_ref, m_scr, l_scr, acc_scr = refs
     tile = pages * block_size
     b = pl.program_id(0)
     hi = pl.program_id(1)                  # block of ``heads`` KV heads
@@ -189,8 +211,13 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, heads,
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        if sunk:
+            # the sink is in the denominator before any key: exp(b - b) = 1
+            m_scr[:] = sink_ref[head]
+            l_scr[:] = jnp.ones_like(l_scr)
+        else:
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     start = start_ref[b]
@@ -224,7 +251,7 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, heads,
         v = _joined(v_refs, vscale_ref, q.dtype)
         s = jax.lax.dot_general(q, k, (((q.ndim - 1,), (k.ndim - 1,)), batch),
                                 preferred_element_type=jnp.float32)
-        s = s * (1.0 / np.sqrt(q.shape[-1]))
+        s = s * scale
         if softcap:                        # gemma2 attn_logit_softcapping
             s = softcap * jnp.tanh(s / softcap)
         # row r of the fold is (q-head r // chunk, chunk token r % chunk)
@@ -260,13 +287,16 @@ def _paged_kernel(*refs, block_size, pages, steps, chunk, rows, heads,
 
 def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
                     window=None, softcap=None, k_scales=None, v_scales=None,
-                    interpret: bool = False):
-    """q: [B, T, H, d] (T=1 decode / B=1 prefill chunk);
-    k_pages/v_pages: [Hkv, NB, block_size, d]; block_tables: [B, MB] int32
-    (trash-padded); start_pos: [B] int32 — global position of q row t=0
-    (row t attends kpos <= start+t). ``k_scales``/``v_scales``: optional
-    [Hkv, NB] fp32 per-(head, page) dequant scales for fp8 pages (ride as
-    scalar prefetch; applied on load in-kernel). Returns [B, T, H, d].
+                    sinks=None, scale=None, interpret: bool = False):
+    """q: [B, T, H, dk] (T=1 decode / B=1 prefill chunk);
+    k_pages: [Hkv, NB, block_size, dk]; v_pages: [Hkv, NB, block_size, dv];
+    block_tables: [B, MB] int32 (trash-padded); start_pos: [B] int32 — global
+    position of q row t=0 (row t attends kpos <= start+t).
+    ``k_scales``/``v_scales``: optional [Hkv, NB] fp32 per-(head, page)
+    dequant scales for fp8 pages (ride as scalar prefetch; applied on load
+    in-kernel). ``sinks``: optional [H] logits, one a query head, that join
+    the softmax's denominator. ``scale``: the softmax scale, ``dk ** -0.5``
+    left out. Returns [B, T, H, dv].
 
     The KV written for q's own tokens must already be in the pages (the decode/
     prefill step scatters K/V before calling attention); causal masking then
@@ -274,52 +304,69 @@ def paged_attention(q, k_pages, v_pages, block_tables, start_pos,
     tail entries of the last page are never visible.
     """
     return _paged_call(q, k_pages, v_pages, jnp.zeros((2,), jnp.int32),
-                       block_tables, start_pos, k_scales, v_scales,
+                       block_tables, start_pos, k_scales, v_scales, sinks,
                        hkv=k_pages.shape[0], window=window, softcap=softcap,
-                       interpret=interpret)
+                       scale=_softmax_scale(q, scale), interpret=interpret)
+
+
+def _softmax_scale(q, scale) -> float:
+    return float(1.0 / np.sqrt(q.shape[-1])) if scale is None \
+        else float(scale)
 
 
 def paged_attention_pool(q, pool, layer, block_tables, start_pos,
-                         window=None, softcap=None, scales=None,
-                         interpret: bool = False):
-    """``paged_attention`` over layer ``layer`` of the whole KV pool
-    [L, 2, Hkv, NB, block_size, d] (``scales``: [L, 2, Hkv, NB]). The pool
-    goes to the kernel as it lies in memory, its leading dimensions merged,
-    and the index maps start at the layer's K and V heads: a step program
-    that handed ``pool[layer, 0]`` and ``pool[layer, 1]`` to the kernel
-    copied each out first, the whole pool once a step. Where those heads
-    start is a value handed to the kernel, so the layers of a pool share one
-    traced and lowered call."""
-    hkv = pool.shape[2]
-    pages = pool.reshape((-1,) + pool.shape[3:])
+                         window=None, softcap=None, scales=None, sinks=None,
+                         scale=None, interpret: bool = False):
+    """``paged_attention`` over layer ``layer`` of the whole KV pool: K and V
+    planes of one array [L, 2, Hkv, NB, block_size, d] (``scales``:
+    [L, 2, Hkv, NB]), or where keys and values have widths of their own a
+    pair ``(K [L, Hkv, NB, block_size, dk], V [L, Hkv, NB, block_size,
+    dv])``. A pool goes to the kernel as it lies in memory, its leading
+    dimensions merged, and the index maps start at the layer's K and V
+    heads: a step program that handed ``pool[layer, 0]`` and
+    ``pool[layer, 1]`` to the kernel copied each out first, the whole pool
+    once a step. Where those heads start is a value handed to the kernel, so
+    the layers of a pool share one traced and lowered call."""
+    if isinstance(pool, tuple):
+        k_pool, v_pool = pool
+        hkv = k_pool.shape[1]
+        k_pages = k_pool.reshape((-1,) + k_pool.shape[2:])
+        v_pages = v_pool.reshape((-1,) + v_pool.shape[2:])
+        heads0 = jnp.asarray([layer * hkv, layer * hkv], jnp.int32)
+    else:
+        hkv = pool.shape[2]
+        k_pages = v_pages = pool.reshape((-1,) + pool.shape[3:])
+        heads0 = jnp.asarray([2 * layer * hkv, (2 * layer + 1) * hkv],
+                             jnp.int32)
     ks, vs = (scales[layer, 0], scales[layer, 1]) if scales is not None \
         else (None, None)
-    heads0 = jnp.asarray([2 * layer * hkv, (2 * layer + 1) * hkv], jnp.int32)
-    return _paged_call(q, pages, pages, heads0, block_tables, start_pos, ks,
-                       vs, hkv=hkv, window=window, softcap=softcap,
-                       interpret=interpret)
+    return _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
+                       ks, vs, sinks, hkv=hkv, window=window, softcap=softcap,
+                       scale=_softmax_scale(q, scale), interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("hkv", "window", "softcap",
-                                             "interpret"))
+                                             "scale", "interpret"))
 def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
-                k_scales, v_scales, *, hkv: int, window, softcap,
-                interpret: bool):
+                k_scales, v_scales, sinks=None, *, hkv: int, window, softcap,
+                scale: float, interpret: bool):
     """The kernel call: step ``hi`` of the grid's head axis reads the
-    ``heads`` rows of ``k_pages`` from ``heads0[0] + hi * heads`` and those of
-    ``v_pages`` from ``heads0[1] + hi * heads`` ([X, NB, bs, d]; ``heads`` is
-    ``_tile``'s: ``hkv`` or 1). A function of its own under ``jit`` so that a
-    step program traces and lowers it once a layer kind and not once a
-    layer."""
+    ``heads`` rows of ``k_pages`` ([X, NB, bs, dk]) from ``heads0[0] + hi *
+    heads`` and those of ``v_pages`` ([Y, NB, bs, dv]) from ``heads0[1] + hi
+    * heads`` (``heads`` is ``_tile``'s: ``hkv`` or 1). A function of its own
+    under ``jit`` so that a step program traces and lowers it once a layer
+    kind and not once a layer."""
     b, t, h, d = q.shape
     _, nb, bs, _ = k_pages.shape
+    dv = v_pages.shape[-1]
     rep = h // hkv
     g = rep * t
     mb = block_tables.shape[1]
-    rows, pages, heads = _tile(g, mb, bs, d, k_pages.dtype.itemsize, hkv)
+    rows, pages, heads = _tile(g, mb, bs, d, k_pages.dtype.itemsize, hkv, dv)
     gp = -(-g // rows) * rows
     steps = -(-mb // pages)
     scaled = k_scales is not None
+    sunk = sinks is not None
 
     qf = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, d)
     if gp != g:
@@ -328,26 +375,37 @@ def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
     tables = jnp.pad(block_tables.astype(jnp.int32),
                      ((0, 0), (0, steps * pages - mb)))
 
-    def slot(kv, p):
+    def slot(kv, p, width):
         # slot p of a step reads its own entry of the sequence's table: that
         # block of ``heads`` KV heads, one strided copy for all of them
         return pl.BlockSpec(
-            (heads, 1, bs, d), lambda bi, hi, i, j, *pf:
+            (heads, 1, bs, width), lambda bi, hi, i, j, *pf:
             (pf[2][kv] + hi, pf[0][(bi * steps + j) * pages + p], 0, 0))
 
-    rows_spec = pl.BlockSpec((1, heads, rows, d), lambda bi, hi, i, j, *pf:
-                             (bi, hi, i, 0))
+    def rows_spec(width):
+        return pl.BlockSpec((1, heads, rows, width),
+                            lambda bi, hi, i, j, *pf: (bi, hi, i, 0))
+
     lead = (heads,) if heads > 1 else ()
+    extra, extra_specs = [], []
+    if sunk:
+        # row r of a KV head's fold is query head r // t of its group: the
+        # sink of every row, [Hkv, gp, 1] (0 on the fold's padding rows)
+        per_row = jnp.repeat(sinks.astype(jnp.float32).reshape(hkv, rep), t,
+                             axis=1)
+        extra = [jnp.pad(per_row, ((0, 0), (0, gp - g)))[..., None]]
+        extra_specs = [pl.BlockSpec((heads, rows, 1),
+                                    lambda bi, hi, i, j, *pf: (hi, i, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5 if scaled else 3,
         grid=(b, hkv // heads, gp // rows, steps),
-        in_specs=[rows_spec] + [slot(kv, p) for kv in (0, 1)
-                                for p in range(pages)],
-        out_specs=rows_spec,
+        in_specs=[rows_spec(d)] + [slot(0, p, d) for p in range(pages)]
+        + [slot(1, p, dv) for p in range(pages)] + extra_specs,
+        out_specs=rows_spec(dv),
         scratch_shapes=[
             pltpu.VMEM(lead + (rows, 1), jnp.float32),
             pltpu.VMEM(lead + (rows, 1), jnp.float32),
-            pltpu.VMEM(lead + (rows, d), jnp.float32),
+            pltpu.VMEM(lead + (rows, dv), jnp.float32),
         ],
     )
     heads0 = heads0.astype(jnp.int32)
@@ -362,27 +420,31 @@ def _paged_call(q, k_pages, v_pages, heads0, block_tables, start_pos,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
                           steps=steps, chunk=t, rows=rows, heads=heads,
-                          window=window, softcap=softcap,
-                          num_blocks=nb if scaled else 0),
+                          window=window, softcap=softcap, scale=scale,
+                          sunk=sunk, num_blocks=nb if scaled else 0),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, gp, dv), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(*prefetch, qf, *[k_pages] * pages, *[v_pages] * pages)
+    )(*prefetch, qf, *[k_pages] * pages, *[v_pages] * pages, *extra)
 
-    out = out[:, :, :g].reshape(b, hkv, rep, t, d)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, d)
+    out = out[:, :, :g].reshape(b, hkv, rep, t, dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, dv)
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, start_pos,
                               window=None, softcap=None, k_scales=None,
-                              v_scales=None):
+                              v_scales=None, sinks=None, scale=None):
     """Gather-based jnp reference with identical semantics (numerics oracle for
     kernel tests; also the CPU fallback path). ``softcap`` tanh-caps the
     scaled logits before masking (gemma2 attn_logit_softcapping);
-    ``k_scales``/``v_scales``: [Hkv, NB] per-(head, page) fp8 dequant."""
+    ``k_scales``/``v_scales``: [Hkv, NB] per-(head, page) fp8 dequant;
+    ``sinks``: [H] logits that join each head's denominator as one more
+    column with no value; ``scale``: the softmax scale, ``dk ** -0.5`` left
+    out. v_pages: [Hkv, NB, bs, dv]; returns [B, T, H, dv]."""
     b, t, h, d = q.shape
     hkv, _, bs, _ = k_pages.shape
+    dv = v_pages.shape[-1]
     rep = h // hkv
     mb = block_tables.shape[1]
     # [Hkv, B, MB, bs, d] -> [B, MB*bs, Hkv, d]
@@ -392,14 +454,15 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, start_pos,
         gk = gk.astype(jnp.float32) * k_scales[:, block_tables][..., None, None]
         gv = gv.astype(jnp.float32) * v_scales[:, block_tables][..., None, None]
     ctx_k = gk.transpose(1, 2, 3, 0, 4).reshape(b, mb * bs, hkv, d)
-    ctx_v = gv.transpose(1, 2, 3, 0, 4).reshape(b, mb * bs, hkv, d)
+    ctx_v = gv.transpose(1, 2, 3, 0, 4).reshape(b, mb * bs, hkv, dv)
     if rep > 1:
         ctx_k = jnp.repeat(ctx_k, rep, axis=2)
         ctx_v = jnp.repeat(ctx_v, rep, axis=2)
     ctx_k = ctx_k.astype(q.dtype)          # fp8 pages dequantize on load
     ctx_v = ctx_v.astype(q.dtype)
     s = jnp.einsum("bthd,bkhd->bhtk", q, ctx_k,
-                   preferred_element_type=jnp.float32) / np.sqrt(d)
+                   preferred_element_type=jnp.float32)
+    s = s / np.sqrt(d) if scale is None else s * scale
     from deepspeed_tpu.models.llama import softcap_logits
     s = softcap_logits(s, softcap)
     qpos = start_pos[:, None] + jnp.arange(t)[None, :]          # [B, T]
@@ -408,5 +471,10 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, start_pos,
     if window is not None:
         mask = jnp.logical_and(mask, kpos > qpos[..., None] - window)
     s = jnp.where(mask[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(ctx_v.dtype)
-    return jnp.einsum("bhtk,bkhd->bthd", p, ctx_v)
+    if sinks is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        sink = jnp.broadcast_to(
+            sinks.astype(jnp.float32)[None, :, None, None], s.shape[:3] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, sink], -1), axis=-1)[..., :-1]
+    return jnp.einsum("bhtk,bkhd->bthd", p.astype(ctx_v.dtype), ctx_v)

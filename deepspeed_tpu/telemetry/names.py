@@ -134,7 +134,10 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/prefill": ("complete",),
     "serve/decode": ("complete",),
     "serve/kv_bytes": ("counter",),
-    "serve/kv_pages": ("counter",),         # blocks held by kind of page, a tick
+    # blocks held by kind of page a tick, and their bytes: `kv_held_bytes`,
+    # and by kind `kv_full_bytes` and `kv_window_bytes`, each from a block of
+    # the kind's own pool (its KV heads, its key and value rows as stored)
+    "serve/kv_pages": ("counter",),
     "serve/tick_stage_share": ("counter",),
     "serve/kv_tier": ("counter",),
     "serve/prefix_cache": ("counter",),
@@ -237,7 +240,8 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: projections and the fold, the row's write, the paged decode kernel with
 #: the value unfold, a chunk's gather, up-projection and prefill kernel.
 #: ``attn/full`` and ``attn/window`` hold a layer's write and paged attention
-#: where a cache keeps pages by layer kind, ``attn/gate`` a per-head output
+#: where a cache keeps pages by layer kind (whatever heads and widths a kind
+#: states, with or without sinks), ``attn/gate`` a per-head output
 #: gate, ``hc/pre``, ``hc/post`` and ``hc/head`` the mixing of several
 #: residual streams round a sublayer (``inference/v2/hyper_connection.py``)
 SERVED_SCOPES: Tuple[str, ...] = (

@@ -256,3 +256,101 @@ def test_windowed_kind_gives_blocks_back_and_refuses_what_moves_one_pools_ids(
         num_blocks=NB, dtype=jnp.float32, layer_windows=(6, 6)))
     assert not one.two_kinds and one.window_allocator is None
     assert one.blocks_needed(40) == 10 and one.usable_blocks == NB - 1
+
+
+@pytest.mark.parametrize("shapes", [
+    pytest.param({"full": (1, 8, 8), "window": (2, 8, 8)},
+                 id="unequal-heads-square"),
+    pytest.param({"full": (1, 12, 8), "window": (2, 12, 8)},
+                 id="unequal-heads-keys-wider-than-values"),
+    pytest.param({"full": (2, 12, 8), "window": (2, 8, 8)},
+                 id="one-kind-split-one-square")])
+def test_a_kind_states_its_own_heads_and_widths(shapes):
+    """Pages by layer kind where each kind has its own KV head count and its
+    keys and values their own widths: a square kind keeps K and V planes of
+    one array at its own head count, a kind whose key is wider than its value
+    a K pool and a V pool, each row padded to whole lane tiles; a write lands
+    in the named slot of its own kind's pool at its own widths, the other
+    kind's pool does not move, and every byte count is the kind's own."""
+    from deepspeed_tpu.inference.v2.kv_cache import (HeadPageShape,
+                                                     latent_row_width)
+    windows = (None, 6, 6)
+    kinds = {k: HeadPageShape(*v) for k, v in shapes.items()}
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=3, num_kv_heads=1, head_dim=12, block_size=BS,
+        num_blocks=NB, dtype=jnp.float32, layer_windows=windows,
+        window_blocks=7, kind_pages=kinds))
+    spec = KVCacheSpec(3, 1, 12, 64, jnp.float32, None,
+                       layer_windows=windows, kind_pages=kinds)
+    kind = page_kind(spec, kv.pool)
+    layers, blocks = {"full": 1, "window": 2}, {"full": NB, "window": 7}
+    for name, at in kinds.items():
+        pool = kv.pool[name]
+        if at.square:
+            assert pool.shape == (layers[name], 2, at.num_kv_heads,
+                                  blocks[name], BS, at.key_dim)
+            per_token = 2 * at.num_kv_heads * at.key_dim
+        else:
+            assert {k: v.shape for k, v in pool.items()} == {
+                "k": (layers[name], at.num_kv_heads, blocks[name], BS, 128),
+                "v": (layers[name], at.num_kv_heads, blocks[name], BS, 128)}
+            per_token = 2 * at.num_kv_heads * latent_row_width(at.key_dim)
+        assert kv._block_bytes[name] == layers[name] * BS * per_token * 4
+        assert kind.pages[name].trash_block(pool) == blocks[name] - 1
+        assert kv.kind.stored(name, kv.cfg).key_dim == (
+            at.key_dim if at.square else 128)
+    # a decode batch of two in the second windowed layer (layer 2, its
+    # kind's layer 1): one token each at positions 5 and 2
+    at = kinds["window"]
+    tables = {"full": jnp.asarray([[3, 4], [6, 1]], jnp.int32),
+              "window": jnp.asarray([[2, 5, 6], [4, 6, 6]], jnp.int32)}
+    positions = jnp.asarray([5, 2], jnp.int32)
+    key = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(key[0], (2, 2 * at.num_kv_heads, at.key_dim))
+    k = jax.random.normal(key[1], (2, at.num_kv_heads, at.key_dim))
+    v = jax.random.normal(key[2], (2, at.num_kv_heads, at.value_dim))
+    slots = kind.decode_slots(kv.pool, tables, positions,
+                              jnp.asarray([True, True]), BS)
+    before = jax.tree.map(jnp.copy, kv.pool)
+    out, pool = kind.attend_decode(kv.pool, 2, slots, tables, positions,
+                                   "gather", q, k, v)
+    assert out.shape == (2, 2 * at.num_kv_heads, at.value_dim)
+    for a, b in zip(jax.tree.leaves(pool["full"]),
+                    jax.tree.leaves(before["full"])):
+        np.testing.assert_array_equal(a, b)
+    win = pool["window"]
+    k_rows, v_rows = (win[1, 0], win[1, 1]) if at.square else \
+        (win["k"][1], win["v"][1])                   # [H, NB_w, BS, W]
+    for row, (block, offset) in enumerate([(5, 1), (4, 2)]):
+        np.testing.assert_array_equal(
+            k_rows[:, block, offset, :at.key_dim], k[row])
+        np.testing.assert_array_equal(
+            v_rows[:, block, offset, :at.value_dim], v[row])
+        assert not np.asarray(k_rows[:, block, offset, at.key_dim:]).any()
+    assert int(sum(jnp.count_nonzero(x) for x in jax.tree.leaves(win))) \
+        == 2 * at.num_kv_heads * (at.key_dim + at.value_dim)
+    # the bytes sequences hold, by kind, from a block of the kind's own pool
+    kv.reserve(3)
+    kv.window_allocator.allocate(2)
+    held = kv.pages_held()
+    assert held["full_bytes"] == 3 * kv._block_bytes["full"]
+    assert held["window_bytes"] == 2 * kv._block_bytes["window"]
+    assert held["held_bytes"] == held["full_bytes"] + held["window_bytes"]
+    # a decode call's slot copies follow the kind's own heads and widths
+    copies = kv.decode_slot_copies(4, 8)
+    assert copies["slot_copies"] > 0 and copies["slot_copies_windowed"] > 0
+
+
+def test_more_than_one_window_size_is_refused_by_name():
+    from deepspeed_tpu.inference.v2.kv_cache import (mixes_layer_kinds,
+                                                     one_window)
+    assert one_window((None, 6, 6)) == 6 and one_window((None, None)) is None
+    assert one_window(()) is None and not mixes_layer_kinds((6, 6))
+    for ask in (one_window, mixes_layer_kinds):
+        with pytest.raises(ValueError, match="more than one window size"):
+            ask((None, 6, 8))
+    with pytest.raises(ValueError, match="more than one window size"):
+        BlockedKVCache(KVCacheConfig(
+            num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+            num_blocks=NB, dtype=jnp.float32, layer_windows=(None, 6, 8),
+            window_blocks=7))

@@ -186,6 +186,51 @@ def test_paged_kernel_fits_the_scoped_vmem_at_the_tallest_folds(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+# MiMo-V2.5's folds as its pools store them (a key's 192 values in a row of
+# 256 lanes, values of 128; 4 KV heads in a full layer, 8 in a windowed one;
+# 64 query heads; a 390-block table, and behind a window of 128 a table of 67
+# blocks for a 4,096-token chunk and of 3 for a decode row): batch, chunk
+# tokens, KV heads, table blocks, window, sinks; then the step's (rows, pages,
+# heads).
+_SPLIT_FOLDS = {
+    "mimo-full-chunk": (1, 4096, 4, 390, None, False, (1024, 8, 1)),
+    "mimo-windowed-chunk-sinks": (1, 4096, 8, 67, 128, True, (1024, 8, 1)),
+    "mimo-full-decode-32": (32, 1, 4, 390, None, False, (16, 8, 4)),
+    "mimo-windowed-decode-32-sinks": (32, 1, 8, 3, 128, True, (8, 4, 8)),
+}
+
+
+@pytest.mark.parametrize("fold", sorted(_SPLIT_FOLDS))
+def test_paged_kernel_with_keys_wider_than_values_compiles_for_a_v5e(one_chip,
+                                                                     fold):
+    """K rows of 256 lanes beside V rows of 128 in pools of their own, the
+    softmax scaled for a key of 192, a sink a query head where the layer has
+    them: the tile ``_tile`` chooses by its count of both widths compiles
+    inside the default scoped VMEM, every KV head of a decode fold in one
+    step."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    b, t, hkv, mb, window, sunk, tile = _SPLIT_FOLDS[fold]
+    assert pa._tile(64 // hkv * t, mb, BLOCK, 256, 2, hkv, 128) == tile
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    blocks = 12481 if window is None else 161
+    compiled = jax.jit(
+        lambda q, k, v, sinks, tables, start: pa.paged_attention_pool(
+            q, (k, v), 1, tables, start, window=window, sinks=sinks,
+            scale=192 ** -0.5)).lower(
+        on_chip((b, t, 64, 256)), on_chip((2, hkv, blocks, BLOCK, 256)),
+        on_chip((2, hkv, blocks, BLOCK, 128)),
+        on_chip((64,), jnp.float32) if sunk else None,
+        on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    # the pools go in whole, once a slot; what is made is a chunk's q folded
+    # by KV head (134 MB) and its output before and after the unfold (67 MB
+    # each), never a full pool (2 layers here: 3.3 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20
+
+
 @pytest.fixture
 def as_on_a_tpu(monkeypatch):
     """The expert layers take the Pallas grouped matmul where the backend is
