@@ -642,9 +642,13 @@ class InferenceEngineV2:
                 seq.in_flight += 1
                 seq.token_on_device = True
                 rec.chunks[-1] = (seq, chunk.start, chunk.length, sampled)
+            # ... and the keys of the tiles the paged kernel multiplied the
+            # chunk's rows by and the page copies a layer's call issued
             d.chunk_marks.append((c0, time.monotonic(), dict(
                 uid=seq.uid, tokens=chunk.length, bucket=chunk.bucket,
-                start=chunk.start)))
+                start=chunk.start, **(self.kv.chunk_tile_keys(
+                    chunk.start, chunk.bucket, mb, self._window)
+                    if tracer.enabled else {}))))
         if plan.prefill_chunks:
             d.t_prefill = time.monotonic() - d.prefill_t0
 
@@ -716,7 +720,7 @@ class InferenceEngineV2:
                     ctx_tokens_windowed=sum(min(c, window) for c in contexts)
                     if window else whole, ctx_blocks=mb,
                     **self.kv.decode_tile_keys(contexts, mb, window),
-                    **self.kv.decode_slot_copies(b, mb))
+                    **self.kv.decode_slot_copies(contexts, b, mb, window))
         return d
 
     def _device_ran_dry(self) -> Optional[int]:

@@ -29,7 +29,7 @@ the context length can never satisfy qpos >= kpos.
 
 import dataclasses
 from functools import partial
-from typing import List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,8 +37,8 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.ops.pallas.paged_attention import (
-    decode_slot_copies, decode_tile_keys, paged_attention_pool,
-    paged_attention_reference)
+    chunk_tile_keys, decode_slot_copies, decode_tile_keys,
+    paged_attention_pool, paged_attention_reference)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +80,9 @@ class KVCacheConfig:
     # of layer differ in KV heads, or keys and values in width; None: both
     # kinds hold ``num_kv_heads`` x ``head_dim`` keys and values
     kind_pages: Optional[dict] = None
+    # query heads a layer, or ``{"full": n, "window": m}``; None: as many as
+    # KV heads (``modules.KVCacheSpec.query_heads``)
+    query_heads: Any = None
 
     @property
     def page_shape(self) -> HeadPageShape:
@@ -186,7 +189,8 @@ class BlockedKVCache:
             num_blocks=num_blocks, dtype=dtypes[kv_cache_dtype],
             latent_dim=spec.latent_dim,
             layer_windows=tuple(spec.layer_windows or ()),
-            window_blocks=window_blocks, kind_pages=spec.kind_pages))
+            window_blocks=window_blocks, kind_pages=spec.kind_pages,
+            query_heads=spec.query_heads))
 
     # ------------------------------------------------------------------
     # pages by layer kind: the windowed pool's host side
@@ -273,8 +277,35 @@ class BlockedKVCache:
         table[:len(live)] = live
         return table
 
+    def _fold_of(self, kind: str) -> dict:
+        """What the paged kernel's tile follows in a layer of ``kind``
+        (``"full"``, ``"window"``), under the names the kernel's own counts
+        take: its KV heads, the query heads it folds over each, the block,
+        the rows' widths and the item size."""
+        cfg = self.cfg
+        at = self.kind.stored(kind, cfg) if self.two_kinds else cfg.page_shape
+        heads = cfg.query_heads
+        if isinstance(heads, dict):
+            heads = heads[kind]
+        return dict(hkv=at.num_kv_heads, d=at.key_dim, dv=at.value_dim,
+                    group=max((heads or 0) // at.num_kv_heads, 1),
+                    bs=cfg.block_size, itemsize=jnp.dtype(cfg.dtype).itemsize)
+
+    def _behind_window(self, positions, rows: int, table_blocks: int):
+        """``(positions, table blocks)`` as a layer behind the window is
+        handed them for a step of ``rows`` queries a sequence from
+        ``positions``: over one pool the full table's; over pages by kind
+        the windowed kind's own table, which starts at the block the
+        position's window starts in and is as long as the window and the
+        rows."""
+        if not self.two_kinds:
+            return list(positions), table_blocks
+        window, bs = self.kind.window, self.cfg.block_size
+        return [p - int(blocks_behind_window(p, window, bs)) * bs
+                for p in positions], windowed_table_blocks(rows, window, bs)
+
     def decode_tile_keys(self, contexts, table_blocks: int, window) -> dict:
-        """Keys the paged kernel's live steps cover for a decode batch of
+        """Keys the paged kernel's tiles cover for a decode batch of
         ``contexts`` tokens a sequence over full tables of ``table_blocks``:
         ``tile_keys`` with every context read whole, ``tile_keys_windowed``
         with the layers that have a ``window`` reading theirs (a windowed
@@ -284,42 +315,55 @@ class BlockedKVCache:
         bs = self.cfg.block_size
         if self.cfg.latent_dim:
             return {}
-        whole = decode_tile_keys(contexts, table_blocks, bs)
-        if window is None:
-            windowed = whole
-        elif self.two_kinds:
-            behind = [int(blocks_behind_window(c - 1, window, bs)) * bs
-                      for c in contexts]
-            windowed = decode_tile_keys(
-                [c - b for c, b in zip(contexts, behind)],
-                self.window_steady_blocks, bs, window)
-        else:
-            windowed = decode_tile_keys(contexts, table_blocks, bs, window)
+        whole = windowed = decode_tile_keys(contexts, table_blocks, bs)
+        if window is not None:
+            last, mb = self._behind_window([c - 1 for c in contexts], 1,
+                                           table_blocks)
+            windowed = decode_tile_keys([p + 1 for p in last], mb, bs, window)
         return {"tile_keys": whole, "tile_keys_windowed": windowed}
 
-    def decode_slot_copies(self, bucket: int, table_blocks: int) -> dict:
-        """Slot copies one layer's paged kernel call issues for a decode
-        batch padded to ``bucket`` over full tables of ``table_blocks``:
-        ``slot_copies`` a call of a layer that walks the full table (every
-        layer of a pool of one kind, behind a window too), and
-        ``slot_copies_windowed`` a call of a windowed kind's layer over its
-        own table, each from the heads and the row widths of the kind's own
-        pages. Empty over a latent pool."""
-        cfg = self.cfg
-        if cfg.latent_dim:
+    def decode_slot_copies(self, contexts, bucket: int, table_blocks: int,
+                           window) -> dict:
+        """Page copies one layer's paged kernel call issues for a decode
+        batch of ``contexts`` padded to ``bucket`` over full tables of
+        ``table_blocks``: ``slot_copies`` a call of a layer that walks the
+        full table, and ``slot_copies_windowed`` a call of a layer behind
+        ``window`` (a windowed kind's over its own table), each from the
+        heads and the row widths of the kind's own pages. Empty over a
+        latent pool."""
+        if self.cfg.latent_dim:
             return {}
+        whole = windowed = decode_slot_copies(
+            contexts, bucket, mb=table_blocks, **self._fold_of("full"))
+        if window is not None:
+            last, mb = self._behind_window([c - 1 for c in contexts], 1,
+                                           table_blocks)
+            windowed = decode_slot_copies(
+                [p + 1 for p in last], bucket, mb=mb, window=window,
+                **self._fold_of("window"))
+        return {"slot_copies": whole, "slot_copies_windowed": windowed}
 
-        def copies(mb, kind):
-            at = self.kind.stored(kind, cfg) if self.two_kinds else \
-                cfg.page_shape
-            return decode_slot_copies(
-                bucket, at.num_kv_heads, mb, cfg.block_size, at.key_dim,
-                jnp.dtype(cfg.dtype).itemsize, dv=at.value_dim)
-        whole = copies(table_blocks, "full")
-        return {"slot_copies": whole,
-                "slot_copies_windowed":
-                copies(self.window_steady_blocks, "window")
-                if self.two_kinds else whole}
+    def chunk_tile_keys(self, start: int, bucket: int, table_blocks: int,
+                        window) -> dict:
+        """What the paged kernel reads for one chunk padded to ``bucket``
+        rows at positions ``start ..`` over a full table of
+        ``table_blocks``: ``tile_keys``, the keys of the tiles a query row
+        is multiplied by, summed over the chunk's rows, in a layer that
+        reads the context whole, and ``tile_keys_windowed`` in a layer
+        behind ``window`` (the visible pairs over each is the tiles' fill);
+        ``tile_copies``, the K and V page copies a call of the first issues
+        for all its heads. Empty over a latent pool."""
+        if self.cfg.latent_dim:
+            return {}
+        whole, copies = chunk_tile_keys(start, bucket, mb=table_blocks,
+                                        **self._fold_of("full"))
+        windowed = whole
+        if window is not None:
+            (first,), mb = self._behind_window([start], bucket, table_blocks)
+            windowed, _ = chunk_tile_keys(first, bucket, mb=mb, window=window,
+                                          **self._fold_of("window"))
+        return {"tile_keys": whole, "tile_keys_windowed": windowed,
+                "tile_copies": copies}
 
     def pages_held(self) -> dict:
         """Blocks sequences hold now, by kind, and their bytes, each kind's

@@ -76,6 +76,11 @@ class KVCacheSpec:
     # not as wide as a value. None: both kinds hold ``num_kv_heads`` x
     # ``head_dim``
     kind_pages: Any = None
+    # query heads a layer (``{"full": n, "window": m}`` where the kinds
+    # differ): the paged kernel folds the query heads of a KV head into its
+    # rows, so the cache's count of a call's tiles and copies needs them.
+    # None: as many as KV heads
+    query_heads: Any = None
 
 
 def register_policy(name: str, config_type: type):
@@ -143,7 +148,8 @@ class LlamaPolicy:
     @staticmethod
     def cache_spec(cfg: LlamaConfig) -> KVCacheSpec:
         return KVCacheSpec(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_,
-                           cfg.max_seq_len, cfg.dtype, cfg.sliding_window)
+                           cfg.max_seq_len, cfg.dtype, cfg.sliding_window,
+                           query_heads=cfg.num_heads)
 
     @staticmethod
     def _norm_scale(scale, cfg):
@@ -204,7 +210,8 @@ class FalconPolicy:
     @staticmethod
     def cache_spec(cfg: FalconConfig) -> KVCacheSpec:
         return KVCacheSpec(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_,
-                           cfg.max_seq_len, cfg.dtype, None)
+                           cfg.max_seq_len, cfg.dtype, None,
+                           query_heads=cfg.num_heads)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -386,7 +393,8 @@ class MixtralPolicy:
     def cache_spec(cfg: MixtralConfig) -> KVCacheSpec:
         b = cfg.base
         return KVCacheSpec(b.num_layers, b.num_kv_heads, b.head_dim_,
-                           b.max_seq_len, b.dtype, b.sliding_window)
+                           b.max_seq_len, b.dtype, b.sliding_window,
+                           query_heads=b.num_heads)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -605,7 +613,8 @@ class Qwen2MoEPolicy:
     def cache_spec(cfg: Qwen2MoEConfig) -> KVCacheSpec:
         b = cfg.base
         return KVCacheSpec(b.num_layers, b.num_kv_heads, b.head_dim_,
-                           b.max_seq_len, b.dtype, b.sliding_window)
+                           b.max_seq_len, b.dtype, b.sliding_window,
+                           query_heads=b.num_heads)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -663,7 +672,8 @@ class Gemma2Policy:
     @staticmethod
     def cache_spec(cfg: Gemma2Config) -> KVCacheSpec:
         return KVCacheSpec(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim,
-                           cfg.max_seq_len, cfg.dtype, None)
+                           cfg.max_seq_len, cfg.dtype, None,
+                           query_heads=cfg.num_heads)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -845,10 +855,13 @@ class LagunaPolicy:
 
     @staticmethod
     def cache_spec(cfg) -> KVCacheSpec:
+        heads = dict(zip(cfg.layer_types, cfg.heads_per_layer))
         return KVCacheSpec(
             cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.max_seq_len,
             cfg.dtype, None,
-            layer_windows=tuple(cfg.window(i) for i in range(cfg.num_layers)))
+            layer_windows=tuple(cfg.window(i) for i in range(cfg.num_layers)),
+            query_heads={"full": heads.get(_laguna.FULL),
+                         "window": heads.get(_laguna.SLIDING)})
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -926,7 +939,9 @@ class MiMoV2Policy:
             cfg.num_layers, cfg.full.num_kv_heads, cfg.full.head_dim,
             cfg.max_seq_len, cfg.dtype, None,
             layer_windows=tuple(cfg.window(i) for i in range(cfg.num_layers)),
-            kind_pages=kinds)
+            kind_pages=kinds,
+            query_heads={"full": cfg.full.num_heads,
+                         "window": cfg.swa.num_heads})
 
     @staticmethod
     def embed(params, tokens, positions, cfg):

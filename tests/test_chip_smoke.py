@@ -261,7 +261,7 @@ def test_paged_kernel_cuts_a_tall_fold_into_row_blocks():
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     rng = np.random.default_rng(0)
     hkv, d, bs, nb, t, mb = 1, 128, 64, 40, 1024, 32
-    assert 4 * t * d > pa._MAX_FOLD_ELEMS
+    assert pa._tile(4 * t, mb, bs, d, 4, hkv)[0] == pa._MAX_ROWS < 4 * t
     kp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(1, t, 4, d)), jnp.float32)

@@ -443,8 +443,16 @@ def test_counts_of_held_and_absent_rows_ride_on_the_spans(half):
     kv = eng.kv
     assert all(
         {k: d[k] for k in ("slot_copies", "slot_copies_windowed")}
-        == kv.decode_slot_copies(d["bucket"], d["ctx_blocks"])
+        == kv.decode_slot_copies([d["ctx_tokens"]], d["bucket"],
+                                 d["ctx_blocks"], 24)
         for d in decodes)
+    # a chunk's span says what the kernel multiplied and copied for it
+    assert all(
+        {k: c[k] for k in ("tile_keys", "tile_keys_windowed", "tile_copies")}
+        == kv.chunk_tile_keys(c["start"], c["bucket"],
+                              eng._ctx_bucket_blocks(c["start"] + c["tokens"]),
+                              24)
+        and c["tile_keys_windowed"] > 0 for c in chunks)
     assert all(0 < d["slot_copies_windowed"] <= d["slot_copies"]
                for d in decodes)
     pages = [e for e in events if e[1] == "serve/kv_pages"]

@@ -295,23 +295,41 @@ def test_the_served_expert_layer_runs_over_its_held_share(share):
 # --- the kernel at widths of its own, with sinks -------------------------------
 
 @pytest.mark.parametrize("case", [
-    #  batch, chunk, heads, kv heads, table blocks, window, sinks
-    pytest.param((3, 1, 8, 2, 6, None, False), id="decode-fold-all-heads"),
-    pytest.param((3, 1, 8, 2, 6, 20, True), id="decode-windowed-sinks"),
-    pytest.param((1, 40, 4, 1, 8, None, True), id="chunk-sinks"),
-    pytest.param((1, 40, 8, 2, 8, 20, True), id="chunk-windowed-sinks"),
-    pytest.param((1, 300, 4, 2, 40, 24, True), id="chunk-row-blocks-sinks")])
+    #  batch, chunk, heads, kv heads, table blocks, window, sinks, tile
+    pytest.param((3, 1, 8, 2, 6, None, False, (8, 8, 2)),
+                 id="decode-fold-all-heads"),
+    pytest.param((3, 1, 8, 2, 6, 20, True, (8, 8, 2)),
+                 id="decode-windowed-sinks"),
+    pytest.param((1, 40, 4, 1, 8, None, True, (160, 8, 1)), id="chunk-sinks"),
+    pytest.param((1, 40, 8, 2, 8, 20, True, (160, 8, 2)),
+                 id="chunk-windowed-sinks"),
+    # folds cut into row blocks at these toy widths (the rule's sizes
+    # scaled down): a full fold in blocks of 64 rows that span heads over
+    # tiles of 16 pages, the first blocks' horizon tiles before the table's
+    # end; and behind a window blocks of 32 rows inside one q head, each over
+    # the ONE tile of 8 pages that holds what its rows see, from a table
+    # entry of its own (the live span of every block but the first begins
+    # past entry 0)
+    pytest.param((1, 300, 4, 2, 40, None, True, (64, 16, 1)),
+                 id="chunk-row-blocks-sinks"),
+    pytest.param((1, 256, 4, 2, 40, 24, True, (32, 8, 1)),
+                 id="chunk-windowed-row-blocks-sinks")])
 def test_paged_kernel_with_unequal_widths_and_sinks_is_the_reference(
         case, monkeypatch):
-    """Keys of 128 lanes beside values of 256 and of 128 beside 128 in K and V
-    pools of their own, a sink a query head in the denominator, a softmax
-    scale that is not the stored width's; decode folds that take every KV
-    head in a step and chunks cut into row blocks."""
-    b, t, h, hkv, mb, window, sunk = case
-    if t == 300:
-        # a fold taller than one row block at these toy widths
-        monkeypatch.setattr(pa, "_MAX_FOLD_ELEMS", 64 * 128)
+    """Keys of 128 lanes beside values of 256 (and, behind the window's row
+    blocks, of 256 beside 128 as MiMo stores them) in K and V pools of their
+    own, a sink a query head in the denominator, a softmax scale that is not
+    the stored width's; decode folds that take every KV head in a step and
+    chunks cut into row blocks."""
+    b, t, h, hkv, mb, window, sunk, tile = case
     bs, nb, dk, dv = 8, 48, 128, 256
+    if t >= 256:
+        monkeypatch.setattr(pa, "_DEFAULT_VMEM_BYTES", 0)
+        monkeypatch.setattr(pa, "_MAX_ROWS", 64)
+        monkeypatch.setattr(pa, "_WINDOW_ROWS", 32)
+        if window:
+            dk, dv = 256, 128
+    assert pa._tile(h // hkv * t, mb, bs, dk, 4, hkv, dv, window) == tile
     key = jax.random.split(jax.random.PRNGKey(b * 100 + t + h), 5)
     q = jax.random.normal(key[0], (b, t, h, dk))
     q = q.at[..., 96:].set(0.0)            # a key of 96 in a row of 128
@@ -361,22 +379,28 @@ def test_the_pool_pair_is_read_at_the_layers_own_heads():
 
 
 def test_tile_counts_both_widths():
-    # square heads: the count of PR 36 and PR 40 to the letter
-    assert pa._tile(6 * 4096, 260, 64, 128, 2) == (2048, 8, 1)
+    # square heads and MiMo's as stored (a key's row of 256, values of 128)
+    # take the same steps: a full chunk 2,048 rows over 16 pages, which the
+    # wider key no longer halves (the call asks for the VMEM its tile counts
+    # to: 29.4 MiB); a decode fold every KV head of its kind in one step
+    assert pa._tile(6 * 4096, 260, 64, 128, 2, 8) == (2048, 16, 1)
+    assert pa._tile(16 * 4096, 390, 64, 256, 2, 4, 128) == (2048, 16, 1)
+    assert pa._vmem_limit(2048, 16, 1, 64, 256, 128, 2) == 30801920
     assert pa._tile(8, 260, 64, 128, 2, 8) == (8, 8, 8)
     assert pa._tile(8, 260, 64, 128, 2, 8, 128) == (8, 8, 8)
-    # the published MiMo shapes as stored (a key's row of 256, values of
-    # 128): a chunk's fold of 16 x 4,096 rows in blocks of 1,024, a decode
-    # fold with every KV head of its kind in one step
-    assert pa._tile(16 * 4096, 390, 64, 256, 2, 4, 128) == (1024, 8, 1)
-    assert pa._tile(8 * 4096, 67, 64, 256, 2, 8, 128) == (1024, 8, 1)
     assert pa._tile(16, 390, 64, 256, 2, 4, 128) == (16, 8, 4)
-    assert pa._tile(8, 3, 64, 256, 2, 8, 128) == (8, 4, 8)
+    assert pa._tile(8, 3, 64, 256, 2, 8, 128, 128) == (8, 4, 8)
+    # behind the window of 128 a chunk's tile does not widen with the full
+    # fold's: 256 rows over the one tile of 8 pages that holds the 383 keys
+    # they see wherever in a page the first falls
+    assert pa._tile(8 * 4096, 67, 64, 256, 2, 8, 128, 128) == (256, 8, 1)
     # wider values leave fewer rows beside the same key tile
-    assert pa._tile(10 ** 6, 64, 64, 128, 2, 1, 512)[0] \
-        < pa._tile(10 ** 6, 64, 64, 128, 2, 1, 128)[0]
-    assert pa.decode_slot_copies(32, 4, 390, 64, 256, 2, dv=128) \
-        == 32 * 1 * 49 * 8 * 2
+    assert pa._vmem_bytes(2048, 16, 1, 64, 128, 512, 2) \
+        > pa._vmem_bytes(2048, 16, 1, 64, 128, 128, 2)
+    # a decode call copies a K and a V page a live table entry for all four
+    # heads at once: contexts of 24,960 and 100, and 30 padding rows' one
+    assert pa.decode_slot_copies([24960, 100], 32, 4, 390, 64, 256, 2, 16,
+                                 128) == 2 * (390 + 2 + 30)
 
 
 # --- the model and the served path against the reference ----------------------
@@ -515,8 +539,16 @@ def test_bytes_by_kind_and_counts_ride_on_the_tick(share):
     kv = eng.kv
     assert all(
         {k: d[k] for k in ("slot_copies", "slot_copies_windowed")}
-        == kv.decode_slot_copies(d["bucket"], d["ctx_blocks"])
+        == kv.decode_slot_copies([d["ctx_tokens"]], d["bucket"],
+                                 d["ctx_blocks"], 24)
         for d in decodes)
+    # a chunk's span says what the kernel multiplied and copied for it
+    assert all(
+        {k: c[k] for k in ("tile_keys", "tile_keys_windowed", "tile_copies")}
+        == kv.chunk_tile_keys(c["start"], c["bucket"],
+                              eng._ctx_bucket_blocks(c["start"] + c["tokens"]),
+                              24)
+        and c["tile_keys_windowed"] > 0 for c in chunks)
     # a block of each kind, from its own heads and its rows as stored: 2
     # full layers x 1 head and 3 windowed x 2, K and V rows of 128 lanes
     assert kv._block_bytes == {"full": 2 * 1 * BLOCK * 256 * 4,

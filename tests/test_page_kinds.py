@@ -337,7 +337,7 @@ def test_a_kind_states_its_own_heads_and_widths(shapes):
     assert held["window_bytes"] == 2 * kv._block_bytes["window"]
     assert held["held_bytes"] == held["full_bytes"] + held["window_bytes"]
     # a decode call's slot copies follow the kind's own heads and widths
-    copies = kv.decode_slot_copies(4, 8)
+    copies = kv.decode_slot_copies([30, 9], 4, 8, kv.kind.window)
     assert copies["slot_copies"] > 0 and copies["slot_copies_windowed"] > 0
 
 

@@ -217,14 +217,20 @@ def test_paged_attention_over_the_whole_pool(layer, pages):
 # and two KV heads unless said: a tile is 8 pages = 128 keys, or the table
 # where that is shorter. ``starts``: the queries' first positions, a batch row
 # each; ``tile``: the (rows, pages, heads) the kernel's rule has to choose for
-# the case to test what it says: a fold that is one row block takes a table
-# entry's page of every KV head in one copy (PR 40), a fold cut into row
-# blocks one head.
+# the case to test what it says: a short fold takes a table entry's page of
+# every KV head in one copy (PR 40), any other one head over up to 16 pages
+# (PR 42). ``small``: the rule's sizes scaled down to these toy shapes (row
+# blocks of 64, behind a narrow window of 32, no fold short enough for every
+# head), so that a chunk of 256 tokens is cut as one of 4,096 is on the chip.
+# ``nan_past``: every page no context reaches holds NaN, the trash block too.
+_SMALL = {"_DEFAULT_VMEM_BYTES": 0, "_MAX_ROWS": 64, "_WINDOW_ROWS": 32}
+
+
 def _case(b, t, h, mb, starts, tile, window=None, softcap=None, fp8=False,
-          d=32, hkv=2, trash=True):
+          d=32, hkv=2, trash=True, small=False, nan_past=False):
     return dict(b=b, t=t, h=h, mb=mb, starts=starts, tile=tile,
                 window=window, softcap=softcap, fp8=fp8, d=d, hkv=hkv,
-                trash=trash)
+                trash=trash, small=small, nan_past=nan_past)
 
 
 _JOINED_TILE_CASES = {
@@ -249,12 +255,38 @@ _JOINED_TILE_CASES = {
     "chunk-window-over-tiles": _case(1, 72, 4, 20, [230], (144, 8, 2),
                                      window=50),
     # a fold cut into row blocks (heads of 128: 2,048 rows a block, one q
-    # head's whole chunk each) behind a window narrower than a block
-    "chunk-cut-window-under-rows": _case(1, 2048, 4, 132, [50], (2048, 8, 1),
+    # head's whole chunk each) behind a window narrower than a block and
+    # wider than a tile of 16 pages
+    "chunk-cut-window-under-rows": _case(1, 2048, 4, 132, [50], (2048, 16, 1),
                                          window=300, d=128),
     # ... and cut into blocks that span heads, from position 0
-    "chunk-cut-over-heads": _case(1, 512, 16, 32, [0], (2048, 8, 1),
-                                  window=200, d=128),
+    "chunk-cut-over-heads": _case(1, 512, 16, 32, [0], (2048, 16, 1),
+                                  window=300, d=128),
+    # a chunk that starts inside a block and inside a tile, cut into four row
+    # blocks a q head over a table (40 blocks) that is no whole number of
+    # tiles (16 pages): the first blocks stop at their own horizon, tiles
+    # before the chunk's end
+    "chunk-cut-first-blocks-stop-early": _case(1, 256, 4, 40, [300],
+                                               (64, 16, 1), small=True),
+    # ... and behind a narrow window each row block reads ONE tile, from the
+    # table entry its own window starts in (entries 15 to 29, never 0)
+    "chunk-cut-window-tile-of-its-own": _case(1, 256, 4, 40, [300],
+                                              (32, 8, 1), window=50,
+                                              small=True),
+    "chunk-cut-window-softcap-fp8": _case(1, 256, 4, 40, [300], (32, 8, 1),
+                                          window=50, softcap=20.0, fp8=True,
+                                          small=True),
+    # pages that no context reaches hold NaN: a tile that is skipped, a page
+    # of a live tile that is not copied, a padding row and a context of one
+    # token (its table all trash but entry 0) leak nothing
+    "nan-past-decode-dead-rows": _case(4, 1, 8, 20, [300, 0, 0, 170],
+                                       (8, 8, 2), nan_past=True),
+    "nan-past-heads-8-window": _case(3, 1, 32, 20, [300, 0, 319], (8, 8, 8),
+                                     hkv=8, window=100, nan_past=True),
+    "nan-past-chunk-cut": _case(1, 256, 4, 40, [300], (64, 16, 1),
+                                small=True, nan_past=True),
+    "nan-past-chunk-cut-window": _case(1, 256, 4, 40, [300], (32, 8, 1),
+                                       window=50, small=True, nan_past=True),
     # fp8 pages: every slot of a tile under its own (head, page) scale
     "fp8-scales-a-slot": _case(3, 1, 8, 20, [300, 0, 319], (8, 8, 2),
                                fp8=True),
@@ -290,29 +322,32 @@ _JOINED_TILE_CASES = {
     # are reckoned by the same remainder under every head
     "heads-8-short-chunk-window": _case(1, 24, 16, 20, [270], (48, 8, 8),
                                         hkv=8, window=50),
-    # a chunk cut into row blocks still takes one KV head a step, whatever
-    # the heads: the chunk programs of the cells are the parent's
+    # a chunk cut into row blocks takes one KV head a step, whatever the
+    # heads
     "chunk-cut-of-8-heads-takes-one": _case(1, 1024, 32, 80, [200],
-                                            (2048, 8, 1), hkv=8, d=128),
+                                            (2048, 16, 1), hkv=8, d=128),
 }
 
 
 @pytest.mark.parametrize("name", sorted(_JOINED_TILE_CASES))
-def test_paged_attention_joined_key_tile(name):
-    """The kernel's grid step joins several pages into one key tile: against
-    the gather reference where the table is no whole number of tiles, where
-    contexts and windows end inside a tile, at folds of 8 and 16 rows, where
-    the fold is cut into row blocks taller than the window, with fp8 slots
-    under different scales, with softcap; and where a step takes a table
-    entry's page of every KV head at once (8, 4 and 2 heads, groups of 4, 6
-    and 9, each head's page under its own scale), while a fold cut into row
-    blocks takes one."""
+def test_paged_attention_joined_key_tile(name, monkeypatch):
+    """The kernel copies several pages of the table into one key tile a
+    step: against the gather reference where the table is no whole number of
+    tiles, where contexts and windows end inside a tile, at folds of 8 and 16
+    rows, where the fold is cut into row blocks (under a window wider than a
+    tile, and under a narrow one that gives each block a tile of its own),
+    with fp8 pages under different scales, with softcap; where a step takes a
+    table entry's page of every KV head at once (8, 4 and 2 heads, groups of
+    4, 6 and 9, each head's page under its own scale); and where every page
+    the kernel has no business reading holds NaN."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     c = _JOINED_TILE_CASES[name]
     b, t, h, mb, d, hkv = c["b"], c["t"], c["h"], c["mb"], c["d"], c["hkv"]
     nb, bs = 192, 16
-    assert pa._tile(h // hkv * t, mb, bs, d, 1 if c["fp8"] else 4, hkv) \
-        == c["tile"]
+    for size, value in (_SMALL if c["small"] else {}).items():
+        monkeypatch.setattr(pa, size, value)
+    assert pa._tile(h // hkv * t, mb, bs, d, 1 if c["fp8"] else 4, hkv,
+                    window=c["window"]) == c["tile"]
     rng = np.random.default_rng(sorted(_JOINED_TILE_CASES).index(name))
     kp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(hkv, nb, bs, d)), jnp.float32)
@@ -335,55 +370,93 @@ def test_paged_attention_joined_key_tile(name):
     tables = jnp.asarray(tables)
     start = jnp.asarray(c["starts"], jnp.int32)
     how = dict(window=c["window"], softcap=c["softcap"], **scales)
-    out = pa.paged_attention(q, kp, vp, tables, start, interpret=True, **how)
     ref = pa.paged_attention_reference(q, kp, vp, tables, start, **how)
+    if c["nan_past"]:
+        unreached = jnp.asarray(np.append(free, nb - 1))
+        kp, vp = kp.at[:, unreached].set(jnp.nan), \
+            vp.at[:, unreached].set(jnp.nan)
+    out = pa.paged_attention(q, kp, vp, tables, start, interpret=True, **how)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_tile_follows_the_fold_and_the_table():
-    """``_tile`` at the served shapes (blocks of 64, heads of 128, bfloat16,
-    eight KV heads): the key tile is 8 pages or the table, a decode fold is
-    its own rows, a tall fold is cut where the scoped VMEM ends beside the
-    tile (2,048 rows beside 8 pages; 1,024 beside the 16 that were swept), by
-    powers of two; a fold that is one row block takes every KV head a step
-    where that many fit by the same count (every decode fold of the cells),
-    a fold cut into row blocks one (every chunk of the cells)."""
+def test_paged_tile_follows_the_fold_the_table_and_the_window():
+    """``_tile`` at the served shapes (blocks of 64, bfloat16, eight KV heads
+    of 128 unless said): a short fold (every decode fold) is its own rows
+    over 8 pages or the table and takes every KV head a step where that many
+    count to the default VMEM; any other takes one head, 2,048 rows over 16
+    pages or the table, whatever the width of its keys; behind a window
+    narrow enough for one tile of 16 pages its tile does not widen with the
+    full fold's: 256 rows over the smallest tile that holds all they see.
+    And the VMEM the call asks for is what its own tile counts to."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     shape = (64, 128, 2, 8)
-    assert pa._MAX_PAGES == 8
+    assert (pa._MAX_PAGES, pa._MAX_PAGES_CUT, pa._MAX_ROWS,
+            pa._WINDOW_ROWS) == (8, 16, 2048, 256)
     assert pa._tile(6, 260, *shape) == (8, 8, 8)        # Laguna, full decode
-    assert pa._tile(9, 9, *shape) == (16, 8, 8)         # ... sliding decode
+    assert pa._tile(9, 9, *shape, window=512) == (16, 8, 8)     # ... sliding
     assert pa._tile(4, 64, *shape) == (8, 8, 8)         # Mixtral's decode
-    assert pa._tile(4, 32, *shape) == (8, 8, 8)         # chat's decode
+    assert pa._tile(4, 32, *shape, window=4096) == (8, 8, 8)    # chat's
     assert pa._tile(4, 64, 64, 128, 1, 8) == (8, 8, 8)  # ... over fp8 pages
-    assert pa._tile(6 * 4096, 260, *shape) == (2048, 8, 1)
-    assert pa._tile(9 * 4096, 73, *shape) == (2048, 8, 1)
-    assert pa._tile(4 * 2048, 64, *shape) == (2048, 8, 1)   # Mixtral's chunk
-    # chat's chunks: one row block of 2,048 rows, and eight do not fit
-    assert pa._tile(4 * 512, 16, *shape) == (2048, 8, 1)
-    assert pa._tile(4 * 512, 8, *shape) == (2048, 8, 1)
     assert pa._tile(4, 4, *shape) == (8, 4, 8)   # a table shorter than a tile
     assert pa._tile(4, 5, *shape) == (8, 8, 8)
-    assert pa._tile(4 * 512, 16, 64, 128, 1) == (2048, 8, 1)     # fp8 pages
-    assert pa._tile(8 * 1024, 32, 64, 256, 2) == (1024, 8, 1)    # heads of 256
-    # the heads a step takes are all of them or one, and one KV head is the
-    # kernel as it was; a fold of one row block too tall for eight takes one
+    # chunks: Laguna's full, Mixtral's, chat's (one row block of 2,048 rows
+    # behind a window wider than any tile), fp8 pages, heads of 256, MiMo's
+    # keys of 256 lanes beside values of 128
+    assert pa._tile(6 * 4096, 260, *shape) == (2048, 16, 1)
+    assert pa._tile(4 * 2048, 64, *shape) == (2048, 16, 1)
+    assert pa._tile(4 * 512, 16, *shape, window=4096) == (2048, 16, 1)
+    assert pa._tile(4 * 512, 8, *shape, window=4096) == (2048, 8, 1)
+    assert pa._tile(4 * 512, 16, 64, 128, 1, 8) == (2048, 16, 1)
+    assert pa._tile(8 * 1024, 32, 64, 256, 2, 8) == (2048, 16, 1)
+    assert pa._tile(16 * 4096, 390, 64, 256, 2, 4, 128) == (2048, 16, 1)
+    # behind a window: Laguna's 512 needs 256 + 511 keys from wherever in a
+    # page, 13 pages, one tile of 16; MiMo's 128 one tile of 8; a window of
+    # 1,024 fits no tile beside 256 rows and walks the table as a full fold
+    assert pa._tile(9 * 4096, 73, *shape, window=512) == (256, 16, 1)
+    assert pa._tile(8 * 4096, 67, 64, 256, 2, 8, 128, 128) == (256, 8, 1)
+    assert pa._tile(8 * 512, 11, 64, 256, 2, 8, 128, 128) == (256, 8, 1)
+    assert pa._tile(4 * 4096, 128, *shape, window=1024) == (2048, 16, 1)
+    # every head a step up to the fold that counts to the default VMEM with
+    # eight of them (232 rows of 128: 15.97 MiB), one past it; one KV head is
+    # a step of one
     assert pa._tile(4, 64, 64, 128, 2) == (8, 8, 1)
     assert pa._tile(4, 64, 64, 128, 2, 2) == (8, 8, 2)
-    assert pa._tile(192, 64, *shape) == (192, 8, 8)
-    assert pa._tile(200, 64, *shape) == (200, 8, 1)
+    assert pa._tile(232, 64, *shape) == (232, 8, 8)
+    assert pa._tile(240, 64, *shape) == (240, 16, 1)
 
-    # the module's count of the scoped VMEM: what compiled and what did not
-    def counted(rows, pages, heads=1):
-        return heads * (rows * (128 * 12 + 1024) + 6 * pages * 64 * 128 * 2
-                        + 8 * rows * pages * 64)
-    assert counted(2048, 8) <= pa._SCOPED_VMEM_BYTES < counted(2048, 16)
-    assert counted(1024, 16) <= pa._SCOPED_VMEM_BYTES
-    assert counted(192, 8, 8) <= pa._SCOPED_VMEM_BYTES < counted(200, 8, 8)
-    # what the decode call's seconds divide by: code-mixed's full layer
-    assert pa.decode_slot_copies(32, 8, 260, 64, 128, 2, group=6) == 16896
-    assert pa.decode_slot_copies(32, 1, 260, 64, 128, 2) * 8 == 135168
+    # the module's count: a row's q and output twice, its float32
+    # accumulator, maximum and sum, a float32 score and probability a key; a
+    # key's K and V rows in each of the two buffers the kernel copies into
+    def counted(rows, pages, heads=1, d=128, dv=128):
+        return heads * (rows * (4 * (d + dv) + 4 * dv + 1024 + 8 * pages * 64)
+                        + 4 * pages * 64 * (d + dv))
+    for tile, widths in (((2048, 16, 1), (128, 128)),
+                         ((2048, 16, 1), (256, 128)),
+                         ((232, 8, 8), (128, 128)), ((256, 8, 1), (256, 128))):
+        count = pa._vmem_bytes(*tile, 64, *widths, 2)
+        assert count == counted(*tile, *widths) <= pa._SCOPED_VMEM_BYTES
+        assert pa._vmem_limit(*tile, 64, *widths, 2) \
+            == max(count + count // 4, 16 << 20) < 32 << 20
+    assert counted(232, 8, 8) <= pa._DEFAULT_VMEM_BYTES < counted(240, 8, 8)
+    # what a decode call's seconds divide by: code-mixed's full layer reads
+    # a K and a V page a live table entry for all eight heads at once, and
+    # a padding row's one; a head a step reads each eight times
+    contexts = [16384] * 8 + [3000] * 20
+    assert pa.decode_slot_copies(contexts, 32, 8, 260, 64, 128, 2, group=6) \
+        == 2 * (8 * 256 + 20 * 47 + 4)
+    assert pa.decode_slot_copies(contexts, 32, 1, 260, 64, 128, 2) * 8 \
+        == 16 * (8 * 256 + 20 * 47 + 4)
+    # a chunk of 4,096 at 8,192 in a full layer of six heads a KV head: two
+    # row blocks a head over 10 and 12 tiles of 1,024 keys (the visible pairs
+    # over it, 41,945,088, is the tiles' fill: 0.909), 160 + 192 entries a
+    # head; behind Laguna's window (its own table of 73 blocks from where the
+    # first query's window starts: position 511 of it) sixteen row blocks of
+    # 256 a head, each over its one tile of 1,024 keys
+    assert pa.chunk_tile_keys(8192, 4096, 6, 8, 192, 64, 128, 2) \
+        == (2048 * (10240 + 12288), 2 * (160 + 192) * 6 * 8)
+    assert pa.chunk_tile_keys(511, 4096, 9, 8, 73, 64, 128, 2,
+                              window=512)[0] == 4096 * 1024
 
 
 def test_quantized_psum_scatter(mesh_dp8):

@@ -190,10 +190,10 @@ def _decode_args(tracing, eng):
 
 def test_step_decode_counts_the_keys_of_the_tiles_the_kernel_read(
         params, tracing):
-    """``tile_keys`` beside ``ctx_tokens``: every context rounded out to the
-    key tiles of the paged kernel (several pages a grid step), so that the
-    tiles' fill can be read from a trace; the windowed one counts from the
-    tile that holds the window's first key."""
+    """``tile_keys`` beside ``ctx_tokens``: every context's live table
+    entries rounded out to the key tiles of the paged kernel (several pages a
+    step), so that the tiles' fill can be read from a trace; the windowed one
+    counts from the page that holds the window's first key."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     cfg, p = params
     eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32,
@@ -201,65 +201,109 @@ def test_step_decode_counts_the_keys_of_the_tiles_the_kernel_read(
     eng.put([1, 2, 3], [[1] * 5, [2] * 12, [3] * 20])
     contexts = [s.total_tokens for s in eng.state.decoding()]
     args = _decode_args(tracing, eng)
-    tile = 4 * pa._tile_pages(args["ctx_blocks"])
+    pages = pa._tile_pages(args["ctx_blocks"])
+    tile = 4 * pages
     assert args["ctx_tokens"] == sum(contexts) == 6 + 13 + 21
     assert args["tile_keys"] == sum(-(-c // tile) * tile for c in contexts)
     assert args["tile_keys"] >= args["ctx_tokens"]
-    # behind the window of 8 a context reads from the tile that holds key
-    # ``context - 8``
+    # behind the window of 8 a context reads from the page that holds key
+    # ``context - 8``, in whole tiles from there
     assert args["tile_keys_windowed"] == sum(
-        (-(-c // tile) - max(c - WINDOW, 0) // tile) * tile for c in contexts)
+        -(-(-(-c // 4) - max(c - WINDOW, 0) // 4) // pages) * tile
+        for c in contexts)
     assert args["ctx_tokens_windowed"] <= args["tile_keys_windowed"] \
         <= args["tile_keys"]
 
 
-def test_step_decode_counts_the_slot_copies_of_a_layers_call(params, tracing):
+def test_step_decode_counts_the_page_copies_of_a_layers_call(params, tracing):
     """``slot_copies`` beside ``tile_keys``: the K and V page copies one
-    layer's paged kernel call issued for the tick's decode batch, the
-    kernel's own grid times its slots (dead steps and padding rows too: a
-    copy costs what it costs). One kind of page: a windowed layer walks the
-    same table, so both counts are one."""
+    layer's paged kernel call issued for the tick's decode batch: one of each
+    a live table entry a step of the grid's head axis, a padding row's one
+    entry too, and nothing for what lies past a context. One kind of page: a
+    windowed layer walks the same table from the page its window starts in."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     cfg, p = params
     eng = InferenceEngineV2(p, cfg, V2EngineConfig(kv_num_blocks=32,
                                                    kv_block_size=4))
     eng.put([1, 2, 3], [[1] * 5, [2] * 12, [3] * 20])
+    contexts = [s.total_tokens for s in eng.state.decoding()]
     args = _decode_args(tracing, eng)
     kv = eng.kv.cfg
-    _, pages, heads = pa._tile(
+    _, _, heads = pa._tile(
         cfg.num_heads // kv.num_kv_heads, args["ctx_blocks"], kv.block_size,
         kv.head_dim, jax.numpy.dtype(kv.dtype).itemsize, kv.num_kv_heads)
     assert heads == kv.num_kv_heads > 1
-    grid = (args["bucket"], kv.num_kv_heads // heads, 1,
-            -(-args["ctx_blocks"] // pages))
-    assert args["slot_copies"] == grid[0] * grid[1] * grid[2] * grid[3] \
-        * pages * 2
-    assert args["slot_copies_windowed"] == args["slot_copies"]
-    assert args["slot_copies"] * kv.block_size >= 2 * args["tile_keys"]
+    padding = args["bucket"] - len(contexts)
+    assert args["slot_copies"] == 2 * (
+        sum(-(-c // 4) for c in contexts) + padding)
+    assert args["slot_copies_windowed"] == 2 * (
+        sum(-(-c // 4) - max(c - WINDOW, 0) // 4 for c in contexts) + padding)
+    assert args["slot_copies"] * kv.block_size <= 2 * args["tile_keys"] \
+        + 2 * padding * 4 * pa._tile_pages(args["ctx_blocks"])
 
 
-@pytest.mark.parametrize("case,kv_heads,windows,bucket,blocks,want", [
-    # code-mixed's decode: 32 rows over 260 blocks of the full kind, 33 steps
-    # of 8 slots for all eight heads at once; 9 blocks of the windowed kind
-    # are two steps
-    ("two kinds", 8, (None, 512), 32, 260, (16896, 32 * 2 * 8 * 2)),
+@pytest.mark.parametrize("case,kv_heads,windows,contexts,bucket,blocks,want", [
+    # code-mixed's decode: contexts of 16,400 and 300 in a bucket of four over
+    # 260 blocks of the full kind, all eight heads a copy: 257 + 5 entries and
+    # a padding row's one each; the windowed kind's own table holds the 9
+    # blocks (8 where the window starts on a page's edge) the window falls in
+    ("two kinds", 8, (None, 512), [16400, 300], 4, 260,
+     (2 * (257 + 5 + 2), 2 * (9 + 5 + 2))),
     # batch-rag's and chat's: one kind, every layer walks the full table
-    ("one kind", 8, (), 32, 64, (4096, 4096)),
-    ("one kind, a full bucket of chat's", 8, (), 16, 32, (1024, 1024)),
-    # one KV head is a copy a head a page, as every fold was before PR 40
-    ("one head", 1, (), 32, 260, (16896, 16896)),
-    ("a table shorter than a tile", 2, (), 4, 3, (4 * 4 * 2,) * 2),
+    ("one kind", 8, (), [3000, 64, 65], 4, 64, (2 * (47 + 1 + 2 + 1),) * 2),
+    # one KV head a step is a copy a head a page, as every fold was before
+    # PR 40
+    ("one head", 1, (), [16384, 300], 4, 260, (2 * (256 + 5 + 2),) * 2),
+    ("a table shorter than a tile", 2, (), [130], 4, 3, (2 * (3 + 3),) * 2),
 ])
-def test_decode_slot_copies_by_hand(case, kv_heads, windows, bucket, blocks,
-                                    want):
+def test_decode_slot_copies_by_hand(case, kv_heads, windows, contexts, bucket,
+                                    blocks, want):
     from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
-                                                     KVCacheConfig)
+                                                     KVCacheConfig,
+                                                     one_window)
     kv = BlockedKVCache(KVCacheConfig(
         num_layers=2, num_kv_heads=kv_heads, head_dim=128, block_size=64,
         num_blocks=4, layer_windows=windows,
         window_blocks=4 if windows else 0))
-    got = kv.decode_slot_copies(bucket, blocks)
+    got = kv.decode_slot_copies(contexts, bucket, blocks, one_window(windows))
     assert (got["slot_copies"], got["slot_copies_windowed"]) == want
+
+
+@pytest.mark.parametrize("case,config,chunk,want", [
+    # MiMo's pages: a chunk of 4,096 at 8,192 over 192 blocks. A full layer
+    # (4 KV heads of 16 query heads, keys stored in 256 lanes): two row
+    # blocks of 2,048 a head over 10 and 12 tiles of 1,024 keys, 160 + 192
+    # table entries a q head. A windowed layer (8 KV heads, window 128, its
+    # own table from block 126 on: the chunk starts at position 128 of it):
+    # sixteen row blocks of 256 a head, each over ONE tile of 512 keys
+    ("two kinds", dict(
+        num_kv_heads=4, head_dim=256, layer_windows=(None, 128),
+        window_blocks=4, query_heads={"full": 64, "window": 64},
+        kind_pages={"full": (4, 192, 128), "window": (8, 192, 128)}),
+     (8192, 4096, 192, 128),
+     (2048 * (10240 + 12288), 4096 * 512, 2 * (160 + 192) * 16 * 4)),
+    # chat's: one kind behind a window of 4,096, a chunk of 512 at 1,024 of
+    # four query heads a KV head is one row block that spans them, over the
+    # 24 live entries of its table in two tiles of 16 pages
+    ("one kind", dict(num_kv_heads=8, head_dim=128, query_heads=32),
+     (1024, 512, 32, 4096), (512 * 2048, 512 * 2048, 2 * 24 * 8)),
+])
+def test_chunk_tile_keys_by_hand(case, config, chunk, want):
+    """What rides on ``serve/prefill_chunk`` as ``tile_keys``,
+    ``tile_keys_windowed`` and ``tile_copies``."""
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     HeadPageShape,
+                                                     KVCacheConfig)
+    kinds = config.pop("kind_pages", None)
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=2, block_size=64, num_blocks=4,
+        kind_pages=kinds and {k: HeadPageShape(*v) for k, v in kinds.items()},
+        **config))
+    got = kv.chunk_tile_keys(*chunk)
+    assert (got["tile_keys"], got["tile_keys_windowed"],
+            got["tile_copies"]) == want
+    from benchmarks.harness.costs_latent import chunk_pairs
+    assert chunk_pairs(chunk[0], chunk[1]) <= got["tile_keys"]
 
 
 def test_decode_slot_copies_are_absent_over_a_latent_pool():
@@ -268,28 +312,31 @@ def test_decode_slot_copies_are_absent_over_a_latent_pool():
     latent = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=1,
                                           head_dim=64, latent_dim=48,
                                           num_blocks=4))
-    assert latent.decode_slot_copies(8, 4) == {}
+    assert latent.decode_slot_copies([100], 8, 4, None) == {}
+    assert latent.chunk_tile_keys(0, 64, 4, None) == {}
 
 
 @pytest.mark.parametrize("case,contexts,window,want", [
-    # blocks of 16 over a 32-block table: a tile is 8 pages = 128 keys
+    # blocks of 16 over a 96-block table: a tile is 8 pages = 128 keys
     ("whole tiles", [128, 256, 1280], None, (1664, 1664)),
     ("ends inside a tile", [1, 129, 300], None, (128 + 256 + 384,) * 2),
-    # window 100: context 300 reads keys 200-299 (tiles 1 and 2 of three),
-    # context 129 keys 29-128 (both its tiles), context 520 keys 420-519
-    # (tiles 3 and 4 of five), context 100 keys 0-99 (its one tile)
+    # window 100: context 300 reads keys 200-299, pages 12-18, one tile from
+    # page 12 on (tiles start at the first live page, not at a multiple of
+    # 8); context 129 keys 29-128, pages 1-8; context 520 keys 420-519, pages
+    # 26-32; context 100 keys 0-99, its one tile; a window of 100 keys that
+    # starts on a page's last key takes 8 pages, still one tile
     ("windowed", [300, 129, 520, 100], 100,
-     (384 + 256 + 640 + 128, 256 + 256 + 256 + 128)),
+     (384 + 256 + 640 + 128, 128 + 128 + 128 + 128)),
 ])
 def test_decode_tile_keys_by_hand(case, contexts, window, want):
     from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
                                                      KVCacheConfig)
     from deepspeed_tpu.ops.pallas import paged_attention as pa
-    assert pa._tile_pages(32) == 8
+    assert pa._tile_pages(96) == 8
     kv = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=2,
                                       head_dim=32, block_size=16,
                                       num_blocks=8, dtype=jax.numpy.float32))
-    got = kv.decode_tile_keys(contexts, 32, window)
+    got = kv.decode_tile_keys(contexts, 96, window)
     assert (got["tile_keys"], got["tile_keys_windowed"]) == want
     assert (got["tile_keys"] == sum(contexts)) == (case == "whole tiles")
 
@@ -298,7 +345,7 @@ def test_decode_tile_keys_of_a_windowed_kind_count_from_its_table():
     """Pages by layer kind: a windowed layer's table starts behind the window
     and is nine blocks long at window 512 over blocks of 64, so context 5,000
     reads keys 4,488-4,999 as positions 8-519 of a table that starts at
-    block 70: two tiles of 8 slots, 1,024 keys for the window's 512; a
+    block 70: nine pages, two tiles of 8, 1,024 keys for the window's 512; a
     context of 300 is one tile of 512 keys for either kind of layer."""
     from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
                                                      KVCacheConfig)

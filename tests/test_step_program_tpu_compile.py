@@ -129,60 +129,84 @@ def test_layers_of_one_kind_share_one_paged_kernel_body(one_chip):
     assert len(re.findall(r"call @_paged_call\b", text)) == CFG.num_layers
 
 
+def _kernel_operands(fn, *shapes) -> int:
+    """Operands of the one ``paged_attention`` call in ``fn``'s trace beside
+    its prefetched scalars (the table, the positions, the heads' origin, and
+    over fp8 pages the two scale vectors)."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+    (call,) = calls(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    assert call.params["name"] == "paged_attention"
+    return len(call.invars) \
+        - call.params["grid_mapping"].num_index_operands
+
+
 # The tallest folds and longest tables the cells hand the kernel (blocks of
 # 64, heads of 128, bfloat16, 8 KV heads): batch, chunk tokens, query heads,
 # table blocks, window; then the (rows, pages, heads) of a grid step: a decode
 # fold takes a table entry's page of all eight KV heads in one copy (PR 40),
-# a chunk's fold one head.
+# a chunk's fold one head over 16 pages, behind a narrow window 256 rows over
+# the one tile that holds all they see (PR 42).
 _TALLEST_FOLDS = {
-    "laguna-full-chunk": (1, 4096, 48, 260, None, (2048, 8, 1)),
-    "laguna-sliding-chunk": (1, 4096, 72, 73, 512, (2048, 8, 1)),
+    "laguna-full-chunk": (1, 4096, 48, 260, None, (2048, 16, 1)),
+    "laguna-sliding-chunk": (1, 4096, 72, 73, 512, (256, 16, 1)),
     "laguna-full-decode-32": (32, 1, 48, 260, None, (8, 8, 8)),
     "laguna-sliding-decode-32": (32, 1, 72, 9, 512, (16, 8, 8)),
-    "mixtral-chunk": (1, 2048, 32, 64, None, (2048, 8, 1)),
+    "mixtral-chunk": (1, 2048, 32, 64, None, (2048, 16, 1)),
     "mixtral-decode-32": (32, 1, 32, 64, None, (8, 8, 8)),
     # a row block that spans the heads of a group (the masks then reckon a
-    # row's token by a remainder), behind a window
-    "mistral-chunk-over-heads": (1, 512, 32, 16, 4096, (2048, 8, 1)),
+    # row's token by a remainder), behind a window wider than any tile
+    "mistral-chunk-over-heads": (1, 512, 32, 16, 4096, (2048, 16, 1)),
     "mistral-first-chunk-2048-rows": (1, 512, 32, 8, 4096, (2048, 8, 1)),
     "mistral-decode-64-fp8": (64, 1, 32, 64, 4096, (8, 8, 8)),
     "mistral-decode-16": (16, 1, 32, 32, 4096, (8, 8, 8)),
-    # the tallest fold of one row block that takes all eight heads by the
-    # module's count, behind a window; and over fp8 pages
-    "short-chunk-8-heads-192-rows": (1, 48, 32, 16, 512, (192, 8, 8)),
-    "short-chunk-8-heads-272-rows-fp8": (1, 68, 32, 16, 512, (272, 8, 8)),
+    # the tallest fold that takes all eight heads by the module's count,
+    # behind a window; and over fp8 pages
+    "short-chunk-8-heads-232-rows": (1, 58, 32, 16, 512, (232, 8, 8)),
+    "short-chunk-8-heads-296-rows-fp8": (1, 74, 32, 16, 512, (296, 8, 8)),
 }
 
 
 @pytest.mark.parametrize("fold", sorted(_TALLEST_FOLDS))
-def test_paged_kernel_fits_the_scoped_vmem_at_the_tallest_folds(one_chip,
-                                                                fold):
-    """The tile ``_tile`` chooses compiles for the v5e inside the default
-    16 MiB of scoped VMEM (the kernel sets no compiler parameter): 2,048 rows
-    beside 8 pages, a 260-block table, decode buckets of 32 and 64, fp8 pages
-    dequantized a slot at a time; and a decode fold with all eight KV heads a
-    step (one strided copy a table entry, the products batched over the
-    heads) at Laguna's full and sliding shapes, Mixtral's and chat's, up to
-    the tallest fold the rule gives eight heads."""
+def test_paged_kernel_gets_the_vmem_it_asks_for_at_the_tallest_folds(one_chip,
+                                                                     fold):
+    """The tile ``_tile`` chooses compiles for the v5e in the VMEM the call
+    asks for (``_vmem_limit``: the tile's own count and a quarter): 2,048 rows
+    beside 16 pages, 256 rows beside the tile of a window of 512, a 260-block
+    table, decode buckets of 32 and 64, fp8 pages dequantized an entry at a
+    time; a decode fold with all eight KV heads a step (one strided copy a
+    table entry, the products batched over the heads), up to the tallest fold
+    the rule gives eight heads. Whatever the tile, the call has three
+    operands: q and the pool, once for its keys and once for its values."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     b, t, h, mb, window, tile = _TALLEST_FOLDS[fold]
     fp8 = fold.endswith("fp8")
     dtype = jnp.float8_e4m3fn if fp8 else jnp.bfloat16
-    assert pa._tile(h // 8 * t, mb, BLOCK, 128,
-                    jnp.dtype(dtype).itemsize, 8) == tile
+    assert pa._tile(h // 8 * t, mb, BLOCK, 128, jnp.dtype(dtype).itemsize, 8,
+                    window=window) == tile
+    assert pa._vmem_limit(*tile, BLOCK, 128, 128,
+                          jnp.dtype(dtype).itemsize) <= 32 << 20
 
     def on_chip(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pool = on_chip((2, 2, 8, NUM_BLOCKS, BLOCK, 128), dtype)
     scales = on_chip(pool.shape[:4], jnp.float32) if fp8 else None
-    compiled = jax.jit(
-        lambda q, pool, scales, tables, start: pa.paged_attention_pool(
-            q, pool, 1, tables, start, window=window, scales=scales)).lower(
-        on_chip((b, t, h, 128), jnp.bfloat16), pool, scales,
-        on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32)).compile()
+
+    def attend(q, pool, scales, tables, start):
+        return pa.paged_attention_pool(q, pool, 1, tables, start,
+                                       window=window, scales=scales)
+    shapes = (on_chip((b, t, h, 128), jnp.bfloat16), pool, scales,
+              on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32))
+    assert _kernel_operands(attend, *shapes) == 3
+    compiled = jax.jit(attend).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
-    # the pool goes in whole, once a slot: nothing of its size is made
+    # the pool goes in whole, as it lies in memory: nothing of its size is
+    # made
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
@@ -193,8 +217,8 @@ def test_paged_kernel_fits_the_scoped_vmem_at_the_tallest_folds(one_chip,
 # tokens, KV heads, table blocks, window, sinks; then the step's (rows, pages,
 # heads).
 _SPLIT_FOLDS = {
-    "mimo-full-chunk": (1, 4096, 4, 390, None, False, (1024, 8, 1)),
-    "mimo-windowed-chunk-sinks": (1, 4096, 8, 67, 128, True, (1024, 8, 1)),
+    "mimo-full-chunk": (1, 4096, 4, 390, None, False, (2048, 16, 1)),
+    "mimo-windowed-chunk-sinks": (1, 4096, 8, 67, 128, True, (256, 8, 1)),
     "mimo-full-decode-32": (32, 1, 4, 390, None, False, (16, 8, 4)),
     "mimo-windowed-decode-32-sinks": (32, 1, 8, 3, 128, True, (8, 4, 8)),
 }
@@ -205,29 +229,33 @@ def test_paged_kernel_with_keys_wider_than_values_compiles_for_a_v5e(one_chip,
                                                                      fold):
     """K rows of 256 lanes beside V rows of 128 in pools of their own, the
     softmax scaled for a key of 192, a sink a query head where the layer has
-    them: the tile ``_tile`` chooses by its count of both widths compiles
-    inside the default scoped VMEM, every KV head of a decode fold in one
-    step."""
+    them: the tile ``_tile`` chooses by its count of both widths (a full
+    chunk's 2,048 rows beside 16 pages as a square head's, in the 29.4 MiB
+    the call asks for) compiles, every KV head of a decode fold in one step;
+    the call's operands are q, the two pools and at most the sinks."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     b, t, hkv, mb, window, sunk, tile = _SPLIT_FOLDS[fold]
-    assert pa._tile(64 // hkv * t, mb, BLOCK, 256, 2, hkv, 128) == tile
+    assert pa._tile(64 // hkv * t, mb, BLOCK, 256, 2, hkv, 128, window) == tile
 
     def on_chip(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     blocks = 12481 if window is None else 161
-    compiled = jax.jit(
-        lambda q, k, v, sinks, tables, start: pa.paged_attention_pool(
+
+    def attend(q, k, v, sinks, tables, start):
+        return pa.paged_attention_pool(
             q, (k, v), 1, tables, start, window=window, sinks=sinks,
-            scale=192 ** -0.5)).lower(
-        on_chip((b, t, 64, 256)), on_chip((2, hkv, blocks, BLOCK, 256)),
-        on_chip((2, hkv, blocks, BLOCK, 128)),
-        on_chip((64,), jnp.float32) if sunk else None,
-        on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32)).compile()
+            scale=192 ** -0.5)
+    shapes = (on_chip((b, t, 64, 256)), on_chip((2, hkv, blocks, BLOCK, 256)),
+              on_chip((2, hkv, blocks, BLOCK, 128)),
+              on_chip((64,), jnp.float32) if sunk else None,
+              on_chip((b, mb), jnp.int32), on_chip((b,), jnp.int32))
+    assert _kernel_operands(attend, *shapes) == 3 + sunk
+    compiled = jax.jit(attend).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_attention" in text
-    # the pools go in whole, once a slot; what is made is a chunk's q folded
-    # by KV head (134 MB) and its output before and after the unfold (67 MB
-    # each), never a full pool (2 layers here: 3.3 GB)
+    # the pools go in whole, as they lie in memory; what is made is a chunk's
+    # q folded by KV head (134 MB) and its output before and after the unfold
+    # (67 MB each), never a full pool (2 layers here: 3.3 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 320 << 20
 
 
