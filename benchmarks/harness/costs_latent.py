@@ -14,13 +14,17 @@ every expert that has a row, and three matmuls of every row.
 ``ticks_with_device_work`` and ``step_counts`` select the program's spans
 that the readers of these costs share (``metrics/latent_*_roofline.py``,
 ``metrics/moe_grouped_roofline.py``, ``metrics/moe_rows_per_touched_expert.py``),
-and ``is_grouped_expert_op`` the device operations that
-``moe_grouped_roofline`` and ``moe_grouped_share`` both call the experts'.
+``ends_in`` the kernel calls that the decode rooflines count beside those
+spans' bytes (``metrics/paged_kernel_roofline.py``, ``paged_decode_roofline``,
+``latent_paged_roofline``, ``mixed_paged_roofline``), and
+``is_grouped_expert_op`` the device operations that ``moe_grouped_roofline``
+and ``moe_grouped_share`` both call the experts'.
 """
 
 import bisect
 
 from benchmarks.harness import program_events as pe
+from benchmarks.harness import trace as tr
 
 #: the args the step programs' counts are put on these spans under
 COUNTED_SPANS = (pe.PREFILL_CHUNK, pe.STEP_DECODE)
@@ -66,14 +70,34 @@ def latent_prefill_flops(pairs: int, hf: dict) -> int:
     return per_pair * hf["num_attention_heads"] * int(pairs)
 
 
+#: (the key that counts a layer's routed experts, the key of one expert's
+#: width), by family: DeepSeek's names (JoyAI, Xing4.0, MiMo-V2) and
+#: Qwen2-MoE's (Laguna). A further family's names join here.
+EXPERT_KEYS = (("n_routed_experts", "moe_intermediate_size"),
+               ("num_experts", "moe_intermediate_size"))
+
+
+def expert_shape(hf: dict):
+    """(routed experts a layer holds HERE, one expert's width) under the
+    family's own keys, or None where the configuration has no routed
+    experts. A configuration that holds a share of a deployment's experts
+    states the share under the same key (``reduced`` names it, ``published``
+    has the router's width), so the count is of the experts held: the most
+    a step can touch a layer, and what its bytes are of."""
+    for count, width in EXPERT_KEYS:
+        if hf.get(count) and hf.get(width):
+            return int(hf[count]), int(hf[width])
+    return None
+
+
 def grouped_expert_flops(rows: int, hf: dict) -> int:
     """``rows`` token-expert pairs through a gated MLP: three matmuls."""
-    return 6 * hf["hidden_size"] * hf["moe_intermediate_size"] * int(rows)
+    return 6 * hf["hidden_size"] * expert_shape(hf)[1] * int(rows)
 
 
 def grouped_expert_bytes(touched: int, hf: dict, itemsize: int) -> int:
     """The three matrices of every expert with at least one row."""
-    return 3 * hf["hidden_size"] * hf["moe_intermediate_size"] * itemsize \
+    return 3 * hf["hidden_size"] * expert_shape(hf)[1] * itemsize \
         * int(touched)
 
 
@@ -109,6 +133,39 @@ def ticks_with_device_work(evs, window):
         if a <= lo and hi <= b:
             out.append(((lo, hi), work))
     return out
+
+
+def ends_in(ticks):
+    """A test ``(end)``: does a device call that ends at ``end`` end inside
+    the stretch that the intervals of ``ticks`` (``ticks_with_device_work``'s
+    answer) cover, ``lo < end <= hi``? With a step in flight a step's
+    programs run while the host is anywhere in its loop, so no host span
+    holds a kernel's calls: what holds them is the interval between the
+    ends of two waits. The host sees a wait end some hundred microseconds
+    after the device began the next program, so a program's first call may
+    lie across the edge between two intervals: it is counted whole where it
+    ends and never dropped (asked whether ONE interval holds it whole, every
+    decode program would lose that call: ``costs_kind_pages.joined``). One
+    merge and one bisection a call."""
+    stretches = tr.merge(interval for interval, _ in ticks)
+    starts = [lo for lo, _ in stretches]
+
+    def test(end: float) -> bool:
+        i = bisect.bisect_left(starts, end)
+        return i > 0 and end <= stretches[i - 1][1]
+    return test
+
+
+def decode_steps(ticks, *counts):
+    """The ``serve/step_decode`` spans among the ticks' work that carry every
+    arg of ``counts``: the decode batches whose bytes a decode roofline sets
+    against the calls ``ends_in`` finds. With a step in flight a span's
+    batch runs in the interval of the tick AFTER the one that stamped it, so
+    over a stretch of n ticks the bytes are of steps 1..n and the calls of
+    steps 0..n-1: the two ends cancel to within a step's share of the
+    stretch, which is the metric's resolution."""
+    return [e for _, work in ticks for e in work if e.name == pe.STEP_DECODE
+            and all(e.arg(c) is not None for c in counts)]
 
 
 def step_counts(evs):

@@ -4,15 +4,17 @@ to read (``harness/costs_mixed_pages.py``: the full layers' K and V of
 ``ctx_tokens``, the windowed layers' of ``ctx_tokens_windowed``, both on the
 program's ``serve/step_decode``) over the published HBM bandwidth, divided by
 the device time of the calls named ``paged_attention`` in the decode step
-programs, full and windowed layers' alike. Over the ticks that ran wholly
-inside the traced window; a call counts when it ran inside one of those
-ticks' spans. A chunk's attention is bound by its operations, not by these
-bytes, and is left out. None where the configuration has no ``layer_types``
-or the program no such kernel."""
+programs, full and windowed layers' alike. Bytes and calls come from the same
+stretches: the ticks that ended in a wait for the device and ran wholly
+inside the traced window (``costs_latent.ticks_with_device_work``), and the
+calls that ended inside them (``costs_latent.ends_in``): with a step in
+flight no host span holds a step's calls. A chunk's attention is
+bound by its operations, not by these bytes, and is left out. None where the
+configuration has no ``layer_types`` or the program no such kernel."""
 
-from benchmarks.harness import costs_mixed_pages, named_readers, peaks, readers
+from benchmarks.harness import (costs_latent, costs_mixed_pages,
+                                named_readers, peaks, readers)
 from benchmarks.harness import program_events as pe
-from benchmarks.harness import trace as tr
 from benchmarks.harness import xplane_names as xn
 
 KERNEL = "paged_attention"
@@ -23,20 +25,20 @@ def read(obs):
     found = named_readers.named_ops(obs)
     if found is None or "layer_types" not in hf:
         return None
-    ticks = [e for e in pe.inside(pe.events(), obs.trace.window)
-             if e.name == pe.STEP_DECODE and e.arg("ctx_tokens") is not None
-             and e.arg("ctx_tokens_windowed") is not None]
-    if not ticks:
+    ticks = costs_latent.ticks_with_device_work(pe.events(), obs.trace.window)
+    decodes = costs_latent.decode_steps(ticks, "ctx_tokens",
+                                        "ctx_tokens_windowed")
+    if not decodes:
         return None
     ops, _, shift = found
-    in_a_tick = tr.held_whole((t.start, t.end) for t in ticks)
+    in_a_tick = costs_latent.ends_in(ticks)
     spent = sum(o.dur for o in ops
                 if xn.kernel_of(o) == KERNEL and "decode_step" in o.program
-                and in_a_tick(o.start + shift, o.end + shift))
+                and in_a_tick(o.end + shift))
     if not spent:
         return None
     need = sum(costs_mixed_pages.mixed_decode_bytes(
         e.arg("ctx_tokens"), e.arg("ctx_tokens_windowed"), hf,
-        readers.itemsize(hf)) for e in ticks)
+        readers.itemsize(hf)) for e in decodes)
     least = need / peaks.peak(obs.device_kind, "hbm_bytes_per_s")
     return 100.0 * least / spent

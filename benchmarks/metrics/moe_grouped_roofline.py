@@ -1,6 +1,7 @@
 """The grouped expert matmuls against their roofline, from inside the
 program. The least time of a step program is the larger of its token-expert
-pairs x 6 x hidden x expert width operations over the published bf16 peak and
+pairs x 6 x hidden x expert width (under the family's own key:
+``costs_latent.expert_shape``) operations over the published bf16 peak and
 its touched experts x three matrices' bytes over the published HBM bandwidth
 (``harness/costs_latent.py``; ``expert_rows`` and ``experts_touched`` are
 counted on the device, summed over the expert layers, and put on
@@ -17,7 +18,7 @@ from benchmarks.harness import trace as tr
 def read(obs):
     hf = obs.cell.config
     found = named_readers.named_ops(obs)
-    if found is None or "moe_intermediate_size" not in hf:
+    if found is None or costs_latent.expert_shape(hf) is None:
         return None
     ticks = costs_latent.ticks_with_device_work(pe.events(), obs.trace.window)
     counts = costs_latent.step_counts([e for _, work in ticks for e in work])

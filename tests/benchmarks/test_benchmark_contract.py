@@ -1,32 +1,46 @@
 """``BENCHMARK.json`` against the contract's limits, and every name in it
-against a file the harness can find."""
+against a file the harness can find: of the file as it is and of a copy with
+a later family appended (``conftest.py``'s ``bench``), and the guard that
+keeps every other test of the entries to that fixture."""
 
+import ast
 import json
 import pathlib
 import re
 
 import pytest
 
+import benchmark_rehearsal as rehearsal
 from benchmarks.harness import cells
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
-BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-def test_top_level_keys_and_size():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def entries(*lists):
+    """One case an entry of ``lists``: the file's own under the ids they
+    always had, then the later family's. Each case hands its test the file
+    the entry stands in as ``bench``, in the fixture's place."""
+    later = rehearsal.with_a_later_family(
+        json.loads((REPO / "BENCHMARK.json").read_text()))
+    return [pytest.param(later, entry, id=entry["name"])
+            for key in lists for entry in later[key]]
+
+
+def test_top_level_keys_and_size(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert (REPO / "BENCHMARK.json").stat().st_size <= 64 * 1024
-    assert 1 <= len(BENCH["command"]) <= 32
-    assert 1 <= len(BENCH["paths"]) <= 16
-    assert all((REPO / p).is_dir() for p in BENCH["paths"])
+    assert len(json.dumps(bench, indent=2)) <= 64 * 1024
+    assert 1 <= len(bench["command"]) <= 32
+    assert 1 <= len(bench["paths"]) <= 16
+    assert all((REPO / p).is_dir() for p in bench["paths"])
 
 
-def test_run_seconds_fits_the_full_check_with_24_cells():
-    rs = BENCH["run_seconds"]
+def test_run_seconds_fits_the_full_check_with_24_cells(bench):
+    rs = bench["run_seconds"]
     assert isinstance(rs, int) and 1 <= rs <= 51
     assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
 
@@ -138,16 +152,16 @@ def configuration_faults(entry: dict, data: dict, floors: bool = True) -> list:
     return faults
 
 
-@pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
-def test_configuration_entry_and_file(config):
-    assert any(config["file"].startswith(p + "/") for p in BENCH["paths"])
+@pytest.mark.parametrize("bench,config", entries("configs"))
+def test_configuration_entry_and_file(bench, config):
+    assert any(config["file"].startswith(p + "/") for p in bench["paths"])
     data = json.loads((REPO / config["file"]).read_text())
     assert configuration_faults(
         config, data, floors=config["name"] not in PREDATE_THE_FLOORS) == []
     # widths as published, where the table has the source's numbers
     for key, value in PUBLISHED_WIDTHS.get(config["source"], {}).items():
         assert key in config["reduced"] or data[key] == value, key
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    assert any(w["config"] == config["name"] for w in bench["workloads"])
 
 
 def made_up(**changes):
@@ -236,17 +250,17 @@ def test_rule_holds_the_file_to_its_entry():
                                                  "published"]
 
 
-def test_configuration_files_are_distinct():
-    files = [c["file"] for c in BENCH["configs"]]
+def test_configuration_files_are_distinct(bench):
+    files = [c["file"] for c in bench["configs"]]
     assert len(set(files)) == len(files)
 
 
-@pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
-def test_cell_entry_finds_its_files(cell):
+@pytest.mark.parametrize("bench,cell", entries("workloads"))
+def test_cell_entry_finds_its_files(bench, cell):
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
     assert cell["chips"] in (1, 4) and 1 <= len(cell["why"]) <= 200
     assert NAME.match(cell["name"]) and NAME.match(cell["traffic"])
-    found = cells.find_cell(BENCH, cell["name"], REPO)
+    found = cells.find_cell(bench, cell["name"], REPO)
     assert found.traffic["kind"] in ("serve", "train")
     if found.traffic.get("loop") == "open":
         assert isinstance(found.traffic["rate_rps"], (int, float))
@@ -257,19 +271,18 @@ def test_cell_entry_finds_its_files(cell):
         assert metric["moves"] in reported
 
 
-def test_cells_are_distinct_and_four_chip_quota_holds():
-    names = [w["name"] for w in BENCH["workloads"]]
+def test_cells_are_distinct_and_four_chip_quota_holds(bench):
+    names = [w["name"] for w in bench["workloads"]]
     assert len(set(names)) == len(names)
-    combos = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    combos = [(w["config"], w["traffic"]) for w in bench["workloads"]]
     assert len(set(combos)) == len(combos)
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
     assert four <= max(1, len(names) // 4)
 
 
-@pytest.mark.parametrize("metric", BENCH["end_to_end"] + BENCH["per_layer"],
-                         ids=lambda m: m["name"])
-def test_metric_entry_has_a_reader(metric):
-    end_to_end = metric in BENCH["end_to_end"]
+@pytest.mark.parametrize("bench,metric", entries("end_to_end", "per_layer"))
+def test_metric_entry_has_a_reader(bench, metric):
+    end_to_end = metric in bench["end_to_end"]
     keys = {"name", "unit", "better", "source"} | (
         {"bound"} if end_to_end else {"layer", "moves"})
     assert set(metric) - {"workloads"} == keys
@@ -280,16 +293,16 @@ def test_metric_entry_has_a_reader(metric):
         assert metric["source"] in ("host_clock", "device_trace")
         assert 0.01 <= metric["bound"] <= 0.1
     else:
-        assert metric["moves"] in {m["name"] for m in BENCH["end_to_end"]}
+        assert metric["moves"] in {m["name"] for m in bench["end_to_end"]}
         assert "\n" not in metric["layer"] and len(metric["layer"]) <= 200
     if "workloads" in metric:
-        assert set(metric["workloads"]) <= {w["name"] for w in BENCH["workloads"]}
-    reader = cells.load_module(REPO, BENCH, "metrics", metric["name"])
+        assert set(metric["workloads"]) <= {w["name"] for w in bench["workloads"]}
+    reader = cells.load_module(REPO, bench, "metrics", metric["name"])
     assert reader is not None and callable(reader.read)
 
 
-def test_metric_names_are_distinct_and_rooflines_are_percent():
-    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+def test_metric_names_are_distinct_and_rooflines_are_percent(bench):
+    metrics = bench["end_to_end"] + bench["per_layer"]
     names = [m["name"] for m in metrics]
     assert len(set(names)) == len(names)
     assert "setup_s" in names
@@ -298,15 +311,128 @@ def test_metric_names_are_distinct_and_rooflines_are_percent():
             assert m["unit"] == "%"
 
 
-def test_unknown_cell_is_an_error():
+def test_unknown_cell_is_an_error(bench):
     with pytest.raises(cells.CellError):
-        cells.find_cell(BENCH, "no-such-cell", REPO)
+        cells.find_cell(bench, "no-such-cell", REPO)
 
 
-def test_files_under_paths_are_named_from_name_characters():
+def test_files_under_paths_are_named_from_name_characters(bench):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
-    for base in BENCH["paths"]:
+    for base in bench["paths"]:
         for path in (REPO / base).rglob("*"):
             if "__pycache__" in path.parts:
                 continue
             assert ok.match(str(path.relative_to(REPO))), path
+
+
+# --- what a test under tests/benchmarks/ may say of the file ------------------
+# Its own entries exist and hold their own values; its cell is IN a list. Not
+# where an entry stands, who else is in a list, or how many entries there are:
+# a later family appends, and three families' tests in a row (PRs 31, 39, 41)
+# closed the door behind them. ``conftest.py``'s ``bench`` runs every such
+# test against a copy with a family appended; this holds the tests to it.
+
+LISTS = ("configs", "workloads", "per_layer", "end_to_end")
+TEST_FILES = sorted((REPO / "tests" / "benchmarks").glob("*.py"))
+
+
+def _keyed(node, keys) -> bool:
+    return isinstance(node, ast.Subscript) \
+        and isinstance(node.slice, ast.Constant) and node.slice.value in keys
+
+
+def pins(source: str) -> list:
+    """(line, what is wrong) for every place where a test file reads
+    ``BENCHMARK.json``'s entries round the fixture or pins what a later
+    family changes: a test that indexes a module-level read of the file (or
+    of ``cells.load_benchmark``, or a name made from one); an index or a
+    slice by position into one of the file's lists or a metric's
+    ``workloads``; a metric's ``workloads`` compared with ``==`` or ``!=``,
+    or a cell said to be ``not in`` it."""
+    tree = ast.parse(source)
+    read = set()
+    for node in tree.body:
+        if isinstance(node, ast.Assign):
+            text = ast.get_source_segment(source, node.value) or ""
+            made_from = {n.id for n in ast.walk(node.value)
+                         if isinstance(n, ast.Name)}
+            if "BENCHMARK.json" in text or "load_benchmark" in text \
+                    or made_from & read:
+                read |= {t.id for t in node.targets
+                         if isinstance(t, ast.Name)}
+    out = []
+    for fn in ast.walk(tree):
+        if isinstance(fn, ast.FunctionDef) and fn.name.startswith("test_"):
+            out += [(n.lineno, f"{fn.name} indexes the module-level read "
+                               f"{n.value.id}: take ``bench`` from the fixture")
+                    for n in ast.walk(fn) if isinstance(n, ast.Subscript)
+                    and isinstance(n.value, ast.Name) and n.value.id in read]
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Subscript) and _keyed(node.value, LISTS) \
+                and not isinstance(node.slice, (ast.Name, ast.Attribute)):
+            out.append((node.lineno, f"an entry of {node.value.slice.value!r} "
+                                     f"taken by position"))
+        if isinstance(node, ast.Compare) and any(
+                isinstance(op, (ast.Eq, ast.NotEq, ast.NotIn))
+                for op in node.ops) and any(
+                _keyed(n, ("workloads",))
+                for side in [node.left] + node.comparators
+                for n in ast.walk(side)):
+            out.append((node.lineno, "a metric's workloads compared with "
+                                     "==, != or not in: say that a cell is IN"))
+    return sorted(set(out))
+
+
+@pytest.mark.parametrize("path", TEST_FILES, ids=lambda p: p.name)
+def test_no_test_pins_a_position_or_steps_round_the_fixture(path):
+    assert pins(path.read_text()) == []
+
+
+HEAD = 'import json\nBENCH = json.loads(open("BENCHMARK.json").read())\n'
+PINNED = [
+    pytest.param(HEAD + 'def test_x():\n    assert BENCH["configs"]\n',
+                 "indexes the module-level read BENCH", id="module-level-read"),
+    pytest.param(HEAD + 'LATER = dict(BENCH)\ndef test_x():\n'
+                 '    by_name = {m["name"]: m for m in LATER["per_layer"]}\n'
+                 '    assert by_name\n',
+                 "indexes the module-level read LATER", id="a-name-made-from-it"),
+    pytest.param('from benchmarks.harness import cells\n'
+                 'B = cells.load_benchmark()\ndef test_x():\n'
+                 '    assert B["paths"]\n',
+                 "indexes the module-level read B", id="the-loader"),
+    pytest.param('def test_x(bench):\n'
+                 '    assert bench["configs"][-1]["name"] == "mine"\n',
+                 "'configs' taken by position", id="last-configuration"),
+    pytest.param('def test_x(bench):\n'
+                 '    assert [m["name"] for m in bench["per_layer"][-2:]]\n',
+                 "'per_layer' taken by position", id="last-two-metrics"),
+    pytest.param('def test_x(tokens):\n'
+                 '    assert tokens["workloads"][-1] == "mine"\n',
+                 "'workloads' taken by position", id="last-cell-of-a-list"),
+    pytest.param('def test_x(by_name):\n'
+                 '    assert by_name["m"]["workloads"] == ["mine"]\n',
+                 "compared with", id="a-list-closed-with-=="),
+    pytest.param('def test_x(a, b):\n'
+                 '    assert "theirs" not in a["workloads"] + b["workloads"]\n',
+                 "compared with", id="a-cell-said-to-be-absent"),
+]
+
+
+@pytest.mark.parametrize("source,fault", PINNED)
+def test_guard_finds_each_pin_it_is_there_for(source, fault):
+    found = pins(source)
+    assert found and all(fault in what for _, what in found[-1:]), found
+
+
+def test_guard_passes_membership_and_own_values():
+    assert pins(HEAD + 'from benchmarks.harness import cells\n'
+                'def test_x(bench, tmp_path):\n'
+                '    by_name = {m["name"]: m for m in bench["per_layer"]}\n'
+                '    assert "mine" in by_name["m"]["workloads"]\n'
+                '    assert by_name["m"]["moves"] == "serve_tokens_per_s"\n'
+                '    entry, = [c for c in bench["configs"]\n'
+                '              if c["name"] == "mine"]\n'
+                '    assert entry["reduced"] == ["num_hidden_layers"]\n'
+                '    assert set(["a"]) <= set(by_name["m"]["workloads"])\n'
+                '    assert cells.load_module(tmp_path, BENCH, "metrics", "m")\n'
+                ) == []

@@ -1,8 +1,13 @@
 """Operations, bytes and peaks: hand-worked cases."""
 
+import json
+import pathlib
+
 import pytest
 
-from benchmarks.harness import costs, peaks
+from benchmarks.harness import costs, costs_latent, peaks
+
+REPO = pathlib.Path(__file__).resolve().parents[2]
 
 
 @pytest.mark.parametrize("seq,window,pairs", [
@@ -61,3 +66,40 @@ def test_v5e_peaks_and_unknown_devices():
         peaks.peak("cpu", "bf16_flops_per_s")
     with pytest.raises(KeyError):
         peaks.peak("TPU v5 lite", "fp8_flops_per_s")
+
+
+# --- a routed expert's count and width, under each family's own keys ----------
+
+def _config(name):
+    return json.loads((REPO / "benchmarks" / "configs" /
+                       f"{name}.json").read_text())
+
+
+@pytest.mark.parametrize("config,held,width,hidden", [
+    # DeepSeek's names: all 256 experts of a layer on the chip
+    ("joyai-llm-flash-serve-d5", 256, 768, 2048),
+    ("xing4.0-29b-a4b-serve-d7", 64, 1024, 3584),
+    # a HELD share counts the experts held: 16 of the router's 256
+    ("mimo-v2.5-serve-d7-e16", 16, 2048, 4096),
+    # Qwen2-MoE's names: 128 of 256
+    ("laguna-s-2.1-serve-d5-e128", 128, 1024, 3072),
+])
+def test_expert_shape_reads_the_familys_own_keys(config, held, width, hidden):
+    hf = _config(config)
+    assert costs_latent.expert_shape(hf) == (held, width)
+    if "published" in hf and len(hf["reduced"]) > 1:
+        count_key = next(k for k in hf["reduced"] if k.endswith("_experts"))
+        assert held == hf[count_key] < hf["published"][count_key]
+    # a row through an expert is three matmuls, an expert three matrices
+    assert costs_latent.grouped_expert_flops(1, hf) == 6 * hidden * width
+    assert costs_latent.grouped_expert_bytes(1, hf, 2) == 6 * hidden * width
+    # every expert held, touched by one row each: the weights bound it
+    assert costs_latent.grouped_least_seconds(
+        held, held, hf, 2, 197e12, 819e9) == pytest.approx(
+            held * 6 * hidden * width / 819e9)
+
+
+def test_expert_shape_of_a_dense_model_is_none():
+    assert costs_latent.expert_shape(_config("mistral-7b-serve-d8")) is None
+    assert costs_latent.expert_shape({"n_routed_experts": None,
+                                      "moe_intermediate_size": 64}) is None

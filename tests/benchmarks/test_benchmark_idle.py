@@ -11,7 +11,7 @@ import pytest
 from benchmarks.harness import idle_readers as ir
 from benchmarks.harness import program_events as pe
 from benchmarks.harness import trace as tr
-from benchmarks.harness.cells import load_module
+from benchmarks.harness.cells import find_cell, load_module
 from benchmarks.harness.observations import Observations
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
@@ -197,15 +197,17 @@ def test_the_program_under_test_declares_both_spans():
 # --- where BENCHMARK.json lists them ---------------------------------------------
 
 @pytest.mark.parametrize("name", READERS)
-def test_each_reader_is_listed_twice_by_what_it_moves(name):
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+def test_each_reader_is_listed_twice_by_what_it_moves(name, bench):
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     plain, twin = by_name[name], by_name["tick_" + name]
     assert plain["moves"] == "serve_tokens_per_s"
     assert twin["moves"] == "serve_tpot_p50_ms"
     assert set(THROUGHPUT) <= set(plain["workloads"])
     assert set(LATENCY) <= set(twin["workloads"])
-    assert "mistral7b-train-8k" not in plain["workloads"] + twin["workloads"]
+    # a training cell has no serve loop to idle: every cell listed is served
+    for cell in plain["workloads"] + twin["workloads"]:
+        assert find_cell(bench, cell, REPO).traffic["kind"] == "serve"
     for key in ("unit", "better", "source", "layer"):
         assert plain[key] == twin[key]
-    assert plain["layer"] in {m["layer"] for m in BENCH["per_layer"]
+    assert plain["layer"] in {m["layer"] for m in bench["per_layer"]
                               if not m["name"].endswith(name)}

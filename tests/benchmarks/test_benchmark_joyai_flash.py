@@ -73,7 +73,7 @@ def test_configuration_keeps_the_rule_and_the_floors(config):
     assert contract.configuration_faults(entry, data) == []
 
 
-def test_configuration_is_the_catalog_row_but_for_depth():
+def test_configuration_is_the_catalog_row_but_for_depth(bench):
     """Every key of the published config at its published value, except
     depth (the row is copied here: the catalog lies outside the
     repository)."""
@@ -99,10 +99,10 @@ def test_configuration_is_the_catalog_row_but_for_depth():
     assert differ == set(data["reduced"]) == {"num_hidden_layers"}
     assert data["published"] == {"num_hidden_layers": 40}
     assert data["num_hidden_layers"] == 5
-    entry, = [c for c in BENCH["configs"]
+    entry, = [c for c in bench["configs"]
               if c["name"] == "joyai-llm-flash-serve-d5"]
     assert entry["source"] == data["source"]
-    cell, = [w for w in BENCH["workloads"] if w["name"] == LIKE]
+    cell, = [w for w in bench["workloads"] if w["name"] == LIKE]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("joyai-llm-flash-serve-d5", "doc-qa", 1)
 
@@ -122,17 +122,17 @@ def test_traffic_is_the_issues_letter_for_letter():
     assert "order_seed" not in mix
 
 
-def test_new_metrics_are_the_cells_alone_and_move_tokens_per_second():
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [LIKE]
+def test_new_metrics_list_the_cell_and_move_tokens_per_second(bench):
+    """Membership only: a later cell may join any of these lists, and this
+    one any list it reports, with no edit here. (Why this cell reports
+    ``moe_grouped_share`` and not ``moe_expert_share`` is in that reader's
+    docstring.)"""
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_METRICS + ("prefill_tokens_per_tick",
+                               "prefill_bucket_fill",
+                               "prefill_idle_attributed_share"):
+        assert LIKE in by_name[name]["workloads"], name
         assert by_name[name]["moves"] == "serve_tokens_per_s"
-    for name in ("prefill_tokens_per_tick", "prefill_bucket_fill",
-                 "prefill_idle_attributed_share"):
-        assert by_name[name]["workloads"][-1] == LIKE
-    # XLA's grouped-matmul call carries no scope, so the scope's share is
-    # not the experts' in this cell: moe_grouped_share is
-    assert LIKE not in by_name["moe_expert_share"]["workloads"]
 
 
 def test_family_bounds_the_context_and_builds_the_published_widths():
@@ -162,7 +162,7 @@ def test_toy_cell_is_correct_and_compiles_nothing_in_the_window(runs, traced):
 
 def test_untraced_run_reports_tokens_per_second(runs):
     _, line, _ = runs[False]
-    assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+    assert {"serve_tokens_per_s", "setup_s"} <= set(line["metrics"])
     assert all(m["value"] > 0 for m in line["metrics"].values())
 
 
@@ -288,13 +288,15 @@ def _patched(monkeypatch, reader, ops, evs):
 
 
 def test_paged_roofline_reader_on_hand_built_ticks(monkeypatch):
-    """Two decode ticks of 10,000 and 30,000 cached tokens over 5 layers;
-    the kernel's calls inside them took 1 ms together; a call in a prefill
-    program, one outside any tick and another kernel's are left out."""
+    """Two decode ticks of 10,000 and 30,000 cached tokens over 5 layers,
+    each ended by its wait; the kernel's calls that ended between the waits
+    took 1 ms together; a call in a prefill program, one after the last wait
+    and another kernel's are left out. (A step in flight:
+    ``test_benchmark_decode_rooflines.py``.)"""
     reader = cells.load_module(REPO, BENCH, "metrics", "latent_paged_roofline")
-    ticks = [pe.Event(pe.STEP_DECODE, 1.0, 1.0, args={"ctx_tokens": 10_000}),
-             pe.Event(pe.STEP_DECODE, 3.0, 1.0, args={"ctx_tokens": 30_000}),
-             pe.Event(pe.STEP_DECODE, 5.0, 1.0, args={})]
+    ticks = (_tick(1, 0.98, 2.0, decode={"ctx_tokens": 10_000})
+             + _tick(2, 2.98, 4.0, decode={"ctx_tokens": 30_000})
+             + _tick(3, 4.98, 6.0, decode={"batch": 0}))
     scope = "jit(decode_step_g)/attn/latent_paged"
     ops = [_op("latent_paged_attention.3", 1.1, 0.0004, scope),
            _op("latent_paged_attention.3", 3.1, 0.0006, scope),

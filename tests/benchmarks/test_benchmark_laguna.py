@@ -75,7 +75,7 @@ def test_configuration_keeps_the_rule_and_the_floors(config):
     assert data["deployment_chips"] == 2 and data["first_expert_held"] == 0
 
 
-def test_configuration_is_the_catalog_row_but_for_depth_and_experts():
+def test_configuration_is_the_catalog_row_but_for_depth_and_experts(bench):
     """Every number of the published config at its published value, except
     depth and the experts held; the per-layer lists keep their first five
     entries (the row is copied here: the catalog lies outside the
@@ -112,9 +112,9 @@ def test_configuration_is_the_catalog_row_but_for_depth_and_experts():
     assert HF["mlp_layer_types"] == ["dense"] + ["sparse"] * 4
     assert HF["gating_types"] == ["per_head"] * 5
     assert len(HF["assumed"]) >= 5
-    entry, = [c for c in BENCH["configs"] if c["name"] == REAL_NAME]
+    entry, = [c for c in bench["configs"] if c["name"] == REAL_NAME]
     assert entry["source"] == HF["source"]
-    cell, = [w for w in BENCH["workloads"] if w["name"] == LIKE]
+    cell, = [w for w in bench["workloads"] if w["name"] == LIKE]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (REAL_NAME, "code-mixed", 1)
 
@@ -137,16 +137,16 @@ def test_traffic_is_the_issues_letter_for_letter():
     assert 0.69 < prompts[prompts >= 4096].sum() / prompts.sum() < 0.71
 
 
-def test_new_metrics_list_the_cell_and_move_tokens_per_second():
+def test_new_metrics_list_the_cell_and_move_tokens_per_second(bench):
     """Membership only: a later cell may join any of these lists, and this
     one any list it reports, with no edit here."""
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_METRICS:
         assert LIKE in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "serve_tokens_per_s"
     assert by_name["kv_bytes_per_live_token"]["layer"] == "cache manager"
     assert by_name["mixed_paged_roofline"]["layer"] == "kernels"
-    tokens, = [m for m in BENCH["end_to_end"]
+    tokens, = [m for m in bench["end_to_end"]
                if m["name"] == "serve_tokens_per_s"]
     assert LIKE in tokens["workloads"]
 
@@ -187,9 +187,11 @@ def test_toy_cell_is_correct_and_compiles_nothing_in_the_window(runs, traced):
 
 def test_traced_run_reports_the_program_counter_metrics(runs):
     """Device-trace metrics need a TPU plane: their readers find nothing on
-    the CPU, return None and are left out, as on a program without them."""
+    the CPU, return None and are left out, as on a program without them.
+    The cell's own counter is IN the line; which of the accepted counters
+    read the ring beside it is theirs to say."""
     _, line, _ = runs[True]
-    assert set(line["metrics"]) == {"kv_bytes_per_live_token"}
+    assert "kv_bytes_per_live_token" in line["metrics"]
     assert not any(k.endswith("_roofline") or k.endswith("_share")
                    for k in line["metrics"])
     # two full layers of 2 x 16 in bfloat16 cost 256 bytes a token, all five
@@ -252,17 +254,20 @@ def _patched(monkeypatch, reader, ops, evs):
 
 
 def test_mixed_roofline_reader_on_hand_built_ticks(monkeypatch):
-    """Two decode ticks; the kernel's calls inside them (full and windowed
-    layers' alike) took 10 ms together; a call in a prefill program, one
-    outside any tick and another kernel's are left out."""
+    """Two decode ticks, each ended by its wait; the kernel's calls that
+    ended between the waits (full and windowed layers' alike) took 10 ms
+    together; a call in a prefill program, one after the last wait and
+    another kernel's are left out. (A step in flight:
+    ``test_benchmark_decode_rooflines.py``.)"""
     reader = cells.load_module(REPO, BENCH, "metrics", "mixed_paged_roofline")
-    ticks = [pe.Event(pe.STEP_DECODE, 1.0, 1.0,
-                      args={"ctx_tokens": 100_000,
-                            "ctx_tokens_windowed": 16_384}),
-             pe.Event(pe.STEP_DECODE, 3.0, 1.0,
-                      args={"ctx_tokens": 50_000,
-                            "ctx_tokens_windowed": 10_000}),
-             pe.Event(pe.STEP_DECODE, 5.0, 1.0, args={"ctx_tokens": 9})]
+
+    def tick(n, start, **counts):
+        return [pe.Event(pe.STEP_DECODE, start, 1.0,
+                         args=dict(counts, tick=n)),
+                pe.Event(pe.DECODE_WAIT, start + 0.9, 0.1, args={"tick": n})]
+    ticks = (tick(1, 1.0, ctx_tokens=100_000, ctx_tokens_windowed=16_384)
+             + tick(2, 3.0, ctx_tokens=50_000, ctx_tokens_windowed=10_000)
+             + tick(3, 5.0, ctx_tokens=9))
     full = "jit(decode_step_g)/attn/full/attn/paged"
     window = "jit(decode_step_g)/attn/window/attn/paged"
     ops = [_op("paged_attention.3", 1.1, 0.004, full),
@@ -282,7 +287,7 @@ def test_mixed_roofline_reader_on_hand_built_ticks(monkeypatch):
     assert reader.read(_Obs(HF)) is None
     _patched(monkeypatch, reader, ops, ticks)          # another family
     assert reader.read(_Obs({"num_hidden_layers": 3})) is None
-    _patched(monkeypatch, reader, ops, ticks[2:])      # the parent's spans
+    _patched(monkeypatch, reader, ops, ticks[4:])      # the parent's spans
     assert reader.read(_Obs(HF)) is None
 
 

@@ -84,7 +84,7 @@ def test_configuration_keeps_the_rule_and_the_floors(config, chips):
     assert data["first_expert_held"] == 0
 
 
-def test_configuration_is_the_catalog_row_but_for_depth_and_experts():
+def test_configuration_is_the_catalog_row_but_for_depth_and_experts(bench):
     """Every number of the published config at its published value, except
     depth and the experts held; the two per-layer lists keep their first
     seven entries (the row is copied here: the catalog lies outside the
@@ -125,10 +125,9 @@ def test_configuration_is_the_catalog_row_but_for_depth_and_experts():
     for said in ("sink", "attention_value_scale", "attention_chunk_size",
                  "multi-token-prediction", "fused_qkv"):
         assert any(said in text for text in HF["assumed"]), said
-    entry, = [c for c in BENCH["configs"] if c["name"] == REAL_NAME]
+    entry, = [c for c in bench["configs"] if c["name"] == REAL_NAME]
     assert entry["source"] == HF["source"]
-    assert entry is BENCH["configs"][-1]           # appended, last
-    cell, = [w for w in BENCH["workloads"] if w["name"] == LIKE]
+    cell, = [w for w in bench["workloads"] if w["name"] == LIKE]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (REAL_NAME, "agent-long", 1)
     assert "sixteen" in HF["deployment"] and "4,523,557,184" in \
@@ -159,22 +158,21 @@ def test_traffic_is_the_issues_letter_for_letter():
     assert longest == HF["serve"]["max_context"] == 390 * 64
 
 
-def test_metrics_list_the_cell_and_move_tokens_per_second():
+def test_metrics_list_the_cell_and_move_tokens_per_second(bench):
     """Membership only: a later cell may join any of these lists, and this
     one any list it reports, with no edit here."""
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_METRICS:
-        assert by_name[name]["workloads"] == [LIKE]
+        assert LIKE in by_name[name]["workloads"]
         assert (by_name[name]["moves"], by_name[name]["layer"],
                 by_name[name]["source"], by_name[name]["unit"]) == \
             ("serve_tokens_per_s", "kernels", "device_trace", "%")
-    assert [m["name"] for m in BENCH["per_layer"][-2:]] == list(NEW_METRICS)
     for name in JOINED:
         assert LIKE in by_name[name]["workloads"], name
         assert by_name[name]["moves"] == "serve_tokens_per_s"
-    tokens, = [m for m in BENCH["end_to_end"]
+    tokens, = [m for m in bench["end_to_end"]
                if m["name"] == "serve_tokens_per_s"]
-    assert tokens["workloads"][-1] == LIKE
+    assert LIKE in tokens["workloads"]
 
 
 def test_family_builds_the_published_widths_and_the_held_sixteenth():
@@ -230,9 +228,13 @@ def test_toy_cell_is_correct_and_compiles_nothing_in_the_window(runs, traced):
 
 def test_traced_run_reports_the_program_counter_metrics(runs):
     """Device-trace metrics need a TPU plane: their readers find nothing on
-    the CPU, return None and are left out, as on a program without them."""
+    the CPU, return None and are left out, as on a program without them.
+    The cell's own counter is IN the line; which of the accepted counters
+    read the ring beside it is theirs to say."""
     _, line, _ = runs[True]
-    assert set(line["metrics"]) == {"kv_bytes_per_live_token"}
+    assert "kv_bytes_per_live_token" in line["metrics"]
+    assert not any(k.endswith("_roofline") or k.endswith("_share")
+                   for k in line["metrics"])
     # two full layers of 1 head cost 2 x (24 + 16) x 2 = 160 bytes a token
     # where no row is padded, and 2 x (128 + 128) x 2 = 1,024 as the toy's
     # pool stores them (rows of 128 lanes); the windowed layers' blocks

@@ -315,7 +315,6 @@ def test_idle_attributed_and_skew_and_fills(run):
     assert metric("trace_clock_skew_us").read(run) == pytest.approx(2.0)
     assert metric("decode_bucket_fill").read(run) == pytest.approx(75.0)
     assert metric("prefill_bucket_fill").read(run) is None
-    assert metric("decode_host_bubble_p50_ms").read(run) is None   # one tick
 
 
 def test_flash_rooflines_split_forward_and_backward(run, monkeypatch):
@@ -356,7 +355,7 @@ def test_a_program_that_names_nothing_gives_every_reader_nothing(run,
     monkeypatch.setattr(xn, "of_run", lambda o: plain)
     run.train = {"tokens_per_step": 1024, "seq_len": 512, "steps": 1,
                  "remat": True}
-    for name in ("decode_host_bubble_p50_ms", "decode_bucket_fill",
+    for name in ("decode_bucket_fill",
                  "prefill_bucket_fill", "paged_kernel_roofline",
                  "moe_expert_share", "flash_fwd_roofline",
                  "flash_bwd_roofline", "trace_clock_skew_us"):

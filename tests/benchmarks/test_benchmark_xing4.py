@@ -75,7 +75,7 @@ def test_configuration_keeps_the_rule_and_the_floors(config):
     assert data["reduced"] == ["num_hidden_layers"]
 
 
-def test_configuration_is_the_catalog_row_but_for_depth():
+def test_configuration_is_the_catalog_row_but_for_depth(bench):
     """Every key of the published config at its published value, except the
     depth (the row is copied here: the catalog lies outside the
     repository)."""
@@ -106,9 +106,9 @@ def test_configuration_is_the_catalog_row_but_for_depth():
     assert HF["num_hidden_layers"] == 7
     assert len(HF["assumed"]) >= 8
     assert "9,841,733,492" in HF["deployment"]
-    entry, = [c for c in BENCH["configs"] if c["name"] == REAL_NAME]
+    entry, = [c for c in bench["configs"] if c["name"] == REAL_NAME]
     assert entry["source"] == HF["source"]
-    cell, = [w for w in BENCH["workloads"] if w["name"] == LIKE]
+    cell, = [w for w in bench["workloads"] if w["name"] == LIKE]
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (REAL_NAME, "reasoning", 1)
 
@@ -134,10 +134,10 @@ def test_traffic_is_the_issues_letter_for_letter():
     assert outputs.sum() > prompts.sum()
 
 
-def test_new_metrics_list_the_cell_and_move_tokens_per_second():
+def test_new_metrics_list_the_cell_and_move_tokens_per_second(bench):
     """Membership only: a later cell may join any of these lists, and this
     one any list it reports, with no edit here."""
-    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    by_name = {m["name"]: m for m in bench["per_layer"]}
     for name in NEW_METRICS:
         assert LIKE in by_name[name]["workloads"]
         assert by_name[name]["moves"] == "serve_tokens_per_s"
@@ -145,7 +145,7 @@ def test_new_metrics_list_the_cell_and_move_tokens_per_second():
     assert by_name["hc_mix_share"]["layer"] == "model step"
     assert by_name["hc_mix_share"]["better"] == "lower"
     assert by_name["hc_mix_roofline"]["layer"] == "kernels"
-    tokens, = [m for m in BENCH["end_to_end"]
+    tokens, = [m for m in bench["end_to_end"]
                if m["name"] == "serve_tokens_per_s"]
     assert LIKE in tokens["workloads"]
 
