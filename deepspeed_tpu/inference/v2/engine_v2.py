@@ -165,7 +165,11 @@ class InferenceEngineV2:
             spec, self.config.kv_cache_dtype,
             block_size=self.config.kv_block_size,
             num_blocks=self.config.kv_num_blocks,
-            window_blocks=self._window_pool_blocks(spec))
+            window_blocks=self._window_pool_blocks(spec),
+            state_slots=self.config.max_tracked_sequences)
+        if self.config.speculative_k > 0 and self.kv.has_state:
+            # refused here, not at the first proposal that hits
+            self.kv.require_one_kind("speculative decoding")
         self.state = StateManager(
             max_tracked_sequences=self.config.max_tracked_sequences,
             max_context_length=spec.max_seq_len)
@@ -272,7 +276,8 @@ class InferenceEngineV2:
 
     def require_one_page_kind(self, what: str) -> None:
         """Raise ``TwoPageKindsError`` naming ``what`` over a cache that
-        keeps pages by layer kind."""
+        keeps pages of two kinds, ``StateKindError`` over one some of whose
+        layers keep a recurrent state."""
         self.kv.require_one_kind(what)
 
     def enable_prefix_cache(self, max_cached_blocks: int = 0) -> None:
@@ -398,10 +403,15 @@ class InferenceEngineV2:
         padded to the context bucket, and over pages by layer kind the
         windowed layers' table beside it (``BlockedKVCache.window_table``)."""
         table = self._block_table(seq, bucket_blocks)
-        if not self.kv.two_kinds:
+        if not self.kv.by_layer_kind:
             return table
-        return {"full": table,
-                "window": self.kv.window_table(seq, first_query, rows)}
+        tables = {"full": table}
+        if self.kv.two_kinds:
+            tables["window"] = self.kv.window_table(seq, first_query, rows)
+        if self.kv.has_state:
+            # the state kind's entry is the sequence's slot itself
+            tables["state"] = np.int32(seq.slot)
+        return tables
 
     def _decode_tables(self, seqs, batch: int, bucket_blocks: int):
         """The decode batch's block tables on the device, [batch, ...] a
@@ -414,12 +424,13 @@ class InferenceEngineV2:
             out[:len(tables)] = tables
             return jnp.asarray(out)
         cfg = self.kv.cfg
-        if not self.kv.two_kinds:
+        if not self.kv.by_layer_kind:
             return stacked(rows, cfg.num_blocks - 1)
-        return {"full": stacked([r["full"] for r in rows],
-                                cfg.num_blocks - 1),
-                "window": stacked([r["window"] for r in rows],
-                                  cfg.window_blocks - 1)}
+        # what pads a kind's rows: its trash block, or the slot past the last
+        pads = {"full": cfg.num_blocks - 1, "window": cfg.window_blocks - 1,
+                "state": self._pad_slot}
+        return {kind: stacked([r[kind] for r in rows], pads[kind])
+                for kind in rows[0]}
 
     def _advanced(self, seq: SequenceDescriptor) -> None:
         """``seq.seen_tokens`` moved on: over pages by layer kind, give back
@@ -680,6 +691,8 @@ class InferenceEngineV2:
                 # (a windowed block given back resets the signature:
                 # ``_advanced``)
                 sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
+                if self.kv.has_state:
+                    sig += (tuple(s.slot for s in seqs),)
                 rebuilt = sig != self._table_sig
                 if rebuilt:
                     self._dev_tables = self._decode_tables(seqs, b, mb)
@@ -797,8 +810,9 @@ class InferenceEngineV2:
         raised: its rows and chunks count as not run, so the next step
         dispatches them again with the tokens the host holds. Returns the
         uids that cannot go on: over pages by layer kind, a sequence whose
-        windowed blocks were given back past where it now stands (the
-        server recomputes it from its tokens)."""
+        windowed blocks were given back past where it now stands, and over a
+        state kind every sequence taken back (the server recomputes it from
+        its tokens)."""
         touched = {}
         while self._pending:
             rec = self._pending.pop()
@@ -816,9 +830,11 @@ class InferenceEngineV2:
         lost = []
         for uid, seq in touched.items():
             seq.token_on_device = False
-            if self.kv.two_kinds and seq.window_base > blocks_behind_window(
-                    seq.seen_tokens, self.kv.kind.window,
-                    self.kv.cfg.block_size):
+            # a state has summed the rows taken back and cannot give them up
+            if self.kv.has_state or (
+                    self.kv.two_kinds and seq.window_base >
+                    blocks_behind_window(seq.seen_tokens, self.kv.kind.window,
+                                         self.kv.cfg.block_size)):
                 lost.append(uid)
         return lost
 
@@ -838,9 +854,14 @@ class InferenceEngineV2:
         ``kv_window_bytes``, each from a block of the kind's own pool), and
         the tokens whose keys and values they hold."""
         held = self.kv.pages_held()
-        return {"kv_live_tokens": sum(s.seen_tokens for s in self.state.all()
-                                      if not s.paused),
-                **{f"kv_{name}": n for name, n in held.items()}}
+        out = {"kv_live_tokens": sum(s.seen_tokens for s in self.state.all()
+                                     if not s.paused),
+               **{f"kv_{name}": n for name, n in held.items()}}
+        if self.kv.slot_bytes:
+            # a state kind: the slots sequences hold, whatever their lengths
+            out["state_slots_held"] = len(self.state)
+            out["kv_state_bytes"] = len(self.state) * self.kv.slot_bytes
+        return out
 
     def _keep_counts(self, rec: _PendingStep, counts) -> None:
         """Keep what a step program counted (nothing where its policy counts
@@ -1242,7 +1263,10 @@ class InferenceEngineV2:
         arithmetic on the cache array — never a transfer): the conversion
         the serving gauges use to state occupancy in bytes instead of
         blocks."""
-        nbytes = sum(int(x.nbytes) for x in jax.tree.leaves(self.kv.pool))
+        pool = self.kv.pool
+        if self.kv.has_state:
+            pool = {k: v for k, v in pool.items() if k != "state"}
+        nbytes = sum(int(x.nbytes) for x in jax.tree.leaves(pool))
         return nbytes // max(self.kv.cfg.num_blocks
                              + self.kv.cfg.window_blocks, 1)
 

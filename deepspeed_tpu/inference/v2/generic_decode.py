@@ -11,8 +11,8 @@ the block computed on to the kind with the layer and the step's slots.
 
 Every phase of a step sits under a ``jax.named_scope`` whose name reaches the
 device trace (an operation's ``tf_op``): ``embed`` and ``lm_head`` here,
-``attn/kv_write``, ``attn/paged`` and the ``attn/latent_*`` scopes in the
-kinds, ``attn/qkv``, ``attn/out``, ``mlp``, ``moe/router`` and
+``attn/kv_write``, ``attn/paged``, the ``attn/latent_*`` scopes and a state
+kind's ``ssm/conv``, ``ssm/scan`` and ``ssm/update`` in the kinds, ``attn/qkv``, ``attn/out``, ``mlp``, ``moe/router`` and
 ``moe/experts`` in the policies. Metadata only: the compiled program is the
 same.
 
@@ -29,7 +29,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.kv_cache import page_kind
+from deepspeed_tpu.inference.v2.kv_cache import StateKindError, page_kind
 
 
 def _summed(counted):
@@ -101,7 +101,14 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
     chain (draft-free prompt-lookup speculation; no reference analog —
     FastGen has no speculative decoding). Rejected rows' K/V writes land at
     positions beyond the accepted context and are invisible (causal masking
-    doubles as the context-length mask) until a later step overwrites them."""
+    doubles as the context-length mask) until a later step overwrites them:
+    true of pages alone, so a pool some of whose layers keep a recurrent
+    state (which has summed every row) is refused by name."""
+    if getattr(page_kind(policy.cache_spec(cfg), cache_data), "state", None):
+        raise StateKindError(
+            "verify_chunk_g writes rows that may be rejected, and a "
+            "recurrent state cannot give them up: speculative verification "
+            "is not supported over a cache with a state kind")
     x, cache, counts = _chunk_states(params, cache_data, tokens, start,
                                      block_table, true_len, policy, cfg,
                                      block_size, attn_impl)

@@ -12,7 +12,9 @@ latent plane (``_LatentPages``), head pages held by LAYER kind
 (``_LayerKindPages``: a pool of the full layers' pages and a pool of the
 windowed layers', each with its block tables and, where the model says so,
 its own KV head count and key and value widths, for a model that mixes the
-two). A kind owns, and nothing outside this
+two; and, beside the pages, the layers that keep no pages at all but a
+recurrent state of a size that does not grow with the context, one SLOT a
+sequence and no block table, ``_StateSlots``). A kind owns, and nothing outside this
 module knows: the pool's shape and block axis, the trash block, whether the
 step programs carry an array or ``(pages, scales)``, the slots a step's rows
 land in, their write, and the chunk and decode attention over its pages,
@@ -36,9 +38,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
+from deepspeed_tpu.ops import ssm
+from deepspeed_tpu.ops.pallas import ssm_update as _ssm_update
 from deepspeed_tpu.ops.pallas.paged_attention import (
     chunk_tile_keys, decode_slot_copies, decode_tile_keys,
     paged_attention_pool, paged_attention_reference)
+
+FULL, WINDOW, STATE = "full", "window", "state"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,6 +60,47 @@ class HeadPageShape:
     def square(self) -> bool:
         """Keys and values of one width: K and V planes of one array."""
         return self.key_dim == self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSlotShape:
+    """What a slot of the state kind holds of one sequence in one layer: the
+    recurrent state of ``heads`` heads, ``[head_dim, d_state]`` float32 each
+    (a sum over every token the sequence has seen, so not the compute type),
+    and the last ``conv_width - 1`` rows of the ``conv_channels`` that the
+    layer's causal convolution looks back on, in the compute type.
+    ``scan_block`` is the block a prefill chunk's closed form sums over."""
+    heads: int
+    head_dim: int
+    d_state: int
+    conv_width: int
+    conv_channels: int
+    scan_block: int = 256
+
+    @property
+    def pack(self) -> int:
+        """Heads side by side in the stored state's lanes
+        (``ops/pallas/ssm_update.py``)."""
+        return _ssm_update.state_pack(self.heads, self.head_dim)
+
+    @property
+    def stored(self) -> Tuple[int, int, int]:
+        """A layer's state as a slot stores it: [heads / pack, d_state,
+        pack * head_dim]."""
+        return (self.heads // self.pack, self.d_state,
+                self.pack * self.head_dim)
+
+    @property
+    def tail(self) -> int:
+        """Values of a layer's convolution tail, stored flat (whole lane
+        tiles at the published 3 x 4,352; [3, 4352] would pad its 3 rows to
+        a sublane tile)."""
+        return (self.conv_width - 1) * self.conv_channels
+
+    def layer_bytes(self, itemsize: int) -> int:
+        """One sequence's bytes in one layer."""
+        return self.heads * self.head_dim * self.d_state * 4 \
+            + self.tail * itemsize
 
 
 @dataclasses.dataclass
@@ -83,6 +130,14 @@ class KVCacheConfig:
     # query heads a layer, or ``{"full": n, "window": m}``; None: as many as
     # KV heads (``modules.KVCacheSpec.query_heads``)
     query_heads: Any = None
+    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``) where
+    # ``layer_windows`` cannot say it: a model some of whose layers keep a
+    # recurrent state in a slot and no pages. ``num_layers`` then counts the
+    # layers of all kinds; ``state_slot`` is what a slot holds of a layer and
+    # ``state_slots`` how many sequences have one (one more is padding's)
+    layer_kinds: Tuple[str, ...] = ()
+    state_slot: Optional[StateSlotShape] = None
+    state_slots: int = 0
 
     @property
     def page_shape(self) -> HeadPageShape:
@@ -110,6 +165,14 @@ class TwoPageKindsError(NotImplementedError):
     """Something that moves block ids of one pool (prefix reuse, the host
     offload tier, the prefix handoff, fp8 scaled pages) was asked of a cache
     that keeps pages of two kinds."""
+
+
+class StateKindError(NotImplementedError):
+    """Something that takes a sequence's cache to be its pages (prefix reuse,
+    the host offload tier, the prefix handoff, fp8 scaled pages, speculative
+    verification, whose rejected rows are invisible in pages and are NOT in
+    a state that has summed them) was asked of a cache some of whose layers
+    keep a recurrent state in a slot."""
 
 
 def one_window(layer_windows) -> Optional[int]:
@@ -142,15 +205,18 @@ class BlockedKVCache:
     def __init__(self, cfg: KVCacheConfig):
         self.cfg = cfg
         scaled = cfg.dtype == jnp.float8_e4m3fn
-        if mixes_layer_kinds(cfg.layer_windows):
-            self.kind = _LayerKindPages(cfg.layer_windows, cfg.kind_pages)
+        if cfg.layer_kinds or mixes_layer_kinds(cfg.layer_windows):
+            self.kind = _LayerKindPages(cfg.layer_windows, cfg.kind_pages,
+                                        cfg.layer_kinds, cfg.state_slot)
         else:
             self.kind = _kind_for(cfg.latent_dim, scaled)()
         # the last block is the kind's trash block, the target of
         # padding-token writes: never handed out by the allocator
         self.allocator = BlockedAllocator(cfg.num_blocks - 1)
         # ``scales`` is None unless the kind's pages carry them; with pages
-        # by layer kind ``data`` is {"full": pool, "window": pool}
+        # by layer kind ``data`` is {"full": pool, "window": pool}, and
+        # where some layers keep a state {"full": pool, "state": {"ssm",
+        # "conv"}}
         self.data, self.scales = self.kind.new_pool(cfg)
         # the windowed kind's own pool, trash block and allocator, and how
         # many of its blocks sequences have given back from behind their
@@ -161,24 +227,30 @@ class BlockedKVCache:
             return sum(int(x.nbytes) for x in jax.tree.leaves(pool)) // blocks
         if self.two_kinds:
             self.window_allocator = BlockedAllocator(cfg.window_blocks - 1)
-            # each kind's block as its own pool stores it: its heads, its
-            # widths, its rows' padding
-            self._block_bytes = {
-                "full": block_bytes(self.data["full"], cfg.num_blocks),
-                "window": block_bytes(self.data["window"],
-                                      cfg.window_blocks)}
-        else:
-            self._block_bytes = {"full": block_bytes(self.data,
-                                                     cfg.num_blocks)}
+        # each paged kind's block as its own pool stores it: its heads, its
+        # widths, its rows' padding
+        pools = self.data if self.by_layer_kind else {FULL: self.data}
+        self._block_bytes = {
+            kind: block_bytes(pools[kind], blocks)
+            for kind, blocks in ((FULL, cfg.num_blocks),
+                                 (WINDOW, cfg.window_blocks))
+            if kind in pools}
+        # what one sequence's slot holds over all the state layers
+        self.slot_bytes = 0
+        if self.has_state:
+            self.slot_bytes = self.kind.kinds.count(STATE) \
+                * cfg.state_slot.layer_bytes(jnp.dtype(cfg.dtype).itemsize)
 
     @classmethod
     def for_spec(cls, spec, kv_cache_dtype: str, block_size: int,
-                 num_blocks: int, window_blocks: int = 0) -> "BlockedKVCache":
+                 num_blocks: int, window_blocks: int = 0,
+                 state_slots: int = 0) -> "BlockedKVCache":
         """The cache a policy's ``KVCacheSpec`` asks for, its pages stored as
         the engine's ``kv_cache_dtype`` says: ``"model"`` (the spec's compute
         dtype) or ``"fp8"`` (float8_e4m3 pages under scales). Where the spec
         mixes full and windowed layers, ``num_blocks`` is the full layers'
-        pool and ``window_blocks`` the windowed layers'."""
+        pool and ``window_blocks`` the windowed layers'; where some layers
+        keep a state, ``state_slots`` sequences have a slot."""
         dtypes = {"model": spec.dtype, "fp8": jnp.float8_e4m3fn}
         if kv_cache_dtype not in dtypes:
             raise ValueError(f"unknown kv_cache_dtype {kv_cache_dtype!r}; "
@@ -190,18 +262,38 @@ class BlockedKVCache:
             latent_dim=spec.latent_dim,
             layer_windows=tuple(spec.layer_windows or ()),
             window_blocks=window_blocks, kind_pages=spec.kind_pages,
-            query_heads=spec.query_heads))
+            query_heads=spec.query_heads,
+            layer_kinds=tuple(spec.layer_kinds or ()),
+            state_slot=spec.state_slot, state_slots=state_slots))
 
     # ------------------------------------------------------------------
     # pages by layer kind: the windowed pool's host side
     # ------------------------------------------------------------------
     @property
-    def two_kinds(self) -> bool:
+    def by_layer_kind(self) -> bool:
+        """The pool, and a step program's tables, are a dict by layer kind."""
         return isinstance(self.kind, _LayerKindPages)
+
+    @property
+    def two_kinds(self) -> bool:
+        """Pages of two kinds: the windowed layers have a pool of their own."""
+        return self.by_layer_kind and WINDOW in self.kind.pages
+
+    @property
+    def has_state(self) -> bool:
+        """Some layers keep a recurrent state in a slot."""
+        return self.by_layer_kind and self.kind.state is not None
 
     def require_one_kind(self, what: str) -> None:
         """Raise by name where ``what`` moves block ids of one pool and this
-        cache keeps two."""
+        cache keeps two, or takes a sequence's cache to be its pages and
+        some layers keep a state."""
+        if self.has_state:
+            raise StateKindError(
+                f"{what} takes a sequence's cache to be its pages, and in "
+                f"this cache some layers keep a recurrent state in a slot "
+                f"(no pages, no block table, nothing a block id names): not "
+                f"supported over it")
         if self.two_kinds:
             raise TwoPageKindsError(
                 f"{what} moves block ids of one pool, and this cache keeps "
@@ -926,12 +1018,118 @@ def mixes_layer_kinds(layer_windows) -> bool:
         and None in set(layer_windows)
 
 
+class _StateSlots:
+    """The layers that keep no pages: a recurrent state a sequence
+    (``StateSlotShape``), one SLOT of ``{"ssm": [L_state, slots + 1, G, N, W]
+    float32, "conv": [L_state, slots + 1, (K - 1) * C]}`` and no block table
+    (``ssm`` as ``ops/pallas/ssm_update.py`` stores a state). A sequence's
+    slot is the engine's ``SequenceDescriptor.slot``, held from ``create`` to
+    ``pop``; the slot past the last is padding's, as the trash block is the
+    pages'. Nobody zeroes a slot when a sequence leaves it: a chunk that
+    starts at position 0 starts from a zero state and a zero tail, whoever
+    held the slot before. The kind owns the tail, the state, the scan or the
+    update, and the writes; what a model's parameters are called and how its
+    step is made is the policy's. A block hands its ``attend`` plain arrays,
+    as an attention layer hands q, k and v: ``xbc`` [N, C] (the mixer's
+    projection before the convolution, one row a token), ``step`` [N, H]
+    float32 (> 0: after the model's softplus), the convolution's ``kernel``
+    [C, K] and ``bias`` [C], ``a_log`` [H] (``A = -exp(a_log)``) and the skip
+    ``d`` [H]; it gets back ``y`` [N, H * P] with the skip term in it."""
+
+    def __init__(self, shape: StateSlotShape):
+        self.shape = shape
+
+    def empty(self, layers: int, slots: int, dtype):
+        at = self.shape
+        return {"ssm": jnp.zeros((layers, slots + 1) + at.stored, jnp.float32),
+                "conv": jnp.zeros((layers, slots + 1, at.tail), dtype)}
+
+    def _split(self, conv):
+        at = self.shape
+        return ssm.split_conv(conv, at.heads, at.head_dim, at.d_state)
+
+    def attend_chunk(self, cache, layer, slots, attn_impl, xbc, step, kernel,
+                     bias, a_log, d):
+        """One sequence's chunk: the convolution over its rows behind the
+        slot's tail, the closed-form scan from the slot's state, and both
+        written back. Rows that are bucket padding step by 0 and the new
+        tail is cut behind the last REAL row, so padding moves nothing."""
+        at = self.shape
+        slot, valid, fresh = slots
+        true_len = jnp.sum(valid)
+        with jax.named_scope("ssm/conv"):
+            tail = jnp.where(fresh, 0, cache["conv"][layer, slot]).reshape(
+                at.conv_width - 1, at.conv_channels)
+            conv, rows = ssm.causal_conv(xbc, tail, kernel, bias)
+            tail = jax.lax.dynamic_slice_in_dim(rows, true_len,
+                                                at.conv_width - 1)
+            x, bm, cm = self._split(conv.astype(xbc.dtype))
+        # the slot's state in and out by a kernel wherever there is a TPU,
+        # whatever ``attn_impl`` says: a slice of the pool invites XLA there
+        # to re-lay the whole pool out (``ssm_update.slot_read``)
+        impl = _resolve_impl(attn_impl)
+        sliced = impl == "gather" and jax.default_backend() != "tpu"
+        how = dict(interpret=impl == "kernel_interpret")
+        with jax.named_scope("ssm/scan"):
+            held = cache["ssm"][layer, slot] if sliced else \
+                _ssm_update.slot_read(cache["ssm"], layer, slot, **how)
+            s0 = _ssm_update.unpack_state(jnp.where(fresh, 0.0, held),
+                                          at.pack)
+            y, s = ssm.ssm_chunk_scan(x, jnp.where(valid[:, None], step, 0.0),
+                                      a_log, bm, cm, s0, at.scan_block)
+            y = y + d.astype(jnp.float32)[:, None] * x
+            s = _ssm_update.pack_state(s, at.pack)
+            states = cache["ssm"].at[layer, slot].set(s) if sliced else \
+                _ssm_update.slot_write(cache["ssm"], layer, slot, s, **how)
+            cache = {"ssm": states, "conv": cache["conv"].at[
+                layer, slot].set(tail.reshape(-1))}
+        return y.reshape(y.shape[0], -1).astype(xbc.dtype), cache
+
+    def attend_decode(self, cache, layer, slots, attn_impl, xbc, step, kernel,
+                      bias, a_log, d):
+        """One token a sequence: each row's tail shifted by its token, its
+        state read and written once (the Pallas kernel, or gather, update,
+        scatter: ``attn_impl``)."""
+        at = self.shape
+        with jax.named_scope("ssm/conv"):
+            tails = cache["conv"][layer, slots].reshape(
+                -1, at.conv_width - 1, at.conv_channels)
+            rows = jnp.concatenate([tails, xbc[:, None]], axis=1)
+            # tap by tap, as ``ssm.causal_conv`` sums a chunk's
+            taps = kernel.astype(jnp.float32)
+            conv = jax.nn.silu(sum(
+                rows[:, j].astype(jnp.float32) * taps[:, j]
+                for j in range(at.conv_width))
+                + bias.astype(jnp.float32)).astype(xbc.dtype)
+            tails = cache["conv"].at[layer, slots].set(
+                rows[:, 1:].reshape(rows.shape[0], -1))
+            x, bm, cm = self._split(conv)
+        impl = _resolve_impl(attn_impl)
+        with jax.named_scope("ssm/update"):
+            if impl == "gather":
+                y, pool = _ssm_update.ssm_update_reference(
+                    cache["ssm"], layer, slots, x, step, a_log, bm, cm)
+            else:
+                y, pool = _ssm_update.ssm_update(
+                    cache["ssm"], layer, slots, x, step, a_log, bm, cm,
+                    interpret=impl == "kernel_interpret")
+            y = y + d.astype(jnp.float32)[:, None] * x
+        return y.reshape(y.shape[0], -1).astype(xbc.dtype), \
+            {"ssm": pool, "conv": tails}
+
+
 class _LayerKindPages:
-    """Head pages held by layer kind, for a model that mixes full and
-    windowed layers: ``{"full": [L_full, 2, H_kv, NB, bs, D], "window":
-    [L_window, 2, H_kv, NB_w, bs, D]}``, each pool with its own trash block,
-    allocator and block tables (the step programs carry ``{"full": table,
-    "window": table}`` likewise). ``kind_pages`` states each kind's own KV
+    """A cache held by layer kind, for a model whose layers differ in what
+    they keep: ``{"full": [L_full, 2, H_kv, NB, bs, D], "window": [L_window,
+    2, H_kv, NB_w, bs, D]}`` for one that mixes full and windowed layers,
+    each pool with its own trash block, allocator and block tables (the step
+    programs carry ``{"full": table, "window": table}`` likewise);
+    ``{"full": ..., "state": {"ssm", "conv"}}`` for one some of whose layers
+    keep a recurrent state (``_StateSlots``: a slot and no block table; the
+    step programs' ``"state"`` entry IS the slot). The kinds are data: a
+    layer's is ``layer_kinds[l]`` where the spec names them and follows from
+    ``layer_windows[l]`` where it does not, and a kind that no layer has
+    has no pool. ``kind_pages`` states each paged kind's own KV
     heads and key and value widths where the kinds differ (left out, both
     hold the config's ``num_kv_heads`` x ``head_dim``); a kind whose keys
     and values differ in width keeps them in a pool of two arrays
@@ -941,12 +1139,22 @@ class _LayerKindPages:
     and program both derive from the position: nothing else is handed in)
     and is ``windowed_table_blocks`` long whatever the context, so its
     kernel's grid is the window's pages and the blocks behind can be given
-    back. Inside, each kind is ``_HeadPages`` over its own pool with
+    back. Inside, each paged kind is ``_HeadPages`` over its own pool with
     positions counted from its table's first block."""
 
-    def __init__(self, layer_windows, kind_pages=None):
+    def __init__(self, layer_windows, kind_pages=None, layer_kinds=(),
+                 state_slot=None):
         self.window = one_window(layer_windows)
-        self.kinds = tuple("window" if w else "full" for w in layer_windows)
+        self.kinds = tuple(layer_kinds) or tuple(
+            WINDOW if w else FULL for w in layer_windows)
+        if set(self.kinds) - {FULL, WINDOW, STATE} \
+                or (STATE in self.kinds) != (state_slot is not None) \
+                or (WINDOW in self.kinds) != (self.window is not None):
+            raise ValueError(f"layer kinds {sorted(set(self.kinds))} with "
+                             f"window {self.window} and state slot "
+                             f"{state_slot}: a kind is full, window (behind "
+                             f"layer_windows' one window) or state (which "
+                             f"states what its slot holds)")
         # a layer's index in its kind's pool
         self.local = tuple(self.kinds[:i].count(k)
                            for i, k in enumerate(self.kinds))
@@ -956,8 +1164,10 @@ class _LayerKindPages:
             shape = kind_pages and kind_pages[kind]
             return _HeadPages(window) if not shape or shape.square \
                 else _SplitHeadPages(window, shape)
-        self.pages = {"full": pages("full", None),
-                      "window": pages("window", self.window)}
+        self.pages = {kind: pages(kind, window)
+                      for kind, window in ((FULL, None), (WINDOW, self.window))
+                      if kind in self.kinds}
+        self.state = _StateSlots(state_slot) if state_slot else None
 
     def shape(self, kind: str, cfg: KVCacheConfig) -> HeadPageShape:
         """What ``kind``'s page holds of a token a layer."""
@@ -972,54 +1182,83 @@ class _LayerKindPages:
 
     def new_pool(self, cfg: KVCacheConfig):
         if cfg.dtype == jnp.float8_e4m3fn:
+            if self.state is not None:
+                raise StateKindError(
+                    "fp8 scaled pages are not supported over a cache some of "
+                    "whose layers keep a recurrent state in a slot; use "
+                    "kv_cache_dtype='model'")
             raise TwoPageKindsError(
                 "fp8 scaled pages are not supported over a cache that keeps "
                 "pages of two kinds (full layers' and windowed layers'); use "
                 "kv_cache_dtype='model'")
-        if cfg.window_blocks < 2:
+        if WINDOW in self.pages and cfg.window_blocks < 2:
             raise ValueError(f"window_blocks {cfg.window_blocks}: the "
                              f"windowed layers' pool needs its trash block "
                              f"and at least one more")
-
-        def pool(kind, blocks):
-            return self.pages[kind].empty(
-                self.kinds.count(kind), self.shape(kind, cfg), blocks,
-                cfg.block_size, cfg.dtype)
-        return {"full": pool("full", cfg.num_blocks),
-                "window": pool("window", cfg.window_blocks)}, None
+        if self.state is not None and cfg.state_slots < 1:
+            raise ValueError(f"state_slots {cfg.state_slots}: the state "
+                             f"layers' pool needs a slot a sequence")
+        blocks = {FULL: cfg.num_blocks, WINDOW: cfg.window_blocks}
+        pool = {kind: pages.empty(
+                    self.kinds.count(kind), self.shape(kind, cfg),
+                    blocks[kind], cfg.block_size, cfg.dtype)
+                for kind, pages in self.pages.items()}
+        if self.state is not None:
+            pool[STATE] = self.state.empty(self.kinds.count(STATE),
+                                           cfg.state_slots, cfg.dtype)
+        return pool, None
 
     def _behind(self, first_query, block_size: int):
         """Tokens before a windowed table's first block."""
         return blocks_behind_window(first_query, self.window,
                                     block_size) * block_size
 
+    def _slots(self, how: str, cache, tables, first, safe_pos, valid,
+               block_size: int, state_slot):
+        """A step's slots by kind: the paged kinds' (block, offset) a row
+        (the windowed kind's counted from its table's first block), the
+        state kind's ``state_slot``."""
+        slots = {}
+        if FULL in self.pages:
+            slots[FULL] = getattr(self.pages[FULL], how)(
+                cache[FULL], tables[FULL], *first, safe_pos, valid,
+                block_size)
+        if WINDOW in self.pages:
+            at = first[0] if first else safe_pos
+            behind = self._behind(at, block_size)
+            slots[WINDOW] = getattr(self.pages[WINDOW], how)(
+                cache[WINDOW], tables[WINDOW], *(f - behind for f in first),
+                safe_pos - behind, valid, block_size)
+            slots["behind"] = behind
+        if self.state is not None:
+            slots[STATE] = state_slot
+        return slots
+
     def chunk_slots(self, cache, block_table, start, safe_pos, valid,
                     block_size: int):
-        behind = self._behind(start, block_size)
-        return {"full": self.pages["full"].chunk_slots(
-                    cache["full"], block_table["full"], start, safe_pos,
-                    valid, block_size),
-                "window": self.pages["window"].chunk_slots(
-                    cache["window"], block_table["window"], start - behind,
-                    safe_pos - behind, valid, block_size),
-                "behind": behind}
+        return self._slots(
+            "chunk_slots", cache, block_table, (start,), safe_pos, valid,
+            block_size, self.state and
+            (block_table[STATE], valid, jnp.asarray(start) == 0))
 
     def decode_slots(self, cache, block_tables, safe_pos, valid,
                      block_size: int):
-        behind = self._behind(safe_pos, block_size)
-        return {"full": self.pages["full"].decode_slots(
-                    cache["full"], block_tables["full"], safe_pos, valid,
-                    block_size),
-                "window": self.pages["window"].decode_slots(
-                    cache["window"], block_tables["window"],
-                    safe_pos - behind, valid, block_size),
-                "behind": behind}
+        # a row that is batch padding lands in the slot past the last
+        return self._slots(
+            "decode_slots", cache, block_tables, (), safe_pos, valid,
+            block_size, self.state and jnp.where(
+                valid, block_tables[STATE],
+                cache[STATE]["conv"].shape[1] - 1))
 
     def _attend(self, how, cache, layer, slots, tables, positions, *args,
                 **kwargs):
         kind = self.kinds[layer]
+        if kind == STATE:
+            out, pool = getattr(self.state, how)(
+                cache[kind], self.local[layer], slots[kind], *args, **kwargs)
+            return out, {**cache, kind: pool}
         attend = getattr(self.pages[kind], how)
-        if kind == "window":
+        if kind == WINDOW:
             with jax.named_scope("attn/window"):
                 out, pool = attend(
                     cache[kind], self.local[layer], slots[kind],
@@ -1054,5 +1293,6 @@ def page_kind(spec, cache):
     ``KVCacheSpec`` and the pool's structure: ``(pages, scales)``, pages
     alone, or a pool a layer kind."""
     if isinstance(cache, dict):
-        return _LayerKindPages(spec.layer_windows, spec.kind_pages)
+        return _LayerKindPages(spec.layer_windows, spec.kind_pages,
+                               spec.layer_kinds or (), spec.state_slot)
     return _kind_for(spec.latent_dim, isinstance(cache, tuple))(spec.window)

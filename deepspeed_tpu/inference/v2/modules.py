@@ -81,6 +81,13 @@ class KVCacheSpec:
     # rows, so the cache's count of a call's tiles and copies needs them.
     # None: as many as KV heads
     query_heads: Any = None
+    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``) for
+    # a model some of whose layers keep a recurrent state in a slot and no
+    # pages, and what such a slot holds of a layer
+    # (``kv_cache.StateSlotShape``). None: the kinds follow from
+    # ``layer_windows``
+    layer_kinds: Any = None
+    state_slot: Any = None
 
 
 def register_policy(name: str, config_type: type):
@@ -973,3 +980,101 @@ class MiMoV2Policy:
         x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
         return x.astype(jnp.float32) @ \
             params["lm_head"]["kernel"].astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# Granite 4.0-H (Mamba-2 state-space layers with an attention layer among
+# every ten, a shared gated MLP, scalar multipliers, no positions)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.inference.v2.kv_cache import StateSlotShape  # noqa: E402
+from deepspeed_tpu.models import granite_hybrid as _granite  # noqa: E402
+
+
+@register_policy("granite_hybrid", _granite.GraniteHybridConfig)
+class GraniteHybridPolicy:
+    """models/granite_hybrid.py's serving twin. ``cache_spec`` names every
+    layer's kind, ``full`` (an attention layer's pages) or ``state`` (a
+    Mamba-2 layer's slot: ``StateSlotShape``), and the cache keeps both by
+    layer kind. A Mamba layer's ``block`` computes what needs no state (the
+    first projection, the gated norm, the second projection) and hands the
+    rest to ``attend`` as plain arrays (the rows before the convolution, the
+    step after softplus, the convolution's kernel and bias, ``a_log``, ``D``):
+    the state kind runs the convolution behind the slot's tail and the
+    recurrence from the slot's state, and writes both.
+
+    An attention layer's heads are 64 wide, half a row of the TPU's 128
+    lanes, which a page would pad: ``cfg.kv_pack`` KV heads share a row
+    (their keys side by side, and their values), the cache sees
+    ``num_kv_heads / kv_pack`` heads of 128, and a query head scores against
+    the row with zeros in the other heads' lanes, which is its own head's
+    score exactly; its output is its own head's lanes of the row's."""
+
+    @staticmethod
+    def cache_spec(cfg) -> KVCacheSpec:
+        pack = cfg.kv_pack
+        return KVCacheSpec(
+            cfg.num_layers, cfg.num_kv_heads // pack, cfg.head_dim * pack,
+            cfg.max_seq_len, cfg.dtype, None, query_heads=cfg.num_heads,
+            layer_kinds=tuple("state" if cfg.is_mamba(i) else "full"
+                              for i in range(cfg.num_layers)),
+            state_slot=StateSlotShape(
+                cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state,
+                cfg.mamba_d_conv, cfg.conv_channels, cfg.mamba_chunk_size))
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens] \
+            * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        dtype, eps = cfg.dtype, cfg.rms_norm_eps
+        r = jnp.asarray(cfg.residual_multiplier, dtype)
+        if cfg.is_mamba(i):
+            mp = lp["mamba"]
+            with jax.named_scope("ssm/in_proj"):
+                h = _rms(x, lp["mixer_norm"]["scale"], eps)
+                z, xbc, dt = _granite.split_in_proj(
+                    h @ mp["in_proj"].astype(dtype), cfg)
+                step = jax.nn.softplus(dt.astype(jnp.float32)
+                                       + mp["dt_bias"].astype(jnp.float32))
+            y = attend(xbc, step, mp["conv_kernel"], mp["conv_bias"],
+                       mp["a_log"], mp["d"])
+            with jax.named_scope("ssm/norm"):
+                y = _granite.gated_norm(y, z, mp["norm"], eps)
+            with jax.named_scope("ssm/out_proj"):
+                x = x + r * (y @ mp["out_proj"].astype(dtype))
+        else:
+            with jax.named_scope("attn/qkv"):
+                h = _rms(x, lp["mixer_norm"]["scale"], eps)
+                q, k, v = _qkv(lp, h, dtype)
+                pack, d = cfg.kv_pack, cfg.head_dim
+                # the pages' attention scores at (row width) ** -0.5
+                q = q * jnp.asarray(
+                    cfg.attention_multiplier * (d * pack) ** 0.5, dtype)
+                # query head i reads KV head i // rep, which lies in lanes
+                # ``lane .. lane + d`` of packed head i // (rep * pack)
+                rep = cfg.num_heads // cfg.num_kv_heads
+                lane = (jnp.arange(cfg.num_heads) // rep % pack) * d
+                own = (jnp.arange(d * pack) // d * d)[None, :] \
+                    == lane[:, None]                          # [H, d * pack]
+                q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
+                k, v = (t.reshape(t.shape[0], -1, d * pack) for t in (k, v))
+            attn = attend(q, k, v)
+            with jax.named_scope("attn/out"):
+                attn = jnp.sum(jnp.where(own, attn, 0).reshape(
+                    attn.shape[0], cfg.num_heads, pack, d), axis=2)
+                x = x + r * jnp.einsum("thk,hkd->td", attn,
+                                       lp["attn"]["wo"]["kernel"].astype(dtype))
+        with jax.named_scope("mlp"):
+            h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
+            return x + r * _mlp(lp, h2, dtype), None
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return jnp.einsum("nd,vd->nv", x,
+                          params["embed"]["embedding"].astype(x.dtype),
+                          preferred_element_type=jnp.float32) \
+            / cfg.logits_scaling

@@ -145,6 +145,10 @@ _REGISTRY = {
                              "Xing4ForCausalLM", "convert_hf_xing4"),
     "mimo_v2": _family_entry("mimo_v2", "mimo_v2_config_from_hf",
                              "MiMoV2ForCausalLM", "convert_hf_mimo_v2"),
+    "granitemoehybrid": _family_entry("granite_hybrid",
+                                      "granite_hybrid_config_from_hf",
+                                      "GraniteHybridForCausalLM",
+                                      "convert_hf_granite_hybrid"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

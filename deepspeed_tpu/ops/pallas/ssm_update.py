@@ -1,0 +1,216 @@
+"""One decode step of a selective state-space layer (Mamba-2) over states
+kept in slots: ``S <- a S + (dt x) (x) B``, ``y = S C``, each live slot's
+state read once and written once, in place.
+
+A pool of states is ``[L, slots, G, N, W]`` float32: layer, slot, a group of
+``pack`` heads, the state's ``d_state`` rows, and ``W = pack * head_dim``
+lanes that hold the group's heads side by side (``state_pack``: two heads of
+64 fill the TPU's 128 lanes). With the heads' values in the lanes and the
+state's rows in the sublanes, everything a token brings is a broadcast the
+vector unit has (``a`` and ``dt x`` one value a lane, down the sublanes; ``B``
+and ``C`` one value a sublane, across the lanes) and ``y`` is a sum down the
+sublanes, plain adds of whole registers. The other way round (``d_state`` in
+the lanes) every head's ``y`` is a reduction ACROSS lanes, a rotate-and-add
+ladder a register, and the kernel is bound by that and not by the bytes.
+
+``ssm_update`` is the Pallas kernel: the rows' slots and the layer arrive by
+scalar prefetch (as ``paged_attention.py`` takes its tables), the pool whole
+with ``input_output_aliases`` so that only the tiles of the rows' slots move,
+a grid over (row, tile of groups). ``ssm_update_reference`` is the same step
+in plain ``jax.numpy`` (gather the rows' states, update, scatter): the numerics
+oracle of the kernel's tests, and the path where there is no TPU. Rows that
+are batch padding name the pool's last slot, which no sequence holds.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+#: groups of heads a grid step updates: [GROUP_TILE, d_state, W] float32 is
+#: 1 MiB at 16 x 128 x 128, in and out and double-buffered 4 MiB of VMEM
+GROUP_TILE = 16
+
+
+def state_pack(heads: int, head_dim: int) -> int:
+    """Heads whose values lie side by side in a state's lanes."""
+    return math.gcd(heads, max(LANES // head_dim, 1))
+
+
+def pack_state(state, pack: int):
+    """[..., H, P, N] as the pool stores it, [..., H / pack, N, pack * P]."""
+    *lead, h, p, n = state.shape
+    s = state.reshape(*lead, h // pack, pack, p, n)
+    return jnp.moveaxis(s, -1, -3).reshape(*lead, h // pack, n, pack * p)
+
+
+def unpack_state(stored, pack: int):
+    """The inverse of ``pack_state``: [..., G, N, pack * P] -> [..., H, P, N]."""
+    *lead, g, n, w = stored.shape
+    s = stored.reshape(*lead, g, n, pack, w // pack)
+    return jnp.moveaxis(s, -3, -1).reshape(*lead, g * pack, w // pack, n)
+
+
+def _lanes_of(v, pack: int, head_dim: int):
+    """[B, H] a value a head -> [B, G, W], the value in each of the head's
+    lanes."""
+    b, h = v.shape
+    return jnp.repeat(v.reshape(b, h // pack, pack), head_dim, axis=-1)
+
+
+def _operands(x, dt, a_log, pack: int):
+    """What both paths feed on, float32: ``a`` [B, G, W] (``exp(dt A)`` in
+    each of its head's lanes) and ``dt x`` [B, G, W]."""
+    b, h, p = x.shape
+    dt = dt.astype(jnp.float32)
+    decay = jnp.exp(dt * -jnp.exp(a_log.astype(jnp.float32)))
+    xdt = x.astype(jnp.float32) * dt[..., None]
+    return (_lanes_of(decay, pack, p),
+            xdt.reshape(b, h // pack, pack * p))
+
+
+def ssm_update_reference(pool, layer: int, slots, x, dt, a_log, bm, cm):
+    """pool: [L, slots, G, N, W] float32; slots: [B] int32; x: [B, H, P];
+    dt: [B, H] (after softplus); a_log: [H]; bm, cm: [B, N]. Returns
+    (y [B, H, P] float32 without the skip term, the pool)."""
+    b, h, p = x.shape
+    pack = h // pool.shape[2]
+    a, xdt = _operands(x, dt, a_log, pack)
+    s = pool[layer, slots]                                    # [B, G, N, W]
+    s = a[:, :, None, :] * s + \
+        bm.astype(jnp.float32)[:, None, :, None] * xdt[:, :, None, :]
+    y = jnp.sum(s * cm.astype(jnp.float32)[:, None, :, None], axis=2)
+    return y.reshape(b, h, p), pool.at[layer, slots].set(s)
+
+
+def _column(row):
+    """[1, N] -> [N, 1]: the row's values down the sublanes. One masked
+    lane-reduction of an [N, N] tile a grid step, in the place of a relayout."""
+    n = row.shape[-1]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _update_kernel(slots_ref, layer_ref, a_ref, xdt_ref, b_ref, c_ref, s_ref,
+                   y_ref, s_out_ref, *, groups: int):
+    del slots_ref, layer_ref                  # the index maps read them
+    bcol = _column(b_ref[0])                                  # [N, 1]
+    ccol = _column(c_ref[0])
+    for g in range(groups):
+        s = a_ref[0, g:g + 1, :] * s_ref[0, 0, g] \
+            + bcol * xdt_ref[0, g:g + 1, :]                   # [N, W]
+        s_out_ref[0, 0, g] = s.astype(s_out_ref.dtype)
+        y_ref[0, g:g + 1, :] = jnp.sum(s * ccol, axis=0, keepdims=True)
+
+
+def ssm_update(pool, layer: int, slots, x, dt, a_log, bm, cm,
+               interpret: bool = False):
+    """``ssm_update_reference`` as one Pallas call that moves the tiles of
+    the rows' slots alone and leaves the rest of the pool where it is."""
+    b, h, p = x.shape
+    pack = h // pool.shape[2]
+    a, xdt = _operands(x, dt, a_log, pack)
+    y, pool = _update_call(
+        slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        a, xdt, bm.astype(jnp.float32)[:, None, :],
+        cm.astype(jnp.float32)[:, None, :], pool, interpret=interpret)
+    return y.reshape(b, h, p), pool
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _update_call(slots, layer, a, xdt, bm, cm, pool, *, interpret: bool):
+    """The kernel call, under a ``jit`` of its own with the layer a value:
+    a step program traces and lowers it once and calls it once a layer."""
+    b, groups, w = a.shape
+    n = pool.shape[3]
+    tile = GROUP_TILE if groups % GROUP_TILE == 0 else groups
+    row = lambda i, t, slots, layer: (i, t, 0)
+    vec = lambda i, t, slots, layer: (i, 0, 0)
+    state = lambda i, t, slots, layer: (layer[0], slots[i], t, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b, groups // tile),
+        in_specs=[pl.BlockSpec((1, tile, w), row),
+                  pl.BlockSpec((1, tile, w), row),
+                  pl.BlockSpec((1, 1, n), vec), pl.BlockSpec((1, 1, n), vec),
+                  pl.BlockSpec((1, 1, tile, n, w), state)],
+        out_specs=[pl.BlockSpec((1, tile, w), row),
+                   pl.BlockSpec((1, 1, tile, n, w), state)])
+    return pl.pallas_call(
+        functools.partial(_update_kernel, groups=tile),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, groups, w), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 6 (after the two prefetched scalars) is the pool
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssm_update",
+    )(slots, layer, a, xdt, bm, cm, pool)
+
+
+# --- one slot's state in and out, for a prefill chunk -------------------------
+# A chunk reads its sequence's state, runs the closed form in plain
+# ``jax.numpy`` and writes the state back. Written ``pool[layer, slot]`` and
+# ``pool.at[layer, slot].set``, XLA on the TPU gives the slice the layout its
+# transposed consumer likes, hands that layout up the chain of updates to the
+# pool itself, and copies the WHOLE pool into it and back, 4.6 GB each way a
+# step (my chip run, PR 44: a 2,048-token chunk program asked for 16.78 GB of
+# the chip's 15.75). A kernel's operands keep the layout they have, so these
+# two move one slot's tiles and nothing else.
+
+def _copy_kernel(slot_ref, layer_ref, src_ref, dst_ref):
+    del slot_ref, layer_ref
+    dst_ref[...] = src_ref[...].reshape(dst_ref.shape)
+
+
+def _slot_specs(groups: int, n: int, w: int):
+    tile = GROUP_TILE if groups % GROUP_TILE == 0 else groups
+    return tile, pl.BlockSpec((tile, n, w), lambda t, slot, layer: (t, 0, 0)), \
+        pl.BlockSpec((1, 1, tile, n, w),
+                     lambda t, slot, layer: (layer[0], slot[0], t, 0, 0))
+
+
+def _scalars(layer, slot):
+    return (jnp.asarray(slot, jnp.int32).reshape(1),
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def slot_read(pool, layer, slot, interpret: bool = False):
+    """``pool[layer, slot]`` [G, N, W], by a kernel."""
+    _, _, groups, n, w = pool.shape
+    tile, one, in_pool = _slot_specs(groups, n, w)
+    return pl.pallas_call(
+        _copy_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(groups // tile,),
+            in_specs=[in_pool], out_specs=one),
+        out_shape=jax.ShapeDtypeStruct((groups, n, w), pool.dtype),
+        interpret=interpret, name="ssm_slot_read",
+    )(*_scalars(layer, slot), pool)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def slot_write(pool, layer, slot, state, interpret: bool = False):
+    """``pool.at[layer, slot].set(state)`` in place, by a kernel."""
+    _, _, groups, n, w = pool.shape
+    tile, one, in_pool = _slot_specs(groups, n, w)
+    return pl.pallas_call(
+        lambda slot_ref, layer_ref, src_ref, _, dst_ref: _copy_kernel(
+            slot_ref, layer_ref, src_ref, dst_ref),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(groups // tile,),
+            in_specs=[one, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=in_pool),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operand 3 (after the two prefetched scalars and the state) is the
+        # pool: written where the index map says, left alone elsewhere
+        input_output_aliases={3: 0},
+        interpret=interpret, name="ssm_slot_write",
+    )(*_scalars(layer, slot), state.astype(pool.dtype), pool)

@@ -136,7 +136,10 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     "serve/kv_bytes": ("counter",),
     # blocks held by kind of page a tick, and their bytes: `kv_held_bytes`,
     # and by kind `kv_full_bytes` and `kv_window_bytes`, each from a block of
-    # the kind's own pool (its KV heads, its key and value rows as stored)
+    # the kind's own pool (its KV heads, its key and value rows as stored);
+    # over a cache some of whose layers keep a recurrent state, and only
+    # there, `state_slots_held` (the sequences that hold a slot) and
+    # `kv_state_bytes` (what their slots hold, whatever their lengths)
     "serve/kv_pages": ("counter",),
     "serve/tick_stage_share": ("counter",),
     "serve/kv_tier": ("counter",),
@@ -243,12 +246,19 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: where a cache keeps pages by layer kind (whatever heads and widths a kind
 #: states, with or without sinks), ``attn/gate`` a per-head output
 #: gate, ``hc/pre``, ``hc/post`` and ``hc/head`` the mixing of several
-#: residual streams round a sublayer (``inference/v2/hyper_connection.py``)
+#: residual streams round a sublayer (``inference/v2/hyper_connection.py``),
+#: the ``ssm/*`` six a state-space (Mamba-2) mixer: its first projection,
+#: the causal convolution behind the slot's tail, a prefill chunk's
+#: closed-form scan from the slot's state, a decode batch's in-place update
+#: of each row's state (``kv_cache._StateSlots``), the gated norm, the
+#: second projection
 SERVED_SCOPES: Tuple[str, ...] = (
     "embed", "attn/qkv", "attn/kv_write", "attn/paged", "attn/out",
     "attn/full", "attn/window", "attn/gate", "attn/latent_q", "attn/latent_write", "attn/latent_paged",
     "attn/latent_prefill", "mlp", "moe/router", "moe/experts", "moe/shared",
-    "lm_head", "sample", "hc/pre", "hc/post", "hc/head")
+    "lm_head", "sample", "hc/pre", "hc/post", "hc/head",
+    "ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/update", "ssm/norm",
+    "ssm/out_proj")
 
 #: counts a step program computes on the device where its policy's layers
 #: count (``generic_decode.py``), in the order of the int32 vector it hands

@@ -354,3 +354,140 @@ def test_more_than_one_window_size_is_refused_by_name():
             num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
             num_blocks=NB, dtype=jnp.float32, layer_windows=(None, 6, 8),
             window_blocks=7))
+
+
+# --- a kind that is a slot and no block table ----------------------------------
+
+@pytest.mark.parametrize("impl", ["gather", "kernel_interpret"])
+def test_state_kind_is_a_slot_a_sequence_beside_the_full_layers_pages(impl):
+    """A cache over one attention layer and two layers that keep a recurrent
+    state: the kinds are data (``layer_kinds``), the full layers' pool is
+    what it is in any cache by layer kind and has the one allocator, the
+    state kind's pool is ``{"ssm", "conv"}`` with a slot a sequence and one
+    more for padding; a step's ``"state"`` table entry IS the slot; a chunk
+    that starts at 0 reads nothing of the slot's last holder, a padding row
+    of a decode batch lands in the slot past the last; what takes a cache to
+    be its pages refuses by name."""
+    from deepspeed_tpu.inference.v2.kv_cache import (StateKindError,
+                                                     StateSlotShape)
+    from deepspeed_tpu.ops.pallas.ssm_update import unpack_state
+    kinds = ("state", "full", "state")
+    heads, p, n, taps = 4, 8, 16, 4
+    channels = heads * p + 2 * n
+    at = StateSlotShape(heads, p, n, taps, channels, scan_block=4)
+    slots_n = 3
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+        num_blocks=NB, dtype=jnp.float32, layer_kinds=kinds, state_slot=at,
+        state_slots=slots_n))
+    spec = KVCacheSpec(3, H, D, 64, jnp.float32, None, layer_kinds=kinds,
+                       state_slot=at)
+    kind = page_kind(spec, kv.pool)
+    assert type(kind) is type(kv.kind)
+    assert kv.by_layer_kind and kv.has_state and not kv.two_kinds
+    assert kv.window_allocator is None and kind.window is None
+    assert (at.pack, at.stored, at.tail) == (4, (1, n, 4 * p), 3 * channels)
+    assert jax.tree.map(lambda x: x.shape, kv.pool) == {
+        "full": (1, 2, H, NB, BS, D),
+        "state": {"ssm": (2, slots_n + 1, 1, n, 4 * p),
+                  "conv": (2, slots_n + 1, 3 * channels)}}
+    assert kv.pool["state"]["ssm"].dtype == jnp.float32
+    assert kind.local == (0, 0, 1) and kv.usable_blocks == NB - 1
+    assert kv.slot_bytes == 2 * (heads * p * n * 4 + 3 * channels * 4)
+    assert kv.blocks_needed(40) == 10 and kv.pages_held()["held_bytes"] == 0
+
+    key = jax.random.split(jax.random.PRNGKey(0), 8)
+    w = {"conv_kernel": jax.random.normal(key[0], (channels, taps)) * 0.3,
+         "conv_bias": jax.random.normal(key[1], (channels,)) * 0.1,
+         "dt_bias": jnp.full((heads,), -1.0),
+         "a_log": jnp.log(jnp.linspace(1.0, 8.0, heads)),
+         "d": jnp.ones((heads,))}
+    # every slot dirty, as its last holder left it
+    pool = {**kv.pool, "state": jax.tree.map(
+        lambda x: jnp.full_like(x, 0.5), kv.pool["state"])}
+    # a chunk of 6 rows, 5 of them real, from position 0, into slot 1
+    rows, real = 6, 5
+    table = {"full": jnp.asarray([7, 2, TRASH], jnp.int32),
+             "state": jnp.int32(1)}
+    valid = jnp.arange(rows) < real
+    slots = kind.chunk_slots(pool, table, 0, jnp.arange(rows), valid, BS)
+    xbc = jax.random.normal(key[2], (rows, channels))
+    dt = jax.random.normal(key[3], (rows, heads))
+    before = jax.tree.map(jnp.copy, pool)
+    # the policy's part: the step after its softplus, the parameters as
+    # plain arrays
+    step_of = lambda dt: jax.nn.softplus(dt + w["dt_bias"])
+    plain = (w["conv_kernel"], w["conv_bias"], w["a_log"], w["d"])
+    out, pool = kind.attend_chunk(pool, 2, slots, table, 0, impl, xbc,
+                                  step_of(dt), *plain)
+    assert out.shape == (rows, heads * p)
+    # layer 2 is the state kind's second; slots 0, 2, 3, the other layer and
+    # the pages are what they were
+    for name in ("ssm", "conv"):
+        got, was = pool["state"][name], before["state"][name]
+        np.testing.assert_array_equal(got[0], was[0])
+        np.testing.assert_array_equal(got[1, [0, 2, 3]], was[1, [0, 2, 3]])
+    np.testing.assert_array_equal(pool["full"], before["full"])
+    # ... and slot 1 holds what five tokens from a ZERO state leave: the
+    # recurrence token by token, and the last three real rows
+    from deepspeed_tpu.ops import ssm
+    conv, joined = ssm.causal_conv(xbc[:real], jnp.zeros((3, channels)),
+                                   w["conv_kernel"], w["conv_bias"])
+    x = conv[:, :heads * p].reshape(real, heads, p)
+    step = jax.nn.softplus(dt[:real] + w["dt_bias"])
+    y, s = ssm.ssm_token_scan(x, step, w["a_log"], conv[:, heads * p:-n],
+                              conv[:, -n:], jnp.zeros((heads, p, n)))
+    np.testing.assert_allclose(
+        unpack_state(pool["state"]["ssm"][1, 1], at.pack), s, atol=1e-5)
+    np.testing.assert_array_equal(
+        pool["state"]["conv"][1, 1].reshape(3, channels), xbc[real - 3:real])
+    np.testing.assert_allclose(
+        out[:real], (y + x).reshape(real, -1), atol=1e-5)
+
+    # a decode batch of 3, the middle one batch padding, slots 2 and 0
+    tables = {"full": jnp.asarray([[3, 4], [TRASH] * 2, [6, 1]], jnp.int32),
+              "state": jnp.asarray([2, slots_n, 0], jnp.int32)}
+    valid = jnp.asarray([True, False, True])
+    slots = kind.decode_slots(pool, tables, jnp.asarray([5, 0, 8]), valid, BS)
+    assert slots["state"].tolist() == [2, slots_n, 0]
+    # (whatever a padding row's table names, it lands in the last slot)
+    assert kind.decode_slots(
+        pool, {**tables, "state": jnp.asarray([2, 1, 0], jnp.int32)},
+        jnp.asarray([5, 0, 8]), valid, BS)["state"].tolist() == [2, slots_n, 0]
+    before = jax.tree.map(jnp.copy, pool)
+    out, pool = kind.attend_decode(
+        pool, 0, slots, tables, jnp.asarray([5, 0, 8]), impl,
+        jax.random.normal(key[4], (3, channels)),
+        step_of(jax.random.normal(key[5], (3, heads))), *plain)
+    assert out.shape == (3, heads * p)
+    for name in ("ssm", "conv"):
+        got, was = pool["state"][name], before["state"][name]
+        np.testing.assert_array_equal(got[1], was[1])
+        np.testing.assert_array_equal(got[0, 1], was[0, 1])
+        for slot in (0, 2, slots_n):
+            assert not np.array_equal(got[0, slot], was[0, slot])
+    # a tail moves on by one row
+    np.testing.assert_array_equal(
+        pool["state"]["conv"][0, 2].reshape(3, channels)[:2],
+        before["state"]["conv"][0, 2].reshape(3, channels)[1:])
+
+    for moved in (lambda: kv.gather_blocks([0]),
+                  lambda: kv.require_one_kind("the prefix cache")):
+        with pytest.raises(StateKindError, match="recurrent state"):
+            moved()
+    with pytest.raises(StateKindError, match="fp8"):
+        BlockedKVCache(KVCacheConfig(
+            num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+            num_blocks=NB, dtype=FP8, layer_kinds=kinds, state_slot=at,
+            state_slots=slots_n))
+    for bad in (dict(layer_kinds=kinds), dict(layer_kinds=("full", "linear")),
+                dict(layer_kinds=("full", "window"), state_slot=None)):
+        with pytest.raises(ValueError, match="layer kinds"):
+            BlockedKVCache(KVCacheConfig(
+                num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+                num_blocks=NB, dtype=jnp.float32, **bad))
+    with pytest.raises(ValueError, match="state_slots"):
+        BlockedKVCache(KVCacheConfig(
+            num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
+            num_blocks=NB, dtype=jnp.float32, layer_kinds=kinds,
+            state_slot=at))

@@ -549,3 +549,70 @@ def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
     if fn_name != "verify_chunk_g":
         assert stats.temp_size_in_bytes < pool_bytes
     assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+
+
+# --- a state kind beside the pages (granite-4.0-h-micro's widths) --------------
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
+                                                                 fn_name):
+    """Three layers of granite-4.0-h-micro at the published widths (Mamba-2,
+    attention, Mamba-2) with the cell's 64 slots and 3,073 blocks: the pages
+    AND the states are aliased in the executable (what ``serve/kv_alias``
+    reports), the decode program holds next to nothing beside its arguments,
+    the update is the Pallas kernel, lowered once for both Mamba layers, and
+    the attention layer's pages are two 64-wide KV heads a 128-lane row."""
+    from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache
+    from deepspeed_tpu.models import granite_hybrid as gh
+    cfg = gh.GraniteHybridConfig(
+        layer_types=(gh.MAMBA, gh.ATTENTION, gh.MAMBA), max_seq_len=4096)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(gh.GraniteHybridForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    policy = policy_for(cfg)
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: BlockedKVCache.for_spec(policy.cache_spec(cfg), "model", 64,
+                                        3073, state_slots=64).pool))
+    assert jax.tree.map(lambda x: x.shape, pool) == {
+        "full": (1, 2, 4, 3073, 64, 128),
+        "state": {"ssm": (2, 65, 32, 128, 128), "conv": (2, 65, 3 * 4352)}}
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+    if fn_name == "decode_step_g":
+        tail = (ints(64), ints(64), {"full": ints(64, 48), "state": ints(64)},
+                jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=one_chip))
+    else:
+        tail = (ints(2048), ints(), {"full": ints(48), "state": ints()},
+                ints())
+    lowered = getattr(gd, fn_name).lower(
+        params, pool, *tail, policy=policy, cfg=cfg, block_size=64,
+        attn_impl="kernel")
+    compiled = lowered.compile()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in compiled.as_text().splitlines()[0]
+    assert "paged_attention" in compiled.as_text()
+    if fn_name == "decode_step_g":
+        assert stats.temp_size_in_bytes < 64 << 20
+        assert lowered.as_text().count('kernel_name = "ssm_update"') == 1
+        assert "ssm_update" in compiled.as_text()
+    else:
+        # a 2,048-token chunk's closed form: blocks of 256, scores a head;
+        # and no operation makes a value of the states' pool's shape (read
+        # as a slice, the pool was copied whole into another layout and
+        # back: ``ops/pallas/ssm_update.py`` ``slot_read``)
+        assert stats.temp_size_in_bytes < 256 << 20
+        entry = compiled.as_text()
+        entry = entry[entry.index("\nENTRY"):]
+        assert [line.strip()[:120] for line in entry.splitlines()
+                if re.search(r"= \(?f32\[2,65,32,128,128\]\S* (copy|fusion)\(",
+                             line)] == []
+        assert "ssm_slot_read" in entry and "ssm_slot_write" in entry
