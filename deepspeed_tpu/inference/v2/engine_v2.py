@@ -5,9 +5,12 @@ Reference analog: ``deepspeed/inference/v2/engine_v2.py:30``
 forward; ``query``/``can_schedule`` gate admission on free KV blocks; the state
 manager + blocked KV cache hold per-sequence context.
 
-TPU adaptation: per step, the SplitFuse plan becomes (a) one bucketed
-``prefill_chunk_g`` call per admitted chunk and (b) one padded
-``decode_step_g`` call for all running decodes (``generic_decode.py``) — every
+TPU adaptation: per step, the SplitFuse plan becomes one bucketed
+``prefill_chunk_g`` call per admitted chunk, the last of which carries the
+running decodes' rows through the layers with its own (one forward pass a
+step, every weight read once: the reference's ragged forward at static
+shapes), or, where the plan holds no chunk (and always over a pool with a
+state kind), one padded ``decode_step_g`` call (``generic_decode.py``) — every
 shape from a small bucket ladder, so steady-state serving runs entirely from
 compiled programs. What a KV page is, the engine leaves to ``kv_cache.py``.
 
@@ -217,6 +220,28 @@ class InferenceEngineV2:
         # tokens/positions are [B] ints and always refresh)
         self._table_sig = None
         self._dev_tables = None
+        # the decode half every chunk program carries, (rows, table blocks):
+        # the largest decode batch bucket over the widest context bucket a
+        # sequence can reach (the model's longest context, in a pool that
+        # holds it), whatever a tick decodes, so that a chunk program's
+        # shape follows from its chunk and this configuration alone; and a
+        # half of padding, for the chunks that carry no decode rows. None
+        # over a pool with a state kind: there a tick keeps its two programs
+        # (the fused step read 2-5% UNDER them on the chip, cause not found:
+        # PERF.md section 6, PR 45; ROADMAP S1(f))
+        sched = self.config.scheduler
+        self._fused_decode = self._no_decode = None
+        if not self.kv.has_state:
+            b = snap_bucket(min(sched.max_decode_batch,
+                                self.state.max_tracked_sequences),
+                            self.config.decode_batch_buckets)
+            mb = snap_bucket(min(self.kv.blocks_of(spec.max_seq_len),
+                                 self.kv.cfg.num_blocks - 1),
+                             self.config.ctx_block_buckets)
+            self._fused_decode = (b, mb)
+            self._no_decode = (
+                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+                self._decode_tables((), b, mb), jnp.zeros((b,), bool))
         # host-RAM KV offload tier (serving demotion target; kv_offload.py)
         self.host_kv = HostKVStore()
         # radix prefix cache over KV pages (prefix_cache.py); None = off
@@ -415,22 +440,80 @@ class InferenceEngineV2:
 
     def _decode_tables(self, seqs, batch: int, bucket_blocks: int):
         """The decode batch's block tables on the device, [batch, ...] a
-        kind, the rows that are batch padding all trash."""
+        kind, the rows that are batch padding (all of them where ``seqs`` is
+        empty) all trash."""
+        cfg = self.kv.cfg
+        # what pads a kind's rows: its trash block, or the slot past the last
+        pad = {"full": np.full((bucket_blocks,), cfg.num_blocks - 1, np.int32)}
+        if self.kv.two_kinds:
+            pad["window"] = np.full(
+                (self.kv.window_steady_blocks,), cfg.window_blocks - 1,
+                np.int32)
+        if self.kv.has_state:
+            pad["state"] = np.int32(self._pad_slot)
         rows = [self._step_tables(seq, bucket_blocks, seq.total_tokens - 1, 1)
                 for seq in seqs]
 
-        def stacked(tables, trash):
-            out = np.full((batch,) + tables[0].shape, trash, np.int32)
-            out[:len(tables)] = tables
+        def stacked(tables, pad):
+            out = np.broadcast_to(pad, (batch,) + pad.shape).copy()
+            if tables:
+                out[:len(tables)] = tables
             return jnp.asarray(out)
-        cfg = self.kv.cfg
         if not self.kv.by_layer_kind:
-            return stacked(rows, cfg.num_blocks - 1)
-        # what pads a kind's rows: its trash block, or the slot past the last
-        pads = {"full": cfg.num_blocks - 1, "window": cfg.window_blocks - 1,
-                "state": self._pad_slot}
-        return {kind: stacked([r[kind] for r in rows], pads[kind])
-                for kind in rows[0]}
+            return stacked(rows, pad["full"])
+        return {kind: stacked([r[kind] for r in rows], pad[kind])
+                for kind in pad}
+
+    def _decode_rows(self, seqs, batch: int, bucket_blocks: int):
+        """A decode batch of ``seqs`` as a step program takes it, padded to
+        ``batch`` rows over tables of ``bucket_blocks``: (``rows`` [2, batch]
+        int32: row j's slot in the last-token vector, and its token where the
+        host holds it (-1: read the slot); (positions, block tables, valid)
+        on the device; every sequence's context in tokens; whether the
+        tables were built anew). Reserves the blocks the rows land in."""
+        rows = np.full((2, batch), -1, np.int32)
+        rows[0] = self._pad_slot
+        positions = np.zeros((batch,), np.int32)
+        valid = np.zeros((batch,), bool)
+        for j, seq in enumerate(seqs):
+            self._ensure_blocks(seq, seq.total_tokens)
+            rows[0, j] = seq.slot
+            if not seq.token_on_device:
+                rows[1, j] = seq.generated[-1] if seq.generated \
+                    else seq.prompt_tokens[-1]
+            positions[j] = seq.total_tokens - 1
+            valid[j] = True
+        # signature covers the actual block ids: uid reuse after
+        # flush() can hand a same-shaped batch different pages
+        # (a windowed block given back resets the signature:
+        # ``_advanced``)
+        sig = (batch, bucket_blocks, tuple(tuple(s.blocks) for s in seqs))
+        if self.kv.has_state:
+            sig += (tuple(s.slot for s in seqs),)
+        rebuilt = sig != self._table_sig
+        if rebuilt:
+            self._dev_tables = self._decode_tables(seqs, batch, bucket_blocks)
+            self._table_sig = sig
+        return rows, (jnp.asarray(positions), self._dev_tables,
+                      jnp.asarray(valid)), \
+            [s.total_tokens for s in seqs], rebuilt
+
+    def _decoded(self, rec: "_PendingStep", seqs, logits, rows) -> None:
+        """A decode batch's logits are on their way: sample on the device
+        (only [B] token ids cross to the host: the [B, vocab] logits D2H
+        fetch would dominate the loop) and move ``seqs`` on by their row. A
+        batch of padding alone is sampled too (one path, and the sampler's
+        shapes warm whether a tick decodes or not) and leaves no record."""
+        sampled = self._sample_dispatch(logits, rows)
+        if not seqs:
+            return
+        rec.decode_sampled = sampled
+        rec.decode_seqs = seqs
+        for seq in seqs:
+            seq.seen_tokens = seq.total_tokens
+            self._advanced(seq)
+            seq.in_flight += 1
+            seq.token_on_device = True
 
     def _advanced(self, seq: SequenceDescriptor) -> None:
         """``seq.seen_tokens`` moved on: over pages by layer kind, give back
@@ -605,7 +688,7 @@ class InferenceEngineV2:
                 + [c.seq.uid for c in plan.prefill_chunks]))
             self._pending.append(rec)
 
-        # --- prefill chunks (SplitFuse) ---
+        # --- prefill chunks (SplitFuse), the decode batch in the last ---
         d.prefill_t0 = time.monotonic()
         for chunk in plan.prefill_chunks:
             seq = chunk.seq
@@ -619,6 +702,20 @@ class InferenceEngineV2:
             tokens[:chunk.length] = seq.prompt_tokens[chunk.start:end]
             mb = self._ctx_bucket_blocks(end)
             table = self._step_tables(seq, mb, chunk.start, chunk.bucket)
+            # every chunk program carries a decode half of one shape
+            # (``_fused_decode``): the tick's decode rows ride in its last
+            # chunk's, so that the layers' weights are read once for both;
+            # any other chunk's half is padding (over a state kind no chunk
+            # carries one, and the decode rows follow in their own program)
+            last = chunk is plan.prefill_chunks[-1]
+            fused = last and self._fused_decode is not None
+            if fused:
+                rows, half, _, _ = self._decode_rows(
+                    plan.decode_seqs, *self._fused_decode)
+                rows = jnp.asarray(rows)
+                half = (feed_tokens(self._last_tokens, rows),) + half
+            else:
+                half = self._no_decode
             if d.ahead and chunk is plan.prefill_chunks[0]:
                 d.starved = self._device_ran_dry()
             # the step programs consume the pool they are given: what
@@ -628,10 +725,12 @@ class InferenceEngineV2:
             logits, self.kv.pool, counts = prefill_chunk_g(
                 self.params, self.kv.pool, jnp.asarray(tokens),
                 chunk.start,
-                jax.tree.map(jnp.asarray, table), chunk.length,
+                jax.tree.map(jnp.asarray, table), chunk.length, half,
                 policy=self.policy, cfg=self.model_config,
                 block_size=self.kv.cfg.block_size,
                 attn_impl=self.config.attn_impl)
+            if half is not None:
+                logits, decode_logits = logits
             self._keep_counts(rec, counts)
             seq.seen_tokens = end
             self._advanced(seq)
@@ -646,94 +745,67 @@ class InferenceEngineV2:
                     seq.uid, seq.prompt_tokens, seq.blocks,
                     min(seq.seen_tokens, len(seq.prompt_tokens)))
             if not seq.in_prefill:
-                rows = np.full((2, 1), -1, np.int32)
-                rows[0] = seq.slot
+                slot = np.full((2, 1), -1, np.int32)
+                slot[0] = seq.slot
                 sampled = self._sample_dispatch(logits[None],
-                                                jnp.asarray(rows))
+                                                jnp.asarray(slot))
                 seq.in_flight += 1
                 seq.token_on_device = True
                 rec.chunks[-1] = (seq, chunk.start, chunk.length, sampled)
-            # ... and the keys of the tiles the paged kernel multiplied the
-            # chunk's rows by and the page copies a layer's call issued
-            d.chunk_marks.append((c0, time.monotonic(), dict(
-                uid=seq.uid, tokens=chunk.length, bucket=chunk.bucket,
-                start=chunk.start, **(self.kv.chunk_tile_keys(
-                    chunk.start, chunk.bucket, mb, self._window)
-                    if tracer.enabled else {}))))
+            # ... the keys of the tiles the paged kernel multiplied the
+            # chunk's rows by and the page copies a layer's call issued,
+            # and on the tick's last chunk what rode in its decode half
+            # (no ``serve/step_decode`` span says it: the decode rooflines
+            # set that span's bytes against ``decode_step_g``'s kernels)
+            args = dict(uid=seq.uid, tokens=chunk.length, bucket=chunk.bucket,
+                        start=chunk.start, **(self.kv.chunk_tile_keys(
+                            chunk.start, chunk.bucket, mb, self._window)
+                            if tracer.enabled else {}))
+            if fused:
+                self._decoded(rec, plan.decode_seqs, decode_logits, rows)
+            if last:
+                args.update(fused_rows=len(plan.decode_seqs) if fused else 0)
+            d.chunk_marks.append((c0, time.monotonic(), args))
         if plan.prefill_chunks:
             d.t_prefill = time.monotonic() - d.prefill_t0
+            if self._fused_decode is not None or not plan.decode_seqs:
+                return d
 
-        # --- decode batch ---
-        if plan.decode_seqs:
-            d.decode_t0 = time.monotonic()
-            with tracer.span("serve/decode_build", cat="serve",
-                             tick=tick) as build:
-                seqs = plan.decode_seqs
-                b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
-                contexts = [s.total_tokens for s in seqs]
-                mb = self._ctx_bucket_blocks(max(contexts))
-                # row j's slot in the last-token vector, and its token
-                # where the host holds it (-1: read the slot)
-                rows = np.full((2, b), -1, np.int32)
-                rows[0] = self._pad_slot
-                positions = np.zeros((b,), np.int32)
-                valid = np.zeros((b,), bool)
-                for j, seq in enumerate(seqs):
-                    self._ensure_blocks(seq, seq.total_tokens)
-                    rows[0, j] = seq.slot
-                    if not seq.token_on_device:
-                        rows[1, j] = seq.generated[-1] if seq.generated \
-                            else seq.prompt_tokens[-1]
-                    positions[j] = seq.total_tokens - 1
-                    valid[j] = True
-                # signature covers the actual block ids: uid reuse after
-                # flush() can hand a same-shaped batch different pages
-                # (a windowed block given back resets the signature:
-                # ``_advanced``)
-                sig = (b, mb, tuple(tuple(s.blocks) for s in seqs))
-                if self.kv.has_state:
-                    sig += (tuple(s.slot for s in seqs),)
-                rebuilt = sig != self._table_sig
-                if rebuilt:
-                    self._dev_tables = self._decode_tables(seqs, b, mb)
-                    self._table_sig = sig
-                build.note(tables_rebuilt=rebuilt)
-            with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
-                if d.ahead and not plan.prefill_chunks:
-                    d.starved = self._device_ran_dry()
-                rows = jnp.asarray(rows)
-                logits, self.kv.pool, counts = decode_step_g(
-                    self.params, self.kv.pool,
-                    feed_tokens(self._last_tokens, rows),
-                    jnp.asarray(positions), self._dev_tables,
-                    jnp.asarray(valid),
-                    policy=self.policy, cfg=self.model_config,
-                    block_size=self.kv.cfg.block_size,
-                    attn_impl=self.config.attn_impl)
-                self._keep_counts(rec, counts)
-                # sample on device; only [B] token ids cross to the host —
-                # the [B, vocab] logits D2H fetch would dominate the loop
-                rec.decode_sampled = self._sample_dispatch(logits, rows)
-                rec.decode_seqs = seqs
-                for seq in seqs:
-                    seq.seen_tokens = seq.total_tokens
-                    self._advanced(seq)
-                    seq.in_flight += 1
-                    seq.token_on_device = True
-            # what the scheduler decided, as plain host ints the step
-            # already holds: the batch and the bucket it was padded to,
-            # the context the paged kernel had to read (whole, and cut
-            # to the sliding window where the model has one), the keys of
-            # the tiles it read them in and the slot copies a layer's call
-            # issued for them
-            if tracer.enabled:
-                window, whole = self._window, sum(contexts)
-                d.decode_args = dict(
-                    batch=len(seqs), bucket=b, ctx_tokens=whole,
-                    ctx_tokens_windowed=sum(min(c, window) for c in contexts)
-                    if window else whole, ctx_blocks=mb,
-                    **self.kv.decode_tile_keys(contexts, mb, window),
-                    **self.kv.decode_slot_copies(contexts, b, mb, window))
+        # --- decode rows that rode in no chunk: their own program ---
+        d.decode_t0 = time.monotonic()
+        with tracer.span("serve/decode_build", cat="serve",
+                         tick=tick) as build:
+            seqs = plan.decode_seqs
+            b = snap_bucket(len(seqs), self.config.decode_batch_buckets)
+            mb = self._ctx_bucket_blocks(max(s.total_tokens for s in seqs))
+            rows, half, contexts, rebuilt = self._decode_rows(seqs, b, mb)
+            build.note(tables_rebuilt=rebuilt)
+        with tracer.span("serve/decode_dispatch", cat="serve", tick=tick):
+            if d.ahead and not plan.prefill_chunks:
+                d.starved = self._device_ran_dry()
+            rows = jnp.asarray(rows)
+            logits, self.kv.pool, counts = decode_step_g(
+                self.params, self.kv.pool,
+                feed_tokens(self._last_tokens, rows), *half,
+                policy=self.policy, cfg=self.model_config,
+                block_size=self.kv.cfg.block_size,
+                attn_impl=self.config.attn_impl)
+            self._keep_counts(rec, counts)
+            self._decoded(rec, seqs, logits, rows)
+        # what the scheduler decided, as plain host ints the step
+        # already holds: the batch and the bucket it was padded to,
+        # the context the paged kernel had to read (whole, and cut
+        # to the sliding window where the model has one), the keys of
+        # the tiles it read them in and the slot copies a layer's call
+        # issued for them
+        if tracer.enabled:
+            window, whole = self._window, sum(contexts)
+            d.decode_args = dict(
+                batch=len(seqs), bucket=b, ctx_tokens=whole,
+                ctx_tokens_windowed=sum(min(c, window) for c in contexts)
+                if window else whole, ctx_blocks=mb,
+                **self.kv.decode_tile_keys(contexts, mb, window),
+                **self.kv.decode_slot_copies(contexts, b, mb, window))
         return d
 
     def _device_ran_dry(self) -> Optional[int]:

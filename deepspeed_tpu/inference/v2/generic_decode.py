@@ -9,6 +9,13 @@ step's rows land, their write, and the attention over the pages. The loop
 binds the two: each layer's block is handed one ``attend``, which passes what
 the block computed on to the kind with the layer and the step's slots.
 
+What a tick dispatches: the reference runs a prompt's chunk and the running
+sequences' tokens as one ragged forward a step (FastGen's Dynamic SplitFuse).
+Here that is ``prefill_chunk_g`` handed a decode half: the chunk's rows and
+the decode batch's go through the embedding, every block and the output head
+together, and only ``attend`` tells them apart, by the operands the kind
+calls rows. A step of decode rows alone is ``decode_step_g``.
+
 Every phase of a step sits under a ``jax.named_scope`` whose name reaches the
 device trace (an operation's ``tf_op``): ``embed`` and ``lm_head`` here,
 ``attn/kv_write``, ``attn/paged``, the ``attn/latent_*`` scopes and a state
@@ -17,7 +24,8 @@ kind's ``ssm/conv``, ``ssm/scan`` and ``ssm/update`` in the kinds, ``attn/qkv``,
 same.
 
 A block returns ``(x, counts or None)``; the three step programs return
-``(logits, cache, counts)``, the counts one int32 vector of
+``(logits, cache, counts)`` (the logits a pair, the chunk's and the decode
+rows', where a chunk carries a decode half), the counts one int32 vector of
 ``telemetry/names.py`` ``STEP_COUNTER_ARGS`` summed over the layers that
 count (sums over what a router has anyway), and empty where none does.
 ``cache_data`` is whatever the kind's pool is (``BlockedKVCache.pool``): the
@@ -40,33 +48,72 @@ def _summed(counted):
     return sum(counted[1:], counted[0])
 
 
-def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
-                  policy, cfg, block_size: int, attn_impl: str):
-    """Shared chunk forward: embeds a bucket-padded token chunk, has the page
-    kind write each layer's new rows into the pages and attend over the paged
-    context, and returns (per-row hidden states [Tb, D], updated cache, the
-    counts handed out)."""
+def _step_states(params, cache_data, chunk, decode, policy, cfg,
+                 block_size: int, attn_impl: str):
+    """The shared forward of a step's rows: a chunk's (``chunk``: tokens
+    [Tb] bucket-padded, start, block_table [MB], true_len; or None), then a
+    decode batch's (``decode``: tokens, positions [B], block_tables [B, MB],
+    valid [B]; or None), embedded and taken through every layer TOGETHER. Only
+    the ``attend`` a block is handed knows the halves apart: it cuts the
+    operands the kind calls rows (``row_operands``) where the chunk's end,
+    has the kind write and attend each half with its own slots and tables
+    (``attend_chunk``, ``attend_decode``), and joins the outputs. A chunk's
+    sequence and the decoding ones are different sequences, so the halves
+    write different blocks and slots; padding rows of both write the trash
+    block and the pad slot. Returns (per-row states [Tb + B, ...], updated
+    cache, the counts handed out, once for all the rows)."""
     spec = policy.cache_spec(cfg)
     kind = page_kind(spec, cache_data)
-    tb = tokens.shape[0]
+    tokens, positions, valid, halves = [], [], [], []
 
-    positions = start + jnp.arange(tb)
-    safe_pos = jnp.minimum(positions, spec.max_seq_len - 1)
-    valid = jnp.arange(tb) < true_len
-    slots = kind.chunk_slots(cache_data, block_table, start, safe_pos, valid,
-                             block_size)
+    def half(toks, pos, ok, attend_half, *where):
+        at = sum(t.shape[0] for t in tokens)
+        halves.append((slice(at, at + toks.shape[0]), attend_half, where))
+        tokens.append(toks), positions.append(pos), valid.append(ok)
+
+    if chunk is not None:
+        toks, start, block_table, true_len = chunk
+        tb = toks.shape[0]
+        pos = jnp.minimum(start + jnp.arange(tb), spec.max_seq_len - 1)
+        ok = jnp.arange(tb) < true_len
+        half(toks, pos, ok, kind.attend_chunk,
+             kind.chunk_slots(cache_data, block_table, start, pos, ok,
+                              block_size), block_table, start)
+    if decode is not None:
+        toks, pos, block_tables, ok = decode
+        pos = jnp.minimum(pos, spec.max_seq_len - 1)
+        half(toks, pos, ok, kind.attend_decode,
+             kind.decode_slots(cache_data, block_tables, pos, ok, block_size),
+             block_tables, pos)
+
+    def joined(parts):
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+    safe_pos, valid = joined(positions), joined(valid)
 
     with jax.named_scope("embed"):
-        x = policy.embed(params, tokens, safe_pos, cfg)
+        x = policy.embed(params, joined(tokens), safe_pos, cfg)
 
     cache = cache_data
     counted = []
     for i in range(spec.num_layers):
         def attend(*computed, i=i, **how):
             nonlocal cache
-            out, cache = kind.attend_chunk(cache, i, slots, block_table,
-                                           start, attn_impl, *computed, **how)
-            return out
+            n = kind.row_operands(i)
+            outs = []
+            for rows, attend_half, where in halves:
+                mine = computed[:n] if len(halves) == 1 else \
+                    tuple(r[rows] for r in computed[:n])
+                if outs:
+                    # this half writes the pool the half before has read, and
+                    # nothing it computes says so: tied to that half's
+                    # output, its write is ordered behind the read (left to
+                    # itself XLA:TPU copies a pool of scaled pages whole
+                    # instead)
+                    mine, outs = jax.lax.optimization_barrier((mine, outs))
+                out, cache = attend_half(cache, i, *where, attn_impl, *mine,
+                                         *computed[n:], **how)
+                outs.append(out)
+            return joined(outs)
         x, counts = policy.block(params, i, x, attend, safe_pos, cfg, valid)
         if counts is not None:
             counted.append(counts)
@@ -76,18 +123,28 @@ def _chunk_states(params, cache_data, tokens, start, block_table, true_len,
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
          donate_argnames=("cache_data",))
 def prefill_chunk_g(params, cache_data, tokens, start, block_table, true_len,
-                    policy, cfg, block_size: int, attn_impl: str = "auto"):
-    """One sequence, one chunk. tokens: [Tb] (bucket-padded); start: the
-    chunk's offset in the sequence; block_table: [MB] block ids
-    (trash-padded); true_len: real chunk tokens. Returns (last-token logits
-    [V], updated cache_data, counts: module docstring)."""
-    x, cache, counts = _chunk_states(params, cache_data, tokens, start,
-                                     block_table, true_len, policy, cfg,
-                                     block_size, attn_impl)
-    last = x[jnp.maximum(true_len - 1, 0)]
+                    decode=None, *, policy, cfg, block_size: int,
+                    attn_impl: str = "auto"):
+    """One sequence, one chunk, and where ``decode`` is given a decode batch
+    in the same forward pass (FastGen's ragged forward of a step, at static
+    shapes): every weight is read once for both. tokens: [Tb]
+    (bucket-padded); start: the chunk's offset in the sequence; block_table:
+    [MB] block ids (trash-padded); true_len: real chunk tokens; decode:
+    ``decode_step_g``'s (tokens, positions, block_tables, valid), rows that
+    decode nothing not ``valid``. Returns (the chunk's last-token logits [V],
+    updated cache_data, counts: module docstring), the logits a pair (the
+    chunk's [V], the decode rows' [B, V]) where ``decode`` is given: the
+    output head runs once over the chunk's last row and the decode rows."""
+    x, cache, counts = _step_states(
+        params, cache_data, (tokens, start, block_table, true_len), decode,
+        policy, cfg, block_size, attn_impl)
+    rows = x[jnp.maximum(true_len - 1, 0)][None]
+    if decode is not None:
+        rows = jnp.concatenate([rows, x[tokens.shape[0]:]])
     with jax.named_scope("lm_head"):
-        logits = policy.unembed(params, last[None], cfg)[0]
-    return logits, cache, counts
+        logits = policy.unembed(params, rows, cfg)
+    return (logits[0] if decode is None else (logits[0], logits[1:])), \
+        cache, counts
 
 
 @partial(jax.jit, static_argnames=("policy", "cfg", "block_size", "attn_impl"),
@@ -109,9 +166,9 @@ def verify_chunk_g(params, cache_data, tokens, start, block_table, true_len,
             "verify_chunk_g writes rows that may be rejected, and a "
             "recurrent state cannot give them up: speculative verification "
             "is not supported over a cache with a state kind")
-    x, cache, counts = _chunk_states(params, cache_data, tokens, start,
-                                     block_table, true_len, policy, cfg,
-                                     block_size, attn_impl)
+    x, cache, counts = _step_states(
+        params, cache_data, (tokens, start, block_table, true_len), None,
+        policy, cfg, block_size, attn_impl)
     with jax.named_scope("lm_head"):
         return policy.unembed(params, x, cfg), cache, counts
 
@@ -123,32 +180,11 @@ def decode_step_g(params, cache_data, tokens, positions, block_tables, valid,
     """Batched single-token decode. tokens/positions/valid: [B] (the rows
     that are batch padding not ``valid``); block_tables: [B, MB]. Returns
     (logits [B, V], updated cache_data, counts: module docstring)."""
-    spec = policy.cache_spec(cfg)
-    kind = page_kind(spec, cache_data)
-
-    safe_pos = jnp.minimum(positions, spec.max_seq_len - 1)
-    slots = kind.decode_slots(cache_data, block_tables, safe_pos, valid,
-                              block_size)
-
-    with jax.named_scope("embed"):
-        x = policy.embed(params, tokens, safe_pos, cfg)
-
-    cache = cache_data
-    counted = []
-    for i in range(spec.num_layers):
-        def attend(*computed, i=i, **how):
-            nonlocal cache
-            out, cache = kind.attend_decode(cache, i, slots, block_tables,
-                                            safe_pos, attn_impl, *computed,
-                                            **how)
-            return out
-        x, counts = policy.block(params, i, x, attend, safe_pos, cfg, valid)
-        if counts is not None:
-            counted.append(counts)
-
+    x, cache, counts = _step_states(
+        params, cache_data, None, (tokens, positions, block_tables, valid),
+        policy, cfg, block_size, attn_impl)
     with jax.named_scope("lm_head"):
-        logits = policy.unembed(params, x, cfg)
-    return logits, cache, _summed(counted)
+        return policy.unembed(params, x, cfg), cache, counts
 
 
 # compile-event ledger: every XLA compile of the serving step fns emits an

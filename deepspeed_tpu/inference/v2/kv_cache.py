@@ -17,8 +17,10 @@ recurrent state of a size that does not grow with the context, one SLOT a
 sequence and no block table, ``_StateSlots``). A kind owns, and nothing outside this
 module knows: the pool's shape and block axis, the trash block, whether the
 step programs carry an array or ``(pages, scales)``, the slots a step's rows
-land in, their write, and the chunk and decode attention over its pages,
-Pallas kernel or gather path (``attn_impl``). The served loop
+land in, their write, the chunk and decode attention over its pages,
+Pallas kernel or gather path (``attn_impl``), and which of the operands a
+block hands ``attend`` are rows (``row_operands``: what a step of a chunk's
+rows and a decode batch's cuts between the two). The served loop
 (``generic_decode.py``) hands a kind the step's positions and block tables
 and each layer's ``attend`` arguments. A further kind is a class here and
 the policy that calls its ``attend``.
@@ -645,9 +647,14 @@ def write_kv_scaled(cache_data, scales, layer: int, kv: int, vals,
     return cache_data, scales.at[layer, kv].set(new_s)
 
 
+@partial(jax.jit, static_argnames=("layer",))
 def write_kv(cache_data, layer: int, k_new, v_new, block_ids, offsets):
     """Scatter new K/V tokens into their page slots, in place where the pool
-    is donated (``generic_decode``).
+    is donated (``generic_decode``). Under a ``jit`` of its own so that the
+    two scatters are traced once a layer and a shape in a PROCESS and not
+    once a layer in every step program (every chunk program writes a decode
+    half's rows too, the same shape in all of them: PERF.md section 6, PR
+    45); the layer stays static, so the program XLA sees is the same.
 
     cache_data: [L, 2, H, NB, bs, D]; k_new/v_new: [T, H, D]; block_ids/
     offsets: [T], the slot of each token. The head is an index like the block
@@ -698,8 +705,8 @@ def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
     (``ops/pallas/latent_attention.py``). q_nope: [B, H, d_n]; q_rope:
     [B, H, d_r], rotated; w_ukv: [rank, H, d_n + d_v]. Returns [B, H, d_v].
     Kernel against gather path, as ``_HeadPages._read``."""
-    from deepspeed_tpu.ops.pallas.latent_attention import (
-        latent_paged_attention, latent_paged_attention_reference)
+    from deepspeed_tpu.ops.pallas.latent_attention import \
+        latent_paged_attention_reference
     rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
     with jax.named_scope("attn/latent_q"):
         q = jnp.concatenate(
@@ -711,10 +718,40 @@ def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
             o = latent_paged_attention_reference(
                 q, pool[layer], block_tables, positions, scale, rank)
         else:
-            o = latent_paged_attention(
-                q, pool, layer, block_tables, positions, scale, rank,
+            # the kernel reads the pool as [L * NB, bs, W]: the layer goes
+            # into the tables, so that every layer's call is layer 0's
+            o = _latent_paged_call(
+                q, pool, block_tables + layer * pool.shape[1], positions,
+                scale=float(scale), rank=rank,
                 interpret=impl == "kernel_interpret")
         return jnp.einsum("bhr,rhv->bhv", o, w_ukv[..., d_n:])
+
+
+@partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _latent_paged_call(q, pool, block_tables, positions, *, scale: float,
+                       rank: int, interpret: bool):
+    """The latent decode kernel over the blocks ``block_tables`` name in the
+    pool read as ONE layer of ``L * NB`` blocks. A function of its own under
+    ``jit``, and the same for every layer, so that a step program traces it
+    and lowers it to Mosaic once and not once a layer (as
+    ``paged_attention._paged_call``): every chunk program carries this fold
+    for its decode half, and a lowering a layer was a second and a half of
+    every such program's first call at five layers."""
+    from deepspeed_tpu.ops.pallas.latent_attention import \
+        latent_paged_attention
+    return latent_paged_attention(q, pool, 0, block_tables, positions, scale,
+                                  rank, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("scale", "interpret"))
+def _latent_prefill_call(q_nope, q_rope, k_nope, k_rope, v, start, *,
+                         scale: float, interpret: bool):
+    """The latent prefill kernel, under ``jit`` for ``_latent_paged_call``'s
+    reason: it takes no layer, so the layers' calls are one."""
+    from deepspeed_tpu.ops.pallas.latent_attention import \
+        latent_prefill_attention
+    return latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
+                                    scale, interpret=interpret)
 
 
 def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
@@ -727,8 +764,7 @@ def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
     rotated; block_table: [MB]; start: the chunk's first position. Returns
     [T, H, d_v]."""
     from deepspeed_tpu.ops.pallas.latent_attention import (
-        PREFILL_BLOCK_K, latent_prefill_attention,
-        latent_prefill_attention_reference)
+        PREFILL_BLOCK_K, latent_prefill_attention_reference)
     rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
     d_r = q_rope.shape[-1]
     nb, bs = pool.shape[1], pool.shape[2]
@@ -747,13 +783,13 @@ def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
     args = (q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2),
             jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., :d_n]),
             rows[:, rank:rank + d_r],
-            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., d_n:]), start, scale)
+            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., d_n:]), start)
     impl = _resolve_impl(attn_impl)
     if impl == "gather":
-        out = latent_prefill_attention_reference(*args)
+        out = latent_prefill_attention_reference(*args, scale)
     else:
-        out = latent_prefill_attention(*args,
-                                       interpret=impl == "kernel_interpret")
+        out = _latent_prefill_call(*args, scale=float(scale),
+                                   interpret=impl == "kernel_interpret")
     return out.transpose(1, 0, 2)
 
 
@@ -765,6 +801,15 @@ class _Pages:
 
     def __init__(self, window=None):
         self.window = window
+
+    def row_operands(self, layer: int) -> int:
+        """How many of the operands a block hands ``attend`` hold one row a
+        token (q, k, v; a latent kind's q_nope, q_rope, row), leading the
+        rest, which are the layer's own whatever their shapes (an
+        up-projection, a scale; every keyword: sinks, a window, a softcap).
+        A step of a chunk's rows and a decode batch's cuts these, and only
+        these, between ``attend_chunk`` and ``attend_decode``."""
+        return 3
 
     def trash_block(self, cache) -> int:
         """The pool's last block, where padding rows are written. The pages
@@ -1039,6 +1084,22 @@ class _StateSlots:
     def __init__(self, shape: StateSlotShape):
         self.shape = shape
 
+    # a kind is what its slot holds: ``attend_chunk`` and ``attend_decode``
+    # run under ``jit`` with the kind static and the LAYER A VALUE, so that a
+    # step program of 36 such layers traces and lowers each once and calls it
+    # a layer (as ``ssm_update._update_call``; traced a layer, the decode
+    # half every chunk program carries was a second of host time a program
+    # at 40 layers: PERF.md section 6, PR 45)
+    def __hash__(self):
+        return hash(self.shape)
+
+    def __eq__(self, other):
+        return type(other) is type(self) and other.shape == self.shape
+
+    def row_operands(self, layer: int) -> int:
+        """``xbc`` and ``step`` (``_Pages.row_operands``)."""
+        return 2
+
     def empty(self, layers: int, slots: int, dtype):
         at = self.shape
         return {"ssm": jnp.zeros((layers, slots + 1) + at.stored, jnp.float32),
@@ -1048,6 +1109,7 @@ class _StateSlots:
         at = self.shape
         return ssm.split_conv(conv, at.heads, at.head_dim, at.d_state)
 
+    @partial(jax.jit, static_argnames=("self", "attn_impl"))
     def attend_chunk(self, cache, layer, slots, attn_impl, xbc, step, kernel,
                      bias, a_log, d):
         """One sequence's chunk: the convolution over its rows behind the
@@ -1085,6 +1147,7 @@ class _StateSlots:
                 layer, slot].set(tail.reshape(-1))}
         return y.reshape(y.shape[0], -1).astype(xbc.dtype), cache
 
+    @partial(jax.jit, static_argnames=("self", "attn_impl"))
     def attend_decode(self, cache, layer, slots, attn_impl, xbc, step, kernel,
                       bias, a_log, d):
         """One token a sequence: each row's tail shifted by its token, its
@@ -1207,6 +1270,12 @@ class _LayerKindPages:
             pool[STATE] = self.state.empty(self.kinds.count(STATE),
                                            cfg.state_slots, cfg.dtype)
         return pool, None
+
+    def row_operands(self, layer: int) -> int:
+        """What the layer's own kind says (``_Pages.row_operands``)."""
+        kind = self.kinds[layer]
+        return (self.state if kind == STATE
+                else self.pages[kind]).row_operands(layer)
 
     def _behind(self, first_query, block_size: int):
         """Tokens before a windowed table's first block."""

@@ -6,7 +6,14 @@ carries a fixed token budget; running decodes get 1 token each, remaining budget
 filled by *chunks* of pending prefills (long prompts split across steps — SplitFuse).
 
 TPU adaptation: chunk sizes snap to a bucket ladder so every distinct compiled
-shape is reused (XLA static shapes); decodes batch into a padded [max_batch] call.
+shape is reused (XLA static shapes). The reference composes a prompt's chunk
+and the running sequences' tokens into ONE ragged forward pass a step; so does
+the engine where a step's plan holds a chunk: the decode rows ride in the
+last chunk's step program, at a fixed padded shape, and every weight is read
+once (``engine_v2._dispatch``, ``generic_decode.prefill_chunk_g``). A plan
+without a chunk is one padded ``decode_step_g`` call at the batch's bucket,
+and so are the decode rows of any plan over a pool with a state kind, after
+its chunks' programs (the fused step measured slower there).
 """
 
 import dataclasses

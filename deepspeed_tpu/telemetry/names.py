@@ -114,9 +114,13 @@ TRACE_NAMES: Dict[str, Tuple[str, ...]] = {
     # beside it where `ahead` is 1 (1: that pending step's tokens were
     # already there when this one was dispatched, so the device had run dry
     # for want of the host; 0: it was still running), and on the
-    # tick's decode span, or its last chunk's where it decoded nothing,
-    # `rows_dropped` (rows whose sequence had ended by the time their token
-    # was read) beside the STEP_COUNTER_ARGS. Every span of a tick carries
+    # tick's decode span, or its last chunk's where it dispatched no decode
+    # program, `rows_dropped` (rows whose sequence had ended by the time
+    # their token was read) beside the STEP_COUNTER_ARGS. A tick whose plan
+    # holds a chunk dispatches none: its decode rows ride in its last
+    # chunk's program, that chunk's span says how many (`fused_rows`), and
+    # the tick stamps no `serve/step_decode`, whose `batch` and `ctx_tokens`
+    # are of `decode_step_g`'s kernels alone. Every span of a tick carries
     # the number of the host loop's tick it lies in, whichever step its
     # wait and commit are for
     "serve/step_prefill": ("complete",),

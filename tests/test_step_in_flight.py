@@ -496,6 +496,10 @@ def test_the_wait_of_a_loop_closes_after_that_loops_dispatch(built, tracing):
     for number, spans in ticks.items():
         waits = [e for e in spans if e.name == pe.DECODE_WAIT]
         dispatches = [e for e in spans if e.name == "serve/decode_dispatch"]
+        # a tick that decodes beside chunks dispatches its rows in its last
+        # chunk's program, and that chunk's span holds the wait
+        fused = [e for e in spans if e.name == pe.PREFILL_CHUNK
+                 and e.arg("fused_rows")]
         if waits and dispatches:
             seen += 1
             assert max(d.end for d in dispatches) <= min(w.start
@@ -503,6 +507,12 @@ def test_the_wait_of_a_loop_closes_after_that_loops_dispatch(built, tracing):
             (decode,) = [e for e in spans if e.name == pe.STEP_DECODE]
             assert all(decode.start <= e.start and e.end <= decode.end + 1e-6
                        for e in waits + dispatches)
+        elif waits and fused:
+            seen += 1
+            (chunk,) = fused
+            assert not [e for e in spans if e.name == pe.STEP_DECODE]
+            assert all(chunk.start <= w.start and w.end <= chunk.end + 1e-6
+                       for w in waits)
     assert seen >= max(BUDGETS) - 2
 
 
@@ -567,7 +577,10 @@ def test_the_threaded_loop_stamps_the_same_spans(built, tracing):
         server.stop(drain_timeout=10.0)
     evs = [pe.Event(e[1], e[4], e[5], e[6], e[7])
            for e in tracing.events_snapshot() if e[3] == "X"]
-    decodes = [e for e in pe.loop_thread(evs) if e.name == pe.STEP_DECODE]
+    # the span that holds a tick's wait: its decode span, or its last chunk's
+    # where the decode rows rode in that chunk's program
+    decodes = [e for e in pe.loop_thread(evs) if e.name == pe.STEP_DECODE
+               or e.name == pe.PREFILL_CHUNK and e.arg("fused_rows")]
     assert len(decodes) >= max(BUDGETS) - 1
     ahead = [e.arg("ahead") for e in decodes]
     assert sum(ahead) >= 0.9 * (len(ahead) - 1)

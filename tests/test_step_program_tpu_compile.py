@@ -73,12 +73,34 @@ def _shapes(one_chip, fn_name, pages):
          spec.head_dim), dtype, sharding=one_chip)
     cache = pool if pages == "plain" else (pool, jax.ShapeDtypeStruct(
         pool.shape[:4], jnp.float32, sharding=one_chip))
-    if fn_name == "decode_step_g":
-        tail = (ints(16), ints(16), ints(16, 8), jax.ShapeDtypeStruct(
-            (16,), jnp.bool_, sharding=one_chip))
-    else:
-        tail = (ints(512), ints(), ints(16), ints())
+    batch, blocks = (64, 64) if fn_name == FUSED else (16, 8)
+    tail = _tail(fn_name, (ints(512), ints(), ints(16), ints()),
+                 (ints(batch), ints(batch), ints(batch, blocks),
+                  jax.ShapeDtypeStruct((batch,), jnp.bool_,
+                                       sharding=one_chip)))
     return (params, cache) + tail, pool
+
+
+#: ``prefill_chunk_g`` handed a decode half (the engine's every chunk program
+#: since PR 45): the chunk's shapes, then the largest decode batch bucket over
+#: the widest context bucket of the cell's engine
+FUSED = "prefill_chunk_g+decode"
+
+
+def _program(fn_name):
+    return gd.prefill_chunk_g if fn_name == FUSED else getattr(gd, fn_name)
+
+
+def _tail(fn_name, chunk, decode):
+    """A step program's arguments after the pool."""
+    return {"decode_step_g": decode, FUSED: chunk + (decode,)}.get(fn_name,
+                                                                   chunk)
+
+
+def _outputs(fn_name, pools=1):
+    """Leaves a step program returns: the logits (a pair where a decode half
+    rides along), the pool's, the counts."""
+    return (2 if fn_name == FUSED else 1) + pools + 1
 
 
 def _pool_shaped_moves(text, pool):
@@ -95,11 +117,11 @@ def _pool_shaped_moves(text, pool):
 
 @pytest.mark.parametrize("pages", ["plain", "fp8-scaled"])
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
-                                     "verify_chunk_g"])
+                                     "verify_chunk_g", FUSED])
 def test_step_program_updates_the_pool_in_place_on_a_v5e(one_chip, fn_name,
                                                          pages):
     args, pool = _shapes(one_chip, fn_name, pages)
-    compiled = getattr(gd, fn_name).lower(
+    compiled = _program(fn_name).lower(
         *args, policy=policy_for(CFG), cfg=CFG, block_size=BLOCK,
         attn_impl="kernel").compile()
     text = compiled.as_text()
@@ -292,16 +314,14 @@ def _latent_shapes(one_chip, fn_name):
     pool = jax.ShapeDtypeStruct(
         (spec.num_layers, NUM_BLOCKS, BLOCK, latent_row_width(spec.latent_dim)),
         spec.dtype, sharding=one_chip)
-    if fn_name == "decode_step_g":
-        tail = (ints(32), ints(32), ints(32, 132), jax.ShapeDtypeStruct(
-            (32,), jnp.bool_, sharding=one_chip))
-    else:
-        tail = (ints(2048), ints(), ints(132), ints())
+    tail = _tail(fn_name, (ints(2048), ints(), ints(132), ints()),
+                 (ints(32), ints(32), ints(32, 132), jax.ShapeDtypeStruct(
+                     (32,), jnp.bool_, sharding=one_chip)))
     return cfg, (params, pool) + tail, pool
 
 
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
-                                     "verify_chunk_g"])
+                                     "verify_chunk_g", FUSED])
 def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(
         one_chip, as_on_a_tpu, fn_name):
     """One plane of 640-lane rows (576 values and the zero lanes that keep the
@@ -312,7 +332,7 @@ def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(
     grouped calls is), and the counts leave beside the logits."""
     cfg, args, pool = _latent_shapes(one_chip, fn_name)
     assert pool.shape == (2, NUM_BLOCKS, BLOCK, 640)
-    compiled = getattr(gd, fn_name).lower(
+    compiled = _program(fn_name).lower(
         *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
         attn_impl="kernel").compile()
     text = compiled.as_text()
@@ -323,9 +343,11 @@ def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(
     # activations of a 2,048-token chunk (the context's up-projected keys and
     # values are 138 MB; the verifier's logits over 129,280 rows 1.06 GB),
     # not a second pool: nothing pool-shaped is made anew
-    kernel = "latent_paged_attention" if fn_name == "decode_step_g" \
-        else "latent_prefill_attention"
-    assert "tpu_custom_call" in text and kernel in text
+    assert "tpu_custom_call" in text
+    assert ("latent_paged_attention" in text) == (
+        fn_name in ("decode_step_g", FUSED))
+    assert ("latent_prefill_attention" in text) == (
+        fn_name != "decode_step_g")
     assert "grouped_matmul" in text and "ragged-dot" not in text
     entry = text[text.index("\nENTRY"):]
     whole = ",".join(str(d) for d in pool.shape)
@@ -339,7 +361,26 @@ def test_latent_step_program_updates_the_pool_in_place_on_a_v5e(
         # under one [E, T, F] intermediate of the chunk (256 x 2048 x 768
         # bfloat16 = 805 MB, which all-experts-then-pick makes twice a layer)
         assert stats.temp_size_in_bytes < 256 * 2048 * 768 * 2 // 2
-    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+    assert len(jax.tree.leaves(compiled.out_info)) == _outputs(fn_name)
+
+
+def test_layers_of_a_latent_pool_share_one_body_a_latent_kernel(one_chip,
+                                                                as_on_a_tpu):
+    """What holds ``setup_s`` where every chunk program carries a decode
+    fold: the layer goes into the block tables and each latent kernel's call
+    sits under a ``jit`` of its own (``kv_cache._latent_paged_call``,
+    ``_latent_prefill_call``), so a program of two layers lowers each kernel
+    to Mosaic ONCE and calls it a layer (a lowering a layer was 0.2 s of
+    host time each on the chip's machine, PERF.md section 6, PR 45)."""
+    cfg, args, _ = _latent_shapes(one_chip, FUSED)
+    text = gd.prefill_chunk_g.lower(
+        *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+        attn_impl="kernel").as_text()
+    for kernel, call in (("latent_paged_attention", "_latent_paged_call"),
+                         ("latent_prefill_attention",
+                          "_latent_prefill_call")):
+        assert text.count(f'kernel_name = "{kernel}"') == 1
+        assert len(re.findall(rf"call @{call}\b", text)) == cfg.num_layers
 
 
 # --- the softmax-routed experts -----------------------------------------------
@@ -373,16 +414,16 @@ def _mixtral_shapes(one_chip, fn_name):
     pool = jax.ShapeDtypeStruct(
         (spec.num_layers, 2, spec.num_kv_heads, 1472, BLOCK, spec.head_dim),
         spec.dtype, sharding=one_chip)
-    if fn_name == "decode_step_g":
-        tail = (ints(32), ints(32), ints(32, 49), jax.ShapeDtypeStruct(
-            (32,), jnp.bool_, sharding=one_chip))
-    else:
-        tail = (ints(2048), ints(), ints(49), ints())
+    batch, blocks = (64, 64) if fn_name == FUSED else (32, 49)
+    tail = _tail(fn_name, (ints(2048), ints(), ints(49), ints()),
+                 (ints(batch), ints(batch), ints(batch, blocks),
+                  jax.ShapeDtypeStruct((batch,), jnp.bool_,
+                                       sharding=one_chip)))
     return cfg, (params, pool) + tail, pool
 
 
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
-                                     "verify_chunk_g"])
+                                     "verify_chunk_g", FUSED])
 def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
         one_chip, as_on_a_tpu, fn_name):
     """A 2,048-token chunk and a decode batch of 32 alike: the grouped
@@ -394,7 +435,7 @@ def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
     counts leave beside the logits."""
     cfg, args, pool = _mixtral_shapes(one_chip, fn_name)
     assert cfg.moe.num_experts == 8 and cfg.base.dtype == jnp.bfloat16
-    compiled = getattr(gd, fn_name).lower(
+    compiled = _program(fn_name).lower(
         *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
         attn_impl="kernel").compile()
     text = compiled.as_text()
@@ -403,13 +444,14 @@ def test_mixtral_step_program_computes_the_chosen_experts_alone_on_a_v5e(
     assert stats.alias_size_in_bytes >= pool_bytes
     assert "may-alias" in text.splitlines()[0]
     assert "tpu_custom_call" in text and "paged_attention" in text
-    rows = args[2].shape[0]                     # 2,048 a chunk, 32 a batch
+    # 2,048 a chunk, 32 a batch, 2,048 + 64 the two in one pass
+    rows = args[2].shape[0] + (64 if fn_name == FUSED else 0)
     assert "grouped_matmul" in text and "ragged-dot" not in text
     assert re.search(r"\[8,%d,14336\]" % rows, text) is None
     # 259 MB a chunk, 142 MB the verifier
     assert stats.temp_size_in_bytes < 8 * 2048 * 14336 * 2
     assert _pool_shaped_moves(text, pool) == []
-    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+    assert len(jax.tree.leaves(compiled.out_info)) == _outputs(fn_name)
 
 
 # --- pages by layer kind --------------------------------------------------------
@@ -440,19 +482,20 @@ def _laguna_shapes(one_chip, fn_name):
         (1, 2, spec.num_kv_heads, blocks, BLOCK, spec.head_dim), spec.dtype,
         sharding=one_chip)
         for kind, blocks in (("full", NUM_BLOCKS), ("window", 355))}
-    if fn_name == "decode_step_g":
-        tables = {"full": ints(32, 260),
-                  "window": ints(32, windowed_table_blocks(1, 512, BLOCK))}
-        tail = (ints(32), ints(32), tables, jax.ShapeDtypeStruct(
-            (32,), jnp.bool_, sharding=one_chip))
-    else:
-        tables = {"full": ints(260),
-                  "window": ints(windowed_table_blocks(4096, 512, BLOCK))}
-        tail = (ints(4096), ints(), tables, ints())
+    tail = _tail(
+        fn_name,
+        (ints(4096), ints(),
+         {"full": ints(260),
+          "window": ints(windowed_table_blocks(4096, 512, BLOCK))}, ints()),
+        (ints(32), ints(32),
+         {"full": ints(32, 260),
+          "window": ints(32, windowed_table_blocks(1, 512, BLOCK))},
+         jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip)))
     return cfg, (params, pool) + tail, pool
 
 
-@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     FUSED])
 def test_layer_kind_step_program_updates_both_pools_in_place_on_a_v5e(
         one_chip, as_on_a_tpu, fn_name):
     """A pool a layer kind at the cell's largest shapes (32 sequences or a
@@ -464,7 +507,7 @@ def test_layer_kind_step_program_updates_both_pools_in_place_on_a_v5e(
     cfg, args, pool = _laguna_shapes(one_chip, fn_name)
     assert args[4]["window"].shape[-1] == (9 if fn_name == "decode_step_g"
                                            else 73)
-    compiled = getattr(gd, fn_name).lower(
+    compiled = _program(fn_name).lower(
         *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
         attn_impl="kernel").compile()
     text = compiled.as_text()
@@ -481,7 +524,7 @@ def test_layer_kind_step_program_updates_both_pools_in_place_on_a_v5e(
     assert stats.temp_size_in_bytes < 1 << 30
     logits, _, counts = compiled.out_info
     assert counts.shape == (4,)
-    assert len(jax.tree.leaves(compiled.out_info)) == 4   # two pools
+    assert len(jax.tree.leaves(compiled.out_info)) == _outputs(fn_name, 2)
 
 
 # --- several residual streams ---------------------------------------------------
@@ -508,16 +551,14 @@ def _xing4_shapes(one_chip, fn_name):
     pool = jax.ShapeDtypeStruct(
         (spec.num_layers, NUM_BLOCKS, BLOCK, latent_row_width(spec.latent_dim)),
         spec.dtype, sharding=one_chip)
-    if fn_name == "decode_step_g":
-        tail = (ints(64), ints(64), ints(64, 65), jax.ShapeDtypeStruct(
-            (64,), jnp.bool_, sharding=one_chip))
-    else:
-        tail = (ints(1024), ints(), ints(32), ints())
+    tail = _tail(fn_name, (ints(1024), ints(), ints(32), ints()),
+                 (ints(64), ints(64), ints(64, 65), jax.ShapeDtypeStruct(
+                     (64,), jnp.bool_, sharding=one_chip)))
     return cfg, (params, pool) + tail, pool
 
 
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
-                                     "verify_chunk_g"])
+                                     "verify_chunk_g", FUSED])
 def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
         one_chip, as_on_a_tpu, fn_name):
     """The reasoning cell's largest shapes (64 sequences over 65 blocks, a
@@ -529,7 +570,7 @@ def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
     cfg, args, pool = _xing4_shapes(one_chip, fn_name)
     assert (cfg.hc_mult, cfg.hidden_size, cfg.n_routed_experts) == (4, 3584,
                                                                     64)
-    compiled = getattr(gd, fn_name).lower(
+    compiled = _program(fn_name).lower(
         *args, policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
         attn_impl="kernel").compile()
     text = compiled.as_text()
@@ -537,9 +578,11 @@ def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= pool_bytes
     assert "may-alias" in text.splitlines()[0]
-    kernel = "latent_paged_attention" if fn_name == "decode_step_g" \
-        else "latent_prefill_attention"
-    assert "tpu_custom_call" in text and kernel in text
+    assert "tpu_custom_call" in text
+    assert ("latent_paged_attention" in text) == (
+        fn_name in ("decode_step_g", FUSED))
+    assert ("latent_prefill_attention" in text) == (
+        fn_name != "decode_step_g")
     assert "grouped_matmul" in text and "ragged-dot" not in text
     for scope in ("/hc/pre/", "/hc/post/", "/hc/head/"):
         assert scope in text
@@ -548,12 +591,13 @@ def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
     # logits over 131,072 rows are 0.54 GB)
     if fn_name != "verify_chunk_g":
         assert stats.temp_size_in_bytes < pool_bytes
-    assert len(jax.tree.leaves(compiled.out_info)) == 3   # + the counts
+    assert len(jax.tree.leaves(compiled.out_info)) == _outputs(fn_name)
 
 
 # --- a state kind beside the pages (granite-4.0-h-micro's widths) --------------
 
-@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
+                                     FUSED])
 def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
                                                                  fn_name):
     """Three layers of granite-4.0-h-micro at the published widths (Mamba-2,
@@ -586,13 +630,12 @@ def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
         "state": {"ssm": (2, 65, 32, 128, 128), "conv": (2, 65, 3 * 4352)}}
     pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                      for x in jax.tree.leaves(pool))
-    if fn_name == "decode_step_g":
-        tail = (ints(64), ints(64), {"full": ints(64, 48), "state": ints(64)},
-                jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=one_chip))
-    else:
-        tail = (ints(2048), ints(), {"full": ints(48), "state": ints()},
-                ints())
-    lowered = getattr(gd, fn_name).lower(
+    tail = _tail(
+        fn_name,
+        (ints(2048), ints(), {"full": ints(48), "state": ints()}, ints()),
+        (ints(64), ints(64), {"full": ints(64, 48), "state": ints(64)},
+         jax.ShapeDtypeStruct((64,), jnp.bool_, sharding=one_chip)))
+    lowered = _program(fn_name).lower(
         params, pool, *tail, policy=policy, cfg=cfg, block_size=64,
         attn_impl="kernel")
     compiled = lowered.compile()
@@ -600,10 +643,11 @@ def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
     assert stats.alias_size_in_bytes >= pool_bytes
     assert "may-alias" in compiled.as_text().splitlines()[0]
     assert "paged_attention" in compiled.as_text()
-    if fn_name == "decode_step_g":
-        assert stats.temp_size_in_bytes < 64 << 20
+    if fn_name != "prefill_chunk_g":
         assert lowered.as_text().count('kernel_name = "ssm_update"') == 1
         assert "ssm_update" in compiled.as_text()
+    if fn_name == "decode_step_g":
+        assert stats.temp_size_in_bytes < 64 << 20
     else:
         # a 2,048-token chunk's closed form: blocks of 256, scores a head;
         # and no operation makes a value of the states' pool's shape (read
@@ -616,3 +660,94 @@ def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
                 if re.search(r"= \(?f32\[2,65,32,128,128\]\S* (copy|fusion)\(",
                              line)] == []
         assert "ssm_slot_read" in entry and "ssm_slot_write" in entry
+
+
+# --- pages by layer kind, each kind with its own heads and widths ----------------
+# MiMo-V2.5 at its published widths, cut to the leading dense full layer and
+# one windowed expert layer of the chip's 16 experts, so that a compile takes
+# seconds: keys of 192 beside values of 128 in a K pool and a V pool a kind,
+# 4 and 8 KV heads, a sink a query head in the windowed layer's softmax.
+
+def _mimo_shapes(one_chip, fn_name):
+    from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
+                                                     windowed_table_blocks)
+    from deepspeed_tpu.models.mimo_v2 import MiMoV2Config, MiMoV2ForCausalLM
+    cfg = MiMoV2Config(hybrid_layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+                       experts_held=16, max_seq_len=24960)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(MiMoV2ForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    # the agent-long cell's pools: 12,481 blocks, and the windowed layers'
+    # 31 x 3 + 67 + 1 as the engine derives them
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: BlockedKVCache.for_spec(policy_for(cfg).cache_spec(cfg),
+                                        "model", BLOCK, 12481,
+                                        window_blocks=161).pool))
+
+    def tables(lead, rows):
+        return {"full": ints(*lead, 390),
+                "window": ints(*lead, windowed_table_blocks(rows, 128, BLOCK))}
+    tail = _tail(
+        fn_name, (ints(4096), ints(), tables((), 4096), ints()),
+        (ints(32), ints(32), tables((32,), 1),
+         jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip)))
+    return cfg, (params, pool) + tail, pool
+
+
+def _held(stats) -> int:
+    """Device bytes a program holds while it runs: its arguments, its
+    temporaries and what it returns, less what it returns in place."""
+    return (stats.argument_size_in_bytes + stats.temp_size_in_bytes
+            + stats.output_size_in_bytes - stats.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", FUSED])
+def test_split_head_pages_step_program_updates_four_pools_in_place_on_a_v5e(
+        one_chip, as_on_a_tpu, fn_name):
+    """The agent-long cell's largest shapes (32 sequences or a 4,096-token
+    chunk over 390 blocks; the windowed layer's table 3 and 67 blocks): a K
+    pool and a V pool a kind, all four aliased whole, nothing pool-shaped
+    copied, the paged kernel once a layer kind and fold with the windowed
+    layer's sinks an operand of its calls; and a chunk program with the
+    decode half holds no more of the chip than the chunk alone but the decode
+    rows' logits (the cell peaks at 94-97% of the chip's memory)."""
+    cfg, args, pool = _mimo_shapes(one_chip, fn_name)
+    assert jax.tree.map(lambda x: x.shape, pool) == {
+        "full": {"k": (1, 4, 12481, 64, 256), "v": (1, 4, 12481, 64, 128)},
+        "window": {"k": (1, 8, 161, 64, 256), "v": (1, 8, 161, 64, 128)}}
+    how = dict(policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+               attn_impl="kernel")
+    lowered = _program(fn_name).lower(*args, **how)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaves = jax.tree.leaves(pool)
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in leaves)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert text.splitlines()[0].count("may-alias") >= 4
+    assert "grouped_matmul" in text and "ragged-dot" not in text
+    for p in leaves:
+        assert _pool_shaped_moves(text, p) == []
+    # a kernel a layer kind and fold: the full layer's and the windowed
+    # layer's (whose calls take the layer's 64 sinks beside q and the pools)
+    folds = 2 if fn_name == FUSED else 1
+    calls = [line for line in lowered.as_text().splitlines()
+             if 'kernel_name = "paged_attention"' in line]
+    assert len(calls) == 2 * folds
+    assert sum("x1xf32>) ->" in call for call in calls) == folds
+    assert len(jax.tree.leaves(compiled.out_info)) == _outputs(fn_name, 4)
+    if fn_name == FUSED:
+        alone = gd.prefill_chunk_g.lower(*args[:-1], **how).compile()
+        rows, vocab = compiled.out_info[0][1].shape
+        assert (rows, vocab) == (32, cfg.vocab_size)
+        assert _held(stats) <= _held(alone.memory_analysis()) \
+            + 2 * rows * vocab * 4
+        assert stats.temp_size_in_bytes < 1 << 30
