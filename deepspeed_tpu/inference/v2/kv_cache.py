@@ -446,9 +446,15 @@ class BlockedKVCache:
         reads the context whole, and ``tile_keys_windowed`` in a layer
         behind ``window`` (the visible pairs over each is the tiles' fill);
         ``tile_copies``, the K and V page copies a call of the first issues
-        for all its heads. Empty over a latent pool."""
+        for all its heads. Over a latent pool, which the paged kernel does
+        not read, the panels of the latent prefill kernel's grid instead
+        (``latent_attention.prefill_panels``: ``latent_panels`` a head a
+        layer, ``latent_panels_masked`` of them, ``latent_panels_dead``)."""
         if self.cfg.latent_dim:
-            return {}
+            from deepspeed_tpu.ops.pallas.latent_attention import \
+                prefill_panels
+            return prefill_panels(start, bucket,
+                                  table_blocks * self.cfg.block_size)
         whole, copies = chunk_tile_keys(start, bucket, mb=table_blocks,
                                         **self._fold_of("full"))
         windowed = whole
@@ -764,16 +770,14 @@ def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
     rotated; block_table: [MB]; start: the chunk's first position. Returns
     [T, H, d_v]."""
     from deepspeed_tpu.ops.pallas.latent_attention import (
-        PREFILL_BLOCK_K, latent_prefill_attention_reference)
+        latent_prefill_attention_reference, prefill_keys)
     rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
     d_r = q_rope.shape[-1]
     nb, bs = pool.shape[1], pool.shape[2]
-    # whole key blocks for the kernel: dead slots read the trash page, which
+    # whole key panels for the kernel: dead slots read the trash page, which
     # no query's horizon reaches
     mb = block_table.shape[0]
-    keys = mb * bs
-    if keys > PREFILL_BLOCK_K:
-        keys = -(-keys // PREFILL_BLOCK_K) * PREFILL_BLOCK_K
+    keys = prefill_keys(q_nope.shape[0], mb * bs)
     table = jnp.pad(block_table, (0, -(-keys // bs) - mb),
                     constant_values=nb - 1)
     rows = pool[layer, table].reshape(-1, pool.shape[-1])[:keys]

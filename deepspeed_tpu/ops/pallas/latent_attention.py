@@ -34,6 +34,34 @@ with the chunk's start in scalar prefetch; the rope part of a score is a
 second small matmul against the one rotated key all heads share, so nothing
 is concatenated or broadcast. Key blocks past a query block's causal horizon
 are skipped, and map to the last live block so that they are not fetched.
+
+What a grid step of it costs was swept on a v5e at the two latent cells'
+shapes (PERF.md section 6, PR 46, has the table), and three things follow
+from that sweep:
+
+- the running maximum and sum are held lane-dense, ``[block_q, 128]`` with
+  every lane the row's value. As ``[block_q, 1]`` columns they were broadcast
+  across lanes in every step, and that, not the passes over the float32
+  panel, was a third of a step: 2.2 -> 1.5 us a 512 x 512 panel;
+- a panel is ``prefill_panel(t, s)``: 1,024 rows x 512 keys, or the call's
+  own where it has fewer. Twice the rows pay the step's fixed cost and the
+  key and value fetches once for twice the pairs (another 2-4%, 10% where
+  half the grid is dead steps); wider key panels (1,024, 2,048) gained
+  nothing further once the columns were lane-dense, and pad a table of 132
+  blocks by up to 1,792 dead rows that are gathered and up-projected for
+  nothing;
+- the step has two bodies. A panel whose last key the row block's FIRST row
+  already sees lies wholly under the horizon and runs no ``iota``, compare
+  or ``where``; a panel the horizon crosses runs the masked step. On the
+  unmasked panel the values are the masked step's bit for bit. Worth 2-5%
+  with lane-dense columns (nothing before: the mask's passes hid under the
+  broadcasts). ``prefill_panels`` counts both kinds and the dead steps.
+
+Timed and dropped: one 192-wide score contraction over concatenated
+``[q_nope ; q_rope]`` and ``[k_nope ; k_rope]`` (concatenated in VMEM: within
+1% either way; as one more array a call: 3-23% slower), the row sum as a
+matmul against ones (13-18% slower) and a mask from one ``iota`` difference
+(no change). The scale stays a multiply on the float32 scores.
 """
 
 import functools
@@ -47,23 +75,49 @@ NEG_INF = -1e30
 
 #: Keys a decode grid step multiplies against, at most.
 _KEYS_PER_STEP = 1024
-#: Query rows and keys of one prefill grid cell, at most.
-PREFILL_BLOCK_Q = 512
-PREFILL_BLOCK_K = 512
+#: Lanes of a vector register: the prefill kernel holds its running maximum
+#: and sum this wide (every lane the row's value), as jax's own
+#: ``pallas/ops/tpu/flash_attention.py`` does, where a ``[rows, 1]`` column
+#: is broadcast across lanes in every step.
+_LANES = 128
+#: Query rows and keys of one prefill panel (a grid step's), at most.
+_PANEL_ROWS = 1024
+_PANEL_KEYS = 512
+
+
+def _across(col, width: int):
+    """A per-row value ``[rows, 1]``, or one held lane-dense ``[rows, 128]``
+    (every lane the row's value), as wide as ``width`` lanes."""
+    lanes = col.shape[1]
+    if lanes in (1, width):
+        return col
+    if width % lanes:
+        return col[:, :1]
+    return pltpu.repeat(col, width // lanes, 1)
+
+
+def _softmax_update(s, mask, v, m_prev, l_prev, acc_prev):
+    """One flash step on scores ``s`` [rows, keys] against ``v``: the new
+    ``(m, l, acc)``. ``m`` and ``l`` are ``[rows, 1]`` or lane-dense
+    ``[rows, 128]``. ``mask`` None is a panel every row sees whole: no
+    ``where``, and the same values bit for bit as an all-true mask gives
+    (``where(True, x, ..)`` is ``x``)."""
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - _across(m_new, s.shape[1]))
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    return m_new, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), \
+        acc_prev * _across(alpha, acc_prev.shape[1]) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
 
 def _online_softmax_step(s, mask, v, m_scr, l_scr, acc_scr):
-    """One flash step on masked scores ``s`` [rows, keys] against ``v``."""
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[:]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    m_scr[:] = m_new
-    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    m_scr[:], l_scr[:], acc_scr[:] = _softmax_update(
+        s, mask, v, m_scr[:], l_scr[:], acc_scr[:])
 
 
 def _init_scratch(m_scr, l_scr, acc_scr):
@@ -189,33 +243,75 @@ def latent_paged_attention_reference(q, pages, block_tables, positions,
 # ---------------------------------------------------------------------------
 # prefill: a chunk's queries over up-projected keys and values
 # ---------------------------------------------------------------------------
+def prefill_panel(t: int, s: int):
+    """``(block_q, block_k)``: the query rows and the keys of one grid step
+    for a chunk of ``t`` rows over ``s`` keys, from those two static sizes
+    alone. The kernel cuts its grid by it and ``kv_cache._latent_prefill_attn``
+    gathers ``prefill_keys`` of them, over which it is the same panel."""
+    return min(t, _PANEL_ROWS), min(s, _PANEL_KEYS)
+
+
+def prefill_keys(t: int, s: int) -> int:
+    """``s`` keys rounded up to whole key panels of the call for ``t`` rows:
+    what a block table is padded to before its rows are gathered."""
+    block_k = prefill_panel(t, s)[1]
+    return -(-s // block_k) * block_k
+
+
+def prefill_panels(start: int, t: int, s: int) -> dict:
+    """What one head's grid of one layer's call is made of, for a chunk of
+    ``t`` rows at positions ``start ..`` over a table of ``s`` keys (gathered
+    as ``prefill_keys`` of them): ``latent_panels`` that compute (some row
+    sees some key), ``latent_panels_masked`` of them that the causal horizon
+    crosses (they build and apply the mask; the others lie wholly under it)
+    and ``latent_panels_dead`` grid steps past a row block's horizon, which
+    fetch and compute nothing. The kernel's own guards, on plain ints."""
+    block_q, block_k = prefill_panel(t, s)
+    steps = prefill_keys(t, s) // block_k
+    live = whole = 0
+    for first in range(start, start + t, block_q):
+        live += min((first + block_q - 1) // block_k + 1, steps)
+        whole += min((first + 1) // block_k, steps)
+    return {"latent_panels": live, "latent_panels_masked": live - whole,
+            "latent_panels_dead": -(-t // block_q) * steps - live}
+
+
 def _prefill_kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
                     m_scr, l_scr, acc_scr, *, block_q, block_k, steps, scale):
     i = pl.program_id(1)
     j = pl.program_id(2)
 
     pl.when(j == 0)(lambda: _init_scratch(m_scr, l_scr, acc_scr))
-    start = start_ref[0]
+    first = start_ref[0] + i * block_q      # the row block's first position
 
-    def _compute():
+    def _step(masked):
         contract = (((1,), (1,)), ((), ()))
         s = jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
                                 preferred_element_type=jnp.float32)
         s = s + jax.lax.dot_general(qr_ref[0], kr_ref[...], contract,
                                     preferred_element_type=jnp.float32)
         s = s * scale
-        qpos = start + i * block_q + \
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        _online_softmax_step(s, kpos <= qpos, v_ref[0], m_scr, l_scr, acc_scr)
+        mask = None
+        if masked:
+            qpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = j * block_k + \
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos <= qpos
+        _online_softmax_step(s, mask, v_ref[0], m_scr, l_scr, acc_scr)
 
-    # the block's last query sees keys up to its own position
-    pl.when(j * block_k <= start + (i + 1) * block_q - 1)(_compute)
+    # a panel's kind, once: the block's last row sees keys up to its own
+    # position (live), and its FIRST row already sees a panel's last key
+    # where the panel lies wholly under the horizon
+    live = j * block_k <= first + block_q - 1
+    whole = (j + 1) * block_k - 1 <= first
+    pl.when(whole)(lambda: _step(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(whole)))(
+        lambda: _step(True))
 
     @pl.when(j == steps - 1)
     def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).astype(o_ref.dtype)
+        l = _across(l_scr[:], acc_scr.shape[1])
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
@@ -227,12 +323,11 @@ def latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
     positions <= start + t. Returns [H, T, d_v]."""
     h, t, d_n = q_nope.shape
     s, d_r, d_v = k_nope.shape[1], q_rope.shape[2], v.shape[2]
-    block_q = min(t, PREFILL_BLOCK_Q)
+    block_q, block_k = prefill_panel(t, s)
     tp = -(-t // block_q) * block_q
     if tp != t:
         q_nope = jnp.pad(q_nope, ((0, 0), (0, tp - t), (0, 0)))
         q_rope = jnp.pad(q_rope, ((0, 0), (0, tp - t), (0, 0)))
-    block_k = min(s, PREFILL_BLOCK_K)
     if s % block_k:
         raise ValueError(f"{s} keys are no multiple of the key block "
                          f"{block_k}: gather a table padded to whole blocks")
@@ -259,8 +354,8 @@ def latent_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
         out_specs=pl.BlockSpec((1, block_q, d_v), lambda hi, i, j, st:
                                (hi, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
     )

@@ -67,6 +67,19 @@ F32_TOL = 1e-4
 #: (0.42-0.63), shared expert 3.0 (1.6-3.3), scaling factor 2.7 (1.9-2.7)
 BF16_TOL = 0.2
 BF16_SPREAD = 0.09
+#: (rows, gathered keys, start) of the latent prefill kernel's cases
+PREFILL_CASES = {
+    "first-chunk": (16, 64, 0), "later-chunk": (16, 64, 40),
+    "blocks-of-512": (600, 1024, 300),
+    # two row blocks of the tallest panel (the second padded) whose horizon
+    # crosses three key panels each, one wholly under it before them
+    "two-crossing-panels-a-row-block": (1100, 2560, 700),
+    # a first chunk over a bucket of keys it does not reach: dead steps
+    "first-chunk-dead-steps": (1024, 2048, 0),
+    # one row block shorter than the tallest panel, start on a panel's edge
+    "aligned-start": (512, 1536, 1024),
+    # a short chunk late in a context: one narrow row block, unaligned
+    "a-quarter-of-a-row-block": (256, 1024, 700)}
 
 
 def build(dtype=jnp.float32, seed=0, **over):
@@ -258,20 +271,145 @@ def test_latent_decode_kernel_equals_the_gather_path(batch, mb, bs):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-@pytest.mark.parametrize("t,s,start", [(16, 64, 0), (16, 64, 40),
-                                       (600, 1024, 300)],
-                         ids=["first-chunk", "later-chunk", "blocks-of-512"])
-def test_latent_prefill_kernel_equals_the_gather_path(t, s, start):
+def _prefill_args(t, s, start):
     heads, d_n, d_r, d_v = 2, 8, 4, 8
     key = jax.random.split(jax.random.PRNGKey(1), 5)
-    args = (jax.random.normal(key[0], (heads, t, d_n)),
+    return (jax.random.normal(key[0], (heads, t, d_n)),
             jax.random.normal(key[1], (heads, t, d_r)),
             jax.random.normal(key[2], (heads, s, d_n)),
             jax.random.normal(key[3], (s, d_r)),
             jax.random.normal(key[4], (heads, s, d_v)), start, 0.25)
+
+
+@pytest.mark.parametrize("t,s,start", PREFILL_CASES.values(),
+                         ids=PREFILL_CASES.keys())
+def test_latent_prefill_kernel_equals_the_gather_path(t, s, start):
+    args = _prefill_args(t, s, start)
     want = la.latent_prefill_attention_reference(*args)
     got = la.latent_prefill_attention(*args, interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_every_panel_the_function_can_choose_has_a_case():
+    """The cases above run the kernel at every panel ``prefill_panel`` gives
+    over the sizes a served chunk can have (a bucket of rows over a bucket of
+    blocks), each in more than one step of the key axis and the tallest in
+    more than one row block."""
+    served = {la.prefill_panel(t, s) for t in (256, 512, 1024, 2048, 4096)
+              for s in range(1024, 8449, 64)}
+    row_blocks, key_steps = {}, {}
+    for t, s, _ in PREFILL_CASES.values():
+        panel = block_q, block_k = la.prefill_panel(t, s)
+        assert s % block_k == 0         # as ``_latent_prefill_attn`` pads
+        row_blocks[panel] = max(row_blocks.get(panel, 0), -(-t // block_q))
+        key_steps[panel] = max(key_steps.get(panel, 0), s // block_k)
+    assert served <= set(key_steps)
+    assert all(key_steps[panel] > 1 for panel in served)
+    assert row_blocks[max(served)] > 1
+
+
+def test_a_table_that_is_no_whole_panel_is_padded_with_the_trash_page():
+    """70 blocks of 8 are 560 keys, no multiple of the 512-key panel: the
+    table is padded to 1,024 with the trash page, which no row's horizon
+    reaches, and the kernel over it gives what the gather path gives."""
+    heads, d_n, d_r, d_v, rank, bs, nb = 2, 8, 4, 8, 16, 8, 80
+    key = jax.random.split(jax.random.PRNGKey(4), 4)
+    pool = jnp.zeros((1, nb, bs, 128)).at[..., :rank + d_r].set(
+        jax.random.normal(key[0], (1, nb, bs, rank + d_r)))
+    w_ukv = jax.random.normal(key[1], (rank, heads, d_n + d_v))
+    q_nope = jax.random.normal(key[2], (24, heads, d_n))
+    q_rope = jax.random.normal(key[3], (24, heads, d_r))
+    table = jnp.arange(70, dtype=jnp.int32)
+    assert la.prefill_keys(24, 70 * bs) == 1024
+    want, got = (kv_cache._latent_prefill_attn(
+        q_nope, q_rope, pool, 0, table, 530, w_ukv, 0.29, impl)
+        for impl in ("gather", "kernel_interpret"))
+    assert got.shape == (24, heads, d_v)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("rows,keys", [(16, 64), (512, 2048), (600, 8448),
+                                       (4096, 8448), (1024, 4160),
+                                       (256, 1024), (2048, 6144)])
+def test_keys_are_padded_to_the_panel_the_kernel_then_chooses(rows, keys):
+    """``_latent_prefill_attn`` pads the gathered keys with the panel of
+    ``(rows, keys)`` and the kernel sees the padded keys: the same panel,
+    whole panels, and under one panel more than the table holds."""
+    padded = la.prefill_keys(rows, keys)
+    block_k = la.prefill_panel(rows, keys)[1]
+    assert la.prefill_panel(rows, padded) == la.prefill_panel(rows, keys)
+    assert padded % block_k == 0 and keys <= padded < keys + block_k
+
+
+@pytest.mark.parametrize("lanes", [1, 128], ids=["columns", "lane-dense"])
+@pytest.mark.parametrize("fresh", [False, True],
+                         ids=["mid-row-block", "first-panel"])
+def test_unmasked_step_is_the_masked_step_under_an_all_true_mask(lanes,
+                                                                 fresh):
+    """A panel wholly under the horizon runs a step that builds no mask: the
+    same ``(m, l, acc)`` bit for bit as today's step gives where every key
+    is seen, from the same scratch (a row block's first panel starts from
+    ``(-inf, 0, 0)``), the maximum and sum held either way."""
+    key = jax.random.split(jax.random.PRNGKey(3), 5)
+    s = 3.0 * jax.random.normal(key[0], (64, 256))
+    v = jax.random.normal(key[1], (256, 16)).astype(jnp.bfloat16)
+    if fresh:
+        scratch = (jnp.full((64, lanes), la.NEG_INF), jnp.zeros((64, lanes)),
+                   jnp.zeros((64, 16)))
+    else:
+        scratch = (jnp.tile(jax.random.normal(key[2], (64, 1)), (1, lanes)),
+                   jnp.tile(jnp.abs(jax.random.normal(key[3], (64, 1))),
+                            (1, lanes)),
+                   jax.random.normal(key[4], (64, 16)))
+    bare = la._softmax_update(s, None, v, *scratch)
+    masked = la._softmax_update(s, jnp.ones(s.shape, bool), v, *scratch)
+    for a, b in zip(bare, masked):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert bare[0].shape == bare[1].shape == (64, lanes)
+
+
+def _panels_from_the_mask(start, t, s):
+    """The panel counts from the ``[rows, keys]`` causal mask itself: a panel
+    computes where any of its pairs is kept, is masked where not all are."""
+    block_q, block_k = la.prefill_panel(t, s)
+    rows = -(-t // block_q) * block_q       # the kernel pads the last block
+    mask = np.arange(s)[None, :] <= start + np.arange(rows)[:, None]
+    cut = mask.reshape(rows // block_q, block_q, s // block_k, block_k)
+    some, every = cut.any(axis=(1, 3)), cut.all(axis=(1, 3))
+    return {"latent_panels": int(some.sum()),
+            "latent_panels_masked": int((some & ~every).sum()),
+            "latent_panels_dead": int((~some).sum())}
+
+
+@pytest.mark.parametrize("t", [256, 512, 600, 1024, 2048, 4096])
+def test_panel_counts_equal_a_count_from_the_mask_itself(t):
+    for keys in (1024, 2048, 4096, 4160, 6144, 8448):
+        for start in (0, 1, 255, 256, 511, 512, 700, 1023, 1024, 3000, 4096,
+                      4097, 4500, 7000):
+            if start + t > keys:
+                continue
+            assert la.prefill_panels(start, t, keys) == _panels_from_the_mask(
+                start, t, la.prefill_keys(t, keys)), (start, t, keys)
+
+
+@pytest.mark.parametrize("chunk,want", [
+    # docqa's first chunk, 4,096 rows over the 64-block bucket: four row
+    # blocks of 1,024 see 2, 4, 6, 8 panels of 512 keys, the last two of each
+    # on the diagonal; the same over the 132-block bucket, 8,448 keys padded
+    # to 8,704 = 17 steps, leaves 48 steps dead
+    ((0, 4096, 4096), (20, 8, 12)), ((0, 4096, 8448), (20, 8, 48)),
+    # its second chunk at 4,096: 10, 12, 14, 16 panels, two crossed each
+    ((4096, 4096, 8448), (52, 8, 16)),
+    # an unaligned start crosses three: rows 700-1,723 over keys 512-2,047
+    ((700, 1024, 2048), (4, 3, 0)),
+    # reasoning's first chunk of 1,024 rows: both its panels are crossed
+    ((0, 1024, 1024), (2, 2, 0)), ((3000, 256, 4160), (7, 2, 2)),
+])
+def test_panel_counts_by_hand(chunk, want):
+    start, t, keys = chunk
+    got = la.prefill_panels(start, t, keys)
+    assert (got["latent_panels"], got["latent_panels_masked"],
+            got["latent_panels_dead"]) == want
 
 
 def test_folded_decode_equals_unfolded_attention():
@@ -588,6 +726,42 @@ def test_counts_ride_on_the_spans_that_wait(f32):
                    for k in ("tile_keys", "slot_copies",
                              "slot_copies_windowed"))
     assert eng._pending_counts == []
+
+
+def test_latent_panel_counts_ride_on_the_chunk_spans(f32, monkeypatch):
+    """Over a latent pool a chunk's span says what its kernel's grid was made
+    of, beside ``tokens``, ``bucket`` and ``start``: the panels of one head
+    of one layer's call, from the function that shares the panel with the
+    kernel; an engine that is not traced does not ask for them."""
+    from deepspeed_tpu.telemetry.tracer import get_tracer
+    cfg, _, params = f32
+    asked = []
+    counted = BlockedKVCache.chunk_tile_keys
+    monkeypatch.setattr(
+        BlockedKVCache, "chunk_tile_keys",
+        lambda self, *chunk: asked.append(chunk) or counted(self, *chunk))
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.configure(enabled=True)
+    tracer.clear()
+    try:
+        engine(cfg, params).generate(tokens(40).tolist(), max_new_tokens=2)
+        events = tracer.events_snapshot()
+        traced = asked[:]
+        tracer.configure(enabled=False)
+        engine(cfg, params).generate(tokens(40).tolist(), max_new_tokens=2)
+    finally:
+        tracer.configure(enabled=was)
+    assert asked == traced              # the untraced engine asked for none
+    chunks = [e[7] for e in events if e[1] == "serve/prefill_chunk"]
+    assert [(c["start"], c["bucket"]) for c in chunks] == [(0, 32), (32, 16)]
+    assert [chunk[:2] for chunk in asked] == [(0, 32), (32, 16)]
+    for c, (_, _, table_blocks, _) in zip(chunks, asked):
+        want = la.prefill_panels(c["start"], c["bucket"],
+                                 table_blocks * BLOCK)
+        assert want["latent_panels"] >= 1
+        assert {k: c[k] for k in want} == want
+        assert not any(k in c for k in ("tile_keys", "tile_copies"))
 
 
 def test_untraced_engine_keeps_no_counts(f32):

@@ -313,7 +313,11 @@ def test_decode_slot_copies_are_absent_over_a_latent_pool():
                                           head_dim=64, latent_dim=48,
                                           num_blocks=4))
     assert latent.decode_slot_copies([100], 8, 4, None) == {}
-    assert latent.chunk_tile_keys(0, 64, 4, None) == {}
+    # a chunk's counts over a latent pool are the latent prefill kernel's
+    # panels (one here: 64 rows over 4 blocks of 16), not the paged kernel's
+    assert latent.chunk_tile_keys(0, 64, 4, None) == {
+        "latent_panels": 1, "latent_panels_masked": 1,
+        "latent_panels_dead": 0}
 
 
 @pytest.mark.parametrize("case,contexts,window,want", [

@@ -383,6 +383,29 @@ def test_layers_of_a_latent_pool_share_one_body_a_latent_kernel(one_chip,
         assert len(re.findall(rf"call @{call}\b", text)) == cfg.num_layers
 
 
+@pytest.mark.parametrize("rows,blocks", [(4096, 132), (1024, 65)],
+                         ids=["docqa-4096-over-132", "reasoning-1024-over-65"])
+def test_latent_prefill_panels_compile_for_a_v5e(one_chip, rows, blocks):
+    """The panel ``prefill_panel`` chooses at the two latent cells' largest
+    calls (a chunk of 4,096 rows over the 132-block bucket, one of 1,024
+    over 65 blocks; 32 heads of 128 + 64 / 128), keys padded as
+    ``_latent_prefill_attn`` pads them: Mosaic takes both bodies, the panel
+    and the scratch inside its scoped VMEM."""
+    from deepspeed_tpu.inference.v2 import kv_cache
+    from deepspeed_tpu.ops.pallas.latent_attention import prefill_keys
+    keys = prefill_keys(rows, blocks * BLOCK)
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    text = kv_cache._latent_prefill_call.lower(
+        arr(32, rows, 128), arr(32, rows, 64), arr(32, keys, 128),
+        arr(keys, 64), arr(32, keys, 128),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        scale=192 ** -0.5, interpret=False).compile().as_text()
+    assert "latent_prefill_attention" in text
+
+
 # --- the softmax-routed experts -----------------------------------------------
 # Mixtral-8x7B as the batch-rag cell serves it: 3 layers, 8 experts of
 # 4096 x 14336 top-2, a pool of 1472 blocks, a 2,048-token chunk or 32
