@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.inference.v2.engine_contract import ServingEngine
 from deepspeed_tpu.inference.v2.generic_decode import (decode_step_g,
                                                        prefill_chunk_g,
                                                        verify_chunk_g)
@@ -45,7 +46,6 @@ from deepspeed_tpu.inference.v2.ragged_manager import SequenceDescriptor, StateM
 from deepspeed_tpu.inference.v2.sampling import (SamplingConfig, feed_tokens,
                                                  sample_into)
 from deepspeed_tpu.inference.v2.scheduler import (
-    PrefillChunk,
     SchedulerConfig,
     StepPlan,
     plan_step,
@@ -144,7 +144,7 @@ class _Dispatched:
         return {"ahead": self.ahead, "starved": self.starved}
 
 
-class InferenceEngineV2:
+class InferenceEngineV2(ServingEngine):
     """Serves any registered arch (llama family incl. mistral/qwen2/phi3,
     falcon, opt, mixtral, joyai_llm_flash over its latent cache, ...) — the
     policy registry picks the decode implementation from the model config
@@ -1204,6 +1204,9 @@ class InferenceEngineV2:
     def host_kv_bytes(self) -> int:
         return self.host_kv.total_bytes
 
+    def host_kv_compression(self) -> float:
+        return self.host_kv.compression_ratio()
+
     def kv_ledger(self) -> Dict[str, int]:
         """Both tiers' occupancy in one dict — the serving drain test's
         "ledger returns to zero" surface and the bench_serve proof.
@@ -1269,9 +1272,10 @@ class InferenceEngineV2:
         return out
 
     # ------------------------------------------------------------------
-    # serving hooks (consumed by deepspeed_tpu/serving: the serve loop
-    # admits without stepping, steps in its own cadence, and reaps
-    # finished sequences between steps)
+    # serving hooks (``engine_contract.ServingEngine`` names all that
+    # deepspeed_tpu/serving uses of an engine: the serve loop admits
+    # without stepping, steps in its own cadence, and reaps finished
+    # sequences between steps)
     # ------------------------------------------------------------------
     def admit(self, uid: int, prompt_tokens: Sequence[int],
               max_new_tokens: Optional[int] = None) -> SequenceDescriptor:
@@ -1296,6 +1300,23 @@ class InferenceEngineV2:
         if seq is not None:
             seq.done = True
 
+    def is_done(self, uid: int) -> bool:
+        seq = self.state.get(uid)
+        return seq is not None and seq.done
+
+    def has_rows_left(self, uid: int) -> bool:
+        seq = self.state.get(uid)
+        return seq is not None and not seq.done and not seq.budget_spent
+
+    def max_context_length(self) -> int:
+        return self.state.max_context_length
+
+    def decode_batch_buckets(self) -> Sequence[int]:
+        return self.config.decode_batch_buckets
+
+    def prefill_buckets(self) -> Sequence[int]:
+        return self.config.scheduler.prefill_buckets
+
     def finished_uids(self) -> List[int]:
         """Done sequences with no row in flight: one that ended with a row
         already queued (``eos_token_id`` read a tick late, a cancel) is
@@ -1319,6 +1340,9 @@ class InferenceEngineV2:
         """Blocks available to sequences (the last block is the permanent
         trash page for padding writes and never allocates)."""
         return self.kv.usable_blocks
+
+    def kv_blocks_needed(self, num_tokens: int) -> int:
+        return self.kv.blocks_needed(num_tokens)
 
     def kv_occupancy(self) -> float:
         """Fraction of usable KV cache blocks currently reserved (0..1)."""

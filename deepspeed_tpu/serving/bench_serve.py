@@ -230,12 +230,8 @@ def warm_scenario(server: InferenceServer, scenario: ServeScenario
     warm_sc = dataclasses.replace(scenario, seed=scenario.seed + 104_729,
                                   shared_prefix_frac=0.0)
     conc = max(scenario.concurrency, 1)
-    try:
-        buckets = sorted({snap_bucket(
-            n, server.engine.config.decode_batch_buckets)
-            for n in range(1, conc + 1)})
-    except AttributeError:        # engine without decode buckets: one wave
-        buckets = [conc]
+    buckets = sorted({snap_bucket(n, server.engine.decode_batch_buckets())
+                      for n in range(1, conc + 1)})
     # the LONGEST declared shapes: prompts stretched to the range max and
     # the max generation length, so the deepest context bucket (and every
     # shallower one passed through while decoding) compiles now
@@ -281,7 +277,7 @@ class _Lane:
 
     def run(self):
         sc = self.scenario
-        max_ctx = self.server.engine.state.max_context_length
+        max_ctx = self.server.engine.max_context_length()
         for turn in range(max(sc.turns, 1)):
             for index in self.indices:
                 prompt, max_new, priority, shared_len = _request_shape(
@@ -370,16 +366,13 @@ def run_scenario(server: InferenceServer, scenario: ServeScenario,
     # the measured proof set (its uids are likewise dropped from the
     # span-derived latency percentiles below)
     compile_mark = compiles_total()
-    if hasattr(server.engine, "sched_mark"):
-        # reset the tick-ledger window maxima (max prefill tokens/tick,
-        # max decode stall) so the scheduler proof set below covers the
-        # measured window only, like every other counter here
-        server.engine.sched_mark()
+    # reset the tick-ledger window maxima (max prefill tokens/tick, max
+    # decode stall) so the scheduler proof set below covers the measured
+    # window only, like every other counter here
+    server.engine.sched_mark()
     pre_snap = server.metrics.snapshot() if warmup else {}
     pre_slo = server.metrics.slo_snapshot() if warmup else {}
-    pre_prefix = (server.engine.prefix_stats()
-                  if warmup and hasattr(server.engine, "prefix_stats")
-                  else {})
+    pre_prefix = server.engine.prefix_stats() if warmup else {}
     results: dict = {}
     lock = threading.Lock()
     t0 = time.monotonic()
@@ -435,13 +428,11 @@ def run_scenario(server: InferenceServer, scenario: ServeScenario,
     for rec in results.values():
         states[rec["state"]] = states.get(rec["state"], 0) + 1
         client_tokens += len(rec.get("tokens") or ())
-    ledger = (server.engine.kv_ledger()
-              if hasattr(server.engine, "kv_ledger") else {})
+    ledger = server.engine.kv_ledger()
     # engine-truth prefix/prefill counters (the metrics mirror can lag
     # one tick; after the drain these are final and exact)
-    prefix = (server.engine.prefix_stats()
-              if hasattr(server.engine, "prefix_stats") else {})
-    if prefix and pre_prefix:
+    prefix = server.engine.prefix_stats()
+    if pre_prefix:
         # warmed run: the monotonic prefix counters become measured-window
         # deltas (occupancy gauges stay live values) and the hit ratio is
         # recomputed over the window — warm traffic is deliberately novel
@@ -457,44 +448,33 @@ def run_scenario(server: InferenceServer, scenario: ServeScenario,
             prefix["prefix_hit_ratio"] = (
                 prefix.get("prefix_hit_tokens", 0)
                 / max(prefix.get("prefix_lookup_tokens", 0), 1))
-    if prefix:
-        # ground-truth denominator: tokens the workload genuinely made
-        # shareable (conversation histories + shared-pool prefixes); the
-        # cache can never legitimately save more than this
-        prefix["expected_reusable_tokens"] = sum(
-            rec.get("reusable_tokens", 0) for rec in results.values())
-        prefix["conservation_ok"] = (
-            prefix.get("prefill_tokens_saved", 0)
-            + prefix.get("prefill_tokens_computed", 0)
-            == prefix.get("prefill_tokens_total", 0))
-        prefix["bytes_per_resident_token"] = \
-            snap["bytes_per_resident_token"]
-        prefix["host_compression_ratio"] = \
-            snap["host_kv_compression_ratio"]
+    # ground-truth denominator: tokens the workload genuinely made
+    # shareable (conversation histories + shared-pool prefixes); the
+    # cache can never legitimately save more than this
+    prefix["expected_reusable_tokens"] = sum(
+        rec.get("reusable_tokens", 0) for rec in results.values())
+    prefix["conservation_ok"] = (
+        prefix.get("prefill_tokens_saved", 0)
+        + prefix.get("prefill_tokens_computed", 0)
+        == prefix.get("prefill_tokens_total", 0))
+    prefix["bytes_per_resident_token"] = snap["bytes_per_resident_token"]
+    prefix["host_compression_ratio"] = snap["host_kv_compression_ratio"]
     # scheduler proof set: the engine tick ledger (per-tick prefill-token
     # maxima, cap utilization, decode-gap in ticks). Window maxima cover
     # the measured window (sched_mark above); totals are cumulative, and
     # the conservation check ties them to the engine-truth prefill
     # counter — chunking must neither lose nor duplicate a prompt token.
-    sched: dict = {}
-    if hasattr(server.engine, "sched_stats"):
-        sched_cfg = dict(getattr(server.config, "scheduler", None) or {})
-        cap = int(sched_cfg.get("prefill_chunk_tokens", 0) or 0)
-        plan_cfg = getattr(getattr(server.engine, "config", None),
-                           "scheduler", None)
-        # unchunked runs report the decode gap in units of the smallest
-        # prefill bucket so a chunked A/B can re-state its gap in the
-        # same units (sched_stats(gap_unit_tokens=...))
-        unit = cap or (int(plan_cfg.prefill_buckets[0])
-                       if plan_cfg is not None and plan_cfg.prefill_buckets
-                       else 0)
-        sched = server.engine.sched_stats(gap_unit_tokens=unit)
-        if hasattr(server.engine, "prefix_stats"):
-            computed = int(server.engine.prefix_stats()
-                           .get("prefill_tokens_computed", 0))
-            sched["prefill_tokens_engine"] = computed
-            sched["chunk_conservation_ok"] = \
-                sched["chunk_tokens_total"] == computed
+    cap = int(server.config.scheduler.get("prefill_chunk_tokens", 0) or 0)
+    # unchunked runs report the decode gap in units of the smallest
+    # prefill bucket so a chunked A/B can re-state its gap in the
+    # same units (sched_stats(gap_unit_tokens=...))
+    ladder = server.engine.prefill_buckets()
+    unit = cap or (int(ladder[0]) if ladder else 0)
+    sched = server.engine.sched_stats(gap_unit_tokens=unit)
+    computed = int(server.engine.prefix_stats()
+                   .get("prefill_tokens_computed", 0))
+    sched["prefill_tokens_engine"] = computed
+    sched["chunk_conservation_ok"] = sched["chunk_tokens_total"] == computed
     # the SLO proof set + its conservation gate: every measured request
     # that produced a first token lands in the TTFT histogram exactly
     # once (on_finish observes iff first_token_ts is set, and the client
@@ -522,10 +502,6 @@ def run_scenario(server: InferenceServer, scenario: ServeScenario,
         "serving_config": dataclasses.asdict(server.config),
         "trace_path": (os.path.abspath(env_trace) if env_trace else None),
     }
-    kv_cfg = getattr(getattr(server.engine, "kv", None), "cfg", None)
-    if kv_cfg is not None:
-        prov["kv_num_blocks"] = kv_cfg.num_blocks
-        prov["kv_block_size"] = kv_cfg.block_size
     if provenance:
         prov.update(provenance)
     return {
@@ -711,9 +687,7 @@ def run_fleet_scenario(router, scenario: ServeScenario,
     pre_slo: List[Dict[str, dict]] = [
         server.metrics.slo_snapshot() for server, _fe in members]
     pre_prefix: List[dict] = [
-        server.engine.prefix_stats() if hasattr(server.engine,
-                                                "prefix_stats") else {}
-        for server, _fe in members]
+        server.engine.prefix_stats() for server, _fe in members]
     results: dict = {}
     lock = threading.Lock()
     t0 = time.monotonic()
@@ -752,8 +726,6 @@ def run_fleet_scenario(router, scenario: ServeScenario,
         client_tokens += len(rec.get("tokens") or ())
     prefix: dict = {}
     for i, (server, _fe) in enumerate(members):
-        if not hasattr(server.engine, "prefix_stats"):
-            continue
         stats = server.engine.prefix_stats()
         for k in ("prefill_tokens_total", "prefill_tokens_saved",
                   "prefill_tokens_computed", "prefix_lookups",

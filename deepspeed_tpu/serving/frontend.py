@@ -229,7 +229,8 @@ class ServingFrontend:
                         return
                     try:
                         frontend.serving.adopt_prefix_handoff(path)
-                    except (ValueError, AttributeError) as e:
+                    except (ValueError, NotImplementedError) as e:
+                        # an engine that cannot adopt refuses by name
                         self._json(400, {"error": f"cannot adopt: {e!r}"})
                         return
                     self._json(200, {"adopted": True, "handoff_path": path})

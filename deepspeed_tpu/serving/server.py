@@ -52,6 +52,8 @@ from typing import Dict, List, Optional, Sequence
 
 from deepspeed_tpu.comm.guard import CommOutcome, classify_exception
 from deepspeed_tpu.config import constants as C
+from deepspeed_tpu.inference.v2.engine_contract import ServingEngine
+from deepspeed_tpu.inference.v2.kv_offload import KV_CODECS
 from deepspeed_tpu.resilience.chaos import REPLICA_ID_ENV, monkey_from_env
 from deepspeed_tpu.serving.degradation import (DegradationLadder,
                                                LadderConfig, ServeLevel)
@@ -276,7 +278,8 @@ class ServingConfig:
 
 
 class InferenceServer:
-    """Drives one ``InferenceEngineV2`` from a background thread with
+    """Drives one ``ServingEngine`` (``inference/v2/engine_contract.py``: all
+    this module uses of an engine) from a background thread with
     continuous batching, streaming fan-out, tiered admission control, a
     degradation ladder, request-level fault isolation, and graceful drain
     (the shutdown AND elastic-resize hook: drain, resize or recreate the
@@ -284,6 +287,14 @@ class InferenceServer:
 
     def __init__(self, engine, config: Optional[ServingConfig] = None,
                  monitor=None, membership=None, chaos=None):
+        # (``engine`` carries no annotation: dslint's call graph follows a
+        # typed receiver to the class named, not to its subclasses, and the
+        # engine's own methods would fall out of the hot-path closure)
+        if not isinstance(engine, ServingEngine):
+            raise TypeError(
+                f"InferenceServer serves a ServingEngine "
+                f"(inference/v2/engine_contract.py), got "
+                f"{type(engine).__name__}")
         self.engine = engine
         self.config = config or ServingConfig()
         # optional resilience.membership.MembershipView: a wedged/lost peer
@@ -321,43 +332,31 @@ class InferenceServer:
         self._kv_watermark_scale = 1.0   # drift-recalibrated multiplier
         self._wake = threading.Event()         # submit() nudges the loop
         self._thread: Optional[threading.Thread] = None
-        # the offload tier needs the engine-side hooks (real engines have
-        # them; minimal doubles in tests may not)
-        self._tier_capable = (self.config.kv_offload_enabled
-                              and hasattr(engine, "demote_kv"))
-        if self._tier_capable and hasattr(engine, "require_one_page_kind"):
-            # refused by name here, not at the first demotion under load
-            engine.require_one_page_kind("the host KV offload tier")
-        from deepspeed_tpu.inference.v2.kv_offload import KV_CODECS
         if self.config.host_kv_quantize not in KV_CODECS:
             raise ValueError(
                 f"host_kv_quantize must be one of {KV_CODECS}, got "
                 f"{self.config.host_kv_quantize!r}")
-        # radix prefix cache: the serving knob flips it on at the engine
-        # (where admission lives); minimal test doubles without the hook
-        # simply run uncached
-        if self.config.prefix_cache_enabled and \
-                hasattr(engine, "enable_prefix_cache"):
+        # a capability the configuration asks for is wired into the engine
+        # here, and an engine that does not provide it refuses by name here
+        # (``EngineCapabilityError``; over pages of two kinds or a state
+        # kind, ``kv_cache``'s own errors), not at the first demotion under
+        # load, and never by serving without it
+        self._tier_capable = self.config.kv_offload_enabled
+        if self._tier_capable:
+            engine.require_one_page_kind("the host KV offload tier")
+        # the radix prefix cache is flipped on at the engine, where
+        # admission lives
+        self._prefix_capable = self.config.prefix_cache_enabled
+        if self._prefix_capable:
             engine.enable_prefix_cache(self.config.prefix_cache_max_blocks)
-        self._prefix_capable = (self.config.prefix_cache_enabled
-                                and getattr(engine, "prefix_cache", None)
-                                is not None)
-        # decode-first chunked prefill: wire the scheduler sub-group's cap
-        # into the engine's SplitFuse planner (minimal test doubles without
-        # the hook simply run uncapped); cap 0 touches nothing, so the
+        # decode-first chunked prefill: the scheduler sub-group's cap goes
+        # into the engine's SplitFuse planner; cap 0 touches nothing, so the
         # default config leaves planning bit-identical
         cap = int(self.config.scheduler.get("prefill_chunk_tokens", 0) or 0)
-        if cap > 0 and hasattr(engine, "configure_chunked_prefill"):
+        if cap > 0:
             engine.configure_chunked_prefill(cap)
-        # one step in flight: while the loop runs, ``engine.step`` dispatches
-        # tick k and collects tick k-1, so the fan-out, the reap and the
-        # admission between two ticks run while the device does. The depth
-        # is the loop's own (``_serve_loop`` sets and clears it), not a
-        # configuration key; an engine double without ``collect`` runs as
-        # before. Such an engine is told the request's budget at admission,
-        # so that it dispatches no row past it
-        self._pipelined = hasattr(engine, "collect")
-        self._block_bytes_cache: Optional[int] = None
+        self._block_bytes = engine.kv_block_bytes()
+        self._sampled: Dict[str, dict] = {}    # counter tracks' last samples
         # serving-tick stage clocks (serve-loop-private): cumulative busy
         # seconds per stage + cumulative tick seconds, feeding the
         # serve/tick_stage_share counter track (/metrics + dstrace)
@@ -458,8 +457,8 @@ class InferenceServer:
         between ticks, so this is safe to call from the frontend's admin
         route while requests are in flight. With no serve loop running
         (worker startup), the import runs inline."""
-        if not hasattr(self.engine, "import_prefix_handoff"):
-            raise ValueError("engine has no prefix-handoff support")
+        # refused by name at the call, not in the loop's log a tick later
+        self.engine.require_one_page_kind("the prefix handoff (import)")
         if not self.running:
             self._import_handoff(path)
             return
@@ -496,8 +495,6 @@ class InferenceServer:
             raise RuntimeError(
                 "export_prefix_handoff requires a stopped server "
                 "(drain + stop first)")
-        if not hasattr(self.engine, "export_prefix_handoff"):
-            return {"chains": 0, "blocks": 0}
         q = quantize if quantize is not None else self.config.host_kv_quantize
         got = self.engine.export_prefix_handoff(path, quantize=q)
         get_tracer().instant("serve/prefix_handoff_export", cat="serve",
@@ -520,6 +517,7 @@ class InferenceServer:
             demoted = len(self._demoted)
             degraded = self._degraded
             fault_episode = self._fault_episode
+        cache = self.engine.prefix_cache
         state = ("stopped" if self._stopped else
                  # a FATAL engine-step failure means the KV/sequence state
                  # is suspect: report unhealthy (503 at /healthz) so load
@@ -542,10 +540,8 @@ class InferenceServer:
                # the fleet router's affinity + retirement signals
                "replica_id": self.replica_id,
                "draining": self._draining,
-               "prefix_cache_blocks": (
-                   self.engine.prefix_cache.cached_blocks()
-                   if getattr(self.engine, "prefix_cache", None) is not None
-                   else 0)}
+               "prefix_cache_blocks": (cache.cached_blocks()
+                                       if cache is not None else 0)}
         if degraded:
             out["degraded_reason"] = degraded
         if self._tier_capable:
@@ -561,24 +557,15 @@ class InferenceServer:
         # worst case AT COMPLETION: prompt + full budget. Invariant under
         # eviction/re-admission (generated tokens move from budget to
         # prompt, the total is unchanged)
-        return self.engine.kv.blocks_needed(
+        return self.engine.kv_blocks_needed(
             len(req.prompt_tokens) + req.max_new_tokens)
-
-    def _block_bytes(self) -> int:
-        if self._block_bytes_cache is None:
-            fn = getattr(self.engine, "kv_block_bytes", None)
-            self._block_bytes_cache = fn() if fn is not None else 0
-        return self._block_bytes_cache
 
     def _host_budget_blocks(self) -> int:
         """The host tier's capacity expressed in device-block equivalents
         — what admission projects against beyond the device watermark."""
-        if not self._tier_capable:
+        if not self._tier_capable or self._block_bytes <= 0:
             return 0
-        bb = self._block_bytes()
-        if bb <= 0:
-            return 0
-        return self.config.host_kv_budget_bytes // bb
+        return self.config.host_kv_budget_bytes // self._block_bytes
 
     def submit(self, prompt_tokens: Sequence[int],
                max_new_tokens: Optional[int] = None,
@@ -622,7 +609,7 @@ class InferenceServer:
             req.trace_id = str(trace_id)
         if not req.prompt_tokens:
             raise ValueError("empty prompt")
-        max_ctx = self.engine.state.max_context_length
+        max_ctx = self.engine.max_context_length()
         if len(req.prompt_tokens) + req.max_new_tokens > max_ctx:
             # past max_seq_len the decode would silently clamp positions
             # (garbage RoPE rotations), so reject at the door
@@ -706,16 +693,18 @@ class InferenceServer:
     # the serve loop (single thread; sole owner of the engine)
     # ------------------------------------------------------------------
     def _serve_loop(self):
-        if self._pipelined:
-            self.engine.depth = 1
+        # one step in flight: while the loop runs, ``engine.step`` dispatches
+        # tick k and collects tick k-1, so the fan-out, the reap and the
+        # admission between two ticks run while the device does. The depth
+        # is the loop's own, not a configuration key
+        self.engine.depth = 1
         try:
             self._serve_ticks()
         finally:
-            if self._pipelined:
-                # the engine goes back to whoever calls it next as it
-                # came: nothing pending, every step's tokens from its call
-                self._collect_pending()
-                self.engine.depth = 0
+            # the engine goes back to whoever calls it next as it came:
+            # nothing pending, every step's tokens from its call
+            self._collect_pending()
+            self.engine.depth = 0
 
     def _collect_pending(self) -> None:
         """Collect what the engine has in flight (its next ``step`` returns
@@ -798,17 +787,16 @@ class InferenceServer:
                 with get_tracer().span("serve/engine_step", cat="serve",
                                        tick=self._tick):
                     # dispatches this tick; ``out`` is what the tick before
-                    # dispatched (this tick's, from an engine double)
+                    # dispatched
                     out = self.engine.step()
             except Exception as e:
                 raise _EngineStepError(str(e)) from e
             t0 = time.monotonic()
-            counters = getattr(self.engine, "last_step_counters", None) or {}
+            counters = self.engine.last_step_counters
             self.metrics.on_step(ahead=counters.get("ahead", 0),
                                  rows_dropped=counters.get("rows_dropped", 0),
                                  starved=counters.get("starved", 0))
-            self._note_clean_step(
-                getattr(self.engine, "last_collected_uids", None))
+            self._note_clean_step(self.engine.last_collected_uids)
             worked = True
             # what follows the step belongs to no stage; its mark ends
             # where the fan-out's begins, so the two tile
@@ -838,8 +826,9 @@ class InferenceServer:
             projected_blocks = (sum(self._blocks_for(r) for r in self._queue)
                                 + sum(self._blocks_for(r)
                                       for r in self._inflight.values()))
-        self._reconcile_kv(projected_blocks)
-        self._prefix_gauges()
+        busy = worked or moved > 0
+        self._reconcile_kv(projected_blocks, busy)
+        self._prefix_gauges(busy)
         self._observe_ladder(queued, stolen_frac)
         self.metrics.set_gauges(queue_depth=queued, inflight=inflight,
                                 kv_occupancy=self.engine.kv_occupancy())
@@ -849,16 +838,27 @@ class InferenceServer:
                 self.metrics.export(self.monitor, self.metrics.engine_steps)
             except Exception:
                 logger.exception("serve loop: monitor export failed")
-        if worked or moved:
+        if busy:
             # only ticks that did something land in the ring: an idle
             # server polling its queue must not flood the bounded trace
-            # (the stretch they lie in is stamped whole, ``_LoopIdle``)
+            # (the stretch they lie in is stamped whole, ``_LoopIdle``;
+            # their counter samples: ``_sample``)
             self._idle.close(t_tick0)
             self._emit_tick_spans(marks, t_tick0, t0, worked, queued,
                                   inflight)
         else:
             self._idle.saw(queued, inflight)
         return worked
+
+    def _sample(self, busy: bool, name: str, **series) -> None:
+        """One sample of a memory counter track: from every tick that did
+        something, and from one that did not only where the track moved
+        (the reap after the last step): an idle traced server polls its
+        queue fifty times a second."""
+        last = self._sampled.get(name, {})     # no sample yet: all zero
+        if busy or any(v != last.get(k, 0) for k, v in series.items()):
+            self._sampled[name] = series
+            get_tracer().counter(name, cat="mem", **series)
 
     def _mark(self, marks: list, stage: Optional[str], t0: float,
               span: Optional[str] = None, **args) -> float:
@@ -883,9 +883,9 @@ class InferenceServer:
         stamped last, so this emission lies inside both and the tick's
         spans tile it to its end."""
         stage_s = {s: 0.0 for s in _TICK_STAGES}
-        timing = getattr(self.engine, "last_step_timing", None)
-        if worked and timing:
+        if worked:
             # the engine timed (and trace-spanned) its own step interior
+            timing = self.engine.last_step_timing
             stage_s["prefill"] = timing.get("prefill_s", 0.0)
             stage_s["decode"] = timing.get("decode_s", 0.0)
         for stage, _name, t0, t1, _args in marks:
@@ -966,14 +966,8 @@ class InferenceServer:
         # pages would be a wasted copy that skews the demotion counters),
         # nor with its whole budget dispatched (its last token is on the
         # device or on its way out: it is done once that is fanned out)
-        active = []
-        for u, r in snapshot:
-            if u in dem:
-                continue
-            seq = self.engine.state.get(u)
-            if seq is None or seq.done or getattr(seq, "budget_spent", False):
-                continue
-            active.append(r)
+        active = [r for u, r in snapshot
+                  if u not in dem and self.engine.has_rows_left(u)]
         worst = [self._blocks_for(r) for r in active]
         held = [self.engine.kv_held_blocks(r.uid) for r in active]
         reserved = self.engine.kv_reserved_blocks()
@@ -992,7 +986,7 @@ class InferenceServer:
         plan = plan_demotions(worst, held, reserved, capacity,
                               demote_wm * effective,
                               cfg.min_active_requests)
-        bb = self._block_bytes()
+        bb = self._block_bytes
         demoted_now = 0
         promoted_now = 0
         executed = set()
@@ -1025,20 +1019,16 @@ class InferenceServer:
         with self._lock:
             demoted_pairs = [(u, self._inflight[u]) for u in self._demoted
                              if u in self._inflight]
-        demoted_reqs = []
-        for u, req in demoted_pairs:
-            seq = self.engine.state.get(u)
-            if seq is None or seq.done:
-                continue
-            demoted_reqs.append(req)
+        demoted_reqs = [req for u, req in demoted_pairs
+                        if self.engine.has_rows_left(u)]
         if demoted_reqs:
             d_worst = [self._blocks_for(r) for r in demoted_reqs]
             d_held = [self.engine.demoted_blocks(r.uid)
                       for r in demoted_reqs]
+            reserved = self.engine.kv_reserved_blocks()
             n_promote = plan_promotions(d_worst, d_held, active_worst_sum,
-                                        capacity, self.engine.kv.free_blocks,
-                                        self.engine.kv_reserved_blocks(),
-                                        demote_wm * effective)
+                                        capacity, usable - reserved,
+                                        reserved, demote_wm * effective)
             for r in demoted_reqs[:n_promote]:
                 t0 = time.monotonic()
                 restored = self.engine.promote_kv(r.uid)
@@ -1103,31 +1093,24 @@ class InferenceServer:
             return 0
         return self.engine.prefix_cache.evictable_blocks()
 
-    def _prefix_gauges(self) -> None:
+    def _prefix_gauges(self, busy: bool) -> None:
         """Fold the engine's prefix/prefill counters into the serving
         metrics each tick (pure host reads — the counters are plain
         ints the engine already maintains) and emit the dsmem-idiom
         counter track so cache occupancy lines up with the serve spans
         on the trace timeline."""
-        stats_fn = getattr(self.engine, "prefix_stats", None)
-        if stats_fn is None:
-            return
-        stats = stats_fn()
-        resident = self.engine.resident_tokens()
-        resident_bytes = self.engine.kv_resident_bytes()
-        host = getattr(self.engine, "host_kv", None)
+        stats = self.engine.prefix_stats()
         self.metrics.set_prefix_gauges(
-            stats, resident_tokens=resident, resident_bytes=resident_bytes,
-            host_compression=(host.compression_ratio()
-                              if host is not None else 1.0))
+            stats, resident_tokens=self.engine.resident_tokens(),
+            resident_bytes=self.engine.kv_resident_bytes(),
+            host_compression=(self.engine.host_kv_compression()
+                              if self._tier_capable else 1.0))
         if self._prefix_capable:
-            tracer = get_tracer()
-            if tracer.enabled:
-                tracer.counter(
-                    "serve/prefix_cache", cat="mem",
-                    cached_blocks=int(stats.get("prefix_cached_blocks", 0)),
-                    pinned_blocks=int(stats.get("prefix_pinned_blocks", 0)),
-                    hit_tokens=int(stats.get("prefix_hit_tokens", 0)))
+            self._sample(
+                busy, "serve/prefix_cache",
+                cached_blocks=int(stats.get("prefix_cached_blocks", 0)),
+                pinned_blocks=int(stats.get("prefix_pinned_blocks", 0)),
+                hit_tokens=int(stats.get("prefix_hit_tokens", 0)))
 
     # ------------------------------------------------------------------
     # degradation ladder
@@ -1137,15 +1120,11 @@ class InferenceServer:
         (DS002-registered); the ladder emits its own edge instants."""
         usable = max(self.engine.kv_usable_blocks(), 1)
         effective = effective_usable_blocks(usable, stolen_frac)
-        reserved_fn = getattr(self.engine, "kv_reserved_blocks", None)
-        if reserved_fn is not None:
-            reserved = reserved_fn()
-        else:
-            reserved = int(self.engine.kv_occupancy() * usable)
         # a warm cache is reclaimable capacity, not pressure: without
         # this an idle server with an absorbed-history cache would sit
         # in brownout forever (evictable blocks free on demand)
-        reserved = max(reserved - self._cache_evictable_blocks(), 0)
+        reserved = max(self.engine.kv_reserved_blocks()
+                       - self._cache_evictable_blocks(), 0)
         host_bytes = (self.engine.host_kv_bytes()
                       if self._tier_capable else 0)
         pressure, reason = tier_pressure(
@@ -1235,21 +1214,16 @@ class InferenceServer:
     # ------------------------------------------------------------------
     # request-level fault isolation
     # ------------------------------------------------------------------
-    def _note_clean_step(self, collected=None) -> None:
+    def _note_clean_step(self, collected: Sequence[int]) -> None:
         """A successful engine step: reset the fault window; after N clean
         steps a fault episode is declared over (health auto-recovery — the
         anti-sticky-503 half of the isolation story). ``collected`` names
         the sequences of the steps whose tokens came back (a step is read a
-        tick after its dispatch, and only then has a request survived it);
-        None, from an engine double: every admitted request."""
+        tick after its dispatch, and only then has a request survived it)."""
         self._consecutive_faults = 0
         if self._admitted_since_clean:
-            if collected is None:
-                self._admitted_since_clean.clear()
-            else:
-                self._admitted_since_clean = [
-                    u for u in self._admitted_since_clean
-                    if u not in collected]
+            self._admitted_since_clean = [
+                u for u in self._admitted_since_clean if u not in collected]
         if self._fault_episode:
             self._clean_steps += 1
             self._maybe_recover()
@@ -1275,12 +1249,10 @@ class InferenceServer:
         # not the ones just planned. Where the dispatch itself raised, the
         # step before is still pending and is collected now, so that
         # whatever the handler evicts has no row in flight
-        fault = None
-        if self._pipelined:
-            fault = getattr(self.engine, "last_fault", None)
-            if fault is None:
-                self._collect_pending()
-                fault = getattr(self.engine, "last_fault", None)
+        fault = self.engine.last_fault
+        if fault is None:
+            self._collect_pending()
+            fault = self.engine.last_fault
         outcome = classify_exception(cause)
         self.metrics.on_step_fault()
         self._consecutive_faults += 1
@@ -1404,7 +1376,7 @@ class InferenceServer:
     # ------------------------------------------------------------------
     # KV drift reconciliation (projected model vs engine reality)
     # ------------------------------------------------------------------
-    def _reconcile_kv(self, projected_blocks: int) -> None:
+    def _reconcile_kv(self, projected_blocks: int, busy: bool = True) -> None:
         """Reconcile the projected KV watermark (admission control's model
         of memory) against what the engine actually reserved — so the
         model itself is observable: ``kv_projected_bytes`` vs
@@ -1418,10 +1390,7 @@ class InferenceServer:
         when the drift clears. The safe direction (projection worst-case >
         current reservation, expected mid-decode) recalibrates nothing.
         Pure host-int arithmetic — the serve tick stays sync-free."""
-        block_bytes = getattr(self.engine, "kv_block_bytes", None)
-        if block_bytes is None:
-            return
-        bb = block_bytes()
+        bb = self._block_bytes
         projected = projected_blocks * bb
         # evictable cache blocks are attributable to NO live request:
         # counting them as observed occupancy would fire a kv_drift edge
@@ -1431,10 +1400,9 @@ class InferenceServer:
         observed = (self.engine.kv_reserved_blocks()
                     - self._cache_evictable_blocks()) * bb
         self.metrics.set_kv_bytes(projected, observed)
+        self._sample(busy, "serve/kv_bytes",
+                     projected=projected, observed=observed)
         tracer = get_tracer()
-        if tracer.enabled:
-            tracer.counter("serve/kv_bytes", cat="mem",
-                           projected=projected, observed=observed)
         drifted = (max(projected, observed) > 0
                    and abs(projected - observed)
                    / max(projected, observed) > 0.10)
@@ -1536,14 +1504,12 @@ class InferenceServer:
                 self._inflight[req.uid] = req
                 self._admitted_since_clean.append(req.uid)
             try:
-                if self._pipelined:
-                    # what is left of the budget: a retry's sent tokens
-                    # have become prompt
-                    self.engine.admit(
-                        req.uid, req.engine_prompt(),
-                        max_new_tokens=req.max_new_tokens - len(req.tokens))
-                else:
-                    self.engine.admit(req.uid, req.engine_prompt())
+                # the engine is told what is left of the budget (a retry's
+                # sent tokens have become prompt), so that a step dispatched
+                # ahead computes no row past it
+                self.engine.admit(
+                    req.uid, req.engine_prompt(),
+                    max_new_tokens=req.max_new_tokens - len(req.tokens))
             except Exception as e:
                 # fail THIS request, not the batch (e.g. prompt longer than
                 # the engine's max context)
@@ -1578,21 +1544,19 @@ class InferenceServer:
     def _fan_out(self, step_out: Dict[int, int]):
         now = time.monotonic()
         n = 0
-        ledger = getattr(self.engine, "sched_ledger", None)
+        ledger = self.engine.sched_ledger
         for uid, tok in step_out.items():
             req = self._inflight.get(uid)
             if req is None or req.state.terminal:
                 continue
             req.state = RequestState.DECODE
             req.push_token(int(tok), now=now)
-            if ledger is not None:
-                # book this tick's decode work against the request — the
-                # wall-clock-free per-request denominator (TickLedger
-                # request attribution; settled into describe() at reap)
-                ledger.attribute_request(uid, decode_tokens=1)
+            # book this tick's decode work against the request — the
+            # wall-clock-free per-request denominator (TickLedger
+            # request attribution; settled into describe() at reap)
+            ledger.attribute_request(uid, decode_tokens=1)
             n += 1
-            seq = self.engine.state.get(uid)
-            if seq is not None and seq.done:
+            if self.engine.is_done(uid):
                 req.finalize(RequestState.FINISHED, "eos")
             elif len(req.tokens) >= req.max_new_tokens:
                 req.finalize(RequestState.FINISHED, "length")
@@ -1635,7 +1599,7 @@ class InferenceServer:
         reap AND the fault-eviction path (whose reap_finished() may flush
         OTHER done sequences too; dropping those uids would leak their
         requests in ``_inflight`` forever)."""
-        ledger = getattr(self.engine, "sched_ledger", None)
+        ledger = self.engine.sched_ledger
         for uid in reaped:
             with self._lock:
                 req = self._inflight.pop(uid, None)
@@ -1643,14 +1607,12 @@ class InferenceServer:
                     self._demoted.remove(uid)
                 if uid in self._admitted_since_clean:
                     self._admitted_since_clean.remove(uid)
+            # settle the request's tick attribution (also bounds the
+            # ledger table: finished uids never linger there)
+            attribution = ledger.pop_request(uid)
             if req is None:
-                if ledger is not None:
-                    ledger.pop_request(uid)
                 continue
-            if ledger is not None:
-                # settle the request's tick attribution (also bounds the
-                # ledger table: finished uids never linger there)
-                req.sched_attribution = ledger.pop_request(uid)
+            req.sched_attribution = attribution
             if not req.state.terminal:
                 # engine marked it done (eos) but no token crossed this step
                 req.finalize(RequestState.FINISHED, "eos")

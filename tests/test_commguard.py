@@ -38,6 +38,7 @@ from deepspeed_tpu.resilience import (ChaosConfig, ChaosMonkey,
                                       StragglerDetector,
                                       find_latest_committed)
 from deepspeed_tpu.telemetry import get_tracer
+from serving_fakes import ResidentEngine
 
 pytestmark = pytest.mark.chaos
 
@@ -680,36 +681,12 @@ def test_agent_exports_init_budget_env_from_config():
 # ---------------------------------------------------------------------------
 # serving: membership view flips health to degraded
 # ---------------------------------------------------------------------------
-class _IdleEngine:
+class _IdleEngine(ResidentEngine):
     """Minimal engine double that never has work — the membership poll on
     the serve tick is the thing under test."""
 
-    def __init__(self):
-        import types
-        self.state = types.SimpleNamespace(max_context_length=512,
-                                           get=lambda uid: None)
-        self.kv = types.SimpleNamespace(blocks_needed=lambda total: 1)
-
-    def kv_usable_blocks(self):
-        return 64
-
-    def kv_occupancy(self):
-        return 0.0
-
-    def can_schedule(self, uids, needs):
-        return True
-
-    def admit(self, uid, tokens):
-        pass
-
     def has_work(self):
         return False
-
-    def step(self):
-        pass
-
-    def reap_finished(self):
-        return []
 
 
 def test_serving_degrades_on_lost_peer(tmp_path):

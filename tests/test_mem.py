@@ -29,6 +29,7 @@ from deepspeed_tpu.telemetry.memory import (MEM_BASELINE_NAME, MemoryLedger,
                                             next_offload_tier, preflight,
                                             tie_out, write_mem_baseline)
 from deepspeed_tpu.telemetry.tracer import Tracer, configure_tracing, get_tracer
+from serving_fakes import ResidentEngine
 
 pytestmark = pytest.mark.mem
 
@@ -554,36 +555,28 @@ def test_autotuner_oom_experiment_capture():
     assert exp.memory["ledger"]["inputs"]["zero_stage"] == 2
 
 
+class FakeEngine(ResidentEngine):
+    """One block of 1 KiB held of seven, whatever is admitted; a request
+    projects two."""
+
+    def kv_usable_blocks(self):
+        return 7
+
+    def kv_reserved_blocks(self):
+        return 1
+
+    def kv_block_bytes(self):
+        return 1024
+
+    def kv_blocks_needed(self, num_tokens):
+        return 2
+
+
 def test_serving_kv_reconciliation():
     """Projected (admission model) vs observed (engine-reserved) KV bytes:
     gauges on /metrics, an edge-triggered drift instant, counter track."""
     from deepspeed_tpu.serving.request import Request
     from deepspeed_tpu.serving.server import InferenceServer, ServingConfig
-
-    class FakeKV:
-        class cfg:
-            num_blocks = 8
-        data = type("A", (), {"nbytes": 8 * 1024})()
-        scales = None
-
-        @staticmethod
-        def blocks_needed(total):
-            return 2
-
-    class FakeEngine:
-        kv = FakeKV()
-
-        def kv_usable_blocks(self):
-            return 7
-
-        def kv_reserved_blocks(self):
-            return 1
-
-        def kv_block_bytes(self):
-            return 1024
-
-        def kv_occupancy(self):
-            return 1 / 7
 
     configure_tracing(enabled=True)
     # the counts below are of this test's events: a ring that an earlier

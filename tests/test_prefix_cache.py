@@ -314,12 +314,11 @@ def test_serving_config_prefix_keys():
     assert cfg.prefix_cache_enabled and cfg.host_kv_quantize == "int8"
     assert cfg.prefix_cache_max_blocks == 8
 
-    class _Eng:
-        pass
-
     from deepspeed_tpu.serving import InferenceServer
+    from serving_fakes import ResidentEngine
     with pytest.raises(ValueError, match="host_kv_quantize"):
-        InferenceServer(_Eng(), ServingConfig(host_kv_quantize="int4"))
+        InferenceServer(ResidentEngine(),
+                        ServingConfig(host_kv_quantize="int4"))
 
 
 def test_prometheus_prefix_rows_one_type_block_each():

@@ -8,7 +8,6 @@ double here: what is tested is the loop, not a model."""
 import math
 import threading
 import time
-import types
 
 import pytest
 
@@ -16,6 +15,7 @@ from deepspeed_tpu.serving.request import RequestState
 from deepspeed_tpu.serving.server import (IDLE_PIECE_S, InferenceServer,
                                           ServingConfig)
 from deepspeed_tpu.telemetry.tracer import HOST_GC_TID, get_tracer
+from serving_fakes import ResidentEngine
 
 IDLE, TICK = "serve/idle", "serve/tick"
 #: what two stamps of one instant may differ by (float sums of a monotonic
@@ -23,48 +23,24 @@ IDLE, TICK = "serve/idle", "serve/tick"
 EXACT, TILE = 1e-6, 20e-3
 
 
-class _Engine:
-    """An engine double that hands every resident sequence one token a step.
-    No ``collect``: the server runs it one step at a time."""
+class _Engine(ResidentEngine):
+    """An engine double that hands every resident sequence one token a step,
+    from the step's own call: its ``collect`` has nothing pending."""
 
     def __init__(self, schedulable=True, explode_at=None):
-        self.state = types.SimpleNamespace(max_context_length=512,
-                                           get=lambda uid: None)
-        self.kv = types.SimpleNamespace(blocks_needed=lambda total: 1)
+        super().__init__()
         self.schedulable = schedulable
         self.explode_at = explode_at
         self.steps = 0
-        self._resident, self._finished = [], []
-
-    def kv_usable_blocks(self):
-        return 64
-
-    def kv_occupancy(self):
-        return 0.0
 
     def can_schedule(self, uids, needs):
         return self.schedulable
-
-    def admit(self, uid, tokens):
-        self._resident.append(uid)
-
-    def has_work(self):
-        return bool(self._resident)
 
     def step(self):
         self.steps += 1
         if self.steps == self.explode_at:
             raise RuntimeError("transient: one step lost")
-        return {uid: 7 for uid in self._resident}
-
-    def finish(self, uid):
-        if uid in self._resident:
-            self._resident.remove(uid)
-            self._finished.append(uid)
-
-    def reap_finished(self):
-        out, self._finished = self._finished, []
-        return out
+        return super().step()
 
 
 @pytest.fixture
@@ -165,9 +141,23 @@ def test_idle_and_tick_spans_tile_the_loops_life(ring):
         pytest.approx(idle_s, abs=1e-4)
 
 
-def test_an_idle_server_writes_at_most_four_events_a_second(ring):
-    server = InferenceServer(_Engine(),
-                             ServingConfig(idle_poll_s=0.002)).start()
+def _tiny_engine_with_every_tier():
+    from deepspeed_tpu.serving.bench_serve import build_tiny_server
+    return build_tiny_server().engine      # a pool, the host tier, the cache
+
+
+@pytest.mark.parametrize("engine,config", [
+    (_Engine, {}),
+    # the memory counter tracks are sampled by ticks that did something,
+    # whatever engine is served: over the double the parent's server left
+    # ``serve/kv_bytes`` out, over an engine it wrote it fifty times a second
+    (_tiny_engine_with_every_tier, {"kv_offload_enabled": True,
+                                    "prefix_cache_enabled": True}),
+], ids=["double", "engine-with-tier-and-cache"])
+def test_an_idle_server_writes_at_most_four_events_a_second(ring, engine,
+                                                            config):
+    server = InferenceServer(engine(), ServingConfig(idle_poll_s=0.002,
+                                                     **config)).start()
     try:
         t0 = time.monotonic()
         time.sleep(1.3)

@@ -11,7 +11,6 @@ acceptance drills run the real serve loop on the tiny fp32 llama.
 """
 
 import time
-import types
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +32,7 @@ from deepspeed_tpu.serving.kv_tier import (effective_usable_blocks,
                                            tier_pressure)
 from deepspeed_tpu.serving.server import _EngineStepError
 from deepspeed_tpu.telemetry.tracer import get_tracer
+from serving_fakes import ResidentEngine
 
 pytestmark = pytest.mark.serve_load
 
@@ -267,56 +267,28 @@ def test_kv_offload_demote_promote_parity(model_and_params):
 # ---------------------------------------------------------------------------
 # fake engines for exact-tick fault isolation / drift tests
 # ---------------------------------------------------------------------------
-class _FakeSeq:
-    def __init__(self):
-        self.done = False
-
-
-class _FakeEngine:
+class _FakeEngine(ResidentEngine):
     """Functional minimal engine: one token per resident sequence per
-    step; scriptable step failures by 1-based step-call index."""
+    step; scriptable step failures by 1-based step-call index. A sequence
+    holds a block until it is reaped."""
 
     def __init__(self, fail_calls=(), fail_exc=None):
-        self._seqs = {}
+        super().__init__()
         self.step_calls = 0
         self.fail_calls = set(fail_calls)
         self.fail_exc = fail_exc or RuntimeError("connection reset by peer")
-        self.state = types.SimpleNamespace(
-            max_context_length=512,
-            get=lambda uid: self._seqs.get(uid))
-        self.kv = types.SimpleNamespace(
-            blocks_needed=lambda total: (total + 15) // 16, free_blocks=63)
 
-    def kv_usable_blocks(self):
-        return 64
+    def kv_reserved_blocks(self):
+        return len(self._resident) + len(self._finished)
 
-    def kv_occupancy(self):
-        return len(self._seqs) / 64.0
-
-    def can_schedule(self, uids, needs):
-        return True
-
-    def admit(self, uid, tokens):
-        self._seqs[uid] = _FakeSeq()
-
-    def has_work(self):
-        return any(not s.done for s in self._seqs.values())
+    def kv_blocks_needed(self, num_tokens):
+        return (num_tokens + 15) // 16
 
     def step(self):
         self.step_calls += 1
         if self.step_calls in self.fail_calls:
             raise self.fail_exc
-        return {uid: 7 for uid, s in self._seqs.items() if not s.done}
-
-    def finish(self, uid):
-        if uid in self._seqs:
-            self._seqs[uid].done = True
-
-    def reap_finished(self):
-        done = [u for u, s in self._seqs.items() if s.done]
-        for u in done:
-            self._seqs.pop(u)
-        return {u: [] for u in done}
+        return super().step()
 
 
 def test_transient_step_fault_recovers_without_restart():

@@ -22,6 +22,7 @@ from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM, TINY_LLAMA
 from deepspeed_tpu.serving import (BackpressureError, InferenceServer,
                                    RequestState, ServerClosedError,
                                    ServingConfig, ServingFrontend)
+from serving_fakes import ResidentEngine
 
 
 def _tiny_fp32():
@@ -312,40 +313,12 @@ def test_monitor_export(model_and_params, tmp_path):
 # ---------------------------------------------------------------------------
 # engine failure -> degraded health (load balancers must stop routing)
 # ---------------------------------------------------------------------------
-class _ExplodingEngine:
+class _ExplodingEngine(ResidentEngine):
     """Minimal engine double whose step() always raises — the serve loop
     must fail the in-flight requests AND flip health to unhealthy."""
 
-    def __init__(self):
-        import types
-        self.state = types.SimpleNamespace(max_context_length=512,
-                                           get=lambda uid: None)
-        self.kv = types.SimpleNamespace(blocks_needed=lambda total: 1)
-        self._resident = set()
-
-    def kv_usable_blocks(self):
-        return 64
-
-    def kv_occupancy(self):
-        return 0.0
-
-    def can_schedule(self, uids, needs):
-        return True
-
-    def admit(self, uid, tokens):
-        self._resident.add(uid)
-
-    def has_work(self):
-        return bool(self._resident)
-
     def step(self):
         raise RuntimeError("kaboom: device went away")
-
-    def finish(self, uid):
-        self._resident.discard(uid)
-
-    def reap_finished(self):
-        return []
 
 
 def test_health_degraded_after_engine_step_failure():

@@ -34,6 +34,7 @@ import deepspeed_tpu
 from deepspeed_tpu.models.simple import SimpleModel, random_batch
 from deepspeed_tpu.telemetry import get_tracer, request_tid
 from deepspeed_tpu.telemetry.tracer import HOST_GC_TID, Tracer
+from serving_fakes import ResidentEngine
 
 pytestmark = pytest.mark.telemetry
 
@@ -361,41 +362,8 @@ def test_dump_trace_and_summary_from_engine(tmp_path, tracing):
 # ---------------------------------------------------------------------------
 # serving: TTFT derivable from the trace alone
 # ---------------------------------------------------------------------------
-class _OneTokenPerStepEngine:
+class _OneTokenPerStepEngine(ResidentEngine):
     """Engine double: every resident sequence yields one token per step."""
-
-    def __init__(self):
-        self.state = types.SimpleNamespace(max_context_length=512,
-                                           get=lambda uid: None)
-        self.kv = types.SimpleNamespace(blocks_needed=lambda total: 1)
-        self._resident = set()
-        self._finished = []
-
-    def kv_usable_blocks(self):
-        return 64
-
-    def kv_occupancy(self):
-        return 0.0
-
-    def can_schedule(self, uids, needs):
-        return True
-
-    def admit(self, uid, tokens):
-        self._resident.add(uid)
-
-    def has_work(self):
-        return bool(self._resident)
-
-    def step(self):
-        return {uid: 7 for uid in sorted(self._resident)}
-
-    def finish(self, uid):
-        self._resident.discard(uid)
-        self._finished.append(uid)
-
-    def reap_finished(self):
-        gone, self._finished = self._finished, []
-        return gone
 
 
 def test_serving_request_spans_reproduce_ttft(tracing):
