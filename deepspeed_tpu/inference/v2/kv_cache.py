@@ -47,6 +47,10 @@ from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_attention_pool, paged_attention_reference)
 
 FULL, WINDOW, STATE = "full", "window", "state"
+#: the kind of a layer that keeps nothing of a sequence (a layer that is an
+#: MLP or routed experts alone): no pool, no slot, and its block calls no
+#: ``attend``
+NONE = "none"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,19 +75,22 @@ class StateSlotShape:
     (a sum over every token the sequence has seen, so not the compute type),
     and the last ``conv_width - 1`` rows of the ``conv_channels`` that the
     layer's causal convolution looks back on, in the compute type.
-    ``scan_block`` is the block a prefill chunk's closed form sums over."""
+    ``scan_block`` is the block a prefill chunk's closed form sums over;
+    ``groups`` how many ``B`` and ``C`` a token brings (``conv_channels`` =
+    ``heads * head_dim + 2 * groups * d_state``; a head reads its group's)."""
     heads: int
     head_dim: int
     d_state: int
     conv_width: int
     conv_channels: int
     scan_block: int = 256
+    groups: int = 1
 
     @property
     def pack(self) -> int:
         """Heads side by side in the stored state's lanes
         (``ops/pallas/ssm_update.py``)."""
-        return _ssm_update.state_pack(self.heads, self.head_dim)
+        return _ssm_update.state_pack(self.heads, self.head_dim, self.groups)
 
     @property
     def stored(self) -> Tuple[int, int, int]:
@@ -132,9 +139,10 @@ class KVCacheConfig:
     # query heads a layer, or ``{"full": n, "window": m}``; None: as many as
     # KV heads (``modules.KVCacheSpec.query_heads``)
     query_heads: Any = None
-    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``) where
-    # ``layer_windows`` cannot say it: a model some of whose layers keep a
-    # recurrent state in a slot and no pages. ``num_layers`` then counts the
+    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``,
+    # ``"none"``) where ``layer_windows`` cannot say it: a model some of whose
+    # layers keep a recurrent state in a slot and no pages, or nothing at all
+    # (a layer without a mixer that looks back). ``num_layers`` then counts the
     # layers of all kinds; ``state_slot`` is what a slot holds of a layer and
     # ``state_slots`` how many sequences have one (one more is padding's)
     layer_kinds: Tuple[str, ...] = ()
@@ -1111,7 +1119,8 @@ class _StateSlots:
 
     def _split(self, conv):
         at = self.shape
-        return ssm.split_conv(conv, at.heads, at.head_dim, at.d_state)
+        return ssm.split_conv(conv, at.heads, at.head_dim, at.d_state,
+                              at.groups)
 
     @partial(jax.jit, static_argnames=("self", "attn_impl"))
     def attend_chunk(self, cache, layer, slots, attn_impl, xbc, step, kernel,
@@ -1193,7 +1202,8 @@ class _LayerKindPages:
     programs carry ``{"full": table, "window": table}`` likewise);
     ``{"full": ..., "state": {"ssm", "conv"}}`` for one some of whose layers
     keep a recurrent state (``_StateSlots``: a slot and no block table; the
-    step programs' ``"state"`` entry IS the slot). The kinds are data: a
+    step programs' ``"state"`` entry IS the slot); a layer of kind ``"none"``
+    keeps nothing and is in no pool. The kinds are data: a
     layer's is ``layer_kinds[l]`` where the spec names them and follows from
     ``layer_windows[l]`` where it does not, and a kind that no layer has
     has no pool. ``kind_pages`` states each paged kind's own KV
@@ -1214,14 +1224,14 @@ class _LayerKindPages:
         self.window = one_window(layer_windows)
         self.kinds = tuple(layer_kinds) or tuple(
             WINDOW if w else FULL for w in layer_windows)
-        if set(self.kinds) - {FULL, WINDOW, STATE} \
+        if set(self.kinds) - {FULL, WINDOW, STATE, NONE} \
                 or (STATE in self.kinds) != (state_slot is not None) \
                 or (WINDOW in self.kinds) != (self.window is not None):
             raise ValueError(f"layer kinds {sorted(set(self.kinds))} with "
                              f"window {self.window} and state slot "
                              f"{state_slot}: a kind is full, window (behind "
-                             f"layer_windows' one window) or state (which "
-                             f"states what its slot holds)")
+                             f"layer_windows' one window), state (which "
+                             f"states what its slot holds) or none")
         # a layer's index in its kind's pool
         self.local = tuple(self.kinds[:i].count(k)
                            for i, k in enumerate(self.kinds))

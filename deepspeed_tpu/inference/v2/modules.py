@@ -42,7 +42,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.llama_decode import _mlp, _qkv, _rms
 from deepspeed_tpu.models.llama import LlamaConfig, rope_freqs
-from deepspeed_tpu.moe.grouped_experts import (grouped_expert_ffn,
+from deepspeed_tpu.moe.grouped_experts import (grouped_expert_ffn, relu2,
                                                softmax_route)
 
 DECODE_POLICIES: Dict[str, type] = {}
@@ -81,11 +81,11 @@ class KVCacheSpec:
     # rows, so the cache's count of a call's tiles and copies needs them.
     # None: as many as KV heads
     query_heads: Any = None
-    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``) for
-    # a model some of whose layers keep a recurrent state in a slot and no
-    # pages, and what such a slot holds of a layer
-    # (``kv_cache.StateSlotShape``). None: the kinds follow from
-    # ``layer_windows``
+    # every layer's kind by name (``"full"``, ``"window"``, ``"state"``,
+    # ``"none"``) for a model some of whose layers keep a recurrent state in
+    # a slot and no pages, or nothing at all (a layer that is experts alone),
+    # and what such a slot holds of a layer (``kv_cache.StateSlotShape``).
+    # None: the kinds follow from ``layer_windows``
     layer_kinds: Any = None
     state_slot: Any = None
 
@@ -329,12 +329,20 @@ def _routed_sum(experts, h2, weights, ids, valid, impl, first=0):
     else:
         from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
         interpret = impl == "kernel_interpret"
+        # a gated expert's first product is two stacks [E, D, F] in one pass,
+        # an ungated one's (``w_in``) one stack [E, F, D]
+        if "w_in" in experts:
+            first_product, stacks = gmm.grouped_relu2_in, 1
+            e, f, d = experts["w_in"].shape
+        else:
+            first_product, stacks = gmm.grouped_gate_up, 2
+            e, d, f = experts["w_gate"].shape
         y, rows = grouped_expert_ffn(
             h2, experts, weights, ids, valid,
             matmul=functools.partial(gmm.grouped_matmul, interpret=interpret),
-            gate_up=functools.partial(gmm.grouped_gate_up,
-                                      interpret=interpret), first=first)
-        tm = gmm.tiling(ids.size, *experts["w_gate"].shape, h2.dtype, 2)[0]
+            gate_up=functools.partial(first_product, interpret=interpret),
+            first=first)
+        tm = gmm.tiling(ids.size, e, d, f, h2.dtype, stacks)[0]
         tile_rows = gmm.visited_tile_rows(rows, ids.size, tm)
     # the layer's counts, in the order of ``STEP_COUNTER_ARGS``; the
     # assignments of rows that are no padding to experts held elsewhere are
@@ -990,6 +998,34 @@ from deepspeed_tpu.inference.v2.kv_cache import StateSlotShape  # noqa: E402
 from deepspeed_tpu.models import granite_hybrid as _granite  # noqa: E402
 
 
+def _mamba_mixer(mp, norm_scale, x, attend, cfg):
+    """What a Mamba-2 layer adds to ``x`` [N, D], its own pre-norm inside:
+    what needs no state is computed here (the first projection, the gated
+    norm over ``cfg.norm_groups`` groups, the second projection) and the rest
+    handed to ``attend`` as plain arrays (``kv_cache._StateSlots``)."""
+    dtype, eps = cfg.dtype, cfg.rms_norm_eps
+    with jax.named_scope("ssm/in_proj"):
+        h = _rms(x, norm_scale, eps)
+        z, xbc, dt = _granite.split_in_proj(
+            h @ mp["in_proj"].astype(dtype), cfg)
+        step = jax.nn.softplus(dt.astype(jnp.float32)
+                               + mp["dt_bias"].astype(jnp.float32))
+    y = attend(xbc, step, mp["conv_kernel"], mp["conv_bias"],
+               mp["a_log"], mp["d"])
+    with jax.named_scope("ssm/norm"):
+        y = _granite.gated_norm(y, z, mp["norm"], eps, cfg.norm_groups)
+    with jax.named_scope("ssm/out_proj"):
+        return y @ mp["out_proj"].astype(dtype)
+
+
+def _state_slot(cfg) -> StateSlotShape:
+    """What a Mamba-2 layer of ``cfg`` keeps of a sequence."""
+    return StateSlotShape(
+        cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state,
+        cfg.mamba_d_conv, cfg.conv_channels, cfg.mamba_chunk_size,
+        cfg.mamba_groups)
+
+
 @register_policy("granite_hybrid", _granite.GraniteHybridConfig)
 class GraniteHybridPolicy:
     """models/granite_hybrid.py's serving twin. ``cache_spec`` names every
@@ -1017,9 +1053,7 @@ class GraniteHybridPolicy:
             cfg.max_seq_len, cfg.dtype, None, query_heads=cfg.num_heads,
             layer_kinds=tuple("state" if cfg.is_mamba(i) else "full"
                               for i in range(cfg.num_layers)),
-            state_slot=StateSlotShape(
-                cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state,
-                cfg.mamba_d_conv, cfg.conv_channels, cfg.mamba_chunk_size))
+            state_slot=_state_slot(cfg))
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -1032,19 +1066,8 @@ class GraniteHybridPolicy:
         dtype, eps = cfg.dtype, cfg.rms_norm_eps
         r = jnp.asarray(cfg.residual_multiplier, dtype)
         if cfg.is_mamba(i):
-            mp = lp["mamba"]
-            with jax.named_scope("ssm/in_proj"):
-                h = _rms(x, lp["mixer_norm"]["scale"], eps)
-                z, xbc, dt = _granite.split_in_proj(
-                    h @ mp["in_proj"].astype(dtype), cfg)
-                step = jax.nn.softplus(dt.astype(jnp.float32)
-                                       + mp["dt_bias"].astype(jnp.float32))
-            y = attend(xbc, step, mp["conv_kernel"], mp["conv_bias"],
-                       mp["a_log"], mp["d"])
-            with jax.named_scope("ssm/norm"):
-                y = _granite.gated_norm(y, z, mp["norm"], eps)
-            with jax.named_scope("ssm/out_proj"):
-                x = x + r * (y @ mp["out_proj"].astype(dtype))
+            x = x + r * _mamba_mixer(lp["mamba"], lp["mixer_norm"]["scale"],
+                                     x, attend, cfg)
         else:
             with jax.named_scope("attn/qkv"):
                 h = _rms(x, lp["mixer_norm"]["scale"], eps)
@@ -1078,3 +1101,72 @@ class GraniteHybridPolicy:
                           params["embed"]["embedding"].astype(x.dtype),
                           preferred_element_type=jnp.float32) \
             / cfg.logits_scaling
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H (every layer ONE mixer: a Mamba-2 layer of several groups of B
+# and C, routed ungated relu2 experts beside a shared one, or attention
+# without positions)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.models import nemotron_h as _nemotron  # noqa: E402
+
+
+@register_policy("nemotron_h", _nemotron.NemotronHConfig)
+class NemotronHPolicy:
+    """models/nemotron_h.py's serving twin: a layer is ``x + Mixer(RMSNorm(
+    x))`` with the mixer its letter names, and nothing else (no MLP behind a
+    mixer, so no ``mlp`` scope). ``cache_spec`` names every layer's kind:
+    ``state`` (a Mamba-2 layer's slot, ``mamba_groups`` groups of B and C:
+    ``_mamba_mixer``, granite's), ``full`` (an attention layer's pages, two
+    KV heads of 128) or ``none`` (an expert layer keeps nothing of a
+    sequence and calls no ``attend``). The experts stacked here are the
+    router's ``first_expert ..`` (one chip's share), two matrices an expert:
+    ``_chosen_experts`` takes a stack of ``w_in`` and ``w_down`` as ungated."""
+
+    @staticmethod
+    def cache_spec(cfg) -> KVCacheSpec:
+        kinds = {_nemotron.MAMBA: "state", _nemotron.ATTENTION: "full",
+                 _nemotron.EXPERTS: "none"}
+        return KVCacheSpec(
+            cfg.num_layers, cfg.num_kv_heads, cfg.head_dim, cfg.max_seq_len,
+            cfg.dtype, None, query_heads=cfg.num_heads,
+            layer_kinds=tuple(kinds[letter] for letter in cfg.pattern),
+            state_slot=_state_slot(cfg))
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        dtype, kind = cfg.dtype, cfg.pattern[i]
+        scale = lp["norm"]["scale"]
+        if kind == _nemotron.MAMBA:
+            return x + _mamba_mixer(lp["mamba"], scale, x, attend, cfg), None
+        if kind == _nemotron.ATTENTION:
+            with jax.named_scope("attn/qkv"):
+                q, k, v = _qkv(lp, _rms(x, scale, cfg.rms_norm_eps), dtype)
+            attn = attend(q, k, v)
+            with jax.named_scope("attn/out"):
+                return x + jnp.einsum(
+                    "thk,hkd->td", attn,
+                    lp["attn"]["wo"]["kernel"].astype(dtype)), None
+        moe = lp["moe"]
+        with jax.named_scope("moe/router"):
+            u = _rms(x, scale, cfg.rms_norm_eps)
+            weights, ids = route(u, moe, cfg)
+        y, counts = _chosen_experts(moe["experts"], u, weights, ids, valid,
+                                    first=cfg.first_expert)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe/shared"):
+                shared = moe["shared"]
+                up = u @ shared["w_up"]["kernel"].astype(dtype)
+                y = y + relu2(up) @ shared["w_down"]["kernel"].astype(dtype)
+        return x + y, counts
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return x.astype(jnp.float32) @ \
+            params["lm_head"]["kernel"].astype(jnp.float32)

@@ -85,10 +85,21 @@ class GraniteHybridConfig:
     def d_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
+    # one group of B and C, one group in the gated norm: what the mixer
+    # below reads from whichever config it is given (``NemotronHConfig``
+    # states eight of each)
+    @property
+    def mamba_groups(self) -> int:
+        return 1
+
+    @property
+    def norm_groups(self) -> int:
+        return 1
+
     @property
     def conv_channels(self) -> int:
         """``[x ; B ; C]``: what the convolution runs over."""
-        return self.d_inner + 2 * self.mamba_d_state
+        return self.d_inner + 2 * self.mamba_groups * self.mamba_d_state
 
     @property
     def in_proj_width(self) -> int:
@@ -120,12 +131,15 @@ def split_in_proj(zxbcdt, cfg: GraniteHybridConfig):
     return zxbcdt[..., :a], zxbcdt[..., a:b], zxbcdt[..., b:]
 
 
-def gated_norm(y, z, scale, eps):
-    """``RMSNorm(y * silu(z))`` over the whole width: the gate before the
-    norm, one group."""
+def gated_norm(y, z, scale, eps, groups: int = 1):
+    """``RMSNorm(y * silu(z))``: the gate before the norm, each of ``groups``
+    equal parts of the width normalised alone (one: the whole width), one
+    learned scale over all of it."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
-    return (g * scale).astype(y.dtype)
+    parts = g.reshape(*g.shape[:-1], groups, -1)
+    parts = parts * jax.lax.rsqrt(
+        jnp.mean(jnp.square(parts), -1, keepdims=True) + eps)
+    return (parts.reshape(g.shape) * scale).astype(y.dtype)
 
 
 def mamba_sequence(mp, u, cfg: GraniteHybridConfig):
@@ -138,13 +152,13 @@ def mamba_sequence(mp, u, cfg: GraniteHybridConfig):
     conv, _ = ssm.causal_conv(xbc, tail, mp["conv_kernel"], mp["conv_bias"])
     conv = conv.astype(dtype)
     x, bm, cm = ssm.split_conv(conv, cfg.mamba_heads, cfg.mamba_head_dim,
-                               cfg.mamba_d_state)
+                               cfg.mamba_d_state, cfg.mamba_groups)
     step = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
     s0 = jnp.zeros((cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state))
     y, _ = ssm.ssm_chunk_scan(x, step, mp["a_log"], bm, cm, s0,
                               cfg.mamba_chunk_size)
     y = (y + mp["d"][:, None] * x).reshape(-1, cfg.d_inner).astype(dtype)
-    y = gated_norm(y, z, mp["norm"], cfg.rms_norm_eps)
+    y = gated_norm(y, z, mp["norm"], cfg.rms_norm_eps, cfg.norm_groups)
     return y @ mp["out_proj"].astype(dtype)
 
 

@@ -149,6 +149,9 @@ _REGISTRY = {
                                       "granite_hybrid_config_from_hf",
                                       "GraniteHybridForCausalLM",
                                       "convert_hf_granite_hybrid"),
+    "nemotron_h": _family_entry("nemotron_h", "nemotron_h_config_from_hf",
+                                "NemotronHForCausalLM",
+                                "convert_hf_nemotron_h"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

@@ -46,17 +46,32 @@ def softmax_route(h, gate_kernel, top_k: int, norm_topk_prob: bool):
     return w, ids
 
 
+def relu2(x):
+    """``relu(x) ** 2``: an ungated expert's activation (``mlp_hidden_act:
+    relu2``)."""
+    return jnp.square(jnp.maximum(x, 0))
+
+
 def grouped_expert_ffn(h, experts, weights, ids, valid=None,
                        matmul=jax.lax.ragged_dot, gate_up=None,
                        first: int = 0):
     """``sum_k weights[t, k] * E_ids[t, k](h[t])`` with each ``E`` a gated
     MLP of the stacked weights ``experts`` (``w_gate``, ``w_up`` [E, D, F];
-    ``w_down`` [E, F, D]). ``h``: [T, D] in the compute type; ``weights``,
-    ``ids``: [T, K]; ``valid``: [T] bool, rows to leave out (bucket padding).
-    ``matmul(rows, stacked weights, counts)`` is the grouped matmul, with
-    ``jax.lax.ragged_dot``'s meaning; ``gate_up(rows, w_gate, w_up, counts)``,
-    where given, computes ``silu(rows @ w_gate) * (rows @ w_up)`` a group in
-    one pass. Returns (y [T, D], rows on each expert [E] int32).
+    ``w_down`` [E, F, D]), or an UNGATED one of two matrices, ``W_down
+    relu(W_in h) ** 2``, where ``experts`` is ``w_in`` and ``w_down``, BOTH
+    [E, F, D]: a hidden unit's row in and its row out. (``F`` need be no
+    multiple of the TPU's 128 lanes, Nemotron-H's 1,856 is not, and a device
+    array whose last axis is not gets a layout with another axis minor, out
+    of which a kernel's operand is copied whole, 640 MB a layer a step; ``D``
+    last, the stack lies as the kernel reads it.) ``h``: [T, D] in the
+    compute type; ``weights``, ``ids``: [T, K]; ``valid``: [T] bool, rows to
+    leave out (bucket padding). ``matmul(rows, stacked weights, counts)`` is
+    the grouped matmul, with ``jax.lax.ragged_dot``'s meaning; ``gate_up``,
+    where given, computes the first product and its activation a group in one
+    pass: ``gate_up(rows, w_gate, w_up, counts)`` = ``silu(rows @ w_gate) *
+    (rows @ w_up)``, or of an ungated expert ``gate_up(rows, w_in, counts)``
+    = ``relu2(rows @ w_in.T)``. Returns (y [T, D], rows on each expert [E]
+    int32).
 
     ``experts`` may be a share of the router's: the ``E`` stacked here are the
     router's experts ``first .. first + E - 1`` (expert parallelism's share
@@ -70,7 +85,7 @@ def grouped_expert_ffn(h, experts, weights, ids, valid=None,
     sizes cover, and the rows past them (uninitialised in its output) are
     zeroed before they are gathered back."""
     t, k = ids.shape
-    e = experts["w_gate"].shape[0]
+    e = experts["w_down"].shape[0]
     held = ids - first
     keep = (held >= 0) & (held < e)
     if valid is not None:
@@ -80,13 +95,16 @@ def grouped_expert_ffn(h, experts, weights, ids, valid=None,
     counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
     dtype = h.dtype
     xs = h[order // k]                                           # [T*K, D]
-    w_gate = experts["w_gate"].astype(dtype)
-    w_up = experts["w_up"].astype(dtype)
-    if gate_up is None:
-        act = jax.nn.silu(matmul(xs, w_gate, counts)) * \
-            matmul(xs, w_up, counts)
+    first_product = tuple(experts[name].astype(dtype)
+                          for name in ("w_gate", "w_up", "w_in")
+                          if name in experts)
+    if gate_up is not None:
+        act = gate_up(xs, *first_product, counts)
+    elif "w_in" in experts:
+        act = relu2(matmul(xs, jnp.swapaxes(first_product[0], 1, 2), counts))
     else:
-        act = gate_up(xs, w_gate, w_up, counts)
+        act = jax.nn.silu(matmul(xs, first_product[0], counts)) * \
+            matmul(xs, first_product[1], counts)
     out = matmul(act, experts["w_down"].astype(dtype), counts)
     computed = jnp.arange(t * k) < jnp.sum(counts)
     out = jnp.where(computed[:, None], out, 0)

@@ -137,7 +137,7 @@ def visited_tile_rows(counts, m: int, tm: int):
 
 
 def _kernel(offsets_ref, group_ref, tile_ref, live_ref, x_ref, *refs,
-            tm: int, blocks_k: int, weights: int, epilogue):
+            tm: int, blocks_k: int, weights: int, epilogue, rows_out: bool):
     w_refs, o_ref, acc_refs = refs[:weights], refs[weights], refs[weights + 1:]
     v, ki = pl.program_id(1), pl.program_id(2)
     sub = _SUB_ROWS if tm % _SUB_ROWS == 0 else tm
@@ -160,8 +160,12 @@ def _kernel(offsets_ref, group_ref, tile_ref, live_ref, x_ref, *refs,
         @pl.when((v < live_ref[0]) & (first < hi) & (first + sub > lo))
         def _some_row_is_the_groups():
             x = x_ref[rows, :]
-            parts = [jnp.dot(x, w_ref[...],
-                             preferred_element_type=jnp.float32)
+            # a weight block is [tk, tn], or with ``rows_out`` [tn, tk]: the
+            # contraction over both operands' minor axis, which the MXU does
+            # as it does the other
+            contract = (((1,), (1 if rows_out else 0,)), ((), ()))
+            parts = [jax.lax.dot_general(x, w_ref[...], contract,
+                                         preferred_element_type=jnp.float32)
                      for w_ref in w_refs]
             if blocks_k == 1:
                 _store(*parts)
@@ -180,11 +184,13 @@ def _kernel(offsets_ref, group_ref, tile_ref, live_ref, x_ref, *refs,
         jax.lax.fori_loop(0, tm // sub, _run, None)
 
 
-def _call(xs, ws, counts, epilogue, name, tiles, interpret):
+def _call(xs, ws, counts, epilogue, name, tiles, interpret,
+          rows_out: bool = False):
     """``epilogue`` of the float32 products of the sorted rows ``xs`` with
-    each stack of ``ws``, a group at a time, in one pass over the rows."""
+    each stack of ``ws``, a group at a time, in one pass over the rows.
+    ``rows_out``: the stacks are ``[E, N, K]``, a row an output column."""
     m, k = xs.shape
-    e, _, n = ws[0].shape
+    e, n = ws[0].shape[0], ws[0].shape[1 if rows_out else 2]
     tm, tk, tn = tiles or tiling(m, e, k, n, xs.dtype, len(ws))
     if k % tk:
         raise ValueError(f"a contraction block of {tk} does not divide {k}")
@@ -195,13 +201,19 @@ def _call(xs, ws, counts, epilogue, name, tiles, interpret):
         # a visit without rows keeps the blocks of the step before it
         return jnp.where(v < live_ref[0], ki, blocks_k - 1)
 
-    w_spec = pl.BlockSpec((None, tk, tn),
-                          lambda ni, v, ki, off, grp, til, live:
-                          (grp[v], k_block(v, ki, live), ni))
+    if rows_out:
+        w_spec = pl.BlockSpec((None, tn, tk),
+                              lambda ni, v, ki, off, grp, til, live:
+                              (grp[v], ni, k_block(v, ki, live)))
+    else:
+        w_spec = pl.BlockSpec((None, tk, tn),
+                              lambda ni, v, ki, off, grp, til, live:
+                              (grp[v], k_block(v, ki, live), ni))
     size = xs.dtype.itemsize
     return pl.pallas_call(
         functools.partial(_kernel, tm=tm, blocks_k=blocks_k,
-                          weights=len(ws), epilogue=epilogue),
+                          weights=len(ws), epilogue=epilogue,
+                          rows_out=rows_out),
         out_shape=jax.ShapeDtypeStruct((m, n), xs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -249,3 +261,18 @@ def grouped_gate_up(xs, w_gate, w_up, counts, *, tiles=None,
     return _call(xs, (w_gate, w_up), counts,
                  lambda gate, up: jax.nn.silu(gate) * up,
                  "grouped_matmul_gate_up", tiles, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
+def grouped_relu2_in(xs, w_in, counts, *, tiles=None, interpret: bool = False):
+    """``relu(xs @ w_in[g].T) ** 2`` a group: an UNGATED expert's first
+    product and its activation (``mlp_hidden_act: relu2``), the float32
+    product squared in VMEM and rounded once. ``w_in`` is ``[E, N, K]``, a
+    row a hidden unit (``moe/grouped_experts.py`` says why), so the kernel
+    contracts the minor axis of both operands; ``grouped_matmul``'s body
+    under another epilogue otherwise. Where the expert's width ``N`` is no
+    multiple of 128 (Nemotron-H's 1,856) and a whole matrix is the block
+    (``tiling``), ``N`` is a full dimension of every block it is in."""
+    return _call(xs, (w_in,), counts,
+                 lambda acc: jnp.square(jnp.maximum(acc, 0.0)),
+                 "grouped_matmul_relu2_in", tiles, interpret, rows_out=True)

@@ -13,6 +13,10 @@ sublanes, plain adds of whole registers. The other way round (``d_state`` in
 the lanes) every head's ``y`` is a reduction ACROSS lanes, a rotate-and-add
 ladder a register, and the kernel is bound by that and not by the bytes.
 
+``B`` and ``C`` come ``[B, groups, N]``: the heads of a pack read one group's
+(``state_pack`` packs no heads of two groups), packs ``g * packs / groups ..``
+group ``g``'s, one group where a model has one.
+
 ``ssm_update`` is the Pallas kernel: the rows' slots and the layer arrive by
 scalar prefetch (as ``paged_attention.py`` takes its tables), the pool whole
 with ``input_output_aliases`` so that only the tiles of the rows' slots move,
@@ -36,9 +40,10 @@ LANES = 128
 GROUP_TILE = 16
 
 
-def state_pack(heads: int, head_dim: int) -> int:
-    """Heads whose values lie side by side in a state's lanes."""
-    return math.gcd(heads, max(LANES // head_dim, 1))
+def state_pack(heads: int, head_dim: int, groups: int = 1) -> int:
+    """Heads whose values lie side by side in a state's lanes: of one group
+    of ``B`` and ``C``."""
+    return math.gcd(heads // groups, max(LANES // head_dim, 1))
 
 
 def pack_state(state, pack: int):
@@ -75,15 +80,17 @@ def _operands(x, dt, a_log, pack: int):
 
 def ssm_update_reference(pool, layer: int, slots, x, dt, a_log, bm, cm):
     """pool: [L, slots, G, N, W] float32; slots: [B] int32; x: [B, H, P];
-    dt: [B, H] (after softplus); a_log: [H]; bm, cm: [B, N]. Returns
+    dt: [B, H] (after softplus); a_log: [H]; bm, cm: [B, groups, N]. Returns
     (y [B, H, P] float32 without the skip term, the pool)."""
     b, h, p = x.shape
-    pack = h // pool.shape[2]
-    a, xdt = _operands(x, dt, a_log, pack)
+    packs = pool.shape[2]
+    a, xdt = _operands(x, dt, a_log, h // packs)
+    # each pack's own group's B and C, down the state's rows: [B, G, N, 1]
+    bm, cm = (jnp.repeat(v.astype(jnp.float32), packs // v.shape[1],
+                         axis=1)[..., None] for v in (bm, cm))
     s = pool[layer, slots]                                    # [B, G, N, W]
-    s = a[:, :, None, :] * s + \
-        bm.astype(jnp.float32)[:, None, :, None] * xdt[:, :, None, :]
-    y = jnp.sum(s * cm.astype(jnp.float32)[:, None, :, None], axis=2)
+    s = a[:, :, None, :] * s + bm * xdt[:, :, None, :]
+    y = jnp.sum(s * cm, axis=2)
     return y.reshape(b, h, p), pool.at[layer, slots].set(s)
 
 
@@ -99,9 +106,12 @@ def _column(row):
 def _update_kernel(slots_ref, layer_ref, a_ref, xdt_ref, b_ref, c_ref, s_ref,
                    y_ref, s_out_ref, *, groups: int):
     del slots_ref, layer_ref                  # the index maps read them
-    bcol = _column(b_ref[0])                                  # [N, 1]
-    ccol = _column(c_ref[0])
+    # the tile's packs in runs of one group of B and C each
+    run = groups // b_ref.shape[1]
     for g in range(groups):
+        if g % run == 0:
+            bcol = _column(b_ref[0, g // run])                # [N, 1]
+            ccol = _column(c_ref[0, g // run])
         s = a_ref[0, g:g + 1, :] * s_ref[0, 0, g] \
             + bcol * xdt_ref[0, g:g + 1, :]                   # [N, W]
         s_out_ref[0, 0, g] = s.astype(s_out_ref.dtype)
@@ -117,8 +127,8 @@ def ssm_update(pool, layer: int, slots, x, dt, a_log, bm, cm,
     a, xdt = _operands(x, dt, a_log, pack)
     y, pool = _update_call(
         slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        a, xdt, bm.astype(jnp.float32)[:, None, :],
-        cm.astype(jnp.float32)[:, None, :], pool, interpret=interpret)
+        a, xdt, bm.astype(jnp.float32)[:, :, None, :],
+        cm.astype(jnp.float32)[:, :, None, :], pool, interpret=interpret)
     return y.reshape(b, h, p), pool
 
 
@@ -129,14 +139,21 @@ def _update_call(slots, layer, a, xdt, bm, cm, pool, *, interpret: bool):
     b, groups, w = a.shape
     n = pool.shape[3]
     tile = GROUP_TILE if groups % GROUP_TILE == 0 else groups
+    # a tile of packs reads whole groups of B and C (``[B, G, 1, N]``), or
+    # several tiles one: the packs of a group follow each other
+    run = groups // bm.shape[1]
+    if tile % run and run % tile:
+        tile = groups
+    held, tiles = max(tile // run, 1), max(run // tile, 1)
     row = lambda i, t, slots, layer: (i, t, 0)
-    vec = lambda i, t, slots, layer: (i, 0, 0)
+    vec = lambda i, t, slots, layer: (i, t // tiles, 0, 0)
     state = lambda i, t, slots, layer: (layer[0], slots[i], t, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(b, groups // tile),
         in_specs=[pl.BlockSpec((1, tile, w), row),
                   pl.BlockSpec((1, tile, w), row),
-                  pl.BlockSpec((1, 1, n), vec), pl.BlockSpec((1, 1, n), vec),
+                  pl.BlockSpec((1, held, 1, n), vec),
+                  pl.BlockSpec((1, held, 1, n), vec),
                   pl.BlockSpec((1, 1, tile, n, w), state)],
         out_specs=[pl.BlockSpec((1, tile, w), row),
                    pl.BlockSpec((1, 1, tile, n, w), state)])

@@ -3,8 +3,9 @@ decay a head), in plain ``jax.numpy``, and the causal depthwise convolution
 before it.
 
 A head's state ``S`` is ``[head_dim, d_state]``; a token brings ``x``
-``[head_dim]``, a step ``dt > 0``, and ``B``, ``C`` ``[d_state]`` shared by
-the heads of a group (one group here). With ``a_t = exp(dt_t A)``, ``A =
+``[head_dim]``, a step ``dt > 0``, and ``B``, ``C`` ``[groups, d_state]``:
+head ``j`` of ``H`` reads those of group ``j // (H / groups)`` (one group in
+Granite 4.0-H, eight in Nemotron-H). With ``a_t = exp(dt_t A)``, ``A =
 -exp(a_log) < 0``:
 
     S_t = a_t S_{t-1} + dt_t x_t (x) B_t          y_t = S_t C_t
@@ -49,21 +50,26 @@ def causal_conv(x, tail, weight, bias):
     return jax.nn.silu(out), rows
 
 
-def split_conv(rows, heads: int, head_dim: int, d_state: int):
-    """(x [T, H, P], B [T, N], C [T, N]) of the convolved rows ``[x ; B ;
-    C]`` [T, H * P + 2 * N]."""
-    inner = heads * head_dim
+def split_conv(rows, heads: int, head_dim: int, d_state: int,
+               groups: int = 1):
+    """(x [T, H, P], B [T, G, N], C [T, G, N]) of the convolved rows ``[x ;
+    B ; C]`` [T, H * P + 2 * G * N]."""
+    inner, bc = heads * head_dim, groups * d_state
     return (rows[:, :inner].reshape(-1, heads, head_dim),
-            rows[:, inner:inner + d_state], rows[:, inner + d_state:])
+            rows[:, inner:inner + bc].reshape(-1, groups, d_state),
+            rows[:, inner + bc:].reshape(-1, groups, d_state))
 
 
 def ssm_token_scan(x, dt, a_log, bm, cm, s0):
-    """x: [T, H, P]; dt: [T, H]; a_log: [H]; bm, cm: [T, N]; s0: [H, P, N].
-    Returns (y [T, H, P], S_T), float32."""
+    """x: [T, H, P]; dt: [T, H]; a_log: [H]; bm, cm: [T, G, N]; s0: [H, P,
+    N]. Returns (y [T, H, P], S_T), float32."""
     a_neg = -jnp.exp(a_log.astype(F32))
+    rep = x.shape[1] // bm.shape[1]
 
     def step(s, row):
         x_t, dt_t, b_t, c_t = row
+        b_t, c_t = (jnp.repeat(v, rep, axis=0)[:, None, :]
+                    for v in (b_t, c_t))                      # [H, 1, N]
         s = jnp.exp(dt_t * a_neg)[:, None, None] * s \
             + (dt_t[:, None] * x_t)[:, :, None] * b_t
         return s, jnp.sum(s * c_t, axis=-1)
@@ -78,6 +84,8 @@ def ssm_chunk_scan(x, dt, a_log, bm, cm, s0, block: int):
     (the rows padded to whole blocks with ``dt`` 0). ``x``, ``bm`` and ``cm``
     in the compute type; returns (y [T, H, P] float32, S_T float32)."""
     t, h, p = x.shape
+    g, n = bm.shape[1:]
+    r = h // g                                   # heads a group of B and C
     q = min(block, t)
     pad = -t % q
     if pad:
@@ -85,32 +93,34 @@ def ssm_chunk_scan(x, dt, a_log, bm, cm, s0, block: int):
                          for v in (x, dt, bm, cm))
     nc = (t + pad) // q
     dt = dt.astype(F32).reshape(nc, q, h)
-    x = x.reshape(nc, q, h, p)
-    bm, cm = bm.reshape(nc, q, -1), cm.reshape(nc, q, -1)
+    x = x.reshape(nc, q, g, r, p)
+    bm, cm = bm.reshape(nc, q, g, n), cm.reshape(nc, q, g, n)
     # L: the block's running log-decay, [nc, Q, H]
     run = jnp.cumsum(dt * -jnp.exp(a_log.astype(F32)), axis=1)
     last = run[:, -1]                                         # [nc, H]
 
-    # inside a block: scores C_t . B_s, decayed and stepped, times x_s
-    scores = jnp.einsum("cqn,csn->cqs", cm, bm, preferred_element_type=F32)
+    # inside a block: a group's scores C_t . B_s, and for each of its heads
+    # decayed and stepped, times x_s
+    scores = jnp.einsum("cqgn,csgn->cgqs", cm, bm, preferred_element_type=F32)
     seen = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
     span = run.transpose(0, 2, 1)                             # [nc, H, Q]
     decay = jnp.exp(jnp.where(seen, span[..., :, None] - span[..., None, :],
                               -jnp.inf))                      # [nc, H, Q, S]
-    weights = scores[:, None] * decay * dt.transpose(0, 2, 1)[:, :, None, :]
-    y = jnp.einsum("chqs,cshp->cqhp", weights.astype(x.dtype), x,
+    weights = decay * dt.transpose(0, 2, 1)[:, :, None, :]
+    weights = scores[:, :, None] * weights.reshape(nc, g, r, q, q)
+    y = jnp.einsum("cgrqs,csgrp->cqgrp", weights.astype(x.dtype), x,
                    preferred_element_type=F32)
 
     # what each block adds to the state, and the carry over the blocks
-    toward_end = jnp.exp(last[:, None] - run) * dt            # [nc, Q, H]
-    added = jnp.einsum("cqhp,cqn->chpn", toward_end[..., None] * x.astype(F32),
-                       bm.astype(F32), precision=_EXACT)
+    toward_end = (jnp.exp(last[:, None] - run) * dt).reshape(nc, q, g, r)
+    added = jnp.einsum("cqgrp,cqgn->cgrpn", toward_end[..., None]
+                       * x.astype(F32), bm.astype(F32), precision=_EXACT)
     carried = []
-    s = s0.astype(F32)
+    s = s0.astype(F32).reshape(g, r, p, n)
     for c in range(nc):
         carried.append(s)
-        s = jnp.exp(last[c])[:, None, None] * s + added[c]
-    y = y + jnp.exp(run)[..., None] * jnp.einsum(
-        "cqn,chpn->cqhp", cm.astype(F32), jnp.stack(carried),
+        s = jnp.exp(last[c]).reshape(g, r, 1, 1) * s + added[c]
+    y = y + jnp.exp(run).reshape(nc, q, g, r, 1) * jnp.einsum(
+        "cqgn,cgrpn->cqgrp", cm.astype(F32), jnp.stack(carried),
         precision=_EXACT)
-    return y.reshape(nc * q, h, p)[:t], s
+    return y.reshape(nc * q, h, p)[:t], s.reshape(h, p, n)

@@ -78,7 +78,7 @@ def main(argv=None):
                         "(e.g. MISTRAL_7B)")
     p.add_argument("--checkpoint", default=None,
                    help="msgpack/orbax params path (random init when unset)")
-    p.add_argument("--max-queue-depth", type=int, default=64)
+    p.add_argument("--max-queue-depth", type=int, default=256)
     p.add_argument("--max-new-tokens", type=int, default=64,
                    help="default per-request generation budget")
     p.add_argument("--kv-num-blocks", type=int, default=512)

@@ -196,7 +196,11 @@ class _EngineStepError(RuntimeError):
 
 @dataclass
 class ServingConfig:
-    max_queue_depth: int = 64            # bounded admission queue
+    # bounded admission queue: by default what an engine tracks by default
+    # (``V2EngineConfig.max_tracked_sequences`` 256), so that a burst the
+    # engine could run at once is not refused at the door (at 64, 128 callers
+    # that arrive together fill the queue, read as pressure 1.0 and are shed)
+    max_queue_depth: int = 256
     kv_high_watermark: float = 0.95      # projected KV-occupancy reject line
     default_max_new_tokens: int = 64
     default_timeout_s: Optional[float] = None   # per-request deadline

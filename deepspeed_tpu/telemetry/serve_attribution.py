@@ -118,7 +118,7 @@ _STAGE_OF = dict(_NAMES.SERVE_STAGE_OF)
 #: import: this module loads standalone by contract; tests pin the copies
 #: against serving.server.ServingConfig)
 SERVING_DEFAULTS = {
-    "max_queue_depth": 64,
+    "max_queue_depth": 256,
     "kv_high_watermark": 0.95,
     "kv_offload_enabled": False,
     "host_kv_budget_bytes": 256 << 20,

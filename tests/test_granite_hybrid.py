@@ -253,8 +253,8 @@ def test_closed_form_is_the_recurrence_from_a_state_in_hand(rows):
     x = jax.random.normal(k[0], (rows, h, p))
     dt = jax.nn.softplus(jax.random.normal(k[1], (rows, h)) - 2)
     a_log = jnp.log(jax.random.uniform(k[2], (h,), minval=1, maxval=16))
-    bm = jax.random.normal(k[3], (rows, n))
-    cm = jax.random.normal(k[4], (rows, n))
+    bm = jax.random.normal(k[3], (rows, 1, n))      # one group of B and C
+    cm = jax.random.normal(k[4], (rows, 1, n))
     s0 = jax.random.normal(k[5], (h, p, n))
     want_y, want_s = ssm.ssm_token_scan(x, dt, a_log, bm, cm, s0)
     got_y, got_s = ssm.ssm_chunk_scan(x, dt, a_log, bm, cm, s0, 256)
@@ -284,8 +284,8 @@ def test_update_kernel_is_the_plain_update(heads, head_dim, d_state):
     x = jax.random.normal(k[1], (b, heads, head_dim))
     dt = jax.nn.softplus(jax.random.normal(k[2], (b, heads)))
     a_log = jnp.log(jax.random.uniform(k[3], (heads,), minval=1, maxval=16))
-    bm = jax.random.normal(k[4], (b, d_state))
-    cm = jax.random.normal(k[5], (b, d_state))
+    bm = jax.random.normal(k[4], (b, 1, d_state))   # one group of B and C
+    cm = jax.random.normal(k[5], (b, 1, d_state))
     y0, p0 = su.ssm_update_reference(pool, 1, slots, x, dt, a_log, bm, cm)
     y1, p1 = su.ssm_update(pool, 1, slots, x, dt, a_log, bm, cm,
                            interpret=True)
@@ -293,8 +293,8 @@ def test_update_kernel_is_the_plain_update(heads, head_dim, d_state):
     np.testing.assert_allclose(p1[:, :slots_n], p0[:, :slots_n], atol=1e-6)
     s0 = su.unpack_state(pool[1, slots], pack)
     s1 = jnp.exp(-dt * jnp.exp(a_log))[..., None, None] * s0 \
-        + (dt[..., None] * x)[..., None] * bm[:, None, None, :]
-    np.testing.assert_allclose(y0, jnp.einsum("bhpn,bn->bhp", s1, cm),
+        + (dt[..., None] * x)[..., None] * bm[:, :, None, :]
+    np.testing.assert_allclose(y0, jnp.einsum("bhpn,bn->bhp", s1, cm[:, 0]),
                                atol=1e-4)
     np.testing.assert_allclose(su.unpack_state(p0[1, slots[:2]], pack),
                                s1[:2], atol=1e-6)

@@ -435,8 +435,10 @@ def test_state_kind_is_a_slot_a_sequence_beside_the_full_layers_pages(impl):
                                    w["conv_kernel"], w["conv_bias"])
     x = conv[:, :heads * p].reshape(real, heads, p)
     step = jax.nn.softplus(dt[:real] + w["dt_bias"])
-    y, s = ssm.ssm_token_scan(x, step, w["a_log"], conv[:, heads * p:-n],
-                              conv[:, -n:], jnp.zeros((heads, p, n)))
+    # one group of B and C: [rows, 1, n]
+    y, s = ssm.ssm_token_scan(x, step, w["a_log"],
+                              conv[:, None, heads * p:-n], conv[:, None, -n:],
+                              jnp.zeros((heads, p, n)))
     np.testing.assert_allclose(
         unpack_state(pool["state"]["ssm"][1, 1], at.pack), s, atol=1e-5)
     np.testing.assert_array_equal(

@@ -87,12 +87,22 @@ def test_one_tick_emits_the_phases_nested_in_step_decode(params, tracing):
     again = {e[1]: e for e in _spans(tracing)}
     assert again["serve/decode_build"][7] == {"tick": 42,
                                               "tables_rebuilt": False}
-    # the phases tile the step: what no phase holds is microseconds
-    held = sum(again[n][5]
-               for n in ("serve/plan", "serve/step_finish", *PHASES))
-    whole = again["serve/step_finish"][4] + again["serve/step_finish"][5] \
-        - again["serve/plan"][4]
-    assert whole - held < 5e-4
+    # the phases tile the step: what no phase holds is a small share of it.
+    # Held as a share (a host that six test workers share stretches step and
+    # gap alike) and over a few steps' smallest (a worker preempted between
+    # two spans adds its wait to one step's gap, never takes from it)
+    def untiled_share(by_name):
+        held = sum(by_name[n][5]
+                   for n in ("serve/plan", "serve/step_finish", *PHASES))
+        finish = by_name["serve/step_finish"]
+        whole = finish[4] + finish[5] - by_name["serve/plan"][4]
+        return (whole - held) / whole
+    shares = [untiled_share(again)]
+    for _ in range(4):
+        tracing.clear()
+        eng.step()
+        shares.append(untiled_share({e[1]: e for e in _spans(tracing)}))
+    assert min(shares) < 0.15, shares
 
 
 def test_every_prefill_chunk_has_its_span_with_counts_and_the_wait(

@@ -218,7 +218,7 @@ def test_serving_config_from_ds_config():
                     "brownout_pressure": 0.5}})
     assert cfg.max_queue_depth == 4
     assert cfg.kv_offload_enabled and cfg.brownout_pressure == 0.5
-    assert ServingConfig.from_ds_config({}).max_queue_depth == 64
+    assert ServingConfig.from_ds_config({}).max_queue_depth == 256
     with pytest.raises(ValueError, match="unknown 'serving' config keys"):
         ServingConfig.from_ds_config({"serving": {"max_que_depth": 4}})
 

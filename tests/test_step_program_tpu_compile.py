@@ -774,3 +774,70 @@ def test_split_head_pages_step_program_updates_four_pools_in_place_on_a_v5e(
         assert _held(stats) <= _held(alone.memory_analysis()) \
             + 2 * rows * vocab * 4
         assert stats.temp_size_in_bytes < 1 << 30
+
+
+# --- every layer ONE mixer: a state, experts of two matrices, or pages --------
+# NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, one layer of each
+# kind with the chip's 64 of 128 experts, the cell's 128 slots and 6,145
+# blocks, so that a compile takes seconds.
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+def test_nemotron_h_step_program_reads_its_experts_where_they_lie(
+        one_chip, as_on_a_tpu, fn_name):
+    """The states (eight groups of B and C: a tile of 16 head pairs reads
+    four of them) and the pages are aliased in the executable, an expert
+    layer has no pool at all, the ungated experts' first product is the
+    grouped kernel under its ``relu^2`` epilogue, and NO expert stack is
+    copied: 1,856 is no multiple of 128 lanes, so a stack ``[64, 2688,
+    1856]`` lies on the device with another axis minor and the kernel's
+    operand was copied out of it whole, 609 MiB a layer a step (a compile
+    of that form, PR 49); ``w_in`` is ``[64, 1856, 2688]`` and lies as the
+    kernel reads it."""
+    from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache
+    from deepspeed_tpu.models import nemotron_h as nh
+    cfg = nh.NemotronHConfig(pattern="ME*", experts_held=64,
+                             max_seq_len=3072)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(nh.NemotronHForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    policy = policy_for(cfg)
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: BlockedKVCache.for_spec(policy.cache_spec(cfg), "model", 64,
+                                        6145, state_slots=128).pool))
+    assert jax.tree.map(lambda x: x.shape, pool) == {
+        "full": (1, 2, 2, 6145, 64, 128),
+        "state": {"ssm": (1, 129, 32, 128, 128), "conv": (1, 129, 3 * 6144)}}
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+    tail = _tail(
+        fn_name,
+        (ints(1024), ints(), {"full": ints(48), "state": ints()}, ints()),
+        (ints(128), ints(128), {"full": ints(128, 48), "state": ints(128)},
+         jax.ShapeDtypeStruct((128,), jnp.bool_, sharding=one_chip)))
+    compiled = _program(fn_name).lower(
+        params, pool, *tail, policy=policy, cfg=cfg, block_size=64,
+        attn_impl="kernel").compile()
+    stats, text = compiled.memory_analysis(), compiled.as_text()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    for kernel in ("paged_attention", "grouped_matmul_relu2_in",
+                   "grouped_matmul"):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text
+    assert ("ssm_slot_read" in text) == (fn_name != "decode_step_g")
+    # beside its arguments a step holds activations: no second copy of an
+    # expert stack (609 MiB) or of a pool
+    assert stats.temp_size_in_bytes < 128 << 20
+    entry = text[text.index("\nENTRY"):]
+    assert [line.strip()[:120] for line in entry.splitlines()
+            if re.search(r"= \(?bf16\[64,(1856,2688|2688,1856)\]\S* "
+                         r"(copy|fusion)\(", line)] == []
+    assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
