@@ -182,7 +182,7 @@ def _ring_fwd(q_l, k_l, v_l, seg_l, sp, mode, axis_name, interpret):
                 causal_shift=shift,
                 segment_ids=(seg_l, kseg_cur) if has_seg else None)
             return (o.astype(jnp.float32),
-                    lse[:, :s_l, 0].reshape(b, h, s_l))
+                    lse[:, 0, :s_l].reshape(b, h, s_l))
 
         def skip():
             return (jnp.zeros((b, s_l, h, d), jnp.float32),
@@ -212,11 +212,9 @@ def _ring_bwd(sp, mode, axis_name, interpret, res, g):
     q_l, k_l, v_l, seg_l, out, lse = res
     b, s_l, h, d = q_l.shape
     blk = _ring_blocks(s_l)
-    # the bwd impl consumes lse in its folded padded layout [B*H, S_pad, 1]
-    pad = (-s_l) % blk
-    lse_f = lse.reshape(b * h, s_l, 1)
-    if pad:
-        lse_f = jnp.pad(lse_f, ((0, 0), (0, pad), (0, 0)))
+    # the bwd impl consumes lse in its folded padded layout [B*H, 1, S_pad]
+    lse_f = jnp.pad(lse.reshape(b * h, 1, s_l),
+                    ((0, 0), (0, 0), (0, (-s_l) % blk)))
     idx = jax.lax.axis_index(axis_name)
     perm = [(j, (j + 1) % sp) for j in range(sp)]
     has_seg = seg_l is not None

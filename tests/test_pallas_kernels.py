@@ -661,3 +661,231 @@ def test_pallas_flash_sliding_window(window):
     for a, b in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the flash kernels' panel schedule: which panels a grid visits, which body
+# each gets (interpret mode; panels small enough that one call holds dead,
+# whole, diagonal-crossed and window-edge-crossed panels)
+# ---------------------------------------------------------------------------
+def _dense_mask(sq, sk, causal, window, shift=0, q_ids=None, k_ids=None):
+    """[B or 1, sq, sk] bool by brute force: the contract of ``_mask``."""
+    qpos, kpos = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    mask = np.ones((sq, sk), bool)
+    if causal or window is not None:
+        mask &= qpos >= kpos + shift
+    if window is not None:
+        mask &= kpos > qpos - window
+    mask = mask[None]
+    if q_ids is not None:
+        mask = mask & (np.asarray(q_ids)[:, :, None]
+                       == np.asarray(k_ids)[:, None, :])
+    return mask
+
+
+def _dense_attention(q, k, v, mask):
+    """Softmax attention under an explicit mask; a row that sees no key
+    gives zeros (a ring step's skipped rows), where a softmax over a row of
+    NEG_INF would give the mean of the values."""
+    h, hkv = q.shape[2], k.shape[2]
+    k, v = jnp.repeat(k, h // hkv, axis=2), jnp.repeat(v, h // hkv, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    m = jnp.asarray(mask)[:, None]
+    s = jnp.where(m, s, -1e30)
+    p = jnp.where(m, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _seg(b, s, seed, segments=3):
+    return np.sort(np.random.default_rng(seed).integers(
+        0, segments, size=(b, s)), axis=1).astype(np.int32)
+
+
+#: name -> (s, h, hkv, block_q, block_k, causal, window, shift, segment ids)
+_SCHEDULE_CASES = {
+    # a window that is no multiple of the panel: dead panels on both sides of
+    # the band, whole ones inside, the diagonal and the edge each crossing
+    "window-no-multiple": (128, 4, 4, 16, 16, True, 40, 0, None),
+    "window-equals-panel": (96, 2, 2, 16, 16, True, 16, 0, None),
+    "window-under-a-panel": (96, 2, 2, 16, 16, True, 5, 0, None),
+    "rows-taller-than-keys": (128, 2, 2, 32, 16, True, 32, 0, None),
+    "keys-wider-than-rows": (128, 2, 2, 16, 32, True, 24, 0, None),
+    "unaligned-gqa4-window": (100, 8, 2, 32, 16, True, 40, 0, None),
+    "causal-no-window": (96, 2, 2, 16, 32, True, None, 0, None),
+    "causal-unaligned-gqa4": (72, 4, 1, 32, 32, True, None, 0, None),
+    "strict-band": (64, 2, 2, 16, 16, True, None, 1, None),
+    "strict-band-rect": (96, 2, 1, 32, 16, True, None, 1, None),
+    "non-causal": (64, 2, 2, 16, 32, False, None, 0, None),
+    "non-causal-padded-tail": (72, 2, 1, 16, 32, False, None, 0, None),
+    "segments-window": (96, 2, 2, 16, 16, True, 24, 0, "shared"),
+    "segments-non-causal": (64, 2, 2, 32, 16, False, None, 0, "shared"),
+    "ring-pair-full": (64, 2, 2, 16, 16, False, None, 0, "pair"),
+    "ring-pair-strict": (80, 4, 2, 16, 32, True, None, 1, "pair"),
+}
+
+
+def _run_flash(case, q, k, v, w):
+    """(out, lse, dq, dk, dv) of the kernels, and the dense mask; ``case`` a
+    row of ``_SCHEDULE_CASES``."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    s, _, _, bq, bk, causal, window, shift, seg = case
+    b = q.shape[0]
+    ids = {None: None, "shared": (_seg(b, s, 1),) * 2,
+           "pair": (_seg(b, s, 2), _seg(b, s, 3))}[seg]
+    segment_ids = None if ids is None else (
+        jnp.asarray(ids[0]) if seg == "shared" else
+        tuple(jnp.asarray(i) for i in ids))
+    out, lse = fa._pallas_flash_fwd_impl(q, k, v, causal, bq, bk, True,
+                                         window, shift, segment_ids)
+    grads = fa._pallas_flash_bwd_impl(q, k, v, out, lse, w, causal, bq, bk,
+                                      True, window, shift, segment_ids)
+    mask = _dense_mask(s, s, causal, window, shift, *(ids or (None, None)))
+    return (out, lse) + tuple(grads), mask
+
+
+@pytest.mark.parametrize("case", sorted(_SCHEDULE_CASES))
+def test_flash_panel_schedule_matches_dense(case):
+    """Forward, lse and all three gradients of the banded grid with its two
+    bodies against dense attention under the brute-force mask."""
+    s, h, hkv = _SCHEDULE_CASES[case][:3]
+    q, k, v = qkv(b=2, s=s, h=h, hkv=hkv, seed=len(case))
+    w = jnp.asarray(np.random.default_rng(5).normal(size=q.shape), jnp.float32)
+    (out, lse, dq, dk, dv), mask = _run_flash(_SCHEDULE_CASES[case], q, k, v, w)
+    if _SCHEDULE_CASES[case][-1] is None and not _SCHEDULE_CASES[case][-2]:
+        causal, window = _SCHEDULE_CASES[case][5:7]
+        np.testing.assert_allclose(
+            np.asarray(_dense_attention(q, k, v, mask)),
+            np.asarray(attention_reference(q, k, v, causal=causal,
+                                           window=window)),
+            atol=2e-5, rtol=2e-5)
+    ref, vjp = jax.vjp(lambda q, k, v: _dense_attention(q, k, v, mask),
+                       q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    for name, got, want in zip(("dq", "dk", "dv"), (dq, dk, dv), vjp(w)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-4, rtol=2e-4, err_msg=name)
+    # lse: a row [B*H, 1, S padded]; rows that see a key hold the logsumexp
+    kr = jnp.repeat(k, h // hkv, axis=2)
+    sc = np.asarray(jnp.einsum("bqhd,bkhd->bhqk", q, kr)) / np.sqrt(32)
+    sees = np.broadcast_to(mask[:, None], sc.shape)
+    want = np.log(np.sum(np.where(sees, np.exp(sc), 0.0), axis=-1) + 1e-300)
+    got = np.asarray(lse)[:, 0, :s].reshape(2, h, s)
+    rows = sees.any(axis=-1)
+    np.testing.assert_allclose(got[rows], want[rows], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kernel,lanes", [
+    ("fwd", 1), ("fwd", 128), ("dq", 1), ("dq", 128),
+    ("dkv", 1)])    # dKV reads its statistics as rows, never lane-dense
+def test_flash_two_bodies_agree_bit_for_bit(kernel, lanes):
+    """A panel wholly inside the band may take either body: the unmasked
+    one gives what the masked one gives under an all-true mask, bit for bit
+    (``where(True, x, ..)`` is ``x``), whether a row block's statistics are
+    columns or lane-dense. The bodies as pure functions, op by op: inside a
+    kernel the CPU's compiler fuses each body on its own and reorders sums."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    from deepspeed_tpu.ops.pallas.latent_attention import _softmax_update
+    rng = np.random.default_rng(3)
+    q, k, v, do = (jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
+                   for _ in range(4))
+    col = lambda x: jnp.tile(jnp.asarray(x, jnp.float32), (1, lanes))
+    lse, delta = col(rng.normal(size=(64, 1)) + 3), col(rng.normal(size=(64, 1)))
+    if kernel == "fwd":
+        s = fa._nt(q, k) * 0.2
+        scratch = (col(rng.normal(size=(64, 1))),
+                   col(np.abs(rng.normal(size=(64, 1)))),
+                   jnp.asarray(rng.normal(size=(64, 32)), jnp.float32))
+        bare = _softmax_update(s, None, v, *scratch)
+        masked = _softmax_update(s, jnp.ones(s.shape, bool), v, *scratch)
+    elif kernel == "dq":
+        bare = (fa._dq_panel(q, k, v, do, lse, delta, None, 0.2),)
+        masked = (fa._dq_panel(q, k, v, do, lse, delta,
+                               jnp.ones((64, 64), bool), 0.2),)
+    else:
+        bare = fa._dkv_panel(q, k, v, do, lse.T, delta.T, None, 0.2)
+        masked = fa._dkv_panel(q, k, v, do, lse.T, delta.T,
+                               jnp.ones((64, 64), bool), 0.2)
+    for a, b in zip(bare, masked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+#: (sq, sk, block_q, block_k, causal, window, shift)
+_COUNT_CASES = [
+    (128, 128, 16, 16, True, 40, 0), (128, 128, 32, 16, True, 32, 0),
+    (128, 128, 16, 32, True, 24, 0), (100, 100, 32, 16, True, 40, 0),
+    (96, 96, 16, 16, True, 5, 0), (96, 96, 16, 16, True, 16, 0),
+    (96, 96, 16, 32, True, None, 0), (64, 64, 16, 16, True, None, 1),
+    (96, 96, 32, 16, True, None, 1), (72, 72, 16, 32, False, None, 0),
+    (64, 64, 16, 32, False, None, 0), (160, 64, 16, 16, True, 24, 0),
+    (64, 160, 32, 16, True, None, 0), (200, 120, 16, 32, True, 48, 1),
+    (8192, 8192, 1024, 1024, True, 4096, 0),
+    (8192, 8192, 512, 512, True, 4096, 0),
+]
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,window,shift", _COUNT_CASES)
+def test_flash_schedule_counts_equal_brute_force(sq, sk, bq, bk, causal,
+                                                 window, shift):
+    """Live, whole and dead by the schedule equal a count over the dense
+    mask, for the row-major grids (forward, dQ) and the key-major one (dKV);
+    a band's live panels are visited once each, and a surplus step names the
+    block the step before it named, so nothing is fetched for it."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    sched = fa._schedule(sq, sk, bq, bk, causal, window, shift, False)
+    # padded query rows are rows like any other; padded keys are masked
+    dense = np.zeros((sched.nq * bq, sched.nk * bk), bool)
+    dense[:, :sk] = _dense_mask(sched.nq * bq, sk, causal, window, shift)[0]
+    panel = dense.reshape(sched.nq, bq, sched.nk, bk).transpose(0, 2, 1, 3)
+    live, whole = panel.any(axis=(2, 3)), panel.all(axis=(2, 3))
+
+    def walk(outer, steps, first, block, panel_of):
+        """One grid: ``panel_of(a, b)`` is the panel ``(qi, ki)`` of outer
+        block ``a`` at inner block ``b``; ``block(a, j)`` the inner block
+        step ``j`` names."""
+        seen_live = np.zeros_like(live)
+        seen_whole = np.zeros_like(whole)
+        dead = 0
+        for a in range(outer):
+            named = [int(block(a, j)) for j in range(steps)]
+            for j in range(steps):
+                at = panel_of(a, first(a) + j)
+                is_live, is_whole = sched.kind(*at)
+                if is_live:
+                    assert panel_of(a, named[j]) == at
+                    assert not seen_live[at], "a panel visited twice"
+                    seen_live[at], seen_whole[at] = True, bool(is_whole)
+                else:
+                    dead += 1
+                    assert j == 0 or named[j] == named[j - 1]
+        return seen_live, seen_whole, dead
+
+    for outer, steps, first, block, panel_of in (
+            (sched.nq, sched.k_steps, sched.first_k, sched.k_block,
+             lambda qi, ki: (qi, ki)),
+            (sched.nk, sched.q_steps, sched.first_q, sched.q_block,
+             lambda ki, qi: (qi, ki))):
+        seen_live, seen_whole, dead = walk(outer, steps, first, block,
+                                           panel_of)
+        np.testing.assert_array_equal(seen_live, live)
+        np.testing.assert_array_equal(seen_whole, whole)
+        assert dead == outer * steps - live.sum()
+
+
+def test_flash_panels_at_the_train_cell():
+    """``panels`` at the panel ``flash_attention_auto`` would choose: the
+    train cell's call and its brute-force count."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    got = fa.panels(8192, 8192, True, 4096, head_dim=128)
+    bq, bk = fa._auto_panel(8192, 8192, 128)
+    dense = _dense_mask(8192, 8192, True, 4096)[0]
+    panel = dense.reshape(8192 // bq, bq, 8192 // bk, bk)
+    live = panel.any(axis=(1, 3))
+    assert got["flash_panels"] == live.sum()
+    assert got["flash_panels_masked"] == (live & ~panel.all(axis=(1, 3))).sum()
+    steps = fa._schedule(8192, 8192, bq, bk, True, 4096, 0, False).k_steps
+    assert got["flash_steps_dead"] == live.shape[0] * steps - live.sum()
+    # no band, no padded tail: no mask anywhere, no dead step
+    assert fa.panels(2048, 2048, False)["flash_panels_masked"] == 0
+    assert fa.panels(2048, 2048, False)["flash_steps_dead"] == 0

@@ -406,6 +406,45 @@ def test_latent_prefill_panels_compile_for_a_v5e(one_chip, rows, blocks):
     assert "latent_prefill_attention" in text
 
 
+#: (rows, window, heads, kv heads, head width, segment ids): the train cell's
+#: call (Mistral-7B at 8,192 rows: twice the window), a head width of 256
+#: (the panel is cut smaller above 128) and a packed row, which masks every
+#: panel and takes its ids as a column and a row
+_FLASH_CALLS = {"train-8k-window-4096": (8192, 4096, 32, 8, 128, False),
+                "head-width-256": (4096, None, 8, 4, 256, False),
+                "packed-train-8k": (8192, 4096, 32, 8, 128, True)}
+
+
+@pytest.mark.parametrize("call", sorted(_FLASH_CALLS))
+def test_flash_kernels_compile_for_a_v5e(one_chip, call):
+    """The three flash kernels, each at the panel ``flash_attention_auto``
+    would choose for it (that asks for the backend, which is the CPU's here,
+    so the test makes its call): Mosaic takes the banded grid, both bodies,
+    the lane-dense statistics and their row form, and the panel inside its
+    scoped VMEM; ``lse`` and ``delta`` cross HBM as rows, not as ``[.., S,
+    1]`` columns tiled (8, 128)."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    rows, window, heads, kv_heads, width, packed = _FLASH_CALLS[call]
+
+    def arr(h):
+        return jax.ShapeDtypeStruct((1, rows, h, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    seg = jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, seg):
+        return jnp.sum(fa.pallas_flash_attention(
+            q, k, v, True, None, None, False, window,
+            seg if packed else None).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arr(heads), arr(kv_heads), arr(kv_heads), seg).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    assert f"f32[{heads},1,{rows}]" in text
+    assert f"f32[{heads},{rows},1]" not in text
+
+
 # --- the softmax-routed experts -----------------------------------------------
 # Mixtral-8x7B as the batch-rag cell serves it: 3 layers, 8 experts of
 # 4096 x 14336 top-2, a pool of 1472 blocks, a 2,048-token chunk or 32
