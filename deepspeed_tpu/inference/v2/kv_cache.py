@@ -101,10 +101,16 @@ class StateSlotShape:
 
     @property
     def tail(self) -> int:
-        """Values of a layer's convolution tail, stored flat (whole lane
-        tiles at the published 3 x 4,352; [3, 4352] would pad its 3 rows to
-        a sublane tile)."""
+        """Values of a layer's convolution tail: ``conv_width - 1`` rows of
+        ``conv_channels``."""
         return (self.conv_width - 1) * self.conv_channels
+
+    @property
+    def tail_stored(self) -> Tuple[int, int]:
+        """A layer's tail as a slot stores it: the values in their order over
+        128 lanes, [104, 128] at the published 3 x 4,352, so that a slot is
+        whole tiles which a copy can name (``ssm_update.tail_stored``)."""
+        return _ssm_update.tail_stored(self.conv_width, self.conv_channels)
 
     def layer_bytes(self, itemsize: int) -> int:
         """One sequence's bytes in one layer."""
@@ -1078,8 +1084,9 @@ def mixes_layer_kinds(layer_windows) -> bool:
 class _StateSlots:
     """The layers that keep no pages: a recurrent state a sequence
     (``StateSlotShape``), one SLOT of ``{"ssm": [L_state, slots + 1, G, N, W]
-    float32, "conv": [L_state, slots + 1, (K - 1) * C]}`` and no block table
-    (``ssm`` as ``ops/pallas/ssm_update.py`` stores a state). A sequence's
+    float32, "conv": [L_state, slots + 1, rows, lanes]}`` and no block table
+    (as ``ops/pallas/ssm_update.py`` stores a state and a tail, ``stored``
+    and ``tail_stored``). A sequence's
     slot is the engine's ``SequenceDescriptor.slot``, held from ``create`` to
     ``pop``; the slot past the last is padding's, as the trash block is the
     pages'. Nobody zeroes a slot when a sequence leaves it: a chunk that
@@ -1115,7 +1122,8 @@ class _StateSlots:
     def empty(self, layers: int, slots: int, dtype):
         at = self.shape
         return {"ssm": jnp.zeros((layers, slots + 1) + at.stored, jnp.float32),
-                "conv": jnp.zeros((layers, slots + 1, at.tail), dtype)}
+                "conv": jnp.zeros((layers, slots + 1) + at.tail_stored,
+                                  dtype)}
 
     def _split(self, conv):
         at = self.shape
@@ -1133,8 +1141,9 @@ class _StateSlots:
         slot, valid, fresh = slots
         true_len = jnp.sum(valid)
         with jax.named_scope("ssm/conv"):
-            tail = jnp.where(fresh, 0, cache["conv"][layer, slot]).reshape(
-                at.conv_width - 1, at.conv_channels)
+            tail = _ssm_update.unpack_tail(
+                jnp.where(fresh, 0, cache["conv"][layer, slot]),
+                at.conv_width, at.conv_channels)
             conv, rows = ssm.causal_conv(xbc, tail, kernel, bias)
             tail = jax.lax.dynamic_slice_in_dim(rows, true_len,
                                                 at.conv_width - 1)
@@ -1157,38 +1166,29 @@ class _StateSlots:
             states = cache["ssm"].at[layer, slot].set(s) if sliced else \
                 _ssm_update.slot_write(cache["ssm"], layer, slot, s, **how)
             cache = {"ssm": states, "conv": cache["conv"].at[
-                layer, slot].set(tail.reshape(-1))}
+                layer, slot].set(_ssm_update.pack_tail(tail, at.tail_stored))}
         return y.reshape(y.shape[0], -1).astype(xbc.dtype), cache
 
     @partial(jax.jit, static_argnames=("self", "attn_impl"))
     def attend_decode(self, cache, layer, slots, attn_impl, xbc, step, kernel,
                       bias, a_log, d):
         """One token a sequence: each row's tail shifted by its token, its
-        state read and written once (the Pallas kernel, or gather, update,
-        scatter: ``attn_impl``)."""
-        at = self.shape
-        with jax.named_scope("ssm/conv"):
-            tails = cache["conv"][layer, slots].reshape(
-                -1, at.conv_width - 1, at.conv_channels)
-            rows = jnp.concatenate([tails, xbc[:, None]], axis=1)
-            # tap by tap, as ``ssm.causal_conv`` sums a chunk's
-            taps = kernel.astype(jnp.float32)
-            conv = jax.nn.silu(sum(
-                rows[:, j].astype(jnp.float32) * taps[:, j]
-                for j in range(at.conv_width))
-                + bias.astype(jnp.float32)).astype(xbc.dtype)
-            tails = cache["conv"].at[layer, slots].set(
-                rows[:, 1:].reshape(rows.shape[0], -1))
-            x, bm, cm = self._split(conv)
+        state read and written once, both by a Pallas kernel that moves the
+        rows' slots alone (or gather, update, scatter: ``attn_impl``)."""
         impl = _resolve_impl(attn_impl)
+        how = {} if impl == "gather" else \
+            dict(interpret=impl == "kernel_interpret")
+        with jax.named_scope("ssm/conv"):
+            shift = _ssm_update.ssm_conv_step_reference if impl == "gather" \
+                else _ssm_update.ssm_conv_step
+            conv, tails = shift(cache["conv"], layer, slots, xbc, kernel,
+                                bias, **how)
+            x, bm, cm = self._split(conv)
         with jax.named_scope("ssm/update"):
-            if impl == "gather":
-                y, pool = _ssm_update.ssm_update_reference(
-                    cache["ssm"], layer, slots, x, step, a_log, bm, cm)
-            else:
-                y, pool = _ssm_update.ssm_update(
-                    cache["ssm"], layer, slots, x, step, a_log, bm, cm,
-                    interpret=impl == "kernel_interpret")
+            update = _ssm_update.ssm_update_reference if impl == "gather" \
+                else _ssm_update.ssm_update
+            y, pool = update(cache["ssm"], layer, slots, x, step, a_log, bm,
+                             cm, **how)
             y = y + d.astype(jnp.float32)[:, None] * x
         return y.reshape(y.shape[0], -1).astype(xbc.dtype), \
             {"ssm": pool, "conv": tails}

@@ -24,6 +24,12 @@ a grid over (row, tile of groups). ``ssm_update_reference`` is the same step
 in plain ``jax.numpy`` (gather the rows' states, update, scatter): the numerics
 oracle of the kernel's tests, and the path where there is no TPU. Rows that
 are batch padding name the pool's last slot, which no sequence holds.
+
+``ssm_conv_step`` is the step before it, the same way: each row's convolution
+tail (the last ``K - 1`` rows of the layer's convolution input, in the cache's
+type) read, shifted by the row's token and written, by copies the kernel
+issues itself out of and into the rows' slots of a pool that stays in HBM
+whole; ``ssm_conv_step_reference`` is its oracle.
 """
 
 import functools
@@ -169,6 +175,187 @@ def _update_call(slots, layer, a, xdt, bm, cm, pool, *, interpret: bool):
         interpret=interpret,
         name="ssm_update",
     )(slots, layer, a, xdt, bm, cm, pool)
+
+
+# --- the convolution's tail, one decode step -----------------------------------
+# A slot's tail is the last ``K - 1`` rows of the layer's convolution input,
+# ``[K - 1, C]`` in the cache's type, stored ``[rows, 128]`` (``tail_stored``):
+# the same values in the same order, laid so that a slot is a block of whole
+# tiles that a copy can name. One row of a ``[slots, (K - 1) * C]`` array is a
+# sublane of every tile it crosses, which XLA gathers and scatters a row at a
+# time: 100 us a layer a tick where the bytes take 5 (my chip run, PR 44).
+
+def tail_stored(width: int, channels: int):
+    """A slot's tail as the pool stores it: [rows, lanes], the ``(K - 1) * C``
+    values in their order over 128 lanes and zero rows up to a whole tile of
+    8 (a copy names whole tiles: 102 rows are 104 at granite's 3 x 4,352);
+    at toy widths, where the channels fill no lanes, one row a tap."""
+    if channels % LANES:
+        return (width - 1, channels)
+    return (-(-(width - 1) * channels // LANES // 8) * 8, LANES)
+
+
+def pack_tail(tail, stored):
+    """[..., K - 1, C] as the pool stores it (``tail_stored``)."""
+    rows, lanes = stored
+    lead = tail.shape[:-2]
+    flat = tail.reshape(lead + (-1,))
+    zeros = rows * lanes - flat.shape[-1]
+    return jnp.pad(flat, ((0, 0),) * len(lead) + ((0, zeros),)).reshape(
+        lead + stored)
+
+
+def unpack_tail(stored, width: int, channels: int):
+    """The inverse of ``pack_tail``: [..., rows, lanes] -> [..., K - 1, C]."""
+    lead = stored.shape[:-2]
+    return stored.reshape(lead + (-1,))[..., :(width - 1) * channels].reshape(
+        lead + (width - 1, channels))
+
+
+def ssm_conv_step_reference(pool, layer, slots, xbc, taps, bias):
+    """pool: [L, slots, rows, lanes] (``tail_stored``); slots: [B] int32;
+    xbc: [B, C]; taps: [C, K]; bias: [C]. Each row's tail shifted by its
+    token: returns (the convolved rows [B, C] in ``xbc``'s type, after
+    ``silu``; the pool). Tap by tap in float32, as ``ssm.causal_conv`` sums
+    a chunk's."""
+    c, width = taps.shape
+    used = (width - 1) * c // pool.shape[-1]     # the rest are a tile's zeros
+    tails = unpack_tail(pool[layer, slots], width, c)
+    rows = jnp.concatenate([tails, xbc[:, None]], axis=1)
+    w = taps.astype(jnp.float32)
+    conv = jax.nn.silu(sum(
+        rows[:, j].astype(jnp.float32) * w[:, j] for j in range(width))
+        + bias.astype(jnp.float32)).astype(xbc.dtype)
+    return conv, pool.at[layer, slots, :used].set(
+        rows[:, 1:].reshape(-1, used, pool.shape[-1]).astype(pool.dtype))
+
+
+#: rows whose tails one grid step copies in, shifts and copies out
+CONV_ROWS = 16
+
+
+def _conv_kernel(slots_ref, layer_ref, x_ref, w_ref, bias_ref, pool_ref,
+                 y_ref, pool_out_ref, held, shifted, sems, *, rows: int,
+                 width: int):
+    """Step ``i`` of the grid: the tails of rows ``i * rows ..`` are in
+    ``held[i % 2]`` (copied there while step ``i - 1`` computed), the step's
+    new tails go from ``shifted[i % 2]`` while step ``i + 1`` computes."""
+    i, n = pl.program_id(0), pl.num_programs(0)
+    buf = i % 2
+    cr = x_ref.shape[1]                    # a tap's rows of the stored tail
+
+    def copies(do, step, buf, into_pool: bool):
+        """Start (or wait for) the copies of step ``step``'s rows: their
+        slots' tails into ``held[buf]``, or ``shifted[buf]`` into the slots."""
+        def one(r, base):
+            pool = pool_out_ref if into_pool else pool_ref
+            slot = pool.at[layer_ref[0], slots_ref[base + r]]
+            do(pltpu.make_async_copy(shifted.at[buf, r], slot, sems.at[1, buf])
+               if into_pool else
+               pltpu.make_async_copy(slot, held.at[buf, r], sems.at[0, buf]))
+            return base
+        jax.lax.fori_loop(0, rows, one, step * rows)
+
+    start, wait = (lambda c: c.start()), (lambda c: c.wait())
+
+    @pl.when(i == 0)
+    def _():
+        copies(start, i, buf, False)
+
+    @pl.when(i + 1 < n)
+    def _():
+        copies(start, i + 1, 1 - buf, False)
+
+    copies(wait, i, buf, False)
+
+    @pl.when(i >= 2)
+    def _():                               # ``shifted[buf]`` is step i - 2's
+        copies(wait, i - 2, buf, True)
+
+    def one(r, carry):
+        x = x_ref[r]
+        acc = held[buf, r, 0:cr].astype(jnp.float32) * w_ref[0]
+        for j in range(1, width - 1):
+            acc = acc + held[buf, r, j * cr:(j + 1) * cr].astype(
+                jnp.float32) * w_ref[j]
+        acc = acc + x.astype(jnp.float32) * w_ref[width - 1] + bias_ref[...]
+        y_ref[r] = jax.nn.silu(acc).astype(y_ref.dtype)
+        if width > 2:
+            shifted[buf, r, 0:(width - 2) * cr] = \
+                held[buf, r, cr:(width - 1) * cr]
+        shifted[buf, r, (width - 2) * cr:(width - 1) * cr] = x.astype(
+            shifted.dtype)
+        if shifted.shape[2] > (width - 1) * cr:     # the tile's zero rows
+            shifted[buf, r, (width - 1) * cr:] = held[buf, r,
+                                                      (width - 1) * cr:]
+        return carry
+    jax.lax.fori_loop(0, rows, one, 0)
+
+    copies(start, i, buf, True)
+
+    @pl.when(i == n - 1)
+    def _():
+        copies(wait, i, buf, True)
+
+        @pl.when(i >= 1)
+        def _():
+            copies(wait, i - 1, 1 - buf, True)
+
+
+def ssm_conv_step(pool, layer, slots, xbc, taps, bias,
+                  interpret: bool = False):
+    """``ssm_conv_step_reference`` as one Pallas call that copies the rows'
+    slots' tails alone, in place: the pool stays in HBM whole."""
+    b, c = xbc.shape
+    lanes = pool.shape[-1]
+    laid = lambda v: v.astype(jnp.float32).reshape(-1, c // lanes, lanes)
+    conv, pool = _conv_call(
+        slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        xbc.reshape(b, c // lanes, lanes), laid(taps.T), laid(bias)[0], pool,
+        interpret=interpret)
+    return conv.reshape(b, c), pool
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _conv_call(slots, layer, x, taps, bias, pool, *, interpret: bool):
+    """The kernel call, under a ``jit`` of its own with the layer a value
+    (``_update_call``)."""
+    b, cr, lanes = x.shape
+    width = taps.shape[0]
+    rows = math.gcd(b, CONV_ROWS)
+    block = pl.BlockSpec((rows, cr, lanes), lambda i, slots, layer: (i, 0, 0))
+    whole = lambda *shape: pl.BlockSpec(
+        shape, lambda i, slots, layer: (0,) * len(shape))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    tails = (2, rows) + pool.shape[2:]
+    return pl.pallas_call(
+        functools.partial(_conv_kernel, rows=rows, width=width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b // rows,),
+            in_specs=[block, whole(width, cr, lanes), whole(cr, lanes),
+                      in_hbm],
+            out_specs=[block, in_hbm],
+            scratch_shapes=[pltpu.VMEM(tails, pool.dtype),
+                            pltpu.VMEM(tails, pool.dtype),
+                            # [in, out] x the two buffers
+                            pltpu.SemaphoreType.DMA((2, 2))]),
+        # the pool is in HBM by name, in and out. Left to choose, XLA moves a
+        # pool of up to tens of MB whole into VMEM before a step's first call
+        # and back after its last (28 MB each way a tick at Nemotron's cell,
+        # 3.4 MB around EVERY call at two layers: compiled for a v5e, PR 51).
+        # A program has to donate the pool it hands in, as the step programs
+        # do: around the copy it makes of one it keeps, this XLA's memory
+        # space assignment fails a check and takes the process with it
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   pltpu.HBM(pool.shape, pool.dtype)],
+        # operand 5 (after the two prefetched scalars) is the pool
+        input_output_aliases={5: 1},
+        # a step starts the copies of the step after it: in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="ssm_conv_step",
+    )(slots, layer, x, taps, bias, pool)
 
 
 # --- one slot's state in and out, for a prefill chunk -------------------------

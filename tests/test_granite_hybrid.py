@@ -7,6 +7,7 @@ no block table), and what refuses a state kind by name.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -303,6 +304,102 @@ def test_update_kernel_is_the_plain_update(heads, head_dim, d_state):
         np.testing.assert_array_equal(p1[layer, rows], pool[layer, rows])
     np.testing.assert_array_equal(
         su.unpack_state(su.pack_state(s0, pack), pack), s0)
+
+
+def _conv_case(rows, channels, layers=3, spare=3, width=4, seed=0,
+               dtype=jnp.bfloat16):
+    """(pool, slots in a shuffled order, xbc, taps, bias) of a decode step:
+    ``rows`` live slots of ``rows + spare`` and the padding's past them."""
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+    pool = draw(layers, rows + spare + 1, *su.tail_stored(width, channels))
+    slots = jnp.asarray(rng.permutation(rows + spare)[:rows], jnp.int32)
+    return pool, slots, draw(rows, channels), draw(channels, width), \
+        draw(channels)
+
+
+# (rows, channels): granite's published widths (4,096 + 2 x 128 of one B/C
+# group), Nemotron's (4,096 + 2 x 8 x 128) and a toy width
+_CONV_CASES = [(64, 4352), (128, 6144), (4, 24)]
+
+
+@pytest.mark.parametrize("rows,channels", _CONV_CASES)
+def test_conv_step_kernel_is_the_plain_shift(rows, channels):
+    """The Pallas kernel in interpret mode against gather, shift, scatter,
+    to the last bit: the convolved rows and the WHOLE pool, rows in any slot
+    order; slots no row names and other layers keep their bytes."""
+    pool, slots, xbc, taps, bias = _conv_case(rows, channels)
+    want, kept = su.ssm_conv_step_reference(pool, 1, slots, xbc, taps, bias)
+    got, held = su.ssm_conv_step(pool, 1, slots, xbc, taps, bias,
+                                 interpret=True)
+    assert got.dtype == xbc.dtype and held.dtype == pool.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_array_equal(np.asarray(held, np.float32),
+                                  np.asarray(kept, np.float32))
+    unnamed = sorted(set(range(pool.shape[1])) - set(np.asarray(slots)))
+    assert len(unnamed) == 4
+    for layer, where in ((0, slice(None)), (2, slice(None)), (1, unnamed)):
+        np.testing.assert_array_equal(
+            np.asarray(held[layer, where], np.float32),
+            np.asarray(pool[layer, where], np.float32))
+    # and a row's new tail is its old one a row on, behind its token
+    new = su.unpack_tail(held[1, slots], 4, channels)
+    old = su.unpack_tail(pool[1, slots], 4, channels)
+    np.testing.assert_array_equal(np.asarray(new[:, :2], np.float32),
+                                  np.asarray(old[:, 1:], np.float32))
+    np.testing.assert_array_equal(np.asarray(new[:, 2], np.float32),
+                                  np.asarray(xbc, np.float32))
+
+
+@pytest.mark.parametrize("rows,channels", _CONV_CASES)
+def test_conv_step_padding_rows_touch_the_last_slot_alone(rows, channels):
+    """Half the rows are batch padding and all name the last slot: it may
+    hold anything, every other slot's tail is what the reference leaves, and
+    the live rows' convolutions are the reference's."""
+    pool, slots, xbc, taps, bias = _conv_case(rows, channels, seed=1)
+    last = pool.shape[1] - 1
+    live = np.arange(rows) % 2 == 0
+    slots = jnp.where(live, slots, last)
+    want, kept = su.ssm_conv_step_reference(pool, 2, slots, xbc, taps, bias)
+    got, held = su.ssm_conv_step(pool, 2, slots, xbc, taps, bias,
+                                 interpret=True)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[live],
+                                  np.asarray(want, np.float32)[live])
+    np.testing.assert_array_equal(np.asarray(held[:, :last], np.float32),
+                                  np.asarray(kept[:, :last], np.float32))
+    np.testing.assert_array_equal(np.asarray(held[:2, last], np.float32),
+                                  np.asarray(pool[:2, last], np.float32))
+
+
+@pytest.mark.parametrize("impl", ["gather", "kernel_interpret"])
+@pytest.mark.parametrize("channels", [24, 256])
+def test_three_decode_steps_are_a_chunk_of_three_rows(channels, impl):
+    """Decode and chunk agree on what a tail is: three steps in a row equal
+    ``ssm.causal_conv`` over the same three rows from the same tail, the
+    convolved rows and the tail left behind."""
+    pool, slots, _, taps, bias = _conv_case(4, channels, seed=2,
+                                            dtype=jnp.float32)
+    xbc = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (3, 4, channels)), jnp.float32)
+    step = su.ssm_conv_step_reference if impl == "gather" else \
+        functools.partial(su.ssm_conv_step, interpret=True)
+    held, got = pool, []
+    for t in range(3):
+        conv, held = step(held, 1, slots, xbc[t], taps, bias)
+        got.append(conv)
+    for row, slot in enumerate(np.asarray(slots)):
+        tail = su.unpack_tail(pool[1, slot], 4, channels)
+        conv, rows = ssm.causal_conv(xbc[:, row], tail, taps, bias)
+        np.testing.assert_allclose(jnp.stack(got)[:, row], conv, atol=1e-5)
+        np.testing.assert_array_equal(
+            su.unpack_tail(held[1, slot], 4, channels), rows[3:])
+    by_row = xbc.transpose(1, 0, 2)                 # [rows, K - 1, C]
+    np.testing.assert_array_equal(
+        su.unpack_tail(su.pack_tail(by_row, su.tail_stored(4, channels)), 4,
+                       channels), by_row)
 
 
 # --- what refuses a state kind by name ---------------------------------------

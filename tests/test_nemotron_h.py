@@ -166,7 +166,7 @@ def test_one_cache_manager_keeps_a_state_pages_and_nothing(params):
     pool = eng.kv.pool
     # three state layers, one attention layer, and the expert layers nowhere
     assert pool["state"]["ssm"].shape == (3, 5, 4, 16, 16)
-    assert pool["state"]["conv"].shape == (3, 5, 3 * (64 + 2 * 4 * 16))
+    assert pool["state"]["conv"].shape == (3, 5, 3, 64 + 2 * 4 * 16)
     assert pool["full"].shape[0] == 1 and set(pool) == {"full", "state"}
     eng.put([1, 2], [tokens(20), tokens(9)])
     c = eng.last_step_counters

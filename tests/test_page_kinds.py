@@ -387,10 +387,14 @@ def test_state_kind_is_a_slot_a_sequence_beside_the_full_layers_pages(impl):
     assert kv.by_layer_kind and kv.has_state and not kv.two_kinds
     assert kv.window_allocator is None and kind.window is None
     assert (at.pack, at.stored, at.tail) == (4, (1, n, 4 * p), 3 * channels)
+    # a toy tail fills no lanes: a row a tap (the published 3 x 4,352 are
+    # 102 rows of 128 lanes and two of zeros, a whole tile)
+    assert at.tail_stored == (3, channels)
+    assert StateSlotShape(64, 64, 128, 4, 4352).tail_stored == (104, 128)
     assert jax.tree.map(lambda x: x.shape, kv.pool) == {
         "full": (1, 2, H, NB, BS, D),
         "state": {"ssm": (2, slots_n + 1, 1, n, 4 * p),
-                  "conv": (2, slots_n + 1, 3 * channels)}}
+                  "conv": (2, slots_n + 1, 3, channels)}}
     assert kv.pool["state"]["ssm"].dtype == jnp.float32
     assert kind.local == (0, 0, 1) and kv.usable_blocks == NB - 1
     assert kv.slot_bytes == 2 * (heads * p * n * 4 + 3 * channels * 4)

@@ -658,6 +658,22 @@ def test_xing4_step_program_keeps_its_streams_and_the_pool_in_place_on_a_v5e(
 
 # --- a state kind beside the pages (granite-4.0-h-micro's widths) --------------
 
+def _makers_of(text, shape):
+    """The operations of a compiled program that make or move a value of
+    ``shape`` (an XLA type, ``bf16[2,65,104,128]``), but for the program's
+    own argument, a kernel that takes it aliased and what hands its result
+    on: the lines that would be a gather, a scatter, a slice's update, a
+    copy or a change of layout of that buffer."""
+    passes_on = re.compile(
+        r" (parameter|custom-call|get-tuple-element|tuple|bitcast)\(")
+    return [line.strip()[:160] for line in text.splitlines()
+            if " = " in line and not line.startswith("HloModule")
+            and (re.search(r"= \(?[^=]*" + re.escape(shape), line)
+                 or re.search(r"(gather|scatter|dynamic-update-slice)\(.*"
+                              + re.escape(shape), line))
+            and not passes_on.search(line)]
+
+
 @pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g",
                                      FUSED])
 def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
@@ -689,7 +705,7 @@ def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
                                         3073, state_slots=64).pool))
     assert jax.tree.map(lambda x: x.shape, pool) == {
         "full": (1, 2, 4, 3073, 64, 128),
-        "state": {"ssm": (2, 65, 32, 128, 128), "conv": (2, 65, 3 * 4352)}}
+        "state": {"ssm": (2, 65, 32, 128, 128), "conv": (2, 65, 104, 128)}}
     pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                      for x in jax.tree.leaves(pool))
     tail = _tail(
@@ -708,8 +724,16 @@ def test_state_kind_step_program_updates_the_whole_pool_in_place(one_chip,
     if fn_name != "prefill_chunk_g":
         assert lowered.as_text().count('kernel_name = "ssm_update"') == 1
         assert "ssm_update" in compiled.as_text()
+        # and the tails' shift: traced once, called a layer
+        assert lowered.as_text().count('kernel_name = "ssm_conv_step"') == 1
+        assert "ssm_conv_step" in compiled.as_text()
     if fn_name == "decode_step_g":
         assert stats.temp_size_in_bytes < 64 << 20
+        # the tails move by slot in the kernel and nowhere else: nothing
+        # gathers from, scatters into, copies or re-lays-out their pool
+        # (left to choose, XLA also moved these 3.4 MB whole into VMEM and
+        # back around each call: the kernel names HBM)
+        assert _makers_of(compiled.as_text(), "bf16[2,65,104,128]") == []
     else:
         # a 2,048-token chunk's closed form: blocks of 256, scores a head;
         # and no operation makes a value of the states' pool's shape (read
@@ -853,7 +877,7 @@ def test_nemotron_h_step_program_reads_its_experts_where_they_lie(
                                         6145, state_slots=128).pool))
     assert jax.tree.map(lambda x: x.shape, pool) == {
         "full": (1, 2, 2, 6145, 64, 128),
-        "state": {"ssm": (1, 129, 32, 128, 128), "conv": (1, 129, 3 * 6144)}}
+        "state": {"ssm": (1, 129, 32, 128, 128), "conv": (1, 129, 144, 128)}}
     pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                      for x in jax.tree.leaves(pool))
     tail = _tail(
@@ -872,6 +896,12 @@ def test_nemotron_h_step_program_reads_its_experts_where_they_lie(
         assert kernel in text, kernel
     assert "ragged-dot" not in text
     assert ("ssm_slot_read" in text) == (fn_name != "decode_step_g")
+    assert ("ssm_slot_write" in text) == (fn_name != "decode_step_g")
+    if fn_name == "decode_step_g":
+        # one state layer: the tails' shift and the update, a kernel each,
+        # and nothing else touches the tails' pool
+        assert text.count("%ssm_conv_step") >= 1 and "ssm_update" in text
+        assert _makers_of(text, "bf16[1,129,144,128]") == []
     # beside its arguments a step holds activations: no second copy of an
     # expert stack (609 MiB) or of a pool
     assert stats.temp_size_in_bytes < 128 << 20
