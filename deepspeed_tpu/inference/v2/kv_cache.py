@@ -12,9 +12,10 @@ latent plane (``_LatentPages``), head pages held by LAYER kind
 (``_LayerKindPages``: a pool of the full layers' pages and a pool of the
 windowed layers', each with its block tables and, where the model says so,
 its own KV head count and key and value widths, for a model that mixes the
-two; and, beside the pages, the layers that keep no pages at all but a
-recurrent state of a size that does not grow with the context, one SLOT a
-sequence and no block table, ``_StateSlots``). A kind owns, and nothing outside this
+two; and, beside the pages, the layers that keep no pages at all but what
+does not grow with the context, one SLOT a sequence and no block table: the
+tail of a causal convolution alone, ``_TailSlots``, or that and a recurrent
+state, ``_StateSlots``). A kind owns, and nothing outside this
 module knows: the pool's shape and block axis, the trash block, whether the
 step programs carry an array or ``(pages, scales)``, the slots a step's rows
 land in, their write, the chunk and decode attention over its pages,
@@ -70,14 +71,18 @@ class HeadPageShape:
 
 @dataclasses.dataclass(frozen=True)
 class StateSlotShape:
-    """What a slot of the state kind holds of one sequence in one layer: the
-    recurrent state of ``heads`` heads, ``[head_dim, d_state]`` float32 each
-    (a sum over every token the sequence has seen, so not the compute type),
-    and the last ``conv_width - 1`` rows of the ``conv_channels`` that the
-    layer's causal convolution looks back on, in the compute type.
-    ``scan_block`` is the block a prefill chunk's closed form sums over;
+    """What a slot of the state kind holds of one sequence in one layer.
+    Always the TAIL of the layer's causal convolution: the last ``conv_width
+    - 1`` rows of the ``conv_channels`` it looks back on, in the compute
+    type. Where ``heads`` > 0 (a Mamba-2 layer) also the recurrent state
+    of ``heads`` heads, ``[head_dim, d_state]`` float32 each (a sum over
+    every token the sequence has seen, so not the compute type):
+    ``scan_block`` is the block a prefill chunk's closed form sums over,
     ``groups`` how many ``B`` and ``C`` a token brings (``conv_channels`` =
-    ``heads * head_dim + 2 * groups * d_state``; a head reads its group's)."""
+    ``heads * head_dim + 2 * groups * d_state``; a head reads its group's).
+    With no heads (``tail_only``: a gated short convolution) the slot is the
+    tail and nothing else, and no pool of states exists. What follows the
+    convolution's taps' sum is the kind's to say (``_TailSlots.activation``)."""
     heads: int
     head_dim: int
     d_state: int
@@ -85,6 +90,16 @@ class StateSlotShape:
     conv_channels: int
     scan_block: int = 256
     groups: int = 1
+
+    @classmethod
+    def tail_only(cls, conv_width: int, conv_channels: int):
+        """A slot that is a convolution tail alone."""
+        return cls(0, 0, 0, conv_width, conv_channels)
+
+    @property
+    def recurrent(self) -> bool:
+        """The slot holds a recurrent state beside its tail."""
+        return self.heads > 0
 
     @property
     def pack(self) -> int:
@@ -108,12 +123,14 @@ class StateSlotShape:
     @property
     def tail_stored(self) -> Tuple[int, int]:
         """A layer's tail as a slot stores it: the values in their order over
-        128 lanes, [104, 128] at the published 3 x 4,352, so that a slot is
+        128 lanes, [104, 128] at a Mamba-2 layer's published 3 x 4,352 and
+        [32, 128] at a short convolution's 2 x 2,048, so that a slot is
         whole tiles which a copy can name (``ssm_update.tail_stored``)."""
         return _ssm_update.tail_stored(self.conv_width, self.conv_channels)
 
     def layer_bytes(self, itemsize: int) -> int:
-        """One sequence's bytes in one layer."""
+        """One sequence's bytes in one layer (a tail alone: 8 KB at 2 x
+        2,048 bfloat16)."""
         return self.heads * self.head_dim * self.d_state * 4 \
             + self.tail * itemsize
 
@@ -1081,24 +1098,28 @@ def mixes_layer_kinds(layer_windows) -> bool:
         and None in set(layer_windows)
 
 
-class _StateSlots:
-    """The layers that keep no pages: a recurrent state a sequence
-    (``StateSlotShape``), one SLOT of ``{"ssm": [L_state, slots + 1, G, N, W]
-    float32, "conv": [L_state, slots + 1, rows, lanes]}`` and no block table
-    (as ``ops/pallas/ssm_update.py`` stores a state and a tail, ``stored``
-    and ``tail_stored``). A sequence's
-    slot is the engine's ``SequenceDescriptor.slot``, held from ``create`` to
-    ``pop``; the slot past the last is padding's, as the trash block is the
-    pages'. Nobody zeroes a slot when a sequence leaves it: a chunk that
-    starts at position 0 starts from a zero state and a zero tail, whoever
-    held the slot before. The kind owns the tail, the state, the scan or the
-    update, and the writes; what a model's parameters are called and how its
-    step is made is the policy's. A block hands its ``attend`` plain arrays,
-    as an attention layer hands q, k and v: ``xbc`` [N, C] (the mixer's
-    projection before the convolution, one row a token), ``step`` [N, H]
-    float32 (> 0: after the model's softplus), the convolution's ``kernel``
-    [C, K] and ``bias`` [C], ``a_log`` [H] (``A = -exp(a_log)``) and the skip
-    ``d`` [H]; it gets back ``y`` [N, H * P] with the skip term in it."""
+class _TailSlots:
+    """The layers that keep no pages: what a sequence leaves in one of them
+    does not grow with its context, one SLOT a sequence and no block table.
+    This class is the kind whose slot is the TAIL of a causal convolution and
+    nothing else (``StateSlotShape.tail_only``: a gated short convolution of
+    ``K`` taps looks ``K - 1`` rows back and keeps no other state), a pool
+    ``{"conv": [L_state, slots + 1, rows, lanes]}`` in the compute type (as
+    ``ops/pallas/ssm_update.py`` stores a tail, ``tail_stored``: 8 KB a layer
+    a sequence at 2 x 2,048 bfloat16); ``_StateSlots`` below adds a
+    recurrent state to it. A sequence's slot is the engine's
+    ``SequenceDescriptor.slot``, held from ``create`` to ``pop``; the slot
+    past the last is padding's, as the trash block is the pages'. Nobody
+    zeroes a slot when a sequence leaves it: a chunk that starts at position
+    0 starts from a zero tail, whoever held the slot before. The kind owns
+    the tail, the taps' sum (then ``activation``: none here) and the write;
+    what is multiplied in before and after the
+    convolution, and both projections, are the policy's. A block hands its
+    ``attend`` plain arrays: ``x`` [N, C] (one row a token, what the
+    convolution runs over) and the convolution's ``kernel`` [C, K]; it gets
+    back the convolved rows [N, C] in ``x``'s type."""
+    #: what follows the taps' sum, by ``ops/ssm.py`` ``ACTIVATIONS``' name
+    activation = None
 
     def __init__(self, shape: StateSlotShape):
         self.shape = shape
@@ -1110,20 +1131,96 @@ class _StateSlots:
     # half every chunk program carries was a second of host time a program
     # at 40 layers: PERF.md section 6, PR 45)
     def __hash__(self):
-        return hash(self.shape)
+        return hash((type(self), self.shape))
 
     def __eq__(self, other):
         return type(other) is type(self) and other.shape == self.shape
+
+    def row_operands(self, layer: int) -> int:
+        """``x`` (``_Pages.row_operands``)."""
+        return 1
+
+    def empty(self, layers: int, slots: int, dtype):
+        return {"conv": jnp.zeros(
+            (layers, slots + 1) + self.shape.tail_stored, dtype)}
+
+    def _held_tail(self, tails, layer, slot, fresh):
+        """The slot's tail [K - 1, C], zeros where the chunk is ``fresh``."""
+        return _ssm_update.unpack_tail(
+            jnp.where(fresh, 0, tails[layer, slot]),
+            self.shape.conv_width, self.shape.conv_channels)
+
+    def _new_tail(self, rows, true_len):
+        """The tail a chunk leaves, of ``causal_conv``'s joined ``rows``: cut
+        behind the last REAL row, so that bucket padding moves nothing."""
+        return jax.lax.dynamic_slice_in_dim(rows, true_len,
+                                            self.shape.conv_width - 1)
+
+    def _tail_written(self, tails, layer, slot, tail):
+        return tails.at[layer, slot].set(
+            _ssm_update.pack_tail(tail, self.shape.tail_stored))
+
+    def _decode_conv(self, tails, layer, slots, attn_impl, x, kernel, bias):
+        """One token a row: each row's tail shifted by its token, by the
+        Pallas kernel that moves the rows' slots alone (or gather, shift,
+        scatter: ``attn_impl``). Returns (the convolved rows [B, C] in ``x``'s
+        type, the tails' pool)."""
+        impl = _resolve_impl(attn_impl)
+        how = {} if impl == "gather" else \
+            dict(interpret=impl == "kernel_interpret")
+        shift = _ssm_update.ssm_conv_step_reference if impl == "gather" \
+            else _ssm_update.ssm_conv_step
+        return shift(tails, layer, slots, x, kernel, bias,
+                     activation=self.activation, **how)
+
+    @partial(jax.jit, static_argnames=("self", "attn_impl"))
+    def attend_chunk(self, cache, layer, slots, attn_impl, x, kernel):
+        """One sequence's chunk: the convolution over its rows behind the
+        slot's tail, and the new tail written back."""
+        slot, valid, fresh = slots
+        true_len = jnp.sum(valid)
+        with jax.named_scope("conv/shift"):
+            conv, rows = ssm.causal_conv(
+                x, self._held_tail(cache["conv"], layer, slot, fresh), kernel,
+                activation=self.activation)
+            return conv.astype(x.dtype), {"conv": self._tail_written(
+                cache["conv"], layer, slot, self._new_tail(rows, true_len))}
+
+    @partial(jax.jit, static_argnames=("self", "attn_impl"))
+    def attend_decode(self, cache, layer, slots, attn_impl, x, kernel):
+        """One token a sequence: each row's tail shifted by its token."""
+        with jax.named_scope("conv/shift"):
+            conv, tails = self._decode_conv(
+                cache["conv"], layer, slots, attn_impl, x, kernel,
+                jnp.zeros(x.shape[1:], jnp.float32))
+        return conv, {"conv": tails}
+
+
+class _StateSlots(_TailSlots):
+    """A slot that holds a Mamba-2 layer's recurrent state beside the tail
+    (``StateSlotShape`` with heads): ``{"ssm": [L_state, slots + 1, G, N, W]
+    float32, "conv": [L_state, slots + 1, rows, lanes]}`` (as
+    ``ops/pallas/ssm_update.py`` stores a state and a tail, ``stored`` and
+    ``tail_stored``), the slots and their bookkeeping ``_TailSlots``'s. A
+    chunk that starts at position 0 starts from a zero state and a zero tail.
+    The kind owns the tail, the state, the scan or the update, and the
+    writes; what a model's parameters are called and how its step is made is
+    the policy's. A block hands its ``attend`` plain arrays, as an attention
+    layer hands q, k and v: ``xbc`` [N, C] (the mixer's projection before the
+    convolution, one row a token), ``step`` [N, H] float32 (> 0: after the
+    model's softplus), the convolution's ``kernel`` [C, K] and ``bias`` [C],
+    ``a_log`` [H] (``A = -exp(a_log)``) and the skip ``d`` [H]; it gets back
+    ``y`` [N, H * P] with the skip term in it."""
+    activation = "silu"
 
     def row_operands(self, layer: int) -> int:
         """``xbc`` and ``step`` (``_Pages.row_operands``)."""
         return 2
 
     def empty(self, layers: int, slots: int, dtype):
-        at = self.shape
-        return {"ssm": jnp.zeros((layers, slots + 1) + at.stored, jnp.float32),
-                "conv": jnp.zeros((layers, slots + 1) + at.tail_stored,
-                                  dtype)}
+        return {"ssm": jnp.zeros((layers, slots + 1) + self.shape.stored,
+                                 jnp.float32),
+                **super().empty(layers, slots, dtype)}
 
     def _split(self, conv):
         at = self.shape
@@ -1141,12 +1238,10 @@ class _StateSlots:
         slot, valid, fresh = slots
         true_len = jnp.sum(valid)
         with jax.named_scope("ssm/conv"):
-            tail = _ssm_update.unpack_tail(
-                jnp.where(fresh, 0, cache["conv"][layer, slot]),
-                at.conv_width, at.conv_channels)
-            conv, rows = ssm.causal_conv(xbc, tail, kernel, bias)
-            tail = jax.lax.dynamic_slice_in_dim(rows, true_len,
-                                                at.conv_width - 1)
+            conv, rows = ssm.causal_conv(
+                xbc, self._held_tail(cache["conv"], layer, slot, fresh),
+                kernel, bias)
+            tail = self._new_tail(rows, true_len)
             x, bm, cm = self._split(conv.astype(xbc.dtype))
         # the slot's state in and out by a kernel wherever there is a TPU,
         # whatever ``attn_impl`` says: a slice of the pool invites XLA there
@@ -1165,8 +1260,8 @@ class _StateSlots:
             s = _ssm_update.pack_state(s, at.pack)
             states = cache["ssm"].at[layer, slot].set(s) if sliced else \
                 _ssm_update.slot_write(cache["ssm"], layer, slot, s, **how)
-            cache = {"ssm": states, "conv": cache["conv"].at[
-                layer, slot].set(_ssm_update.pack_tail(tail, at.tail_stored))}
+            cache = {"ssm": states, "conv": self._tail_written(
+                cache["conv"], layer, slot, tail)}
         return y.reshape(y.shape[0], -1).astype(xbc.dtype), cache
 
     @partial(jax.jit, static_argnames=("self", "attn_impl"))
@@ -1179,10 +1274,8 @@ class _StateSlots:
         how = {} if impl == "gather" else \
             dict(interpret=impl == "kernel_interpret")
         with jax.named_scope("ssm/conv"):
-            shift = _ssm_update.ssm_conv_step_reference if impl == "gather" \
-                else _ssm_update.ssm_conv_step
-            conv, tails = shift(cache["conv"], layer, slots, xbc, kernel,
-                                bias, **how)
+            conv, tails = self._decode_conv(cache["conv"], layer, slots,
+                                            attn_impl, xbc, kernel, bias)
             x, bm, cm = self._split(conv)
         with jax.named_scope("ssm/update"):
             update = _ssm_update.ssm_update_reference if impl == "gather" \
@@ -1201,8 +1294,10 @@ class _LayerKindPages:
     each pool with its own trash block, allocator and block tables (the step
     programs carry ``{"full": table, "window": table}`` likewise);
     ``{"full": ..., "state": {"ssm", "conv"}}`` for one some of whose layers
-    keep a recurrent state (``_StateSlots``: a slot and no block table; the
-    step programs' ``"state"`` entry IS the slot); a layer of kind ``"none"``
+    keep a recurrent state and a convolution's tail (``_StateSlots``: a slot
+    and no block table; the step programs' ``"state"`` entry IS the slot),
+    ``{"full": ..., "state": {"conv"}}`` where the tail is all such a layer
+    keeps (``_TailSlots``: no pool of states exists); a layer of kind ``"none"``
     keeps nothing and is in no pool. The kinds are data: a
     layer's is ``layer_kinds[l]`` where the spec names them and follows from
     ``layer_windows[l]`` where it does not, and a kind that no layer has
@@ -1244,7 +1339,8 @@ class _LayerKindPages:
         self.pages = {kind: pages(kind, window)
                       for kind, window in ((FULL, None), (WINDOW, self.window))
                       if kind in self.kinds}
-        self.state = _StateSlots(state_slot) if state_slot else None
+        self.state = state_slot and (
+            _StateSlots if state_slot.recurrent else _TailSlots)(state_slot)
 
     def shape(self, kind: str, cfg: KVCacheConfig) -> HeadPageShape:
         """What ``kind``'s page holds of a token a layer."""

@@ -761,11 +761,12 @@ def _latent_attention(lp, x, attend, positions, cfg):
         return jnp.einsum("thv,hvd->td", o, ap["wo"]["kernel"].astype(dtype))
 
 
-def _dense_or_experts(lp, i, x, cfg, valid):
+def _dense_or_experts(lp, i, x, cfg, valid, route=route):
     """(what layer ``i``'s second sublayer adds to ``x`` [N, D], its own
     pre-norm inside, the layer's counts or None): the gated MLP of a dense
     layer, else the chosen routed experts of those held here (the router's
-    ``first_expert ..``) and the shared one where there is one."""
+    ``first_expert ..``, by the family's ``route``) and the shared one where
+    there is one."""
     dtype = cfg.dtype
     h2 = _rms(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps)
     if cfg.is_dense(i):
@@ -1018,6 +1019,33 @@ def _mamba_mixer(mp, norm_scale, x, attend, cfg):
         return y @ mp["out_proj"].astype(dtype)
 
 
+def _packed_heads(q, k, v, cfg, scale: float):
+    """Heads narrower than the TPU's 128 lanes, ``cfg.kv_pack`` KV heads to a
+    page's row (their keys side by side, and their values): (q [N, H, d *
+    pack] with zeros in the other heads' lanes, so that a query head's score
+    against the row is its own head's exactly, times ``scale`` over the
+    pages' own ``(row width) ** -0.5``; k, v [N, H_kv / pack, d * pack];
+    ``own`` [H, d * pack], a query head's lanes of its row)."""
+    pack, d = cfg.kv_pack, cfg.head_dim
+    q = q * jnp.asarray(scale * (d * pack) ** 0.5, q.dtype)
+    # query head i reads KV head i // rep, which lies in lanes
+    # ``lane .. lane + d`` of packed head i // (rep * pack)
+    rep = cfg.num_heads // cfg.num_kv_heads
+    lane = (jnp.arange(cfg.num_heads) // rep % pack) * d
+    own = (jnp.arange(d * pack) // d * d)[None, :] \
+        == lane[:, None]                                      # [H, d * pack]
+    q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
+    k, v = (t.reshape(t.shape[0], -1, d * pack) for t in (k, v))
+    return q, k, v, own
+
+
+def _own_lanes(attn, own, cfg):
+    """A query head's output is its own head's lanes of the row's: [N, H,
+    d * pack] -> [N, H, d]."""
+    return jnp.sum(jnp.where(own, attn, 0).reshape(
+        attn.shape[0], cfg.num_heads, cfg.kv_pack, cfg.head_dim), axis=2)
+
+
 def _state_slot(cfg) -> StateSlotShape:
     """What a Mamba-2 layer of ``cfg`` keeps of a sequence."""
     return StateSlotShape(
@@ -1071,24 +1099,12 @@ class GraniteHybridPolicy:
         else:
             with jax.named_scope("attn/qkv"):
                 h = _rms(x, lp["mixer_norm"]["scale"], eps)
-                q, k, v = _qkv(lp, h, dtype)
-                pack, d = cfg.kv_pack, cfg.head_dim
-                # the pages' attention scores at (row width) ** -0.5
-                q = q * jnp.asarray(
-                    cfg.attention_multiplier * (d * pack) ** 0.5, dtype)
-                # query head i reads KV head i // rep, which lies in lanes
-                # ``lane .. lane + d`` of packed head i // (rep * pack)
-                rep = cfg.num_heads // cfg.num_kv_heads
-                lane = (jnp.arange(cfg.num_heads) // rep % pack) * d
-                own = (jnp.arange(d * pack) // d * d)[None, :] \
-                    == lane[:, None]                          # [H, d * pack]
-                q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
-                k, v = (t.reshape(t.shape[0], -1, d * pack) for t in (k, v))
+                q, k, v, own = _packed_heads(*_qkv(lp, h, dtype), cfg,
+                                             cfg.attention_multiplier)
             attn = attend(q, k, v)
             with jax.named_scope("attn/out"):
-                attn = jnp.sum(jnp.where(own, attn, 0).reshape(
-                    attn.shape[0], cfg.num_heads, pack, d), axis=2)
-                x = x + r * jnp.einsum("thk,hkd->td", attn,
+                x = x + r * jnp.einsum("thk,hkd->td",
+                                       _own_lanes(attn, own, cfg),
                                        lp["attn"]["wo"]["kernel"].astype(dtype))
         with jax.named_scope("mlp"):
             h2 = _rms(x, lp["mlp_norm"]["scale"], eps)
@@ -1170,3 +1186,94 @@ class NemotronHPolicy:
         x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
         return x.astype(jnp.float32) @ \
             params["lm_head"]["kernel"].astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# LFM2-MoE (gated short convolutions that keep a tail and no state, GQA with
+# a norm a head on q and k, routed experts behind EVERY mixer)
+# ---------------------------------------------------------------------------
+from deepspeed_tpu.models import lfm2_moe as _lfm2  # noqa: E402
+
+
+def _short_conv_mixer(cp, norm_scale, x, attend, cfg):
+    """What a gated short-convolution layer adds to ``x`` [N, D], its own
+    pre-norm inside: both projections and both gates are computed here (``B *
+    v`` before the convolution, ``C *`` behind it) and the gated rows handed
+    to ``attend`` with the taps (``kv_cache._TailSlots``: the taps' sum
+    behind the slot's tail, no activation, the new tail written)."""
+    dtype = cfg.dtype
+    with jax.named_scope("conv/in_proj"):
+        h = _rms(x, norm_scale, cfg.rms_norm_eps)
+        b, c, v = jnp.split(h @ cp["in_proj"].astype(dtype), 3, axis=-1)
+        gated = b * v
+    conv = attend(gated, cp["conv_kernel"])
+    with jax.named_scope("conv/out_proj"):
+        return (c * conv) @ cp["out_proj"].astype(dtype)
+
+
+@register_policy("lfm2_moe", _lfm2.Lfm2MoeConfig)
+class Lfm2MoePolicy:
+    """models/lfm2_moe.py's serving twin: a layer is a mixer, then a dense
+    MLP or the chosen routed experts (``_dense_or_experts``, JoyAI's, behind
+    the family's own ``route``), so an expert layer sits behind a state
+    layer in ONE layer. ``cache_spec`` names every layer's kind: ``state`` (a
+    conv layer, whose slot is the convolution's TAIL alone,
+    ``StateSlotShape.tail_only``: 2 rows of ``hidden_size`` and no recurrent
+    state, so the cache allocates no pool of states) or ``full`` (an
+    attention layer's pages, two KV heads of 64 to a row: granite's
+    ``kv_pack``). A conv layer's ``block`` computes both projections and both
+    gates (``_short_conv_mixer``) and hands the kind the gated rows and the
+    taps: the kind runs the three-tap sum behind the slot's tail, with no
+    activation, and writes the new tail. An attention layer's q and k are
+    RMS-normed a head before rope."""
+
+    @staticmethod
+    def cache_spec(cfg) -> KVCacheSpec:
+        pack = cfg.kv_pack
+        return KVCacheSpec(
+            cfg.num_layers, cfg.num_kv_heads // pack, cfg.head_dim * pack,
+            cfg.max_seq_len, cfg.dtype, None, query_heads=cfg.num_heads,
+            layer_kinds=tuple("state" if cfg.is_conv(i) else "full"
+                              for i in range(cfg.num_layers)),
+            state_slot=StateSlotShape.tail_only(cfg.conv_width,
+                                                cfg.hidden_size))
+
+    @staticmethod
+    def embed(params, tokens, positions, cfg):
+        return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+    @staticmethod
+    def block(params, i, x, attend, positions, cfg, valid):
+        lp = params[f"layer_{i}"]
+        dtype, eps = cfg.dtype, cfg.rms_norm_eps
+        if cfg.is_conv(i):
+            x = x + _short_conv_mixer(lp["conv"], lp["mixer_norm"]["scale"],
+                                      x, attend, cfg)
+        else:
+            ap = lp["attn"]
+            with jax.named_scope("attn/qkv"):
+                q, k, v = _qkv(lp, _rms(x, lp["mixer_norm"]["scale"], eps),
+                               dtype)
+            with jax.named_scope("attn/qk_norm"):
+                q = _lfm2.head_norm(q, ap["q_norm"], eps)
+                k = _lfm2.head_norm(k, ap["k_norm"], eps)
+            with jax.named_scope("attn/qkv"):
+                cos, sin = _rope_tables(cfg.head_dim, cfg.max_seq_len,
+                                        cfg.rope_theta)
+                q, k, v, own = _packed_heads(
+                    _rope_rows(q, cos, sin, positions),
+                    _rope_rows(k, cos, sin, positions), v, cfg,
+                    cfg.head_dim ** -0.5)
+            attn = attend(q, k, v)
+            with jax.named_scope("attn/out"):
+                x = x + jnp.einsum("thk,hkd->td", _own_lanes(attn, own, cfg),
+                                   ap["wo"]["kernel"].astype(dtype))
+        y, counts = _dense_or_experts(lp, i, x, cfg, valid, _lfm2.route)
+        return x + y, counts
+
+    @staticmethod
+    def unembed(params, x, cfg):
+        x = _rms(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return jnp.einsum("nd,vd->nv", x,
+                          params["embed"]["embedding"].astype(x.dtype),
+                          preferred_element_type=jnp.float32)

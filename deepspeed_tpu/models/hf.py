@@ -152,6 +152,8 @@ _REGISTRY = {
     "nemotron_h": _family_entry("nemotron_h", "nemotron_h_config_from_hf",
                                 "NemotronHForCausalLM",
                                 "convert_hf_nemotron_h"),
+    "lfm2_moe": _family_entry("lfm2_moe", "lfm2_moe_config_from_hf",
+                              "Lfm2MoeForCausalLM", "convert_hf_lfm2_moe"),
     "falcon": _family_entry("falcon", _falcon_config, "FalconForCausalLM",
                             "convert_hf_falcon"),
     "opt": _family_entry("opt", _opt_config, "OPTForCausalLM",

@@ -29,7 +29,10 @@ are batch padding name the pool's last slot, which no sequence holds.
 tail (the last ``K - 1`` rows of the layer's convolution input, in the cache's
 type) read, shifted by the row's token and written, by copies the kernel
 issues itself out of and into the rows' slots of a pool that stays in HBM
-whole; ``ssm_conv_step_reference`` is its oracle.
+whole; ``ssm_conv_step_reference`` is its oracle. What follows the taps' sum
+is the caller's to say (``activation``: ``"silu"``, a Mamba-2 layer's, or
+None, a gated short convolution's, whose slot is such a tail and nothing
+else), so the same kernel moves the tails of both.
 """
 
 import functools
@@ -39,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.ssm import ACTIVATIONS
 
 LANES = 128
 #: groups of heads a grid step updates: [GROUP_TILE, d_state, W] float32 is
@@ -188,8 +193,10 @@ def _update_call(slots, layer, a, xdt, bm, cm, pool, *, interpret: bool):
 def tail_stored(width: int, channels: int):
     """A slot's tail as the pool stores it: [rows, lanes], the ``(K - 1) * C``
     values in their order over 128 lanes and zero rows up to a whole tile of
-    8 (a copy names whole tiles: 102 rows are 104 at granite's 3 x 4,352);
-    at toy widths, where the channels fill no lanes, one row a tap."""
+    8 (a copy names whole tiles: 102 rows are 104 at granite's 3 x 4,352,
+    Nemotron-H's 3 x 6,144 are 144 as they are, and a short convolution's
+    2 x 2,048 are 32, 16 a tap); at toy widths, where the channels fill no
+    lanes, one row a tap."""
     if channels % LANES:
         return (width - 1, channels)
     return (-(-(width - 1) * channels // LANES // 8) * 8, LANES)
@@ -212,18 +219,19 @@ def unpack_tail(stored, width: int, channels: int):
         lead + (width - 1, channels))
 
 
-def ssm_conv_step_reference(pool, layer, slots, xbc, taps, bias):
+def ssm_conv_step_reference(pool, layer, slots, xbc, taps, bias,
+                            activation="silu"):
     """pool: [L, slots, rows, lanes] (``tail_stored``); slots: [B] int32;
     xbc: [B, C]; taps: [C, K]; bias: [C]. Each row's tail shifted by its
     token: returns (the convolved rows [B, C] in ``xbc``'s type, after
-    ``silu``; the pool). Tap by tap in float32, as ``ssm.causal_conv`` sums
-    a chunk's."""
+    ``activation``, ``"silu"`` or None; the pool). Tap by tap in float32, as
+    ``ssm.causal_conv`` sums a chunk's."""
     c, width = taps.shape
     used = (width - 1) * c // pool.shape[-1]     # the rest are a tile's zeros
     tails = unpack_tail(pool[layer, slots], width, c)
     rows = jnp.concatenate([tails, xbc[:, None]], axis=1)
     w = taps.astype(jnp.float32)
-    conv = jax.nn.silu(sum(
+    conv = ACTIVATIONS[activation](sum(
         rows[:, j].astype(jnp.float32) * w[:, j] for j in range(width))
         + bias.astype(jnp.float32)).astype(xbc.dtype)
     return conv, pool.at[layer, slots, :used].set(
@@ -236,7 +244,7 @@ CONV_ROWS = 16
 
 def _conv_kernel(slots_ref, layer_ref, x_ref, w_ref, bias_ref, pool_ref,
                  y_ref, pool_out_ref, held, shifted, sems, *, rows: int,
-                 width: int):
+                 width: int, activation):
     """Step ``i`` of the grid: the tails of rows ``i * rows ..`` are in
     ``held[i % 2]`` (copied there while step ``i - 1`` computed), the step's
     new tails go from ``shifted[i % 2]`` while step ``i + 1`` computes."""
@@ -279,7 +287,7 @@ def _conv_kernel(slots_ref, layer_ref, x_ref, w_ref, bias_ref, pool_ref,
             acc = acc + held[buf, r, j * cr:(j + 1) * cr].astype(
                 jnp.float32) * w_ref[j]
         acc = acc + x.astype(jnp.float32) * w_ref[width - 1] + bias_ref[...]
-        y_ref[r] = jax.nn.silu(acc).astype(y_ref.dtype)
+        y_ref[r] = ACTIVATIONS[activation](acc).astype(y_ref.dtype)
         if width > 2:
             shifted[buf, r, 0:(width - 2) * cr] = \
                 held[buf, r, cr:(width - 1) * cr]
@@ -302,7 +310,7 @@ def _conv_kernel(slots_ref, layer_ref, x_ref, w_ref, bias_ref, pool_ref,
             copies(wait, i - 1, 1 - buf, True)
 
 
-def ssm_conv_step(pool, layer, slots, xbc, taps, bias,
+def ssm_conv_step(pool, layer, slots, xbc, taps, bias, activation="silu",
                   interpret: bool = False):
     """``ssm_conv_step_reference`` as one Pallas call that copies the rows'
     slots' tails alone, in place: the pool stays in HBM whole."""
@@ -312,12 +320,13 @@ def ssm_conv_step(pool, layer, slots, xbc, taps, bias,
     conv, pool = _conv_call(
         slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
         xbc.reshape(b, c // lanes, lanes), laid(taps.T), laid(bias)[0], pool,
-        interpret=interpret)
+        activation=activation, interpret=interpret)
     return conv.reshape(b, c), pool
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _conv_call(slots, layer, x, taps, bias, pool, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("activation", "interpret"))
+def _conv_call(slots, layer, x, taps, bias, pool, *, activation,
+               interpret: bool):
     """The kernel call, under a ``jit`` of its own with the layer a value
     (``_update_call``)."""
     b, cr, lanes = x.shape
@@ -329,7 +338,8 @@ def _conv_call(slots, layer, x, taps, bias, pool, *, interpret: bool):
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     tails = (2, rows) + pool.shape[2:]
     return pl.pallas_call(
-        functools.partial(_conv_kernel, rows=rows, width=width),
+        functools.partial(_conv_kernel, rows=rows, width=width,
+                          activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b // rows,),
             in_specs=[block, whole(width, cr, lanes), whole(cr, lanes),

@@ -1,6 +1,7 @@
 """The selective state-space recurrence of Mamba-2 (the SSD form: a scalar
 decay a head), in plain ``jax.numpy``, and the causal depthwise convolution
-before it.
+before it (which a gated short-convolution layer runs alone, without the
+activation: ``causal_conv``).
 
 A head's state ``S`` is ``[head_dim, d_state]``; a token brings ``x``
 ``[head_dim]``, a step ``dt > 0``, and ``B``, ``C`` ``[groups, d_state]``:
@@ -35,19 +36,25 @@ F32 = jnp.float32
 _EXACT = jax.lax.Precision.HIGHEST
 
 
-def causal_conv(x, tail, weight, bias):
-    """Depthwise causal convolution of width ``K`` and silu over one
-    sequence's rows. x: [T, C]; tail: [K - 1, C], the rows before them;
-    weight: [C, K] (``weight[:, K - 1]`` meets the row itself); bias: [C].
-    Returns ([T, C] float32, the rows with the tail before them [T + K - 1,
-    C], which the next call's tail is cut from)."""
+#: what may follow a convolution's taps' sum, by the name a caller gives it
+ACTIVATIONS = {"silu": jax.nn.silu, None: lambda v: v}
+
+
+def causal_conv(x, tail, weight, bias=None, activation="silu"):
+    """Depthwise causal convolution of width ``K`` over one sequence's rows,
+    then ``activation`` (``"silu"``, a Mamba-2 layer's; None: the sum as it
+    is, a gated short convolution's). x: [T, C]; tail: [K - 1, C], the rows before
+    them; weight: [C, K] (``weight[:, K - 1]`` meets the row itself); bias:
+    [C] or None. Returns ([T, C] float32, the rows with the tail before them
+    [T + K - 1, C], which the next call's tail is cut from)."""
     k = weight.shape[-1]
     rows = jnp.concatenate([tail.astype(x.dtype), x], axis=0)
     w = weight.astype(F32)
     t = x.shape[0]
-    out = bias.astype(F32) + sum(
-        rows[j:j + t].astype(F32) * w[:, j] for j in range(k))
-    return jax.nn.silu(out), rows
+    out = sum(rows[j:j + t].astype(F32) * w[:, j] for j in range(k))
+    if bias is not None:
+        out = bias.astype(F32) + out
+    return ACTIVATIONS[activation](out), rows
 
 
 def split_conv(rows, heads: int, head_dim: int, d_state: int,

@@ -255,14 +255,20 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: the causal convolution behind the slot's tail, a prefill chunk's
 #: closed-form scan from the slot's state, a decode batch's in-place update
 #: of each row's state (``kv_cache._StateSlots``), the gated norm, the
-#: second projection
+#: second projection; the ``conv/*`` three a gated short convolution (LFM2):
+#: the norm, the first projection and the gate before the convolution, the
+#: tail's read, the taps' sum and the tail's write in a chunk and a decode
+#: batch alike (``kv_cache._TailSlots``), the gate behind the convolution and
+#: the second projection; ``attn/qk_norm`` the norm a head of q and of k
+#: before rope
 SERVED_SCOPES: Tuple[str, ...] = (
     "embed", "attn/qkv", "attn/kv_write", "attn/paged", "attn/out",
     "attn/full", "attn/window", "attn/gate", "attn/latent_q", "attn/latent_write", "attn/latent_paged",
     "attn/latent_prefill", "mlp", "moe/router", "moe/experts", "moe/shared",
     "lm_head", "sample", "hc/pre", "hc/post", "hc/head",
     "ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/update", "ssm/norm",
-    "ssm/out_proj")
+    "ssm/out_proj", "conv/in_proj", "conv/shift", "conv/out_proj",
+    "attn/qk_norm")
 
 #: counts a step program computes on the device where its policy's layers
 #: count (``generic_decode.py``), in the order of the int32 vector it hands

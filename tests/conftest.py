@@ -40,6 +40,19 @@ jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _tracer_left_as_found():
+    """A test file hands the global tracer on as it found it: a worker runs
+    one file after another, and a file that ends with tracing on (the
+    benchmark's traced toy cells do) is not the next file's to mend."""
+    from deepspeed_tpu.telemetry import get_tracer
+    tracer = get_tracer()
+    was = tracer.enabled
+    yield
+    if tracer.enabled != was:
+        tracer.configure(enabled=was)
+
+
 @pytest.fixture
 def mesh8():
     """data=2, fsdp=4 mesh over the 8 virtual devices."""

@@ -910,3 +910,78 @@ def test_nemotron_h_step_program_reads_its_experts_where_they_lie(
             if re.search(r"= \(?bf16\[64,(1856,2688|2688,1856)\]\S* "
                          r"(copy|fusion)\(", line)] == []
     assert len(jax.tree.leaves(compiled.out_info)) == 1 + 3 + 1
+
+
+# --- a tail-only state kind, experts behind every mixer -----------------------
+# LFM2-24B-A2B at its published widths: one dense conv layer, then an
+# attention layer and a conv layer with all 64 experts behind each, the cell's
+# 256 slots and 10,241 blocks, so that a compile takes seconds.
+
+@pytest.mark.parametrize("fn_name", ["decode_step_g", "prefill_chunk_g"])
+def test_lfm2_moe_step_program_keeps_tails_and_pages_and_no_states(
+        one_chip, as_on_a_tpu, fn_name):
+    """The 2,048-token chunk and the 256-row decode program: the pool is the
+    attention layer's pages (two KV heads of 64 to a row) and the conv
+    layers' tails, 32 rows of 128 lanes a slot, and NO pool of states; both
+    are aliased in the executable; the experts behind a state layer and
+    behind an attention layer go through the grouped kernels; a decode step
+    shifts its 256 rows' tails by slot in ``ssm_conv_step`` (traced once,
+    called a layer, without an activation) and nothing else touches the
+    tails' pool."""
+    from deepspeed_tpu.inference.v2.kv_cache import BlockedKVCache
+    from deepspeed_tpu.models import lfm2_moe as lfm2
+    cfg = lfm2.Lfm2MoeConfig(
+        layer_types=(lfm2.CONV, lfm2.ATTENTION, lfm2.CONV),
+        num_dense_layers=1, max_seq_len=2560)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: cast_to_compute(lfm2.Lfm2MoeForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+            cfg.dtype), jax.random.PRNGKey(0)))
+    policy = policy_for(cfg)
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: BlockedKVCache.for_spec(policy.cache_spec(cfg), "model", 64,
+                                        10241, state_slots=256).pool))
+    assert jax.tree.map(lambda x: x.shape, pool) == {
+        "full": (1, 2, 4, 10241, 64, 128),
+        "state": {"conv": (2, 257, 32, 128)}}
+    pool_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                     for x in jax.tree.leaves(pool))
+    tail = _tail(
+        fn_name,
+        (ints(2048), ints(), {"full": ints(40), "state": ints()}, ints()),
+        (ints(256), ints(256), {"full": ints(256, 40), "state": ints(256)},
+         jax.ShapeDtypeStruct((256,), jnp.bool_, sharding=one_chip)))
+    lowered = _program(fn_name).lower(
+        params, pool, *tail, policy=policy, cfg=cfg, block_size=64,
+        attn_impl="kernel")
+    compiled = lowered.compile()
+    stats, text = compiled.memory_analysis(), compiled.as_text()
+    assert stats.alias_size_in_bytes >= pool_bytes
+    assert "may-alias" in text.splitlines()[0]
+    for kernel in ("paged_attention", "grouped_matmul_gate_up",
+                   "grouped_matmul"):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    assert not kernels & {"ssm_update", "ssm_slot_read", "ssm_slot_write"}
+    if fn_name == "decode_step_g":
+        assert lowered.as_text().count('kernel_name = "ssm_conv_step"') == 1
+        assert text.count("%ssm_conv_step") >= 1
+        assert _makers_of(text, "bf16[2,257,32,128]") == []
+    else:
+        assert "ssm_conv_step" not in kernels
+    # beside its arguments a step holds activations: no second copy of an
+    # expert stack (403 MB) or of a pool
+    assert stats.temp_size_in_bytes < 512 << 20
+    entry = text[text.index("\nENTRY"):]
+    assert [line.strip()[:120] for line in entry.splitlines()
+            if re.search(r"= \(?bf16\[64,(1536,2048|2048,1536)\]\S* "
+                         r"(copy|fusion)\(", line)] == []
+    assert len(jax.tree.leaves(compiled.out_info)) == 1 + 2 + 1
