@@ -436,10 +436,14 @@ class BlockedKVCache:
         with the layers that have a ``window`` reading theirs (a windowed
         kind's table starts behind the window and is as long as it). Each
         over ``ctx_tokens`` / ``ctx_tokens_windowed`` is the tiles' fill.
-        Empty over a latent pool, which the paged kernel does not read."""
+        Over a latent pool, which the paged kernel does not read, the keys
+        of the latent decode kernel's tiles instead (``latent_tile_keys``,
+        ``latent_attention.decode_tile_keys``)."""
         bs = self.cfg.block_size
         if self.cfg.latent_dim:
-            return {}
+            from deepspeed_tpu.ops.pallas import latent_attention
+            return {"latent_tile_keys": latent_attention.decode_tile_keys(
+                contexts, table_blocks, bs)}
         whole = windowed = decode_tile_keys(contexts, table_blocks, bs)
         if window is not None:
             last, mb = self._behind_window([c - 1 for c in contexts], 1,
@@ -454,10 +458,14 @@ class BlockedKVCache:
         ``table_blocks``: ``slot_copies`` a call of a layer that walks the
         full table, and ``slot_copies_windowed`` a call of a layer behind
         ``window`` (a windowed kind's over its own table), each from the
-        heads and the row widths of the kind's own pages. Empty over a
-        latent pool."""
+        heads and the row widths of the kind's own pages. Over a latent
+        pool ``latent_page_copies``, the latent decode kernel's
+        (``latent_attention.decode_page_copies``: one a live page, a page
+        has no heads and its row is key and value)."""
         if self.cfg.latent_dim:
-            return {}
+            from deepspeed_tpu.ops.pallas import latent_attention
+            return {"latent_page_copies": latent_attention.decode_page_copies(
+                contexts, bucket, table_blocks, self.cfg.block_size)}
         whole = windowed = decode_slot_copies(
             contexts, bucket, mb=table_blocks, **self._fold_of("full"))
         if window is not None:

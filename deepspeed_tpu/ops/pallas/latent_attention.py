@@ -16,15 +16,57 @@ out of every step; the tiled row-major layout pads the row in memory anyway.
 **Decode** (``latent_paged_attention``): the key up-projection is folded into
 the query (``q~ = W_uk^T q_nope``) and the value up-projection into the
 output, so a head scores against the whole row and sums its first ``rank``
-values: one tile read serves both matmuls and all heads. Grid (sequence, page
-group), page groups innermost, online-softmax accumulators in VMEM scratch,
-block tables and positions in scalar prefetch as in ``paged_attention.py``.
-Two things differ from that kernel: ``pages`` pages are fetched a grid step
-(the pool is handed to the call that many times, each with its own index
-map), so the per-step overhead is paid once for ``pages * block_size`` keys;
-and a page past a sequence's last live one maps to the last live one, whose
-block index equals the step before's, so the pipeline fetches nothing and a
-short sequence in a long table costs its own pages only.
+values: one tile read serves both matmuls and all heads. **A grid step is one
+sequence, and the kernel walks that sequence's own live pages with copies it
+starts itself**, as ``paged_attention._paged_kernel`` does: the pool goes to
+the call once, whole, as it lies in HBM (``memory_space=ANY``), block tables
+and positions ride in scalar prefetch, and inside a step a loop runs over the
+row's live tiles, ``ceil((pos // block_size + 1) / pages)`` of them: one DMA a
+live table entry straight into its place in a joined ``[pages * block_size,
+W]`` buffer, two buffers deep. The next tile's copies (after a row's last
+tile, the NEXT row's first tile) start before this tile's products and are
+waited for after them, across grid steps, which is why the grid's axis is
+``arbitrary``: in order, on one core. No step exists for a tile past the
+context; a last tile copies its live entries only (the buffer's other rows
+keep finite rows of an earlier tile, masked by position: the buffers are
+zeroed once, since a cached row is key AND value); a padding row of a batch
+(position 0, trash table) costs one page. Only a row's last tile builds and
+applies the mask (``kpos <= qpos``); the tiles below it are seen whole and
+run ``_softmax_update`` with ``mask=None``. The running maximum and sum are
+held lane-dense, as the prefill kernel's are. ``decode_tile_keys`` and
+``decode_page_copies`` count, on plain ints, what a call multiplies and
+copies.
+
+Until PR 53 the grid was (sequence, page group) over the table BUCKET, a
+tile's pages were ``pages`` pipelined operands joined with a ``concatenate``
+every step, every step built the mask and the statistics were ``[rows, 1]``
+columns: 64 rows over a 65-block bucket paid 320 grid steps whatever the
+contexts held. Swept on a v5e (PR 53; one layer's call, ms; 32 folded heads
+of 640 lanes, bfloat16, blocks of 64, contexts drawn as the cell's traffic
+draws them; "bytes" is the contexts' rows at 819 GB/s):
+
+- 64 rows over 65 blocks, contexts of 1,584 tokens in the mean (bytes 0.143):
+  parent 0.519; own copies at 4 / 8 / 16 / 32 pages a tile 0.298 / 0.231 /
+  **0.219** / 0.218;
+- 32 rows over 132 blocks, 5,820 tokens (bytes 0.262): parent 0.524; 0.507 /
+  0.375 / **0.340** / 0.344;
+- 8 rows over 16 blocks, 600 tokens: parent 0.031; 0.029 / 0.028 / 0.031 /
+  0.028; 4 rows over 32 blocks, 1,523 tokens: parent 0.029; 0.029 / 0.029 /
+  0.028 / 0.028 (a call this small is its launch: no width is told from
+  another).
+
+So a tile is ``decode_pages``: ``_KEYS_PER_STEP`` = 1,024 keys (16 pages of
+64), the table where it is shorter; static shapes alone choose. At 16 pages
+the call moves its live pages at 72% of the published bandwidth (what a plain
+elementwise pass reaches on this chip), so what is left is the bytes. Of the
+ingredients, at 16 pages (64 x 65 / 32 x 132): ``[rows, 1]`` statistics 0.225
+/ 0.344 (lane-dense is worth 2.8% / 1.0%); the mask built on every tile 0.219
+/ 0.340 (two bodies are worth nothing here: the step is bound by its copies;
+they stay because a row's last tile is a piece of code of its own anyway: it
+waits for a part tile and starts the next row); the walk over live tiles
+with the kernel's own copies is the rest (2.3x / 1.5x). Timed and dropped:
+one wait a whole tile for all its copies' bytes (0.219 / 0.340) and the copy
+loop unrolled (the same).
 
 **Prefill** (``latent_prefill_attention``): a chunk's queries against
 per-head keys ``[k_nope_i ; k_rope]`` and values of head dims 192 and 128,
@@ -68,6 +110,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -129,35 +172,112 @@ def _init_scratch(m_scr, l_scr, acc_scr):
 # ---------------------------------------------------------------------------
 # decode: folded queries over the cached rows
 # ---------------------------------------------------------------------------
-def _decode_kernel(tables_ref, pos_ref, q_ref, *refs, block_size, pages,
-                   steps, rank, scale):
-    page_refs = refs[:pages]
-    o_ref, m_scr, l_scr, acc_scr = refs[pages:]
+def decode_pages(mb: int, bs: int) -> int:
+    """Pages of one key tile of the decode kernel over tables of ``mb``
+    blocks of ``bs`` rows, from those two static sizes alone: up to
+    ``_KEYS_PER_STEP`` keys, the table where it is shorter."""
+    return max(min(_KEYS_PER_STEP // bs, mb), 1)
+
+
+def _live_pages(pos, mb: int, bs: int, xp=np):
+    """Table entries that hold a key the token at ``pos`` sees: up to its own
+    page, or the table's end. ints or arrays with ``np``, traced scalars
+    with ``jnp``."""
+    return xp.minimum(pos // bs + 1, mb)
+
+
+def decode_tile_keys(contexts, mb: int, bs: int) -> int:
+    """Keys the decode kernel's tiles cover for a batch of ``contexts``
+    tokens a sequence over tables of ``mb`` blocks: each context's live
+    pages rounded out to whole key tiles. ``contexts`` over it is the tiles'
+    fill: what the kernel multiplies against what a token sees."""
+    pages = decode_pages(mb, bs)
+    return sum(-(-int(_live_pages(c - 1, mb, bs)) // pages) * pages * bs
+               for c in contexts)
+
+
+def decode_page_copies(contexts, bucket: int, mb: int, bs: int) -> int:
+    """Page copies one layer's decode call issues for a batch of
+    ``contexts`` padded to ``bucket`` rows: one a live table entry (a
+    padding row reads the one page of position 0)."""
+    return sum(int(_live_pages(c - 1, mb, bs)) for c in contexts) \
+        + bucket - len(contexts)
+
+
+def _decode_kernel(tables_ref, pos_ref, q_ref, pool, o_ref, m_scr, l_scr,
+                   acc_scr, kv_buf, sems, slot_scr, *, block_size, pages,
+                   table_blocks, rank, scale):
+    bs, mb = block_size, table_blocks
+    keys = pages * bs
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    keys = pages * block_size
 
-    pl.when(j == 0)(lambda: _init_scratch(m_scr, l_scr, acc_scr))
-    qpos = pos_ref[b]
+    def tile_copies(do, b, count, t, slot):
+        """Start (or wait for) the copies of the first ``count`` pages of
+        tile ``t`` of sequence ``b`` into buffer ``slot``: one DMA a table
+        entry straight to its place in the joined tile."""
+        def one(p, carry):
+            page = tables_ref[b * mb + t * pages + p]
+            at = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            do(pltpu.make_async_copy(pool.at[page], kv_buf.at[slot, at],
+                                     sems.at[slot]))
+            return carry
+        jax.lax.fori_loop(0, count, one, 0)
 
-    def _compute():
+    end = _live_pages(pos_ref[b], mb, bs, jnp)
+    n = pl.cdiv(end, pages)
+
+    def live_in(end, t):
+        """Pages of tile ``t`` that are among a row's live ``end``."""
+        return jnp.minimum(end - t * pages, pages)
+
+    @pl.when(b == 0)
+    def _first_step():
+        # nobody fetched this step's first tile. A page that is not copied
+        # (past a context, in a last tile) leaves its rows of the buffer as
+        # they were: as keys they are masked, and as values they must be
+        # numbers
+        slot_scr[0] = 0
+        kv_buf[:] = jnp.zeros_like(kv_buf)
+        tile_copies(lambda c: c.start(), b, live_in(end, 0), 0, 0)
+
+    _init_scratch(m_scr, l_scr, acc_scr)
+
+    def fold(slot, masked_at):
         q = q_ref[0]                                   # [rows, W]
-        kv = page_refs[0][0] if pages == 1 else jnp.concatenate(
-            [p[0] for p in page_refs], axis=0)         # [keys, W]
-        kv = kv.astype(q.dtype)
+        kv = kv_buf[slot].astype(q.dtype)              # [keys, W]
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = j * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # causal == context-length mask
-        _online_softmax_step(s, kpos <= qpos, kv[:, :rank], m_scr, l_scr,
-                             acc_scr)
+        mask = None
+        if masked_at is not None:
+            # causal == context-length mask; past the table nothing is live
+            kpos = masked_at * keys + \
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = kpos <= jnp.minimum(pos_ref[b], mb * bs - 1)
+        _online_softmax_step(s, mask, kv[:, :rank], m_scr, l_scr, acc_scr)
 
-    pl.when(j * keys <= qpos)(_compute)
+    def whole(t, slot):
+        # a tile below the one that holds the row's own position: every page
+        # live, every key seen; the row's next tile is fetched meanwhile
+        tile_copies(lambda c: c.start(), b, live_in(end, t + 1), t + 1,
+                    1 - slot)
+        tile_copies(lambda c: c.wait(), b, pages, t, slot)
+        fold(slot, None)
+        return 1 - slot
 
-    @pl.when(j == steps - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).astype(o_ref.dtype)
+    slot = jax.lax.fori_loop(0, n - 1, whole, slot_scr[0])
+
+    # the row's last tile, and the NEXT row's first meanwhile
+    @pl.when(b + 1 < pl.num_programs(0))
+    def _next_row():
+        following = _live_pages(pos_ref[b + 1], mb, bs, jnp)
+        tile_copies(lambda c: c.start(), b + 1, live_in(following, 0), 0,
+                    1 - slot)
+
+    tile_copies(lambda c: c.wait(), b, live_in(end, n - 1), n - 1, slot)
+    fold(slot, n - 1)
+    slot_scr[0] = 1 - slot
+    l = _across(l_scr[:], acc_scr.shape[1])
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def latent_paged_attention(q, pool, layer: int, block_tables, positions,
@@ -172,55 +292,45 @@ def latent_paged_attention(q, pool, layer: int, block_tables, positions,
     value up-projection.
 
     The tokens' own rows must already be in the pages; causal masking then
-    doubles as the context-length mask."""
+    doubles as the context-length mask, and the table entries past a token's
+    own page are never read (whatever they name)."""
     b, h, w = q.shape
     nb, bs = pool.shape[1], pool.shape[2]
     mb = block_tables.shape[1]
-    pages = max(min(_KEYS_PER_STEP // bs, mb), 1)
-    steps = -(-mb // pages)
-    if steps * pages != mb:     # dead slots: never live, so never fetched
-        block_tables = jnp.pad(block_tables,
-                               ((0, 0), (0, steps * pages - mb)),
-                               constant_values=nb - 1)
-        mb = steps * pages
+    pages = decode_pages(mb, bs)
     rows = -(-h // 8) * 8
     if rows != h:
         q = jnp.pad(q, ((0, 0), (0, rows - h), (0, 0)))
     flat = pool.reshape((-1,) + pool.shape[2:])        # [L * NB, bs, W]
 
-    def page_map(n):
-        def index(bi, j, tables, pos):
-            # past the sequence's last live page: that page again, which
-            # the pipeline does not fetch twice
-            live = jnp.minimum(j * pages + n,
-                               jnp.minimum(pos[bi] // bs, mb - 1))
-            return (layer * nb + tables[bi * mb + live], 0, 0)
-        return index
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, steps),
-        in_specs=[pl.BlockSpec((1, rows, w), lambda bi, j, *pf: (bi, 0, 0))]
-        + [pl.BlockSpec((1, bs, w), page_map(n)) for n in range(pages)],
-        out_specs=pl.BlockSpec((1, rows, rank), lambda bi, j, *pf:
-                               (bi, 0, 0)),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, rows, w), lambda bi, *pf: (bi, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, rows, rank), lambda bi, *pf: (bi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, rank), jnp.float32),
+            # the joined tile of cached rows, two buffers deep
+            pltpu.VMEM((2, pages * bs, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),       # the buffer of the next tile
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_size=bs, pages=pages,
-                          steps=steps, rank=rank, scale=scale),
+                          table_blocks=mb, rank=rank, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, rank), q.dtype),
+        # a step starts the copies of the step after it: in order, on one core
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="latent_paged_attention",
-    )(block_tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
-      q, *([flat] * pages))
+    )((block_tables + layer * nb).reshape(-1).astype(jnp.int32),
+      positions.astype(jnp.int32), q, flat)
     return out[:, :h]
 
 

@@ -254,21 +254,81 @@ def test_layer_0_is_dense_and_the_others_route(f32):
 
 # --- kernels against their gather paths -------------------------------------
 
-@pytest.mark.parametrize("batch,mb,bs", [(3, 4, 8), (2, 5, 16), (1, 20, 64)],
-                         ids=["b3-4x8", "b2-5x16", "b1-20x64-two-steps"])
-def test_latent_decode_kernel_equals_the_gather_path(batch, mb, bs):
-    heads, rank, width, layers, nb = 2, 16, 128, 2, 32
+#: (table blocks, block size, keys a tile or None for the module's own,
+#: positions): the first three at the module's tile (a table shorter than a
+#: tile; 16 pages of 64 over 20 blocks), the others at tiles of 4 pages of 8
+#: rows, so that a few hundred keys are several tiles. A position 0 is a
+#: padding row of a batch: its table is all trash.
+DECODE_CASES = {
+    "b3-4x8": (4, 8, None, [31, 3, 8]),
+    "b2-5x16": (5, 16, None, [79, 3]),
+    "b1-20x64-two-steps": (20, 64, None, [1279]),
+    "one-token": (6, 8, 32, [0]),
+    "ends-on-a-pages-last-row": (6, 8, 32, [7, 15]),
+    "ends-on-a-tiles-last-row": (9, 8, 32, [31, 63]),
+    "a-tile-and-a-row": (9, 8, 32, [32, 64]),
+    "fills-a-65-block-table": (65, 8, 32, [519, 512]),
+    "tiles-1-2-5": (20, 8, 32, [20, 40, 140]),
+    "tiles-5-2-1": (20, 8, 32, [140, 40, 20]),
+    "padding-rows-between": (12, 8, 32, [50, 0, 0, 70, 0, 33, 0]),
+}
+
+
+@pytest.mark.parametrize("unread", [False, True],
+                         ids=["", "inf-in-unread-pages"])
+@pytest.mark.parametrize("mb,bs,tile,positions", DECODE_CASES.values(),
+                         ids=DECODE_CASES.keys())
+def test_latent_decode_kernel_equals_the_gather_path(mb, bs, tile, positions,
+                                                     unread, monkeypatch):
+    """``unread``: every page no row sees (a table's entries past the row's
+    own page, and the blocks in no table) holds ``inf`` in the kernel's pool
+    and zeros in the oracle's: a page the kernel copied without need, or a
+    stale row of its buffers, would reach the output as NaN."""
+    if tile:
+        monkeypatch.setattr(la, "_KEYS_PER_STEP", tile)
+        assert la.decode_pages(mb, bs) * bs == tile
+    batch = len(positions)
+    heads, rank, width, layers = 2, 16, 128, 2
+    nb = batch * mb + 2
     key = jax.random.split(jax.random.PRNGKey(0), 3)
     pool = jax.random.normal(key[0], (layers, nb, bs, width))
     q = jax.random.normal(key[1], (batch, heads, width))
-    tables = jax.random.permutation(key[2], nb - 1)[:batch * mb].reshape(
-        batch, mb).astype(jnp.int32)
-    positions = jnp.asarray([mb * bs - 1, 3, bs][:batch], jnp.int32)
-    want = la.latent_paged_attention_reference(q, pool[1], tables, positions,
-                                               0.3, rank)
-    got = la.latent_paged_attention(q, pool, 1, tables, positions, 0.3, rank,
+    positions = np.asarray(positions, np.int32)
+    tables = np.asarray(jax.random.permutation(key[2], nb - 1))[
+        :batch * mb].reshape(batch, mb).astype(np.int32)
+    tables[positions == 0] = nb - 1             # padding rows: the trash block
+    seen = np.zeros(nb, bool)
+    for table, pos in zip(tables, positions):
+        seen[table[:pos // bs + 1]] = True
+    clean = pool * jnp.asarray(seen, pool.dtype)[None, :, None, None]
+    want = la.latent_paged_attention_reference(
+        q, clean[1] if unread else pool[1], jnp.asarray(tables),
+        jnp.asarray(positions), 0.3, rank)
+    if unread:
+        pool = jnp.where(jnp.asarray(seen)[None, :, None, None], pool,
+                         jnp.inf)
+    got = la.latent_paged_attention(q, pool, 1, jnp.asarray(tables),
+                                    jnp.asarray(positions), 0.3, rank,
                                     interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_latent_decode_counts_are_the_kernels_own(monkeypatch):
+    """What rides on ``serve/step_decode`` over a latent pool: the keys of
+    the tiles the kernel multiplies (each context's live pages in whole
+    tiles) and its page copies (one a live page, one a padding row)."""
+    monkeypatch.setattr(la, "_KEYS_PER_STEP", 32)
+    assert la.decode_pages(20, 8) == 4 and la.decode_pages(3, 8) == 3
+    # contexts of 21, 41 and 141 tokens are positions 20, 40 and 140
+    assert la.decode_tile_keys([21, 41, 141], 20, 8) == 32 + 64 + 160
+    assert la.decode_page_copies([21, 41, 141], 8, 20, 8) == 3 + 6 + 18 + 5
+    # a context past the table reads the table and no more
+    assert la.decode_tile_keys([999], 9, 8) == 96
+    assert la.decode_page_copies([999], 1, 9, 8) == 9
+    # the module's own tile at the served sizes: 16 pages of 64
+    monkeypatch.undo()
+    assert la.decode_pages(65, 64) == la.decode_pages(132, 64) == 16
+    assert la.decode_tile_keys([1, 1024, 1025], 65, 64) == 1024 * 4
 
 
 def _prefill_args(t, s, start):
@@ -721,10 +781,13 @@ def test_counts_ride_on_the_spans_that_wait(f32):
                            d["experts_touched"] == 4 * 3 and
                            d["expert_tile_rows"] >= d["expert_rows"]
                            for d in decodes)
-    # a latent pool is not the paged kernel's: no tiles, no slot copies
+    # a latent pool is not the paged kernel's: no tiles, no slot copies of
+    # it, and the latent decode kernel's own beside ``ctx_tokens``
     assert not any(k in d for d in decodes
                    for k in ("tile_keys", "slot_copies",
                              "slot_copies_windowed"))
+    assert all(d["latent_tile_keys"] >= d["ctx_tokens"] > 0 and
+               d["latent_page_copies"] >= d["bucket"] for d in decodes)
     assert eng._pending_counts == []
 
 

@@ -316,13 +316,20 @@ def test_chunk_tile_keys_by_hand(case, config, chunk, want):
     assert chunk_pairs(chunk[0], chunk[1]) <= got["tile_keys"]
 
 
-def test_decode_slot_copies_are_absent_over_a_latent_pool():
+def test_decode_slot_copies_over_a_latent_pool_are_the_latent_kernels():
     from deepspeed_tpu.inference.v2.kv_cache import (BlockedKVCache,
                                                      KVCacheConfig)
     latent = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=1,
                                           head_dim=64, latent_dim=48,
                                           num_blocks=4))
-    assert latent.decode_slot_copies([100], 8, 4, None) == {}
+    # the latent decode kernel's copies instead: a page has no heads and one
+    # row is key and value, so one copy a live page (a context of 100 holds
+    # 2 blocks of 64; past the table's 4 it reads the 4) and one a padding
+    # row (7 of 8)
+    assert latent.decode_slot_copies([100], 8, 4, None) == {
+        "latent_page_copies": 2 + 7}
+    assert latent.decode_slot_copies([65, 1, 999], 3, 4, None) == {
+        "latent_page_copies": 2 + 1 + 4}
     # a chunk's counts over a latent pool are the latent prefill kernel's
     # panels (one here: 64 rows over 4 blocks of 16), not the paged kernel's
     assert latent.chunk_tile_keys(0, 64, 4, None) == {
@@ -369,8 +376,9 @@ def test_decode_tile_keys_of_a_windowed_kind_count_from_its_table():
     got = kv.decode_tile_keys([5000, 300], 128, 512)
     assert got["tile_keys_windowed"] == 1024 + 512
     assert got["tile_keys"] == 5120 + 512
-    # and nothing over a latent pool, which the paged kernel does not read
+    # over a latent pool, which the paged kernel does not read, the latent
+    # decode kernel's tiles: the table's 4 blocks of 64 are one tile
     latent = BlockedKVCache(KVCacheConfig(num_layers=1, num_kv_heads=1,
                                           head_dim=64, latent_dim=48,
                                           num_blocks=4))
-    assert latent.decode_tile_keys([100], 4, None) == {}
+    assert latent.decode_tile_keys([100], 4, None) == {"latent_tile_keys": 256}

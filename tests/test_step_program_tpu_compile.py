@@ -151,10 +151,10 @@ def test_layers_of_one_kind_share_one_paged_kernel_body(one_chip):
     assert len(re.findall(r"call @_paged_call\b", text)) == CFG.num_layers
 
 
-def _kernel_operands(fn, *shapes) -> int:
-    """Operands of the one ``paged_attention`` call in ``fn``'s trace beside
-    its prefetched scalars (the table, the positions, the heads' origin, and
-    over fp8 pages the two scale vectors)."""
+def _kernel_operands(fn, *shapes, name="paged_attention") -> int:
+    """Operands of the one call of the kernel ``name`` in ``fn``'s trace
+    beside its prefetched scalars (the table, the positions, the paged
+    kernel's heads' origin, and over fp8 pages the two scale vectors)."""
     def calls(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
@@ -162,7 +162,7 @@ def _kernel_operands(fn, *shapes) -> int:
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 yield from calls(sub)
     (call,) = calls(jax.make_jaxpr(fn)(*shapes).jaxpr)
-    assert call.params["name"] == "paged_attention"
+    assert call.params["name"] == name
     return len(call.invars) \
         - call.params["grid_mapping"].num_index_operands
 
@@ -404,6 +404,36 @@ def test_latent_prefill_panels_compile_for_a_v5e(one_chip, rows, blocks):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         scale=192 ** -0.5, interpret=False).compile().as_text()
     assert "latent_prefill_attention" in text
+
+
+@pytest.mark.parametrize("rows,blocks", [(64, 65), (32, 132)],
+                         ids=["reasoning-64-over-65", "docqa-32-over-132"])
+def test_latent_decode_tiles_compile_for_a_v5e(one_chip, rows, blocks):
+    """The tile ``decode_pages`` chooses at the two latent cells' widest
+    decode calls (64 sequences over the 65-block bucket, which no tile
+    divides, and 32 over 132; 32 folded heads of 640 lanes): Mosaic takes the
+    copies out of the whole pool, both bodies and the two joined buffers
+    inside its scoped VMEM, the pool goes to the call once and as it lies
+    (no operand a page, nothing pool-shaped made for it)."""
+    from deepspeed_tpu.inference.v2 import kv_cache
+    from deepspeed_tpu.ops.pallas.latent_attention import decode_pages
+    assert decode_pages(blocks, BLOCK) == 16
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arr((7, NUM_BLOCKS, BLOCK, 640))
+    static = dict(scale=192 ** -0.5, rank=512, interpret=False)
+    shapes = (arr((rows, 32, 640)), pool, arr((rows, blocks), jnp.int32),
+              arr((rows,), jnp.int32))
+    # q and the pool: once, where a page of a tile was an operand each
+    assert _kernel_operands(
+        lambda *args: kv_cache._latent_paged_call(*args, **static), *shapes,
+        name="latent_paged_attention") == 2
+    compiled = kv_cache._latent_paged_call.lower(*shapes, **static).compile()
+    assert "latent_paged_attention" in compiled.as_text()
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 100
 
 
 #: (rows, window, heads, kv heads, head width, segment ids): the train cell's
