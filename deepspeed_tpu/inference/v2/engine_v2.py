@@ -53,7 +53,7 @@ from deepspeed_tpu.inference.v2.scheduler import (
 )
 from deepspeed_tpu.models.llama import LlamaConfig
 from deepspeed_tpu.runtime.sched import TickLedger
-from deepspeed_tpu.telemetry.names import STEP_COUNTER_ARGS
+from deepspeed_tpu.telemetry.names import GATHERED_ROWS_ARG, STEP_COUNTER_ARGS
 from deepspeed_tpu.telemetry.tracer import get_tracer
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -117,8 +117,10 @@ class _PendingStep:
     chunks: List[tuple] = dataclasses.field(default_factory=list)
     decode_seqs: Sequence[SequenceDescriptor] = ()
     decode_sampled: Optional[jax.Array] = None
-    # what the step's programs counted (``_keep_counts``)
+    # what the step's programs counted (``_keep_counts``), and beside it
+    # the rows their expert layers gathered (``GATHERED_ROWS_ARG``)
     counts: List[jax.Array] = dataclasses.field(default_factory=list)
+    gathered: int = 0
 
 
 @dataclasses.dataclass
@@ -273,6 +275,7 @@ class InferenceEngineV2(ServingEngine):
         # ended no prompt), kept until the next read of a sampled token
         # reads them with it
         self._pending_counts: List[jax.Array] = []
+        self._pending_gathered = 0
         # what a windowed layer's decode reads of a context, in tokens (the
         # one window of all layers, or the windowed kind's), or None
         self._window = self.kv.kind.window if self.kv.two_kinds \
@@ -731,7 +734,8 @@ class InferenceEngineV2(ServingEngine):
                 attn_impl=self.config.attn_impl)
             if half is not None:
                 logits, decode_logits = logits
-            self._keep_counts(rec, counts)
+            self._keep_counts(rec, counts, chunk.bucket + (
+                0 if half is None else self._fused_decode[0]))
             seq.seen_tokens = end
             self._advanced(seq)
             self._prefill_computed += chunk.length
@@ -790,7 +794,7 @@ class InferenceEngineV2(ServingEngine):
                 policy=self.policy, cfg=self.model_config,
                 block_size=self.kv.cfg.block_size,
                 attn_impl=self.config.attn_impl)
-            self._keep_counts(rec, counts)
+            self._keep_counts(rec, counts, b)
             self._decoded(rec, seqs, logits, rows)
         # what the scheduler decided, as plain host ints the step
         # already holds: the batch and the bucket it was padded to,
@@ -844,8 +848,10 @@ class InferenceEngineV2(ServingEngine):
         if not rows:
             self._pending.popleft()
             self._pending_counts += rec.counts
+            self._pending_gathered += rec.gathered
             return {}
         kept = self._pending_counts + rec.counts
+        gathered = self._pending_gathered + rec.gathered
         try:
             with tracer.span("serve/decode_wait", cat="serve", tick=tick):
                 # the step's one wait: the sampled tokens' readback, with
@@ -857,7 +863,7 @@ class InferenceEngineV2(ServingEngine):
                                "lost": self._abandon_pending()}
             raise
         self._pending.popleft()
-        self._pending_counts = []
+        self._pending_counts, self._pending_gathered = [], 0
         with tracer.span("serve/decode_commit", cat="serve", tick=tick):
             for (seqs, _, decode), toks in zip(rows, values):
                 for seq, tok in zip(seqs, toks):
@@ -875,7 +881,8 @@ class InferenceEngineV2(ServingEngine):
             if not kept:
                 return {}
             return dict(zip(STEP_COUNTER_ARGS, (
-                int(v) for v in np.sum(values[len(rows):], axis=0))))
+                int(v) for v in np.sum(values[len(rows):], axis=0))),
+                **{GATHERED_ROWS_ARG: gathered})
 
     def _abandon_pending(self) -> List[int]:
         """Take back every pending step, newest first, after a read that
@@ -897,7 +904,7 @@ class InferenceEngineV2(ServingEngine):
                 seq.seen_tokens = start
                 self._prefill_computed -= length
                 touched[seq.uid] = seq
-        self._pending_counts = []
+        self._pending_counts, self._pending_gathered = [], 0
         self._table_sig = None
         lost = []
         for uid, seq in touched.items():
@@ -935,11 +942,16 @@ class InferenceEngineV2(ServingEngine):
             out["kv_state_bytes"] = len(self.state) * self.kv.slot_bytes
         return out
 
-    def _keep_counts(self, rec: _PendingStep, counts) -> None:
-        """Keep what a step program counted (nothing where its policy counts
-        nothing) with its step, while a tracer is there to read it."""
+    def _keep_counts(self, rec: _PendingStep, counts, rows: int) -> None:
+        """Keep what a step program of ``rows`` rows (padding and a chunk
+        program's decode half among them) counted, nothing where its policy
+        counts nothing, with its step, while a tracer is there to read it;
+        and with it the rows the program's expert layers sorted and
+        gathered, which its shapes say: ``ids.size`` a layer."""
         if counts.size and get_tracer().enabled:
             rec.counts.append(counts)
+            rec.gathered += rows * self.policy.routed_assignments(
+                self.model_config)
 
     def _sample_dispatch(self, logits, rows):
         """[B, V] device logits -> [B] device token ids, stored into the

@@ -20,8 +20,10 @@ Every phase of a step sits under a ``jax.named_scope`` whose name reaches the
 device trace (an operation's ``tf_op``): ``embed`` and ``lm_head`` here,
 ``attn/kv_write``, ``attn/paged``, the ``attn/latent_*`` scopes and a state
 kind's ``ssm/conv``, ``ssm/scan`` and ``ssm/update`` in the kinds, ``attn/qkv``, ``attn/out``, ``mlp``, ``moe/router`` and
-``moe/experts`` in the policies. Metadata only: the compiled program is the
-same.
+``moe/experts`` in the policies; a scope that holds several kinds of work
+(``moe/experts``, ``attn/latent_prefill``, ``attn/latent_paged``) is opened
+through its leaves (``telemetry/names.py`` ``SERVED_LEAF_SCOPES``). Metadata
+only: the compiled program is the same.
 
 A block returns ``(x, counts or None)``; the three step programs return
 ``(logits, cache, counts)`` (the logits a pair, the chunk's and the decode

@@ -46,6 +46,7 @@ from deepspeed_tpu.ops.pallas import ssm_update as _ssm_update
 from deepspeed_tpu.ops.pallas.paged_attention import (
     chunk_tile_keys, decode_slot_copies, decode_tile_keys,
     paged_attention_pool, paged_attention_reference)
+from deepspeed_tpu.telemetry.names import LATENT_KEYS_ARG
 
 FULL, WINDOW, STATE = "full", "window", "state"
 #: the kind of a layer that keeps nothing of a sequence (a layer that is an
@@ -488,12 +489,16 @@ class BlockedKVCache:
         for all its heads. Over a latent pool, which the paged kernel does
         not read, the panels of the latent prefill kernel's grid instead
         (``latent_attention.prefill_panels``: ``latent_panels`` a head a
-        layer, ``latent_panels_masked`` of them, ``latent_panels_dead``)."""
+        layer, ``latent_panels_masked`` of them, ``latent_panels_dead``) and
+        beside them ``latent_keys_gathered``, the rows one layer's call
+        gathers from the pages and up-projects for the chunk
+        (``_latent_prefill_attn``: ``prefill_keys`` of the table's)."""
         if self.cfg.latent_dim:
-            from deepspeed_tpu.ops.pallas.latent_attention import \
-                prefill_panels
-            return prefill_panels(start, bucket,
-                                  table_blocks * self.cfg.block_size)
+            from deepspeed_tpu.ops.pallas.latent_attention import (
+                prefill_keys, prefill_panels)
+            keys = table_blocks * self.cfg.block_size
+            return {**prefill_panels(start, bucket, keys),
+                    LATENT_KEYS_ARG: prefill_keys(bucket, keys)}
         whole, copies = chunk_tile_keys(start, bucket, mb=table_blocks,
                                         **self._fold_of("full"))
         windowed = whole
@@ -749,7 +754,9 @@ def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
     the cached rows themselves and ``W_uv_i`` is applied to the summed rows
     (``ops/pallas/latent_attention.py``). q_nope: [B, H, d_n]; q_rope:
     [B, H, d_r], rotated; w_ukv: [rank, H, d_n + d_v]. Returns [B, H, d_v].
-    Kernel against gather path, as ``_HeadPages._read``."""
+    Kernel against gather path, as ``_HeadPages._read``. The fold sits under
+    ``attn/latent_q``, the call and the value unfold behind it under the two
+    leaves of ``attn/latent_paged`` (``names.SERVED_LEAF_SCOPES``)."""
     from deepspeed_tpu.ops.pallas.latent_attention import \
         latent_paged_attention_reference
     rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
@@ -758,7 +765,7 @@ def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
             [jnp.einsum("bhk,rhk->bhr", q_nope, w_ukv[..., :d_n]), q_rope], -1)
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pool.shape[-1] - q.shape[-1])))
     impl = _resolve_impl(attn_impl)
-    with jax.named_scope("attn/latent_paged"):
+    with jax.named_scope("attn/latent_paged/kernel"):
         if impl == "gather":
             o = latent_paged_attention_reference(
                 q, pool[layer], block_tables, positions, scale, rank)
@@ -769,6 +776,7 @@ def _latent_paged_attn(q_nope, q_rope, pool, layer, block_tables, positions,
                 q, pool, block_tables + layer * pool.shape[1], positions,
                 scale=float(scale), rank=rank,
                 interpret=impl == "kernel_interpret")
+    with jax.named_scope("attn/latent_paged/unfold"):
         return jnp.einsum("bhr,rhv->bhv", o, w_ukv[..., d_n:])
 
 
@@ -807,7 +815,11 @@ def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
     128) operations a pair a head at the published sizes where the folded
     form costs 2 x (576 + 512). q_nope: [T, H, d_n]; q_rope: [T, H, d_r],
     rotated; block_table: [MB]; start: the chunk's first position. Returns
-    [T, H, d_v]."""
+    [T, H, d_v]. Every operation sits under one of the three leaves of
+    ``attn/latent_prefill`` (``names.SERVED_LEAF_SCOPES``: ``gather``,
+    ``up_proj``, ``kernel``), opened in the order the operations are made, so
+    that a device trace says what the gather and the up-projection of the
+    WHOLE bucket cost beside the kernel."""
     from deepspeed_tpu.ops.pallas.latent_attention import (
         latent_prefill_attention_reference, prefill_keys)
     rank, d_n = w_ukv.shape[0], q_nope.shape[-1]
@@ -817,23 +829,30 @@ def _latent_prefill_attn(q_nope, q_rope, pool, layer, block_table, start,
     # no query's horizon reaches
     mb = block_table.shape[0]
     keys = prefill_keys(q_nope.shape[0], mb * bs)
-    table = jnp.pad(block_table, (0, -(-keys // bs) - mb),
-                    constant_values=nb - 1)
-    rows = pool[layer, table].reshape(-1, pool.shape[-1])[:keys]
+    with jax.named_scope("attn/latent_prefill/gather"):
+        table = jnp.pad(block_table, (0, -(-keys // bs) - mb),
+                        constant_values=nb - 1)
+        rows = pool[layer, table].reshape(-1, pool.shape[-1])[:keys]
+        ckv = rows[:, :rank]
+    with jax.named_scope("attn/latent_prefill/kernel"):
+        q_nope, q_rope = q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2)
     # keys and values each from their own half of the up-projection: one
     # product sliced afterwards is two more copies of the context
-    ckv = rows[:, :rank]
-    args = (q_nope.transpose(1, 0, 2), q_rope.transpose(1, 0, 2),
-            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., :d_n]),
-            rows[:, rank:rank + d_r],
-            jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., d_n:]), start)
+    with jax.named_scope("attn/latent_prefill/up_proj"):
+        k_nope = jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., :d_n])
+    with jax.named_scope("attn/latent_prefill/gather"):
+        k_rope = rows[:, rank:rank + d_r]
+    with jax.named_scope("attn/latent_prefill/up_proj"):
+        v = jnp.einsum("sr,rhk->hsk", ckv, w_ukv[..., d_n:])
+    args = (q_nope, q_rope, k_nope, k_rope, v, start)
     impl = _resolve_impl(attn_impl)
-    if impl == "gather":
-        out = latent_prefill_attention_reference(*args, scale)
-    else:
-        out = _latent_prefill_call(*args, scale=float(scale),
-                                   interpret=impl == "kernel_interpret")
-    return out.transpose(1, 0, 2)
+    with jax.named_scope("attn/latent_prefill/kernel"):
+        if impl == "gather":
+            out = latent_prefill_attention_reference(*args, scale)
+        else:
+            out = _latent_prefill_call(*args, scale=float(scale),
+                                       interpret=impl == "kernel_interpret")
+        return out.transpose(1, 0, 2)
 
 
 # --- the page kinds ------------------------------------------------------
@@ -1086,10 +1105,8 @@ class _LatentPages(_Pages):
                      attn_impl, q_nope, q_rope, row, w_ukv, scale):
         with jax.named_scope("attn/latent_write"):
             cache = write_latent(cache, layer, row, *slots)
-        with jax.named_scope("attn/latent_prefill"):
-            return _latent_prefill_attn(q_nope, q_rope, cache, layer,
-                                        block_table, start, w_ukv, scale,
-                                        attn_impl), cache
+        return _latent_prefill_attn(q_nope, q_rope, cache, layer, block_table,
+                                    start, w_ukv, scale, attn_impl), cache
 
     def attend_decode(self, cache, layer, slots, block_tables, positions,
                       attn_impl, q_nope, q_rope, row, w_ukv, scale):

@@ -325,7 +325,7 @@ def _routed_sum(experts, h2, weights, ids, valid, impl, first=0):
     if impl == "ragged_dot":
         y, rows = grouped_expert_ffn(h2, experts, weights, ids, valid,
                                      first=first)
-        tile_rows = jnp.sum(rows)        # no tiles: every row fills its own
+        visited = jnp.sum                # no tiles: every row fills its own
     else:
         from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
         interpret = impl == "kernel_interpret"
@@ -342,14 +342,17 @@ def _routed_sum(experts, h2, weights, ids, valid, impl, first=0):
             matmul=functools.partial(gmm.grouped_matmul, interpret=interpret),
             gate_up=functools.partial(first_product, interpret=interpret),
             first=first)
-        tm = gmm.tiling(ids.size, e, d, f, h2.dtype, stacks)[0]
-        tile_rows = gmm.visited_tile_rows(rows, ids.size, tm)
+        visited = functools.partial(
+            gmm.visited_tile_rows, m=ids.size,
+            tm=gmm.tiling(ids.size, e, d, f, h2.dtype, stacks)[0])
     # the layer's counts, in the order of ``STEP_COUNTER_ARGS``; the
     # assignments of rows that are no padding to experts held elsewhere are
-    # what is left of the router's choices
-    absent = jnp.sum(valid) * ids.shape[1] - jnp.sum(rows)
-    return y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0), tile_rows,
-                         absent]).astype(jnp.int32)
+    # what is left of the router's choices. Under the leaf that made ``rows``
+    with jax.named_scope("moe/experts/sort"):
+        tile_rows = visited(rows)
+        absent = jnp.sum(valid) * ids.shape[1] - jnp.sum(rows)
+        return y, jnp.stack([jnp.sum(rows), jnp.sum(rows > 0), tile_rows,
+                             absent]).astype(jnp.int32)
 
 
 def _chosen_experts(experts, h2, weights, ids, valid, first=0):
@@ -361,11 +364,25 @@ def _chosen_experts(experts, h2, weights, ids, valid, first=0):
     from what the kernel is given, and the assignments left out because
     their expert is not among the stacked ones (``first``: these are the
     router's experts ``first ..``, one chip's share; 0 for a layer held
-    whole). The scope is opened around the call: the callee's operations are
-    lowered once, without their caller's names."""
-    with jax.named_scope("moe/experts"):
-        return _routed_sum(experts, h2, weights, ids, valid,
-                           impl=_expert_matmul_impl(), first=first)
+    whole). The callee's operations are lowered once, without their caller's
+    names: what names them is inside it, the four leaves of ``moe/experts``
+    (``telemetry/names.py`` ``SERVED_LEAF_SCOPES``)."""
+    return _routed_sum(experts, h2, weights, ids, valid,
+                       impl=_expert_matmul_impl(), first=first)
+
+
+def _routed_assignments(cfg) -> int:
+    """A policy's ``routed_assignments(cfg)``: the assignments to routed
+    experts ONE row of a step program makes, top-k summed over the layers
+    that route, whatever the row holds and wherever the experts are held. A
+    step program's rows times it is the ``ids.size`` its expert layers sort,
+    gather and gather back (``telemetry/names.py`` ``GATHERED_ROWS_ARG``),
+    which the engine stamps beside the rows the grouped matmuls visited.
+    Every policy whose blocks hand out counts states it; this is the form
+    of the families whose config says ``is_dense(i)`` and
+    ``num_experts_per_tok``."""
+    return cfg.num_experts_per_tok * sum(
+        not cfg.is_dense(i) for i in range(cfg.num_layers))
 
 
 def _softmax_moe(moe, h2, cfg, valid):
@@ -410,6 +427,10 @@ class MixtralPolicy:
         return KVCacheSpec(b.num_layers, b.num_kv_heads, b.head_dim_,
                            b.max_seq_len, b.dtype, b.sliding_window,
                            query_heads=b.num_heads)
+
+    @staticmethod
+    def routed_assignments(cfg) -> int:
+        return cfg.moe.top_k * cfg.base.num_layers      # every layer routes
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -632,6 +653,10 @@ class Qwen2MoEPolicy:
                            query_heads=b.num_heads)
 
     @staticmethod
+    def routed_assignments(cfg) -> int:
+        return cfg.moe.top_k * cfg.base.num_layers      # every layer routes
+
+    @staticmethod
     def embed(params, tokens, positions, cfg):
         return params["embed"]["embedding"].astype(cfg.base.dtype)[tokens]
 
@@ -796,6 +821,8 @@ class JoyAIFlashPolicy:
         return KVCacheSpec(cfg.num_layers, 1, cfg.latent_dim, cfg.max_seq_len,
                            cfg.dtype, None, latent_dim=cfg.latent_dim)
 
+    routed_assignments = staticmethod(_routed_assignments)
+
     @staticmethod
     def embed(params, tokens, positions, cfg):
         return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
@@ -879,6 +906,8 @@ class LagunaPolicy:
             query_heads={"full": heads.get(_laguna.FULL),
                          "window": heads.get(_laguna.SLIDING)})
 
+    routed_assignments = staticmethod(_routed_assignments)
+
     @staticmethod
     def embed(params, tokens, positions, cfg):
         return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
@@ -958,6 +987,8 @@ class MiMoV2Policy:
             kind_pages=kinds,
             query_heads={"full": cfg.full.num_heads,
                          "window": cfg.swa.num_heads})
+
+    routed_assignments = staticmethod(_routed_assignments)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):
@@ -1150,6 +1181,10 @@ class NemotronHPolicy:
             state_slot=_state_slot(cfg))
 
     @staticmethod
+    def routed_assignments(cfg) -> int:
+        return cfg.num_experts_per_tok * cfg.pattern.count(_nemotron.EXPERTS)
+
+    @staticmethod
     def embed(params, tokens, positions, cfg):
         return params["embed"]["embedding"].astype(cfg.dtype)[tokens]
 
@@ -1237,6 +1272,8 @@ class Lfm2MoePolicy:
                               for i in range(cfg.num_layers)),
             state_slot=StateSlotShape.tail_only(cfg.conv_width,
                                                 cfg.hidden_size))
+
+    routed_assignments = staticmethod(_routed_assignments)
 
     @staticmethod
     def embed(params, tokens, positions, cfg):

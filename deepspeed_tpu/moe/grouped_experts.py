@@ -83,31 +83,39 @@ def grouped_expert_ffn(h, experts, weights, ids, valid=None,
     Every shape is static: the T*K assignments are sorted by expert with
     those left out last, the grouped matmul visits only the rows its group
     sizes cover, and the rows past them (uninitialised in its output) are
-    zeroed before they are gathered back."""
+    zeroed before they are gathered back. The four kinds of work sit under
+    a ``jax.named_scope`` each (``telemetry/names.py`` ``SERVED_LEAF_SCOPES``:
+    ``moe/experts/sort``, ``gather``, ``matmul``, ``combine``), so that a
+    device trace says what the wrapper costs beside the matmuls."""
     t, k = ids.shape
     e = experts["w_down"].shape[0]
-    held = ids - first
-    keep = (held >= 0) & (held < e)
-    if valid is not None:
-        keep = keep & valid[:, None]
-    key = jnp.where(keep, held, e).reshape(-1)                   # [T*K]
-    order = jnp.argsort(key, stable=True)
-    counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
     dtype = h.dtype
-    xs = h[order // k]                                           # [T*K, D]
-    first_product = tuple(experts[name].astype(dtype)
-                          for name in ("w_gate", "w_up", "w_in")
-                          if name in experts)
-    if gate_up is not None:
-        act = gate_up(xs, *first_product, counts)
-    elif "w_in" in experts:
-        act = relu2(matmul(xs, jnp.swapaxes(first_product[0], 1, 2), counts))
-    else:
-        act = jax.nn.silu(matmul(xs, first_product[0], counts)) * \
-            matmul(xs, first_product[1], counts)
-    out = matmul(act, experts["w_down"].astype(dtype), counts)
-    computed = jnp.arange(t * k) < jnp.sum(counts)
-    out = jnp.where(computed[:, None], out, 0)
-    back = out[jnp.argsort(order)].reshape(t, k, -1)
-    w = jnp.where(keep, weights, 0.0).astype(dtype)
-    return jnp.einsum("tk,tkd->td", w, back), counts
+    with jax.named_scope("moe/experts/sort"):
+        held = ids - first
+        keep = (held >= 0) & (held < e)
+        if valid is not None:
+            keep = keep & valid[:, None]
+        key = jnp.where(keep, held, e).reshape(-1)               # [T*K]
+        order = jnp.argsort(key, stable=True)
+        counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+    with jax.named_scope("moe/experts/gather"):
+        xs = h[order // k]                                       # [T*K, D]
+    with jax.named_scope("moe/experts/matmul"):
+        first_product = tuple(experts[name].astype(dtype)
+                              for name in ("w_gate", "w_up", "w_in")
+                              if name in experts)
+        if gate_up is not None:
+            act = gate_up(xs, *first_product, counts)
+        elif "w_in" in experts:
+            act = relu2(matmul(xs, jnp.swapaxes(first_product[0], 1, 2),
+                               counts))
+        else:
+            act = jax.nn.silu(matmul(xs, first_product[0], counts)) * \
+                matmul(xs, first_product[1], counts)
+        out = matmul(act, experts["w_down"].astype(dtype), counts)
+    with jax.named_scope("moe/experts/combine"):
+        computed = jnp.arange(t * k) < jnp.sum(counts)
+        out = jnp.where(computed[:, None], out, 0)
+        back = out[jnp.argsort(order)].reshape(t, k, -1)
+        w = jnp.where(keep, weights, 0.0).astype(dtype)
+        return jnp.einsum("tk,tkd->td", w, back), counts

@@ -245,7 +245,8 @@ SERVE_STAGE_OF: Dict[str, str] = {
 #: readers match (``generic_decode.py``, ``modules.py``, ``kv_cache.py``).
 #: The ``attn/latent_*`` four are a latent (MLA) cache's: the low-rank
 #: projections and the fold, the row's write, the paged decode kernel with
-#: the value unfold, a chunk's gather, up-projection and prefill kernel.
+#: the value unfold, a chunk's gather, up-projection and prefill kernel
+#: (the last two, and ``moe/experts``, through ``SERVED_LEAF_SCOPES`` below).
 #: ``attn/full`` and ``attn/window`` hold a layer's write and paged attention
 #: where a cache keeps pages by layer kind (whatever heads and widths a kind
 #: states, with or without sinks), ``attn/gate`` a per-head output
@@ -270,6 +271,51 @@ SERVED_SCOPES: Tuple[str, ...] = (
     "ssm/out_proj", "conv/in_proj", "conv/shift", "conv/out_proj",
     "attn/qk_norm")
 
+#: the leaves of the three scopes above that hold several kinds of work.
+#: The code opens the LEAF, by its whole name (``moe/grouped_experts.py``,
+#: ``modules._routed_sum``, ``kv_cache._latent_prefill_attn`` and
+#: ``_latent_paged_attn``), and never the parent alone: a callee under a
+#: ``jit`` of its own is lowered without its caller's names, so only a whole
+#: name stays one piece of an operation's ``tf_op``
+#: (``jit(prefill_chunk_g)/jit(_routed_sum)/moe/experts/sort/...``). Every
+#: operation of a parent is under exactly one of its leaves, so whoever
+#: matches the parent's name reads what it read, and the leaves' seconds sum
+#: to the parent's. A fusion carries one name, its root's.
+SERVED_LEAF_SCOPES: Dict[str, str] = {
+    "moe/experts/sort": "which assignments stay (`keep`), their key, the "
+                        "`argsort`, the rows on each expert and the layer's "
+                        "counts made from them",
+    "moe/experts/gather": "`xs = h[order // k]`: the [T*K, D] rows into "
+                          "expert order",
+    "moe/experts/matmul": "the weights' casts, the first product with its "
+                          "activation and the second product (the Pallas "
+                          "calls keep their names `grouped_matmul*`)",
+    "moe/experts/combine": "the zeroing of the rows past the groups, the "
+                           "inverse `argsort` and the gather back, the "
+                           "weights' `where`, the `einsum` over the top-k",
+    "attn/latent_prefill/gather": "the padded table, `pool[layer, table]`, "
+                                  "the reshape and the cuts to `keys` rows, "
+                                  "their compressed part and their rope part",
+    "attn/latent_prefill/up_proj": "the two `einsum`s from `ckv`: every "
+                                   "head's keys and values of the whole "
+                                   "bucket",
+    "attn/latent_prefill/kernel": "the transposes round it and "
+                                  "`_latent_prefill_call` (the reference on "
+                                  "the gather path)",
+    "attn/latent_paged/kernel": "the layer's offset into the tables and "
+                                "`_latent_paged_call` (the reference on the "
+                                "gather path)",
+    "attn/latent_paged/unfold": "the value up-projection `einsum` behind "
+                                "the kernel",
+}
+
+#: ``jax.named_scope`` names of the trained step (``models/llama.py``,
+#: ``runtime/engine.py``): the output head with the loss behind it, forward
+#: and backward, and the optimizer's update. A layer's work carries its flax
+#: modules' names instead (``.../layer_<i>/attn/...``, ``.../mlp/...``), in
+#: the forward pass, the recomputed forward and the backward pass alike
+TRAINED_SCOPES: Tuple[str, ...] = ("lm_head_loss", "optimizer")
+
 #: counts a step program computes on the device where its policy's layers
 #: count (``generic_decode.py``), in the order of the int32 vector it hands
 #: out: token-expert pairs of the step, experts with at least one row, and
@@ -286,6 +332,21 @@ SERVED_SCOPES: Tuple[str, ...] = (
 STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched",
                                       "expert_tile_rows",
                                       "expert_rows_absent")
+
+#: two host counts beside the ones they divide, from static shapes, stamped
+#: only while the tracer is on. ``expert_rows_gathered`` beside
+#: ``expert_rows``, on the same span and of the same steps (a chunk that
+#: ends no prompt hands both on to the next span that waits): the rows the
+#: experts' wrapper sorts, gathers and gathers back, ``ids.size`` (the
+#: program's rows, padding and a chunk program's decode half among them, x
+#: top-k) summed over the expert layers; ``expert_rows`` over it is the
+#: share of them a grouped matmul visits. ``latent_keys_gathered`` beside
+#: ``latent_panels`` on a ``serve/prefill_chunk`` over a latent pool: the
+#: rows one layer's call gathers and up-projects for the chunk
+#: (``latent_attention.prefill_keys`` of its context bucket); ``start`` +
+#: ``tokens`` over it is the share the chunk's rows can see
+GATHERED_ROWS_ARG = "expert_rows_gathered"
+LATENT_KEYS_ARG = "latent_keys_gathered"
 
 #: per-request tracing namespace (reqtrace.py file-loads this module
 #: standalone, same contract as the tables above). Spans carrying a

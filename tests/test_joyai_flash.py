@@ -758,12 +758,19 @@ def test_counts_ride_on_the_spans_that_wait(f32):
     # the registry is what the served modules open, no more and no less
     import pathlib
     import re
-    served = pathlib.Path(jm.__file__).parents[1] / "inference" / "v2"
-    opened = {scope for path in served.glob("*.py") for scope in re.findall(
+    # (a scope that holds several kinds of work is opened through its leaves,
+    # by their whole names, in the served modules and in the experts' wrapper)
+    package = pathlib.Path(jm.__file__).parents[1]
+    served = [*(package / "inference" / "v2").glob("*.py"),
+              package / "moe" / "grouped_experts.py"]
+    opened = {scope for path in served for scope in re.findall(
         r'named_scope\("([^"]+)"\)', path.read_text())}
-    assert opened == set(names.SERVED_SCOPES)
-    assert {"attn/latent_q", "attn/latent_write", "attn/latent_paged",
-            "attn/latent_prefill", "moe/router", "moe/experts",
+    leaves = set(names.SERVED_LEAF_SCOPES)
+    parents = {leaf.rsplit("/", 1)[0] for leaf in leaves}
+    assert parents == {"moe/experts", "attn/latent_prefill",
+                       "attn/latent_paged"} < set(names.SERVED_SCOPES)
+    assert opened == (set(names.SERVED_SCOPES) - parents) | leaves
+    assert {"attn/latent_q", "attn/latent_write", "moe/router",
             "moe/shared"} <= opened
     chunks = [e[7] for e in events if e[1] == "serve/prefill_chunk"]
     decodes = [e[7] for e in events if e[1] == "serve/step_decode"]
@@ -772,6 +779,15 @@ def test_counts_ride_on_the_spans_that_wait(f32):
     assert [c["tokens"] for c in chunks] == [32, 8]
     assert "expert_rows" not in chunks[0]
     assert chunks[1]["expert_rows"] == 40 * 4 * 3
+    # ... and beside them the rows the wrapper gathered for them: both
+    # chunk programs' rows, bucket padding and decode half with them, times
+    # the top-4 of the three layers that route
+    assert "expert_rows_gathered" not in chunks[0]
+    half = eng._fused_decode[0]
+    assert chunks[1]["expert_rows_gathered"] == sum(
+        c["bucket"] + half for c in chunks) * 4 * 3
+    assert all(d["expert_rows_gathered"] == d["bucket"] * 4 * 3
+               for d in decodes)
     assert 3 * 4 <= chunks[1]["experts_touched"] <= 2 * 3 * 16
     # rows of the grouped matmul's tiles: no fewer than the rows in them
     # (as many where ``ragged_dot`` runs and there are no tiles to count)
@@ -824,6 +840,10 @@ def test_latent_panel_counts_ride_on_the_chunk_spans(f32, monkeypatch):
                                  table_blocks * BLOCK)
         assert want["latent_panels"] >= 1
         assert {k: c[k] for k in want} == want
+        # ... and the rows a layer's call gathered and up-projected for it,
+        # no fewer than the chunk's rows can see
+        assert c["latent_keys_gathered"] == la.prefill_keys(
+            c["bucket"], table_blocks * BLOCK) >= c["start"] + c["tokens"]
         assert not any(k in c for k in ("tile_keys", "tile_copies"))
 
 
@@ -831,7 +851,7 @@ def test_untraced_engine_keeps_no_counts(f32):
     cfg, _, params = f32
     eng = engine(cfg, params)
     eng.generate(tokens(20).tolist(), max_new_tokens=3)
-    assert eng._pending_counts == []
+    assert eng._pending_counts == [] and eng._pending_gathered == 0
 
 
 def test_fp8_pages_are_refused_by_name_over_a_latent_cache(f32):

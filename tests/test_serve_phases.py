@@ -332,9 +332,10 @@ def test_decode_slot_copies_over_a_latent_pool_are_the_latent_kernels():
         "latent_page_copies": 2 + 1 + 4}
     # a chunk's counts over a latent pool are the latent prefill kernel's
     # panels (one here: 64 rows over 4 blocks of 16), not the paged kernel's
+    # and beside them the rows its call gathers: the table's 4 blocks, whole
     assert latent.chunk_tile_keys(0, 64, 4, None) == {
         "latent_panels": 1, "latent_panels_masked": 1,
-        "latent_panels_dead": 0}
+        "latent_panels_dead": 0, "latent_keys_gathered": 4 * 64}
 
 
 @pytest.mark.parametrize("case,contexts,window,want", [
