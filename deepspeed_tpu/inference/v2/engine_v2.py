@@ -53,7 +53,10 @@ from deepspeed_tpu.inference.v2.scheduler import (
 )
 from deepspeed_tpu.models.llama import LlamaConfig
 from deepspeed_tpu.runtime.sched import TickLedger
-from deepspeed_tpu.telemetry.names import GATHERED_ROWS_ARG, STEP_COUNTER_ARGS
+from deepspeed_tpu.telemetry.names import (GATHERED_ROWS_ARG,
+                                           KV_ROWS_BY_PAGE_ARG,
+                                           KV_ROWS_WRITTEN_ARG,
+                                           STEP_COUNTER_ARGS)
 from deepspeed_tpu.telemetry.tracer import get_tracer
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -765,6 +768,14 @@ class InferenceEngineV2(ServingEngine):
                         start=chunk.start, **(self.kv.chunk_tile_keys(
                             chunk.start, chunk.bucket, mb, self._window)
                             if tracer.enabled else {}))
+            # ... and the rows the program wrote to the pool: the chunk's,
+            # a page at a time where its bucket is a block or more over
+            # plain pages, and its decode half's, always one by one
+            args.update({
+                KV_ROWS_WRITTEN_ARG: chunk.length + (
+                    len(plan.decode_seqs) if fused else 0),
+                KV_ROWS_BY_PAGE_ARG: chunk.length
+                if self.kv.chunk_by_page(chunk.bucket) else 0})
             if fused:
                 self._decoded(rec, plan.decode_seqs, decode_logits, rows)
             if last:
@@ -808,6 +819,7 @@ class InferenceEngineV2(ServingEngine):
                 batch=len(seqs), bucket=b, ctx_tokens=whole,
                 ctx_tokens_windowed=sum(min(c, window) for c in contexts)
                 if window else whole, ctx_blocks=mb,
+                **{KV_ROWS_WRITTEN_ARG: len(seqs), KV_ROWS_BY_PAGE_ARG: 0},
                 **self.kv.decode_tile_keys(contexts, mb, window),
                 **self.kv.decode_slot_copies(contexts, b, mb, window))
         return d

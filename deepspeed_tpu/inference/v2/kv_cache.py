@@ -27,14 +27,17 @@ and each layer's ``attend`` arguments. A further kind is a class here and
 the policy that calls its ``attend``.
 
 Padding rows are written to the trash block (the pool's last, never handed
-out by the allocator), so the write path needs no mask; on the read path
+out by the allocator), so the row write needs no mask; a chunk's rows go in a
+page at a time (``write_chunk_pages``: pages that are all padding to the trash
+block, the first and the last page's other rows kept under a row mask), a
+decode batch's one by one. On the read path
 causal masking doubles as padding masking: a gathered position at or past
 the context length can never satisfy qpos >= kpos.
 """
 
 import dataclasses
 from functools import partial
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +45,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.ops import ssm
+from deepspeed_tpu.ops.pallas import page_write as _page_write
 from deepspeed_tpu.ops.pallas import ssm_update as _ssm_update
 from deepspeed_tpu.ops.pallas.paged_attention import (
     chunk_tile_keys, decode_slot_copies, decode_tile_keys,
@@ -509,6 +513,11 @@ class BlockedKVCache:
         return {"tile_keys": whole, "tile_keys_windowed": windowed,
                 "tile_copies": copies}
 
+    def chunk_by_page(self, bucket: int) -> bool:
+        """Whether a chunk program of ``bucket`` rows writes the chunk's rows
+        a page at a time (the kind's static rule, ``_Pages.chunk_by_page``)."""
+        return self.kind.chunk_by_page(bucket, self.cfg.block_size)
+
     def pages_held(self) -> dict:
         """Blocks sequences hold now, by kind, and their bytes, each kind's
         from a block of its own pool (``full_bytes`` over the live tokens is
@@ -734,6 +743,45 @@ def write_latent(cache_data, layer: int, rows, block_ids, offsets):
         cast_to_page_dtype(rows, cache_data.dtype))
 
 
+class PageSlots(NamedTuple):
+    """Where one sequence's chunk lands, a page at a time: the block of each
+    page its rows can span (``page_write.page_count`` of them; the trash
+    block for a page that is all bucket padding), and the chunk's rows
+    ``[lo, hi)`` counted from the first page's first row: ``lo`` the first
+    position's offset in its page, ``hi - lo`` the real rows."""
+    pages: Any
+    lo: Any
+    hi: Any
+
+
+@partial(jax.jit, static_argnames=("attn_impl",))
+def write_chunk_pages(pool, layer, rows, slots: PageSlots, attn_impl: str):
+    """A chunk's rows into a paged pool one update a (group, page) tile,
+    ``[block_size, W]`` contiguous in the pool as it lies, in place where
+    the pool is donated: the one write of every pool format a chunk goes
+    through. pool: ``[..., NB, bs, W]`` behind whatever leading axes its kind
+    gives it, the layer first and the ``n`` groups a layer writes together
+    last among them (``[L, 2, H_kv, ...]``: a layer's K heads then its V
+    heads, ``n = 2 * H_kv``; a split kind's ``[L, H_kv, ...]``; a latent
+    pool's ``[L, ...]``, ``n = 1``); rows: ``[T, n, C]`` with ``C <= W``
+    (zero lanes fill the rest). The first page's rows before ``slots.lo``
+    and the last's from ``slots.hi`` on keep what they held
+    (``ops/pallas/page_write.py``: the Pallas kernel on a TPU, the same in
+    ``jax.numpy`` where ``attn_impl`` resolves to ``gather``). Merging the
+    leading axes moves nothing (the tiled axes are the last two), so the
+    pool keeps its layout, as ``write_kv`` says it must. Under a ``jit`` of
+    its own with the layer a VALUE, so that a step program traces it once a
+    shape and not once a layer."""
+    nb, bs, w = pool.shape[-3:]
+    new = _page_write.frames(
+        cast_to_page_dtype(_lanes(rows, w), pool.dtype), slots.lo, bs)
+    impl = _resolve_impl(attn_impl)
+    write = _page_write.write_pages_reference if impl == "gather" else \
+        partial(_page_write.write_pages, interpret=impl == "kernel_interpret")
+    return write(pool.reshape(-1, nb, bs, w), layer, new,
+                 *slots).reshape(pool.shape)
+
+
 # --- attention over the pages: kernel or gather path --------------------
 ATTN_IMPLS = ("auto", "kernel", "kernel_interpret", "gather")
 
@@ -878,16 +926,46 @@ class _Pages:
         are the first leaf of what the step programs carry."""
         return jax.tree.leaves(cache)[0].shape[self.block_axis] - 1
 
-    def chunk_slots(self, cache, block_table, start, safe_pos, valid,
-                    block_size: int):
-        """Where one sequence's chunk lands: each row's (block, offset), the
-        trash block for the rows that are bucket padding. block_table: [MB];
-        start: the chunk's first position; safe_pos, valid: [T]."""
+    #: whether a chunk's rows go into this kind's pool a page at a time
+    by_page = True
+
+    def chunk_by_page(self, rows: int, block_size: int) -> bool:
+        """Whether a chunk padded to ``rows`` is written a page at a time
+        (``write_chunk_pages``) and not a row at a time: a static property
+        of the call. A chunk shorter than a block (speculation's ``k + 1``
+        rows, a bucket under a block) would move two pages' tiles for its
+        few rows and keeps the row scatter."""
+        return self.by_page and rows >= block_size
+
+    def _row_slots(self, cache, block_table, safe_pos, valid,
+                   block_size: int):
+        """Each row's (block, offset), the trash block for the rows that are
+        bucket padding."""
         mb = block_table.shape[0]
         blk = jnp.where(
             valid, block_table[jnp.minimum(safe_pos // block_size, mb - 1)],
             self.trash_block(cache))
         return blk, safe_pos % block_size
+
+    def chunk_slots(self, cache, block_table, start, safe_pos, valid,
+                    block_size: int):
+        """Where one sequence's chunk lands: ``PageSlots`` where it is
+        written a page at a time (``chunk_by_page``), else each row's
+        (block, offset); the trash block for what is bucket padding, a whole
+        page or a row. block_table: [MB]; start: the chunk's first position,
+        anywhere in its block; safe_pos, valid: [T]. The table lookup is the
+        same on both paths: entry ``min(position // block_size, MB - 1)``."""
+        rows = safe_pos.shape[0]
+        if not self.chunk_by_page(rows, block_size):
+            return self._row_slots(cache, block_table, safe_pos, valid,
+                                   block_size)
+        lo = start % block_size
+        hi = lo + jnp.sum(valid)
+        page = jnp.arange(_page_write.page_count(rows, block_size))
+        entry = jnp.minimum(start // block_size + page,
+                            block_table.shape[0] - 1)
+        return PageSlots(jnp.where(page * block_size < hi, block_table[entry],
+                                   self.trash_block(cache)), lo, hi)
 
     def decode_slots(self, cache, block_tables, safe_pos, valid,
                      block_size: int):
@@ -931,6 +1009,18 @@ class _HeadPages(_Pages):
     def _write(self, cache, layer, k, v, slots):
         return write_kv(cache, layer, k, v, *slots)
 
+    def _write_pages(self, cache, layer, k, v, slots, attn_impl):
+        # a layer's K heads then its V heads lie side by side in the pool
+        return write_chunk_pages(cache, layer, jnp.concatenate([k, v], 1),
+                                 slots, attn_impl)
+
+    def _write_chunk(self, cache, layer, k, v, slots, attn_impl):
+        """A chunk's rows by page where its slots say so (``chunk_slots``),
+        else by row."""
+        if isinstance(slots, PageSlots):
+            return self._write_pages(cache, layer, k, v, slots, attn_impl)
+        return self._write(cache, layer, k, v, slots)
+
     def _read(self, pages, layer, q, block_tables, start_pos, attn_impl,
               window="spec", softcap=None, scales=None, sinks=None):
         """q: [B, T, H, D]; kernel or gather reference, both with ``softcap``
@@ -957,7 +1047,7 @@ class _HeadPages(_Pages):
         """Write one chunk's rows, then attend over the sequence's pages from
         position ``start``. Returns (out [T, H, D], the pool)."""
         with jax.named_scope("attn/kv_write"):
-            cache = self._write(cache, layer, k, v, slots)
+            cache = self._write_chunk(cache, layer, k, v, slots, attn_impl)
         with jax.named_scope("attn/paged"):
             return self._read(cache, layer, q[None], block_table[None],
                               jnp.asarray(start).reshape(1), attn_impl,
@@ -966,7 +1056,10 @@ class _HeadPages(_Pages):
     def attend_decode(self, cache, layer, slots, block_tables, positions,
                       attn_impl, q, k, v, **how):
         """Write one token a sequence, then attend over each sequence's
-        pages. Returns (out [B, H, D], the pool)."""
+        pages. Returns (out [B, H, D], the pool). A decode batch's rows lie
+        in as many pages as sequences, so they keep the row scatter (on a
+        v5e 0.06 ms a pool at 32 rows x 8 heads and 0.20 at 256: PERF.md
+        section 6, PR 55)."""
         with jax.named_scope("attn/kv_write"):
             cache = self._write(cache, layer, k, v, slots)
         with jax.named_scope("attn/paged"):
@@ -1008,6 +1101,11 @@ class _SplitHeadPages(_HeadPages):
                                        cache[name].dtype))
                 for name, new in (("k", k), ("v", v))}
 
+    def _write_pages(self, cache, layer, k, v, slots, attn_impl):
+        return {name: write_chunk_pages(cache[name], layer, new, slots,
+                                        attn_impl)
+                for name, new in (("k", k), ("v", v))}
+
     def _read(self, pages, layer, q, block_tables, start_pos, attn_impl,
               window="spec", sinks=None):
         window = self.window if window == "spec" else window
@@ -1039,7 +1137,11 @@ class _ScaledHeadPages(_HeadPages):
     it (``write_kv_scaled``; the reference fp quantizer is group-scaled the
     same way, csrc/fp_quantizer/fp_quantize.cu, group absmax). The step
     programs carry ``(pages, scales)`` and both attention paths dequantize
-    on load."""
+    on load. A chunk's rows keep the row scatter (``by_page`` False): a
+    page's requantisation is tied to the rows that grow its scale, so the
+    write is not a move of tiles; no cell of the benchmark runs fp8 pages
+    and the row path's cost here is not measured."""
+    by_page = False
 
     @staticmethod
     def new_pool(cfg: KVCacheConfig):
@@ -1054,12 +1156,12 @@ class _ScaledHeadPages(_HeadPages):
         # worst-case page count: offsets start%bs .. start%bs+tb-1 span up to
         # (tb + bs - 2)//bs + 1 pages — a chunk smaller than a page that
         # crosses a boundary still touches TWO pages (tb//bs+1 missed that)
-        blk, off = super().chunk_slots(cache, block_table, start, safe_pos,
-                                       valid, block_size)
+        blk, off = self._row_slots(cache, block_table, safe_pos, valid,
+                                   block_size)
         tb, mb = safe_pos.shape[0], block_table.shape[0]
         touch_idx = jnp.minimum(
             start // block_size +
-            jnp.arange((tb + block_size - 2) // block_size + 1), mb - 1)
+            jnp.arange(_page_write.page_count(tb, block_size)), mb - 1)
         return blk, off, block_table[touch_idx]
 
     def decode_slots(self, cache, block_tables, safe_pos, valid,
@@ -1101,10 +1203,18 @@ class _LatentPages(_Pages):
         return jnp.zeros((cfg.num_layers, cfg.num_blocks, cfg.block_size,
                           latent_row_width(cfg.latent_dim)), cfg.dtype), None
 
+    def _write_chunk(self, cache, layer, row, slots, attn_impl):
+        """A chunk's rows by page where its slots say so (``chunk_slots``:
+        a row is its one group's), else by row."""
+        if isinstance(slots, PageSlots):
+            return write_chunk_pages(cache, layer, row[:, None], slots,
+                                     attn_impl)
+        return write_latent(cache, layer, row, *slots)
+
     def attend_chunk(self, cache, layer, slots, block_table, start,
                      attn_impl, q_nope, q_rope, row, w_ukv, scale):
         with jax.named_scope("attn/latent_write"):
-            cache = write_latent(cache, layer, row, *slots)
+            cache = self._write_chunk(cache, layer, row, slots, attn_impl)
         return _latent_prefill_attn(q_nope, q_rope, cache, layer, block_table,
                                     start, w_ukv, scale, attn_impl), cache
 
@@ -1405,6 +1515,11 @@ class _LayerKindPages:
             pool[STATE] = self.state.empty(self.kinds.count(STATE),
                                            cfg.state_slots, cfg.dtype)
         return pool, None
+
+    def chunk_by_page(self, rows: int, block_size: int) -> bool:
+        """What the paged kinds say (they share ``_Pages``' rule)."""
+        return any(pages.chunk_by_page(rows, block_size)
+                   for pages in self.pages.values())
 
     def row_operands(self, layer: int) -> int:
         """What the layer's own kind says (``_Pages.row_operands``)."""

@@ -348,6 +348,19 @@ STEP_COUNTER_ARGS: Tuple[str, ...] = ("expert_rows", "experts_touched",
 GATHERED_ROWS_ARG = "expert_rows_gathered"
 LATENT_KEYS_ARG = "latent_keys_gathered"
 
+#: two host counts, one a span, of what a step program writes to the paged
+#: pool, from what the tick knows (stamped with the span's other args):
+#: ``kv_rows_written`` on a ``serve/prefill_chunk`` is the chunk's ``tokens``
+#: plus the decode rows that rode in its program, on a ``serve/step_decode``
+#: the batch; ``kv_rows_by_page`` is the part of it that went in a page at a
+#: time (``kv_cache.write_chunk_pages``): the chunk's ``tokens`` where the
+#: chunk's bucket is a block or more over pages that are not fp8 scaled
+#: (``BlockedKVCache.chunk_by_page``), 0 on a decode span, whose rows lie in
+#: as many pages as sequences and keep the row scatter. A state kind's slot
+#: is no row of the pool and counts in neither
+KV_ROWS_WRITTEN_ARG = "kv_rows_written"
+KV_ROWS_BY_PAGE_ARG = "kv_rows_by_page"
+
 #: per-request tracing namespace (reqtrace.py file-loads this module
 #: standalone, same contract as the tables above). Spans carrying a
 #: ``trace_id`` arg under REQ_PREFIX are the stitch join; REQ_STAGE_OF

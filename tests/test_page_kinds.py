@@ -104,15 +104,18 @@ def test_a_write_lands_in_the_named_slots_and_nowhere_else(name):
     pool = jax.tree.map(lambda x: jnp.full_like(x, 0.5), kv.pool)
     old = 0.25 if name == "heads_fp8" else 0.5
 
-    def check(pool, before, layer, slots, want):
+    def check(pool, before, layer, slots, want, trash_said=True):
         """``slots``: (block, offset) of each row of ``want``; every other
         slot of ``layer`` reads ``old``, every other layer and every page
-        not named is bit for bit what it was."""
+        not named is bit for bit what it was. Where a chunk went in a page
+        at a time nobody says what the trash block holds."""
         expect = np.full((NB, BS, want.shape[-1]), old, np.float32)
         for (b, o), row in zip(slots, want):
             expect[b, o] = row
-        np.testing.assert_allclose(rows_of(name, pool, layer), expect,
-                                   rtol=tol, atol=tol * 0.25)
+        rows = rows_of(name, pool, layer)
+        if not trash_said:
+            rows, expect = rows[:TRASH], expect[:TRASH]
+        np.testing.assert_allclose(rows, expect, rtol=tol, atol=tol * 0.25)
         named = sorted({b for b, _ in slots})
         for got, was in zip(other_blocks(name, pool, named),
                             other_blocks(name, before, named)):
@@ -124,6 +127,8 @@ def test_a_write_lands_in_the_named_slots_and_nowhere_else(name):
 
     # a chunk of 6 rows, 5 of them real, from position 6: slots 2-3 of the
     # table's second block, 0-2 of its third; the padding row to the trash
+    # where the rows go in one by one (fp8 pages), nowhere where they go in
+    # a page at a time (the third block's last row keeps what it held)
     table = jnp.asarray([7, 2, 9, TRASH], jnp.int32)
     start, rows, real = 6, 6, 5
     safe_pos = start + jnp.arange(rows)
@@ -134,8 +139,11 @@ def test_a_write_lands_in_the_named_slots_and_nowhere_else(name):
     out, pool = kind.attend_chunk(pool, 0, slots, table, start, "gather",
                                   *args)
     assert out.shape[0] == rows and np.isfinite(np.asarray(out)[:real]).all()
+    by_page = kind.chunk_by_page(rows, BS)
+    assert by_page == (name != "heads_fp8")
     check(pool, before, 0,
-          [(2, 2), (2, 3), (9, 0), (9, 1), (9, 2), (TRASH, 3)], want)
+          [(2, 2), (2, 3), (9, 0), (9, 1), (9, 2)]
+          + ([] if by_page else [(TRASH, 3)]), want, trash_said=not by_page)
 
     # a decode batch of 3, the middle one batch padding: one token each at
     # positions 5 and 8 of their own tables
@@ -497,3 +505,223 @@ def test_state_kind_is_a_slot_a_sequence_beside_the_full_layers_pages(impl):
             num_layers=3, num_kv_heads=H, head_dim=D, block_size=BS,
             num_blocks=NB, dtype=jnp.float32, layer_kinds=kinds,
             state_slot=at))
+
+
+# --- a chunk's rows by page ----------------------------------------------------
+# A chunk of 128 rows over blocks of 64 from any offset in its first block:
+# the pool after the page write against the pool after the row scatter.
+
+PBS, BUCKET, PNB, WIN = 64, 128, 9, 100
+LENGTHS = {"bucket": BUCKET, "bucket-1": BUCKET - 1, "one": 1,
+           "under-a-block": 40}
+
+
+def _paged(fmt):
+    """(the cache, its kind, the layer written, that layer's pool name or
+    None, what the layer's block hands ``attend`` for a chunk)."""
+    from deepspeed_tpu.inference.v2.kv_cache import HeadPageShape
+    key = jax.random.split(jax.random.PRNGKey(5), 5)
+    how = dict(num_layers=2, num_kv_heads=H, head_dim=D, block_size=PBS,
+               num_blocks=PNB, dtype=jnp.bfloat16)
+    spec = dict(latent_dim=0)
+    layer, name = 1, None
+    if fmt == "latent":
+        how["latent_dim"] = spec["latent_dim"] = RANK + D_R
+    elif fmt in ("split", "layer-kinds"):
+        layer, name = 2, "window"
+        how.update(num_layers=3, layer_windows=(None, WIN, WIN),
+                   window_blocks=PNB)
+        spec["layer_windows"] = how["layer_windows"]
+        if fmt == "split":
+            how["kind_pages"] = spec["kind_pages"] = {
+                "full": HeadPageShape(1, 12, 8),
+                "window": HeadPageShape(H, 12, 8)}
+    kv = BlockedKVCache(KVCacheConfig(**how))
+    kind = page_kind(KVCacheSpec(how["num_layers"], H, D, 4096, jnp.bfloat16,
+                                 None, **spec), kv.pool)
+    if fmt == "latent":
+        args = (jax.random.normal(key[0], (BUCKET, H, D_N)),
+                jax.random.normal(key[1], (BUCKET, H, D_R)),
+                jax.random.normal(key[2], (BUCKET, RANK + D_R)),
+                jax.random.normal(key[3], (RANK, H, D_N + D_V)), 0.3)
+    else:
+        dk, dv = (12, 8) if fmt == "split" else (D, D)
+        args = (jax.random.normal(key[0], (BUCKET, 2 * H, dk)),
+                jax.random.normal(key[1], (BUCKET, H, dk)),
+                jax.random.normal(key[2], (BUCKET, H, dv)))
+    return kv, kind, layer, name, args
+
+
+def _but_the_trash(pool, kv):
+    """Every leaf of ``pool`` without its trash block, as bits."""
+    kind = kv.kind
+    out = []
+    for name, leaves in (pool.items() if kv.by_layer_kind
+                         else [(None, pool)]):
+        pages = kind.pages[name] if name else kind
+        for x in jax.tree.leaves(leaves):
+            bits = np.asarray(x).view(np.uint16)
+            out.append(np.delete(bits, bits.shape[pages.block_axis] - 1,
+                                 pages.block_axis))
+    return out
+
+
+@pytest.mark.parametrize("ends_its_table", [False, True],
+                         ids=["table-goes-on", "ends-in-the-last-block"])
+@pytest.mark.parametrize("length", sorted(LENGTHS))
+@pytest.mark.parametrize("offset", [0, 1, 37, 63])
+@pytest.mark.parametrize("fmt", ["heads", "split", "latent", "layer-kinds"])
+def test_a_chunk_by_page_leaves_what_the_row_scatter_leaves(
+        monkeypatch, fmt, offset, length, ends_its_table):
+    """``_HeadPages``, ``_SplitHeadPages`` (a windowed kind of a pool by
+    layer kind whose keys are wider than its values), ``_LatentPages`` and a
+    square windowed kind behind its window (positions counted from its
+    table's first block): a chunk padded to 128 rows from ``offset`` in its
+    first block, ``length`` of them real, written a page at a time
+    (``attend_chunk``, the ``jax.numpy`` form; then the kernel, interpreted)
+    leaves every block but the trash block bit for bit as the row scatter
+    leaves it: the first page's rows before the chunk and the last page's
+    behind it keep what they held, a page that is all padding goes to the
+    trash block, and a table that ends in the chunk's last block is not read
+    past its end."""
+    from deepspeed_tpu.inference.v2 import kv_cache
+    kv, kind, layer, name, args = _paged(fmt)
+    real = LENGTHS[length]
+    start = 2 * PBS + offset
+    # noise everywhere, so that a row that should keep what it held shows
+    fill = jax.random.split(jax.random.PRNGKey(11), 8)
+    pool = jax.tree.unflatten(
+        jax.tree.structure(kv.pool),
+        [jax.random.normal(k, x.shape).astype(x.dtype)
+         for k, x in zip(fill, jax.tree.leaves(kv.pool))])
+    ids = np.random.default_rng(offset).permutation(PNB - 1)
+
+    def table_of(first, entries):
+        """``entries`` distinct blocks, cut behind the chunk's last where the
+        table ends there."""
+        if ends_its_table:
+            entries = (first + real - 1) // PBS + 1
+        return jnp.asarray(ids[:entries], jnp.int32)
+    if name:
+        behind = int(kv_cache.blocks_behind_window(start, WIN, PBS)) * PBS
+        table = {"full": table_of(start, 6),
+                 "window": table_of(start - behind,
+                                    kv_cache.windowed_table_blocks(
+                                        BUCKET, WIN, PBS))}
+    else:
+        table = table_of(start, 6)
+    safe_pos = start + jnp.arange(BUCKET)
+    valid = jnp.arange(BUCKET) < real
+
+    def written(by_page, impl="gather"):
+        monkeypatch.setattr(kv_cache._Pages, "by_page", by_page)
+        slots = kind.chunk_slots(pool, table, start, safe_pos, valid, PBS)
+        mine = slots[name] if name else slots
+        assert isinstance(mine, kv_cache.PageSlots) == by_page
+        if impl == "gather":
+            return kind.attend_chunk(jax.tree.map(jnp.copy, pool), layer,
+                                     slots, table, start, impl, *args)[1]
+        # the kernel's write alone (the attention over the pages has its own
+        # interpreted cases elsewhere)
+        pages = kind.pages[name] if name else kind
+        at = kind.local[layer] if name else layer
+        new = pages._write_chunk(pool[name] if name else pool, at,
+                                 *args[-2:] if fmt != "latent" else args[2:3],
+                                 mine, impl)
+        return {**pool, name: new} if name else new
+
+    by_row = _but_the_trash(written(False), kv)
+    for impl in ("gather", "kernel_interpret"):
+        for got, want in zip(_but_the_trash(written(True, impl), kv), by_row):
+            np.testing.assert_array_equal(got, want)
+    # and the write moved something: the chunk's first row is in its slot
+    was = _but_the_trash(pool, kv)
+    assert any((a != b).any() for a, b in zip(by_row, was))
+
+
+@pytest.mark.parametrize("program,dtype,rows,by_page", [
+    ("prefill_chunk_g", jnp.float32, 32, True),
+    ("prefill_chunk_g", jnp.float32, 8, False),      # a bucket under a block
+    ("verify_chunk_g", jnp.float32, 5, False),       # speculation's k + 1
+    ("prefill_chunk_g", FP8, 32, False),             # scaled pages
+    ("verify_chunk_g", FP8, 32, False),
+    ("decode_step_g", jnp.float32, 4, False)],
+    ids=["chunk", "chunk-under-a-block", "verify-5-rows", "chunk-fp8",
+         "verify-fp8", "decode"])
+def test_which_step_programs_write_a_page_at_a_time(program, dtype, rows,
+                                                    by_page):
+    """The page write is in a chunk program whose bucket is a block or more
+    over plain pages and in no other: speculation's verifier at ``k + 1``
+    rows, a bucket under a block, every program over fp8 scaled pages and the
+    decode step are traced as they were, the row scatter and nothing of
+    ``write_chunk_pages``. What the engine counts (``chunk_by_page``) is the
+    same static rule."""
+    from deepspeed_tpu.inference.v2 import generic_decode as gd
+    from deepspeed_tpu.inference.v2.modules import policy_for
+    from deepspeed_tpu.models.llama import (TINY_LLAMA, LlamaConfig,
+                                            LlamaForCausalLM)
+    cfg = LlamaConfig(**{**TINY_LLAMA.__dict__, "dtype": jnp.float32,
+                         "max_seq_len": 512})
+    params = jax.eval_shape(
+        lambda key: LlamaForCausalLM(cfg).init(
+            key, {"input_ids": np.zeros((1, 8), np.int32)})["params"],
+        jax.random.PRNGKey(0))
+    policy = policy_for(cfg)
+    spec = policy.cache_spec(cfg)
+    kv = BlockedKVCache(KVCacheConfig(
+        num_layers=spec.num_layers, num_kv_heads=spec.num_kv_heads,
+        head_dim=spec.head_dim, block_size=16, num_blocks=16, dtype=dtype))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode_step_g":
+        tail = (ints(rows), ints(rows), ints(rows, 4),
+                jax.ShapeDtypeStruct((rows,), jnp.bool_))
+    else:
+        tail = (ints(rows), ints(), ints(4), ints())
+        assert kv.chunk_by_page(rows) == by_page
+    text = getattr(gd, program).lower(
+        params, kv.pool, *tail, policy=policy, cfg=cfg, block_size=16,
+        attn_impl="gather").as_text()
+    assert ("write_chunk_pages" in text) == by_page
+    if not by_page:
+        assert "stablehlo.scatter" in text
+
+
+@pytest.mark.parametrize("pages", ["head_pages", "latent_pages",
+                                   "pages_by_layer_kind", "split_head_pages",
+                                   "state_slots"])
+def test_served_tokens_are_the_row_scatters_over_chunks_at_unaligned_starts(
+        monkeypatch, pages):
+    """A toy model of each pool format served greedily, a long prompt's
+    chunks beside rows that decode (a step of 32 tokens less the decode rows:
+    the next chunk starts at 31, 61, ... in blocks of 8): token for token
+    what the same engine delivers with every chunk written a row at a time,
+    as before PR 55."""
+    from deepspeed_tpu.inference.v2 import kv_cache
+    from deepspeed_tpu.telemetry.tracer import get_tracer
+    from test_fused_step import KINDS, _engine
+    from test_step_in_flight import _prompts, _served
+    build, options = KINDS[pages]
+    cfg, params = build()
+    prompts, budgets = _prompts((11, 93, 45), seed=8), (14, 5, 7)
+    tracer = get_tracer()
+    was = tracer.enabled
+    tracer.configure(enabled=True)
+    tracer.clear()
+    try:
+        by_page, _ = _served(_engine(cfg, params, **options), prompts,
+                             budgets)
+        chunks = [e[7] for e in tracer.events_snapshot()
+                  if e[1] == "serve/prefill_chunk"]
+    finally:
+        tracer.configure(enabled=was)
+        tracer.clear()
+    assert sum(c["start"] % 8 != 0 for c in chunks) >= 3
+    assert all(c["kv_rows_by_page"] == c["tokens"] for c in chunks)
+    # the same programs traced anew with the row scatter in every chunk
+    monkeypatch.setattr(kv_cache._Pages, "by_page", False)
+    jax.clear_caches()
+    try:
+        by_row, _ = _served(_engine(cfg, params, **options), prompts, budgets)
+    finally:
+        jax.clear_caches()
+    assert by_page == by_row and [len(g) for g in by_page] == list(budgets)

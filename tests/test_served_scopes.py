@@ -161,6 +161,36 @@ def test_rows_gathered_are_the_step_programs_ids_size(toy, tracing,
     assert eng._pending_gathered == 0
 
 
+def test_spans_say_how_many_rows_went_to_the_pool_and_how_many_by_page(
+        toy, tracing):
+    """``kv_rows_written`` and ``kv_rows_by_page``, one a span: a chunk's
+    tokens and the decode rows that rode in its program; by page the chunk's
+    tokens where its bucket is a block or more (blocks of 16 here, as the
+    smallest bucket: the engine's rule says no to a bucket of 8), never a
+    decode row."""
+    _, cfg, params = toy
+    eng = _engine(cfg, params)
+    eng.put([1], [[7] * 39])            # chunks of 32 and 7 (a bucket of 16)
+    eng.step()
+    eng.put([2], [[9] * 5])             # a chunk beside a decode row
+    eng.step()
+    eng.step()
+    spans = [e for e in tracing.events_snapshot()
+             if e[1] in ("serve/prefill_chunk", "serve/step_decode")]
+    chunks = [e[7] for e in spans if e[1] == "serve/prefill_chunk"]
+    decodes = [e[7] for e in spans if e[1] == "serve/step_decode"]
+    assert [(c["tokens"], c["bucket"]) for c in chunks] \
+        == [(32, 32), (7, 16), (5, 16)]
+    assert [c[names.KV_ROWS_WRITTEN_ARG] for c in chunks] \
+        == [32, 7, 5 + chunks[2]["fused_rows"]]
+    assert chunks[2]["fused_rows"] == 1
+    assert [c[names.KV_ROWS_BY_PAGE_ARG] for c in chunks] == [32, 7, 5]
+    assert eng.kv.chunk_by_page(16) and not eng.kv.chunk_by_page(8)
+    assert decodes and all(
+        d[names.KV_ROWS_WRITTEN_ARG] == d["batch"]
+        and d[names.KV_ROWS_BY_PAGE_ARG] == 0 for d in decodes)
+
+
 def test_an_untraced_engine_counts_no_gathered_rows(toy):
     _, cfg, params = toy
     assert not get_tracer().enabled

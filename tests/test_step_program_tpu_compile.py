@@ -869,6 +869,70 @@ def test_split_head_pages_step_program_updates_four_pools_in_place_on_a_v5e(
         assert stats.temp_size_in_bytes < 1 << 30
 
 
+# --- a chunk's rows a page at a time ------------------------------------------
+
+def _row_scatters(text, pool):
+    """The scatters of a compiled program whose operand is one of ``pool``'s
+    leaves, however XLA sees its shape (the row scatter's is the pool as
+    rows, ``bf16[412160,256]`` of ``[5, 8, 161, 64, 256]``): by the element
+    count of what the scatter makes."""
+    sizes = {int(np.prod(p.shape)) for p in jax.tree.leaves(pool)}
+    found = []
+    for line in text.splitlines():
+        made = re.search(r"= \(?\w+\[([\d,]+)\]\S* scatter\(", line)
+        if made and int(np.prod([int(d) for d in
+                                 made.group(1).split(",")])) in sizes:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _paged_program(one_chip, pages, fn_name):
+    """(config, arguments, pool) of ``fn_name`` over a pool of ``pages``."""
+    if pages == "plain":
+        args, pool = _shapes(one_chip, fn_name, "plain")
+        return CFG, args, pool
+    return {"latent": _latent_shapes, "layer-kind": _laguna_shapes,
+            "split": _mimo_shapes}[pages](one_chip, fn_name)
+
+
+@pytest.mark.parametrize("pages", ["plain", "split", "latent", "layer-kind"])
+def test_a_chunk_program_writes_its_rows_a_page_at_a_time_in_place(
+        one_chip, as_on_a_tpu, pages):
+    """A chunk program over plain head pages, pages whose keys are wider than
+    their values (four pools), a latent plane and head pages by layer kind, at
+    the cells' shapes: no scatter of one row an update on any pool (it moved
+    512 bytes an update at 82 ns each, a sixth of three cells' busy time:
+    PERF.md section 7, PR 54), the page write's kernel once a pool format of
+    the program whatever the layers, every pool aliased whole and nothing
+    pool-shaped copied; and the decode step keeps the row scatter (a batch's
+    rows lie in as many pages as sequences) and holds no page write."""
+    cfg, args, pool = _paged_program(one_chip, pages, "prefill_chunk_g")
+    how = dict(policy=policy_for(cfg), cfg=cfg, block_size=BLOCK,
+               attn_impl="kernel")
+    lowered = gd.prefill_chunk_g.lower(*args, **how)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    leaves = jax.tree.leaves(pool)
+    assert _row_scatters(text, pool) == []
+    bodies = lowered.as_text().count('kernel_name = "kv_page_write"')
+    # one body a shape of (pool, rows): K and V planes of one array are one,
+    # a split kind's K and V pools two, two layer kinds two
+    assert bodies == {"plain": 1, "latent": 1, "layer-kind": 2,
+                      "split": 4}[pages]
+    assert "kv_page_write" in text
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= sum(
+        int(np.prod(p.shape)) * p.dtype.itemsize for p in leaves)
+    assert text.splitlines()[0].count("may-alias") >= len(leaves)
+    for p in leaves:
+        assert _pool_shaped_moves(text, p) == []
+
+    cfg, args, pool = _paged_program(one_chip, pages, "decode_step_g")
+    text = gd.decode_step_g.lower(*args, **how).compile().as_text()
+    assert "kv_page_write" not in text
+    assert len(_row_scatters(text, pool)) >= len(leaves)
+
+
 # --- every layer ONE mixer: a state, experts of two matrices, or pages --------
 # NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, one layer of each
 # kind with the chip's 64 of 128 experts, the cell's 128 slots and 6,145
